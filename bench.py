@@ -19,6 +19,7 @@ import json
 import os
 import subprocess
 import sys
+import tempfile
 import time
 from functools import lru_cache, partial
 
@@ -30,7 +31,11 @@ MAX_NEW = 128
 ROUNDS = 10
 
 REPO = os.path.dirname(os.path.abspath(__file__))
-LOCAL_CKPT_DIR = os.path.join(REPO, "data", "gpt2-local")
+# Half a gigabyte of seeded artifacts: built under the temp directory, never
+# inside the checkout (a chip call copies the tree as it stands on disk).
+LOCAL_CKPT_DIR = os.path.join(
+    tempfile.gettempdir(), "dlrl_tpu_artifacts", "gpt2-local"
+)
 
 
 def ensure_local_artifacts() -> dict:
@@ -43,7 +48,8 @@ def ensure_local_artifacts() -> dict:
     if not all(os.path.exists(p) for p in (ckpt, vocab, merges)):
         subprocess.run(
             [sys.executable,
-             os.path.join(REPO, "scripts", "make_local_checkpoint.py")],
+             os.path.join(REPO, "scripts", "make_local_checkpoint.py"),
+             "--out", LOCAL_CKPT_DIR, "--bert-out", ""],
             check=True, timeout=900, cwd=REPO,
         )
     return {"checkpoint": ckpt, "vocab_path": vocab, "merges_path": merges}
@@ -424,8 +430,8 @@ def bench_sweep(model: str = "gpt2", tp: int = 1, quant: bool = False,
     Each point is an independent `bench_paged` run (fresh engine, same
     seeded workload scaled to the slot count), so a sweep answers the
     ROADMAP's open questions — slot counts beyond 16, inflight-depth,
-    and megastep ladders — in one command whose output is `jq`-able
-    straight into BENCH_NOTES. `rounds` defaults low (2) because a sweep
+    and megastep ladders — in one command whose output is one `jq`-able
+    JSON record per point. `rounds` defaults low (2) because a sweep
     multiplies runs; raise it for tighter chip numbers. CPU-smoked in
     tests/test_bench_record.py so the grid path cannot rot between chip
     attachments."""

@@ -2,9 +2,9 @@
 marked as intended.
 
 The paged engine's throughput history is a history of accidental host
-syncs: a reap-time `device_get` serialized the loop at ~270 tok/s until
-the copies were started asynchronously (engine/paged.step), and chunk=1
-dispatch paid a ~100 ms round trip per token. A `.item()`, `float()`,
+syncs: a reap-time `device_get` serialized the loop until the copies were
+started asynchronously (engine/paged.step), and chunk=1 dispatch paid a
+host round trip per token. A `.item()`, `float()`,
 `np.asarray(...)` or `jax.device_get(...)` dropped into the dispatch path
 is invisible in review and costs a full device round trip per call.
 

@@ -22,11 +22,7 @@ a full reference-topology example.
 from __future__ import annotations
 
 import dataclasses
-
-try:
-    import tomllib  # Python >= 3.11
-except ImportError:  # pragma: no cover - version-dependent
-    import tomli as tomllib  # type: ignore[no-redef]
+import tomllib
 from typing import Any, Dict, List, Optional
 
 
@@ -499,10 +495,10 @@ class TelemetryConfig:
     slow_burn: float = 1.0          # slow-window threshold (>= 1 means the
     #                                 budget is being spent faster than it
     #                                 accrues)
-    chip_ceiling_tokens_per_s: float = 61500.0  # measured saturation
-    #                                 throughput per chip (BENCH_NOTES
-    #                                 round 5, int8 batch 128+); the
-    #                                 capacity model's utilization anchor
+    chip_ceiling_tokens_per_s: Optional[float] = None  # saturation
+    #                                 throughput per chip, MEASURED on the
+    #                                 serving device; the utilization
+    #                                 shares are reported only when set
 
     def __post_init__(self) -> None:
         if self.sample_interval_s <= 0 or self.ring_points < 2:
@@ -516,7 +512,8 @@ class TelemetryConfig:
             )
         if self.fast_burn <= 0 or self.slow_burn <= 0:
             raise ValueError("[telemetry] burn thresholds must be > 0")
-        if self.chip_ceiling_tokens_per_s <= 0:
+        if (self.chip_ceiling_tokens_per_s is not None
+                and self.chip_ceiling_tokens_per_s <= 0):
             raise ValueError(
                 "[telemetry] chip_ceiling_tokens_per_s must be > 0"
             )

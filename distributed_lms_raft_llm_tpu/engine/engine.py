@@ -65,14 +65,15 @@ class EngineConfig:
     sp: int = 1
     # Fused Pallas decode attention (ops/attention.py). None = off: with the
     # cache's [.., S, 64] head-dim-minor layout the kernel's DMA runs at
-    # half-filled 128-lane tiles and measured slightly SLOWER end-to-end
-    # than XLA's einsum fusions (9.4k vs 9.9k tok/s, BENCH history); it
-    # stays available for explicit experiments (True) and as the base for a
-    # lane-packed cache layout. Not partition-aware: requires mesh size 1.
+    # half-filled 128-lane tiles, so it is not expected to beat XLA's
+    # einsum fusions as it stands (not measured on the v5e); it stays
+    # available for explicit experiments (True) and as the base for a
+    # lane-packed cache layout (ROADMAP S2). Not partition-aware: requires
+    # mesh size 1.
     fused_attention: Optional[bool] = None
     # Weight-only int8 ("int8") halves the parameter bytes the decode loop
-    # streams per step (models/quant.py) — the dominant cost on the bench
-    # chip. None = full-precision (bf16) weights. Composes with tp>1 (the
+    # streams per step (models/quant.py). None = full-precision (bf16)
+    # weights. Composes with tp>1 (the
     # partition rules shard the quantized {q, s} leaf pairs).
     quant: Optional[str] = None
     # int8 KV cache (per-slot scales, models/common.quantize_kv): halves
@@ -80,9 +81,8 @@ class EngineConfig:
     kv_quant: bool = False
     # Decode-segment count: the KV cache grows to each segment's high-water
     # mark instead of being final-size from step one, so attention streams
-    # only slots that can be valid yet (generate.decode; measured numbers
-    # in BENCH_NOTES.md). None = auto from the batch size (4 small / 8
-    # large); 1 = single full-size while_loop.
+    # only slots that can be valid yet (generate.decode). None = auto from
+    # the batch size (4 small / 8 large); 1 = single full-size while_loop.
     decode_segments: Optional[int] = None
     # Speculative decoding (engine/draft.py kernels): propose this many
     # prompt-lookup draft tokens per step and verify them in one forward

@@ -22,9 +22,8 @@ budget into `segments` spans, padding the cache to each span's high-water
 mark between the spans' while_loops. Every attention/softmax/scale op's
 cost is proportional to the cache length it reads, and with a 64-token
 prompt and 128 new tokens the final-size cache wastes ~1/3 of that traffic
-on slots that are not valid yet (measured 47% of the batch-32 decode step —
-profiles/decode_int8w_int8kv_r5_batch32.json); growing it in 4 segments
-recovers most of the waste for a few cheap pad-copies. The last segment's
+on slots that are not valid yet; growing it in 4 segments recovers most of
+the waste for a few cheap pad-copies. The last segment's
 cache is exactly `bucket + max_new_tokens`, so the no-silent-overflow
 precondition documented in models/gpt2.py still holds by construction.
 
@@ -57,9 +56,8 @@ class DecodeState(NamedTuple):
     The cache is prompt-sized coming out of `prefill`; `decode` pads it to
     each segment's high-water mark (see module docstring). `seen` stays the
     dense [B, V] presence plane: a transcript-ids + scatter-min variant was
-    measured SLOWER (+~120 µs/step at batch 32 — TPU scatter serializes;
-    the one_hot|or update and fused mask read cost ~20 µs — see
-    BENCH_NOTES.md round-5 negative results).
+    tried and was slower (TPU scatter serializes; the one_hot|or update
+    and the fused mask read are cheap).
     """
 
     cache: KVCache
@@ -171,15 +169,14 @@ def decode(
     The token budget splits into `segments` spans; each span runs its own
     while_loop against a cache padded to that span's high-water mark, so
     attention streams only the slots that can be valid yet (module
-    docstring — measured ~47% of the batch-32 step was full-size KV reads).
+    docstring).
     A fully-EOS'd batch exits at the next span boundary: each span's cond
     starts false, so trailing spans cost one predicate each.
 
     segments=None picks from the (static) batch size: larger batches spend
     more of each step on KV reads, so finer segmentation pays there while
-    its fixed pad/loop overheads lose at small batches (measured on the
-    bench chip at 128 new tokens: batch 8 — 4 segs 14.4k tok/s vs 8 segs
-    12.7k; batch 32 — 8 segs 27.6k vs 4 segs 25.7k vs 16 segs 25.3k).
+    its fixed pad/loop overheads lose at small batches (the 4-small /
+    8-large split has not been re-measured on the v5e).
 
     Returns (result, final_state). The final state is returned so the
     engine's jit wrapper can donate the input state: the same-shaped

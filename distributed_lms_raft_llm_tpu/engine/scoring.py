@@ -1,8 +1,8 @@
 """Background bulk-scoring tenant: idle-lane harvest toward saturation.
 
-BENCH_NOTES pins chip saturation at ~61.5k tok/s (int8, batch 128+) while
-paged serving runs an order of magnitude below it — the gap is idle
-compute. This module turns `engine.score()` (log-likelihood grading,
+Interactive paged serving at tutoring batch sizes leaves most of the
+chip's throughput unused — the gap is idle compute. This module turns
+`engine.score()` (log-likelihood grading,
 course-material relevance, gate-threshold calibration corpora) into a
 schedulable second tenant:
 
@@ -262,13 +262,16 @@ class ScoringManager:
         *,
         max_job_texts: int = 4096,
         jobs_retained: int = 32,
-        chip_ceiling_tokens_per_s: float = 61500.0,
+        chip_ceiling_tokens_per_s: Optional[float] = None,
     ):
         self.engine = engine
         self.metrics = metrics
         self.max_job_texts = max(1, max_job_texts)
         self.jobs_retained = max(1, jobs_retained)
-        self.chip_ceiling_tokens_per_s = max(1.0, chip_ceiling_tokens_per_s)
+        # The device's saturation throughput, when one was measured and
+        # configured ([telemetry] chip_ceiling_tokens_per_s); without it
+        # the scoring_utilization share is not reported.
+        self.chip_ceiling_tokens_per_s = chip_ceiling_tokens_per_s
         # One quantum = one device batch = the largest batch bucket: the
         # single-dispatch granularity interactive work preempts at.
         self.quantum_texts = int(
@@ -481,14 +484,15 @@ class ScoringManager:
             window_tokens = sum(n for _, n in self._tok_window)
         if span > 0.2:
             tps = window_tokens / span
-            # The tenant-split utilization view: scoring's share of the
-            # measured chip ceiling, next to serving_tokens_per_s for the
-            # interactive tenant.
+            # The tenant-split utilization view: scoring's throughput next
+            # to serving_tokens_per_s for the interactive tenant, and its
+            # share of the chip ceiling where one is configured.
             self.metrics.set_gauge(metric.SCORING_TOKENS_PER_S, tps)
-            self.metrics.set_gauge(
-                metric.SCORING_UTILIZATION,
-                tps / self.chip_ceiling_tokens_per_s,
-            )
+            if self.chip_ceiling_tokens_per_s:
+                self.metrics.set_gauge(
+                    metric.SCORING_UTILIZATION,
+                    tps / self.chip_ceiling_tokens_per_s,
+                )
 
 
 def score_admin_get(path: str,

@@ -1,10 +1,8 @@
 """Weight-only int8 quantization for TPU serving.
 
-The decode loop is HBM-bandwidth-bound: every step streams every parameter.
-On the bench chip the measured streaming ceiling is ~275 GB/s (far below
-the v5e datasheet figure — the chip is virtualized), which makes parameter
-bytes the dominant cost for GPT-2-class models. Weight-only int8 halves
-them: weights store as int8 with a per-output-channel symmetric scale and
+The decode loop is HBM-bandwidth-bound: every step streams every parameter,
+which makes parameter bytes a first-order cost for GPT-2-class models at
+tutoring batch sizes. Weight-only int8 halves them: weights store as int8 with a per-output-channel symmetric scale and
 dequantize on the fly inside the matmul's operand load (XLA fuses the
 convert), so HBM sees int8 while the MXU still computes in bf16/f32.
 Activations, norms, biases, and the position table stay full precision —

@@ -134,6 +134,27 @@ def make_hybrid_mesh(
     return Mesh(devices, axis_order)
 
 
+def device_info() -> dict:
+    """What this process computes on, as JAX reports it (initializes the
+    backend): the fields every entry point logs at start-up and the
+    serving /healthz repeats, so no run is ever silently a CPU run."""
+    devices = jax.devices()
+    return {
+        "platform": devices[0].platform,
+        "kind": devices[0].device_kind,
+        "count": len(devices),
+    }
+
+
+def device_memory() -> dict:
+    """Live allocator figures of the first local device (bytes), or {}
+    where the backend reports none (the CPU)."""
+    stats = jax.local_devices()[0].memory_stats() or {}
+    return {k: stats[k] for k in
+            ("bytes_in_use", "peak_bytes_in_use", "bytes_limit")
+            if k in stats}
+
+
 def single_device_mesh() -> Mesh:
     """Trivial mesh (1 chip) — lets the same pjit code path serve everywhere."""
     return make_mesh({})

@@ -14,11 +14,14 @@ inserted automatically by XLA from these specs.
 
 from __future__ import annotations
 
+import logging
 import re
-from typing import Any, Dict, List, Sequence, Tuple
+from typing import Any, Dict, List, Optional, Sequence, Tuple
 
 import jax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec
+
+log = logging.getLogger(__name__)
 
 P = PartitionSpec
 
@@ -209,15 +212,46 @@ def _key_str(k) -> str:
     return str(k)
 
 
-def match_partition_rules(rules: Rules, tree: Any) -> Any:
-    """Return a pytree of PartitionSpec matching `tree`'s structure."""
+def _fit_spec(spec: PartitionSpec, shape: Tuple[int, ...], mesh: Mesh,
+              path: str) -> PartitionSpec:
+    """`spec` with every axis that does not split `shape` evenly on `mesh`
+    replicated instead (canonical spelling: trailing Nones dropped).
+
+    The published GPT-2 vocab (50,257 = 29 x 1733) has no even split over
+    any tp the head counts admit, so a vocab-sharded embedding cannot be
+    placed at real width; replicating that one table (its lookup and the
+    logits matmul then run whole on every shard) keeps the rest of the
+    model sharded, where refusing would put gpt2 at tp > 1 out of reach.
+    """
+    fitted = []
+    for dim, axes in zip(shape, tuple(spec)):
+        names = (axes,) if isinstance(axes, str) else tuple(axes or ())
+        ways = 1
+        for name in names:
+            ways *= mesh.shape.get(name, 1)
+        fitted.append(axes if dim % ways == 0 else None)
+    while fitted and fitted[-1] is None:
+        fitted.pop()
+    if tuple(fitted) != tuple(spec):
+        log.warning("%s %s does not split evenly as %s on mesh %s: "
+                    "placed as %s", path, shape, spec, dict(mesh.shape),
+                    P(*fitted))
+    return P(*fitted)
+
+
+def match_partition_rules(rules: Rules, tree: Any,
+                          mesh: Optional[Mesh] = None) -> Any:
+    """Return a pytree of PartitionSpec matching `tree`'s structure. With
+    `mesh`, a rule's spec is fitted to each leaf's shape (`_fit_spec`)."""
 
     def spec_for(path: str, leaf) -> PartitionSpec:
         if getattr(leaf, "ndim", 0) == 0:
             return P()
         for pattern, spec in rules:
             if re.search(pattern, path):
-                return spec
+                if mesh is None:
+                    return spec
+                return _fit_spec(spec, leaf.shape, mesh, path)
         raise ValueError(f"no partition rule matched {path!r}")
 
     paths_and_leaves, treedef = jax.tree_util.tree_flatten_with_path(tree)
@@ -234,7 +268,7 @@ def shard_tree(tree: Any, mesh: Mesh, rules: Rules) -> Any:
     Specs naming axes of size 1 are harmless; on a single-device mesh this
     degrades to replication, so the same code path runs on 1 chip or 256.
     """
-    specs = match_partition_rules(rules, tree)
+    specs = match_partition_rules(rules, tree, mesh)
     return jax.tree.map(
         lambda x, s: jax.device_put(x, NamedSharding(mesh, s)), tree, specs
     )
@@ -242,6 +276,6 @@ def shard_tree(tree: Any, mesh: Mesh, rules: Rules) -> Any:
 
 def shardings_for(tree: Any, mesh: Mesh, rules: Rules) -> Any:
     """Pytree of NamedSharding (for jit in_shardings/out_shardings)."""
-    specs = match_partition_rules(rules, tree)
+    specs = match_partition_rules(rules, tree, mesh)
     return jax.tree.map(lambda s: NamedSharding(mesh, s), specs,
                         is_leaf=lambda x: isinstance(x, PartitionSpec))

@@ -33,10 +33,7 @@ from typing import Callable
 import jax
 import jax.numpy as jnp
 
-try:
-    from jax import shard_map  # jax >= 0.5
-except ImportError:  # pragma: no cover - version-dependent
-    from jax.experimental.shard_map import shard_map
+from jax import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 LayerFn = Callable[[jax.Array, jax.Array], jax.Array]  # (layer_params, x) -> x

@@ -36,10 +36,7 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 
-try:
-    from jax import shard_map  # jax >= 0.5
-except ImportError:  # pragma: no cover - version-dependent
-    from jax.experimental.shard_map import shard_map
+from jax import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 NEG_INF = -1e30

@@ -21,6 +21,7 @@ from __future__ import annotations
 import argparse
 import asyncio
 import hashlib
+import importlib.metadata
 import json
 import logging
 import time
@@ -38,8 +39,14 @@ from ..engine import (
     TutoringEngine,
 )
 from ..engine.scoring import score_admin_get
+from ..parallel.mesh import (
+    device_info,
+    device_memory,
+    initialize_multihost,
+)
 from ..proto import lms_pb2, rpc
 from ..utils import auth
+from ..utils.compilation import cache_stats
 from ..utils.guards import make_serving_watchdog
 from ..utils.metrics import Metrics
 from ..utils.resilience import (
@@ -379,13 +386,19 @@ def make_tutoring_admin(service: TutoringService, scorer=None):
 def make_tutoring_health(service: TutoringService, queue,
                          engine_name: str, max_queue: int, scorer=None):
     """/healthz provider: admission pressure + fleet lifecycle state
-    (the router's health poller reads `draining`/`queued`/`node_id`)."""
+    (the router's health poller reads `draining`/`queued`/`node_id`),
+    plus the device this node computes on and its compile-cache account
+    (a JAX-free launcher reads both here)."""
+    device = device_info()
 
     def health() -> dict:
         doc = {
             "ok": True,
             "engine": engine_name,
             "node_id": service.node_id,
+            "device": device,
+            "device_memory": device_memory(),
+            "compile_cache": cache_stats(),
             # Admission pressure at a glance (details in /metrics:
             # shed_overload / shed_expired / engine_batches). `queued`
             # is what the bound is enforced against — for the paged
@@ -428,7 +441,7 @@ async def serve_async(
     scoring: bool = False,
     scoring_max_job_texts: int = 4096,
     scoring_jobs_retained: int = 32,
-    scoring_chip_ceiling: float = 61500.0,
+    scoring_chip_ceiling: Optional[float] = None,
     session_ttl_s: float = 600.0,
     session_max: int = 256,
 ) -> grpc.aio.Server:
@@ -543,6 +556,13 @@ async def serve_async(
         log.info("health/metrics endpoint on http://127.0.0.1:%d", bound)
     log.info("tutoring server listening on %d", server._port)
     return server
+
+
+def _dist_version(dist: str) -> str:
+    try:
+        return importlib.metadata.version(dist)
+    except importlib.metadata.PackageNotFoundError:
+        return "absent"
 
 
 def main(argv=None) -> None:
@@ -697,7 +717,8 @@ def main(argv=None) -> None:
     )
     parser.add_argument(
         "--jax-platform", default="default", choices=["cpu", "default"],
-        help="'cpu' for CPU-only runs (tests/dev); default uses the TPU",
+        help="'cpu' for CPU-only runs (tests/dev); 'default' means the "
+        "TPU: the server refuses to start on anything else",
     )
     args = parser.parse_args(argv)
     args.telemetry = not args.no_telemetry
@@ -748,7 +769,7 @@ def main(argv=None) -> None:
         configure_from(cfg.tracing)
     else:
         args.sampling_overrides = {}
-        args.scoring_chip_ceiling = 61500.0
+        args.scoring_chip_ceiling = None
         args.session_ttl_s = 600.0
         args.session_max = 256
     if args.jax_platform == "cpu":
@@ -763,10 +784,22 @@ def main(argv=None) -> None:
     # Multi-host: joins the JAX cluster when JAX_COORDINATOR_ADDRESS (or
     # Cloud TPU metadata) is present, making jax.devices() global so the
     # tp/dp mesh spans hosts; no-op for the common single-host run.
-    from ..parallel.mesh import initialize_multihost
-
     if initialize_multihost():
         log.info("joined multi-host JAX cluster")
+
+    # Say what this node computes on, and never serve from a CPU that
+    # nobody asked for: without --jax-platform cpu the device is the TPU.
+    device = device_info()
+    log.info(
+        "device: platform=%s device_kind=%s count=%d jax=%s jaxlib=%s "
+        "libtpu=%s", device["platform"], device["kind"], device["count"],
+        *(_dist_version(d) for d in ("jax", "jaxlib", "libtpu")),
+    )
+    if device["platform"] != "tpu" and args.jax_platform != "cpu":
+        raise SystemExit(
+            f"no TPU: JAX initialized platform {device['platform']!r} "
+            "(pass --jax-platform cpu for a CPU-only run)"
+        )
 
     if args.strict_dispatch:
         # Before engine construction so warmup runs under the same guard:

@@ -98,7 +98,7 @@ def train_state_shardings(state, mesh: Mesh):
     param_specs = partition.match_partition_rules(
         partition.RULES_FOR["gpt2_moe"] if is_moe_state
         else partition.GPT2_RULES,
-        state["params"],
+        state["params"], mesh,
     )
     if mesh.shape.get("pp", 1) > 1:
         param_specs["blocks"] = jax.tree.map(

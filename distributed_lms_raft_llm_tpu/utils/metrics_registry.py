@@ -395,7 +395,7 @@ SERVING_TOKENS_PER_S = gauge(
     "recent serving throughput on the paged engine: emitted tokens per "
     "second over the last few seconds of reaps — the utilization "
     "numerator the capacity model divides by the chip's saturation "
-    "ceiling (BENCH_NOTES: ~61.5k tok/s int8 at batch 128+)",
+    "ceiling ([telemetry] chip_ceiling_tokens_per_s, when measured)",
 )
 SERVING_QUEUE_DEPTH = gauge(
     "serving_queue_depth",
@@ -432,9 +432,10 @@ SCORING_TOKENS_PER_S = gauge(
 )
 SCORING_UTILIZATION = gauge(
     "scoring_utilization",
-    "scoring_tokens_per_s as a fraction of the measured chip saturation "
-    "ceiling (BENCH_NOTES: ~61.5k tok/s int8 at batch 128+) — how much "
-    "of the idle headroom the background tenant is actually harvesting",
+    "scoring_tokens_per_s as a fraction of the chip saturation ceiling "
+    "([telemetry] chip_ceiling_tokens_per_s; absent until one is "
+    "measured and configured) — how much of the idle headroom the "
+    "background tenant is actually harvesting",
 )
 SCORING_QUANTA = counter(
     "scoring_quanta",

@@ -8,8 +8,9 @@ weights are unobtainable. What CAN be real offline:
   artifact `models.convert.load_safetensors` + `gpt2_params_from_hf`
   consume in production;
 - the tokenizer: a REAL byte-level BPE trained with the HF `tokenizers`
-  trainer on local text, emitting the standard `vocab.json`/`merges.txt`
-  our `BPETokenizer` loads.
+  trainer on this repo's own text (docs + sources, nothing outside the
+  checkout, so every machine trains the same vocab), emitting the standard
+  `vocab.json`/`merges.txt` our `BPETokenizer` loads.
 
 The bench and servers then run the identical code path a user with hub
 access runs — point `--checkpoint/--vocab/--merges` at downloaded files and
@@ -30,25 +31,22 @@ REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, REPO)
 
 
-def build_corpus(out_path: str, max_files: int = 400) -> str:
-    """Concatenate local prose/code into a BPE training corpus."""
+def build_corpus(out_path: str) -> str:
+    """Concatenate the repo's own prose/code into a BPE training corpus
+    (files of the checkout only: the same text on every machine)."""
     sources: list[str] = []
     for pattern in (
         f"{REPO}/*.md",
         f"{REPO}/distributed_lms_raft_llm_tpu/**/*.py",
         f"{REPO}/tests/*.py",
-        "/usr/lib/python3*/[a-z]*.py",
-        "/usr/share/doc/**/*.txt",
+        f"{REPO}/scripts/*.py",
     ):
-        sources.extend(sorted(glob.glob(pattern, recursive=True))[:max_files])
+        sources.extend(sorted(glob.glob(pattern, recursive=True)))
     with open(out_path, "w", encoding="utf-8") as out:
         for src in sources:
-            try:
-                with open(src, encoding="utf-8", errors="ignore") as f:
-                    out.write(f.read())
-                    out.write("\n")
-            except OSError:
-                continue
+            with open(src, encoding="utf-8", errors="ignore") as f:
+                out.write(f.read())
+                out.write("\n")
     return out_path
 
 
@@ -95,27 +93,35 @@ def build_bert_local(out_dir: str, seed: int = 0,
         print(f"wrote bert-base checkpoint: {n/1e6:.0f}M params -> {ckpt}")
 
 
-def build_gpt2_local(out_dir: str, model: str = "gpt2", seed: int = 0,
-                     vocab_size: int = 50257) -> None:
-    """data/gpt2-local: byte-level BPE vocab/merges trained on local text +
-    a full-size HF-layout GPT2LMHeadModel `.safetensors` (seeded random
-    weights) consumed through the identical `convert.gpt2_params_from_hf`
-    path pretrained weights use."""
+def build_gpt2_vocab(out_dir: str, vocab_size: int = 50257) -> tuple:
+    """Byte-level BPE `vocab.json` + `merges.txt` trained on the repo's own
+    text (idempotent); returns the two paths. Needs `tokenizers` only —
+    chip_smoke.py builds its serving vocab with this, JAX-free."""
     os.makedirs(out_dir, exist_ok=True)
-    ckpt = os.path.join(out_dir, "model.safetensors")
     vocab = os.path.join(out_dir, "vocab.json")
     merges = os.path.join(out_dir, "merges.txt")
-
     if not (os.path.exists(vocab) and os.path.exists(merges)):
         import tokenizers
 
         corpus = build_corpus(os.path.join(out_dir, "corpus.txt"))
         bpe = tokenizers.ByteLevelBPETokenizer()
         bpe.train([corpus], vocab_size=vocab_size, min_frequency=2,
-                  special_tokens=["<|endoftext|>"])
+                  special_tokens=["<|endoftext|>"], show_progress=False)
         bpe.save_model(out_dir)
         os.remove(corpus)
-        print(f"trained BPE vocab: {bpe.get_vocab_size()} tokens -> {vocab}")
+        print(f"trained BPE vocab: {bpe.get_vocab_size()} tokens -> {vocab}",
+              file=sys.stderr)
+    return vocab, merges
+
+
+def build_gpt2_local(out_dir: str, model: str = "gpt2", seed: int = 0,
+                     vocab_size: int = 50257) -> None:
+    """data/gpt2-local: byte-level BPE vocab/merges trained on local text +
+    a full-size HF-layout GPT2LMHeadModel `.safetensors` (seeded random
+    weights) consumed through the identical `convert.gpt2_params_from_hf`
+    path pretrained weights use."""
+    build_gpt2_vocab(out_dir, vocab_size)
+    ckpt = os.path.join(out_dir, "model.safetensors")
 
     if not os.path.exists(ckpt):
         import torch

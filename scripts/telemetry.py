@@ -16,12 +16,12 @@ percentiles — the time dimension `/metrics` snapshots alone can't show:
     # Fit the capacity model over an exported timeline (or a semester-sim
     # BENCH record, which embeds one under "timeline"):
     python scripts/telemetry.py --capacity run_timeline.json \
-        --slo-p95 6.0 --ceiling 61500
+        --slo-p95 6.0 [--ceiling <measured tok/s>]
 
 `--capacity` emits ONE JSON line: req/s per node at the SLO — the
 demonstrated load under which the p95 bound still held, plus the
-utilization extrapolation (serving tok/s against the chip's measured
-saturation ceiling, BENCH_NOTES round 5) and the flight-recorder stage
+utilization extrapolation (serving tok/s against the chip's saturation
+ceiling, where one was measured and given) and the flight-recorder stage
 p95s when available. This artifact is what the ROADMAP's router and
 autoscaler consume: "how many req/s can one node take before the SLO
 goes" as a measured number instead of a guess.
@@ -65,7 +65,7 @@ _DASH_ROWS: Tuple[Tuple[str, str, str], ...] = (
     ("tick stalls/s", "rate", "raft_tick_stalls"),
     ("serving tok/s", "gauge", "serving_tokens_per_s"),
     # The tenant split: background bulk scoring's share of the chip next
-    # to interactive serving (utilization is vs the 61.5k ceiling).
+    # to interactive serving (utilization is vs the configured ceiling).
     ("scoring tok/s", "gauge", "scoring_tokens_per_s"),
     ("scoring util", "gauge", "scoring_utilization"),
     ("score quanta/s", "rate", "scoring_quanta"),
@@ -229,7 +229,7 @@ def fit_capacity(
     doc: Dict[str, Any],
     *,
     slo_p95_s: float,
-    ceiling_tokens_per_s: float,
+    ceiling_tokens_per_s: Optional[float],
     node: Optional[str] = None,
     stage_p95s: Optional[Dict[str, Dict[str, float]]] = None,
     bins: int = 8,
@@ -244,7 +244,9 @@ def fit_capacity(
     highest load bin whose p95 held the SLO. When the run never pushed
     past the SLO the result is a demonstrated LOWER bound
     (`slo_saturated: false`) and the utilization extrapolation (tokens/s
-    against the chip ceiling) says how much headroom the fit left."""
+    against the chip ceiling) says how much headroom the fit left — where
+    no ceiling was measured (`ceiling_tokens_per_s=None`) its shares are
+    None, never a figure borrowed from another device."""
     if "timeline" in doc and isinstance(doc["timeline"], dict):
         if stage_p95s is None:
             stage_p95s = (doc.get("slos") or {}).get("stage_p95s")
@@ -321,14 +323,15 @@ def fit_capacity(
         utilization = {
             "peak_tokens_per_s": round(peak_tokens, 1),
             "chip_ceiling_tokens_per_s": ceiling_tokens_per_s,
-            "peak_fraction": round(peak_tokens / ceiling_tokens_per_s, 4),
+            "peak_fraction": round(peak_tokens / ceiling_tokens_per_s, 4)
+            if ceiling_tokens_per_s else None,
             "tokens_per_req": round(tokens_per_req, 1),
             # Where the chip itself would cap req/s if the SLO never
             # binds first — the extrapolated ceiling, NOT a demonstrated
             # number.
             "token_limited_req_s": round(
                 ceiling_tokens_per_s / tokens_per_req, 2
-            ) if tokens_per_req > 0 else None,
+            ) if ceiling_tokens_per_s and tokens_per_req > 0 else None,
         }
     qdepths = sorted(s["queue_depth"] for s in samples)
     service_p95 = None
@@ -399,7 +402,8 @@ def main(argv: Optional[List[str]] = None) -> int:
                          "slo_answer_p95_s, else 6.0)")
     ap.add_argument("--ceiling", type=float, default=None,
                     help="chip saturation tok/s (default: [telemetry] "
-                         "chip_ceiling_tokens_per_s, else 61500)")
+                         "chip_ceiling_tokens_per_s, else none: the "
+                         "utilization shares are left out)")
     ap.add_argument("--stage-p95s", default=None,
                     help="capacity: JSON file of flight-recorder stage "
                          "p95s to fold into the model")
@@ -407,7 +411,7 @@ def main(argv: Optional[List[str]] = None) -> int:
 
     interval = 1.0
     slo_p95 = 6.0
-    ceiling = 61500.0
+    ceiling = None
     degraded_bound = 0.5
     windows = {"fast": 60.0, "slow": 600.0}
     if args.config:

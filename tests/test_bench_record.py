@@ -1,7 +1,7 @@
-"""CPU smoke of the BENCH record paths (BENCH_NOTES' still-unmeasured
+"""CPU smoke of the BENCH record paths (the still-unmeasured
 `--paged --spec-tokens` configurations).
 
-The real-chip numbers land in BENCH_NOTES when a TPU is attached; these
+Real-chip numbers come only from a chip run (PERF.md); these
 seeded tiny-model runs pin the RECORD path meanwhile — both harnesses
 must keep emitting BENCH-schema dicts that carry the paged+spec fields
 AND the new megastep knobs (megastep/megastep_max/chunk/inflight plus the
@@ -95,8 +95,8 @@ def test_bench_paged_fused_admission_record_smoke():
 def test_bench_sweep_grid_smoke():
     """bench.py --sweep: one BENCH-schema JSON record per
     (slots, inflight, megastep) grid point, each carrying the megastep
-    knobs and the admission-stall fields — the round-6 grid runner the
-    next chip-attached session executes verbatim (BENCH_NOTES round 6)."""
+    knobs and the admission-stall fields — the grid runner a
+    chip-attached session executes verbatim."""
     from bench import bench_sweep
 
     grid = bench_sweep(

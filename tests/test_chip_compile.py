@@ -1,0 +1,251 @@
+"""Ask the TPU v5e's compiler, without a chip, whether it accepts the main
+path's kernel and step programs at the widths users run.
+
+The TPU compiler is installed wherever the tests run; it compiles for a
+chip that is DESCRIBED (`v5e:2x2`), not attached. A compile that passes
+here is not a chip run — nothing executes — but it refuses what the chip
+would refuse: a kernel over its VMEM scope, a misaligned block, a program
+that does not fit HBM, a sharding that leaves a plane whole on one device.
+
+Everything that touches the topology lives in the module-scoped fixtures
+of THIS file: only the xdist worker that runs this file loads the TPU
+library (one process at a time may hold it), and every worker collects the
+same tests.
+"""
+
+import dataclasses
+import os
+from functools import partial
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import NamedSharding, SingleDeviceSharding
+
+from distributed_lms_raft_llm_tpu import config as config_lib
+from distributed_lms_raft_llm_tpu.engine import paged
+from distributed_lms_raft_llm_tpu.engine.draft import build_drafts
+from distributed_lms_raft_llm_tpu.models import quant, registry
+from distributed_lms_raft_llm_tpu.ops.attention import decode_attention
+from distributed_lms_raft_llm_tpu.parallel import mesh as mesh_lib
+from distributed_lms_raft_llm_tpu.parallel import partition
+from distributed_lms_raft_llm_tpu.utils import tokenizer as tok_lib
+
+REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+HBM_BYTES = 16 * 1024**3  # one TPU v5e chip
+
+
+@pytest.fixture(scope="module")
+def topo():
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+
+    # A compile for a described chip is written to the persistent cache
+    # but cannot be read back without one: keep the cache out of it.
+    try:
+        desc = topologies.get_topology_desc(
+            platform="tpu", topology_name="v5e:2x2"
+        )
+    except Exception as e:  # noqa: BLE001 — any failure to describe = skip
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield desc
+    jax.config.update("jax_enable_compilation_cache", was)
+    compilation_cache.reset_cache()
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+def _with(tree, shardings):
+    """Shape tree -> the same shapes carrying `shardings` (a matching tree,
+    or one sharding for every leaf)."""
+    if not isinstance(shardings, (dict, tuple, list)):
+        one = shardings
+        shardings = jax.tree.map(lambda _: one, tree)
+    return jax.tree.map(
+        lambda x, s: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=s),
+        tree, shardings,
+    )
+
+
+# ------------------------------------------------------------- the kernel
+
+@pytest.mark.parametrize(
+    "name,layers,batch,heads,kv_heads,s,dh",
+    [
+        ("gpt2", 12, 8, 12, 12, 1024, 64),
+        # 20 heads x [1024, 64->128 lanes] K and V, double-buffered, is
+        # 20 MiB in one block: over the 16 MiB scope until the head axis
+        # was split across grid steps (ops/attention._kv_heads_per_step).
+        ("gpt2-large", 36, 8, 20, 20, 1024, 64),
+        ("gpt2-batch32", 12, 32, 12, 12, 256, 64),
+    ],
+)
+def test_decode_attention_compiles_for_v5e(one_chip, name, layers, batch,
+                                           heads, kv_heads, s, dh):
+    def sd(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    cache = sd((layers, batch, kv_heads, s, dh), jnp.bfloat16)
+    compiled = jax.jit(decode_attention).lower(
+        sd((batch, heads, 1, dh), jnp.bfloat16), cache, cache,
+        sd((), jnp.int32), sd((batch, 1, s), jnp.float32),
+    ).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+# ------------------------------------------------- the paged step programs
+
+class _Production:
+    """The serving configuration of configs/cluster.toml, as shapes: what
+    `PagedEngine.__init__` derives, without placing anything on a device."""
+
+    def __init__(self):
+        cfg = config_lib.load_config(
+            os.path.join(REPO, "configs", "cluster.toml")
+        )
+        t = cfg.tutoring
+        assert (t.model, t.quant, t.kv_quant, t.paged) == (
+            "gpt2", "int8", True, True
+        )
+        self.t = t
+        econf = config_lib.engine_config(cfg)
+        self.sampling = econf.sampling
+        self.family, mcfg = registry.resolve(
+            t.model, econf.dtype, econf.param_dtype
+        )
+        self.cfg = dataclasses.replace(mcfg, quant_kv=True)
+        self.slots = t.slots
+        self.bucket = min(
+            max(econf.length_buckets),
+            self.cfg.max_position_embeddings - self.sampling.max_new_tokens,
+        )
+        self.width = paged.cfg_tmax(self.cfg, self.sampling, self.bucket)
+        tok = tok_lib.load_gpt2_tokenizer()  # no vocab file: byte ids
+        self.ids = dict(eos_id=tok.eos_id, pad_id=tok.pad_id)
+        self.params = jax.eval_shape(
+            lambda: quant.quantize_params(
+                self.family.init_params(jax.random.key(0), self.cfg),
+                self.family.name,
+            )
+        )
+        self.state = jax.eval_shape(
+            partial(paged._fresh_state, self.family, self.cfg, self.slots,
+                    self.width)
+        )
+        self.key = jax.eval_shape(lambda: jax.random.key(0))
+        self.keys = jax.eval_shape(
+            lambda: jax.random.split(jax.random.key(0), 1)
+        )
+        self.statics = dict(cfg=self.cfg, sampling=self.sampling,
+                            model=self.family)
+
+    def megastep(self):
+        """The fused-admission megastep the production config dispatches
+        at every rung; K rides in on the key stack's shape (here K=1)."""
+        return jax.jit(
+            partial(paged._megastep_program, chunk=self.t.chunk,
+                    spec_tokens=0, prefill_chunk=self.t.prefill_chunk_tokens,
+                    draft_fn=build_drafts, **self.ids, **self.statics),
+            donate_argnums=(1,),
+        )
+
+
+@pytest.fixture(scope="module")
+def prod():
+    return _Production()
+
+
+def _device_bytes(ma) -> int:
+    return (ma.argument_size_in_bytes + ma.output_size_in_bytes
+            + ma.temp_size_in_bytes - ma.alias_size_in_bytes)
+
+
+def test_paged_decode_programs_compile_and_fit_one_v5e(one_chip, prod):
+    params = _with(prod.params, one_chip)
+    state = _with(prod.state, one_chip)
+    mega = prod.megastep().lower(
+        params, state, _with(prod.keys, one_chip)
+    ).compile()
+    step = jax.jit(
+        partial(paged._step_program, chunk=prod.t.chunk, **prod.ids,
+                **prod.statics),
+        donate_argnums=(1,),
+    ).lower(params, state, _with(prod.key, one_chip)).compile()
+    for compiled in (mega, step):
+        # Weights + the widest slot cache + temporaries, with room left
+        # for the prefix-cache blocks and the other widths' programs.
+        assert _device_bytes(compiled.memory_analysis()) < HBM_BYTES // 4
+
+
+def test_paged_prefill_program_compiles_and_fits_one_v5e(one_chip, prod):
+    ids = jax.ShapeDtypeStruct((1, prod.bucket), jnp.int32,
+                               sharding=one_chip)
+    scalar = jax.ShapeDtypeStruct((), jnp.int32, sharding=one_chip)
+    compiled = jax.jit(
+        partial(paged._prefill_program, **prod.statics)
+    ).lower(
+        _with(prod.params, one_chip), ids, scalar,
+        _with(prod.key, one_chip),
+    ).compile()
+    assert _device_bytes(compiled.memory_analysis()) < HBM_BYTES // 4
+
+
+def test_paged_megastep_tp4_shards_planes_over_four_chips(topo, one_chip,
+                                                         prod):
+    """The same step on a tp=4 mesh of the described 2x2: the KV planes and
+    the sharded weights must arrive as quarters — the check that nothing
+    the plane table shards was left whole on one device."""
+    mesh = mesh_lib.make_mesh({"tp": 4, "dp": -1}, devices=topo.devices)
+    rules = partition.RULES_FOR[prod.family.name]
+    p_sh = partition.shardings_for(prod.params, mesh, rules)
+
+    def plane(name):
+        return NamedSharding(mesh, partition.PAGED_PLANE_SPECS[name])
+
+    s_sh = prod.state._replace(
+        cache=prod.state.cache._replace(**{
+            f: plane(f"cache.{f}") for f in ("k", "v", "ks", "vs", "length")
+        }),
+        **{f: plane(f) for f in prod.state._fields if f != "cache"},
+    )
+    replicated = NamedSharding(mesh, jax.sharding.PartitionSpec())
+    args = (_with(prod.params, p_sh), _with(prod.state, s_sh),
+            _with(prod.keys, replicated))
+    with mesh:
+        sharded = prod.megastep().lower(*args).compile()
+    whole = prod.megastep().lower(
+        _with(prod.params, one_chip), _with(prod.state, one_chip),
+        _with(prod.keys, one_chip),
+    ).compile()
+
+    def nbytes(x, shape=None):
+        n = x.dtype.itemsize
+        for d in shape or x.shape:
+            n *= d
+        return n
+
+    leaves = [x for x in jax.tree.leaves(args)
+              if not jax.dtypes.issubdtype(x.dtype, jax.dtypes.prng_key)]
+    per_device = sum(
+        nbytes(x, x.sharding.shard_shape(x.shape)) for x in leaves
+    )
+    split = [x for x in leaves
+             if x.sharding.shard_shape(x.shape) != x.shape]
+    # What the rules shard is most of the bytes, and each lands as 1/4.
+    assert sum(nbytes(x) for x in split) > 0.8 * sum(map(nbytes, leaves))
+    assert all(
+        4 * nbytes(x, x.sharding.shard_shape(x.shape)) == nbytes(x)
+        for x in split
+    )
+    got = sharded.memory_analysis().argument_size_in_bytes
+    one = whole.memory_analysis().argument_size_in_bytes
+    assert abs(got - per_device) < 0.05 * per_device, (got, per_device)
+    assert got < 0.4 * one, (got, one)
+    assert "all-reduce" in sharded.as_text()

@@ -169,7 +169,10 @@ def test_server_cli_config_phases(tmp_path):
     lms_port, tut_port = _free_port(), _free_port()
     f = _write_deploy_toml(tmp_path, lms_port, tut_port)
 
-    targs = _capture_args(tutoring_server, ["--config", str(f)])
+    # `--jax-platform default` means the TPU and refuses anything else;
+    # a CPU run says so.
+    cpu = ["--jax-platform", "cpu"]
+    targs = _capture_args(tutoring_server, ["--config", str(f), *cpu])
     assert targs.port == tut_port
     assert targs.model == "tiny"
     assert targs.kv_quant and targs.paged
@@ -177,7 +180,8 @@ def test_server_cli_config_phases(tmp_path):
 
     # Explicit flag beats the file.
     targs2 = _capture_args(
-        tutoring_server, ["--config", str(f), "--max-new-tokens", "4"]
+        tutoring_server,
+        ["--config", str(f), "--max-new-tokens", "4", *cpu],
     )
     assert targs2.max_new_tokens == 4
 

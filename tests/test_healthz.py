@@ -2,6 +2,7 @@
 
 import asyncio
 import json
+import os
 
 from distributed_lms_raft_llm_tpu.utils.healthz import HealthServer
 from distributed_lms_raft_llm_tpu.utils.metrics import Metrics
@@ -68,6 +69,15 @@ def test_tutoring_server_exposes_endpoint():
         status, body = await _get(hport, "/healthz")
         assert status == 200 and body["ok"]
         assert body["engine"] == "TutoringEngine"
+        # The node says what it computes on (a JAX-free launcher reads
+        # this instead of asking JAX itself) and where it caches compiles.
+        assert body["device"] == {"platform": "cpu", "kind": "cpu",
+                                  "count": 8}
+        assert body["device_memory"] == {}  # the CPU reports none
+        assert body["compile_cache"]["dir"] == os.environ[
+            "JAX_COMPILATION_CACHE_DIR"]
+        assert (0 <= body["compile_cache"]["hits"]
+                <= body["compile_cache"]["requests"])
         status, body = await _get(hport, "/metrics")
         assert status == 200 and "counters" in body
         await server.stop(None)

@@ -1,5 +1,5 @@
-"""The shipped deployment is real end-to-end: configs/cluster.toml points
-at checked-in artifacts and every model boots from them — ZERO random-init
+"""The shipped deployment is real end-to-end: configs/cluster.toml names
+HF-layout artifacts and every model boots from them — ZERO random-init
 warnings.
 
 The reference always serves pretrained weights (reference:
@@ -10,14 +10,16 @@ babble. These tests pin the round-4 verdict's Missing #1/#2: the TOML the
 README quick start uses must load `data/gpt2-local` and `data/bert-local`
 through the identical HF-layout paths hub-downloaded weights use.
 
-`data/` is deliberately untracked (a ~1 GB of seeded-deterministic
-artifacts); on a fresh clone the fixture below builds them once via
-`scripts/make_local_checkpoint.py` — the same step the README quick start
-runs — so the suite is self-contained.
+The artifacts are ~0.9 GB of seeded-deterministic files, so they are never
+built inside the checkout: the fixture below builds them once per run under
+a pytest temp directory via `scripts/make_local_checkpoint.py` — the same
+step the README quick start runs — resolves the config's relative paths
+against that directory, and removes it afterwards.
 """
 
 import logging
 import os
+import shutil
 import sys
 
 import pytest
@@ -34,32 +36,44 @@ def cfg():
     t, g = cfg.tutoring, cfg.gate
     for path in (t.checkpoint, t.vocab, t.merges, g.checkpoint, g.vocab):
         assert path, "production config must name every artifact"
-    if not all(
-        os.path.exists(os.path.join(REPO, p))
-        for p in (t.checkpoint, t.vocab, t.merges, g.checkpoint, g.vocab)
-    ):
-        sys.path.insert(0, os.path.join(REPO, "scripts"))
-        from make_local_checkpoint import build_bert_local, build_gpt2_local
-
-        build_bert_local(os.path.join(REPO, "data", "bert-local"))
-        build_gpt2_local(os.path.join(REPO, "data", "gpt2-local"))
+        assert not os.path.isabs(path), path
     return cfg
 
 
-def test_production_config_artifacts_exist(cfg):
+@pytest.fixture(scope="module")
+def root(cfg, tmp_path_factory):
+    """The directory the config's relative artifact paths resolve against:
+    a temp dir standing in for the deployment's working directory."""
+    root = str(tmp_path_factory.mktemp("production_artifacts"))
+    in_checkout = os.path.join(REPO, "data")
+    had_data = os.path.exists(in_checkout)
+    sys.path.insert(0, os.path.join(REPO, "scripts"))
+    from make_local_checkpoint import build_bert_local, build_gpt2_local
+
+    build_bert_local(os.path.dirname(os.path.join(root, cfg.gate.checkpoint)))
+    build_gpt2_local(
+        os.path.dirname(os.path.join(root, cfg.tutoring.checkpoint))
+    )
+    assert os.path.exists(in_checkout) == had_data, (
+        "artifacts must never be built inside the checkout"
+    )
+    yield root
+    shutil.rmtree(root, ignore_errors=True)
+
+
+def test_production_config_artifacts_exist(cfg, root):
     t, g = cfg.tutoring, cfg.gate
     for path in (t.checkpoint, t.vocab, t.merges, g.checkpoint, g.vocab):
-        assert os.path.exists(os.path.join(REPO, path)), path
+        assert os.path.exists(os.path.join(root, path)), path
 
 
-def test_tutoring_engine_boots_from_shipped_checkpoint(cfg, caplog):
+def test_tutoring_engine_boots_from_shipped_checkpoint(cfg, root, caplog):
     from distributed_lms_raft_llm_tpu.engine import TutoringEngine
 
     econf = config_lib.engine_config(cfg)
-    # Resolve relative to the repo root the TOML ships with.
-    econf.checkpoint = os.path.join(REPO, econf.checkpoint)
-    econf.vocab_path = os.path.join(REPO, econf.vocab_path)
-    econf.merges_path = os.path.join(REPO, econf.merges_path)
+    econf.checkpoint = os.path.join(root, econf.checkpoint)
+    econf.vocab_path = os.path.join(root, econf.vocab_path)
+    econf.merges_path = os.path.join(root, econf.merges_path)
     with caplog.at_level(logging.WARNING):
         eng = TutoringEngine(econf)
     assert not [r for r in caplog.records if "random" in r.message.lower()], (
@@ -73,7 +87,7 @@ def test_tutoring_engine_boots_from_shipped_checkpoint(cfg, caplog):
     assert econf.quant == "int8" and econf.kv_quant
 
 
-def test_gate_boots_from_shipped_checkpoint(cfg, caplog):
+def test_gate_boots_from_shipped_checkpoint(cfg, root, caplog):
     from distributed_lms_raft_llm_tpu.engine import GateConfig, RelevanceGate
 
     g = cfg.gate
@@ -81,8 +95,8 @@ def test_gate_boots_from_shipped_checkpoint(cfg, caplog):
         gate = RelevanceGate(
             GateConfig(
                 model=g.model,
-                checkpoint=os.path.join(REPO, g.checkpoint),
-                vocab_path=os.path.join(REPO, g.vocab),
+                checkpoint=os.path.join(root, g.checkpoint),
+                vocab_path=os.path.join(root, g.vocab),
                 threshold=g.threshold,
                 quant=g.quant,
             )
