@@ -220,14 +220,15 @@ def _argnums(call: ast.Call, name: str) -> Tuple[int, ...]:
 
 
 def _unwrap_partial(expr: ast.expr) -> ast.expr:
-    """partial(fn, ...) / functools.partial(fn, ...) -> fn; factories
+    """partial(fn, ...) / functools.partial(fn, ...) / the engine's
+    `named_partial(fn, ...)` (engine/spans.py) -> fn; factories
     (`make_step(...)`) unwrap to the factory reference."""
     if isinstance(expr, ast.Call):
         func = expr.func
         name = func.attr if isinstance(func, ast.Attribute) else (
             func.id if isinstance(func, ast.Name) else ""
         )
-        if name == "partial" and expr.args:
+        if name in ("partial", "named_partial") and expr.args:
             return _unwrap_partial(expr.args[0])
         return func
     return expr

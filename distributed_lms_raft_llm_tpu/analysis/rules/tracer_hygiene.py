@@ -48,11 +48,12 @@ def _callee_name(call: ast.Call) -> str:
 
 
 def _unwrap_partial(expr: ast.expr) -> Optional[str]:
-    """The function NAME inside `f`, `partial(f, ...)`, or
-    `functools.partial(f, ...)`."""
+    """The function NAME inside `f`, `partial(f, ...)`,
+    `functools.partial(f, ...)` or `named_partial(f, ...)`."""
     if isinstance(expr, ast.Name):
         return expr.id
-    if isinstance(expr, ast.Call) and _callee_name(expr) == "partial":
+    if isinstance(expr, ast.Call) and _callee_name(expr) in (
+            "partial", "named_partial"):
         if expr.args and isinstance(expr.args[0], ast.Name):
             return expr.args[0].id
     return None

@@ -44,6 +44,7 @@ from typing import Any, AsyncIterator, Deque, Dict, List, Optional, Tuple
 from ..utils import metrics_registry as metric
 from ..utils.resilience import Deadline, DeadlineExpired, Overloaded
 from ..utils.tracing import FLAG_DEADLINE, NULL_SPAN, get_tracer
+from .spans import Span
 
 log = logging.getLogger(__name__)
 
@@ -124,6 +125,7 @@ class _StreamState:
     rid: Optional[int] = None
     sent_tokens: int = 0
     abs_text: Optional[str] = None
+    last_push: Optional[float] = None  # monotonic time of the last delta
 
 
 def _observe_program_times(metrics, entries) -> None:
@@ -542,14 +544,6 @@ class PagedQueue:
         # Cumulative per-program (count, wall_s) since queue start; each
         # request snapshots it at submit and diffs at completion.
         self._prog_cum: Dict[str, List[float]] = {}  # guarded-by: event-loop
-        # Cumulative engine dispatch/token counts feeding the
-        # host_dispatches_per_token gauge (a run ratio, not a window one).
-        self._dispatch_cum = 0                       # guarded-by: event-loop
-        self._token_cum = 0                          # guarded-by: event-loop
-        # Cumulative shared-prefix hit/prompt tokens feeding the
-        # prefix_cache_hit_rate gauge (same run-ratio shape).
-        self._prefix_hit_cum = 0                     # guarded-by: event-loop
-        self._prefix_prompt_cum = 0                  # guarded-by: event-loop
         # Recent (monotonic time, emitted tokens) reaps feeding the
         # serving_tokens_per_s utilization gauge — a sliding few-second
         # window, not a run ratio, so the gauge tracks the CURRENT load
@@ -804,15 +798,15 @@ class PagedQueue:
             # ever runs HERE — the engine holds no in-flight interactive
             # work at the idle wait, so a quantum never competes with a
             # live decode train.
-            item = await _next_item(self, self._incoming)
+            with Span("queue.idle"):
+                item = await _next_item(self, self._incoming)
             if item is None:
                 continue  # a scoring quantum ran; arrivals re-checked
             self._admit(*item)
+            self._drain_incoming()
+            self._shed_expired_pending()
             while self.engine.has_work:
-                self._drain_incoming()
-                self._shed_expired_pending()
-                if not self.engine.has_work:
-                    break  # everything backlogged expired; nothing to step
+                t_turn = time.monotonic()
                 try:
                     # step() blocks on device compute; run off-loop so new
                     # submissions keep landing in _incoming meanwhile.
@@ -838,128 +832,120 @@ class PagedQueue:
                     # rebuild it or every later request fails too.
                     self.engine.reset()
                     break
-                self._reap_observability()
-                ttfts = self.engine.pop_ttfts()
+                with Span("queue.between_steps"):
+                    reap_wait_s = self._between_steps(done)
                 if self.metrics is not None:
-                    for ttft in ttfts.values():
-                        self.metrics.hist("ttft").observe(ttft)
-                    # Megastep efficiency: the controller's live K, pad
-                    # lanes burnt by mid-megastep finishes, and the run's
-                    # host-dispatches-per-token ratio (the number the
-                    # megastep exists to shrink).
-                    mk = getattr(self.engine, "megastep_k", None)
-                    if mk is not None:
-                        self.metrics.set_gauge("megastep_k", float(mk))
-                    self.metrics.set_gauge("serving_queue_depth",
-                                           float(self.waiting))
-                    # Multi-chip paged serving: the mesh's tp ways and
-                    # the per-chip KV residency the heads-axis sharding
-                    # buys (tracks cache growth/idle shrink live).
-                    kvb = getattr(self.engine, "kv_bytes_per_chip", None)
-                    if kvb is not None:
-                        self.metrics.set_gauge(
-                            "serving_tp",
-                            float(getattr(self.engine, "tp", 1)),
-                        )
-                        self.metrics.set_gauge(
-                            "serving_kv_bytes_per_chip", float(kvb)
-                        )
-                    pop_ds = getattr(self.engine, "pop_dispatch_stats",
-                                     None)
-                    if pop_ds is not None:
-                        (dispatches, tokens, dead, stall_ms,
-                         stalled) = pop_ds()
-                        if dead:
-                            self.metrics.inc(
-                                "megastep_dead_lane_tokens", dead
-                            )
-                        if stall_ms:
-                            # Decode-train pause attributable to
-                            # admission: the before/after number for
-                            # fused chunked prefill (both stay 0 with
-                            # fusion on — staging never blocks decode).
-                            self.metrics.inc("prefill_stall_ms", stall_ms)
-                        if stalled:
-                            self.metrics.inc(
-                                "decode_stalled_tokens", stalled
-                            )
-                        self._dispatch_cum += dispatches
-                        self._token_cum += tokens
-                        if self._token_cum:
-                            self.metrics.set_gauge(
-                                "host_dispatches_per_token",
-                                self._dispatch_cum / self._token_cum,
-                            )
-                        now = time.monotonic()
-                        self._tok_window.append((now, tokens))
-                        cutoff = now - self._tok_window_s
-                        while self._tok_window[0][0] < cutoff:
-                            self._tok_window.popleft()
-                        span = now - self._tok_window[0][0]
-                        if span > 0.2:
-                            self.metrics.set_gauge(
-                                "serving_tokens_per_s",
-                                sum(n for _, n in self._tok_window) / span,
-                            )
-                    prefix = getattr(self.engine, "pop_prefix_stats",
-                                     lambda: None)()
-                    if prefix is not None:
-                        # Shared-prefix cache effectiveness: tokens whose
-                        # KV came from the radix tree, the eviction
-                        # pressure, the live block level, and the run's
-                        # cumulative hit rate.
-                        hit, total, evicted, blocks_used = prefix
-                        if hit:
-                            self.metrics.inc("prefix_cache_hit_tokens",
-                                             hit)
-                        if evicted:
-                            self.metrics.inc("prefix_cache_evictions",
-                                             evicted)
-                        self.metrics.set_gauge("prefix_cache_blocks_used",
-                                               float(blocks_used))
-                        self._prefix_hit_cum += hit
-                        self._prefix_prompt_cum += total
-                        if self._prefix_prompt_cum:
-                            self.metrics.set_gauge(
-                                "prefix_cache_hit_rate",
-                                self._prefix_hit_cum
-                                / self._prefix_prompt_cum,
-                            )
-                    sess = getattr(self.engine, "session_pin_stats",
-                                   lambda: None)()
-                    if sess is not None:
-                        # Session residency: blocks held by live
-                        # transcript pins (TTL-expired pins are dropped
-                        # inside the stats call).
-                        _n_sessions, pinned = sess
-                        self.metrics.set_gauge("session_pinned_blocks",
-                                               float(pinned))
-                    spec = getattr(self.engine, "pop_spec_stats",
-                                   lambda: None)()
-                    if spec is not None:
-                        windows, emitted = spec
-                        if windows:
-                            # Speculation effectiveness on the default
-                            # serving path: mean emitted tokens per verify
-                            # window (gauge; 1.0 = nothing accepted) and
-                            # the cumulative tokens speculation produced
-                            # beyond the guaranteed one per window.
-                            self.metrics.set_gauge(
-                                "spec_tokens_per_window", emitted / windows
-                            )
-                            self.metrics.inc(
-                                "spec_accepted_tokens", emitted - windows
-                            )
-                # Stream emission BEFORE future resolution: a consumer
-                # woken by its future always finds the final delta (and
-                # any last partials) already queued.
-                self._emit_stream_progress(done)
-                for rid, text in done:
-                    self._pending_deadlines.pop(rid, None)
-                    self._finish_span(rid)
-                    f = self._futures.pop(rid, None)
-                    if f is not None and not f.done():
-                        f.set_result(text)
+                    self.metrics.hist("engine_host_turn").observe(
+                        max(0.0, time.monotonic() - t_turn - reap_wait_s)
+                    )
+
+    def _between_steps(self, done: List[Tuple[int, str]]) -> float:
+        """Everything the loop does from one step()'s return to the next
+        call: drain the engine's stats into the metrics, push stream
+        chunks, resolve finished requests, and hand the engine what
+        arrived meanwhile (a request that expired while backlogged is
+        shed before the next step can admit it). Returns the seconds the
+        step spent blocked on the device (its `engine.reap.wait`)."""
+        reap_wait_s = self._reap_observability()
+        ttfts = self.engine.pop_ttfts()
+        if self.metrics is not None:
+            for ttft in ttfts.values():
+                self.metrics.hist("ttft").observe(ttft)
+            self._export_engine_stats()
+        # Stream emission BEFORE future resolution: a consumer woken by
+        # its future always finds the final delta (and any last
+        # partials) already queued.
+        self._emit_stream_progress(done)
+        for rid, text in done:
+            self._pending_deadlines.pop(rid, None)
+            self._finish_span(rid)
+            f = self._futures.pop(rid, None)
+            if f is not None and not f.done():
+                f.set_result(text)
+        self._drain_incoming()
+        self._shed_expired_pending()
+        return reap_wait_s
+
+    def _export_engine_stats(self) -> None:
+        """The engine's gauges and drained counts, once per turn."""
+        # Megastep efficiency: the controller's live K, pad lanes burnt
+        # by mid-megastep finishes, and the run's host-dispatches-per-
+        # token ratio (the number the megastep exists to shrink).
+        mk = getattr(self.engine, "megastep_k", None)
+        if mk is not None:
+            self.metrics.set_gauge("megastep_k", float(mk))
+        self.metrics.set_gauge("serving_queue_depth", float(self.waiting))
+        # Multi-chip paged serving: the mesh's tp ways and the per-chip
+        # KV residency the heads-axis sharding buys (tracks cache
+        # growth/idle shrink live).
+        kvb = getattr(self.engine, "kv_bytes_per_chip", None)
+        if kvb is not None:
+            self.metrics.set_gauge(
+                "serving_tp", float(getattr(self.engine, "tp", 1))
+            )
+            self.metrics.set_gauge("serving_kv_bytes_per_chip", float(kvb))
+        pop_ds = getattr(self.engine, "pop_dispatch_stats", None)
+        if pop_ds is not None:
+            dispatches, tokens, dead, stall_ms, stalled = pop_ds()
+            if dead:
+                self.metrics.inc("megastep_dead_lane_tokens", dead)
+            if stall_ms:
+                # Decode-train pause attributable to admission: the
+                # before/after number for fused chunked prefill (both
+                # stay 0 with fusion on — staging never blocks decode).
+                self.metrics.inc("prefill_stall_ms", stall_ms)
+            if stalled:
+                self.metrics.inc("decode_stalled_tokens", stalled)
+            n_disp = self.metrics.inc("engine_dispatches", dispatches)
+            n_tok = self.metrics.inc("engine_tokens_emitted", tokens)
+            if n_tok:
+                self.metrics.set_gauge("host_dispatches_per_token",
+                                       n_disp / n_tok)
+            now = time.monotonic()
+            self._tok_window.append((now, tokens))
+            cutoff = now - self._tok_window_s
+            while self._tok_window[0][0] < cutoff:
+                self._tok_window.popleft()
+            span = now - self._tok_window[0][0]
+            if span > 0.2:
+                self.metrics.set_gauge(
+                    "serving_tokens_per_s",
+                    sum(n for _, n in self._tok_window) / span,
+                )
+        prefix = getattr(self.engine, "pop_prefix_stats", lambda: None)()
+        if prefix is not None:
+            # Shared-prefix cache effectiveness: tokens whose KV came
+            # from the radix tree, the eviction pressure, the live block
+            # level, and the run's cumulative hit rate (over the prompt
+            # tokens admitted, which `_reap_observability` has counted).
+            hit, _total, evicted, blocks_used = prefix
+            n_hit = self.metrics.inc("prefix_cache_hit_tokens", hit)
+            if evicted:
+                self.metrics.inc("prefix_cache_evictions", evicted)
+            self.metrics.set_gauge("prefix_cache_blocks_used",
+                                   float(blocks_used))
+            n_prompt = self.metrics.inc("engine_prompt_tokens_admitted", 0)
+            if n_prompt:
+                self.metrics.set_gauge("prefix_cache_hit_rate",
+                                       n_hit / n_prompt)
+        sess = getattr(self.engine, "session_pin_stats", lambda: None)()
+        if sess is not None:
+            # Session residency: blocks held by live transcript pins
+            # (TTL-expired pins are dropped inside the stats call).
+            _n_sessions, pinned = sess
+            self.metrics.set_gauge("session_pinned_blocks", float(pinned))
+        spec = getattr(self.engine, "pop_spec_stats", lambda: None)()
+        if spec is not None:
+            windows, emitted = spec
+            if windows:
+                # Speculation effectiveness on the default serving path:
+                # mean emitted tokens per verify window (gauge; 1.0 =
+                # nothing accepted) and the cumulative tokens speculation
+                # produced beyond the guaranteed one per window.
+                self.metrics.set_gauge(
+                    "spec_tokens_per_window", emitted / windows
+                )
+                self.metrics.inc("spec_accepted_tokens", emitted - windows)
 
     def _emit_stream_progress(
         self, done: List[Tuple[int, str]]
@@ -1006,12 +992,21 @@ class PagedQueue:
             # merges can transiently rewrite the tail): hold back — the
             # already-delivered text must never be retracted.
             return
-        st.q.put_nowait(StreamDelta(
+        self._push(st, StreamDelta(
             offset=st.sent_tokens, count=n - st.sent_tokens,
             text=full[len(st.abs_text):], final=False,
         ))
         st.sent_tokens = n
         st.abs_text = full
+
+    def _push(self, st: _StreamState, delta: StreamDelta) -> None:
+        """Queue one delta for the stream's consumer; from the second on,
+        observe the gap since the one before (`stream_chunk_gap`)."""
+        now = time.monotonic()
+        if st.last_push is not None and self.metrics is not None:
+            self.metrics.hist("stream_chunk_gap").observe(now - st.last_push)
+        st.last_push = now
+        st.q.put_nowait(delta)
 
     def _push_final(self, st: _StreamState,
                     toks: Optional[List[int]], text: str) -> None:
@@ -1023,17 +1018,33 @@ class PagedQueue:
                            if (toks and eff) else "")
         # Best-effort slice when the final decode diverged from a held-
         # back partial (the digest check downstream catches corruption).
-        st.q.put_nowait(StreamDelta(
+        self._push(st, StreamDelta(
             offset=st.sent_tokens, count=max(0, n - st.sent_tokens),
             text=text[len(st.abs_text):], final=True, full_text=text,
         ))
 
-    def _reap_observability(self) -> None:
+    def _reap_observability(self) -> float:
         """Between steps: drain the engine's measured queue waits (closing
         the matching `queue.wait` spans with the true submit->prefill
-        interval) and per-program dispatch times (feeding the
+        interval), per-program dispatch times (feeding the
         `engine_prog_*` histogram series and the shared-attribution
-        accumulator the completion-time engine spans diff against)."""
+        accumulator the completion-time engine spans diff against) and
+        loop counts and observations (`metric.ENGINE_LOOP_*`). Returns
+        the seconds the last step spent blocked on the device."""
+        reap_wait_s = 0.0
+        pop_loop = getattr(self.engine, "pop_loop_stats", None)
+        if pop_loop is not None:
+            counts, observations = pop_loop()
+            reap_wait_s = sum(observations.get("reap_wait", ()))
+            if self.metrics is not None:
+                for key, n in counts.items():
+                    self.metrics.inc(metric.ENGINE_LOOP_COUNTERS[key], n)
+                for key, values in observations.items():
+                    hist = self.metrics.hist(
+                        metric.ENGINE_LOOP_HISTOGRAMS[key]
+                    )
+                    for v in values:
+                        hist.observe(v)
         pop_waits = getattr(self.engine, "pop_queue_waits", None)
         if pop_waits is not None:
             for rid, wait_s in pop_waits().items():
@@ -1059,6 +1070,7 @@ class PagedQueue:
                 entry = self._spans.get(rid)
                 if entry is not None:
                     entry.prefix_hit = hit
+        return reap_wait_s
 
     def _finish_span(self, rid: int) -> None:
         """Synthesize the request's `engine.decode` span: admission (end

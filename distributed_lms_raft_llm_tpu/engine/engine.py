@@ -22,7 +22,6 @@ from __future__ import annotations
 import dataclasses
 import logging
 import time
-from functools import partial
 from typing import Any, List, Optional, Sequence, Tuple
 
 import jax
@@ -38,6 +37,7 @@ from ..utils.guards import intended_transfer
 from .generate import GenerateResult, decode, pick_bucket, prefill
 from .sampling import SamplingParams
 from .scoring import _score_program, derive_score_shapes, score_texts
+from .spans import PROG, ProgramLog, Span, named_partial
 
 log = logging.getLogger(__name__)
 
@@ -235,18 +235,19 @@ class TutoringEngine:
             pad_id=self.tokenizer.pad_id,
             model=self.family,
         )
-        self._prefill = jax.jit(partial(prefill, **statics))
+        self._prefill = jax.jit(named_partial(prefill, **statics))
         if config.spec_tokens > 0:
             from .spec import decode_spec
 
             self._decode = jax.jit(
-                partial(decode_spec, spec_tokens=config.spec_tokens,
-                        **statics),
+                named_partial(decode_spec, spec_tokens=config.spec_tokens,
+                              **statics),
                 donate_argnums=(1,),
             )
         else:
             self._decode = jax.jit(
-                partial(decode, segments=config.decode_segments, **statics),
+                named_partial(decode, segments=config.decode_segments,
+                              **statics),
                 donate_argnums=(1,),
             )
         self.last_ttft_s: Optional[float] = None
@@ -264,9 +265,8 @@ class TutoringEngine:
         self.total_generated_tokens = 0
         # (program, wall-clock start, seconds) per answer_batch device
         # batch, drained by the serving queue into per-program histogram
-        # series and `engine.<program>` trace spans (bounded; see
-        # PagedEngine._prog_times for the paged counterpart).
-        self._prog_times: List[Tuple[str, float, float]] = []
+        # series and `engine.<program>` trace spans (engine/spans.py).
+        self._progs = ProgramLog(1024)
         # Bulk-scoring program (engine/scoring.py): bound at construction
         # like every other program — no lazy first-call compile hiding on
         # the serving path. With sp > 1 the forward runs as ring
@@ -275,7 +275,7 @@ class TutoringEngine:
         if config.sp > 1:
             score_cfg = dataclasses.replace(score_cfg, ring_mesh=self.mesh)
         self._score = jax.jit(
-            partial(_score_program, cfg=score_cfg, model=self.family)
+            named_partial(_score_program, cfg=score_cfg, model=self.family)
         )
         # The score domain warmup covers when `config.scoring` is on —
         # cross-checked against program_inventory.static_score_domain by
@@ -289,12 +289,9 @@ class TutoringEngine:
             if config.scoring else []
         )
 
-    _PROG_TIMES_MAX = 1024
-
     def pop_program_times(self) -> List[Tuple[str, float, float]]:
         """Drain (program, start_unix, wall_s) recorded since last call."""
-        out, self._prog_times = self._prog_times, []
-        return out
+        return self._progs.pop()
 
     @property
     def last_spec_tokens_per_window(self) -> Optional[float]:
@@ -492,13 +489,8 @@ class TutoringEngine:
             chunk = prompts[start : start + cap]
             ids, mask, _ = self.encode_prompts(chunk)
             queued_s = time.monotonic() - t_submit
-            t_gen, t_gen_unix = time.monotonic(), time.time()
-            result = self.generate_ids(ids, mask, real_rows=len(chunk))
-            self._prog_times.append(
-                ("generate", t_gen_unix, time.monotonic() - t_gen)
-            )
-            if len(self._prog_times) > self._PROG_TIMES_MAX:
-                del self._prog_times[: -self._PROG_TIMES_MAX]
+            with Span(PROG + "generate", self._progs):
+                result = self.generate_ids(ids, mask, real_rows=len(chunk))
             # Per-request TTFT counts from batch submission: requests in a
             # later device chunk also waited for every earlier chunk.
             ttfts.extend([queued_s + (self.last_ttft_s or 0.0)] * len(chunk))

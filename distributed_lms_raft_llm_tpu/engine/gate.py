@@ -11,7 +11,6 @@ from __future__ import annotations
 
 import dataclasses
 import logging
-from functools import partial
 from typing import Any, Optional, Sequence, Tuple
 
 import jax
@@ -23,6 +22,7 @@ from ..parallel import mesh as mesh_lib
 from ..parallel import partition
 from ..utils import tokenizer as tok_lib
 from .generate import pick_bucket
+from .spans import named_partial
 
 log = logging.getLogger(__name__)
 
@@ -67,7 +67,7 @@ class RelevanceGate:
 
             params = quant_lib.quantize_params(params, "bert")
         self.params = partition.shard_tree(params, self.mesh, partition.BERT_RULES)
-        self._embed = jax.jit(partial(bert.embed, cfg=self.cfg))
+        self._embed = jax.jit(named_partial(bert.embed, cfg=self.cfg))
         # Context (assignment text) embeddings are static per student and
         # re-checked on every query; caching them halves the per-query gate
         # compute — the reference re-loads the whole MODEL per request

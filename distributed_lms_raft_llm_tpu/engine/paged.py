@@ -83,7 +83,6 @@ from __future__ import annotations
 import dataclasses
 import logging
 import time
-from functools import partial
 from typing import Dict, List, NamedTuple, Optional, Sequence, Tuple
 
 import jax
@@ -111,6 +110,7 @@ from .prefix_cache import (
 )
 from .program_inventory import effective_megastep_max, megastep_ladder
 from .scoring import _score_program, derive_score_shapes, score_texts
+from .spans import PROG, ProgramLog, Span, named_partial
 from .sampling import (
     SamplingParams,
     sample_step,
@@ -161,6 +161,7 @@ class _Request:
     tokens: List[int]
     max_new: int
     submit_time: float = 0.0
+    popped_time: float = 0.0  # left the pending queue for admission
     # Set at reap time; later in-flight chunks dispatched before the finish
     # was known still carry this request in their slot snapshot and must
     # skip it (see PagedEngine.step pipelining).
@@ -510,10 +511,12 @@ def _step_program(params, state: SlotState, rng, *, cfg, sampling,
         offs = jnp.minimum(s.cache.length, tmax - 1)
         cache = s.cache._replace(length=offs)
         kv_mask = jnp.arange(tmax)[None, :] <= offs[:, None]
-        logits, cache = model.forward(
-            params, cfg, s.tok[:, None], cache=cache, kv_mask=kv_mask
-        )
-        nxt = sample_step(step_rng, logits[:, 0], s.seen, sampling)
+        with jax.named_scope("decode"):
+            logits, cache = model.forward(
+                params, cfg, s.tok[:, None], cache=cache, kv_mask=kv_mask
+            )
+        with jax.named_scope("sample"):
+            nxt = sample_step(step_rng, logits[:, 0], s.seen, sampling)
         nxt = jnp.where(s.active, nxt, jnp.asarray(pad_id, jnp.int32))
         still = s.active & (nxt != eos_id)
         lengths = jnp.where(
@@ -591,13 +594,15 @@ def _spec_step_program(
         # kv_mask is needed (no interior pad holes) and positions default
         # to the slot indices.
         feed = jnp.concatenate([s.tok[:, None], drafts], axis=1)  # [S, k+1]
-        logits, cache = model.forward(
-            params, cfg, feed, cache=s.cache._replace(length=offs)
-        )
-        emitted, valid, seen, hit_eos = verify_window(
-            step_rng, logits, drafts, s.seen, s.active, sampling,
-            eos_id, pad_id,
-        )
+        with jax.named_scope("decode"):
+            logits, cache = model.forward(
+                params, cfg, feed, cache=s.cache._replace(length=offs)
+            )
+        with jax.named_scope("sample"):
+            emitted, valid, seen, hit_eos = verify_window(
+                step_rng, logits, drafts, s.seen, s.active, sampling,
+                eos_id, pad_id,
+            )
         # Emitted token i lands at transcript slot offs+1+i (the slot its
         # KV will occupy once it is fed). Clamp-overrun rows route their
         # writes out of bounds and drop them.
@@ -811,11 +816,12 @@ def _megastep_program(params, state: SlotState, rngs, *, cfg, sampling,
             # decode token lands in this same iteration's token plane —
             # the slot joins the train at a scan-iteration boundary, not
             # a dispatch boundary.
-            s, flipped, firsts = _admission_chunk(
-                params, s, cfg=cfg, sampling=sampling, model=model,
-                eos_id=eos_id, pad_id=pad_id,
-                prefill_chunk=prefill_chunk,
-            )
+            with jax.named_scope("prefill_chunk"):
+                s, flipped, firsts = _admission_chunk(
+                    params, s, cfg=cfg, sampling=sampling, model=model,
+                    eos_id=eos_id, pad_id=pad_id,
+                    prefill_chunk=prefill_chunk,
+                )
             extra = (flipped, firsts)
         else:
             extra = ()
@@ -1108,43 +1114,48 @@ class PagedEngine:
         rules = partition.RULES_FOR[self.family.name]
         self.params = partition.shard_tree(params, self.mesh, rules)
 
+        # Every program is a jit of `named_partial(fn, ...)`: a FRESH
+        # partial per engine (jax.jit shares one program cache across
+        # wrappers of the same bare function, and the inventory guard's
+        # exact counts are per engine), carrying the function's name, so a
+        # device trace reads `jit__megastep_program`, not `jit__unknown`.
         statics = dict(cfg=self.cfg, sampling=config.sampling, model=self.family)
-        self._prefill = jax.jit(partial(_prefill_program, **statics))
+        self._prefill = jax.jit(named_partial(_prefill_program, **statics))
         # Shared-prefix programs. Created even with the cache disabled
         # (zero warmed programs then) so the inventory guard sees one
         # stable program set — the _megastep precedent. The partial
         # prefill donates the spliced cache0 accumulator; the block
         # splice donates ONLY the accumulator, never the shared block.
         self._partial_prefill = jax.jit(
-            partial(_partial_prefill_program, **statics),
+            named_partial(_partial_prefill_program, **statics),
             donate_argnums=(1,),
         )
         self._load_block = jax.jit(
-            partial(_load_block_program), donate_argnums=(0,),
+            named_partial(_load_block_program), donate_argnums=(0,),
         )
-        self._export_block = jax.jit(
-            partial(_export_block_program, block=self.prefix_block_tokens),
-        )
+        self._export_block = jax.jit(named_partial(
+            _export_block_program, block=self.prefix_block_tokens,
+        ))
         # The live SlotState is donated on every program that replaces it, so
         # admissions and steps update the multi-slot KV cache in place instead
         # of copying it (a full cache round-trip of HBM traffic otherwise).
         self._install = jax.jit(
-            partial(_install_program, eos_id=self.tokenizer.eos_id),
+            named_partial(_install_program, eos_id=self.tokenizer.eos_id),
             donate_argnums=(0,),
         )
         if self.spec:
             self._step = jax.jit(
-                partial(_spec_step_program, eos_id=self.tokenizer.eos_id,
-                        pad_id=self.tokenizer.pad_id, chunk=self.chunk,
-                        spec_tokens=self.spec, draft_fn=self._draft_fn,
-                        **statics),
+                named_partial(_spec_step_program, eos_id=self.tokenizer.eos_id,
+                              pad_id=self.tokenizer.pad_id, chunk=self.chunk,
+                              spec_tokens=self.spec, draft_fn=self._draft_fn,
+                              **statics),
                 donate_argnums=(1,),
             )
         else:
             self._step = jax.jit(
-                partial(_step_program, eos_id=self.tokenizer.eos_id,
-                        pad_id=self.tokenizer.pad_id, chunk=self.chunk,
-                        **statics),
+                named_partial(_step_program, eos_id=self.tokenizer.eos_id,
+                              pad_id=self.tokenizer.pad_id, chunk=self.chunk,
+                              **statics),
                 donate_argnums=(1,),
             )
         # K>=2 rungs dispatch through the megastep program (K=1 stays on
@@ -1156,10 +1167,11 @@ class PagedEngine:
         # sequential-mode) so the inventory guard sees one stable program
         # set.
         self._megastep = jax.jit(
-            partial(_megastep_program, eos_id=self.tokenizer.eos_id,
-                    pad_id=self.tokenizer.pad_id, chunk=self.chunk,
-                    spec_tokens=self.spec, prefill_chunk=self.prefill_chunk,
-                    draft_fn=self._draft_fn, **statics),
+            named_partial(
+                _megastep_program, eos_id=self.tokenizer.eos_id,
+                pad_id=self.tokenizer.pad_id, chunk=self.chunk,
+                spec_tokens=self.spec, prefill_chunk=self.prefill_chunk,
+                draft_fn=self._draft_fn, **statics),
             donate_argnums=(1,),
         )
         # Fused staged admission programs (zero warmed programs when
@@ -1168,30 +1180,22 @@ class PagedEngine:
         # `_stage_block` donates ONLY the state accumulator, never the
         # shared tree block.
         self._stage = jax.jit(
-            partial(_stage_program), donate_argnums=(0,),
+            named_partial(_stage_program), donate_argnums=(0,),
         )
         self._stage_block = jax.jit(
-            partial(_stage_block_program), donate_argnums=(0,),
+            named_partial(_stage_block_program), donate_argnums=(0,),
         )
-        # Wrapped in partial like the other programs — NOT for the statics
-        # (it has none to bind) but for cache identity: jax.jit shares one
-        # program cache across wrappers of the same bare function, so a
-        # second engine in the process would see the first engine's grow
-        # programs in its counts and the inventory guard's exact-equality
-        # claim (expected_from_inventory) would read cross-engine state.
-        # A fresh partial object keys a fresh cache, per engine, like
-        # _prefill/_install/_step above.
+        # No statics to bind; a fresh partial all the same (see above).
         self._grow = jax.jit(
-            partial(_grow_state_program), static_argnums=(1,),
+            named_partial(_grow_state_program), static_argnums=(1,),
             donate_argnums=(0,),
         )
         # Bulk-scoring program (engine/scoring.py): the background
-        # tenant's full-sequence forward, bound per engine like every
-        # other program (fresh partial = fresh cache — the _grow
-        # precedent). Zero warmed programs when `config.scoring` is off
-        # (the stable-program-set precedent of _megastep/_stage).
+        # tenant's full-sequence forward. Zero warmed programs when
+        # `config.scoring` is off (the stable-program-set precedent of
+        # _megastep/_stage).
         self._score = jax.jit(
-            partial(_score_program, cfg=self.cfg, model=self.family)
+            named_partial(_score_program, cfg=self.cfg, model=self.family)
         )
         self.score_shapes: List[Tuple[int, int]] = (
             derive_score_shapes(
@@ -1250,21 +1254,25 @@ class PagedEngine:
         # wall clock for tokens/sec through the serving path).
         self.total_generated_tokens = 0
         # Megastep efficiency accounting, drained by pop_dispatch_stats():
-        # program dispatches the host issued, tokens emitted to requests
-        # (admission first tokens + reaped stream tokens), and pad lanes
-        # burnt by slots that finished inside a megastep (the on-device
-        # `dead` account). dispatches/tokens is the host-round-trips-per-
-        # token ratio the megastep exists to shrink.
-        self._dispatches = 0
+        # program dispatches the host issued (every `engine.prog.*` span,
+        # counted by `_progs`), tokens emitted to requests (admission
+        # first tokens + reaped stream tokens), and pad lanes burnt by
+        # slots that finished inside a megastep (the on-device `dead`
+        # account). dispatches/tokens is the host-round-trips-per-token
+        # ratio the megastep exists to shrink.
         self._emitted_tokens = 0
         self._dead_lane_tokens = 0
+        # Where the lanes and the waits go, each counted at the line that
+        # does the work and drained by pop_loop_stats().
+        self._counts: Dict[str, int] = {}
+        self._obs: Dict[str, List[float]] = {}
         # Flight-recorder observability, drained by the serving queue:
         # (program, wall-clock start, dispatch seconds) per compiled-
         # program dispatch — program names key the inventory entries and
         # the metrics registry's ENGINE_PROGRAM_HISTOGRAMS — and per-rid
         # pending-queue wait (submit -> popped for admission). Bounded so
         # a queue-less caller (bench drain loops) cannot grow them.
-        self._prog_times: List[Tuple[str, float, float]] = []
+        self._progs = ProgramLog(self._PROG_TIMES_MAX)
         self._queue_waits: Dict[int, float] = {}
         # Shared-prefix accounting: per-rid pinned tree paths (released
         # when the request completes — eviction never frees a block a
@@ -1305,14 +1313,30 @@ class PagedEngine:
             for rid in list(d)[: -self._PROG_TIMES_MAX // 2]:
                 d.pop(rid, None)
 
-    def _time_prog(self, name: str, t0: float, t0_unix: float) -> None:
-        """Record one dispatch's host wall time (device compute overlaps
-        it under pipelining; the dispatch call is what the serving loop
-        actually spends)."""
-        self._dispatches += 1
-        self._prog_times.append((name, t0_unix, time.monotonic() - t0))
-        if len(self._prog_times) > self._PROG_TIMES_MAX:
-            del self._prog_times[: -self._PROG_TIMES_MAX]
+    def _span(self, name: str, **attrs) -> Span:
+        """A host span (engine/spans.py); `engine.prog.*` ones are the
+        timed dispatches."""
+        return Span(name, self._progs, **attrs)
+
+    def _count(self, **amounts: int) -> None:
+        for name, n in amounts.items():
+            self._counts[name] = self._counts.get(name, 0) + n
+
+    def _observe(self, name: str, value: float) -> None:
+        vals = self._obs.setdefault(name, [])
+        vals.append(value)
+        if len(vals) > self._PROG_TIMES_MAX:
+            del vals[: -self._PROG_TIMES_MAX // 2]
+
+    def pop_loop_stats(self) -> Tuple[Dict[str, int], Dict[str, List[float]]]:
+        """Drain (counts, observations) since the last call, keyed as
+        the metrics registry's ENGINE_LOOP_COUNTERS and
+        ENGINE_LOOP_HISTOGRAMS key them; the series' help strings there
+        say what each counts. Observations are seconds, except
+        `decode_lanes` (lanes)."""
+        out = (self._counts, self._obs)
+        self._counts, self._obs = {}, {}
+        return out
 
     def pop_dispatch_stats(self) -> Tuple[int, int, int, float, int]:
         """Drain (host_dispatches, emitted_tokens, dead_lane_tokens,
@@ -1329,10 +1353,11 @@ class PagedEngine:
         serving queue turns these into the `host_dispatches_per_token`
         gauge and the `megastep_dead_lane_tokens`/`prefill_stall_ms`/
         `decode_stalled_tokens` counters."""
-        out = (self._dispatches, self._emitted_tokens,
+        out = (self._progs.dispatches, self._emitted_tokens,
                self._dead_lane_tokens, self._prefill_stall_s * 1000.0,
                self._decode_stalled_tokens)
-        self._dispatches = self._emitted_tokens = self._dead_lane_tokens = 0
+        self._progs.dispatches = 0
+        self._emitted_tokens = self._dead_lane_tokens = 0
         self._prefill_stall_s = 0.0
         self._decode_stalled_tokens = 0
         return out
@@ -1366,8 +1391,7 @@ class PagedEngine:
     def pop_program_times(self) -> List[Tuple[str, float, float]]:
         """Drain (program, start_unix, dispatch_s) recorded since last
         call."""
-        out, self._prog_times = self._prog_times, []
-        return out
+        return self._progs.pop()
 
     def pop_queue_waits(self) -> Dict[int, float]:
         """Drain rid -> seconds spent in the pending queue before its
@@ -1395,39 +1419,15 @@ class PagedEngine:
         return self.kv_bytes_total // max(1, self.tp)
 
     def _init_state(self, width: Optional[int] = None) -> SlotState:
-        state = _fresh_state(self.family, self.cfg, self.slots,
-                             width or self.widths[0])
         # Plane-table mesh shardings from birth, in the canonical
         # spelling: raw single-device arrays would key the jit caches
         # differently than the programs' own (pinned) outputs, so the
         # first install/step after a rebuild would silently recompile
         # (see _plane_spec). KV planes are born tp-sharded over their
         # heads axis; host-state planes replicated.
-        def put(x, name):
-            return jax.device_put(x, jax.sharding.NamedSharding(
-                self.mesh, _plane_spec(name)
-            ))
-
-        return state._replace(
-            cache=state.cache._replace(
-                k=put(state.cache.k, "cache.k"),
-                v=put(state.cache.v, "cache.v"),
-                ks=(None if state.cache.ks is None
-                    else put(state.cache.ks, "cache.ks")),
-                vs=(None if state.cache.vs is None
-                    else put(state.cache.vs, "cache.vs")),
-                length=put(state.cache.length, "cache.length"),
-            ),
-            tok=put(state.tok, "tok"),
-            active=put(state.active, "active"),
-            seen=put(state.seen, "seen"),
-            transcript=put(state.transcript, "transcript"),
-            staged=put(state.staged, "staged"),
-            stage_cursor=put(state.stage_cursor, "stage_cursor"),
-            stage_len=put(state.stage_len, "stage_len"),
-            stage_seq=put(state.stage_seq, "stage_seq"),
-            stage_rng=put(state.stage_rng, "stage_rng"),
-        )
+        return self._canon_state(_fresh_state(
+            self.family, self.cfg, self.slots, width or self.widths[0]
+        ))
 
     # ------------------------------------------------------------ host API
 
@@ -1647,10 +1647,12 @@ class PagedEngine:
             self._prefix_evictions = 0
             self._prefix_hits = {}
         # The warmup drain is not serving traffic: drop its dispatch/token
-        # counts (so the first pop_dispatch_stats() reflects live requests
-        # only) and put the controller back on its configured starting rung
-        # (the idle drain grew K toward the ceiling).
+        # counts and program times (so the first drains reflect live
+        # requests only) and put the controller back on its configured
+        # starting rung (the idle drain grew K toward the ceiling).
         self.pop_dispatch_stats()
+        self.pop_loop_stats()
+        self._progs.pop()
         self.megastep_k = self._megastep_initial
         return time.monotonic() - t0
 
@@ -1763,7 +1765,7 @@ class PagedEngine:
         self._stream_watch = set()
         self._final_tokens = {}
         self._session_reqs = {}
-        self._prog_times = []
+        self._progs.pop()
         self._queue_waits = {}
         self._staged_prompts = {}
         self.megastep_k = self._megastep_initial
@@ -1798,7 +1800,9 @@ class PagedEngine:
         right-padded [1, bucket] id plane both admission paths feed the
         device."""
         req = self._pending.pop(0)
-        self._queue_waits[req.rid] = time.monotonic() - req.submit_time
+        req.popped_time = time.monotonic()
+        wait = self._queue_waits[req.rid] = req.popped_time - req.submit_time
+        self._observe("queue_wait", wait)
         self._shed_oldest(self._queue_waits)
         # Smallest length bucket that fits: a 10-token query prefills a
         # 16/32-wide program, not the full Tmax-wide one (one compiled
@@ -1818,9 +1822,8 @@ class PagedEngine:
             # Pad the live cache up (donated, in device order after any
             # in-flight chunks — their snapshots are separate arrays and
             # unaffected).
-            t0, t0u = time.monotonic(), time.time()
-            self.state = self._grow(self.state, w_req)
-            self._time_prog("grow", t0, t0u)
+            with self._span(PROG + "grow"):
+                self.state = self._grow(self.state, w_req)
 
     def _admit(self) -> None:
         # All free slots fill before any host sync: the prefill+install
@@ -1854,13 +1857,13 @@ class PagedEngine:
                 c1, first, seen_row = self._run_prefill(
                     req, bucket, ids, rng
                 )
-                t0, t0u = time.monotonic(), time.time()
-                self.state = self._install(
-                    self.state, jnp.asarray(slot, jnp.int32), c1,
-                    jnp.asarray(ids), jnp.asarray(req.prompt_len, jnp.int32),
-                    first, seen_row,
-                )
-                self._time_prog("install", t0, t0u)
+                with self._span(PROG + "install"):
+                    self.state = self._install(
+                        self.state, jnp.asarray(slot, jnp.int32), c1,
+                        jnp.asarray(ids),
+                        jnp.asarray(req.prompt_len, jnp.int32),
+                        first, seen_row,
+                    )
             admitted.append((slot, req, first))
         if not admitted:
             return
@@ -1874,11 +1877,8 @@ class PagedEngine:
             )
         for (slot, req, _), first in zip(admitted, firsts):
             req.tokens = [int(first)]
-            self._emitted_tokens += 1
             self._slot_req[slot] = req
-            ttft = now - req.submit_time
-            self.ttfts[req.rid] = ttft
-            self.last_ttft_s = ttft
+            self._first_token(req, now)
 
     def _stage_admissions(self) -> None:
         """Fused admission: hand every admissible pending request to the
@@ -1906,11 +1906,8 @@ class PagedEngine:
                 if cursor0:
                     pc.acquire(match)
                     self._prefix_pins[req.rid] = match
-                self._prefix_hit_tokens += cursor0
-                self._prefix_prompt_tokens += req.prompt_len
-                self._prefix_hits[req.rid] = cursor0
-                self._shed_oldest(self._prefix_hits)
                 self._staged_prompts[req.rid] = list(req.tokens)
+            self._note_admission(req, cursor0)
             # Same canon-before-dispatch discipline as _admit: the
             # grow/stage_block/stage programs key on the warmed input
             # shardings.
@@ -1919,24 +1916,22 @@ class PagedEngine:
                 self._grow_if_needed(w_req)
                 if cursor0:
                     blocks = match.blocks()[: cursor0 // pc.block_tokens]
-                    t0, t0u = time.monotonic(), time.time()
                     for i, blk in enumerate(blocks):
-                        self.state = self._stage_block(
-                            self.state, blk, jnp.asarray(slot, jnp.int32),
-                            jnp.asarray(i * pc.block_tokens, jnp.int32),
-                        )
-                    self._dispatches += max(0, len(blocks) - 1)
-                    self._time_prog("stage_block", t0, t0u)
-                t0, t0u = time.monotonic(), time.time()
-                self.state = self._stage(
-                    self.state, jnp.asarray(slot, jnp.int32),
-                    jnp.asarray(ids),
-                    jnp.asarray(req.prompt_len, jnp.int32),
-                    jnp.asarray(cursor0, jnp.int32),
-                    jnp.asarray(self._stage_seq, jnp.int32),
-                    jax.random.key_data(rng),
-                )
-                self._time_prog("stage", t0, t0u)
+                        with self._span(PROG + "stage_block"):
+                            self.state = self._stage_block(
+                                self.state, blk,
+                                jnp.asarray(slot, jnp.int32),
+                                jnp.asarray(i * pc.block_tokens, jnp.int32),
+                            )
+                with self._span(PROG + "stage"):
+                    self.state = self._stage(
+                        self.state, jnp.asarray(slot, jnp.int32),
+                        jnp.asarray(ids),
+                        jnp.asarray(req.prompt_len, jnp.int32),
+                        jnp.asarray(cursor0, jnp.int32),
+                        jnp.asarray(self._stage_seq, jnp.int32),
+                        jax.random.key_data(rng),
+                    )
             self._stage_seq += 1
             req.live = False
             self._slot_req[slot] = req
@@ -1999,42 +1994,45 @@ class PagedEngine:
             pc.acquire(match)
             self._prefix_pins[req.rid] = match
             blocks = match.blocks()[: prefix_used // pc.block_tokens]
-            t0, t0u = time.monotonic(), time.time()
             cache0 = self._fresh_prefill_cache(bucket)
             for i, blk in enumerate(blocks):
-                cache0 = self._load_block(
-                    cache0, blk,
-                    jnp.asarray(i * pc.block_tokens, jnp.int32),
-                )
-            self._dispatches += max(0, len(blocks) - 1)
-            self._time_prog("load_block", t0, t0u)
+                with self._span(PROG + "load_block"):
+                    cache0 = self._load_block(
+                        cache0, blk,
+                        jnp.asarray(i * pc.block_tokens, jnp.int32),
+                    )
             ids_suf = np.full((1, suffix_bucket), self.tokenizer.pad_id,
                               np.int32)
             ids_suf[0, : req.prompt_len - prefix_used] = (
                 req.tokens[prefix_used:]
             )
-            t0, t0u = time.monotonic(), time.time()
-            c1, first, seen_row = self._partial_prefill(
-                self.params, cache0, jnp.asarray(ids),
-                jnp.asarray(ids_suf),
-                jnp.asarray(prefix_used, jnp.int32),
-                jnp.asarray(req.prompt_len, jnp.int32), rng,
-            )
-            self._time_prog("partial_prefill", t0, t0u)
+            with self._span(PROG + "partial_prefill"):
+                c1, first, seen_row = self._partial_prefill(
+                    self.params, cache0, jnp.asarray(ids),
+                    jnp.asarray(ids_suf),
+                    jnp.asarray(prefix_used, jnp.int32),
+                    jnp.asarray(req.prompt_len, jnp.int32), rng,
+                )
         else:
-            t0, t0u = time.monotonic(), time.time()
-            c1, first, seen_row = self._prefill(
-                self.params, jnp.asarray(ids),
-                jnp.asarray(req.prompt_len, jnp.int32), rng,
-            )
-            self._time_prog("prefill", t0, t0u)
+            with self._span(PROG + "prefill"):
+                c1, first, seen_row = self._prefill(
+                    self.params, jnp.asarray(ids),
+                    jnp.asarray(req.prompt_len, jnp.int32), rng,
+                )
         if pc is not None:
             self._publish(req, c1)
-            self._prefix_hit_tokens += prefix_used
-            self._prefix_prompt_tokens += req.prompt_len
-            self._prefix_hits[req.rid] = prefix_used
-            self._shed_oldest(self._prefix_hits)
+        self._note_admission(req, prefix_used)
         return c1, first, seen_row
+
+    def _note_admission(self, req: _Request, hit: int) -> None:
+        """Count one admitted prompt and the shared-prefix hit it had."""
+        self._count(prompt_tokens=req.prompt_len,
+                    prefill_tokens=req.prompt_len - hit)
+        if self.prefix_cache is not None:
+            self._prefix_hit_tokens += hit
+            self._prefix_prompt_tokens += req.prompt_len
+            self._prefix_hits[req.rid] = hit
+            self._shed_oldest(self._prefix_hits)
 
     def _publish(self, req: _Request, c1: KVCache) -> None:
         """Publish the completed prefill's whole prompt blocks into the
@@ -2043,23 +2041,28 @@ class PagedEngine:
         block budget (after insert, so a publish can never evict blocks
         its own admission still references; pinned paths are never
         evicted regardless)."""
-        pc = self.prefix_cache
-        blk_t = pc.block_tokens
-        t0, t0u = time.monotonic(), time.time()
+        blk_t = self.prefix_cache.block_tokens
+        self._insert_blocks(
+            req.tokens[: (req.prompt_len // blk_t) * blk_t], c1, 0
+        )
+        self._prefix_evictions += self.prefix_cache.evict_to_budget()
+
+    def _insert_blocks(self, tokens: List[int], cache: KVCache,
+                       slot: int) -> None:
+        """Insert `tokens`' whole blocks into the radix tree: for each
+        one it does not hold, an immutable copy exported from `cache` at
+        `slot`. Runs under `self.mesh`, entered ONCE, like every other
+        dispatch (the jit cache keys on the ambient mesh)."""
+        blk_t = self.prefix_cache.block_tokens
+        slot_ix = jnp.asarray(slot, jnp.int32)
 
         def make_block(i: int) -> KVBlock:
-            return self._canon_block(self._export_block(
-                c1, jnp.asarray(i * blk_t, jnp.int32),
-                jnp.asarray(0, jnp.int32),
-            ))
+            with self._span(PROG + "export_block"):
+                return self._canon_block(self._export_block(
+                    cache, jnp.asarray(i * blk_t, jnp.int32), slot_ix,
+                ))
 
-        added = pc.insert(
-            req.tokens[: (req.prompt_len // blk_t) * blk_t], make_block
-        )
-        if added:
-            self._dispatches += added - 1
-            self._time_prog("export_block", t0, t0u)
-        self._prefix_evictions += pc.evict_to_budget()
+        self.prefix_cache.insert(tokens, make_block)
 
     def _publish_staged(self, req: _Request, slot: int) -> None:
         """Fused-admission publish, at flip-reap time: the prompt's KV
@@ -2074,29 +2077,15 @@ class PagedEngine:
         if tokens is None:
             return
         blk_t = pc.block_tokens
-        t0, t0u = time.monotonic(), time.time()
-        slot_ix = jnp.asarray(slot, jnp.int32)
         # Export from a canonical state: the flip-reap hands us a raw
         # megastep output, but warmup compiled `_export_block` against
         # the canonical cache shardings (zero-copy when they agree).
         self.state = self._canon_state(self.state)
-
-        def make_block(i: int) -> KVBlock:
-            # Under the mesh context like every other dispatch: the jit
-            # cache keys on the ambient mesh, and warmup compiled these
-            # programs under it.
-            with self.mesh:
-                return self._canon_block(self._export_block(
-                    self.state.cache, jnp.asarray(i * blk_t, jnp.int32),
-                    slot_ix,
-                ))
-
-        added = pc.insert(
-            tokens[: (req.prompt_len // blk_t) * blk_t], make_block
-        )
-        if added:
-            self._dispatches += added - 1
-            self._time_prog("export_block", t0, t0u)
+        with self.mesh:
+            self._insert_blocks(
+                tokens[: (req.prompt_len // blk_t) * blk_t],
+                self.state.cache, slot,
+            )
         self._prefix_evictions += pc.evict_to_budget()
 
     def _publish_session(self, req: _Request, slot: int) -> None:
@@ -2127,21 +2116,9 @@ class PagedEngine:
         n = (safe // blk_t) * blk_t
         if n <= 0:
             return
-        t0, t0u = time.monotonic(), time.time()
         self.state = self._canon_state(self.state)
-        slot_ix = jnp.asarray(slot, jnp.int32)
-
-        def make_block(i: int) -> KVBlock:
-            with self.mesh:
-                return self._canon_block(self._export_block(
-                    self.state.cache, jnp.asarray(i * blk_t, jnp.int32),
-                    slot_ix,
-                ))
-
-        added = pc.insert(full[:n], make_block)
-        if added:
-            self._dispatches += added - 1
-            self._time_prog("export_block", t0, t0u)
+        with self.mesh:
+            self._insert_blocks(full[:n], self.state.cache, slot)
         pc.pin_session(session_id, full[:n], ttl_s)
         self._prefix_evictions += pc.evict_to_budget()
 
@@ -2295,46 +2272,56 @@ class PagedEngine:
         wide under saturation and boundaries exact where a pending
         request can join.
         """
-        if self.fused:
-            self._stage_admissions()
-        else:
-            self._admit()
-        work = self._live() or self._any_staged()
-        if work:
-            self.megastep_k = next_megastep_k(
-                self.megastep_k, self.megastep_ks, len(self._pending),
-                self._slack_chunks(), fused=self.fused,
-            )
-        if work and (self.fused or self.megastep_k > 1):
-            # Fused admission dispatches through the megastep at EVERY
-            # rung (K=1 included): the scan body carries the in-scan
-            # prefill phase, so staged slots keep advancing no matter
-            # where the controller sits.
-            self.state = self._canon_state(self.state)
-            rngs = self._step_keys(self.megastep_k)
-            t0, t0u = time.monotonic(), time.time()
-            with self.mesh:
+        with self._span("engine.step"):
+            with self._span("engine.admit"):
+                if self.fused:
+                    self._stage_admissions()
+                else:
+                    self._admit()
+            if self._live() or self._any_staged():
+                self.megastep_k = next_megastep_k(
+                    self.megastep_k, self.megastep_ks, len(self._pending),
+                    self._slack_chunks(), fused=self.fused,
+                )
+                # Fused admission dispatches through the megastep at
+                # EVERY rung (K=1 included): the scan body carries the
+                # in-scan prefill phase, so staged slots keep advancing
+                # no matter where the controller sits.
+                mega = self.fused or self.megastep_k > 1
+                k = self.megastep_k if mega else 1
+                with self._span("engine.dispatch", k=k):
+                    self._dispatch(k, mega)
+            done: List[Tuple[int, str]] = []
+            while self._inflight and (
+                len(self._inflight) >= self.inflight_limit
+                if (self._live() or self._any_staged())
+                else True
+            ):
+                done.extend(self._reap(*self._inflight.pop(0)))
+                # _reap may finish the last live request: the loop
+                # condition re-evaluates _live(), so remaining dispatches
+                # drain right here.
+        return done
+
+    def _dispatch(self, k: int, mega: bool) -> None:
+        """Send the device the megastep at rung `k`, or one `_step`."""
+        self.state = self._canon_state(self.state)
+        counts = dead = flipped = firsts = None
+        if mega:
+            rngs = self._step_keys(k)
+            with self.mesh, self._span(PROG + "megastep"):
                 self.state, *outs = self._megastep(
                     self.params, self.state, rngs
                 )
-                if self.fused:
-                    flipped, firsts = outs[-2], outs[-1]
-                    outs = outs[:-2]
-                else:
-                    flipped = firsts = None
-                if self.spec:
-                    toks, counts, active, dead = outs
-                else:
-                    toks, active, dead = outs
-                    counts = None
-            self._time_prog("megastep", t0, t0u)
-            self._push_inflight(toks, counts, active, dead, flipped,
-                                firsts)
-        elif work:
+            if self.fused:
+                *outs, flipped, firsts = outs
+            if self.spec:
+                toks, counts, active, dead = outs
+            else:
+                toks, active, dead = outs
+        else:
             self._rng, rng = jax.random.split(self._rng)
-            self.state = self._canon_state(self.state)
-            t0, t0u = time.monotonic(), time.time()
-            with self.mesh:
+            with self.mesh, self._span(PROG + "step"):
                 if self.spec:
                     self.state, toks, counts, active = self._step(
                         self.params, self.state, rng
@@ -2343,20 +2330,9 @@ class PagedEngine:
                     self.state, toks, active = self._step(
                         self.params, self.state, rng
                     )
-                    counts = None
-            self._time_prog("step", t0, t0u)
-            self._push_inflight(toks, counts, active, None, None, None)
-        done: List[Tuple[int, str]] = []
-        while self._inflight and (
-            len(self._inflight) >= self.inflight_limit
-            if (self._live() or self._any_staged())
-            else True
-        ):
-            done.extend(self._reap(*self._inflight.pop(0)))
-            # _reap may finish the last live request: the loop condition
-            # re-evaluates _live(), so remaining dispatches drain right
-            # here.
-        return done
+        self._count(scan_iterations=k * self.chunk,
+                    lane_steps=k * self.chunk * self.slots)
+        self._push_inflight(toks, counts, active, dead, flipped, firsts)
 
     def _push_inflight(self, toks, counts, active, dead, flipped,
                        firsts) -> None:
@@ -2391,7 +2367,8 @@ class PagedEngine:
         straight from the live cache, and its decode walk starts at the
         flip iteration's rows (earlier rows are pre-flip pad filler, not
         content)."""
-        with intended_transfer():  # THE sync point of the engine loop
+        # THE sync point of the engine loop.
+        with self._span("engine.reap.wait") as wait, intended_transfer():
             toks = np.asarray(toks_dev)  # [(K,) chunk, S(, k+1)]
             counts = None if counts_dev is None else np.asarray(counts_dev)
             # [S] int8 post-chunk flags, or [K, S] per-chunk snapshots
@@ -2402,6 +2379,14 @@ class PagedEngine:
                        else np.asarray(flipped_dev))  # [K, S] bool
             firsts = (None if firsts_dev is None
                       else np.asarray(firsts_dev))    # [K, S] int32
+        self._observe("reap_wait", wait.wall_s)
+        with self._span("engine.reap.host"):
+            return self._walk(toks, counts, active, flipped, firsts,
+                              slot_snapshot)
+
+    def _walk(self, toks, counts, active, flipped, firsts,
+              slot_snapshot) -> List[Tuple[int, str]]:
+        """The host half of a reap: one dispatch's tokens and lanes."""
         k_axis = active.shape[0] if active.ndim == 2 else 1
         if active.ndim == 2:
             # Megastep: flatten the K axis into one [K*chunk, S] token
@@ -2417,10 +2402,14 @@ class PagedEngine:
         done: List[Tuple[int, str]] = []
         eos, pad = self.tokenizer.eos_id, self.tokenizer.pad_id
         now = time.monotonic()
+        rows = toks.shape[0]  # this dispatch's scan iterations
+        decoded = staged = overrun = 0
         for slot, req in enumerate(slot_snapshot):
             if req is None or req.finished:
                 # Empty at dispatch, or finished by an earlier chunk — this
-                # chunk's column holds dead-slot filler.
+                # chunk's column holds dead-slot filler (a finished one's
+                # lane ran all the same: overrun).
+                overrun += rows if req is not None else 0
                 continue
             start_row = 0
             if not req.live:
@@ -2431,19 +2420,18 @@ class PagedEngine:
                 col = (np.zeros((k_axis,), bool) if flipped is None
                        else flipped[:, slot])
                 if not col.any():
+                    staged += rows
                     continue
                 j = int(np.argmax(col))
                 req.tokens = [int(firsts[j, slot])]
                 req.live = True
-                self._emitted_tokens += 1
-                ttft = now - req.submit_time
-                self.ttfts[req.rid] = ttft
-                self.last_ttft_s = ttft
+                self._first_token(req, now)
                 if self.prefix_cache is not None:
                     self._publish_staged(req, slot)
                 # The flip iteration's decode chunk is the slot's first:
                 # earlier rows are pre-flip filler.
                 start_row = j * self.chunk
+                staged += start_row
             finished = False
             dead = not bool(active[slot])
             n_before = len(req.tokens)
@@ -2496,6 +2484,10 @@ class PagedEngine:
                     finished = True
                     break
             self._emitted_tokens += len(req.tokens) - n_before
+            decoded += len(req.tokens) - n_before
+            if finished and not dead and counts is None:
+                # The host's budget cap, which the device does not know.
+                overrun += rows - start_row - (len(req.tokens) - n_before)
             if dead:
                 finished = True
             if finished:
@@ -2530,7 +2522,15 @@ class PagedEngine:
                 self.state = self.state._replace(
                     active=self.state.active.at[slot].set(False)
                 )
+        self._count(staged_lane_steps=staged, overrun_lane_steps=overrun)
+        self._observe("decode_lanes", decoded / rows)
         return done
+
+    def _first_token(self, req: _Request, now: float) -> None:
+        """A request's first token reached the host."""
+        self._emitted_tokens += 1
+        self.ttfts[req.rid] = self.last_ttft_s = now - req.submit_time
+        self._observe("prefill_wait", now - req.popped_time)
 
     def drain(self) -> Dict[int, str]:
         out: Dict[int, str] = {}

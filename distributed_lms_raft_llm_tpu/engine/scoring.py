@@ -48,6 +48,7 @@ import numpy as np
 from ..utils import metrics_registry as metric
 from ..utils.guards import intended_transfer
 from .generate import pick_bucket
+from .spans import PROG, Span
 
 log = logging.getLogger(__name__)
 
@@ -173,15 +174,12 @@ def score_texts(engine: Any, texts: Sequence[str]) -> List[Dict[str, Any]]:
             out.extend(score_texts(engine, texts[start : start + cap]))
         return out
     ids, mask, truncated = encode_score_batch(engine, texts)
-    t0, t0_unix = time.monotonic(), time.time()
-    with engine.mesh, intended_transfer():
+    with Span(PROG + "score", engine._progs), engine.mesh, \
+            intended_transfer():
         total, count = jax.device_get(
             engine._score(engine.params, jnp.asarray(ids),
                           jnp.asarray(mask))
         )
-    engine._prog_times.append(("score", t0_unix, time.monotonic() - t0))
-    if len(engine._prog_times) > engine._PROG_TIMES_MAX:
-        del engine._prog_times[: -engine._PROG_TIMES_MAX]
     out = []
     for i in range(len(texts)):
         n = int(count[i])

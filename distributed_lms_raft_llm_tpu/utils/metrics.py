@@ -49,7 +49,7 @@ class LatencyHistogram:
     all-time p95 up forever).
     """
 
-    def __init__(self, max_samples: int = 4096, recent: int = 1024):
+    def __init__(self, max_samples: int = 4096, recent: int = 4096):
         self._samples: List[float] = []  # guarded-by: _lock
         self._max = max_samples
         self._count = 0                  # guarded-by: _lock
@@ -129,9 +129,14 @@ class Metrics:
         with self._lock:
             self._gauges[name] = float(value)
 
-    def inc(self, name: str, amount: int = 1) -> None:
+    def inc(self, name: str, amount: int = 1) -> int:
+        """Add to a counter; returns its new total (so a ratio gauge can
+        be computed from the counters it is a ratio of)."""
         with self._lock:
-            self._counters[name] = self._counters.get(name, 0) + amount
+            total = self._counters[name] = (
+                self._counters.get(name, 0) + amount
+            )
+        return total
 
     def hist(self, name: str) -> LatencyHistogram:
         with self._lock:

@@ -514,6 +514,24 @@ ENGINE_PROG_STAGE = histogram(
     "arming a slot's staged prompt; the prefill itself runs inside the "
     "megastep scan)",
 )
+ENGINE_PROG_STAGE_BLOCK = histogram(
+    "engine_prog_stage_block",
+    "paged-engine _stage_block program dispatch wall time (fused "
+    "admission: one cached shared-prefix block spliced into a slot's "
+    "pages; one observation per block)",
+)
+ENGINE_PROG_LOAD_BLOCK = histogram(
+    "engine_prog_load_block",
+    "paged-engine _load_block program dispatch wall time (sequential "
+    "admission: one cached shared-prefix block spliced into a fresh "
+    "prompt cache; one observation per block)",
+)
+ENGINE_PROG_EXPORT_BLOCK = histogram(
+    "engine_prog_export_block",
+    "paged-engine _export_block program dispatch wall time (one prompt "
+    "or session block copied out of a cache into the radix tree; one "
+    "observation per block)",
+)
 ENGINE_PROG_SCORE = histogram(
     "engine_prog_score",
     "score program dispatch wall time (one background-scoring quantum: "
@@ -536,8 +554,111 @@ ENGINE_PROGRAM_HISTOGRAMS: Dict[str, str] = {
     "megastep": ENGINE_PROG_MEGASTEP,
     "grow": ENGINE_PROG_GROW,
     "stage": ENGINE_PROG_STAGE,
+    "stage_block": ENGINE_PROG_STAGE_BLOCK,
+    "load_block": ENGINE_PROG_LOAD_BLOCK,
+    "export_block": ENGINE_PROG_EXPORT_BLOCK,
     "score": ENGINE_PROG_SCORE,
     "generate": ENGINE_PROG_GENERATE,
+}
+
+# The paged engine's loop, counted where the work happens (engine/paged.py
+# `_count` / `_observe`, drained by PagedQueue through `pop_loop_stats`
+# and `pop_dispatch_stats`). Counters, so a reader differences them over
+# any window; the run-long ratio gauges above are computed from them.
+
+ENGINE_TOKENS_EMITTED = counter(
+    "engine_tokens_emitted",
+    "tokens the paged engine handed to requests (first tokens and reaped "
+    "decode tokens): the denominator of every per-token ratio",
+)
+ENGINE_DISPATCHES = counter(
+    "engine_dispatches",
+    "compiled programs the paged engine's host loop dispatched (every "
+    "engine.prog.* span, per-block copies included): what "
+    "host_dispatches_per_token divides",
+)
+ENGINE_PROMPT_TOKENS_ADMITTED = counter(
+    "engine_prompt_tokens_admitted",
+    "prompt tokens of the requests admitted to a slot (as served, after "
+    "the engine's cut to its largest prompt bucket)",
+)
+ENGINE_PREFILL_TOKENS = counter(
+    "engine_prefill_tokens",
+    "prompt tokens admitted less the shared-prefix hit: the positions "
+    "the prefill (in the scan, or its own program) really computes",
+)
+ENGINE_SCAN_ITERATIONS = counter(
+    "engine_scan_iterations",
+    "decode scan iterations the device was sent (K x chunk a megastep, "
+    "chunk a step; a verify window each in spec mode)",
+)
+ENGINE_LANE_STEPS = counter(
+    "engine_lane_steps",
+    "engine_scan_iterations x slots: the lanes' budget, of which decode "
+    "tokens, staged, overrun and dead lane-steps are parts and the rest "
+    "ran empty",
+)
+ENGINE_STAGED_LANE_STEPS = counter(
+    "engine_staged_lane_steps",
+    "lane-steps held by a request still prefilling in the scan: for a "
+    "slot staged at dispatch, the iterations before its flip (all of "
+    "them if it did not flip)",
+)
+ENGINE_OVERRUN_LANE_STEPS = counter(
+    "engine_overrun_lane_steps",
+    "lane-steps run for a request past its last token: the rest of the "
+    "dispatch in which the host's budget cap ended it (the device does "
+    "not know the cap), and every dispatch already in flight when the "
+    "host reaped the finish",
+)
+QUEUE_WAIT = histogram(
+    "queue_wait",
+    "engine submit -> popped from the pending queue for admission, per "
+    "request (waiting for a slot)",
+)
+PREFILL_WAIT = histogram(
+    "prefill_wait",
+    "popped for admission -> first token on the host, per request: "
+    "staging, the prefill and the pipeline's lag (queue_wait + this = "
+    "ttft)",
+)
+ENGINE_REAP_WAIT = histogram(
+    "engine_reap_wait",
+    "host blocked on the device's results, per reaped dispatch (the "
+    "engine.reap.wait span)",
+)
+ENGINE_HOST_TURN = histogram(
+    "engine_host_turn",
+    "one turn of the serving loop (engine.step + queue.between_steps) "
+    "less its engine.reap.wait: the host's own work per turn",
+)
+ENGINE_DECODE_LANES = histogram(
+    "engine_decode_lanes",
+    "decode tokens reaped from a dispatch / its scan iterations, per "
+    "reaped dispatch: the batch size a step really ran at. The value is "
+    "a count of LANES (at most the slot count), not seconds",
+)
+STREAM_CHUNK_GAP = histogram(
+    "stream_chunk_gap",
+    "time since the stream's previous chunk, per chunk pushed to a "
+    "streaming client (the gap a student sees between bursts)",
+)
+
+# Engine-reported short name -> declared series (see
+# ENGINE_PROGRAM_HISTOGRAMS for why the mappings live here).
+ENGINE_LOOP_COUNTERS: Dict[str, str] = {
+    "prompt_tokens": ENGINE_PROMPT_TOKENS_ADMITTED,
+    "prefill_tokens": ENGINE_PREFILL_TOKENS,
+    "scan_iterations": ENGINE_SCAN_ITERATIONS,
+    "lane_steps": ENGINE_LANE_STEPS,
+    "staged_lane_steps": ENGINE_STAGED_LANE_STEPS,
+    "overrun_lane_steps": ENGINE_OVERRUN_LANE_STEPS,
+}
+ENGINE_LOOP_HISTOGRAMS: Dict[str, str] = {
+    "queue_wait": QUEUE_WAIT,
+    "prefill_wait": PREFILL_WAIT,
+    "reap_wait": ENGINE_REAP_WAIT,
+    "decode_lanes": ENGINE_DECODE_LANES,
 }
 
 # Storage layer (raft/storage.py + lms/persistence.py via lms/node.py).
