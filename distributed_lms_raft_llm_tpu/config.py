@@ -102,8 +102,8 @@ class TutoringConfig:
     prefill_chunk_tokens: int = 0  # paged: fused stall-free admission —
     #                              stage arriving prompts into SlotState
     #                              and prefill this many tokens per
-    #                              megastep scan iteration INSIDE the
-    #                              decode program, instead of pausing
+    #                              decode iteration INSIDE the
+    #                              megastep program, instead of pausing
     #                              the decode train for a standalone
     #                              prefill dispatch. 0 = sequential
     #                              admission. Admission latency becomes

@@ -61,15 +61,21 @@ Four jitted program families, compiled once each:
   `_stage_program` writes the prompt ids into the slot's transcript row
   and arms a staged-admission plane riding in SlotState (staged flag,
   chunk cursor, true length, first-token rng), with cached shared-prefix
-  blocks spliced straight into the slot's pages (`_stage_block`); then
-  every megastep scan iteration runs ONE token-budgeted prefill chunk
-  (`prefill_chunk_tokens` positions) for the oldest staged slot — the
-  Sarathi-Serve chunked-prefill idea, device-resident — before the
-  decode chunk advances the live slots. The final chunk samples the
-  first token with the cold path's rng/seen-mask contract and flips the
-  slot live mid-megastep; `flipped`/`firsts` planes come back stacked
-  [K, S] so the one batched reap learns admission outcomes with zero
-  extra syncs. Decode never waits on admission (`decode_stalled_tokens`
+  blocks spliced straight into the slot's pages (`_stage_block`); then,
+  while any slot is staged, EVERY decode iteration of the megastep (each
+  row of the per-token scan inside each of its K chunks, not each chunk)
+  first runs one token-budgeted prefill chunk (`prefill_chunk_tokens`
+  positions) for the oldest staged slot — the Sarathi-Serve
+  chunked-prefill idea, device-resident — and then advances the live
+  slots by a token. The final chunk samples the first token with the
+  cold path's rng/seen-mask contract and flips the slot live in that
+  same iteration; `flipped`/`firsts` planes come back stacked
+  [K, chunk, S], a row per iteration like the tokens, so the one batched
+  reap learns admission outcomes with zero extra syncs, and a request
+  holds its lane staged for about as many iterations as the chunks
+  queued before its last (`engine_staged_iterations`), where a chunk per
+  `chunk` iterations held it sixteen times as long. Decode never waits
+  on admission (`decode_stalled_tokens`
   stays 0), prefill compute fills the scan's pipeline bubbles, and
   greedy outputs are bit-identical to the sequential prefill-then-decode
   path at any K and chunk budget (tests/test_fused_prefill.py).
@@ -139,7 +145,8 @@ class SlotState(NamedTuple):
     transcript: jax.Array
     # Staged-admission plane (fused chunked prefill; all [S], inert zeros
     # when `prefill_chunk_tokens` is 0): `staged` marks slots whose
-    # prompt is being prefilled inside the megastep scan, `stage_cursor`
+    # prompt is being prefilled inside the megastep scan (one chunk per
+    # decode iteration, for the oldest by `stage_seq`), `stage_cursor`
     # the next absolute prefill position (starts at the spliced
     # shared-prefix length), `stage_len` the true prompt length,
     # `stage_seq` the host's staging sequence number (FIFO service order
@@ -171,6 +178,9 @@ class _Request:
     # not yet sampled). `tokens` still holds the prompt until the flip is
     # reaped; _live()/_slack_chunks treat staged requests as not-yet-live.
     live: bool = True
+    # Scan iterations this request held a lane while staged, in the
+    # dispatches reaped before its flip's (`engine_staged_iterations`).
+    staged_rows: int = 0
 
 
 def _plane_spec(name: str) -> jax.sharding.PartitionSpec:
@@ -326,7 +336,7 @@ def _stage_program(state: SlotState, slot, ids, true_len, cursor0, seq,
     right-padded prompt into the slot's transcript row and set the
     staged-admission plane — prefill then advances inside the megastep
     scan (`_admission_chunk`), one `prefill_chunk_tokens` chunk per
-    iteration, until the flip samples the first token.
+    decode iteration, until the flip samples the first token.
 
     `cursor0` is the already-spliced shared-prefix length (0 cold; the
     caller stages cached blocks into the slot's pages via `_stage_block`
@@ -479,8 +489,8 @@ def _grow_state_program(state: SlotState, new_len: int) -> SlotState:
 
 
 def _step_program(params, state: SlotState, rng, *, cfg, sampling,
-                  eos_id: int, pad_id: int, model,
-                  chunk: int = 1) -> Tuple[SlotState, jax.Array, jax.Array]:
+                  eos_id: int, pad_id: int, model, chunk: int = 1,
+                  admit=None):
     """`chunk` decode steps for all S slots (per-row cache offsets).
 
     Chunking exists because the paged loop is host-driven: every dispatch
@@ -501,10 +511,20 @@ def _step_program(params, state: SlotState, rng, *, cfg, sampling,
     leading K axis ([K, chunk, S] tokens, [K, S] snapshots) — the
     snapshot/donation invariant is per chunk, so it carries over
     unchanged; only the host reap granularity moves from one chunk to K.
+
+    `admit` (fused admission only; the megastep binds `_admission_chunk`
+    to it) runs at the head of EVERY scan iteration, before the decode:
+    state -> (state, flipped [S], firsts [S]). A slot it flips live
+    decodes its first token in that same iteration, and the two planes
+    come back stacked [chunk, S] after the snapshot. None (every engine
+    with `prefill_chunk_tokens = 0`) leaves body and outputs as they are.
     """
     tmax = state.cache.k.shape[3]
 
-    def one(s: SlotState, step_rng) -> Tuple[SlotState, jax.Array]:
+    def one(s: SlotState, step_rng):
+        extra = ()
+        if admit is not None:
+            s, *extra = admit(s)
         # Inactive/full slots write into their current position; clamp to
         # stay in bounds — the slot is dead or about to be evicted, the
         # data ignored.
@@ -532,18 +552,20 @@ def _step_program(params, state: SlotState, rng, *, cfg, sampling,
                 active=still,
                 seen=seen,
             ),
-            nxt,
+            (nxt, *extra),
         )
 
-    state, toks = jax.lax.scan(one, state, jax.random.split(rng, chunk))
-    return state, toks, state.active.astype(jnp.int8)
+    state, (toks, *extra) = jax.lax.scan(
+        one, state, jax.random.split(rng, chunk)
+    )
+    return (state, toks, state.active.astype(jnp.int8), *extra)
 
 
 def _spec_step_program(
     params, state: SlotState, rng, *, cfg, sampling, eos_id: int,
     pad_id: int, model, spec_tokens: int, chunk: int = 1,
-    draft_fn=build_drafts,
-) -> Tuple[SlotState, jax.Array, jax.Array, jax.Array]:
+    draft_fn=build_drafts, admit=None,
+):
     """`chunk` speculative verify windows for all S slots.
 
     Each scan iteration generalizes the [S, 1] step to a [S, k+1] window:
@@ -570,6 +592,8 @@ def _spec_step_program(
     order (`verify_window`'s valid plane is a contiguous prefix); count 0
     means the slot was inactive. Like the plain step's outputs, all three
     are fresh buffers that survive the next dispatch donating the state.
+    `admit` is `_step_program`'s: it runs before each window, and its
+    [chunk, S] planes follow the snapshot.
     """
     k = spec_tokens
     width = state.cache.k.shape[3]
@@ -577,6 +601,9 @@ def _spec_step_program(
     offs_k1 = jnp.arange(k + 1, dtype=jnp.int32)[None, :]
 
     def one(s: SlotState, step_rng):
+        extra = ()
+        if admit is not None:
+            s, *extra = admit(s)
         offs = jnp.minimum(s.cache.length, width - 1 - k)  # [S] window base
         # Drafts: the pending last token sits at transcript slot `offs`;
         # an anchor must be filled AND have k filled continuation slots
@@ -629,19 +656,23 @@ def _spec_step_program(
                 seen=seen,
                 transcript=transcript,
             ),
-            (emitted, m),
+            (emitted, m, *extra),
         )
 
-    state, (emitted, counts) = jax.lax.scan(
+    state, (emitted, counts, *extra) = jax.lax.scan(
         one, state, jax.random.split(rng, chunk)
     )
-    return state, emitted, counts, state.active.astype(jnp.int8)
+    return (state, emitted, counts, state.active.astype(jnp.int8), *extra)
 
 
 def _admission_chunk(params, s: SlotState, *, cfg, sampling, model,
                      eos_id: int, pad_id: int, prefill_chunk: int):
     """One token-budgeted prefill chunk for the oldest staged admission —
-    the fused-admission phase of a megastep scan iteration.
+    the fused-admission phase at the head of every decode iteration (the
+    megastep hands it to the per-token scan body as `admit`; staging
+    happens only between dispatches, so every staged slot is known at a
+    megastep's entry and is served, oldest first, a chunk an iteration
+    until none is left).
 
     If any slot is staged: slice that slot's pages out of the live cache,
     forward the next `prefill_chunk` prompt ids from its transcript row
@@ -781,10 +812,12 @@ def _megastep_program(params, state: SlotState, rngs, *, cfg, sampling,
     - spec:  (state, emitted [K, chunk, S, k+1], counts [K, chunk, S],
               active [K, S] int8, dead int32)
     - fused admission (`prefill_chunk > 0`): either of the above plus
-      (flipped [K, S] bool, firsts [K, S] int32) — per iteration, the
-      slot whose staged prefill completed and the first token it
-      sampled, so the batched reap learns admission outcomes without an
-      extra sync (see `_admission_chunk`).
+      (flipped [K, chunk, S] bool, firsts [K, chunk, S] int32) — per
+      decode iteration (a row of the token plane), the slot whose staged
+      prefill completed at its head and the first token it sampled, so
+      the batched reap learns admission outcomes without an extra sync
+      and starts the slot's decode walk at that row (see
+      `_admission_chunk`, bound to the scan body's `admit`).
 
     `active[j]` is the post-chunk-j snapshot — the same fresh non-donated
     plane the single-chunk program returns, K of them — so the host's
@@ -798,8 +831,9 @@ def _megastep_program(params, state: SlotState, rngs, *, cfg, sampling,
     mode each lane is a verify window whose forward computes
     spec_tokens+1 token positions. dead = chunk * lane_tokens * sum over
     j<K-1 of |slots LIVE by chunk j but inactive after it| (live =
-    active at entry, or flipped live by a fused admission at an earlier
-    iteration — a flip-then-eos inside one megastep strands lanes too;
+    active at entry, or flipped live by a fused admission at any row of
+    chunk j or an earlier one — a flip-then-eos inside one megastep
+    strands lanes too;
     lane_tokens = spec_tokens+1 when speculating, else 1) — zero at K=1
     (the host reaps every chunk), and exactly the positions a chunk-loop
     host reap would have freed. Slots already dead at entry (empty, or
@@ -808,62 +842,54 @@ def _megastep_program(params, state: SlotState, rngs, *, cfg, sampling,
     stranded decode.
     """
     started = state.active  # read before the scan consumes the donation
-
-    def one_chunk(s: SlotState, r):
-        if prefill_chunk:
-            # Fused admission: one bounded prefill chunk for the oldest
-            # staged slot BEFORE the decode chunk, so a flip's first
-            # decode token lands in this same iteration's token plane —
-            # the slot joins the train at a scan-iteration boundary, not
-            # a dispatch boundary.
+    body = dict(cfg=cfg, sampling=sampling, eos_id=eos_id, pad_id=pad_id,
+                model=model, chunk=chunk)
+    if prefill_chunk:
+        # Fused admission: the scan body serves the oldest staged slot one
+        # bounded prefill chunk BEFORE each decode iteration, so a flip's
+        # first decode token lands in that same row of the token plane —
+        # the slot joins the train at a scan-iteration boundary, not a
+        # chunk or dispatch boundary.
+        def admit(s: SlotState):
             with jax.named_scope("prefill_chunk"):
-                s, flipped, firsts = _admission_chunk(
+                return _admission_chunk(
                     params, s, cfg=cfg, sampling=sampling, model=model,
                     eos_id=eos_id, pad_id=pad_id,
                     prefill_chunk=prefill_chunk,
                 )
-            extra = (flipped, firsts)
-        else:
-            extra = ()
+
+        body["admit"] = admit
+
+    def one_chunk(s: SlotState, r):
         if spec_tokens:
-            s, emitted, counts, active = _spec_step_program(
-                params, s, r, cfg=cfg, sampling=sampling, eos_id=eos_id,
-                pad_id=pad_id, model=model, spec_tokens=spec_tokens,
-                chunk=chunk, draft_fn=draft_fn,
+            s, *outs = _spec_step_program(
+                params, s, r, spec_tokens=spec_tokens, draft_fn=draft_fn,
+                **body,
             )
-            return s, (emitted, counts, active) + extra
-        s, toks, active = _step_program(
-            params, s, r, cfg=cfg, sampling=sampling, eos_id=eos_id,
-            pad_id=pad_id, model=model, chunk=chunk,
-        )
-        return s, (toks, active) + extra
+        else:
+            s, *outs = _step_program(params, s, r, **body)
+        return s, tuple(outs)
 
     state, outs = jax.lax.scan(one_chunk, state, rngs)
     if prefill_chunk:
-        flipped, firsts = outs[-2], outs[-1]  # [K, S] admission planes
-        outs = outs[:-2]
+        *outs, flipped, firsts = outs  # [K, chunk, S] admission planes
     active = outs[-1]  # [K, S] int8 post-chunk snapshots
     lane_tokens = chunk * ((spec_tokens + 1) if spec_tokens else 1)
-    # A lane is stranded from the first iteration it is dead AFTER having
+    # A lane is stranded from the first chunk it is dead AFTER having
     # been live: live = active at entry, or flipped live by a fused
-    # admission at any earlier iteration (a flip-then-eos inside one
-    # megastep burns real pad lanes too). Pre-flip staged iterations are
-    # admission work, not stranded decode, and never count.
+    # admission in that chunk or an earlier one (a flip-then-eos inside
+    # one megastep burns real pad lanes too). Pre-flip staged iterations
+    # are admission work, not stranded decode, and never count.
     if prefill_chunk:
         live = started[None, :] | (
-            jnp.cumsum(flipped.astype(jnp.int32), axis=0) > 0
+            jnp.cumsum(flipped.any(axis=1).astype(jnp.int32), axis=0) > 0
         )
     else:
         live = jnp.broadcast_to(started[None, :], active.shape)
     dead = jnp.asarray(lane_tokens, jnp.int32) * jnp.sum(
         (live[:-1] & (active[:-1] == 0)).astype(jnp.int32)
     )
-    if spec_tokens:
-        emitted, counts, _ = outs
-        res = (state, emitted, counts, active, dead)
-    else:
-        toks, _ = outs
-        res = (state, toks, active, dead)
+    res = (state, *outs, dead)
     if prefill_chunk:
         res = res + (flipped, firsts)
     return res
@@ -1071,11 +1097,11 @@ class PagedEngine:
         # Fused chunked prefill (stall-free admission): with
         # `prefill_chunk_tokens > 0`, admissions are STAGED into SlotState
         # and prefill advances inside the megastep scan — one bounded
-        # chunk per iteration — instead of dispatching a blocking prefill
-        # program between decode dispatches. The budget is clamped so a
-        # final chunk's pad-tail ids still fit the transcript slice
-        # window (the slice starts at cursor <= bucket-1 and must end
-        # inside the cache width = bucket + max_new + spec overhang).
+        # chunk per decode iteration — instead of dispatching a blocking
+        # prefill program between decode dispatches. The budget is
+        # clamped so a final chunk's pad-tail ids still fit the transcript
+        # slice window (the slice starts at cursor <= bucket-1 and must
+        # end inside the cache width = bucket + max_new + spec overhang).
         self.fused = prefill_chunk_tokens > 0
         self.prefill_chunk = 0
         if self.fused:
@@ -1217,7 +1243,7 @@ class PagedEngine:
         #  per-chunk snapshots for a megastep (the reap flattens the K
         #  axis and keys dead-slot detection off the FINAL snapshot),
         #  dead-lane scalar device array for a megastep else None,
-        #  flipped [K, S] bool / firsts [K, S] int32 fused-admission
+        #  flipped / firsts [K, chunk, S] bool / int32 fused-admission
         #  planes (None without fused prefill),
         #  slot->request snapshot at dispatch time).
         # Every device entry is a fresh non-donated buffer (see
@@ -1333,7 +1359,7 @@ class PagedEngine:
         the metrics registry's ENGINE_LOOP_COUNTERS and
         ENGINE_LOOP_HISTOGRAMS key them; the series' help strings there
         say what each counts. Observations are seconds, except
-        `decode_lanes` (lanes)."""
+        `decode_lanes` (lanes) and `staged_iterations` (iterations)."""
         out = (self._counts, self._obs)
         self._counts, self._obs = {}, {}
         return out
@@ -1886,10 +1912,12 @@ class PagedEngine:
         shared-prefix blocks spliced straight into the slot's pages, the
         staged-admission plane armed — with zero blocking work. The
         prefill itself advances inside the megastep scan
-        (`_admission_chunk`), one bounded chunk per iteration, and the
-        flip's first token comes back through the megastep's
-        flipped/firsts planes at the next batched reap: the decode train
-        never pauses for admission."""
+        (`_admission_chunk`), one bounded chunk per decode iteration for
+        the oldest staged slot, and the flip's first token comes back
+        through the megastep's flipped/firsts planes at the next batched
+        reap: the decode train never pauses for admission. This is the
+        only place a slot becomes staged, so a megastep sees all of them
+        at its entry and none appears inside it."""
         self._maybe_rebuild_idle()
         pc = self.prefix_cache
         for slot in range(self.slots):
@@ -2341,7 +2369,7 @@ class PagedEngine:
         No blocking readback here — but START the device->host copies
         now, so the dispatch's results stream back while later programs
         compute and the reap's device_get finds them already on the
-        host. Fused admission's flipped/firsts planes ([K, S]) ride the
+        host. Fused admission's flipped/firsts planes ([K, chunk, S]) ride the
         same pipe, so learning a staged slot went live costs no extra
         sync.
         """
@@ -2365,8 +2393,7 @@ class PagedEngine:
         request's stream head (TTFT recorded here — the first host moment
         the token exists), its prompt blocks publish into the radix tree
         straight from the live cache, and its decode walk starts at the
-        flip iteration's rows (earlier rows are pre-flip pad filler, not
-        content)."""
+        flip's row (earlier rows are pre-flip pad filler, not content)."""
         # THE sync point of the engine loop.
         with self._span("engine.reap.wait") as wait, intended_transfer():
             toks = np.asarray(toks_dev)  # [(K,) chunk, S(, k+1)]
@@ -2376,9 +2403,9 @@ class PagedEngine:
             if dead_dev is not None:
                 self._dead_lane_tokens += int(np.asarray(dead_dev))
             flipped = (None if flipped_dev is None
-                       else np.asarray(flipped_dev))  # [K, S] bool
+                       else np.asarray(flipped_dev))  # [K, chunk, S]
             firsts = (None if firsts_dev is None
-                      else np.asarray(firsts_dev))    # [K, S] int32
+                      else np.asarray(firsts_dev))    # [K, chunk, S]
         self._observe("reap_wait", wait.wall_s)
         with self._span("engine.reap.host"):
             return self._walk(toks, counts, active, flipped, firsts,
@@ -2387,7 +2414,6 @@ class PagedEngine:
     def _walk(self, toks, counts, active, flipped, firsts,
               slot_snapshot) -> List[Tuple[int, str]]:
         """The host half of a reap: one dispatch's tokens and lanes."""
-        k_axis = active.shape[0] if active.ndim == 2 else 1
         if active.ndim == 2:
             # Megastep: flatten the K axis into one [K*chunk, S] token
             # walk (the per-slot scan below is shape-agnostic in its
@@ -2398,6 +2424,10 @@ class PagedEngine:
                                 *toks.shape[2:])
             if counts is not None:
                 counts = counts.reshape(-1, counts.shape[-1])
+            if flipped is not None:
+                # [K, chunk, S] -> one row per scan iteration, like toks.
+                flipped = flipped.reshape(-1, flipped.shape[-1])
+                firsts = firsts.reshape(-1, firsts.shape[-1])
             active = active[-1]
         done: List[Tuple[int, str]] = []
         eos, pad = self.tokenizer.eos_id, self.tokenizer.pad_id
@@ -2417,21 +2447,23 @@ class PagedEngine:
                 # meaningful. No flip yet -> the prefill is still
                 # advancing; the column is pad filler and the slot's
                 # inactive flag must NOT read as a death.
-                col = (np.zeros((k_axis,), bool) if flipped is None
+                col = (np.zeros((rows,), bool) if flipped is None
                        else flipped[:, slot])
                 if not col.any():
                     staged += rows
+                    req.staged_rows += rows
                     continue
-                j = int(np.argmax(col))
-                req.tokens = [int(firsts[j, slot])]
+                # The flip's ROW is the slot's first decode iteration:
+                # earlier rows are pre-flip filler.
+                start_row = int(np.argmax(col))
+                req.tokens = [int(firsts[start_row, slot])]
                 req.live = True
                 self._first_token(req, now)
                 if self.prefix_cache is not None:
                     self._publish_staged(req, slot)
-                # The flip iteration's decode chunk is the slot's first:
-                # earlier rows are pre-flip filler.
-                start_row = j * self.chunk
                 staged += start_row
+                self._observe("staged_iterations",
+                              req.staged_rows + start_row)
             finished = False
             dead = not bool(active[slot])
             n_before = len(req.tokens)

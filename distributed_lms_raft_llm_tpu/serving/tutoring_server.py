@@ -654,8 +654,8 @@ def main(argv=None) -> None:
     parser.add_argument("--prefill-chunk-tokens", type=int, default=0,
                         help="paged engine fused stall-free admission: "
                         "stage arriving prompts into the decode state "
-                        "and prefill this many tokens per megastep scan "
-                        "iteration INSIDE the decode program, so "
+                        "and prefill this many tokens per decode "
+                        "iteration INSIDE the megastep program, so "
                         "admission never pauses the decode train "
                         "(decode_stalled_tokens stays 0; admission "
                         "latency is bounded by scan iterations, not "

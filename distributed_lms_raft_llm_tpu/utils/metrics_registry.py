@@ -601,8 +601,8 @@ ENGINE_LANE_STEPS = counter(
 ENGINE_STAGED_LANE_STEPS = counter(
     "engine_staged_lane_steps",
     "lane-steps held by a request still prefilling in the scan: for a "
-    "slot staged at dispatch, the iterations before its flip (all of "
-    "them if it did not flip)",
+    "slot staged at dispatch, the scan iterations (rows) before its flip "
+    "(all of them if it did not flip)",
 )
 ENGINE_OVERRUN_LANE_STEPS = counter(
     "engine_overrun_lane_steps",
@@ -638,6 +638,13 @@ ENGINE_DECODE_LANES = histogram(
     "reaped dispatch: the batch size a step really ran at. The value is "
     "a count of LANES (at most the slot count), not seconds",
 )
+ENGINE_STAGED_ITERATIONS = histogram(
+    "engine_staged_iterations",
+    "scan iterations a request held a lane while staged, observed once "
+    "per request at its flip: the rows of every dispatch reaped before "
+    "the flip's, plus the flip's row in its own. The value is a count of "
+    "ITERATIONS, not seconds",
+)
 STREAM_CHUNK_GAP = histogram(
     "stream_chunk_gap",
     "time since the stream's previous chunk, per chunk pushed to a "
@@ -659,6 +666,7 @@ ENGINE_LOOP_HISTOGRAMS: Dict[str, str] = {
     "prefill_wait": PREFILL_WAIT,
     "reap_wait": ENGINE_REAP_WAIT,
     "decode_lanes": ENGINE_DECODE_LANES,
+    "staged_iterations": ENGINE_STAGED_ITERATIONS,
 }
 
 # Storage layer (raft/storage.py + lms/persistence.py via lms/node.py).

@@ -237,6 +237,17 @@ def test_counters_conserve_tokens_and_lane_steps():
              + c["engine_overrun_lane_steps"])
     assert 0 < parts <= c["engine_lane_steps"]
     assert c["engine_staged_lane_steps"] > 0
+    # Staged lanes are counted in ROWS (single scan iterations), and each
+    # request's rows are observed once, at its flip: the histogram's sum
+    # is the counter.
+    staged = lat["engine_staged_iterations"]
+    assert staged["count"] == n
+    assert staged["mean_s"] * n == pytest.approx(
+        c["engine_staged_lane_steps"])
+    # Two slots staged together, served one chunk an iteration in
+    # staging order: the second waits out the first's chunks, row by
+    # row, and nobody waits a 16-iteration chunk per prefill chunk.
+    assert 0 < staged["max_s"] < c["engine_scan_iterations"]
     # engine_decode_lanes holds lanes, one observation per reaped
     # dispatch, none above the slot count.
     assert lat["engine_decode_lanes"]["count"] == lat[

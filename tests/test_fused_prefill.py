@@ -19,6 +19,7 @@ prompt-lookup's on a temperature-0.8 workload.
 import asyncio
 
 import jax.numpy as jnp
+import numpy as np
 import pytest
 
 from distributed_lms_raft_llm_tpu.engine import (
@@ -183,6 +184,54 @@ class TestGreedyBitEquality:
         rp = [pipe.submit(p) for p in PROMPTS]
         out_pipe = pipe.drain()
         assert [out_pipe[r] for r in rp] == [out_ser[r] for r in rs]
+
+
+# --------------------------------------- a chunk at every scan iteration
+
+
+STAGED_TOGETHER = ["what is raft?", "hello world", "explain paging",
+                   "k v w x"]
+
+
+@pytest.mark.parametrize("spec_tokens", [0, 2], ids=["plain", "spec"])
+def test_staged_slots_are_served_every_iteration_in_stage_order(spec_tokens):
+    """Four slots staged at one dispatch boundary, each needing several
+    prefill chunks: ONE megastep serves them a chunk per scan iteration
+    (not per `chunk` of iterations), oldest staging first, so slot n
+    flips within the first sum(chunks of slots 0..n) rows of the
+    `flipped` plane — and every stream is still the sequential path's.
+    """
+    budget = 4
+    eng = PagedEngine(
+        make_config(spec_tokens=spec_tokens), slots=4, chunk=2, inflight=2,
+        megastep=8, megastep_max=8, prefill_chunk_tokens=budget,
+    )
+    rs = [eng.submit(p) for p in STAGED_TOGETHER]
+    eng.step()  # stage all four, dispatch one K=8 megastep, reap nothing
+    (_, _, _, _, flipped, firsts, snapshot), = eng._inflight
+    assert [r.rid for r in snapshot] == rs, "staged in slot = submit order"
+    chunks = [-(-r.prompt_len // budget) for r in snapshot]
+    assert min(chunks) > 1 and sum(chunks) <= 8 * 2
+    flipped = np.asarray(flipped)
+    assert flipped.shape == (8, 2, 4) == np.asarray(firsts).shape
+    rows = flipped.reshape(-1, 4)
+    served = 0
+    for slot, need in enumerate(chunks):
+        assert rows[:, slot].sum() == 1, "one flip per staged slot"
+        served += need
+        # FIFO, one chunk an iteration: its last chunk is row served-1.
+        assert int(np.argmax(rows[:, slot])) == served - 1
+    assert rows[served:].sum() == 0
+    out = eng.drain()
+    seq = PagedEngine(make_config(), slots=4, chunk=2, megastep=8,
+                      megastep_max=8)
+    sr = [seq.submit(p) for p in STAGED_TOGETHER]
+    expected = seq.drain()
+    assert [out[r] for r in rs] == [expected[r] for r in sr]
+    _, observations = eng.pop_loop_stats()
+    assert observations["staged_iterations"] == [
+        float(sum(chunks[: i + 1]) - 1) for i in range(4)
+    ]
 
 
 # ------------------------------------------------- stall-free acceptance

@@ -477,7 +477,7 @@ def test_host_readback_in_staged_reap_fails_lint():
     )
 
     project = _project_with_patch(PAGED, (
-        "                col = (np.zeros((k_axis,), bool) if flipped is None\n"
+        "                col = (np.zeros((rows,), bool) if flipped is None\n"
         "                       else flipped[:, slot])",
         "                col = np.asarray(flipped_dev)[:, slot]",
     ))
