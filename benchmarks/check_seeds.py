@@ -1,15 +1,16 @@
-"""Read the two numbers a limit of `benchmarks/check.py` is set from, on the
-chip, at a configuration's own size, in one process:
+"""Read the two numbers each limit of a configuration's `check.limits` is
+set from, on the chip, at the configuration's own size, in one process:
 
-    python benchmarks/check_seeds.py --config gpt2-xl --seeds 12 --control 3
+    python benchmarks/check_seeds.py --config <config> --seeds 12 --control 3
 
 For each seed it builds the program's engine as a run does (no warm-up, no
-server) and prints the three numbers `benchmarks/check.py` compares (logits
-whole, logits at the worst position, keys and values); for the first
-`--control` seeds it also prints those of every control
-(`reference.CONTROLS`: the reference in int4 weights, in int4 K and V, in
-fp8 activations). The last lines sum up each number: the largest sound
-reading, the smallest reading of each control and its ratio to it. Lines also go to
+server) and prints the numbers the configuration's family compares
+(`families/<family>/compare.py` `readings`); for the first `--control`
+seeds it also prints those of every control of the family
+(`families/<family>/reference.py` `CONTROLS`: the reference with one stated
+precision a step lower). The last lines sum up each number: the largest
+sound reading, the smallest reading of each control and its ratio to it,
+beside the limit the configuration file holds. Lines also go to
 `chiprun_out/check_seeds_<config>.jsonl`.
 """
 
@@ -39,7 +40,7 @@ def main(argv=None) -> int:
 
     if args.platform == "cpu":
         jax.config.update("jax_platforms", "cpu")
-    from benchmarks import check, serve
+    from benchmarks import check, families, serve
     from distributed_lms_raft_llm_tpu.utils.compilation import (
         enable_compilation_cache,
     )
@@ -50,12 +51,12 @@ def main(argv=None) -> int:
         return 3
     with open(os.path.join(HERE, "configs", args.config + ".json")) as fh:
         config = json.load(fh)
+    fam = families.of_config(config)
     out_dir = os.path.join(REPO, "chiprun_out")
     os.makedirs(out_dir, exist_ok=True)
-    from benchmarks.reference import gpt2 as reference
 
     sound = []
-    control = {c: [] for c in reference.CONTROLS}
+    control = {c: [] for c in fam.reference.CONTROLS}
     with open(os.path.join(out_dir, f"check_seeds_{args.config}.jsonl"),
               "w") as log:
 
@@ -65,37 +66,34 @@ def main(argv=None) -> int:
             log.write(line + "\n")
             log.flush()
 
-        shape = config["check"]
         for i in range(args.seeds):
             seed = args.first_seed + 7919 * i
             engine = serve.build_engine(config, seed)
             got = check.compare(engine.family, engine.cfg, engine.params,
-                                config, seed, shape)
+                                config, seed)
             del engine
             sound.extend(got["readings"])
             say(seed=seed, sound=got["readings"])
             if i < args.control:
-                seqs = check.sequences(
-                    seed, int(shape["sequences"]),
-                    int(shape["prompt_tokens"]) + int(shape["decode_tokens"]),
-                    int(config["vocab_size"]))
-                want = check.reference_logits(config, seed, seqs)
-                for name in reference.CONTROLS:
-                    ctl = check.reference_logits(config, seed, seqs, name)
-                    read = [check.readings(c, w) for c, w in zip(ctl, want)]
+                seqs = check.sequences_of(config, seed)
+                want = check.reference_side(config, seed, seqs)
+                for name in fam.reference.CONTROLS:
+                    ctl = check.reference_side(config, seed, seqs, name)
+                    read = [fam.compare.readings(c, w)
+                            for c, w in zip(ctl, want)]
                     del ctl
                     control[name].extend(read)
                     say(seed=seed, control=name, readings=read)
-        for key, limit in (("whole", check.LIMIT),
-                           ("position", check.LIMIT_POSITION),
-                           ("kv", check.LIMIT_KV)):
+        for key, limit in config["check"]["limits"].items():
             lows = {c: min(r[key] for r in v)
                     for c, v in control.items() if v}
             top = max(r[key] for r in sound)
-            say(number=key, config=args.config, seeds=args.seeds,
-                sound_max=top, sound_min=min(r[key] for r in sound),
-                control_min=lows, limit=limit,
-                ratios={c: v / top for c, v in lows.items()},
+            say(number=key, config=args.config, family=fam.name,
+                seeds=args.seeds, sound_max=top,
+                sound_min=min(r[key] for r in sound), control_min=lows,
+                limit=limit,
+                ratios={c: v / top if top else None
+                        for c, v in lows.items()},
                 device=jax.devices()[0].device_kind)
     return 0
 

@@ -6,8 +6,8 @@ program. It follows the published model (Radford et al. 2019; the
 `GPT2LMHeadModel` layout): learned position embeddings, pre-LayerNorm
 blocks, one fused QKV projection, causal softmax attention, tanh-GELU MLP,
 final LayerNorm, output head tied to the token embedding. Weights are the
-stacked published-name dictionary `benchmarks/weights.py` draws from the
-seed.
+stacked published-name dictionary `weights.py` beside this file draws from
+the seed.
 
 `CONTROLS` are the controls of the `correct` comparison: the same reference
 with ONE stated precision taken to the nearest step below it, everything
@@ -79,12 +79,14 @@ def _head(x, gain, bias, wte, *, eps, fp8=False):
     return (_through_fp8(h) if fp8 else h) @ wte.T
 
 
-def forward(w: dict, ids, *, n_head: int, eps: float = 1e-5, control=None):
+def forward(w: dict, ids, config: dict, control=None):
     """(logits [T, V], keys [L, H, T, Dh], values [L, H, T, Dh]), float32,
     for one sequence of token ids [T]: the keys and values every layer
-    attends over, as a cache would hold them. `control` names one of
+    attends over, as a cache would hold them. The head count and the
+    LayerNorm epsilon are the configuration file's. `control` names one of
     `CONTROLS` (`int4_weights` rounds `w` in place: a second float32 copy
     of gpt2-xl does not fit beside the first)."""
+    n_head, eps = int(config["n_head"]), float(config["layer_norm_epsilon"])
     if control not in (None,) + CONTROLS:
         raise ValueError(f"no control is called {control!r}: {CONTROLS}")
     if control == "int4_weights":

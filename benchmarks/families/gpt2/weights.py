@@ -30,8 +30,9 @@ import numpy as np
 SIZE_KEYS = ("vocab_size", "n_positions", "n_embd", "n_layer", "n_head")
 
 
-VOCAB = os.path.join(os.path.dirname(os.path.abspath(__file__)), "vocab",
-                     "vocab.json")
+BENCH = os.path.dirname(os.path.dirname(os.path.dirname(
+    os.path.abspath(__file__))))
+VOCAB = os.path.join(BENCH, "vocab", "vocab.json")
 QUIET = 0.01
 
 

@@ -4,19 +4,29 @@ a reader takes what a traced run gathered (`ctx`) and returns a number, or
 None where it finds nothing to read, and the harness then leaves the metric
 out of the result.
 
+A reader's name is looked up in `READERS` below first, then as a file of
+its own, `benchmarks/layer_readers/<reader>.py` with a `read(args, ctx)`:
+a later reader is a new file there, and nothing here is edited for it.
+
 `ctx` holds: `outcomes` (the client's view of every request), `seconds`,
 `tokens_in_window`, `marked` and `collected` (the server's /metrics,
 /healthz and window percentiles when the window opened and after its last
 request was drained), `trace` (what `benchmarks/trace.py` reduced the
-profiler's trace to) and `trace_span` (its start and end on the client's
-clock), `config`, `cell`, `traffic_spec`, `device`.
+profiler's trace to, with `span_counters`, the growth of the server's
+counters over the traced span) and `trace_span` (its start and end on the
+client's clock), `config`, `cell`, `traffic_spec`, `device`.
 """
 
 from __future__ import annotations
 
+import importlib
+import os
 import re
 
-from benchmarks import roofline, stats
+from benchmarks import families, roofline, stats
+
+READERS_DIR = os.path.join(os.path.dirname(os.path.abspath(__file__)),
+                           "layer_readers")
 
 
 def generator_lateness(args: dict, ctx: dict):
@@ -156,22 +166,31 @@ def trace_idle(args: dict, ctx: dict):
 def roofline_share(args: dict, ctx: dict):
     """Least time the chip could take for the decode steps of the traced
     span over the device time of the programs that ran them, in percent.
-    Steps are counted in the trace; the slot-tokens they advanced and the
-    context each attended over are the clients' (`span_tokens`)."""
+    What a step costs, and which of the trace's counts is the steps, is the
+    configuration's family's to say (`families/<family>/roofline.py`); the
+    slot-tokens the steps advanced and the context each attended over are
+    the clients' (`span_tokens`). The family's count goes on the `notes`
+    line also where the trace has no device time to hold it against."""
     trace = ctx["trace"]
-    busy = _program_seconds(ctx, args["programs"])
-    steps = (trace or {}).get("decode_steps")
     span = span_tokens(ctx)
-    if not busy or not steps or not span or not span[0]:
+    if not trace or not span or not span[0]:
         return None
     tokens, context, seconds = span
-    slot_steps = tokens / seconds * trace["window_s"]
-    least = roofline.decode_least_seconds(
-        ctx["config"], ctx["device"]["kind"], steps, slot_steps,
-        context / tokens)
-    ctx.setdefault("notes", {})[args.get("note", "roofline")] = dict(
-        least, steps=steps, slot_steps=slot_steps,
-        mean_context=context / tokens, device_s=busy)
+    # Scaled to the traced window's own length (where the trace holds no
+    # device event, a rehearsal, the client's span stands).
+    slot_steps = tokens / seconds * (trace["window_s"] or seconds)
+    cost = families.of_config(ctx["config"], ("roofline",)).roofline.cost(
+        ctx["config"], trace, slot_steps, context / tokens)
+    if not cost:
+        return None
+    busy = _program_seconds(ctx, args["programs"])
+    note = dict(cost, slot_steps=slot_steps, mean_context=context / tokens,
+                device_s=busy)
+    ctx.setdefault("notes", {})[args.get("note", "roofline")] = note
+    if not busy:
+        return None
+    least = roofline.least_seconds(cost, ctx["device"]["kind"])
+    note.update(least)
     return 100.0 * least["seconds"] / busy
 
 
@@ -187,8 +206,23 @@ READERS = {
 }
 
 
-def read(reader: str, args: dict, ctx: dict):
-    if reader not in READERS:
+def in_files() -> list:
+    """The readers that are files of their own."""
+    return sorted(f[:-3] for f in os.listdir(READERS_DIR)
+                  if f.endswith(".py") and f != "__init__.py")
+
+
+def resolve(reader: str):
+    """The function a reader's name stands for: `READERS`, then
+    `benchmarks/layer_readers/<reader>.py`."""
+    if reader in READERS:
+        return READERS[reader]
+    if reader not in in_files():
         raise KeyError(f"no reader is called {reader!r}: "
-                       f"benchmarks/readers.py has {sorted(READERS)}")
-    return READERS[reader](args, ctx)
+                       f"benchmarks/readers.py has {sorted(READERS)}, "
+                       f"benchmarks/layer_readers has {in_files()}")
+    return importlib.import_module(f"benchmarks.layer_readers.{reader}").read
+
+
+def read(reader: str, args: dict, ctx: dict):
+    return resolve(reader)(args, ctx)

@@ -406,12 +406,12 @@ async def run(args) -> int:
     broken = [o.error for o in failed if not o.error.startswith("rpc ")
               and not o.error.startswith("server refused")]
     ref = ready["reference_check"]
+    # One line for every number the configuration's family is held to, by
+    # the name its `check.limits` gives the limit under.
     compared = [
-        (f"reference_{what}_distance", ref["worst"][key], "<=",
-         ref["limits"][key], ref["worst"][key] <= ref["limits"][key])
-        for key, what in (("whole", "logits"),
-                          ("position", "logits_worst_position"),
-                          ("kv", "keys_and_values"))
+        (f"reference_{key}", ref["worst"][key], "<=", limit,
+         ref["worst"][key] <= limit)
+        for key, limit in ref["limits"].items()
     ] + [
         ("reference_comparison", bool(ref["ok"]), "==", True,
          bool(ref["ok"])),
@@ -422,9 +422,11 @@ async def run(args) -> int:
         ("platform", device["platform"], "==", "tpu",
          device["platform"] == "tpu"),
     ]
-    for name, value, op, limit, ok in compared:
-        say("compared", what=name, value=value, op=op, limit=limit, ok=ok)
-    correct = all(c[-1] for c in compared)
+    compared = [dict(what=name, value=value, op=op, limit=limit, ok=ok)
+                for name, value, op, limit, ok in compared]
+    for line in compared:
+        say("compared", **line)
+    correct = all(line["ok"] for line in compared)
 
     # -- the metrics
     e2e = {}
@@ -442,7 +444,12 @@ async def run(args) -> int:
                                  ctx)
             if value is not None:
                 metrics[name] = {"value": value, "unit": units[name]}
+        # The counts a family may take for its decode steps, side by side:
+        # the trace's loops by their entries, and what the server's
+        # counters grew by over the traced span.
         say("notes", span_tokens=readers.span_tokens(ctx),
+            loops=(reduced or {}).get("loops", [])[:5],
+            span_counters=(reduced or {}).get("span_counters"),
             **ctx.get("notes", {}))
     # The most memory in use while the window's requests were served (the
     # allocator's own peak is set-up: PERF.md section 4).
@@ -469,6 +476,11 @@ async def run(args) -> int:
                                "idle_gaps": reduced["idle_gaps"][:10]}
     if "jax" in sys.modules:
         raise RunFailure("the load-generating parent imported jax")
+    # The same lines close the standard error: of a run that is not correct
+    # the driver's record keeps the end of that.
+    for line in compared:
+        print(json.dumps({"line": "compared", **line}), file=sys.stderr,
+              flush=True)
     if args.platform != "tpu":
         # A rehearsal: never a result.
         say("rehearsal_not_a_result", **result["metrics"])

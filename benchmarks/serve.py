@@ -1,19 +1,24 @@
 """The benchmark's launcher: the one process that touches the chip.
 
 Started by `benchmarks/run.py` (which never imports jax). It makes the
-weights from `--seed`, builds the program's `PagedEngine` from the
-configuration file's serving settings, runs the reference comparison
-(`benchmarks/check.py`), warms the engine up, and serves it through the
-program's own `serve_async`, as `serving/tutoring_server.main` does. Then it
-takes commands, one JSON object a line, on its standard input and answers
-each with one line on its standard output:
+weights from `--seed` as the configuration's family draws them
+(`benchmarks/families/<family>/weights.py`), builds the program's
+`PagedEngine` from the configuration file's serving settings, runs the
+reference comparison (`benchmarks/check.py`), warms the engine up, and
+serves it through the program's own `serve_async`, as
+`serving/tutoring_server.main` does. Then it takes commands, one JSON
+object a line, on its standard input and answers each with one line on its
+standard output:
 
     {"cmd": "mark"}                      start of the measured window
     {"cmd": "collect"}                   /metrics, /healthz, window percentiles,
                                          the most memory in use since the mark
     {"cmd": "trace_start", "dir": ...}   start jax.profiler
     {"cmd": "trace_stop"}                stop it
-    {"cmd": "trace_reduce", ...}         reduce the trace (benchmarks/trace.py)
+    {"cmd": "trace_reduce", ...}         reduce the trace (benchmarks/trace.py);
+                                         with it `span_counters`, the growth
+                                         of /metrics' counters from
+                                         trace_start to trace_stop
     {"cmd": "quit"}                      stop the server and exit 0
 
 Every line it prints is a JSON object with an `"event"` key; logs go to the
@@ -46,11 +51,11 @@ def emit(event: str, **doc) -> None:
 
 def build_engine(config: dict, seed: int):
     """The program's paged engine in the configuration's serving settings,
-    holding the weights `benchmarks/weights.py` draws from `seed`. The
+    holding the weights the configuration's family draws from `seed`. The
     engine casts, quantises and places them itself: they reach it where a
-    checkpoint-less start draws its own, through the family's
+    checkpoint-less start draws its own, through the program family's
     `init_params`."""
-    from benchmarks import weights
+    from benchmarks import families
     from distributed_lms_raft_llm_tpu.engine import (
         EngineConfig,
         PagedEngine,
@@ -75,6 +80,7 @@ def build_engine(config: dict, seed: int):
         scoring=s["scoring"], length_buckets=tuple(s["length_buckets"]),
         seed=int(seed) % (2 ** 31 - 1),
     )
+    weights = families.of_config(config).weights
     family, factory = registry.PRESETS[model]
     held = [weights.program_tree(weights.of_config(
         seed, config, econf.param_dtype))]
@@ -158,7 +164,7 @@ async def serve(args, config: dict, t_start: float) -> int:
 
     t = time.monotonic()
     verdict = check.compare(engine.family, engine.cfg, engine.params, config,
-                            args.seed, config["check"])
+                            args.seed)
     phases["reference_check_s"] = time.monotonic() - t
     emit("reference_check", **verdict)
 
@@ -193,6 +199,7 @@ async def serve(args, config: dict, t_start: float) -> int:
 
     mark = time.monotonic()
     tracing = False
+    span_counters = {}
     memory = MemoryWatch()
     try:
         while True:
@@ -223,8 +230,14 @@ async def serve(args, config: dict, t_start: float) -> int:
                 options.python_tracer_level = int(cmd.get("python", 1))
                 jax.profiler.start_trace(cmd["dir"], profiler_options=options)
                 tracing = True
+                at_start = metrics.snapshot()["counters"]
                 emit("trace_started", t=time.time())
             elif name == "trace_stop":
+                # What the program's counters grew by over the traced span,
+                # as the host counted (an iteration when it is reaped).
+                span_counters = {
+                    k: v - at_start.get(k, 0)
+                    for k, v in metrics.snapshot()["counters"].items()}
                 # Writing the trace takes several times its span: off the
                 # loop, so that the requests still open are served.
                 t = time.monotonic()
@@ -238,7 +251,7 @@ async def serve(args, config: dict, t_start: float) -> int:
                 reduced = await loop.run_in_executor(
                     None, trace.reduce_dir, cmd["dir"])
                 emit("trace_reduced", reduce_s=time.monotonic() - t,
-                     **reduced)
+                     span_counters=span_counters, **reduced)
             elif name == "quit":
                 break
             else:

@@ -135,24 +135,37 @@ def test_spread_is_the_quartile_distance_over_the_median():
 
 
 def test_bytes_and_operations_by_hand():
+    from benchmarks.families.gpt2 import roofline as counted
+
     xl = load("configs", "gpt2-xl.json")
     small = {"n_layer": 12, "n_embd": 768, "n_head": 12, "vocab_size": 50257}
     # gpt2-xl: 48 x 12 x 1600^2 + 50257 x 1600 int8, 4 x (48 x 9 x 1600 +
     # 50257) of scales, 2 x (48 x 13 x 1600 + 3200) of vectors.
-    assert roofline.weight_bytes(xl) == (
+    assert counted.weight_bytes(xl) == (
         1474560000 + 80411200 + 4 * 741457 + 2 * 1001600)
-    assert roofline.weight_bytes(xl) == 1559940228
-    assert roofline.weight_bytes(small) == (
+    assert counted.weight_bytes(xl) == 1559940228
+    assert counted.weight_bytes(small) == (
         84934656 + 38597376 + 4 * 133201 + 2 * 121344)
     # K and V: 2 x layers x width int8, and a float32 scale per head each.
-    assert roofline.kv_bytes_per_token(xl) == 48 * 2 * 1600 + 4 * 48 * 2 * 25
-    assert roofline.kv_bytes_per_token(small) == 12 * 2 * 768 + 4 * 12 * 2 * 12
-    assert roofline.decode_ops(xl, 16, 4000) == (
+    assert counted.kv_bytes_per_token(xl) == 48 * 2 * 1600 + 4 * 48 * 2 * 25
+    assert counted.kv_bytes_per_token(small) == 12 * 2 * 768 + 4 * 12 * 2 * 12
+    assert counted.decode_ops(xl, 16, 4000) == (
         2.0 * (1474560000 + 80411200) * 16 + 4.0 * 48 * 1600 * 4000)
-    least = roofline.decode_least_seconds(xl, "TPU v5 lite", 1, 16, 250)
+    # one step of 16 slots at a mean context of 250, the steps being the
+    # entries of the loop entered most often
+    trace = {"loops": [["%while.7 (s32[])", 1.0], ["%while.3 (s32[])", 0.5]],
+             "span_counters": {"engine_scan_iterations": 3}}
+    cost = counted.cost(xl, trace, 16, 250)
+    assert cost["steps"] == 1 and cost["steps_less_counter"] == -2
+    assert cost["bytes"] == 1559940228 + 16 * 250 * 163200
+    assert cost["ops"] == counted.decode_ops(xl, 1.0, 250) * 16
+    least = roofline.least_seconds(cost, "TPU v5 lite")
     assert least["bound"] == "memory"
     assert least["seconds"] == pytest.approx(
         (1559940228 + 16 * 250 * 163200) / 819e9)
+    assert least["steps"] == 1
+    # a trace that counted no loop: nothing to read, never a share of 0
+    assert counted.cost(xl, {"loops": []}, 16, 250) is None
 
 
 def test_an_unknown_device_kind_raises():
@@ -169,16 +182,16 @@ def test_reference_agrees_with_the_programs_forward_at_tiny_width():
     import jax.numpy as jnp
     import numpy as np
 
-    from benchmarks import check, weights
-    from benchmarks.reference import gpt2 as reference
+    from benchmarks import check
+    from benchmarks.families.gpt2 import compare, reference, weights
     from distributed_lms_raft_llm_tpu.models import registry
 
     config = load("configs", "tiny.json")
     family, cfg = registry.resolve("tiny", jnp.float32, jnp.float32)
-    check.check_sizes(config, cfg)
+    compare.check_sizes(config, cfg)
     w = weights.make(11, weights.sizes_of(config), jnp.float32)
     ids = check.sequences(11, 1, 40, config["vocab_size"])[0]
-    want, _, _ = reference.forward(w, ids, n_head=config["n_head"])
+    want, _, _ = reference.forward(w, ids, config)
     with jax.default_matmul_precision("highest"):
         got, _ = family.forward(weights.program_tree(w), cfg,
                                 jnp.asarray(ids)[None])
@@ -193,34 +206,39 @@ def test_the_served_precision_passes_and_every_control_fails():
     control (the reference with one stated precision a step lower) is
     outside at least one, at 12 layers of 768 (gpt2's own width)."""
     from benchmarks import check, serve
-    from benchmarks.reference import gpt2 as reference
+    from benchmarks.families.gpt2 import compare, reference
 
     config = load("configs", "tiny.json")
     engine = serve.build_engine(config, 5)
-    got = check.compare(engine.family, engine.cfg, engine.params, config, 5,
-                        config["check"])
+    got = check.compare(engine.family, engine.cfg, engine.params, config, 5)
     assert got["ok"]
     assert all(got["worst"][k] < got["limits"][k] / 2 for k in got["limits"])
     limits = got["limits"]
-    mid = {"vocab_size": 2048, "n_positions": 64, "n_embd": 768,
-           "n_layer": 12, "n_head": 12, "layer_norm_epsilon": 1e-5}
+    assert limits == config["check"]["limits"] == load(
+        "configs", "gpt2-xl.json")["check"]["limits"]
+    mid = {"family": "gpt2", "vocab_size": 2048, "n_positions": 64,
+           "n_embd": 768, "n_layer": 12, "n_head": 12,
+           "layer_norm_epsilon": 1e-5}
     for seed in (1, 2, 3):
         seqs = check.sequences(seed, 1, 48, 2048)
-        want = check.reference_logits(mid, seed, seqs)
+        want = check.reference_side(mid, seed, seqs)
         for name in reference.CONTROLS:
-            ctl = check.reference_logits(mid, seed, seqs, name)
+            ctl = check.reference_side(mid, seed, seqs, name)
             for c, w in zip(ctl, want):
-                read = check.readings(c, w)
-                assert any(read[k] > limits[k] for k in limits), (name, read)
+                read = compare.readings(c, w)
+                assert not check.verdict([read], limits)["ok"], (name, read)
     with pytest.raises(ValueError):
-        check.reference_logits(mid, 1, seqs, "int2_everything")
+        check.reference_side(mid, 1, seqs, "int2_everything")
+    # a reading without a limit, or a limit without a reading, is an error
+    with pytest.raises(ValueError):
+        check.verdict([read], dict(limits, routing=0.1))
 
 
 def test_weights_take_a_seed_above_32_signed_bits():
     import jax.numpy as jnp
     import numpy as np
 
-    from benchmarks import weights
+    from benchmarks.families.gpt2 import weights
 
     sizes = weights.sizes_of(load("configs", "tiny.json"))
     a = weights.make(2 ** 31 + 5, sizes)["wte"]
@@ -242,7 +260,8 @@ def test_quiet_tokens_are_the_ones_that_break_a_streamed_text(name):
 
     import numpy as np
 
-    from benchmarks import serve, weights
+    from benchmarks import serve
+    from benchmarks.families.gpt2 import weights
     from distributed_lms_raft_llm_tpu.utils import tokenizer
 
     config = load("configs", name + ".json")
@@ -412,13 +431,16 @@ def test_benchmark_json_keeps_to_the_contract():
         spec = load("layer_metrics", m["name"] + ".json")
         from benchmarks import readers
 
-        assert spec["reader"] in readers.READERS
+        assert callable(readers.resolve(spec["reader"]))
     configs = {c["name"]: c for c in b["configs"]}
     for c in configs.values():
         assert NAME.match(c["name"]) and os.path.exists(
             os.path.join(REPO, c["file"]))
         assert load(os.pardir, c["file"])["reduced"] == c["reduced"]
         assert load(os.pardir, c["file"])["source"] == c["source"]
+        from benchmarks import families
+
+        assert load(os.pardir, c["file"])["family"] in families.names()
     for w in b["workloads"]:
         assert NAME.match(w["name"]) and NAME.match(w["traffic"])
         assert w["chips"] in (1, 4) and len(w["why"]) <= 200
@@ -478,7 +500,14 @@ def test_reduction_on_a_hand_made_trace():
     assert got["programs"] == {"jit__unknown": pytest.approx(0.060),
                                "jit_convert_element_type":
                                pytest.approx(0.001)}
-    assert got["decode_steps"] == 3                    # %while.2 ran 3 times
+    # every loop by its entries: %while.2 ran 3 times, %while.1 twice
+    assert got["loops"] == [["%while.2 (s32[])", 3], ["%while.1 (s32[])", 2]]
+    from benchmarks.families.gpt2 import roofline as counted
+
+    assert counted.decode_steps(got) == 3
+    # every operation is handed on, not the ten longest
+    assert [n for n, _ in got["device_ops"]] == [
+        "%fusion.7 bf16[16,64]", "%fusion.8 f32[4]", "%copy.1 f32[4]"]
     assert got["device_ops"][0] == ["%fusion.7 bf16[16,64]",
                                     pytest.approx(0.033)]
     gaps = dict(got["idle_gaps"])
@@ -502,10 +531,16 @@ def test_reduction_on_the_recorded_trace():
     assert got["busy_s"] == pytest.approx(want["busy_s"])
     assert got["window_s"] == pytest.approx(want["window_s"])
     assert 0 < got["busy_s"] <= got["window_s"]
-    assert got["decode_steps"] == want["decode_steps"]
+    from benchmarks.families.gpt2 import roofline as counted
+
+    assert counted.decode_steps(got) == want["decode_steps"]
     assert got["programs"] == pytest.approx(want["programs"])
-    assert [n for n, _ in got["device_ops"]] == [
+    # the ten longest were recorded; now every operation is handed on
+    assert [n for n, _ in got["device_ops"][:10]] == [
         n for n, _ in want["device_ops"]]
+    assert len(got["device_ops"]) == len(
+        {n for n, _, _ in events["devices"][0]["ops"]
+         if not n.startswith(trace.CONTAINERS)})
     # by hand: the ops of the slice, merged, are the busy time
     ops = events["devices"][0]["ops"]
     total, _ = trace.union_ns((s, s + d) for _, s, d in ops)

@@ -104,13 +104,15 @@ class _Host:
 
 
 def reduce(events: dict) -> dict:
-    """Busy and idle time, time per program, the decode steps counted, the
-    device operations that took most time and the idle gaps by what the
-    host was doing. Times of several devices are averaged."""
+    """Busy and idle time, time per program, every loop's entries, every
+    device operation's time and the idle gaps by what the host was doing,
+    each list longest first and whole: what to print of them, and which
+    loop's entries are a model's decode steps, is the caller's to say.
+    Times and entries of several devices are averaged."""
     devices = [d for d in events["devices"] if d["modules"] or d["ops"]]
     if not devices:
         return {"busy_s": 0.0, "window_s": 0.0, "programs": {},
-                "decode_steps": None, "device_ops": [], "idle_gaps": []}
+                "loops": [], "device_ops": [], "idle_gaps": []}
     every = [(s, s + d) for dev in devices
              for _, s, d in dev["modules"] + dev["ops"]]
     lo, hi = min(a for a, _ in every), max(b for _, b in every)
@@ -155,12 +157,12 @@ def reduce(events: dict) -> dict:
         "busy_s": sum(busy) / len(busy) / 1e9,
         "window_s": (hi - lo) / 1e9,
         "programs": dict(programs),
-        # The loop entered most often is the scan over the layers, once per
-        # decode step.
-        "decode_steps": (max(whiles.values()) / len(devices)
-                         if whiles else None),
-        "device_ops": [[n, s] for n, s in ops.most_common(10)],
-        "idle_gaps": [[n, s] for n, s in gaps.most_common(10)],
+        # One entry per loop instruction (two programs may both have a
+        # `%while.2`: told apart by the whole instruction, printed short).
+        "loops": [[short(n), c / len(devices)]
+                  for n, c in whiles.most_common()],
+        "device_ops": [[n, s] for n, s in ops.most_common()],
+        "idle_gaps": [[n, s] for n, s in gaps.most_common()],
     }
 
 
