@@ -103,10 +103,10 @@ def _spec(sizes: tuple) -> dict:
     }
 
 
-@functools.partial(jax.jit, static_argnames=("sizes", "dtype"))
-def _make(lo, hi, wte_scale, *, sizes, dtype):
+@functools.partial(jax.jit, static_argnames=("spec", "sizes", "dtype"))
+def _make(lo, hi, wte_scale, *, spec, sizes, dtype):
     key = jax.random.fold_in(jax.random.key(lo), hi)
-    spec = _spec(sizes)
+    spec = spec(sizes)
     keys = jax.random.split(key, len(spec))
     out = {}
     for k, (name, (shape, std, mean)) in zip(keys, sorted(spec.items())):
@@ -117,10 +117,13 @@ def _make(lo, hi, wte_scale, *, sizes, dtype):
     return out
 
 
-def make(seed: int, sizes: tuple, dtype=jnp.float32, quiet: tuple = ()) -> dict:
+def make(seed: int, sizes: tuple, dtype=jnp.float32, quiet: tuple = (),
+         spec=_spec) -> dict:
     """The checkpoint for `seed`: float32 for the reference, or cast (after
     the same float32 draw) to the type the program loads it in. `quiet` are
-    the token ids whose embedding rows are scaled by `QUIET`."""
+    the token ids whose embedding rows are scaled by `QUIET`. `spec` maps
+    `sizes` to every tensor's (shape, scale, mean): GPT-2's, or that of a
+    family on the same trunk, whose `sizes` start with GPT-2's."""
     seed = int(seed)
     if seed < 0:
         raise ValueError(f"seed must be >= 0, got {seed}")
@@ -128,7 +131,8 @@ def make(seed: int, sizes: tuple, dtype=jnp.float32, quiet: tuple = ()) -> dict:
     hi = jnp.asarray(seed >> 31, jnp.int32)
     wte_scale = np.ones((sizes[0],), np.float32)
     wte_scale[list(quiet)] = QUIET
-    return _make(lo, hi, wte_scale, sizes=sizes, dtype=jnp.dtype(dtype))
+    return _make(lo, hi, wte_scale, spec=spec, sizes=sizes,
+                 dtype=jnp.dtype(dtype))
 
 
 def of_config(seed: int, config: dict, dtype=jnp.float32) -> dict:

@@ -77,6 +77,10 @@ def program(family, cfg, params, ids, shape: dict):
     n, bucket = int(shape["prompt_tokens"]), int(shape["bucket"])
     if n != bucket:
         raise ValueError(f"the prompt ({n}) has to fill its bucket ({bucket})")
+    # An ordered callback runs on one device: where the engine has laid its
+    # parameters over several (a host of many replicates them), the check's
+    # one sequence takes a copy on the first.
+    params = jax.device_put(params, jax.local_devices()[0])
     del _heard[:]
     with _probed():
         logits, keys, values = trunk.program_logits(

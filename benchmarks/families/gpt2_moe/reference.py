@@ -4,7 +4,8 @@ benchmark's reference for the `gpt2_moe` family.
 `jax.numpy`, float32, matmuls at `highest` precision, the whole sequence at
 once, no cache, no quantisation, and no import from the program. The trunk
 is GPT-2's (learned positions, pre-LayerNorm blocks, fused QKV, causal
-softmax attention, final LayerNorm, tied head). In place of the MLP, the
+softmax attention, final LayerNorm, tied head), and its LayerNorm, GELU,
+head and roundings are the GPT-2 reference's own functions. In place of the MLP, the
 routing `models/moe.py` documents:
 
 - a token's router scores are the softmax, over ALL experts, of its
@@ -41,30 +42,17 @@ import math
 import jax
 import jax.numpy as jnp
 
+from benchmarks.families.gpt2.reference import (
+    _gelu_new,
+    _head,
+    _layer_norm,
+    _round_to_bits,
+    _through_fp8,
+)
+
 MATRICES = ("attn.c_attn.weight", "attn.c_proj.weight",
             "moe.experts.c_fc.weight", "moe.experts.c_proj.weight")
 CONTROLS = ("int4_weights", "int4_kv", "fp8_activations")
-
-
-def _layer_norm(x, gain, bias, eps):
-    mean = jnp.mean(x, axis=-1, keepdims=True)
-    var = jnp.mean(jnp.square(x - mean), axis=-1, keepdims=True)
-    return (x - mean) / jnp.sqrt(var + eps) * gain + bias
-
-
-def _gelu_new(x):
-    return 0.5 * x * (1.0 + jnp.tanh(
-        jnp.sqrt(2.0 / jnp.pi) * (x + 0.044715 * x ** 3)))
-
-
-def _round_to_bits(x, axis, bits):
-    top = float(2 ** (bits - 1) - 1)
-    s = jnp.maximum(jnp.max(jnp.abs(x), axis=axis, keepdims=True) / top, 1e-8)
-    return jnp.clip(jnp.round(x / s), -top, top) * s
-
-
-def _through_fp8(x):
-    return x.astype(jnp.float8_e4m3fn).astype(jnp.float32)
 
 
 def capacity(tokens: int, k: int, experts: int, factor: float) -> int:
@@ -125,12 +113,6 @@ def _block(x, lw, *, n_head, eps, k, factor, passes, kv_bits=None,
                       lw["moe.experts.c_proj.weight"])
            + lw["moe.experts.c_proj.bias"][:, None, :])
     return x + jnp.einsum("te,etd->td", routing, out), key, v, routing
-
-
-@functools.partial(jax.jit, static_argnames=("eps", "fp8"))
-def _head(x, gain, bias, wte, *, eps, fp8=False):
-    h = _layer_norm(x, gain, bias, eps)
-    return (_through_fp8(h) if fp8 else h) @ wte.T
 
 
 def forward(w: dict, ids, config: dict, control=None):

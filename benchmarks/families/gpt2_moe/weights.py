@@ -1,5 +1,5 @@
 """Seeded weights of the GPT-2 trunk with routed experts, made on the
-device in one jitted call.
+device in one jitted call (the GPT-2 family's, over this file's spec).
 
 The trunk's names and shapes are the published GPT-2 checkpoint's, per-layer
 tensors stacked on a leading layer axis; in place of `mlp.*` each layer has
@@ -15,11 +15,7 @@ them. The quiet embedding rows are the GPT-2 family's (same tokenizers).
 
 from __future__ import annotations
 
-import functools
-
-import jax
 import jax.numpy as jnp
-import numpy as np
 
 from benchmarks.families.gpt2 import weights as trunk
 
@@ -55,33 +51,12 @@ def _spec(sizes: tuple) -> dict:
     }
 
 
-@functools.partial(jax.jit, static_argnames=("sizes", "dtype"))
-def _make(lo, hi, wte_scale, *, sizes, dtype):
-    key = jax.random.fold_in(jax.random.key(lo), hi)
-    spec = _spec(sizes)
-    keys = jax.random.split(key, len(spec))
-    out = {}
-    for k, (name, (shape, std, mean)) in zip(keys, sorted(spec.items())):
-        x = mean + std * jax.random.normal(k, shape, jnp.float32)
-        if name == "wte":
-            x = x * wte_scale[:, None]
-        out[name] = x.astype(dtype)
-    return out
-
-
 def of_config(seed: int, config: dict, dtype=jnp.float32) -> dict:
     """The checkpoint every side of a run starts from: float32 for the
     reference, or cast (after the same float32 draw) to the type the
-    program loads it in."""
-    seed = int(seed)
-    if seed < 0:
-        raise ValueError(f"seed must be >= 0, got {seed}")
-    sizes = sizes_of(config)
-    wte_scale = np.ones((sizes[0],), np.float32)
-    wte_scale[list(trunk.quiet_ids(config))] = trunk.QUIET
-    return _make(jnp.asarray(seed & 0x7FFFFFFF, jnp.int32),
-                 jnp.asarray(seed >> 31, jnp.int32), wte_scale,
-                 sizes=sizes, dtype=jnp.dtype(dtype))
+    program loads it in; the trunk's one jitted draw, over this spec."""
+    return trunk.make(seed, sizes_of(config), dtype, trunk.quiet_ids(config),
+                      spec=_spec)
 
 
 def program_tree(w: dict) -> dict:

@@ -23,26 +23,19 @@ def _value(term: dict, metrics: dict):
     return metrics.get("latency", {}).get(term["histogram"], {}).get("count")
 
 
-def _growth(terms: list, then: dict, now: dict, missing):
-    """The terms' growth from `then` to `now`, summed; `missing` stands for
-    a series /metrics does not have at the collection."""
-    total = 0.0
-    for term in terms:
-        end = _value(term, now)
-        if end is None:
-            if missing is None:
-                return None
-            end = missing
-        total += float(term.get("times", 1)) * (
-            end - (_value(term, then) or 0))
-    return total
+def _sum(terms: list, metrics: dict) -> float:
+    return sum(float(term.get("times", 1)) * (_value(term, metrics) or 0)
+               for term in terms)
 
 
 def read(args: dict, ctx: dict):
     then = ctx["marked"].get("metrics", {})
     now = ctx["collected"]["metrics"]
-    below = _growth(args["denominator"], then, now, None)
-    if not below or below < 0:
+    above, below = args["numerator"], args["denominator"]
+    if any(_value(term, now) is None for term in below):
         return None
-    above = _growth(args["numerator"], then, now, 0)
-    return float(args.get("scale", 1.0)) * above / below
+    grew = _sum(below, now) - _sum(below, then)
+    if grew <= 0:
+        return None
+    return (float(args.get("scale", 1.0))
+            * (_sum(above, now) - _sum(above, then)) / grew)

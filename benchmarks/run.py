@@ -406,24 +406,26 @@ async def run(args) -> int:
     broken = [o.error for o in failed if not o.error.startswith("rpc ")
               and not o.error.startswith("server refused")]
     ref = ready["reference_check"]
+
+    def held(what, value, op, limit, ok):
+        return dict(what=what, value=value, op=op, limit=limit, ok=bool(ok))
+
     # One line for every number the configuration's family is held to, by
     # the name its `check.limits` gives the limit under.
     compared = [
-        (f"reference_{key}", ref["worst"][key], "<=", limit,
-         ref["worst"][key] <= limit)
+        held(f"reference_{key}", ref["worst"][key], "<=", limit,
+             ref["worst"][key] <= limit)
         for key, limit in ref["limits"].items()
     ] + [
-        ("reference_comparison", bool(ref["ok"]), "==", True,
-         bool(ref["ok"])),
-        ("compilations_in_window", compiled, "==", 0, compiled == 0),
-        ("answers_breaking_a_guarantee", len(broken), "==", 0, not broken),
-        ("shed_not_seen_by_a_client", max(0, shed - len(failed)), "==", 0,
-         shed <= len(failed)),
-        ("platform", device["platform"], "==", "tpu",
-         device["platform"] == "tpu"),
+        held("reference_comparison", bool(ref["ok"]), "==", True, ref["ok"]),
+        held("compilations_in_window", compiled, "==", 0, compiled == 0),
+        held("answers_breaking_a_guarantee", len(broken), "==", 0,
+             not broken),
+        held("shed_not_seen_by_a_client", max(0, shed - len(failed)), "==", 0,
+             shed <= len(failed)),
+        held("platform", device["platform"], "==", "tpu",
+             device["platform"] == "tpu"),
     ]
-    compared = [dict(what=name, value=value, op=op, limit=limit, ok=ok)
-                for name, value, op, limit, ok in compared]
     for line in compared:
         say("compared", **line)
     correct = all(line["ok"] for line in compared)

@@ -209,3 +209,26 @@ def test_the_roofline_reader_asks_the_configurations_family():
     # no loop counted: nothing at all
     ctx = dict(ctx, trace=dict(trace, loops=[]), notes={})
     assert readers.read("roofline", args, ctx) is None and not ctx["notes"]
+
+
+@pytest.mark.parametrize("config", ["tiny", "tiny-moe"])
+def test_a_program_broken_underneath_fails_the_comparison(config):
+    """The comparison as a run makes it, on the engine a run builds, with
+    the program's forward altered where it takes its tokens (every id
+    turned by one): not correct, for every family."""
+    from benchmarks import check, serve
+
+    doc = load("configs", config + ".json")
+    engine = serve.build_engine(doc, 9)
+    sound = check.compare(engine.family, engine.cfg, engine.params, doc, 9)
+    assert sound["ok"]
+    forward = engine.family.forward
+
+    def broken(params, cfg, ids, **kw):
+        return forward(params, cfg, (ids + 1) % cfg.vocab_size, **kw)
+
+    got = check.compare(engine.family._replace(forward=broken), engine.cfg,
+                        engine.params, doc, 9)
+    assert not got["ok"]
+    assert got["worst"]["logits_distance"] > 5 * sound["limits"][
+        "logits_distance"]

@@ -112,7 +112,7 @@ def test_the_served_precision_passes_and_every_control_fails(config):
         turned = want[0][:3] + (np.roll(np.asarray(want[0][3]), 1, axis=-1),)
         with pytest.raises(ValueError):   # no position is routed alike
             compare.readings(turned, want[0])
-        assert compare.routing_disagreement(turned[3], want[0][3]) > 0.25
+        assert compare.routing_disagreement(turned[3], want[0][3]) > 0.5
     with pytest.raises(ValueError):
         check.reference_side(config, 1, seqs, "int2_everything")
 
