@@ -114,7 +114,13 @@ from .prefix_cache import (
     plan_partial,
     plan_staged,
 )
-from .program_inventory import effective_megastep_max, megastep_ladder
+from .program_inventory import (
+    STAGE_RUN_BLOCKS,
+    bucket_has_runs,
+    effective_megastep_max,
+    megastep_ladder,
+    stage_runs,
+)
 from .scoring import _score_program, derive_score_shapes, score_texts
 from .spans import PROG, ProgramLog, Span, named_partial
 from .sampling import (
@@ -148,7 +154,9 @@ class SlotState(NamedTuple):
     # prompt is being prefilled inside the megastep scan (one chunk per
     # decode iteration, for the oldest by `stage_seq`), `stage_cursor`
     # the next absolute prefill position (starts at the spliced
-    # shared-prefix length), `stage_len` the true prompt length,
+    # shared-prefix length), `stage_len` the true prompt length (it stays
+    # after the flip, and `_install` sets it too: a family with routed
+    # experts knows a lane's request has ended from it, `_live_lanes`),
     # `stage_seq` the host's staging sequence number (FIFO service order
     # — slot index would starve an early admission whenever churn
     # restages a lower slot), and `stage_rng` the raw key data the flip
@@ -212,8 +220,33 @@ def _plane_spec(name: str) -> jax.sharding.PartitionSpec:
     return partition.PAGED_PLANE_SPECS[name]
 
 
+def _forward(model, params, cfg, ids, live, **kw):
+    """`model.forward` -> (logits, cache, counts): `counts` is empty but
+    for a family with routed experts (`ModelFamily.routed`), which is told
+    which tokens are `live` ([B] or [B, T] bool: an idle lane or a pad
+    position reaches no expert) and hands back its three counts, int32 [3]
+    (picks computed, experts reached, expert seats offered). Every other
+    family is called exactly as before, so its programs do not change."""
+    if not model.routed:
+        return (*model.forward(params, cfg, ids, **kw), ())
+    logits, cache, aux = model.forward(params, cfg, ids, live=live,
+                                       aux=True, **kw)
+    return logits, cache, (aux["counts"],)
+
+
+def _live_lanes(s: "SlotState", sampling: SamplingParams) -> jax.Array:
+    """[S] bool: lanes whose decode token a client will get. A lane is
+    active on the device until the host reaps its request's end, which the
+    host's budget sets (`max_new_tokens`), dispatches later: a request of
+    prompt length p has had its last token once its cache holds
+    p + max_new - 1 positions."""
+    return s.active & (
+        s.cache.length < s.stage_len + (sampling.max_new_tokens - 1))
+
+
 def _prefill_program(params, ids, true_len, rng, *, cfg, sampling, model):
-    """[1, T] right-padded prompt -> (cache, first_tok, seen_row).
+    """[1, T] right-padded prompt -> (cache, first_tok, seen_row), and for
+    a family with routed experts its counts (`_forward`) after them.
 
     The returned cache is PROMPT-sized — [L, 1, H, T, Dh] for a T-token
     prompt bucket (plus scale planes when int8-quantized), the prompt
@@ -228,8 +261,9 @@ def _prefill_program(params, ids, true_len, rng, *, cfg, sampling, model):
     cache = model.init_cache(cfg, 1, t, dtype=cfg.dtype)
     kv_mask = (jnp.arange(t) < true_len)[None, :]
     positions = jnp.minimum(jnp.arange(t, dtype=jnp.int32), true_len - 1)[None, :]
-    logits, cache = model.forward(
-        params, cfg, ids, cache=cache, positions=positions, kv_mask=kv_mask
+    logits, cache, moe = _forward(
+        model, params, cfg, ids, kv_mask, cache=cache, positions=positions,
+        kv_mask=kv_mask,
     )
     last = jax.lax.dynamic_index_in_dim(
         logits[0], true_len - 1, 0, keepdims=False
@@ -237,7 +271,7 @@ def _prefill_program(params, ids, true_len, rng, *, cfg, sampling, model):
     valid = (jnp.arange(t) < true_len)[None, :]
     seen = seen_mask_from_ids(ids, valid, cfg.vocab_size)[0]
     first = sample_step(rng, last[None, :], seen[None, :], sampling)[0]
-    return cache, first, update_seen(seen[None, :], first[None])[0]
+    return (cache, first, update_seen(seen[None, :], first[None])[0], *moe)
 
 
 def _partial_prefill_program(params, cache0: KVCache, ids_full, ids_suf,
@@ -265,12 +299,15 @@ def _partial_prefill_program(params, cache0: KVCache, ids_full, ids_suf,
     tests/test_prefix_cache.py).
 
     Returns (cache [.., t, ..], first, seen_row) — the exact contract
-    `_install_program` consumes from `_prefill_program`.
+    `_install_program` consumes from `_prefill_program` (and, like it, a
+    routed family's counts after them).
     """
     _, t = ids_full.shape
     suf_len = true_len - prefix_len
-    logits, cache = model.forward(
-        params, cfg, ids_suf, cache=cache0._replace(length=prefix_len)
+    logits, cache, moe = _forward(
+        model, params, cfg, ids_suf,
+        (jnp.arange(ids_suf.shape[1]) < suf_len)[None, :],
+        cache=cache0._replace(length=prefix_len),
     )
     last = jax.lax.dynamic_index_in_dim(
         logits[0], suf_len - 1, 0, keepdims=False
@@ -278,7 +315,7 @@ def _partial_prefill_program(params, cache0: KVCache, ids_full, ids_suf,
     valid = (jnp.arange(t) < true_len)[None, :]
     seen = seen_mask_from_ids(ids_full, valid, cfg.vocab_size)[0]
     first = sample_step(rng, last[None, :], seen[None, :], sampling)[0]
-    return cache, first, update_seen(seen[None, :], first[None])[0]
+    return (cache, first, update_seen(seen[None, :], first[None])[0], *moe)
 
 
 def _load_block_program(cache0: KVCache, block: KVBlock, off) -> KVCache:
@@ -372,28 +409,43 @@ def _stage_program(state: SlotState, slot, ids, true_len, cursor0, seq,
     )
 
 
-def _stage_block_program(state: SlotState, block: KVBlock, slot,
-                         off) -> SlotState:
-    """Splice one immutable shared KV block straight into a slot's pages
-    of the LIVE multi-slot cache at token offset `off` (fused admission's
-    counterpart of `_load_block`; one compiled program per cache width).
-    Donates the state — a private accumulator between dispatches — and
-    NEVER the block: tree blocks are shared structure
+def _stage_block_program(state: SlotState, block, slot, off,
+                         tokens=None) -> SlotState:
+    """Splice one immutable shared KV block, or a tuple of
+    STAGE_RUN_BLOCKS consecutive ones as one run of which the first
+    `tokens` tokens count (a short run comes padded with its last block),
+    straight into a slot's pages of the LIVE multi-slot cache at token
+    offset `off` (fused admission's counterpart of `_load_block`; one
+    compiled program per cache width, and one more where a bucket can
+    share a run). Donates the state — a private accumulator between
+    dispatches — and NEVER the block: tree blocks are shared structure
     (engine/prefix_cache.py), and donating one would free KV other
     admissions still splice from."""
+    # The argument's STRUCTURE (one block, or a tuple of them with a
+    # count) is fixed at trace time and keys the compiled program; no
+    # traced value is read.
+    # lint: disable-next=tracer-hygiene
+    if not isinstance(block, KVBlock):
+        block = jax.tree.map(
+            lambda *planes: jnp.concatenate(planes, axis=3), *block)
     zero = jnp.zeros((), jnp.int32)
     slot = jnp.asarray(slot, jnp.int32)
     off = jnp.asarray(off, jnp.int32)
-    k = jax.lax.dynamic_update_slice(state.cache.k, block.k,
-                                     (zero, slot, zero, off, zero))
-    v = jax.lax.dynamic_update_slice(state.cache.v, block.v,
-                                     (zero, slot, zero, off, zero))
+
+    def put(plane, new):
+        at = (zero, slot, zero, off) + (zero,) * (plane.ndim - 4)
+        # lint: disable-next=tracer-hygiene
+        if tokens is not None:
+            keep = jnp.arange(new.shape[3]) < jnp.asarray(tokens, jnp.int32)
+            keep = keep.reshape((1, 1, 1, -1) + (1,) * (plane.ndim - 4))
+            new = jnp.where(
+                keep, new, jax.lax.dynamic_slice(plane, at, new.shape))
+        return jax.lax.dynamic_update_slice(plane, new, at)
+
+    k, v = put(state.cache.k, block.k), put(state.cache.v, block.v)
     ks = vs = None
     if state.cache.quantized:
-        ks = jax.lax.dynamic_update_slice(state.cache.ks, block.ks,
-                                          (zero, slot, zero, off))
-        vs = jax.lax.dynamic_update_slice(state.cache.vs, block.vs,
-                                          (zero, slot, zero, off))
+        ks, vs = put(state.cache.ks, block.ks), put(state.cache.vs, block.vs)
     return state._replace(
         cache=state.cache._replace(k=k, v=v, ks=ks, vs=vs)
     )
@@ -465,6 +517,7 @@ def _install_program(state: SlotState, slot, c1: KVCache, ids, true_len,
         active=state.active.at[slot].set(first != eos_id),
         seen=state.seen.at[slot].set(seen_row),
         transcript=transcript,
+        stage_len=state.stage_len.at[slot].set(true_len),
     )
 
 
@@ -486,6 +539,21 @@ def _grow_state_program(state: SlotState, new_len: int) -> SlotState:
         cache=cache,
         transcript=jnp.pad(state.transcript, [(0, 0), (0, grow)]),
     )
+
+
+def _sum_counts(*counts) -> tuple:
+    """() or (sum,) of a scan iteration's routed-experts counts, each
+    itself () or (int32 [3],): the decode's and the admission chunk's."""
+    found = [c[0] for c in counts if c]
+    return (sum(found[1:], found[0]),) if found else ()
+
+
+def _over_iterations(extra: list, model) -> list:
+    """A scanned body's stacked extras with a routed family's counts (the
+    last one, [iterations, 3]) summed over the iterations."""
+    if model.routed:
+        extra = [*extra[:-1], jnp.sum(extra[-1], axis=0)]
+    return extra
 
 
 def _step_program(params, state: SlotState, rng, *, cfg, sampling,
@@ -518,6 +586,10 @@ def _step_program(params, state: SlotState, rng, *, cfg, sampling,
     decodes its first token in that same iteration, and the two planes
     come back stacked [chunk, S] after the snapshot. None (every engine
     with `prefill_chunk_tokens = 0`) leaves body and outputs as they are.
+
+    A family with routed experts (`ModelFamily.routed`) adds one LAST
+    output, its counts summed over the iterations and `admit`'s forward
+    passes, int32 [3] (`_forward`); only `_live_lanes` route.
     """
     tmax = state.cache.k.shape[3]
 
@@ -525,6 +597,7 @@ def _step_program(params, state: SlotState, rng, *, cfg, sampling,
         extra = ()
         if admit is not None:
             s, *extra = admit(s)
+        moe = extra[2:]  # a routed family's counts follow the two planes
         # Inactive/full slots write into their current position; clamp to
         # stay in bounds — the slot is dead or about to be evicted, the
         # data ignored.
@@ -532,8 +605,9 @@ def _step_program(params, state: SlotState, rng, *, cfg, sampling,
         cache = s.cache._replace(length=offs)
         kv_mask = jnp.arange(tmax)[None, :] <= offs[:, None]
         with jax.named_scope("decode"):
-            logits, cache = model.forward(
-                params, cfg, s.tok[:, None], cache=cache, kv_mask=kv_mask
+            logits, cache, counts = _forward(
+                model, params, cfg, s.tok[:, None],
+                _live_lanes(s, sampling), cache=cache, kv_mask=kv_mask,
             )
         with jax.named_scope("sample"):
             nxt = sample_step(step_rng, logits[:, 0], s.seen, sampling)
@@ -552,13 +626,14 @@ def _step_program(params, state: SlotState, rng, *, cfg, sampling,
                 active=still,
                 seen=seen,
             ),
-            (nxt, *extra),
+            (nxt, *extra[:2], *_sum_counts(counts, moe)),
         )
 
     state, (toks, *extra) = jax.lax.scan(
         one, state, jax.random.split(rng, chunk)
     )
-    return (state, toks, state.active.astype(jnp.int8), *extra)
+    return (state, toks, state.active.astype(jnp.int8),
+            *_over_iterations(extra, model))
 
 
 def _spec_step_program(
@@ -593,7 +668,8 @@ def _spec_step_program(
     means the slot was inactive. Like the plain step's outputs, all three
     are fresh buffers that survive the next dispatch donating the state.
     `admit` is `_step_program`'s: it runs before each window, and its
-    [chunk, S] planes follow the snapshot.
+    [chunk, S] planes follow the snapshot; a routed family's counts come
+    last, as there.
     """
     k = spec_tokens
     width = state.cache.k.shape[3]
@@ -604,6 +680,7 @@ def _spec_step_program(
         extra = ()
         if admit is not None:
             s, *extra = admit(s)
+        moe = extra[2:]
         offs = jnp.minimum(s.cache.length, width - 1 - k)  # [S] window base
         # Drafts: the pending last token sits at transcript slot `offs`;
         # an anchor must be filled AND have k filled continuation slots
@@ -622,8 +699,9 @@ def _spec_step_program(
         # to the slot indices.
         feed = jnp.concatenate([s.tok[:, None], drafts], axis=1)  # [S, k+1]
         with jax.named_scope("decode"):
-            logits, cache = model.forward(
-                params, cfg, feed, cache=s.cache._replace(length=offs)
+            logits, cache, counts = _forward(
+                model, params, cfg, feed, _live_lanes(s, sampling),
+                cache=s.cache._replace(length=offs),
             )
         with jax.named_scope("sample"):
             emitted, valid, seen, hit_eos = verify_window(
@@ -656,13 +734,14 @@ def _spec_step_program(
                 seen=seen,
                 transcript=transcript,
             ),
-            (emitted, m, *extra),
+            (emitted, m, *extra[:2], *_sum_counts(counts, moe)),
         )
 
     state, (emitted, counts, *extra) = jax.lax.scan(
         one, state, jax.random.split(rng, chunk)
     )
-    return (state, emitted, counts, state.active.astype(jnp.int8), *extra)
+    return (state, emitted, counts, state.active.astype(jnp.int8),
+            *_over_iterations(extra, model))
 
 
 def _admission_chunk(params, s: SlotState, *, cfg, sampling, model,
@@ -689,13 +768,15 @@ def _admission_chunk(params, s: SlotState, *, cfg, sampling, model,
     is bit-identical to the sequential path's.
 
     Returns (state, flipped [S] bool, firsts [S] int32) — one-hot at the
-    flipped slot. A `lax.cond` skips all of it when nothing is staged,
-    so the steady-state decode iteration pays nothing for the fused
-    capability.
+    flipped slot — and, for a family with routed experts, the chunk's
+    counts (`_forward`; the chunk's pad tail routes nowhere). A `lax.cond`
+    skips all of it when nothing is staged, so the steady-state decode
+    iteration pays nothing for the fused capability.
     """
     n_slots = s.tok.shape[0]
     no_flip = jnp.zeros((n_slots,), jnp.bool_)
     no_first = jnp.full((n_slots,), pad_id, jnp.int32)
+    no_counts = (jnp.zeros((3,), jnp.int32),) if model.routed else ()
 
     def run(s: SlotState):
         c = prefill_chunk
@@ -732,8 +813,10 @@ def _admission_chunk(params, s: SlotState, *, cfg, sampling, model,
         positions = jnp.minimum(
             cur + jnp.arange(c, dtype=jnp.int32), tl - 1
         )[None, :]
-        logits, c1 = model.forward(
-            params, cfg, ids, cache=c1, positions=positions
+        logits, c1, counts = _forward(
+            model, params, cfg, ids,
+            (cur + jnp.arange(c, dtype=jnp.int32) < tl)[None, :],
+            cache=c1, positions=positions,
         )
         k2 = jax.lax.dynamic_update_slice(
             s.cache.k, c1.k, (zero, slot, zero, zero, zero)
@@ -783,10 +866,12 @@ def _admission_chunk(params, s: SlotState, *, cfg, sampling, model,
             new,
             no_flip.at[slot].set(done),
             no_first.at[slot].set(jnp.where(done, first, pad_id)),
+            *counts,
         )
 
     return jax.lax.cond(
-        jnp.any(s.staged), run, lambda s: (s, no_flip, no_first), s
+        jnp.any(s.staged), run,
+        lambda s: (s, no_flip, no_first, *no_counts), s
     )
 
 
@@ -818,6 +903,8 @@ def _megastep_program(params, state: SlotState, rngs, *, cfg, sampling,
       the batched reap learns admission outcomes without an extra sync
       and starts the slot's decode walk at that row (see
       `_admission_chunk`, bound to the scan body's `admit`).
+    - a family with routed experts: any of the above plus, LAST, its
+      counts summed over the dispatch, int32 [3] (`_forward`).
 
     `active[j]` is the post-chunk-j snapshot — the same fresh non-donated
     plane the single-chunk program returns, K of them — so the host's
@@ -871,6 +958,10 @@ def _megastep_program(params, state: SlotState, rngs, *, cfg, sampling,
         return s, tuple(outs)
 
     state, outs = jax.lax.scan(one_chunk, state, rngs)
+    moe = ()
+    if model.routed:
+        *outs, moe = outs  # [K, 3] routed-experts counts, one per chunk
+        moe = (jnp.sum(moe, axis=0),)
     if prefill_chunk:
         *outs, flipped, firsts = outs  # [K, chunk, S] admission planes
     active = outs[-1]  # [K, S] int8 post-chunk snapshots
@@ -892,7 +983,7 @@ def _megastep_program(params, state: SlotState, rngs, *, cfg, sampling,
     res = (state, *outs, dead)
     if prefill_chunk:
         res = res + (flipped, firsts)
-    return res
+    return res + moe
 
 
 def next_megastep_k(current: int, ladder: Sequence[int], pending: int,
@@ -1245,14 +1336,16 @@ class PagedEngine:
         #  dead-lane scalar device array for a megastep else None,
         #  flipped / firsts [K, chunk, S] bool / int32 fused-admission
         #  planes (None without fused prefill),
-        #  slot->request snapshot at dispatch time).
+        #  slot->request snapshot at dispatch time,
+        #  a routed family's counts int32 [3], else None).
         # Every device entry is a fresh non-donated buffer (see
         # _step_program's snapshot note), so chunk-loop and megastep
         # dispatches pipeline under the same donation invariants.
         self._inflight: List[
             Tuple[jax.Array, Optional[jax.Array], jax.Array,
                   Optional[jax.Array], Optional[jax.Array],
-                  Optional[jax.Array], List[Optional[_Request]]]
+                  Optional[jax.Array], List[Optional[_Request]],
+                  Optional[jax.Array]]
         ] = []
         self._next_rid = 0
         self.last_ttft_s: Optional[float] = None
@@ -1328,6 +1421,7 @@ class PagedEngine:
         # Monotonic staging sequence (FIFO service order for the in-scan
         # prefill phase — see SlotState.stage_seq).
         self._stage_seq = 0
+        self._scalars: Dict[int, jax.Array] = {}
 
     _PROG_TIMES_MAX = 4096
 
@@ -1544,16 +1638,17 @@ class PagedEngine:
                 self.state = self._canon_state(self.state)
                 if self.fused:
                     with self.mesh:
+                        # Operands as `_stage_admissions` hands them
+                        # over (numpy but for the cached slot scalar):
+                        # host and device operands key the program apart.
                         self.state = self._stage(
-                            self.state, jnp.asarray(0, jnp.int32),
-                            jnp.asarray(ids), jnp.asarray(1, jnp.int32),
-                            jnp.asarray(0, jnp.int32),
-                            jnp.asarray(0, jnp.int32),
+                            self.state, self._i32(0), ids, np.int32(1),
+                            np.int32(0), np.int32(0),
                             jax.random.key_data(rng),
                         )
                     continue
                 with self.mesh:
-                    c1, first, seen_row = self._prefill(
+                    c1, first, seen_row, *_ = self._prefill(
                         self.params, jnp.asarray(ids),
                         jnp.asarray(1, jnp.int32), rng,
                     )
@@ -1593,6 +1688,18 @@ class PagedEngine:
                             self.state, blk, jnp.asarray(0, jnp.int32),
                             jnp.asarray(0, jnp.int32),
                         )
+                        if any(
+                            bucket_has_runs(
+                                t, self.prefix_block_tokens, width)
+                            and self._required_width(t) <= width
+                            for t in buckets
+                        ):
+                            self.state = self._stage_block(
+                                self.state, (blk,) * STAGE_RUN_BLOCKS,
+                                jnp.asarray(0, jnp.int32),
+                                jnp.asarray(0, jnp.int32),
+                                jnp.asarray(0, jnp.int32),
+                            )
                 continue
             # Step AFTER an install so the compile covers the live
             # install->step handoff (the state the step really sees);
@@ -1635,7 +1742,7 @@ class PagedEngine:
                 ids = np.full((1, t), self.tokenizer.pad_id, np.int32)
                 self._rng, rng = jax.random.split(self._rng)
                 with self.mesh:
-                    c1, _, _ = self._prefill(
+                    c1, *_ = self._prefill(
                         self.params, jnp.asarray(ids),
                         jnp.asarray(1, jnp.int32), rng,
                     )
@@ -1880,7 +1987,7 @@ class PagedEngine:
             self.state = self._canon_state(self.state)
             with self.mesh:
                 self._grow_if_needed(w_req)
-                c1, first, seen_row = self._run_prefill(
+                c1, first, seen_row, *moe = self._run_prefill(
                     req, bucket, ids, rng
                 )
                 with self._span(PROG + "install"):
@@ -1890,7 +1997,7 @@ class PagedEngine:
                         jnp.asarray(req.prompt_len, jnp.int32),
                         first, seen_row,
                     )
-            admitted.append((slot, req, first))
+            admitted.append((slot, req, (first, *moe)))
         if not admitted:
             return
         with intended_transfer():  # ONE sync for the whole admitted group
@@ -1901,7 +2008,9 @@ class PagedEngine:
             self._decode_stalled_tokens += (
                 live_train * self.chunk * len(admitted)
             )
-        for (slot, req, _), first in zip(admitted, firsts):
+        for (slot, req, _), (first, *moe) in zip(admitted, firsts):
+            for counts in moe:
+                self._count_moe(counts)
             req.tokens = [int(first)]
             self._slot_req[slot] = req
             self._first_token(req, now)
@@ -1944,25 +2053,41 @@ class PagedEngine:
                 self._grow_if_needed(w_req)
                 if cursor0:
                     blocks = match.blocks()[: cursor0 // pc.block_tokens]
-                    for i, blk in enumerate(blocks):
+                    fit = self.state.cache.k.shape[3] // pc.block_tokens
+                    for i, n in stage_runs(len(blocks), fit):
+                        args = (blocks[i],) if n == 1 else (
+                            tuple(blocks[i:i + n])
+                            + (blocks[i + n - 1],) * (STAGE_RUN_BLOCKS - n),
+                            self._i32(n * pc.block_tokens))
                         with self._span(PROG + "stage_block"):
                             self.state = self._stage_block(
-                                self.state, blk,
-                                jnp.asarray(slot, jnp.int32),
-                                jnp.asarray(i * pc.block_tokens, jnp.int32),
+                                self.state, args[0], self._i32(slot),
+                                self._i32(i * pc.block_tokens), *args[1:],
                             )
                 with self._span(PROG + "stage"):
+                    # numpy operands ride the call's own transfer; a
+                    # `jnp.asarray` each would be a program of its own.
                     self.state = self._stage(
-                        self.state, jnp.asarray(slot, jnp.int32),
-                        jnp.asarray(ids),
-                        jnp.asarray(req.prompt_len, jnp.int32),
-                        jnp.asarray(cursor0, jnp.int32),
-                        jnp.asarray(self._stage_seq, jnp.int32),
+                        self.state, self._i32(slot), ids,
+                        np.int32(req.prompt_len), np.int32(cursor0),
+                        np.int32(self._stage_seq),
                         jax.random.key_data(rng),
                     )
             self._stage_seq += 1
             req.live = False
             self._slot_req[slot] = req
+
+    def _i32(self, n: int) -> jax.Array:
+        """The device int32 scalar `n`, made once per value (slot indices
+        and block offsets: a few hundred values). `jnp.asarray(n,
+        jnp.int32)` is a program dispatch of its own; an admission makes
+        two for every `_stage_block` call, and they held the host, and so
+        the device, for as long as the splices themselves (PERF.md section
+        5, PR 30)."""
+        x = self._scalars.get(n)
+        if x is None:
+            x = self._scalars[n] = jnp.asarray(n, jnp.int32)
+        return x
 
     def _required_width(self, prompt_len: int) -> int:
         bucket = min(
@@ -2004,7 +2129,8 @@ class PagedEngine:
         blocks are published back into the tree (a cold miss is what
         seeds the course context the next request hits), the matched
         path stays ref-count-pinned until the request finishes, and the
-        caller receives the `_install` contract (c1, first, seen_row).
+        caller receives the `_install` contract (c1, first, seen_row),
+        followed by a routed family's counts.
         Runs under `self.mesh`; consumes the caller's rng split, so a
         hit samples the bit-identical first token a cold prefill would.
         """
@@ -2035,7 +2161,7 @@ class PagedEngine:
                 req.tokens[prefix_used:]
             )
             with self._span(PROG + "partial_prefill"):
-                c1, first, seen_row = self._partial_prefill(
+                c1, first, seen_row, *moe = self._partial_prefill(
                     self.params, cache0, jnp.asarray(ids),
                     jnp.asarray(ids_suf),
                     jnp.asarray(prefix_used, jnp.int32),
@@ -2043,14 +2169,14 @@ class PagedEngine:
                 )
         else:
             with self._span(PROG + "prefill"):
-                c1, first, seen_row = self._prefill(
+                c1, first, seen_row, *moe = self._prefill(
                     self.params, jnp.asarray(ids),
                     jnp.asarray(req.prompt_len, jnp.int32), rng,
                 )
         if pc is not None:
             self._publish(req, c1)
         self._note_admission(req, prefix_used)
-        return c1, first, seen_row
+        return (c1, first, seen_row, *moe)
 
     def _note_admission(self, req: _Request, hit: int) -> None:
         """Count one admitted prompt and the shared-prefix hit it had."""
@@ -2082,12 +2208,11 @@ class PagedEngine:
         `slot`. Runs under `self.mesh`, entered ONCE, like every other
         dispatch (the jit cache keys on the ambient mesh)."""
         blk_t = self.prefix_cache.block_tokens
-        slot_ix = jnp.asarray(slot, jnp.int32)
 
         def make_block(i: int) -> KVBlock:
             with self._span(PROG + "export_block"):
                 return self._canon_block(self._export_block(
-                    cache, jnp.asarray(i * blk_t, jnp.int32), slot_ix,
+                    cache, self._i32(i * blk_t), self._i32(slot),
                 ))
 
         self.prefix_cache.insert(tokens, make_block)
@@ -2334,13 +2459,15 @@ class PagedEngine:
     def _dispatch(self, k: int, mega: bool) -> None:
         """Send the device the megastep at rung `k`, or one `_step`."""
         self.state = self._canon_state(self.state)
-        counts = dead = flipped = firsts = None
+        counts = dead = flipped = firsts = moe = None
         if mega:
             rngs = self._step_keys(k)
             with self.mesh, self._span(PROG + "megastep"):
                 self.state, *outs = self._megastep(
                     self.params, self.state, rngs
                 )
+            if self.family.routed:
+                *outs, moe = outs
             if self.fused:
                 *outs, flipped, firsts = outs
             if self.spec:
@@ -2350,20 +2477,22 @@ class PagedEngine:
         else:
             self._rng, rng = jax.random.split(self._rng)
             with self.mesh, self._span(PROG + "step"):
-                if self.spec:
-                    self.state, toks, counts, active = self._step(
-                        self.params, self.state, rng
-                    )
-                else:
-                    self.state, toks, active = self._step(
-                        self.params, self.state, rng
-                    )
+                self.state, *outs = self._step(
+                    self.params, self.state, rng
+                )
+            if self.family.routed:
+                *outs, moe = outs
+            if self.spec:
+                toks, counts, active = outs
+            else:
+                toks, active = outs
         self._count(scan_iterations=k * self.chunk,
                     lane_steps=k * self.chunk * self.slots)
-        self._push_inflight(toks, counts, active, dead, flipped, firsts)
+        self._push_inflight(toks, counts, active, dead, flipped, firsts,
+                            moe)
 
     def _push_inflight(self, toks, counts, active, dead, flipped,
-                       firsts) -> None:
+                       firsts, moe=None) -> None:
         """Queue one dispatched program's output buffers for a later reap.
 
         No blocking readback here — but START the device->host copies
@@ -2373,18 +2502,25 @@ class PagedEngine:
         same pipe, so learning a staged slot went live costs no extra
         sync.
         """
-        for arr in (toks, counts, active, dead, flipped, firsts):
+        for arr in (toks, counts, active, dead, flipped, firsts, moe):
             if arr is not None:
                 arr.copy_to_host_async()
         # The slot snapshot records which request each column belonged
         # to at dispatch time (a slot reused later belongs to a later
         # dispatch).
         self._inflight.append((toks, counts, active, dead, flipped,
-                               firsts, list(self._slot_req)))
+                               firsts, list(self._slot_req), moe))
+
+    def _count_moe(self, counts) -> None:
+        """A routed family's three counts of some forward passes, read
+        back from the device (`_forward`)."""
+        picks, reached, seats = (int(c) for c in counts)
+        self._count(moe_picks=picks, moe_experts_reached=reached,
+                    moe_expert_seats=seats)
 
     def _reap(self, toks_dev, counts_dev, active_dev, dead_dev,
-              flipped_dev, firsts_dev,
-              slot_snapshot) -> List[Tuple[int, str]]:
+              flipped_dev, firsts_dev, slot_snapshot,
+              moe_dev=None) -> List[Tuple[int, str]]:
         """Read one dispatch's results — a single chunk, or a megastep's
         whole [K, chunk, S] plane in one batched pass — and finish the
         requests it completed. Under fused admission the same pass also
@@ -2406,6 +2542,8 @@ class PagedEngine:
                        else np.asarray(flipped_dev))  # [K, chunk, S]
             firsts = (None if firsts_dev is None
                       else np.asarray(firsts_dev))    # [K, chunk, S]
+            if moe_dev is not None:
+                self._count_moe(np.asarray(moe_dev))
         self._observe("reap_wait", wait.wall_s)
         with self._span("engine.reap.host"):
             return self._walk(toks, counts, active, flipped, firsts,
@@ -2517,6 +2655,7 @@ class PagedEngine:
                     break
             self._emitted_tokens += len(req.tokens) - n_before
             decoded += len(req.tokens) - n_before
+            self._count_past_window(req, n_before)
             if finished and not dead and counts is None:
                 # The host's budget cap, which the device does not know.
                 overrun += rows - start_row - (len(req.tokens) - n_before)
@@ -2558,9 +2697,20 @@ class PagedEngine:
         self._observe("decode_lanes", decoded / rows)
         return done
 
+    def _count_past_window(self, req: _Request, n_before: int) -> None:
+        """Of the tokens `req` gained since it had `n_before`, count those
+        at a position at or past the model's `sliding_window` (token i of
+        an answer sits at position prompt_len + i): there every window
+        layer has dropped keys. Nothing for a model without a window."""
+        window = getattr(self.cfg, "sliding_window", None)
+        if window:
+            first = max(n_before, window - req.prompt_len)
+            self._count(tokens_past_window=max(0, len(req.tokens) - first))
+
     def _first_token(self, req: _Request, now: float) -> None:
         """A request's first token reached the host."""
         self._emitted_tokens += 1
+        self._count_past_window(req, 0)
         self.ttfts[req.rid] = self.last_ttft_s = now - req.submit_time
         self._observe("prefill_wait", now - req.popped_time)
 

@@ -181,6 +181,47 @@ def megastep_ladder(megastep_max: int) -> List[int]:
     return out
 
 
+# Fused admission splices the shared prefix of a prompt in runs of up to this
+# many blocks, one `_stage_block` dispatch a run. The runtime holds few
+# programs ahead of the device, so every small program of an admission is
+# launched while the device waits for it: at a dispatch a block a context
+# of 2,304 tokens (144 blocks) held the device for as long as its splices
+# took, and the 6 to 10 blocks of a short prompt cost as many launches
+# (PERF.md sections 5 and 6, PR 30). A run is always STAGE_RUN_BLOCKS blocks
+# wide (a short one is padded with its last block and told how many of its
+# tokens count), so a width compiles one program for all of them.
+STAGE_RUN_BLOCKS = 16
+
+
+def stage_runs(n_blocks: int, fit_blocks: int) -> list:
+    """How fused admission splices `n_blocks` consecutive blocks into a
+    cache `fit_blocks` blocks wide: (first block, blocks in the call) per
+    `_stage_block` dispatch, runs of up to STAGE_RUN_BLOCKS. A run's
+    program writes a whole run's width, so a last run too near the cache's
+    end starts earlier and splices again some blocks the run before it
+    brought; a lone block goes alone, and so does every block of a cache
+    narrower than a run."""
+    out, i = [], 0
+    while i < n_blocks:
+        n = min(STAGE_RUN_BLOCKS, n_blocks - i)
+        if n == 1 or fit_blocks < STAGE_RUN_BLOCKS:
+            out.append((i, 1))
+            i += 1
+            continue
+        first = min(i, fit_blocks - STAGE_RUN_BLOCKS)
+        out.append((first, i + n - first))
+        i += n
+    return out
+
+
+def bucket_has_runs(bucket: int, block_tokens: int, width: int) -> bool:
+    """Whether a prompt of this bucket can share two blocks or more
+    (`plan_staged` leaves at least one token to recompute) in a cache wide
+    enough for a run's program."""
+    blk = max(1, block_tokens)
+    return (bucket - 1) // blk >= 2 and STAGE_RUN_BLOCKS * blk <= width
+
+
 def static_paged_domain(
     max_position_embeddings: int,
     max_new_tokens: int,
@@ -266,8 +307,15 @@ def static_paged_domain(
         "step_widths": 0 if fused_prefill else len(widths),
         "install_pairs": 0 if fused_prefill else pairs,
         "stage_pairs": pairs if fused_prefill else 0,
+        # One splice program per width, and one more (a run of blocks in
+        # one call) per width that serves a bucket whose prompts can share
+        # two blocks or more and is wide enough for a run.
         "stage_block_widths": (
-            len(widths)
+            len(widths) + sum(
+                1 for w in widths
+                if any(bucket_has_runs(t, blk, w) and nat[t] <= w
+                       for t in buckets)
+            )
             if fused_prefill and prefix_cache and any_block_bucket else 0
         ),
         "grow_pairs": len(widths) * (len(widths) - 1) // 2,

@@ -194,15 +194,20 @@ def merge_heads(x: jax.Array) -> jax.Array:
     return x.transpose(0, 2, 1, 3).reshape(b, t, h * d)
 
 
-def causal_window_mask(q_positions: jax.Array, num_keys: int) -> jax.Array:
+def causal_window_mask(q_positions: jax.Array, num_keys: int,
+                       window: Optional[int] = None) -> jax.Array:
     """Mask for attention against a fixed-size cache window.
 
     q_positions: [B, Tq] absolute positions of the queries.
-    Key slot j holds absolute position j; it is visible iff j <= q_position.
+    Key slot j holds absolute position j; it is visible iff j <= q_position
+    and, with a sliding `window`, j > q_position - window (a query sees
+    itself and the window - 1 keys before it).
     Returns [B, 1, Tq, num_keys] boolean.
     """
     key_pos = jnp.arange(num_keys, dtype=q_positions.dtype)
     mask = key_pos[None, None, :] <= q_positions[:, :, None]
+    if window is not None:
+        mask = mask & (key_pos[None, None, :] > q_positions[:, :, None] - window)
     return mask[:, None, :, :]
 
 

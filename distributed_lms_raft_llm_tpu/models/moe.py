@@ -185,6 +185,68 @@ def moe_mlp(h: jax.Array, mp: Dict[str, jax.Array], cfg,
     return y, aux
 
 
+def route_sigmoid(x: jax.Array, wr: jax.Array, bias: jax.Array, k: int,
+                  norm: bool, scale: float):
+    """Sigmoid routing (the DeepSeek-V3 line, afmoe): x [S, D] ->
+    (experts [S, k] int32, weights [S, k] float32).
+
+    Scores are `sigmoid(x wr)` over ALL experts, in float32 at full
+    precision (a TPU's default float32 product is one bfloat16 pass, and
+    two experts' scores can differ by less than that rounds). The k experts
+    are the top-k of `score + bias`: the per-expert balancing bias takes
+    part in the CHOICE only, the weights are the chosen experts' own
+    scores, over their sum when `norm`, times `scale`.
+    """
+    logits = jnp.einsum("sd,de->se", x.astype(jnp.float32),
+                        wr.astype(jnp.float32),
+                        precision=jax.lax.Precision.HIGHEST)
+    scores = jax.nn.sigmoid(logits)
+    _, top_i = jax.lax.top_k(scores + bias.astype(jnp.float32), k)
+    top_w = jnp.take_along_axis(scores, top_i, axis=-1)
+    if norm:
+        top_w = top_w / (jnp.sum(top_w, axis=-1, keepdims=True) + 1e-20)
+    return top_i.astype(jnp.int32), top_w * scale
+
+
+def grouped_swiglu(x: jax.Array, top_i: jax.Array, top_w: jax.Array,
+                   live: jax.Array, wg: jax.Array, wu: jax.Array,
+                   wd: jax.Array):
+    """Routed SwiGLU experts without capacity and without drops:
+    x [S, D], top_i / top_w [S, k], live [S] bool -> (y [S, D],
+    group sizes [E] int32).
+
+    The S*k picks are sorted by expert and every projection is ONE grouped
+    product over the expert stacks (`jax.lax.ragged_dot`: wg, wu [E, D, M],
+    wd [E, M, D]; on TPU a grouped-matmul kernel that visits only the
+    (row tile, expert) pairs that exist), then weighted and summed back
+    per token. Shapes are static, and each row's product stands alone, so
+    a token's output does not depend on what shares its forward pass. A
+    token that is not `live` (an idle or parked lane, a pad position)
+    contributes no pick: its picks sort behind every group and belong to
+    none, so it reaches no expert, and an expert nobody picked has group
+    size 0 and is not read. The stacks must be whole buffers: a slice of
+    a layer-stacked [L, E, D, M] array into the kernel is a copy of a
+    layer's experts per call (models/afmoe.py keeps one leaf a layer).
+    """
+    s, k = top_i.shape
+    e = wg.shape[0]
+    expert = jnp.where(live[:, None], top_i, e).reshape(s * k)
+    order = jnp.argsort(expert, stable=True)
+    sizes = jnp.zeros((e + 1,), jnp.int32).at[expert].add(1)[:e]
+    xs = x[order // k]                                       # [S*k, D]
+    gate = jax.lax.ragged_dot(xs, wg.astype(x.dtype), sizes)
+    up = jax.lax.ragged_dot(xs, wu.astype(x.dtype), sizes)
+    out = jax.lax.ragged_dot(jax.nn.silu(gate) * up, wd.astype(x.dtype),
+                             sizes)
+    # Rows past the last group belong to no expert; whatever the kernel
+    # left there is dropped, not scaled by a zero weight.
+    w = top_w.reshape(s * k)[order]
+    out = jnp.where((expert[order] < e)[:, None],
+                    out.astype(jnp.float32) * w[:, None], 0.0)
+    y = out[jnp.argsort(order)].reshape(s, k, -1).sum(axis=1)
+    return y.astype(x.dtype), sizes
+
+
 def load_balance_loss(params: Params, cfg: GPT2MoEConfig,
                       hidden: jax.Array, layer: int) -> jax.Array:
     """Switch aux loss for one layer: E * sum_e(frac_tokens_e * mean_prob_e).

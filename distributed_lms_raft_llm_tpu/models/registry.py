@@ -10,22 +10,27 @@ drives any family exposing the same functional surface:
 
 The reference hardcodes one architecture behind `from_pretrained("gpt2")`
 (reference: GUI_RAFT_LLM_SourceCode/tutoring_server.py:10); here presets
-cover the GPT-2 family (BASELINE configs 1-4) and Llama (config 5).
+cover the GPT-2 family (BASELINE configs 1-4), Llama (config 5), the
+home-made GPT-2 with routed experts, and Arcee's afmoe (Trinity-Mini).
 """
 
 from __future__ import annotations
 
 from typing import Any, Callable, NamedTuple, Tuple
 
-from . import convert, gpt2, llama, moe
+from . import afmoe, convert, gpt2, llama, moe
 
 
 class ModelFamily(NamedTuple):
-    name: str  # partition-rule key ("gpt2" | "llama")
+    name: str  # partition-rule key ("gpt2" | "llama" | ...)
     init_params: Callable
     forward: Callable
     init_cache: Callable
     params_from_hf: Callable
+    # The forward also takes `live` (which tokens are real) and `aux`, and
+    # then hands out its routing's three counts (models/afmoe.py): the
+    # paged engine tells such a family its idle lanes and reads the counts.
+    routed: bool = False
 
 
 GPT2_FAMILY = ModelFamily(
@@ -41,6 +46,11 @@ MOE_FAMILY = ModelFamily(
     moe.params_from_hf,
 )
 
+AFMOE_FAMILY = ModelFamily(
+    "afmoe", afmoe.init_params, afmoe.forward, afmoe.init_cache,
+    afmoe.params_from_hf, routed=True,
+)
+
 # preset -> (family, config factory)
 PRESETS = {
     "gpt2": (GPT2_FAMILY, gpt2.GPT2Config.small),
@@ -52,6 +62,9 @@ PRESETS = {
     "llama-tiny": (LLAMA_FAMILY, llama.LlamaConfig.tiny),
     "gpt2-moe": (MOE_FAMILY, moe.GPT2MoEConfig.moe_small),
     "moe-tiny": (MOE_FAMILY, moe.GPT2MoEConfig.tiny),
+    "trinity-mini": (AFMOE_FAMILY, afmoe.AfmoeConfig.trinity_mini),
+    "trinity-mini-1d4e": (AFMOE_FAMILY, afmoe.AfmoeConfig.trinity_mini_1d4e),
+    "afmoe-tiny": (AFMOE_FAMILY, afmoe.AfmoeConfig.tiny),
 }
 
 

@@ -109,6 +109,24 @@ MOE_RULES: List[Tuple[str, PartitionSpec]] = [
     (r"blocks/moe/b[io]$", P(None, "ep")),
 ] + GPT2_RULES
 
+# afmoe (models/afmoe.py): a list of per-layer trees, no leading layer
+# axis. Attention and the dense and shared SwiGLUs shard like Llama's
+# (q/k/v/gate/up column-parallel, o/down row-parallel). The routed
+# experts [E, D, M], the router, its bias and the norms are replicated:
+# the grouped product takes whole stacks, and a chip's share of a layer's
+# experts needs a routed layer that is told which experts it holds
+# (ROADMAP S4; the engines refuse ep > 1 for this family). At tp = 1
+# every spec degrades to replication.
+AFMOE_RULES: List[Tuple[str, PartitionSpec]] = [
+    (r"embed$", P("tp")),
+    (r"lm_head$", P("tp")),
+    (r"layers/\d+/attn/w[qkvg]$", P(None, "tp")),
+    (r"layers/\d+/attn/wo$", P("tp")),
+    (r"layers/\d+/(mlp|moe/shared)/w[gu]$", P(None, "tp")),
+    (r"layers/\d+/(mlp|moe/shared)/wd$", P("tp")),
+    (r".*", P()),
+]
+
 # Rule set per model-family name (models/registry.py ModelFamily.name).
 # (The bucketed engine's KV-cache sharding — [L, B, Hkv, T, Dh]: batch
 # over dp, heads over tp — is derived by jit's sharding propagation from
@@ -121,6 +139,7 @@ RULES_FOR = {
     "llama": LLAMA_RULES,
     "bert": BERT_RULES,
     "gpt2_moe": MOE_RULES,
+    "afmoe": AFMOE_RULES,
 }
 
 # ---------------------------------------------- paged state plane table

@@ -517,8 +517,9 @@ ENGINE_PROG_STAGE = histogram(
 ENGINE_PROG_STAGE_BLOCK = histogram(
     "engine_prog_stage_block",
     "paged-engine _stage_block program dispatch wall time (fused "
-    "admission: one cached shared-prefix block spliced into a slot's "
-    "pages; one observation per block)",
+    "admission: cached shared-prefix blocks spliced into a slot's "
+    "pages, a run of up to 16 or a single block a call; one observation "
+    "per call)",
 )
 ENGINE_PROG_LOAD_BLOCK = histogram(
     "engine_prog_load_block",
@@ -611,6 +612,30 @@ ENGINE_OVERRUN_LANE_STEPS = counter(
     "not know the cap), and every dispatch already in flight when the "
     "host reaped the finish",
 )
+MOE_PICKS = counter(
+    "moe_picks",
+    "expert picks computed by the routed layers (live tokens x experts "
+    "per token x expert layers), summed on the device over a dispatch's "
+    "forward passes and read back at its reap; only a family with routed "
+    "experts counts (models/afmoe.py)",
+)
+MOE_EXPERTS_REACHED = counter(
+    "moe_experts_reached",
+    "experts that got at least one pick, summed over expert layers and "
+    "forward passes: the experts whose weights a pass reads",
+)
+MOE_EXPERT_SEATS = counter(
+    "moe_expert_seats",
+    "experts there were to reach: experts x expert layers, once per "
+    "forward pass (reached / seats is the share of the expert weights a "
+    "pass reads; an idle lane that routed would raise it)",
+)
+ENGINE_TOKENS_PAST_WINDOW = counter(
+    "engine_tokens_past_window",
+    "tokens emitted at a position at or past the model's sliding_window, "
+    "where every window layer drops keys; counted on the host at the "
+    "reap, of the same tokens as engine_tokens_emitted",
+)
 QUEUE_WAIT = histogram(
     "queue_wait",
     "engine submit -> popped from the pending queue for admission, per "
@@ -660,6 +685,10 @@ ENGINE_LOOP_COUNTERS: Dict[str, str] = {
     "lane_steps": ENGINE_LANE_STEPS,
     "staged_lane_steps": ENGINE_STAGED_LANE_STEPS,
     "overrun_lane_steps": ENGINE_OVERRUN_LANE_STEPS,
+    "moe_picks": MOE_PICKS,
+    "moe_experts_reached": MOE_EXPERTS_REACHED,
+    "moe_expert_seats": MOE_EXPERT_SEATS,
+    "tokens_past_window": ENGINE_TOKENS_PAST_WINDOW,
 }
 ENGINE_LOOP_HISTOGRAMS: Dict[str, str] = {
     "queue_wait": QUEUE_WAIT,

@@ -25,6 +25,7 @@ from jax.sharding import NamedSharding, SingleDeviceSharding
 from distributed_lms_raft_llm_tpu import config as config_lib
 from distributed_lms_raft_llm_tpu.engine import paged
 from distributed_lms_raft_llm_tpu.engine.draft import build_drafts
+from distributed_lms_raft_llm_tpu.engine.sampling import SamplingParams
 from distributed_lms_raft_llm_tpu.models import quant, registry
 from distributed_lms_raft_llm_tpu.ops.attention import decode_attention
 from distributed_lms_raft_llm_tpu.parallel import mesh as mesh_lib
@@ -249,3 +250,40 @@ def test_paged_megastep_tp4_shards_planes_over_four_chips(topo, one_chip,
     assert abs(got - per_device) < 0.05 * per_device, (got, per_device)
     assert got < 0.4 * one, (got, one)
     assert "all-reduce" in sharded.as_text()
+
+
+# ------------------------------------- Trinity-Mini's cut (models/afmoe.py)
+
+def test_trinity_mini_cut_step_and_megastep_compile_and_fit_one_v5e(one_chip):
+    """`trinity-mini-1d4e` at the published widths, from shapes alone, in
+    the serving settings of benchmarks/configs/trinity-mini.json (16 slots,
+    width 2,688, chunk 16, fused admission at 32 tokens): bfloat16 weights
+    of 8.5 GB beside the cache, the grouped expert products as the TPU's
+    own kernel, and no copy of a layer's experts into it."""
+    family, cfg = registry.resolve("trinity-mini-1d4e", jnp.bfloat16,
+                                   jnp.bfloat16)
+    sampling = SamplingParams.reference_defaults()
+    params = _with(jax.eval_shape(
+        lambda: family.init_params(jax.random.key(0), cfg)), one_chip)
+    assert sum(x.size for x in jax.tree.leaves(params)) == 4_241_534_720
+    state = _with(jax.eval_shape(
+        partial(paged._fresh_state, family, cfg, 16, 2688)), one_chip)
+    common = dict(chunk=16, eos_id=50256, pad_id=50256, cfg=cfg,
+                  sampling=sampling, model=family)
+    mega = jax.jit(
+        partial(paged._megastep_program, spec_tokens=0, prefill_chunk=32,
+                draft_fn=build_drafts, **common), donate_argnums=(1,),
+    ).lower(params, state, _with(jax.eval_shape(
+        lambda: jax.random.split(jax.random.key(0), 1)), one_chip)).compile()
+    step = jax.jit(
+        partial(paged._step_program, **common), donate_argnums=(1,),
+    ).lower(params, state, _with(jax.eval_shape(
+        lambda: jax.random.key(0)), one_chip)).compile()
+    expert_stack = 128 * 2048 * 1024 * 2
+    for compiled in (mega, step):
+        ma = compiled.memory_analysis()
+        assert _device_bytes(ma) < 0.75 * HBM_BYTES
+        # A sliced or copied stack of a layer's experts would be a
+        # temporary of 537 MB a projection.
+        assert ma.temp_size_in_bytes < 2 * expert_stack
+        assert "ragged-dot" in compiled.as_text()

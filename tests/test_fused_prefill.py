@@ -170,6 +170,36 @@ class TestGreedyBitEquality:
         # fitting to give blocks back).
         assert hit % 4 == 0
 
+    @pytest.mark.parametrize("kv_quant", [False, True],
+                             ids=["plain", "kv_quant"])
+    def test_prefix_cache_hit_spliced_as_a_run(self, kv_quant):
+        """A hit goes into the slot's pages a run of blocks a
+        `_stage_block` call: a short run is padded to STAGE_RUN_BLOCKS
+        with its last block, and only the hit's tokens are written (every
+        plane, the int8 cache's scale planes too). Warm output equals
+        cold and the bucketed engine's."""
+        from distributed_lms_raft_llm_tpu.engine.program_inventory import (
+            STAGE_RUN_BLOCKS)
+        cfg = make_config(length_buckets=(8, 16, 32), kv_quant=kv_quant)
+        q1, q2 = SHARED + " why?", SHARED + " how?"
+        expected = TutoringEngine(cfg).answer_batch([q1, q2])
+        eng = PagedEngine(cfg, slots=2, chunk=2, megastep=2,
+                          megastep_max=4, prefill_chunk_tokens=4,
+                          prefix_cache=True, prefix_cache_blocks=64,
+                          prefix_block_tokens=1)
+        assert STAGE_RUN_BLOCKS <= eng.widths[-1]
+        r1 = eng.submit(q1)
+        o1 = eng.drain()
+        eng._progs.pop()
+        r2 = eng.submit(q2)
+        o2 = eng.drain()
+        assert [o1[r1], o2[r2]] == expected
+        hit = eng.pop_prefix_stats()[0]
+        splices = sum(name == "stage_block"
+                      for name, _, _ in eng._progs.pop())
+        assert hit % STAGE_RUN_BLOCKS >= 2     # the last run is a short one
+        assert splices == -(-hit // STAGE_RUN_BLOCKS)
+
     def test_pipelined_matches_serialized(self):
         """inflight=2 with staged admission: flips are learned one reap
         late, snapshots carry staged requests across dispatches, and the
@@ -208,7 +238,7 @@ def test_staged_slots_are_served_every_iteration_in_stage_order(spec_tokens):
     )
     rs = [eng.submit(p) for p in STAGED_TOGETHER]
     eng.step()  # stage all four, dispatch one K=8 megastep, reap nothing
-    (_, _, _, _, flipped, firsts, snapshot), = eng._inflight
+    (_, _, _, _, flipped, firsts, snapshot, _), = eng._inflight
     assert [r.rid for r in snapshot] == rs, "staged in slot = submit order"
     chunks = [-(-r.prompt_len // budget) for r in snapshot]
     assert min(chunks) > 1 and sum(chunks) <= 8 * 2

@@ -382,12 +382,12 @@ def test_unrebound_donated_state_fails_lint():
     )
 
     project = _project_with_patch(PAGED, (
-        "self.state, toks, active = self._step(\n"
-        "                        self.params, self.state, rng\n"
-        "                    )",
-        "toks, active = self._step(\n"
-        "                        self.params, self.state, rng\n"
-        "                    )[1:]",
+        "self.state, *outs = self._step(\n"
+        "                    self.params, self.state, rng\n"
+        "                )",
+        "outs = self._step(\n"
+        "                    self.params, self.state, rng\n"
+        "                )[1:]",
     ))
     findings = [
         f for f in DonationSafetyRule().check_project(project)
