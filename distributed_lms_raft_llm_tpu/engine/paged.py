@@ -179,7 +179,11 @@ class _Request:
     popped_time: float = 0.0  # left the pending queue for admission
     # Set at reap time; later in-flight chunks dispatched before the finish
     # was known still carry this request in their slot snapshot and must
-    # skip it (see PagedEngine.step pipelining).
+    # skip it (see PagedEngine.step pipelining). Until then the request
+    # may already have left `_slot_req`: a slot is handed to its successor
+    # as soon as this request's end is certain to lie in the dispatches in
+    # flight (`_stage_admissions`), and the request lives on in their
+    # snapshots, which is where `_walk` finishes it.
     finished: bool = False
     # False while the request is STAGED (fused admission: prompt handed to
     # the device, prefill advancing inside the megastep scan, first token
@@ -984,6 +988,28 @@ def _megastep_program(params, state: SlotState, rngs, *, cfg, sampling,
     if prefill_chunk:
         res = res + (flipped, firsts)
     return res + moe
+
+
+def rows_to_certain_end(req: Optional[_Request], tmax: int,
+                        rows_in_flight: int) -> Optional[int]:
+    """Scan iterations (rows) still to DISPATCH before `req`'s end is
+    certain to lie inside dispatched work: its token budget left (the
+    `max_new` cap, or the `tmax` clause `_walk` finishes it by) less
+    `rows_in_flight`, the rows dispatched with it in its lane and not yet
+    reaped. A live lane gains exactly one token a row in the plain step
+    and at least one a verify window in the speculative one, so the bound
+    is an upper limit, never an overshoot: eos or over-acceptance can only
+    end the request sooner. 0 means the end is already in flight. None for
+    no request, a finished one, or a STAGED one: its `tokens` still hold
+    the prompt, and it bounds nothing until its flip is reaped.
+
+    The one horizon of the host's scheduling: `_slack_chunks` sizes K by
+    the least of it over the slots, and `_stage_admissions` hands a slot
+    on where it is 0."""
+    if req is None or req.finished or not req.live:
+        return None
+    have = len(req.tokens) + rows_in_flight
+    return max(0, min(req.max_new - have, tmax - req.prompt_len - have))
 
 
 def next_megastep_k(current: int, ladder: Sequence[int], pending: int,
@@ -1816,6 +1842,8 @@ class PagedEngine:
 
     @property
     def has_work(self) -> bool:
+        # A departing request (its slot handed on, its end still to be
+        # reaped) is in no slot; the dispatches it ends in are in flight.
         return (
             bool(self._pending)
             or bool(self._inflight)
@@ -1838,17 +1866,20 @@ class PagedEngine:
 
     def stream_snapshot(self, rids) -> Dict[int, List[int]]:
         """Incremental token-yield channel: for each requested rid that is
-        live in a slot post-flip, a COPY of its generated-so-far token
-        list with eos filtered — the same token view decode() renders at
-        finish, so a streamed prefix is always a prefix of the final
-        transcript. Called by the serving queue between steps (never
+        live post-flip and not finished, a COPY of its generated-so-far
+        token list with eos filtered — the same token view decode()
+        renders at finish, so a streamed prefix is always a prefix of the
+        final transcript. A request is looked for in its slot and, once
+        its slot has been handed on (`_stage_admissions`), in the
+        snapshots of the dispatches in flight, where it stays until its
+        end is reaped. Called by the serving queue between steps (never
         concurrent with step())."""
         want = set(rids)
         out: Dict[int, List[int]] = {}
         if not want:
             return out
         eos = self.tokenizer.eos_id
-        for req in self._slot_req:
+        for req in (*self._slot_req, *self._departing()):
             if req is None or req.finished or not req.live:
                 continue
             if req.rid in want:
@@ -2026,11 +2057,26 @@ class PagedEngine:
         through the megastep's flipped/firsts planes at the next batched
         reap: the decode train never pauses for admission. This is the
         only place a slot becomes staged, so a megastep sees all of them
-        at its entry and none appears inside it."""
+        at its entry and none appears inside it.
+
+        A slot is admissible when it is empty, and also when its
+        request's END is certain to lie inside the dispatches already in
+        flight (`_end_in_flight`): the slot is HANDED ON without waiting
+        for the reap of that end, two dispatches and more of a lane later.
+        Everything staging does to a slot consumes `self.state`, the last
+        dispatch's output, so on the device it comes after the departing
+        request's last token; `_stage_program` resets the lane whole. The
+        departing request lives on in the snapshots of the dispatches in
+        flight and `_walk` finishes it there with all its tokens, while
+        `_slot_req[slot]`, and so every later snapshot, holds the
+        successor."""
         self._maybe_rebuild_idle()
         pc = self.prefix_cache
         for slot in range(self.slots):
-            if self._slot_req[slot] is not None or not self._pending:
+            if not self._pending:
+                break
+            handed_on = self._slot_req[slot] is not None
+            if handed_on and not self._end_in_flight(slot):
                 continue
             req, bucket, w_req, ids = self._pop_next()
             self._rng, rng = jax.random.split(self._rng)
@@ -2076,6 +2122,8 @@ class PagedEngine:
             self._stage_seq += 1
             req.live = False
             self._slot_req[slot] = req
+            if handed_on:
+                self._count(slots_handed_on=1)
 
     def _i32(self, n: int) -> jax.Array:
         """The device int32 scalar `n`, made once per value (slot indices
@@ -2297,6 +2345,18 @@ class PagedEngine:
             for r in self._slot_req
         )
 
+    def _departing(self) -> List[_Request]:
+        """Requests whose slot has been handed on (`_stage_admissions`)
+        and whose end is not reaped yet: in the snapshot of a dispatch in
+        flight, no longer in `_slot_req`."""
+        out: Dict[int, _Request] = {}
+        for entry in self._inflight:
+            for slot, req in enumerate(entry[6]):
+                if (req is not None and not req.finished
+                        and self._slot_req[slot] is not req):
+                    out[req.rid] = req
+        return list(out.values())
+
     def _any_staged(self) -> bool:
         """Any slot whose staged prefill is still advancing inside the
         scan (fused admission) — device work that must keep dispatching
@@ -2319,37 +2379,42 @@ class PagedEngine:
             keys.append(r)
         return jnp.stack(keys)
 
+    def _rows_to_end(self, slot: int) -> Optional[int]:
+        """`rows_to_certain_end` of the request in `slot`: its budget
+        left, net of the rows of the in-flight dispatches whose snapshot
+        holds it in this lane (host-known lengths lag the device by the
+        pipeline depth; the dispatched debt is what closes the gap)."""
+        req = self._slot_req[slot]
+        debt = sum(
+            (entry[2].shape[0] if entry[2].ndim == 2 else 1) * self.chunk
+            for entry in self._inflight if entry[6][slot] is req
+        )
+        return rows_to_certain_end(req, self.tmax, debt)
+
+    def _end_in_flight(self, slot: int) -> bool:
+        """The request in `slot` is certain to have had its last token
+        inside the dispatches already in flight, so the slot can be
+        staged for a successor now. Never a session turn: its transcript
+        is published from the slot's pages when its end is reaped
+        (`_publish_session`), so its slot waits for that reap."""
+        return (self._rows_to_end(slot) == 0
+                and self._slot_req[slot].rid not in self._session_reqs)
+
     def _slack_chunks(self) -> Optional[int]:
         """Device chunks until some live slot is GUARANTEED to free — the
         K controller's admission-opportunity horizon (see
-        next_megastep_k). A slot with `rem` budget tokens left must
-        finish within ceil(rem/chunk) chunk iterations (each chunk
-        advances every live slot by at least `chunk` tokens — exactly
-        chunk in plain mode, >= chunk in spec mode at one guaranteed
-        token per verify window), minus one chunk of already-dispatched
-        work per in-flight unreaped chunk (host-known lengths lag the
-        device by the pipeline depth; subtracting the dispatched debt
-        keeps the bound an upper limit, never an overshoot). None when
-        no live slot bounds the horizon. Early eos/over-acceptance can
-        beat the bound — that exposure is the dead-lane account, capped
-        by the in-progress K*chunk."""
-        rem = None
-        for req in self._slot_req:
-            if req is None or req.finished or not req.live:
-                # Staged requests (fused admission) hold no token budget
-                # yet — their tokens list is still the prompt; they bound
-                # nothing until the flip.
-                continue
-            r = req.max_new - len(req.tokens)
-            rem = r if rem is None else min(rem, r)
-        if rem is None:
-            return None
-        chunks = -(-max(0, rem) // self.chunk)  # ceil
-        debt = sum(
-            (entry[2].shape[0] if entry[2].ndim == 2 else 1)
-            for entry in self._inflight
-        )
-        return max(0, chunks - debt)
+        next_megastep_k): the least `_rows_to_end` over the slots, in
+        chunks of `chunk` rows, rounded up. None when no live slot bounds
+        the horizon (staged requests bound nothing until their flip).
+        Called after `_stage_admissions`, so a slot whose end was in
+        flight while a request waited has been handed on and no longer
+        counts: 0 is read only where nobody is pending, or for a session
+        turn. Early eos/over-acceptance can beat the bound — that
+        exposure is the dead-lane account, capped by the in-progress
+        K*chunk."""
+        rows = [r for r in map(self._rows_to_end, range(self.slots))
+                if r is not None]
+        return -(-min(rows) // self.chunk) if rows else None
 
     def _canon_state(self, state: SlotState) -> SlotState:
         """Respell every plane's sharding to its plane-table spec before
@@ -2413,6 +2478,13 @@ class PagedEngine:
         dispatch at K>1 — and reap the oldest in-flight dispatch once the
         pipeline is full.
 
+        Fused admission stages into empty slots and into slots whose
+        request's end is certain to lie in the dispatches in flight
+        (`_stage_admissions`): such a slot then carries two requests at
+        once, the departing one in the in-flight snapshots until `_walk`
+        reaps its end, and its successor in `_slot_req` and every
+        dispatch from this one on.
+
         Pipelining (inflight_limit=2 default): the dispatch for program
         N+1 goes out BEFORE program N's tokens are read back, so the
         host's readback and reap overlap N+1's device compute instead of
@@ -2432,8 +2504,16 @@ class PagedEngine:
                 else:
                     self._admit()
             if self._live() or self._any_staged():
+                # The backlog the controller sizes K against counts a
+                # request until its predecessor's end is reaped, as it
+                # did when it waited in `_pending` for that reap: a slot
+                # handed on changes where the successor waits, not that
+                # work was waiting for a slot. (An empty backlog grows K,
+                # and a dispatch of K*chunk rows sent while the ends in
+                # flight are unreaped strands every lane in it.)
                 self.megastep_k = next_megastep_k(
-                    self.megastep_k, self.megastep_ks, len(self._pending),
+                    self.megastep_k, self.megastep_ks,
+                    len(self._pending) + len(self._departing()),
                     self._slack_chunks(), fused=self.fused,
                 )
                 # Fused admission dispatches through the megastep at
@@ -2687,12 +2767,15 @@ class PagedEngine:
                 done.append((req.rid, text))
                 if self._slot_req[slot] is req:
                     self._slot_req[slot] = None
-                # Kill the slot in the LIVE state (which may already be a
-                # chunk ahead): load-bearing for the host-side max_new/tmax
-                # caps, where the device still thinks the slot is active.
-                self.state = self.state._replace(
-                    active=self.state.active.at[slot].set(False)
-                )
+                    # Kill the slot in the LIVE state (which may already
+                    # be a chunk ahead): load-bearing for the host-side
+                    # max_new/tmax caps, where the device still thinks the
+                    # slot is active. A slot that was handed on needs no
+                    # kill, and must not get one: `_stage_program` has
+                    # reset the lane, and it is the successor's now.
+                    self.state = self.state._replace(
+                        active=self.state.active.at[slot].set(False)
+                    )
         self._count(staged_lane_steps=staged, overrun_lane_steps=overrun)
         self._observe("decode_lanes", decoded / rows)
         return done

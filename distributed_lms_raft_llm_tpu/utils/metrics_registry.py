@@ -609,8 +609,16 @@ ENGINE_OVERRUN_LANE_STEPS = counter(
     "engine_overrun_lane_steps",
     "lane-steps run for a request past its last token: the rest of the "
     "dispatch in which the host's budget cap ended it (the device does "
-    "not know the cap), and every dispatch already in flight when the "
-    "host reaped the finish",
+    "not know the cap), and every later dispatch sent with the request "
+    "still in its slot (none once the slot has been handed on: "
+    "engine_slots_handed_on)",
+)
+ENGINE_SLOTS_HANDED_ON = counter(
+    "engine_slots_handed_on",
+    "slots staged for the next request while their previous request's "
+    "end was still in flight: its budget cap was certain to lie in the "
+    "dispatches not yet reaped, so the slot did not wait for that reap. "
+    "Over the requests finished, the share of ends the host foresaw",
 )
 MOE_PICKS = counter(
     "moe_picks",
@@ -685,6 +693,7 @@ ENGINE_LOOP_COUNTERS: Dict[str, str] = {
     "lane_steps": ENGINE_LANE_STEPS,
     "staged_lane_steps": ENGINE_STAGED_LANE_STEPS,
     "overrun_lane_steps": ENGINE_OVERRUN_LANE_STEPS,
+    "slots_handed_on": ENGINE_SLOTS_HANDED_ON,
     "moe_picks": MOE_PICKS,
     "moe_experts_reached": MOE_EXPERTS_REACHED,
     "moe_expert_seats": MOE_EXPERT_SEATS,

@@ -29,8 +29,10 @@ from distributed_lms_raft_llm_tpu.engine import (
 from distributed_lms_raft_llm_tpu.engine.paged import (
     SlotState,
     _megastep_program,
+    _Request,
     _step_program,
     next_megastep_k,
+    rows_to_certain_end,
 )
 from distributed_lms_raft_llm_tpu.engine.program_inventory import (
     effective_megastep_max,
@@ -97,6 +99,68 @@ def test_controller_shrinks_when_pending_queue_nonempty():
     assert next_megastep_k(4, ladder, pending=1, slack_chunks=3) == 2
     assert next_megastep_k(8, ladder, pending=1, slack_chunks=5) == 4
     assert next_megastep_k(1, [1], pending=5, slack_chunks=9) == 1
+
+
+def test_rows_to_certain_end_arithmetic():
+    """The one horizon `_slack_chunks` and the hand-on test share: budget
+    left, net of the rows dispatched and not yet reaped; 0 once the end is
+    in flight; nothing for a staged or a finished request."""
+    def req(n_tokens, prompt_len=10, max_new=128, **kw):
+        return _Request(rid=0, prompt_len=prompt_len,
+                        tokens=[7] * n_tokens, max_new=max_new, **kw)
+
+    tmax = 10 + 128
+    # Budget left: the cap less the tokens the host has.
+    assert rows_to_certain_end(req(1), tmax, 0) == 127
+    assert rows_to_certain_end(req(100), tmax, 0) == 28
+    # Dispatched debt: every row in flight brings the lane a token.
+    assert rows_to_certain_end(req(33), tmax, 64) == 31
+    assert rows_to_certain_end(req(63), tmax, 64) == 1
+    # The end is in flight: at the cap exactly, and past it (overrun).
+    assert rows_to_certain_end(req(64), tmax, 64) == 0
+    assert rows_to_certain_end(req(100), tmax, 64) == 0
+    # The tmax clause `_walk` finishes a request by binds first where the
+    # position table is shorter than prompt + budget.
+    assert rows_to_certain_end(req(1, prompt_len=40), 100, 0) == 59
+    assert rows_to_certain_end(req(28, prompt_len=40), 100, 32) == 0
+    # Staged (tokens still hold the prompt), finished, no request: no bound.
+    assert rows_to_certain_end(req(10, live=False), tmax, 64) is None
+    assert rows_to_certain_end(req(128, finished=True), tmax, 0) is None
+    assert rows_to_certain_end(None, tmax, 64) is None
+
+
+def test_slack_chunks_is_the_least_horizon_net_of_each_slots_debt():
+    """`_slack_chunks` = the least `rows_to_certain_end` over the slots in
+    chunks, rounded up; a slot's debt is the rows of the in-flight
+    dispatches whose snapshot holds ITS request; staged slots are out."""
+    eng = PagedEngine(make_config(), slots=4, chunk=2, megastep=2,
+                      megastep_max=2)
+
+    def req(rid, n_tokens, **kw):
+        return _Request(rid=rid, prompt_len=4, tokens=[7] * n_tokens,
+                        max_new=MAX_NEW, **kw)
+
+    assert eng._slack_chunks() is None
+    a, b, staged = req(0, 1), req(1, 4), req(2, 4, live=False)
+    eng._slot_req = [a, b, staged, None]
+    assert eng._slack_chunks() == 2            # b: 4 rows left, 2 a chunk
+    assert [eng._rows_to_end(s) for s in range(4)] == [7, 4, None, None]
+    # One K=2 dispatch (4 rows) in flight that held a and b, and one from
+    # before a took its slot: b's end is in flight, a's debt is 4 rows.
+    active = np.zeros((2, 4), np.int8)
+    eng._inflight = [
+        (None, None, active, None, None, None, [None, b, None, None], None),
+        (None, None, active, None, None, None, [a, b, staged, None], None),
+    ]
+    assert [eng._rows_to_end(s) for s in range(4)] == [3, 0, None, None]
+    assert eng._slack_chunks() == 0
+    assert eng._end_in_flight(1) and not eng._end_in_flight(0)
+    assert not eng._end_in_flight(2)
+    eng._session_reqs[b.rid] = ("sess", 30.0, [1, 2, 3, 4])
+    assert not eng._end_in_flight(1), "a session turn waits for its reap"
+    eng._slot_req[1] = None
+    assert eng._slack_chunks() == 2            # a: 3 rows left -> 2 chunks
+    eng._inflight = []
 
 
 def test_controller_holds_amortization_under_saturation():

@@ -152,9 +152,11 @@ def test_ring_evicts_oldest_unpinned(tracer):
     retained = {f"r-{i}" for i in range(20)
                 if tracer.tree(f"r-{i}") is not None}
     assert len(retained) <= 8 + 2
-    assert all(tid in pinned for tid in retained - {
-        f"r-{i}" for i in range(20 - 8, 20)
-    }), "anything retained beyond the newest ring entries must be pinned"
+    # The ring bounds the UNPINNED traces: a pin taken late (durations of
+    # empty traces are jitter) leaves its place to an older unpinned one.
+    unpinned = [f"r-{i}" for i in range(20) if f"r-{i}" not in pinned]
+    assert retained - pinned <= set(unpinned[-8:]), (
+        "anything retained beyond the newest unpinned traces must be pinned")
 
 
 def test_slowest_per_route_pinned_past_eviction(tracer):
