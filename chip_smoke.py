@@ -442,7 +442,11 @@ def four_chip_path(*, model="gpt2-large", tp=4, max_new_tokens=48,
                    require_tpu=True) -> dict:
     """One process, all chips: a `PagedEngine` at tp=`tp` over every device
     against the same engine at tp=1 on one, same seed, same greedy prompts.
-    (The rehearsal runs it at `model="tiny"` on virtual CPU devices.)"""
+    (The rehearsal runs it at `model="tiny"` on virtual CPU devices.)
+    The engine is built at its default `prefill_chunk_tokens`, so since
+    PR 32 both sides run the served path (staged admission, every rung
+    through the megastep) and no longer the sequential prefill-and-install
+    programs, which are gone."""
     import jax
     import numpy as np
 

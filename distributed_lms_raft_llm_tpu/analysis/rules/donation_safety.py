@@ -2,7 +2,7 @@
 dispatch.
 
 Every engine state program donates its input (`donate_argnums` on
-`_step`/`_install`/`_grow`/`_decode`): XLA reuses the buffers in place,
+`_megastep`/`_stage`/`_grow`/`_decode`): XLA reuses the buffers in place,
 which is the entire reason admission and decode don't copy the KV cache
 every step. The contract is invisible at the call site, and breaking it
 is a runtime crash ("array has been deleted") that only fires on backends
@@ -25,7 +25,7 @@ branch-aware statement ordering):
 - **unbound-attr-donate**: a donated `self.<attr>` whose result does not
   rebind `self.<attr>` in the same statement. The attribute outlives the
   function, so the NEXT entry into any method reads deleted buffers; the
-  live engine always writes `self.state = self._step(..., self.state,
+  live engine always writes `self.state = self._stage(self.state,
   ...)` in one statement.
 
 Reads the analysis cannot attribute (dynamic dispatch, cross-function

@@ -12,7 +12,6 @@ every entrypoint consumes it:
     python -m ...serving.lms_server --config cluster.toml --id 3
     python -m ...serving.tutoring_server --config cluster.toml
     python -m ...client.cli --config cluster.toml
-    python bench.py --config cluster.toml
 
 CLI flags still work and override file values (two-phase parse: the file
 fills argparse defaults, explicit flags win). See configs/cluster.toml for
@@ -52,7 +51,8 @@ class SamplingConfig:
     top_p: float = 0.9
     repetition_penalty: float = 1.2
     max_new_tokens: int = 128
-    approx_top_k: bool = False  # ~0.95-recall top-k, +12% decode throughput
+    approx_top_k: bool = False  # ~0.95-recall top-k (speed not measured
+    #                             on the chip)
 
 
 @dataclasses.dataclass
@@ -99,22 +99,22 @@ class TutoringConfig:
     #                              shared-prefix tree (16 tokens/block);
     #                              ref-count-pinned blocks are never
     #                              evicted, LRU leaves go first
-    prefill_chunk_tokens: int = 0  # paged: fused stall-free admission —
-    #                              stage arriving prompts into SlotState
-    #                              and prefill this many tokens per
-    #                              decode iteration INSIDE the
-    #                              megastep program, instead of pausing
-    #                              the decode train for a standalone
-    #                              prefill dispatch. 0 = sequential
-    #                              admission. Admission latency becomes
-    #                              bounded by scan iterations (~chunk
-    #                              device steps each), not prompt length
+    prefill_chunk_tokens: int = 32  # paged: arriving prompts are staged
+    #                              into SlotState and prefilled this many
+    #                              tokens per decode iteration INSIDE the
+    #                              megastep program (>= 1). Admission
+    #                              latency is bounded by scan iterations,
+    #                              not by a prefill dispatch of its own
     draft_source: str = "prompt_lookup"  # paged+spec: "prompt_lookup"
     #                              (most-recent n-gram continuation) or
     #                              "ngram" (per-slot modal-continuation
     #                              table — higher acceptance at
     #                              temperature>0)
     auth_key_file: Optional[str] = None
+
+    def __post_init__(self) -> None:
+        if self.prefill_chunk_tokens < 1:
+            raise ValueError("[tutoring] prefill_chunk_tokens must be >= 1")
 
     @property
     def port(self) -> int:

@@ -886,16 +886,9 @@ class PagedQueue:
             self.metrics.set_gauge("serving_kv_bytes_per_chip", float(kvb))
         pop_ds = getattr(self.engine, "pop_dispatch_stats", None)
         if pop_ds is not None:
-            dispatches, tokens, dead, stall_ms, stalled = pop_ds()
+            dispatches, tokens, dead = pop_ds()
             if dead:
                 self.metrics.inc("megastep_dead_lane_tokens", dead)
-            if stall_ms:
-                # Decode-train pause attributable to admission: the
-                # before/after number for fused chunked prefill (both
-                # stay 0 with fusion on — staging never blocks decode).
-                self.metrics.inc("prefill_stall_ms", stall_ms)
-            if stalled:
-                self.metrics.inc("decode_stalled_tokens", stalled)
             n_disp = self.metrics.inc("engine_dispatches", dispatches)
             n_tok = self.metrics.inc("engine_tokens_emitted", tokens)
             if n_tok:

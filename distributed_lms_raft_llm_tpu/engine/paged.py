@@ -11,74 +11,62 @@ joins the running batch at the next step instead of queueing behind it.
 Layout differences from the bucketed path (both by design):
 - prompts are RIGHT-padded into their slot (slot position 0 = first prompt
   token) so per-slot raggedness is just a length integer;
-- decode is a host-driven loop over a jitted CHUNKED step program
+- decode is a host-driven loop over a jitted program of K CHUNKED steps
   (admission needs host control between dispatches), not a device-side
-  while_loop. Each dispatch advances `chunk` tokens for all S slots with
-  one readback — see `_step_program` for what chunking amortizes.
+  while_loop. Each dispatch advances K * `chunk` tokens for all S slots
+  with one readback — see `_step_program` for what chunking amortizes.
 
-Four jitted program families, compiled once each:
-- `_prefill`: one prompt through the model into a fresh single-slot cache,
-  first token sampled. With the shared-prefix cache enabled
-  (`prefix_cache=True`), admission first looks the prompt up in a radix
-  tree of immutable device-resident KV block runs
-  (`engine/prefix_cache.py`): on a hit, `_load_block` splices the cached
-  blocks into a fresh prompt-bucket cache and `_partial_prefill` runs the
-  forward over only the uncached suffix (positions/attention offsets
-  starting at the shared-prefix length), producing the same
-  (cache, first token, seen row) contract cold prefill feeds `_install`;
-  completed prefills publish their prompt's block runs back into the tree
-  (`_export_block`), ref-count-pinned by live slots and LRU-evicted under
-  a block budget;
-- `_install`: splices a prefilled slot into the live donated state;
-- `_step`: [S,1] last-tokens forward with per-row cache offsets (the
-  models' ragged-slot scatter path), fused sampling, lengths/active
-  update, scanned over `chunk` tokens. With `EngineConfig.spec_tokens=k`
-  set, the step generalizes to a [S, k+1] verify window per scan
-  iteration (`_spec_step_program`): prompt-lookup drafts from the
-  device-side transcript, one forward over the window, exact rejection
-  sampling (`engine.draft`, shared with `engine.spec`) — rows accept
-  different counts, so slot lengths advance raggedly between host
-  dispatches and the host reaps a per-window token count;
-- `_megastep`: K chunks of `_step`/`_spec_step` back-to-back on device
-  (`_megastep_program`, a scan over the chunk body), so the host pays one
-  dispatch + one async readback per K*chunk tokens instead of per chunk.
-  Per-chunk token planes and active-mask snapshots come back stacked
-  (`[K, chunk, S, ...]` / `[K, S]`) for one batched host reap; slots that
-  finish mid-megastep burn pad lanes until the boundary (counted on
-  device — `megastep_dead_lane_tokens`) instead of forcing a host reap.
-  Admission joins at megastep boundaries; a TTFT-aware controller
+Admission is STAGED and the decode program is one (`_megastep`); there is
+no prefill program of its own between decode dispatches, so an arriving
+prompt never pauses the decode train:
+- `_stage`: writes a request's right-padded prompt ids into its slot's
+  transcript row and arms the staged-admission plane riding in SlotState
+  (staged flag, chunk cursor, true length, first-token rng). With the
+  shared-prefix cache enabled (`prefix_cache=True`), admission first looks
+  the prompt up in a radix tree of immutable device-resident KV block runs
+  (`engine/prefix_cache.py`): on a hit `_stage_block` splices the cached
+  blocks straight into the slot's pages and the cursor starts behind
+  them; a flipped request's prompt blocks are published back into the
+  tree (`_export_block`), ref-count-pinned by live slots and LRU-evicted
+  under a block budget.
+- `_megastep`: K chunks of `_step_program` back-to-back on device (a scan
+  over the chunk body), so the host pays one dispatch + one async
+  readback per K*chunk tokens. The chunk body is a [S,1] last-tokens
+  forward with per-row cache offsets (the models' ragged-slot scatter
+  path), fused sampling, lengths/active update, scanned over `chunk`
+  tokens. With `EngineConfig.spec_tokens=k` set, it generalizes to a
+  [S, k+1] verify window per scan iteration (`_spec_step_program`):
+  prompt-lookup drafts from the device-side transcript, one forward over
+  the window, exact rejection sampling (`engine.draft`, shared with
+  `engine.spec`) — rows accept different counts, so slot lengths advance
+  raggedly between host dispatches and the host reaps a per-window token
+  count. Per-chunk token planes and active-mask snapshots come back
+  stacked (`[K, chunk, S, ...]` / `[K, S]`) for one batched host reap;
+  slots that finish mid-megastep burn pad lanes until the boundary
+  (counted on device — `megastep_dead_lane_tokens`) instead of forcing a
+  host reap. While any slot is staged, EVERY decode iteration of the
+  megastep (each row of the per-token scan inside each of its K chunks,
+  not each chunk) first runs one token-budgeted prefill chunk
+  (`prefill_chunk_tokens` positions, `_admission_chunk`) for the oldest
+  staged slot — the Sarathi-Serve chunked-prefill idea, device-resident —
+  and then advances the live slots by a token. The final chunk samples
+  the first token from the last real position's logits with the staged
+  rng and the full-prompt seen mask, and flips the slot live in that same
+  iteration; `flipped`/`firsts` planes come back stacked [K, chunk, S], a
+  row per iteration like the tokens, so the one batched reap learns
+  admission outcomes with zero extra syncs, and a request holds its lane
+  staged for about as many iterations as the chunks queued before its
+  last (`engine_staged_iterations`). Prefill compute fills the scan's
+  pipeline bubbles, and greedy outputs are bit-identical to the bucketed
+  engine's (`TutoringEngine`) at any K and chunk budget
+  (tests/test_fused_prefill.py).
+- Staging happens at megastep boundaries; a TTFT-aware controller
   (`next_megastep_k`) grows K toward `megastep_max` when idle and, while
   admissions are waiting, caps K at the guaranteed-admission horizon
-  (chunks until some live slot MUST free, `_slack_chunks`) — wide under
-  saturation, down to the chunk loop exactly at the boundary a waiting
-  request can actually join.
-- `_stage`/`_megastep`+prefill phase (`prefill_chunk_tokens > 0`):
-  stall-free fused admission. The sequential admission above still runs
-  prefill as its own program BETWEEN decode dispatches — every arriving
-  prompt pauses the whole decode train for a full (or suffix-only)
-  prefill (the dominant admission stall once megasteps removed the host
-  from the chunk loop). With fusion on, admission is *staged* instead:
-  `_stage_program` writes the prompt ids into the slot's transcript row
-  and arms a staged-admission plane riding in SlotState (staged flag,
-  chunk cursor, true length, first-token rng), with cached shared-prefix
-  blocks spliced straight into the slot's pages (`_stage_block`); then,
-  while any slot is staged, EVERY decode iteration of the megastep (each
-  row of the per-token scan inside each of its K chunks, not each chunk)
-  first runs one token-budgeted prefill chunk (`prefill_chunk_tokens`
-  positions) for the oldest staged slot — the Sarathi-Serve
-  chunked-prefill idea, device-resident — and then advances the live
-  slots by a token. The final chunk samples the first token with the
-  cold path's rng/seen-mask contract and flips the slot live in that
-  same iteration; `flipped`/`firsts` planes come back stacked
-  [K, chunk, S], a row per iteration like the tokens, so the one batched
-  reap learns admission outcomes with zero extra syncs, and a request
-  holds its lane staged for about as many iterations as the chunks
-  queued before its last (`engine_staged_iterations`), where a chunk per
-  `chunk` iterations held it sixteen times as long. Decode never waits
-  on admission (`decode_stalled_tokens`
-  stays 0), prefill compute fills the scan's pipeline bubbles, and
-  greedy outputs are bit-identical to the sequential prefill-then-decode
-  path at any K and chunk budget (tests/test_fused_prefill.py).
+  (chunks until some live slot MUST free, `_slack_chunks`), never below
+  the ladder's second rung. K = 1 runs through `_megastep` at rung 1.
+- `_grow`: pads the live cache up to the next width when a longer prompt
+  arrives.
 
 The reference has no analogue (HF `generate`, one request at a time —
 reference: GUI_RAFT_LLM_SourceCode/tutoring_server.py:21-29).
@@ -111,7 +99,6 @@ from .prefix_cache import (
     KVBlock,
     Match,
     PrefixCache,
-    plan_partial,
     plan_staged,
 )
 from .program_inventory import (
@@ -144,24 +131,22 @@ class SlotState(NamedTuple):
     # (right-padded: transcript slot j = the token whose KV lives — or
     # will live — in cache slot j). Slots <= cache.length hold real
     # tokens. Feeds the prompt-lookup drafter in spec mode; carried
-    # unchanged (aliased in place by donation) by the plain step. With
-    # fused admission the transcript doubles as the staged prompt's
-    # device-side id store: `_stage_program` writes the whole right-padded
-    # prompt here and the in-scan prefill chunks read their ids back out.
+    # unchanged (aliased in place by donation) by the plain step. The
+    # transcript doubles as the staged prompt's device-side id store:
+    # `_stage_program` writes the whole right-padded prompt here and the
+    # in-scan prefill chunks read their ids back out.
     transcript: jax.Array
-    # Staged-admission plane (fused chunked prefill; all [S], inert zeros
-    # when `prefill_chunk_tokens` is 0): `staged` marks slots whose
-    # prompt is being prefilled inside the megastep scan (one chunk per
-    # decode iteration, for the oldest by `stage_seq`), `stage_cursor`
-    # the next absolute prefill position (starts at the spliced
-    # shared-prefix length), `stage_len` the true prompt length (it stays
-    # after the flip, and `_install` sets it too: a family with routed
-    # experts knows a lane's request has ended from it, `_live_lanes`),
-    # `stage_seq` the host's staging sequence number (FIFO service order
-    # — slot index would starve an early admission whenever churn
-    # restages a lower slot), and `stage_rng` the raw key data the flip
-    # samples the first token with (the same host split sequence the
-    # sequential _admit would have consumed).
+    # Staged-admission plane (in-scan chunked prefill; all [S]): `staged`
+    # marks slots whose prompt is being prefilled inside the megastep scan
+    # (one chunk per decode iteration, for the oldest by `stage_seq`),
+    # `stage_cursor` the next absolute prefill position (starts at the
+    # spliced shared-prefix length), `stage_len` the true prompt length
+    # (it stays after the flip: a family with routed experts knows a
+    # lane's request has ended from it, `_live_lanes`), `stage_seq` the
+    # host's staging sequence number (FIFO service order — slot index
+    # would starve an early admission whenever churn restages a lower
+    # slot), and `stage_rng` the raw key data the flip samples the first
+    # token with (one host split an admission, in admission order).
     staged: jax.Array       # [S] bool
     stage_cursor: jax.Array  # [S] int32
     stage_len: jax.Array     # [S] int32
@@ -185,9 +170,9 @@ class _Request:
     # flight (`_stage_admissions`), and the request lives on in their
     # snapshots, which is where `_walk` finishes it.
     finished: bool = False
-    # False while the request is STAGED (fused admission: prompt handed to
-    # the device, prefill advancing inside the megastep scan, first token
-    # not yet sampled). `tokens` still holds the prompt until the flip is
+    # False while the request is STAGED (prompt handed to the device,
+    # prefill advancing inside the megastep scan, first token not yet
+    # sampled). `tokens` still holds the prompt until the flip is
     # reaped; _live()/_slack_chunks treat staged requests as not-yet-live.
     live: bool = True
     # Scan iterations this request held a lane while staged, in the
@@ -248,110 +233,12 @@ def _live_lanes(s: "SlotState", sampling: SamplingParams) -> jax.Array:
         s.cache.length < s.stage_len + (sampling.max_new_tokens - 1))
 
 
-def _prefill_program(params, ids, true_len, rng, *, cfg, sampling, model):
-    """[1, T] right-padded prompt -> (cache, first_tok, seen_row), and for
-    a family with routed experts its counts (`_forward`) after them.
-
-    The returned cache is PROMPT-sized — [L, 1, H, T, Dh] for a T-token
-    prompt bucket (plus scale planes when int8-quantized), the prompt
-    occupying positions 0..true_len-1. `_install` splices it into the
-    slot's region of the live Tmax-wide cache (a dynamic_update_slice with
-    a smaller-than-operand update); the first generated token's KV lands
-    during the next step program. Prompt buckets therefore compile one
-    prefill program per length bucket, and a short prompt pays a short
-    prefill instead of the full Tmax one.
-    """
-    _, t = ids.shape
-    cache = model.init_cache(cfg, 1, t, dtype=cfg.dtype)
-    kv_mask = (jnp.arange(t) < true_len)[None, :]
-    positions = jnp.minimum(jnp.arange(t, dtype=jnp.int32), true_len - 1)[None, :]
-    logits, cache, moe = _forward(
-        model, params, cfg, ids, kv_mask, cache=cache, positions=positions,
-        kv_mask=kv_mask,
-    )
-    last = jax.lax.dynamic_index_in_dim(
-        logits[0], true_len - 1, 0, keepdims=False
-    )
-    valid = (jnp.arange(t) < true_len)[None, :]
-    seen = seen_mask_from_ids(ids, valid, cfg.vocab_size)[0]
-    first = sample_step(rng, last[None, :], seen[None, :], sampling)[0]
-    return (cache, first, update_seen(seen[None, :], first[None])[0], *moe)
-
-
-def _partial_prefill_program(params, cache0: KVCache, ids_full, ids_suf,
-                             prefix_len, true_len, rng, *, cfg, sampling,
-                             model):
-    """Prefill only the uncached suffix of a shared-prefix prompt.
-
-    `cache0` is a prompt-bucket-wide single-slot cache whose first
-    `prefix_len` positions hold KV spliced from the radix tree
-    (`_load_block_program`); `ids_full` is the [1, t] right-padded FULL
-    prompt (seen-mask seed — identical to what cold prefill consumes),
-    `ids_suf` the [1, s] right-padded uncached suffix. The forward runs
-    over the suffix only: KV scatters at offset `prefix_len` and
-    positions default to the cache slot indices, so positions/attention
-    offsets start at the shared-prefix length — each real suffix query
-    attends causally over [0, prefix_len + j], exactly the key set the
-    cold [1, t] prefill masks in for the same position (the pad tails
-    differ only in garbage no valid query can attend to — the same
-    causal-frontier argument as `_spec_step_program`'s window). The last
-    real suffix position IS the prompt's last position, so sampling from
-    its logits with the cold path's rng split and the full-prompt seen
-    mask makes a cache-hit first token bit-identical to the cold one;
-    the decode path downstream is untouched and inherits the equality
-    (pinned across plain/spec/kv-quant/megastep in
-    tests/test_prefix_cache.py).
-
-    Returns (cache [.., t, ..], first, seen_row) — the exact contract
-    `_install_program` consumes from `_prefill_program` (and, like it, a
-    routed family's counts after them).
-    """
-    _, t = ids_full.shape
-    suf_len = true_len - prefix_len
-    logits, cache, moe = _forward(
-        model, params, cfg, ids_suf,
-        (jnp.arange(ids_suf.shape[1]) < suf_len)[None, :],
-        cache=cache0._replace(length=prefix_len),
-    )
-    last = jax.lax.dynamic_index_in_dim(
-        logits[0], suf_len - 1, 0, keepdims=False
-    )
-    valid = (jnp.arange(t) < true_len)[None, :]
-    seen = seen_mask_from_ids(ids_full, valid, cfg.vocab_size)[0]
-    first = sample_step(rng, last[None, :], seen[None, :], sampling)[0]
-    return (cache, first, update_seen(seen[None, :], first[None])[0], *moe)
-
-
-def _load_block_program(cache0: KVCache, block: KVBlock, off) -> KVCache:
-    """Splice one immutable shared KV block into a fresh single-slot
-    prefill cache at token offset `off` (one compiled program per prompt
-    bucket; the block width is an engine constant). Donates the
-    accumulator `cache0` — a private buffer mid-assembly — and NEVER the
-    block: tree blocks are shared structure (engine/prefix_cache.py),
-    and donating one would free KV that other admissions still splice
-    from (reversion-pinned in tests/test_lint_clean.py)."""
-    zero = jnp.zeros((), jnp.int32)
-    off = jnp.asarray(off, jnp.int32)
-    k = jax.lax.dynamic_update_slice(cache0.k, block.k,
-                                     (zero, zero, zero, off, zero))
-    v = jax.lax.dynamic_update_slice(cache0.v, block.v,
-                                     (zero, zero, zero, off, zero))
-    ks = vs = None
-    if cache0.quantized:
-        ks = jax.lax.dynamic_update_slice(cache0.ks, block.ks,
-                                          (zero, zero, zero, off))
-        vs = jax.lax.dynamic_update_slice(cache0.vs, block.vs,
-                                          (zero, zero, zero, off))
-    return cache0._replace(k=k, v=v, ks=ks, vs=vs)
-
-
 def _export_block_program(c1: KVCache, off, slot, *, block: int) -> KVBlock:
     """Slice one block-aligned KV run out of a prefilled cache — a fresh
-    immutable copy the radix tree owns. `slot` selects the sequence: 0
-    for the sequential path's single-slot admission cache, the live slot
-    index when fused admission publishes straight out of the multi-slot
-    state (the prompt region 0..prompt_len-1 is never rewritten by
-    decode, which scatters at >= prompt_len). Publishing copies rather
+    immutable copy the radix tree owns. `slot` selects the sequence:
+    admission publishes straight out of the live multi-slot state (the
+    prompt region 0..prompt_len-1 is never rewritten by decode, which
+    scatters at >= prompt_len). Publishing copies rather
     than aliasing: the source is transient engine state, and a tree that
     aliased it would see its buffers donated away by the next program."""
     l, _, h, _, dh = c1.k.shape
@@ -373,7 +260,7 @@ def _export_block_program(c1: KVCache, off, slot, *, block: int) -> KVBlock:
 
 def _stage_program(state: SlotState, slot, ids, true_len, cursor0, seq,
                    rng_raw) -> SlotState:
-    """Arm one slot's staged admission (fused chunked prefill): write the
+    """Arm one slot's staged admission (in-scan chunked prefill): write the
     right-padded prompt into the slot's transcript row and set the
     staged-admission plane — prefill then advances inside the megastep
     scan (`_admission_chunk`), one `prefill_chunk_tokens` chunk per
@@ -385,8 +272,7 @@ def _stage_program(state: SlotState, slot, ids, true_len, cursor0, seq,
     phase still computes a forward for every slot, and an inactive row
     scatters its (garbage) KV at its length position — parked above the
     prompt region, the staged pages can never be corrupted by it (the
-    same clamp position a dead slot writes to). Donates the state like
-    `_install`."""
+    same clamp position a dead slot writes to). Donates the state."""
     zero = jnp.zeros((), jnp.int32)
     slot = jnp.asarray(slot, jnp.int32)
     width = state.transcript.shape[1]
@@ -419,12 +305,11 @@ def _stage_block_program(state: SlotState, block, slot, off,
     STAGE_RUN_BLOCKS consecutive ones as one run of which the first
     `tokens` tokens count (a short run comes padded with its last block),
     straight into a slot's pages of the LIVE multi-slot cache at token
-    offset `off` (fused admission's counterpart of `_load_block`; one
-    compiled program per cache width, and one more where a bucket can
-    share a run). Donates the state — a private accumulator between
-    dispatches — and NEVER the block: tree blocks are shared structure
-    (engine/prefix_cache.py), and donating one would free KV other
-    admissions still splice from."""
+    offset `off` (one compiled program per cache width, and one more
+    where a bucket can share a run). Donates the state — a private
+    accumulator between dispatches — and NEVER the block: tree blocks are
+    shared structure (engine/prefix_cache.py), and donating one would free
+    KV other admissions still splice from."""
     # The argument's STRUCTURE (one block, or a tuple of them with a
     # count) is fixed at trace time and keys the compiled program; no
     # traced value is read.
@@ -483,52 +368,11 @@ def _fresh_state(family, cfg, slots: int, width: int) -> SlotState:
     )
 
 
-def _install_program(state: SlotState, slot, c1: KVCache, ids, true_len,
-                     first, seen_row, *, eos_id: int) -> SlotState:
-    """Splice a prefilled slot into the live state (one fused program).
-
-    `ids` is the [1, t] right-padded prompt (the same array `_prefill`
-    consumed): it seeds the slot's transcript row — prompt tokens in
-    transcript slots 0..true_len-1, the first sampled token at slot
-    true_len (its cache slot). Stale tokens from the slot's previous
-    occupant beyond the prompt bucket are harmless: the drafter only
-    reads transcript slots <= cache.length, all (re)written by the
-    current occupant before its length reaches them.
-    """
-    zero = jnp.zeros((), jnp.int32)
-    ck = jax.lax.dynamic_update_slice(
-        state.cache.k, c1.k, (zero, slot, zero, zero, zero)
-    )
-    cv = jax.lax.dynamic_update_slice(
-        state.cache.v, c1.v, (zero, slot, zero, zero, zero)
-    )
-    cks = cvs = None
-    if state.cache.quantized:
-        cks = jax.lax.dynamic_update_slice(
-            state.cache.ks, c1.ks, (zero, slot, zero, zero)
-        )
-        cvs = jax.lax.dynamic_update_slice(
-            state.cache.vs, c1.vs, (zero, slot, zero, zero)
-        )
-    lengths = state.cache.length.at[slot].set(true_len)
-    transcript = jax.lax.dynamic_update_slice(
-        state.transcript, ids, (slot, zero)
-    )
-    transcript = transcript.at[slot, true_len].set(first)
-    return state._replace(
-        cache=KVCache(ck, cv, lengths, ks=cks, vs=cvs),
-        tok=state.tok.at[slot].set(first),
-        active=state.active.at[slot].set(first != eos_id),
-        seen=state.seen.at[slot].set(seen_row),
-        transcript=transcript,
-        stage_len=state.stage_len.at[slot].set(true_len),
-    )
-
-
 def _grow_state_program(state: SlotState, new_len: int) -> SlotState:
     """Zero-pad the cache's slot axis up to `new_len` (width-bucket growth:
     the live cache is only as wide as the widest ACTIVE request needs —
-    see PagedEngine._admit — and pads up when a longer prompt arrives)."""
+    see PagedEngine._grow_if_needed — and pads up when a longer prompt
+    arrives)."""
     grow = new_len - state.cache.k.shape[3]
     pad = [(0, 0), (0, 0), (0, 0), (0, grow), (0, 0)]
     cache = state.cache._replace(
@@ -584,12 +428,12 @@ def _step_program(params, state: SlotState, rng, *, cfg, sampling,
     snapshot/donation invariant is per chunk, so it carries over
     unchanged; only the host reap granularity moves from one chunk to K.
 
-    `admit` (fused admission only; the megastep binds `_admission_chunk`
-    to it) runs at the head of EVERY scan iteration, before the decode:
+    `admit` (the megastep binds `_admission_chunk` to it) runs at the
+    head of EVERY scan iteration, before the decode:
     state -> (state, flipped [S], firsts [S]). A slot it flips live
     decodes its first token in that same iteration, and the two planes
-    come back stacked [chunk, S] after the snapshot. None (every engine
-    with `prefill_chunk_tokens = 0`) leaves body and outputs as they are.
+    come back stacked [chunk, S] after the snapshot. None (a caller that
+    lowers the bare chunk) leaves body and outputs as they are.
 
     A family with routed experts (`ModelFamily.routed`) adds one LAST
     output, its counts summed over the iterations and `admit`'s forward
@@ -751,7 +595,7 @@ def _spec_step_program(
 def _admission_chunk(params, s: SlotState, *, cfg, sampling, model,
                      eos_id: int, pad_id: int, prefill_chunk: int):
     """One token-budgeted prefill chunk for the oldest staged admission —
-    the fused-admission phase at the head of every decode iteration (the
+    the admission phase at the head of every decode iteration (the
     megastep hands it to the per-token scan body as `admit`; staging
     happens only between dispatches, so every staged slot is known at a
     megastep's entry and is served, oldest first, a chunk an iteration
@@ -764,18 +608,17 @@ def _admission_chunk(params, s: SlotState, *, cfg, sampling, model,
     into real pages), and splice the updated pages back. When the cursor
     covers the true length, the flip: sample the first token from the
     last real position's logits with the staged rng and the full-prompt
-    seen mask — the exact contract `_prefill_program` feeds `_install` —
-    then mark the slot live (length=true_len, transcript gains the first
-    token at its cache slot, active unless eos). The computation per
-    real position is identical to the cold prefill's (same KV values,
-    same causal key set, pad tails masked), so the flipped slot's stream
-    is bit-identical to the sequential path's.
+    seen mask, then mark the slot live (length=true_len, transcript gains
+    the first token at its cache slot, active unless eos). The computation
+    per real position is that of one whole-prompt forward (same KV values,
+    same causal key set, pad tails masked), so the flipped slot's greedy
+    stream is bit-identical to the bucketed engine's.
 
     Returns (state, flipped [S] bool, firsts [S] int32) — one-hot at the
     flipped slot — and, for a family with routed experts, the chunk's
     counts (`_forward`; the chunk's pad tail routes nowhere). A `lax.cond`
     skips all of it when nothing is staged, so the steady-state decode
-    iteration pays nothing for the fused capability.
+    iteration pays nothing for it.
     """
     n_slots = s.tok.shape[0]
     no_flip = jnp.zeros((n_slots,), jnp.bool_)
@@ -811,9 +654,9 @@ def _admission_chunk(params, s: SlotState, *, cfg, sampling, model,
             )
         c1 = KVCache(ck, cv, cur[None], ks=cks, vs=cvs)
         ids = jax.lax.dynamic_slice(s.transcript, (slot, cur), (1, c))
-        # Pad-tail positions clamp to the last real position, exactly as
-        # the cold prefill's position plane does; their outputs/KV are
-        # garbage nothing reads (causal frontier + the decode kv_mask).
+        # Pad-tail positions clamp to the last real position; their
+        # outputs/KV are garbage nothing reads (causal frontier + the
+        # decode kv_mask).
         positions = jnp.minimum(
             cur + jnp.arange(c, dtype=jnp.int32), tl - 1
         )[None, :]
@@ -881,33 +724,33 @@ def _admission_chunk(params, s: SlotState, *, cfg, sampling, model,
 
 def _megastep_program(params, state: SlotState, rngs, *, cfg, sampling,
                       eos_id: int, pad_id: int, model, spec_tokens: int,
-                      chunk: int, prefill_chunk: int = 0,
+                      chunk: int, prefill_chunk: int,
                       draft_fn=build_drafts):
     """K `chunk`-token steps back-to-back on device: one dispatch, one
     readback, K*chunk decode iterations.
 
-    `rngs` is a stacked [K] key array holding the SAME sequential splits
-    the chunk-loop host would have fed dispatch-by-dispatch, so chunk j of
-    a megastep consumes exactly the key chunk-loop dispatch j would have —
-    outputs are bit-identical to K separate `_step` dispatches (the K axis
+    `rngs` is a stacked [K] key array of sequential host splits, one a
+    chunk, so chunk j of a megastep consumes the key a dispatch of chunk j
+    alone would have — outputs are bit-identical at every K (the K axis
     is encoded in the rngs shape, so each K compiles its own program; the
     warmed domain is widths x the `megastep_ladder` rungs).
 
-    The scan body is the existing `_step_program`/`_spec_step_program`
-    (selected statically by `spec_tokens`), unchanged; its per-dispatch
-    outputs stack along a leading K axis:
+    The scan body is `_step_program`/`_spec_step_program` (selected
+    statically by `spec_tokens`) with `_admission_chunk` bound to its
+    `admit`: it serves the oldest staged slot one prefill chunk of
+    `prefill_chunk` positions BEFORE each decode iteration, so a slot
+    joins the train at a scan-iteration boundary, not a chunk or dispatch
+    boundary. The per-chunk outputs stack along a leading K axis:
 
-    - plain: (state, toks [K, chunk, S], active [K, S] int8, dead int32)
+    - plain: (state, toks [K, chunk, S], active [K, S] int8, dead int32,
     - spec:  (state, emitted [K, chunk, S, k+1], counts [K, chunk, S],
-              active [K, S] int8, dead int32)
-    - fused admission (`prefill_chunk > 0`): either of the above plus
-      (flipped [K, chunk, S] bool, firsts [K, chunk, S] int32) — per
+              active [K, S] int8, dead int32,
+    - then:   flipped [K, chunk, S] bool, firsts [K, chunk, S] int32) — per
       decode iteration (a row of the token plane), the slot whose staged
       prefill completed at its head and the first token it sampled, so
       the batched reap learns admission outcomes without an extra sync
-      and starts the slot's decode walk at that row (see
-      `_admission_chunk`, bound to the scan body's `admit`).
-    - a family with routed experts: any of the above plus, LAST, its
+      and starts the slot's decode walk at that row.
+    - a family with routed experts: the above plus, LAST, its
       counts summed over the dispatch, int32 [3] (`_forward`).
 
     `active[j]` is the post-chunk-j snapshot — the same fresh non-donated
@@ -922,34 +765,27 @@ def _megastep_program(params, state: SlotState, rngs, *, cfg, sampling,
     mode each lane is a verify window whose forward computes
     spec_tokens+1 token positions. dead = chunk * lane_tokens * sum over
     j<K-1 of |slots LIVE by chunk j but inactive after it| (live =
-    active at entry, or flipped live by a fused admission at any row of
+    active at entry, or flipped live by an admission at any row of
     chunk j or an earlier one — a flip-then-eos inside one megastep
     strands lanes too;
     lane_tokens = spec_tokens+1 when speculating, else 1) — zero at K=1
-    (the host reaps every chunk), and exactly the positions a chunk-loop
-    host reap would have freed. Slots already dead at entry (empty, or
-    reaped earlier) are capacity idle in both modes and do not count,
+    (the host reaps every chunk), and exactly the positions a host reap
+    after every chunk would have freed. Slots already dead at entry
+    (empty, or reaped earlier) are capacity idle and do not count,
     and a staged slot's pre-flip iterations are admission work, never
     stranded decode.
     """
     started = state.active  # read before the scan consumes the donation
-    body = dict(cfg=cfg, sampling=sampling, eos_id=eos_id, pad_id=pad_id,
-                model=model, chunk=chunk)
-    if prefill_chunk:
-        # Fused admission: the scan body serves the oldest staged slot one
-        # bounded prefill chunk BEFORE each decode iteration, so a flip's
-        # first decode token lands in that same row of the token plane —
-        # the slot joins the train at a scan-iteration boundary, not a
-        # chunk or dispatch boundary.
-        def admit(s: SlotState):
-            with jax.named_scope("prefill_chunk"):
-                return _admission_chunk(
-                    params, s, cfg=cfg, sampling=sampling, model=model,
-                    eos_id=eos_id, pad_id=pad_id,
-                    prefill_chunk=prefill_chunk,
-                )
 
-        body["admit"] = admit
+    def admit(s: SlotState):
+        with jax.named_scope("prefill_chunk"):
+            return _admission_chunk(
+                params, s, cfg=cfg, sampling=sampling, model=model,
+                eos_id=eos_id, pad_id=pad_id, prefill_chunk=prefill_chunk,
+            )
+
+    body = dict(cfg=cfg, sampling=sampling, eos_id=eos_id, pad_id=pad_id,
+                model=model, chunk=chunk, admit=admit)
 
     def one_chunk(s: SlotState, r):
         if spec_tokens:
@@ -966,28 +802,21 @@ def _megastep_program(params, state: SlotState, rngs, *, cfg, sampling,
     if model.routed:
         *outs, moe = outs  # [K, 3] routed-experts counts, one per chunk
         moe = (jnp.sum(moe, axis=0),)
-    if prefill_chunk:
-        *outs, flipped, firsts = outs  # [K, chunk, S] admission planes
+    *outs, flipped, firsts = outs  # [K, chunk, S] admission planes
     active = outs[-1]  # [K, S] int8 post-chunk snapshots
     lane_tokens = chunk * ((spec_tokens + 1) if spec_tokens else 1)
     # A lane is stranded from the first chunk it is dead AFTER having
-    # been live: live = active at entry, or flipped live by a fused
+    # been live: live = active at entry, or flipped live by an
     # admission in that chunk or an earlier one (a flip-then-eos inside
     # one megastep burns real pad lanes too). Pre-flip staged iterations
     # are admission work, not stranded decode, and never count.
-    if prefill_chunk:
-        live = started[None, :] | (
-            jnp.cumsum(flipped.any(axis=1).astype(jnp.int32), axis=0) > 0
-        )
-    else:
-        live = jnp.broadcast_to(started[None, :], active.shape)
+    live = started[None, :] | (
+        jnp.cumsum(flipped.any(axis=1).astype(jnp.int32), axis=0) > 0
+    )
     dead = jnp.asarray(lane_tokens, jnp.int32) * jnp.sum(
         (live[:-1] & (active[:-1] == 0)).astype(jnp.int32)
     )
-    res = (state, *outs, dead)
-    if prefill_chunk:
-        res = res + (flipped, firsts)
-    return res + moe
+    return (state, *outs, dead, flipped, firsts, *moe)
 
 
 def rows_to_certain_end(req: Optional[_Request], tmax: int,
@@ -1013,8 +842,7 @@ def rows_to_certain_end(req: Optional[_Request], tmax: int,
 
 
 def next_megastep_k(current: int, ladder: Sequence[int], pending: int,
-                    slack_chunks: Optional[int] = None,
-                    fused: bool = False) -> int:
+                    slack_chunks: Optional[int] = None) -> int:
     """TTFT-aware megastep size controller (pure; one decision per
     dispatch). `ladder` is the warmed rung list (`megastep_ladder`,
     ascending, starting at 1).
@@ -1026,41 +854,30 @@ def next_megastep_k(current: int, ladder: Sequence[int], pending: int,
 
     Work waiting for a slot: shrink K — but against the admission
     OPPORTUNITY, not unconditionally. A waiting request can only be
-    admitted when a slot frees, and the next GUARANTEED free is
+    staged when a slot frees, and the next GUARANTEED free is
     `slack_chunks` device chunks away (the engine derives it from the
     live slots' remaining token budgets net of already-dispatched work —
     see `_slack_chunks`). Boundaries more frequent than that admit
-    nobody; they only forfeit amortization — an unconditional
-    shrink-on-pending pins K=1 under sustained saturation, the exact
-    regime megasteps exist for, and slows the queue drain that
-    dominates TTFT there. So K is capped at the largest rung fitting
-    the slack: megasteps stay wide while no lane can free, step down to
-    1 exactly at the guaranteed-finish boundary (admission timing
-    identical to the chunk loop for budget-bound finishes), and pop
-    back up once the freed lanes are refilled. Early finishes (eos,
-    spec over-acceptance) can still strand a lane for up to the
-    in-progress K*chunk steps — that exposure is the dead-lane account
-    (`megastep_dead_lane_tokens`). slack_chunks=None (no live slot to
-    bound) falls to the floor.
+    nobody; they only forfeit amortization. So K is capped at the
+    largest rung fitting the slack: megasteps stay wide while no lane
+    can free and align a boundary with the next guaranteed slot-free so
+    staging starts promptly. Early finishes (eos, spec over-acceptance)
+    can still strand a lane for up to the in-progress K*chunk steps —
+    that exposure is the dead-lane account
+    (`megastep_dead_lane_tokens`).
 
-    Fused staged admission (`fused=True`) re-derives the horizon math:
-    an admission no longer costs a full prefill dispatch at a boundary —
-    it is STAGED there (one async program) and its prefill chunks drain
-    through the scan iterations themselves, so a boundary's only
-    admission value is handing a freed slot to the stager. Shrinking to
-    the K=1 chunk loop therefore buys nothing it used to: the floor
-    rises to the second rung (K stays wide — >= 2 — under a non-empty
-    pending queue, the pinned saturation behavior), while the slack cap
-    still aligns a boundary with the next guaranteed slot-free so
-    staging starts promptly."""
+    The floor is the ladder's second rung: an admission costs no
+    dispatch of its own at a boundary — it is STAGED there (one async
+    program) and its prefill chunks drain through the scan iterations —
+    so a boundary's only admission value is handing a freed slot to the
+    stager, and the K=1 chunk loop buys nothing over K=2.
+    slack_chunks=None (no live slot to bound) falls to that floor."""
     if len(ladder) <= 1:
         return ladder[0] if ladder else 1
     if pending <= 0:
         i = ladder.index(current) if current in ladder else 0
         return ladder[min(len(ladder) - 1, i + 1)]
-    cap = 1 if slack_chunks is None else max(1, slack_chunks)
-    if fused:
-        cap = max(cap, ladder[1])
+    cap = max(ladder[1], slack_chunks or 1)
     return max(k for k in ladder if k <= cap)
 
 
@@ -1069,8 +886,8 @@ class PagedEngine:
 
     Host API (single-threaded; wrap in an executor for async serving):
       submit(prompt) -> request id
-      step() -> list[(rid, text)] — admit pending into free slots, advance
-                one decode step, return requests that finished this step
+      step() -> list[(rid, text)] — stage pending into free slots, send
+                one decode dispatch, return requests whose end was reaped
       drain() -> dict[rid, text] — run until no work remains
     """
 
@@ -1080,7 +897,12 @@ class PagedEngine:
                  megastep_max: int = 0, prefix_cache: bool = False,
                  prefix_cache_blocks: int = 512,
                  prefix_block_tokens: int = BLOCK_TOKENS,
-                 prefill_chunk_tokens: int = 0):
+                 prefill_chunk_tokens: int = 32):
+        if prefill_chunk_tokens < 1:
+            raise ValueError(
+                f"prefill_chunk_tokens is the size of an in-scan prefill "
+                f"chunk and must be >= 1, not {prefill_chunk_tokens}"
+            )
         enable_compilation_cache()
         self.config = config
         # Tokens per dispatched step program — see _step_program. Mid-chunk
@@ -1088,15 +910,14 @@ class PagedEngine:
         # round-trips shrink by the same factor.
         self.chunk = max(1, chunk)
         # Dispatch programs kept in flight: at 2 the host dispatches
-        # (mega)step N+1 before reading N's tokens, so the readback and
+        # megastep N+1 before reading N's tokens, so the readback and
         # the host's reap overlap the next program's compute instead of
         # serializing every dispatch. 1 = the dispatch-sync-reap loop.
         self.inflight_limit = max(1, inflight)
         # Device-resident megastep decode: `megastep` is the controller's
         # starting K (chunks fused per dispatch), `megastep_max` its
-        # ceiling (0 = follow `megastep`). K=1 everywhere is exactly the
-        # pre-megastep chunk loop. The controller moves along the warmed
-        # `megastep_ladder` rungs — see next_megastep_k.
+        # ceiling (0 = follow `megastep`). The controller moves along the
+        # warmed `megastep_ladder` rungs — see next_megastep_k.
         self.megastep_max = effective_megastep_max(megastep, megastep_max)
         self.megastep_ks = megastep_ladder(self.megastep_max)
         self._megastep_initial = max(
@@ -1196,14 +1017,14 @@ class PagedEngine:
             + self._spec_extra
             for b in config.length_buckets
         })
-        # The warmed prompt buckets (one prefill program each; partial
-        # prefill compiles per admissible (bucket, suffix-bucket) pair).
+        # The warmed prompt buckets (`_stage` compiles per admissible
+        # (bucket, width) pair).
         self.buckets = sorted({
             min(b, self.bucket) for b in config.length_buckets
         })
         # Shared-prefix KV cache (engine/prefix_cache.py): a radix tree
         # of immutable device-resident block runs; admission splices the
-        # longest cached prefix and partial-prefills only the suffix.
+        # longest cached prefix and prefills only the suffix.
         self.prefix_block_tokens = max(1, prefix_block_tokens)
         self.prefix_cache: Optional[PrefixCache] = None
         if prefix_cache:
@@ -1211,29 +1032,24 @@ class PagedEngine:
                 block_tokens=self.prefix_block_tokens,
                 max_blocks=max(1, prefix_cache_blocks),
             )
-        # Fused chunked prefill (stall-free admission): with
-        # `prefill_chunk_tokens > 0`, admissions are STAGED into SlotState
-        # and prefill advances inside the megastep scan — one bounded
-        # chunk per decode iteration — instead of dispatching a blocking
-        # prefill program between decode dispatches. The budget is
+        # In-scan chunked prefill: admissions are STAGED into SlotState
+        # and prefill advances inside the megastep scan, one chunk of this
+        # many positions per decode iteration. The budget is
         # clamped so a final chunk's pad-tail ids still fit the transcript
         # slice window (the slice starts at cursor <= bucket-1 and must
         # end inside the cache width = bucket + max_new + spec overhang).
-        self.fused = prefill_chunk_tokens > 0
-        self.prefill_chunk = 0
-        if self.fused:
-            self.prefill_chunk = max(1, min(
-                prefill_chunk_tokens,
-                config.sampling.max_new_tokens + self._spec_extra + 1,
-            ))
-            if self.spec and config.sampling.max_new_tokens < 2:
-                # The staged slot's parked write position (width-1-k in
-                # spec mode) must sit above the prompt region; max_new=1
-                # would park it inside the staged pages.
-                raise ValueError(
-                    "prefill_chunk_tokens with spec_tokens requires "
-                    "max_new_tokens >= 2 (staged-slot parking position)"
-                )
+        self.prefill_chunk = min(
+            prefill_chunk_tokens,
+            config.sampling.max_new_tokens + self._spec_extra + 1,
+        )
+        if self.spec and config.sampling.max_new_tokens < 2:
+            # The staged slot's parked write position (width-1-k in
+            # spec mode) must sit above the prompt region; max_new=1
+            # would park it inside the staged pages.
+            raise ValueError(
+                "spec_tokens requires max_new_tokens >= 2 (staged-slot "
+                "parking position)"
+            )
         if config.draft_source not in ("prompt_lookup", "ngram"):
             raise ValueError(
                 f"unknown draft_source {config.draft_source!r}; expected "
@@ -1263,52 +1079,18 @@ class PagedEngine:
         # exact counts are per engine), carrying the function's name, so a
         # device trace reads `jit__megastep_program`, not `jit__unknown`.
         statics = dict(cfg=self.cfg, sampling=config.sampling, model=self.family)
-        self._prefill = jax.jit(named_partial(_prefill_program, **statics))
-        # Shared-prefix programs. Created even with the cache disabled
-        # (zero warmed programs then) so the inventory guard sees one
-        # stable program set — the _megastep precedent. The partial
-        # prefill donates the spliced cache0 accumulator; the block
-        # splice donates ONLY the accumulator, never the shared block.
-        self._partial_prefill = jax.jit(
-            named_partial(_partial_prefill_program, **statics),
-            donate_argnums=(1,),
-        )
-        self._load_block = jax.jit(
-            named_partial(_load_block_program), donate_argnums=(0,),
-        )
+        # With the shared-prefix cache disabled the block programs warm
+        # zero entries, and the inventory guard sees one stable program set.
         self._export_block = jax.jit(named_partial(
             _export_block_program, block=self.prefix_block_tokens,
         ))
-        # The live SlotState is donated on every program that replaces it, so
-        # admissions and steps update the multi-slot KV cache in place instead
-        # of copying it (a full cache round-trip of HBM traffic otherwise).
-        self._install = jax.jit(
-            named_partial(_install_program, eos_id=self.tokenizer.eos_id),
-            donate_argnums=(0,),
-        )
-        if self.spec:
-            self._step = jax.jit(
-                named_partial(_spec_step_program, eos_id=self.tokenizer.eos_id,
-                              pad_id=self.tokenizer.pad_id, chunk=self.chunk,
-                              spec_tokens=self.spec, draft_fn=self._draft_fn,
-                              **statics),
-                donate_argnums=(1,),
-            )
-        else:
-            self._step = jax.jit(
-                named_partial(_step_program, eos_id=self.tokenizer.eos_id,
-                              pad_id=self.tokenizer.pad_id, chunk=self.chunk,
-                              **statics),
-                donate_argnums=(1,),
-            )
-        # K>=2 rungs dispatch through the megastep program (K=1 stays on
-        # _step — except under fused admission, where EVERY rung including
-        # K=1 dispatches through the megastep so the in-scan prefill
-        # phase always runs); the K axis rides in on the stacked rng
-        # shape, so each warmed rung is one compiled program per width.
-        # Created even when the ladder is [1] (zero warmed programs
-        # sequential-mode) so the inventory guard sees one stable program
-        # set.
+        # The live SlotState is donated on every program that replaces it,
+        # so admissions and steps update the multi-slot KV cache in place
+        # instead of copying it (a full cache round-trip of HBM traffic
+        # otherwise). The ONE decode program: every rung of the ladder,
+        # K=1 included, dispatches through it; the K axis rides in on the
+        # stacked rng shape, so each warmed rung is one compiled program
+        # per width.
         self._megastep = jax.jit(
             named_partial(
                 _megastep_program, eos_id=self.tokenizer.eos_id,
@@ -1317,9 +1099,6 @@ class PagedEngine:
                 draft_fn=self._draft_fn, **statics),
             donate_argnums=(1,),
         )
-        # Fused staged admission programs (zero warmed programs when
-        # `prefill_chunk_tokens` is 0 — same stable-program-set precedent
-        # as _megastep). `_stage` donates the live state like _install;
         # `_stage_block` donates ONLY the state accumulator, never the
         # shared tree block.
         self._stage = jax.jit(
@@ -1336,7 +1115,7 @@ class PagedEngine:
         # Bulk-scoring program (engine/scoring.py): the background
         # tenant's full-sequence forward. Zero warmed programs when
         # `config.scoring` is off (the stable-program-set precedent of
-        # _megastep/_stage).
+        # the block programs).
         self._score = jax.jit(
             named_partial(_score_program, cfg=self.cfg, model=self.family)
         )
@@ -1351,27 +1130,24 @@ class PagedEngine:
         self.state = self._init_state()
         self._slot_req: List[Optional[_Request]] = [None] * self.slots
         self._pending: List[_Request] = []
-        # Dispatched-but-unread (mega)step programs, oldest first:
-        # (tokens device array — [chunk, S] plain / [chunk, S, k+1] spec,
-        #  with a leading K axis ([K, chunk, S(, k+1)]) when the dispatch
-        #  was a megastep,
-        #  counts [(K,) chunk, S] device array in spec mode else None,
-        #  active int8 device array — [S] post-chunk flags, or [K, S]
-        #  per-chunk snapshots for a megastep (the reap flattens the K
-        #  axis and keys dead-slot detection off the FINAL snapshot),
-        #  dead-lane scalar device array for a megastep else None,
-        #  flipped / firsts [K, chunk, S] bool / int32 fused-admission
-        #  planes (None without fused prefill),
+        # Dispatched-but-unread megasteps, oldest first:
+        # (tokens device array — [K, chunk, S] plain / [K, chunk, S, k+1]
+        #  spec,
+        #  counts [K, chunk, S] device array in spec mode else None,
+        #  active int8 device array — [K, S] per-chunk snapshots (the
+        #  reap flattens the K axis and keys dead-slot detection off the
+        #  FINAL snapshot),
+        #  dead-lane scalar device array,
+        #  flipped / firsts [K, chunk, S] bool / int32 admission planes,
         #  slot->request snapshot at dispatch time,
         #  a routed family's counts int32 [3], else None).
         # Every device entry is a fresh non-donated buffer (see
-        # _step_program's snapshot note), so chunk-loop and megastep
-        # dispatches pipeline under the same donation invariants.
+        # _step_program's snapshot note), so dispatches pipeline under
+        # the donation invariants.
         self._inflight: List[
             Tuple[jax.Array, Optional[jax.Array], jax.Array,
-                  Optional[jax.Array], Optional[jax.Array],
-                  Optional[jax.Array], List[Optional[_Request]],
-                  Optional[jax.Array]]
+                  jax.Array, jax.Array, jax.Array,
+                  List[Optional[_Request]], Optional[jax.Array]]
         ] = []
         self._next_rid = 0
         self.last_ttft_s: Optional[float] = None
@@ -1400,8 +1176,8 @@ class PagedEngine:
         self.total_generated_tokens = 0
         # Megastep efficiency accounting, drained by pop_dispatch_stats():
         # program dispatches the host issued (every `engine.prog.*` span,
-        # counted by `_progs`), tokens emitted to requests (admission
-        # first tokens + reaped stream tokens), and pad lanes burnt by
+        # counted by `_progs`), tokens emitted to requests (first
+        # tokens + reaped stream tokens), and pad lanes burnt by
         # slots that finished inside a megastep (the on-device `dead`
         # account). dispatches/tokens is the host-round-trips-per-token
         # ratio the megastep exists to shrink.
@@ -1429,19 +1205,8 @@ class PagedEngine:
         self._prefix_hit_tokens = 0
         self._prefix_prompt_tokens = 0
         self._prefix_evictions = 0
-        # Admission-stall accounting (the fused-prefill before/after
-        # number, drained by pop_dispatch_stats): host wall seconds the
-        # decode train spent blocked on sequential admission work
-        # (prefill/partial-prefill dispatches + the first-token sync)
-        # while live slots waited, and the proxy token count those slots
-        # would have decoded meanwhile (live slots x chunk per blocking
-        # admission). Both stay 0 by construction under fused staged
-        # admission — staging is one async dispatch and the prefill
-        # chunks ride the scan iterations.
-        self._prefill_stall_s = 0.0
-        self._decode_stalled_tokens = 0
         # rid -> prompt token list for STAGED requests (req.tokens is
-        # replaced by the generated stream at flip-reap; the fused
+        # replaced by the generated stream at flip-reap; the
         # publish into the radix tree still needs the prompt ids).
         self._staged_prompts: Dict[int, List[int]] = {}
         # Monotonic staging sequence (FIFO service order for the in-scan
@@ -1484,28 +1249,18 @@ class PagedEngine:
         self._counts, self._obs = {}, {}
         return out
 
-    def pop_dispatch_stats(self) -> Tuple[int, int, int, float, int]:
-        """Drain (host_dispatches, emitted_tokens, dead_lane_tokens,
-        prefill_stall_ms, decode_stalled_tokens) accumulated since the
-        last call. dispatches/tokens is the host round trips paid per
-        emitted token — the megastep's target ratio; dead_lane_tokens
-        counts pad lanes already-finished slots decoded inside megasteps
-        before the boundary let the host reap them (zero in chunk-loop
-        mode); prefill_stall_ms is the host wall the decode train spent
-        blocked on sequential admission while live slots waited, and
-        decode_stalled_tokens the proxy tokens those slots would have
-        decoded meanwhile (live slots x chunk per blocking admission —
-        both 0 by construction under fused staged admission). The
+    def pop_dispatch_stats(self) -> Tuple[int, int, int]:
+        """Drain (host_dispatches, emitted_tokens, dead_lane_tokens)
+        accumulated since the last call. dispatches/tokens is the host
+        round trips paid per emitted token — the megastep's target ratio;
+        dead_lane_tokens counts pad lanes already-finished slots decoded
+        inside megasteps before the boundary let the host reap them. The
         serving queue turns these into the `host_dispatches_per_token`
-        gauge and the `megastep_dead_lane_tokens`/`prefill_stall_ms`/
-        `decode_stalled_tokens` counters."""
+        gauge and the `megastep_dead_lane_tokens` counter."""
         out = (self._progs.dispatches, self._emitted_tokens,
-               self._dead_lane_tokens, self._prefill_stall_s * 1000.0,
-               self._decode_stalled_tokens)
+               self._dead_lane_tokens)
         self._progs.dispatches = 0
         self._emitted_tokens = self._dead_lane_tokens = 0
-        self._prefill_stall_s = 0.0
-        self._decode_stalled_tokens = 0
         return out
 
     def pop_prefix_stats(self) -> Optional[Tuple[int, int, int, int]]:
@@ -1529,7 +1284,7 @@ class PagedEngine:
 
     def pop_prefix_hits(self) -> Dict[int, int]:
         """Drain rid -> shared-prefix tokens spliced at that request's
-        admission (0 = cold prefill). Feeds the per-request
+        admission (0 = the whole prompt prefilled). Feeds the per-request
         `engine.prefill` span attributes on the trace."""
         out, self._prefix_hits = self._prefix_hits, {}
         return out
@@ -1568,7 +1323,7 @@ class PagedEngine:
         # Plane-table mesh shardings from birth, in the canonical
         # spelling: raw single-device arrays would key the jit caches
         # differently than the programs' own (pinned) outputs, so the
-        # first install/step after a rebuild would silently recompile
+        # first stage/megastep after a rebuild would silently recompile
         # (see _plane_spec). KV planes are born tp-sharded over their
         # heads axis; host-state planes replicated.
         return self._canon_state(_fresh_state(
@@ -1630,120 +1385,69 @@ class PagedEngine:
 
     def warmup(self) -> float:
         """Compile the serving program set so no live request pays an XLA
-        compile: the step program at every cache width, the megastep
-        program at every (cache width, ladder rung K>=2) pair, each prompt
-        bucket's prefill, every admissible (prompt bucket, cache width)
-        install pair (a short prompt can join a batch running at any wider
-        width), every width-growth transition, and — with the
-        shared-prefix cache enabled — the block export/load programs per
-        bucket plus every admissible (bucket, suffix-bucket) partial
-        prefill.
-
-        Fused staged admission replaces the sequential admission set:
-        warmup compiles `_stage` at every admissible (bucket, width)
-        pair, the megastep at every (width, rung) pair INCLUDING rung 1
-        (fused dispatch always goes through the megastep so the prefill
-        phase runs), and — with the shared-prefix cache — the
-        state-export and `_stage_block` splice per width; the sequential
-        prefill/install/partial/load programs compile zero entries.
-        Returns seconds."""
+        compile: `_stage` at every admissible (prompt bucket, cache width)
+        pair (a short prompt can join a batch running at any wider
+        width), the megastep at every (cache width, ladder rung) pair,
+        rung 1 included, every width-growth transition, the scoring
+        domain, and — with the shared-prefix cache enabled — the block
+        export and the `_stage_block` splice per width. Returns seconds."""
         t0 = time.monotonic()
         buckets = self.buckets
         for width in self.widths:
             self.state = self._init_state(width)
             for t in buckets:
-                nat = (cfg_tmax(self.cfg, self.config.sampling, t)
-                       + self._spec_extra)
-                if nat > width:
+                if self._required_width(t) > width:
                     continue  # a prompt this long can't run at this width
                 ids = np.full((1, t), self.tokenizer.pad_id, np.int32)
                 self._rng, rng = jax.random.split(self._rng)
-                # Canon before the admission dispatch exactly as the live
-                # paths do (_admit/_stage_admissions) so warmup and live
-                # traffic key the stage/install programs identically.
+                # Canon before the dispatch exactly as the live path does
+                # (_stage_admissions) so warmup and live traffic key the
+                # stage program identically.
                 self.state = self._canon_state(self.state)
-                if self.fused:
-                    with self.mesh:
-                        # Operands as `_stage_admissions` hands them
-                        # over (numpy but for the cached slot scalar):
-                        # host and device operands key the program apart.
-                        self.state = self._stage(
-                            self.state, self._i32(0), ids, np.int32(1),
-                            np.int32(0), np.int32(0),
-                            jax.random.key_data(rng),
-                        )
-                    continue
                 with self.mesh:
-                    c1, first, seen_row, *_ = self._prefill(
-                        self.params, jnp.asarray(ids),
-                        jnp.asarray(1, jnp.int32), rng,
+                    # Operands as `_stage_admissions` hands them over
+                    # (numpy but for the cached slot scalar): host and
+                    # device operands key the program apart.
+                    self.state = self._stage(
+                        self.state, self._i32(0), ids, np.int32(1),
+                        np.int32(0), np.int32(0),
+                        jax.random.key_data(rng),
                     )
-                    self.state = self._install(
-                        self.state, jnp.asarray(0, jnp.int32), c1,
-                        jnp.asarray(ids), jnp.asarray(1, jnp.int32),
-                        first, seen_row,
-                    )
-            if self.fused:
-                # Every rung dispatches through the megastep when fused
-                # (rung 1 included); the first dispatch consumes the
-                # post-stage state — the exact live stage->megastep
-                # handoff — and lax.cond compiles both admission branches
-                # regardless of the runtime staged flag.
-                for k in self.megastep_ks:
-                    rngs = self._step_keys(k)
-                    self.state = self._canon_state(self.state)
-                    with self.mesh:
-                        self.state = self._megastep(
-                            self.params, self.state, rngs
-                        )[0]
-                if self.prefix_cache is not None and any(
-                    t >= self.prefix_block_tokens for t in buckets
-                ):
-                    # Fused shared-prefix programs per width: publish
-                    # slices blocks straight out of the live state,
-                    # staging splices them straight back in. Canon first
-                    # — the live path (_publish_staged/_stage_admissions)
-                    # exports and splices from a canonical state.
-                    self.state = self._canon_state(self.state)
-                    with self.mesh:
-                        blk = self._canon_block(self._export_block(
-                            self.state.cache, jnp.asarray(0, jnp.int32),
-                            jnp.asarray(0, jnp.int32),
-                        ))
-                        self.state = self._stage_block(
-                            self.state, blk, jnp.asarray(0, jnp.int32),
-                            jnp.asarray(0, jnp.int32),
-                        )
-                        if any(
-                            bucket_has_runs(
-                                t, self.prefix_block_tokens, width)
-                            and self._required_width(t) <= width
-                            for t in buckets
-                        ):
-                            self.state = self._stage_block(
-                                self.state, (blk,) * STAGE_RUN_BLOCKS,
-                                jnp.asarray(0, jnp.int32),
-                                jnp.asarray(0, jnp.int32),
-                                jnp.asarray(0, jnp.int32),
-                            )
-                continue
-            # Step AFTER an install so the compile covers the live
-            # install->step handoff (the state the step really sees);
-            # stepping a raw _init_state would key the cache differently.
-            self._rng, rng = jax.random.split(self._rng)
-            self.state = self._canon_state(self.state)
-            with self.mesh:
-                self.state = self._step(self.params, self.state, rng)[0]
-            # Megastep rungs at this width, fed the post-step state the
-            # live controller hands them (same handoff-coverage argument
-            # as stepping after an install above).
-            for k in self.megastep_ks[1:]:
+            # The first dispatch consumes the post-stage state — the
+            # exact live stage->megastep handoff — and lax.cond compiles
+            # both admission branches regardless of the runtime staged
+            # flag.
+            for k in self.megastep_ks:
                 rngs = self._step_keys(k)
                 self.state = self._canon_state(self.state)
                 with self.mesh:
                     self.state = self._megastep(
                         self.params, self.state, rngs
                     )[0]
+            if self.prefix_cache is not None and any(
+                t >= self.prefix_block_tokens for t in buckets
+            ):
+                # Shared-prefix programs per width: publish slices blocks
+                # straight out of the live state, staging splices them
+                # straight back in. Canon first — the live path
+                # (_publish_staged/_stage_admissions) exports and splices
+                # from a canonical state.
+                self.state = self._canon_state(self.state)
+                zero = self._i32(0)
+                with self.mesh:
+                    blk = self._canon_block(self._export_block(
+                        self.state.cache, zero, zero))
+                    self.state = self._stage_block(
+                        self.state, blk, zero, zero)
+                    if any(
+                        bucket_has_runs(
+                            t, self.prefix_block_tokens, width)
+                        and self._required_width(t) <= width
+                        for t in buckets
+                    ):
+                        self.state = self._stage_block(
+                            self.state, (blk,) * STAGE_RUN_BLOCKS,
+                            zero, zero, zero)
         for i, wa in enumerate(self.widths):
             for wb in self.widths[i + 1:]:
                 throwaway = self._init_state(wa)
@@ -1753,47 +1457,7 @@ class PagedEngine:
         # program per (batch bucket, length bucket) shape, so the first
         # bulk job a quantum dispatches pays zero live XLA compiles.
         self._warm_score()
-        if self.prefix_cache is not None and not self.fused:
-            # Shared-prefix program domain: one export/load program per
-            # prompt bucket wide enough to hold a block, one partial
-            # prefill per admissible (bucket, suffix-bucket) pair —
-            # plan_partial can only pick a suffix bucket that leaves at
-            # least one whole block of prefix in the window. Dynamic
-            # scalars (offsets, lengths) don't key programs, so pad
-            # prompts with throwaway values cover the full live domain.
-            blk_t = self.prefix_block_tokens
-            for t in buckets:
-                if t < blk_t:
-                    continue  # bucket can't hold one block
-                ids = np.full((1, t), self.tokenizer.pad_id, np.int32)
-                self._rng, rng = jax.random.split(self._rng)
-                with self.mesh:
-                    c1, *_ = self._prefill(
-                        self.params, jnp.asarray(ids),
-                        jnp.asarray(1, jnp.int32), rng,
-                    )
-                    blk = self._canon_block(self._export_block(
-                        c1, jnp.asarray(0, jnp.int32),
-                        jnp.asarray(0, jnp.int32),
-                    ))
-                for s in buckets:
-                    if s > t - blk_t:
-                        continue
-                    ids_suf = np.full((1, s), self.tokenizer.pad_id,
-                                      np.int32)
-                    self._rng, rng = jax.random.split(self._rng)
-                    cache0 = self._fresh_prefill_cache(t)
-                    with self.mesh:
-                        cache0 = self._load_block(
-                            cache0, blk, jnp.asarray(0, jnp.int32)
-                        )
-                        self._partial_prefill(
-                            self.params, cache0, jnp.asarray(ids),
-                            jnp.asarray(ids_suf),
-                            jnp.asarray(blk_t, jnp.int32),
-                            jnp.asarray(blk_t + 1, jnp.int32), rng,
-                        )
-        self.reset()  # drop the ghost installs; compiled programs stay cached
+        self.reset()  # drop the ghost stagings; compiled programs stay cached
         rid = self.submit("warmup")
         self.drain()
         self.ttfts.pop(rid, None)
@@ -1916,10 +1580,11 @@ class PagedEngine:
     def reset(self) -> None:
         """Discard all in-flight work and rebuild a clean device state.
 
-        Needed after a failed step: `_step` donates the live SlotState, so an
-        exception mid-step can leave `self.state` pointing at deleted
-        buffers — every subsequent step would fail. Callers (the serving
-        queue) fail the affected requests and reset the engine.
+        Needed after a failed step: the megastep donates the live
+        SlotState, so an exception mid-step can leave `self.state`
+        pointing at deleted buffers — every subsequent step would fail.
+        Callers (the serving queue) fail the affected requests and reset
+        the engine.
         """
         self.state = self._init_state()
         self._slot_req = [None] * self.slots
@@ -1961,17 +1626,16 @@ class PagedEngine:
     def _pop_next(self) -> Tuple[_Request, int, int, np.ndarray]:
         """Take the oldest pending request: record its queue wait, pick
         its prompt bucket and required cache width, and build the
-        right-padded [1, bucket] id plane both admission paths feed the
-        device."""
+        right-padded [1, bucket] id plane `_stage` writes into the slot's
+        transcript row."""
         req = self._pending.pop(0)
         req.popped_time = time.monotonic()
         wait = self._queue_waits[req.rid] = req.popped_time - req.submit_time
         self._observe("queue_wait", wait)
         self._shed_oldest(self._queue_waits)
-        # Smallest length bucket that fits: a 10-token query prefills a
-        # 16/32-wide program, not the full Tmax-wide one (one compiled
-        # prefill per bucket; the decode cache runs at the width the
-        # widest active request needs).
+        # Smallest length bucket that fits: a 10-token query asks for a
+        # 16/32-token bucket's cache width, not the full Tmax (the decode
+        # cache runs at the width the widest active request needs).
         bucket = min(
             pick_bucket(req.prompt_len, self.config.length_buckets),
             self.bucket,
@@ -1989,66 +1653,9 @@ class PagedEngine:
             with self._span(PROG + "grow"):
                 self.state = self._grow(self.state, w_req)
 
-    def _admit(self) -> None:
-        # All free slots fill before any host sync: the prefill+install
-        # programs for every admitted request dispatch back-to-back and
-        # pipeline on device; one blocking readback at the end fetches every
-        # first token (instead of a per-request round-trip stall).
-        self._maybe_rebuild_idle()
-        # The stall this admission path charges itself for: while live
-        # slots sit mid-decode, every prefill program and the first-token
-        # sync below occupy the device/host instead of decode chunks —
-        # the number fused staged admission drives to zero.
-        live_train = sum(
-            1 for r in self._slot_req
-            if r is not None and not r.finished and r.live
-        )
-        t_admit0 = time.monotonic()
-        admitted: List[Tuple[int, _Request, jax.Array]] = []
-        for slot in range(self.slots):
-            if self._slot_req[slot] is not None or not self._pending:
-                continue
-            req, bucket, w_req, ids = self._pop_next()
-            self._rng, rng = jax.random.split(self._rng)
-            # Canon before the admission dispatches for the same reason
-            # step() canons: grow/install input shardings must match the
-            # warmed programs' keys whatever spelling the previous
-            # program's outputs propagated (zero-copy when already
-            # canonical — the steady state).
-            self.state = self._canon_state(self.state)
-            with self.mesh:
-                self._grow_if_needed(w_req)
-                c1, first, seen_row, *moe = self._run_prefill(
-                    req, bucket, ids, rng
-                )
-                with self._span(PROG + "install"):
-                    self.state = self._install(
-                        self.state, jnp.asarray(slot, jnp.int32), c1,
-                        jnp.asarray(ids),
-                        jnp.asarray(req.prompt_len, jnp.int32),
-                        first, seen_row,
-                    )
-            admitted.append((slot, req, (first, *moe)))
-        if not admitted:
-            return
-        with intended_transfer():  # ONE sync for the whole admitted group
-            firsts = jax.device_get([f for _, _, f in admitted])
-        now = time.monotonic()
-        if live_train:
-            self._prefill_stall_s += now - t_admit0
-            self._decode_stalled_tokens += (
-                live_train * self.chunk * len(admitted)
-            )
-        for (slot, req, _), (first, *moe) in zip(admitted, firsts):
-            for counts in moe:
-                self._count_moe(counts)
-            req.tokens = [int(first)]
-            self._slot_req[slot] = req
-            self._first_token(req, now)
-
     def _stage_admissions(self) -> None:
-        """Fused admission: hand every admissible pending request to the
-        device as a STAGED slot — prompt ids into the transcript row,
+        """Hand every admissible pending request to the device as a
+        STAGED slot — prompt ids into the transcript row,
         shared-prefix blocks spliced straight into the slot's pages, the
         staged-admission plane armed — with zero blocking work. The
         prefill itself advances inside the megastep scan
@@ -2091,9 +1698,11 @@ class PagedEngine:
                     self._prefix_pins[req.rid] = match
                 self._staged_prompts[req.rid] = list(req.tokens)
             self._note_admission(req, cursor0)
-            # Same canon-before-dispatch discipline as _admit: the
-            # grow/stage_block/stage programs key on the warmed input
-            # shardings.
+            # Canon before the dispatches for the same reason
+            # _dispatch canons: the grow/stage_block/stage programs key on
+            # the warmed input shardings, whatever spelling the previous
+            # program's outputs propagated (zero-copy when already
+            # canonical — the steady state).
             self.state = self._canon_state(self.state)
             with self.mesh:
                 self._grow_if_needed(w_req)
@@ -2144,88 +1753,6 @@ class PagedEngine:
         return (cfg_tmax(self.cfg, self.config.sampling, bucket)
                 + self._spec_extra)
 
-    def _fresh_prefill_cache(self, width: int) -> KVCache:
-        """A zeroed single-slot prompt cache for the block splice, born
-        under the plane table's shardings (same reasoning as _init_state:
-        raw single-device arrays would key the splice and partial-prefill
-        programs differently than warmup's). Its KV planes use the bare
-        plane names — the single-slot [L, 1, Hkv, T, Dh] layout keeps
-        heads at axis 2, so they share the slot cache's tp spec."""
-        cache = self.family.init_cache(
-            self.cfg, 1, width, dtype=self.cfg.dtype
-        )
-
-        def put(x, name):
-            return jax.device_put(x, jax.sharding.NamedSharding(
-                self.mesh, _plane_spec(name)
-            ))
-
-        return cache._replace(
-            k=put(cache.k, "k"),
-            v=put(cache.v, "v"),
-            ks=None if cache.ks is None else put(cache.ks, "ks"),
-            vs=None if cache.vs is None else put(cache.vs, "vs"),
-            length=put(cache.length, "length"),
-        )
-
-    def _run_prefill(self, req: _Request, bucket: int, ids: np.ndarray,
-                     rng: jax.Array):
-        """One request's prompt into a [1, bucket]-wide cache: a cold
-        full prefill, or — on a shared-prefix cache hit — the cached
-        block runs spliced into a fresh cache plus a partial prefill
-        over only the uncached suffix. Either way the completed prompt's
-        blocks are published back into the tree (a cold miss is what
-        seeds the course context the next request hits), the matched
-        path stays ref-count-pinned until the request finishes, and the
-        caller receives the `_install` contract (c1, first, seen_row),
-        followed by a routed family's counts.
-        Runs under `self.mesh`; consumes the caller's rng split, so a
-        hit samples the bit-identical first token a cold prefill would.
-        """
-        pc = self.prefix_cache
-        prefix_used = suffix_bucket = 0
-        match: Optional[Match] = None
-        if pc is not None:
-            match = pc.lookup(req.tokens)
-            if match.tokens:
-                prefix_used, suffix_bucket = plan_partial(
-                    match.tokens, req.prompt_len, bucket, self.buckets,
-                    pc.block_tokens,
-                )
-        if prefix_used:
-            pc.acquire(match)
-            self._prefix_pins[req.rid] = match
-            blocks = match.blocks()[: prefix_used // pc.block_tokens]
-            cache0 = self._fresh_prefill_cache(bucket)
-            for i, blk in enumerate(blocks):
-                with self._span(PROG + "load_block"):
-                    cache0 = self._load_block(
-                        cache0, blk,
-                        jnp.asarray(i * pc.block_tokens, jnp.int32),
-                    )
-            ids_suf = np.full((1, suffix_bucket), self.tokenizer.pad_id,
-                              np.int32)
-            ids_suf[0, : req.prompt_len - prefix_used] = (
-                req.tokens[prefix_used:]
-            )
-            with self._span(PROG + "partial_prefill"):
-                c1, first, seen_row, *moe = self._partial_prefill(
-                    self.params, cache0, jnp.asarray(ids),
-                    jnp.asarray(ids_suf),
-                    jnp.asarray(prefix_used, jnp.int32),
-                    jnp.asarray(req.prompt_len, jnp.int32), rng,
-                )
-        else:
-            with self._span(PROG + "prefill"):
-                c1, first, seen_row, *moe = self._prefill(
-                    self.params, jnp.asarray(ids),
-                    jnp.asarray(req.prompt_len, jnp.int32), rng,
-                )
-        if pc is not None:
-            self._publish(req, c1)
-        self._note_admission(req, prefix_used)
-        return (c1, first, seen_row, *moe)
-
     def _note_admission(self, req: _Request, hit: int) -> None:
         """Count one admitted prompt and the shared-prefix hit it had."""
         self._count(prompt_tokens=req.prompt_len,
@@ -2235,19 +1762,6 @@ class PagedEngine:
             self._prefix_prompt_tokens += req.prompt_len
             self._prefix_hits[req.rid] = hit
             self._shed_oldest(self._prefix_hits)
-
-    def _publish(self, req: _Request, c1: KVCache) -> None:
-        """Publish the completed prefill's whole prompt blocks into the
-        radix tree — immutable copies sliced out of c1, inserted only
-        for blocks the tree does not already hold — then enforce the
-        block budget (after insert, so a publish can never evict blocks
-        its own admission still references; pinned paths are never
-        evicted regardless)."""
-        blk_t = self.prefix_cache.block_tokens
-        self._insert_blocks(
-            req.tokens[: (req.prompt_len // blk_t) * blk_t], c1, 0
-        )
-        self._prefix_evictions += self.prefix_cache.evict_to_budget()
 
     def _insert_blocks(self, tokens: List[int], cache: KVCache,
                        slot: int) -> None:
@@ -2266,13 +1780,16 @@ class PagedEngine:
         self.prefix_cache.insert(tokens, make_block)
 
     def _publish_staged(self, req: _Request, slot: int) -> None:
-        """Fused-admission publish, at flip-reap time: the prompt's KV
-        lives in the slot's pages of the LIVE cache (no standalone
-        admission cache exists), so whole prompt blocks are sliced
-        straight out of `self.state` — fresh copies; safe because decode
-        only ever scatters at positions >= prompt_len and the slot
-        cannot be restaged before this reap returns. Same
-        insert-then-evict policy as the sequential `_publish`."""
+        """Publish a flipped request's whole prompt blocks into the radix
+        tree, at flip-reap time: the prompt's KV lives in the slot's
+        pages of the LIVE cache, so the blocks are sliced straight out of
+        `self.state` — fresh immutable copies, inserted only where the
+        tree does not already hold them; safe because decode only ever
+        scatters at positions >= prompt_len and the slot cannot be
+        restaged before this reap returns. The block budget is enforced
+        after the insert, so a publish can never evict blocks its own
+        admission still references (pinned paths are never evicted
+        regardless)."""
         pc = self.prefix_cache
         tokens = self._staged_prompts.pop(req.rid, None)
         if tokens is None:
@@ -2359,7 +1876,7 @@ class PagedEngine:
 
     def _any_staged(self) -> bool:
         """Any slot whose staged prefill is still advancing inside the
-        scan (fused admission) — device work that must keep dispatching
+        scan — device work that must keep dispatching
         even when no slot is live yet."""
         return any(
             r is not None and not r.finished and not r.live
@@ -2367,12 +1884,11 @@ class PagedEngine:
         )
 
     def _step_keys(self, k: int) -> jax.Array:
-        """Stack the next `k` sequential dispatch keys into a [k] key
-        array for a megastep. The host RNG advances exactly as k separate
-        chunk-loop dispatches would have advanced it, so a megastep's
-        chunk j consumes bit-identical randomness to chunk-loop dispatch
-        j (greedy streams are identical by construction; stochastic
-        streams match too whenever the admission interleaving matches)."""
+        """Stack the next `k` sequential host splits into a [k] key
+        array for a megastep, one a chunk: the key chunk j consumes does
+        not depend on how the chunks were grouped into dispatches (greedy
+        streams are identical by construction; stochastic streams match
+        too whenever the admission interleaving matches)."""
         keys = []
         for _ in range(k):
             self._rng, r = jax.random.split(self._rng)
@@ -2386,7 +1902,7 @@ class PagedEngine:
         pipeline depth; the dispatched debt is what closes the gap)."""
         req = self._slot_req[slot]
         debt = sum(
-            (entry[2].shape[0] if entry[2].ndim == 2 else 1) * self.chunk
+            entry[2].shape[0] * self.chunk
             for entry in self._inflight if entry[6][slot] is req
         )
         return rows_to_certain_end(req, self.tmax, debt)
@@ -2456,10 +1972,10 @@ class PagedEngine:
         KV sharding before it enters the radix tree, so every cached
         block is a per-shard device-resident run under ONE sharding: a
         later hit splices tp-sharded blocks straight into the (equally
-        sharded) live pages without a gather, and every `_load_block`/
-        `_stage_block` dispatch sees one canonical block sharding (one
-        jit-cache key). Zero-copy when the export already propagated the
-        table spec — the steady state."""
+        sharded) live pages without a gather, and every `_stage_block`
+        dispatch sees one canonical block sharding (one jit-cache key).
+        Zero-copy when the export already propagated the table spec —
+        the steady state."""
 
         def put(x, name):
             sh = jax.sharding.NamedSharding(self.mesh, _plane_spec(name))
@@ -2473,12 +1989,11 @@ class PagedEngine:
         )
 
     def step(self) -> List[Tuple[int, str]]:
-        """Admit pending requests, dispatch the next decode program —
-        `chunk` tokens at controller K=1, K chunks fused into one megastep
-        dispatch at K>1 — and reap the oldest in-flight dispatch once the
-        pipeline is full.
+        """Stage pending requests, dispatch the next megastep — K chunks
+        of `chunk` tokens, K the controller's — and reap the oldest
+        in-flight dispatch once the pipeline is full.
 
-        Fused admission stages into empty slots and into slots whose
+        Admission stages into empty slots and into slots whose
         request's end is certain to lie in the dispatches in flight
         (`_stage_admissions`): such a slot then carries two requests at
         once, the departing one in the in-flight snapshots until `_walk`
@@ -2499,10 +2014,7 @@ class PagedEngine:
         """
         with self._span("engine.step"):
             with self._span("engine.admit"):
-                if self.fused:
-                    self._stage_admissions()
-                else:
-                    self._admit()
+                self._stage_admissions()
             if self._live() or self._any_staged():
                 # The backlog the controller sizes K against counts a
                 # request until its predecessor's end is reaped, as it
@@ -2514,16 +2026,10 @@ class PagedEngine:
                 self.megastep_k = next_megastep_k(
                     self.megastep_k, self.megastep_ks,
                     len(self._pending) + len(self._departing()),
-                    self._slack_chunks(), fused=self.fused,
+                    self._slack_chunks(),
                 )
-                # Fused admission dispatches through the megastep at
-                # EVERY rung (K=1 included): the scan body carries the
-                # in-scan prefill phase, so staged slots keep advancing
-                # no matter where the controller sits.
-                mega = self.fused or self.megastep_k > 1
-                k = self.megastep_k if mega else 1
-                with self._span("engine.dispatch", k=k):
-                    self._dispatch(k, mega)
+                with self._span("engine.dispatch", k=self.megastep_k):
+                    self._dispatch(self.megastep_k)
             done: List[Tuple[int, str]] = []
             while self._inflight and (
                 len(self._inflight) >= self.inflight_limit
@@ -2536,36 +2042,22 @@ class PagedEngine:
                 # drain right here.
         return done
 
-    def _dispatch(self, k: int, mega: bool) -> None:
-        """Send the device the megastep at rung `k`, or one `_step`."""
+    def _dispatch(self, k: int) -> None:
+        """Send the device the megastep at rung `k`."""
         self.state = self._canon_state(self.state)
-        counts = dead = flipped = firsts = moe = None
-        if mega:
-            rngs = self._step_keys(k)
-            with self.mesh, self._span(PROG + "megastep"):
-                self.state, *outs = self._megastep(
-                    self.params, self.state, rngs
-                )
-            if self.family.routed:
-                *outs, moe = outs
-            if self.fused:
-                *outs, flipped, firsts = outs
-            if self.spec:
-                toks, counts, active, dead = outs
-            else:
-                toks, active, dead = outs
+        counts = moe = None
+        rngs = self._step_keys(k)
+        with self.mesh, self._span(PROG + "megastep"):
+            self.state, *outs = self._megastep(
+                self.params, self.state, rngs
+            )
+        if self.family.routed:
+            *outs, moe = outs
+        *outs, flipped, firsts = outs
+        if self.spec:
+            toks, counts, active, dead = outs
         else:
-            self._rng, rng = jax.random.split(self._rng)
-            with self.mesh, self._span(PROG + "step"):
-                self.state, *outs = self._step(
-                    self.params, self.state, rng
-                )
-            if self.family.routed:
-                *outs, moe = outs
-            if self.spec:
-                toks, counts, active = outs
-            else:
-                toks, active = outs
+            toks, active, dead = outs
         self._count(scan_iterations=k * self.chunk,
                     lane_steps=k * self.chunk * self.slots)
         self._push_inflight(toks, counts, active, dead, flipped, firsts,
@@ -2578,7 +2070,7 @@ class PagedEngine:
         No blocking readback here — but START the device->host copies
         now, so the dispatch's results stream back while later programs
         compute and the reap's device_get finds them already on the
-        host. Fused admission's flipped/firsts planes ([K, chunk, S]) ride the
+        host. The flipped/firsts planes ([K, chunk, S]) ride the
         same pipe, so learning a staged slot went live costs no extra
         sync.
         """
@@ -2601,27 +2093,22 @@ class PagedEngine:
     def _reap(self, toks_dev, counts_dev, active_dev, dead_dev,
               flipped_dev, firsts_dev, slot_snapshot,
               moe_dev=None) -> List[Tuple[int, str]]:
-        """Read one dispatch's results — a single chunk, or a megastep's
-        whole [K, chunk, S] plane in one batched pass — and finish the
-        requests it completed. Under fused admission the same pass also
-        learns which staged slots FLIPPED live mid-megastep (the
-        flipped/firsts planes): the flip's first token becomes the
-        request's stream head (TTFT recorded here — the first host moment
+        """Read one dispatch's results — a megastep's whole [K, chunk, S]
+        plane in one batched pass — and finish the requests it completed.
+        The same pass also learns which staged slots FLIPPED live
+        mid-megastep (the flipped/firsts planes): the flip's first token
+        becomes the request's stream head (TTFT recorded here — the first host moment
         the token exists), its prompt blocks publish into the radix tree
         straight from the live cache, and its decode walk starts at the
         flip's row (earlier rows are pre-flip pad filler, not content)."""
         # THE sync point of the engine loop.
         with self._span("engine.reap.wait") as wait, intended_transfer():
-            toks = np.asarray(toks_dev)  # [(K,) chunk, S(, k+1)]
+            toks = np.asarray(toks_dev)  # [K, chunk, S(, k+1)]
             counts = None if counts_dev is None else np.asarray(counts_dev)
-            # [S] int8 post-chunk flags, or [K, S] per-chunk snapshots
-            active = np.asarray(active_dev)
-            if dead_dev is not None:
-                self._dead_lane_tokens += int(np.asarray(dead_dev))
-            flipped = (None if flipped_dev is None
-                       else np.asarray(flipped_dev))  # [K, chunk, S]
-            firsts = (None if firsts_dev is None
-                      else np.asarray(firsts_dev))    # [K, chunk, S]
+            active = np.asarray(active_dev)  # [K, S] per-chunk snapshots
+            self._dead_lane_tokens += int(np.asarray(dead_dev))
+            flipped = np.asarray(flipped_dev)  # [K, chunk, S]
+            firsts = np.asarray(firsts_dev)    # [K, chunk, S]
             if moe_dev is not None:
                 self._count_moe(np.asarray(moe_dev))
         self._observe("reap_wait", wait.wall_s)
@@ -2632,21 +2119,16 @@ class PagedEngine:
     def _walk(self, toks, counts, active, flipped, firsts,
               slot_snapshot) -> List[Tuple[int, str]]:
         """The host half of a reap: one dispatch's tokens and lanes."""
-        if active.ndim == 2:
-            # Megastep: flatten the K axis into one [K*chunk, S] token
-            # walk (the per-slot scan below is shape-agnostic in its
-            # leading axis). Dead-slot detection keys off the FINAL
-            # snapshot: a slot that died in chunk j padded every later
-            # lane, exactly like a mid-chunk death pads the chunk tail.
-            toks = toks.reshape(toks.shape[0] * toks.shape[1],
-                                *toks.shape[2:])
-            if counts is not None:
-                counts = counts.reshape(-1, counts.shape[-1])
-            if flipped is not None:
-                # [K, chunk, S] -> one row per scan iteration, like toks.
-                flipped = flipped.reshape(-1, flipped.shape[-1])
-                firsts = firsts.reshape(-1, firsts.shape[-1])
-            active = active[-1]
+        # Flatten the K axis into one [K*chunk, S] token walk, a row per
+        # scan iteration. Dead-slot detection keys off the FINAL snapshot:
+        # a slot that died in chunk j padded every later lane, exactly
+        # like a mid-chunk death pads the chunk tail.
+        toks = toks.reshape(toks.shape[0] * toks.shape[1], *toks.shape[2:])
+        if counts is not None:
+            counts = counts.reshape(-1, counts.shape[-1])
+        flipped = flipped.reshape(-1, flipped.shape[-1])
+        firsts = firsts.reshape(-1, firsts.shape[-1])
+        active = active[-1]
         done: List[Tuple[int, str]] = []
         eos, pad = self.tokenizer.eos_id, self.tokenizer.pad_id
         now = time.monotonic()
@@ -2665,8 +2147,7 @@ class PagedEngine:
                 # meaningful. No flip yet -> the prefill is still
                 # advancing; the column is pad filler and the slot's
                 # inactive flag must NOT read as a death.
-                col = (np.zeros((rows,), bool) if flipped is None
-                       else flipped[:, slot])
+                col = flipped[:, slot]
                 if not col.any():
                     staged += rows
                     req.staged_rows += rows

@@ -7,7 +7,7 @@ prefill became the dominant per-request device cost under same-course
 traffic. This module is the sharing machinery: a radix tree over
 token-id sequences whose nodes own immutable, device-resident KV block
 runs, so a prompt whose prefix was prefilled by an earlier request
-splices those blocks into its slot and runs a *partial* prefill over
+splices those blocks into its slot and prefills
 only the uncached suffix (the RadixAttention idea from SGLang, over
 vLLM-style fixed-size KV blocks, mapped onto the paged engine's
 contiguous right-padded slot layout).
@@ -18,12 +18,12 @@ Design facts, each load-bearing:
   consecutive token ids; nodes store exact block-aligned KV runs
   ([L, 1, H, B, Dh] per block, plus int8 scale planes when kv-quant).
   Block alignment is what keeps the device programs' shapes static:
-  the engine's `_load_block`/`_export_block` programs compile once per
-  prompt bucket, never per prefix length.
+  the engine's `_stage_block`/`_export_block` programs compile once per
+  cache width, never per prefix length.
 - **Immutability.** Tree-owned arrays are never donated and never
-  written: the splice (`dynamic_update_slice` into a fresh
-  prompt-bucket cache) READS them, the publish slices fresh copies OUT
-  of a completed prefill's cache. The donation-safety and pspec-flow
+  written: the splice (`dynamic_update_slice` into the slot's pages of
+  the live cache) READS them, the publish slices fresh copies OUT
+  of a flipped slot's pages. The donation-safety and pspec-flow
   lint rules sweep this module with the rest of `engine/`;
   `tests/test_lint_clean.py` pins that donating a shared block plane
   fails lint.
@@ -109,45 +109,12 @@ class Match:
         return out
 
 
-def plan_partial(
-    hit_tokens: int,
-    true_len: int,
-    bucket: int,
-    buckets: Sequence[int],
-    block_tokens: int,
-) -> Tuple[int, int]:
-    """Fit a cache hit into the engine's static program domain: returns
-    (prefix_used, suffix_bucket) with prefix_used a positive multiple of
-    `block_tokens` and `prefix_used + suffix_bucket <= bucket`, or
-    (0, 0) when no suffix bucket admits a usable prefix (cold prefill).
-
-    The suffix MUST cover `true_len - prefix_used` real tokens and the
-    spliced window must stay inside the prompt-bucket-wide cache, so a
-    long hit against a small remaining window gives back blocks (they
-    are recomputed inside the suffix forward) rather than overrunning —
-    the same silent-clamp corruption `PagedEngine.__init__` guards
-    against for decode. Smallest admissible suffix bucket wins: it
-    minimizes the partial-prefill compute, which is the entire point.
-
-    At least one real suffix token is always recomputed (prefix_used is
-    capped at `true_len - 1`): the first sampled token needs the
-    prompt's last-position logits, which the cache does not store.
-    """
-    for s in sorted(b for b in buckets if b <= bucket):
-        p = min(hit_tokens, bucket - s, true_len - 1)
-        p -= p % block_tokens
-        if p > 0 and true_len - p <= s:
-            return p, s
-    return 0, 0
-
-
 def plan_staged(hit_tokens: int, true_len: int, block_tokens: int) -> int:
-    """Fit a cache hit into FUSED staged admission: returns the prefix
+    """Fit a cache hit into staged admission: returns the prefix
     length to splice (a multiple of `block_tokens`; 0 = cold staging).
 
-    Staged admission has no suffix-bucket program to fit — the uncached
-    suffix is chunked through the megastep scan at any length — so the
-    only constraints left from `plan_partial` are block alignment and
+    The uncached suffix is chunked through the megastep scan at any
+    length, so the only constraints are block alignment and
     the >= 1 recomputed token rule (the last prompt position's logits
     seed the first sampled token; the cache does not store them). The
     spliced prefix simply moves the staged cursor forward: fewer prefill

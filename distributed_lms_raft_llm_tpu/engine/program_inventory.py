@@ -61,7 +61,7 @@ INVENTORY: Tuple[ProgramEntry, ...] = (
     ProgramEntry(
         engine="PagedEngine", attr="_export_block", target="_export_block_program",
         donate_argnums=(), static_argnums=(),
-        domain="export-buckets", coverage="warmup",
+        domain="export-widths", coverage="warmup",
     ),
     ProgramEntry(
         engine="PagedEngine", attr="_grow", target="_grow_state_program",
@@ -69,29 +69,9 @@ INVENTORY: Tuple[ProgramEntry, ...] = (
         domain="width-pairs", coverage="warmup",
     ),
     ProgramEntry(
-        engine="PagedEngine", attr="_install", target="_install_program",
-        donate_argnums=(0,), static_argnums=(),
-        domain="bucket-width-pairs", coverage="warmup",
-    ),
-    ProgramEntry(
-        engine="PagedEngine", attr="_load_block", target="_load_block_program",
-        donate_argnums=(0,), static_argnums=(),
-        domain="load-buckets", coverage="warmup",
-    ),
-    ProgramEntry(
         engine="PagedEngine", attr="_megastep", target="_megastep_program",
         donate_argnums=(1,), static_argnums=(),
         domain="width-k-pairs", coverage="warmup",
-    ),
-    ProgramEntry(
-        engine="PagedEngine", attr="_partial_prefill", target="_partial_prefill_program",
-        donate_argnums=(1,), static_argnums=(),
-        domain="bucket-suffix-pairs", coverage="warmup",
-    ),
-    ProgramEntry(
-        engine="PagedEngine", attr="_prefill", target="_prefill_program",
-        donate_argnums=(), static_argnums=(),
-        domain="buckets", coverage="warmup",
     ),
     ProgramEntry(
         engine="PagedEngine", attr="_score", target="_score_program",
@@ -107,16 +87,6 @@ INVENTORY: Tuple[ProgramEntry, ...] = (
         engine="PagedEngine", attr="_stage_block", target="_stage_block_program",
         donate_argnums=(0,), static_argnums=(),
         domain="stage-widths", coverage="warmup",
-    ),
-    ProgramEntry(
-        engine="PagedEngine", attr="_step", target="_spec_step_program",
-        donate_argnums=(1,), static_argnums=(),
-        domain="widths", coverage="warmup",
-    ),
-    ProgramEntry(
-        engine="PagedEngine", attr="_step", target="_step_program",
-        donate_argnums=(1,), static_argnums=(),
-        domain="widths", coverage="warmup",
     ),
     ProgramEntry(
         engine="RelevanceGate", attr="_embed", target="bert.embed",
@@ -168,7 +138,7 @@ def megastep_ladder(megastep_max: int) -> List[int]:
     step) plus doubling rungs up to `megastep_max`, which is always the
     top rung even when it is not a power of two (e.g. 6 -> [1, 2, 4, 6]).
     Pure math shared by `PagedEngine.__init__` (which warms one megastep
-    program per (width, rung>=2) pair) and `static_paged_domain` (which
+    program per (width, rung) pair) and `static_paged_domain` (which
     predicts that count for the runtime guard) — the same mirror-and-
     cross-check contract as the bucket/width derivation."""
     out = [1]
@@ -230,13 +200,12 @@ def static_paged_domain(
     megastep_max: int = 0,
     prefix_cache: bool = False,
     prefix_block_tokens: int = 16,
-    fused_prefill: bool = False,
     tp: int = 1,
     ep: int = 1,
 ) -> Dict[str, object]:
     """The paged engine's static-argument domain, derived from config the
     same way `PagedEngine.__init__` derives it: warmed prompt buckets,
-    cache widths, and the admissible (bucket, width) install pairs.
+    cache widths, and the admissible (bucket, width) stage pairs.
 
     Pure math over config values — `scripts/gen_program_inventory.py` runs
     it over config.py defaults and every `configs/*.toml` for the README
@@ -253,15 +222,11 @@ def static_paged_domain(
     mesh axes against this key so a manifest generated for one mesh
     cannot silently vouch for another.
 
-    `fused_prefill` (prefill_chunk_tokens > 0) swaps the admission
-    program family: the sequential prefill/install/partial/load set
-    compiles ZERO entries (admission is staged, never dispatched as its
-    own prefill), `_stage` takes over the admissible (bucket, width)
-    pairs, `_stage_block` splices shared-prefix blocks per width, block
-    export moves to the live cache (per width, any bucket that can hold
-    a block), `_step` is never dispatched (every rung goes through the
-    megastep so the in-scan prefill phase runs), and the megastep
-    compiles one extra program per width for rung 1.
+    Admission is staged: `_stage` compiles per admissible (bucket,
+    width) pair, `_stage_block` splices shared-prefix blocks per width,
+    block export reads the live cache (per width, where any bucket can
+    hold a block), and every rung of the ladder, rung 1 included, is one
+    `_megastep` program per width.
     """
     extra = max(0, spec_tokens - 1)
     bucket_cap = min(
@@ -273,40 +238,19 @@ def static_paged_domain(
         for t in buckets
     }
     widths = sorted(set(nat.values()))
-    pairs = sum(
-        1 for w in widths for t in buckets if nat[t] <= w
-    )
     ladder = megastep_ladder(megastep_max)
     # Shared-prefix programs (engine/prefix_cache.py), zero when the
-    # cache is off (the jit wrappers exist either way — _megastep
-    # precedent). Sequential mode: export compiles per bucket wide
-    # enough to hold one block (any cold admission at that bucket can
-    # publish); load and partial prefill compile only for buckets where
-    # plan_partial can pick a suffix bucket leaving >= one whole block
-    # of prefix. Fused mode: export/stage_block compile per cache width
-    # (the block moves straight between the tree and the live state).
+    # cache is off (the jit wrappers exist either way) or no bucket can
+    # hold a block.
     blk = max(1, prefix_block_tokens)
-    any_block_bucket = any(t >= blk for t in buckets)
-    partial_pairs = sum(
-        1 for t in buckets for s in buckets if s <= t - blk
-    ) if prefix_cache and not fused_prefill else 0
-    if not prefix_cache:
-        export_n = 0
-    elif fused_prefill:
-        export_n = len(widths) if any_block_bucket else 0
-    else:
-        export_n = sum(1 for t in buckets if t >= blk)
-    load_buckets = sum(
-        1 for t in buckets if any(s <= t - blk for s in buckets)
-    ) if prefix_cache and not fused_prefill else 0
+    blocks = prefix_cache and any(t >= blk for t in buckets)
     return {
         "mesh": {"tp": max(1, tp), "ep": max(1, ep)},
         "buckets": buckets,
         "widths": widths,
-        "prefill_buckets": 0 if fused_prefill else len(buckets),
-        "step_widths": 0 if fused_prefill else len(widths),
-        "install_pairs": 0 if fused_prefill else pairs,
-        "stage_pairs": pairs if fused_prefill else 0,
+        "stage_pairs": sum(
+            1 for w in widths for t in buckets if nat[t] <= w
+        ),
         # One splice program per width, and one more (a run of blocks in
         # one call) per width that serves a bucket whose prompts can share
         # two blocks or more and is wide enough for a run.
@@ -316,20 +260,13 @@ def static_paged_domain(
                 if any(bucket_has_runs(t, blk, w) and nat[t] <= w
                        for t in buckets)
             )
-            if fused_prefill and prefix_cache and any_block_bucket else 0
+            if blocks else 0
         ),
         "grow_pairs": len(widths) * (len(widths) - 1) // 2,
-        # Megastep K axis: the K=1 rung dispatches through _step
-        # (sequential) or the megastep itself (fused); every rung >= 2
-        # compiles one _megastep program per width either way (the K is
-        # encoded in the stacked rng shape, not a static argnum).
+        # The K is encoded in the stacked rng shape, not a static argnum.
         "k_ladder": ladder,
-        "megastep_pairs": len(widths) * (
-            len(ladder) if fused_prefill else len(ladder) - 1
-        ),
-        "partial_pairs": partial_pairs,
-        "export_buckets": export_n,
-        "load_buckets": load_buckets,
+        "megastep_pairs": len(widths) * len(ladder),
+        "export_widths": len(widths) if blocks else 0,
     }
 
 
@@ -376,16 +313,11 @@ def static_score_domain(
 
 
 _DOMAIN_SIZES = {
-    "buckets": lambda d: d["prefill_buckets"],
-    "widths": lambda d: d["step_widths"],
-    "bucket-width-pairs": lambda d: d["install_pairs"],
     "stage-pairs": lambda d: d["stage_pairs"],
     "stage-widths": lambda d: d["stage_block_widths"],
     "width-pairs": lambda d: d["grow_pairs"],
     "width-k-pairs": lambda d: d["megastep_pairs"],
-    "bucket-suffix-pairs": lambda d: d["partial_pairs"],
-    "export-buckets": lambda d: d["export_buckets"],
-    "load-buckets": lambda d: d["load_buckets"],
+    "export-widths": lambda d: d["export_widths"],
 }
 
 
@@ -428,7 +360,6 @@ def expected_counts(engine) -> Dict[str, int]:
             megastep_max=getattr(engine, "megastep_max", 0),
             prefix_cache=getattr(engine, "prefix_cache", None) is not None,
             prefix_block_tokens=getattr(engine, "prefix_block_tokens", 16),
-            fused_prefill=getattr(engine, "fused", False),
             tp=engine.config.tp, ep=engine.config.ep,
         )
         live_mesh = {

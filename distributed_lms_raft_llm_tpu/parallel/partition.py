@@ -150,7 +150,7 @@ RULES_FOR = {
 # so the `pspec-flow` lint rule can check every producer against this
 # table). ONE semantic sharding per named plane across all producers:
 # `_init_state`'s birth puts, `_canon_state`'s dispatch-boundary
-# respells, `_fresh_prefill_cache`, and the prefix-cache block
+# respells and the prefix-cache block
 # canonicalization all resolve specs HERE and nowhere else.
 #
 # KV planes shard their heads axis over tp: slot cache k/v are

@@ -651,15 +651,13 @@ def main(argv=None) -> None:
                         help="shared-prefix cache block budget (16 "
                         "tokens/block; LRU eviction, blocks referenced "
                         "by live slots are never freed)")
-    parser.add_argument("--prefill-chunk-tokens", type=int, default=0,
-                        help="paged engine fused stall-free admission: "
-                        "stage arriving prompts into the decode state "
-                        "and prefill this many tokens per decode "
-                        "iteration INSIDE the megastep program, so "
-                        "admission never pauses the decode train "
-                        "(decode_stalled_tokens stays 0; admission "
-                        "latency is bounded by scan iterations, not "
-                        "prompt length). 0 = sequential admission; "
+    parser.add_argument("--prefill-chunk-tokens", type=int, default=32,
+                        help="paged engine admission: arriving prompts "
+                        "are staged into the decode state and prefilled "
+                        "this many tokens (>= 1) per decode iteration "
+                        "INSIDE the megastep program, so admission never "
+                        "pauses the decode train (admission latency is "
+                        "bounded by scan iterations, not prompt length); "
                         "ignored without --paged")
     parser.add_argument("--draft-source", default="prompt_lookup",
                         choices=["prompt_lookup", "ngram"],
@@ -848,9 +846,6 @@ def main(argv=None) -> None:
         if args.prefix_cache:
             log.warning("--prefix-cache applies to the paged engine only; "
                         "ignored without --paged")
-        if args.prefill_chunk_tokens:
-            log.warning("--prefill-chunk-tokens applies to the paged "
-                        "engine only; ignored without --paged")
         engine = TutoringEngine(config)
     if not args.no_warmup:
         secs = (engine.warmup() if args.paged

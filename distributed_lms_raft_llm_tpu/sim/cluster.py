@@ -630,24 +630,22 @@ class SimCluster:
                 # (`course_concentration` > 0) produces a measurable
                 # prefix_cache_hit_rate in the soak's verdict. Two
                 # prompt buckets + 8-token blocks: the tiny position
-                # table caps prompts at 32 tokens, and a partial
-                # prefill needs a suffix bucket that leaves at least
+                # table caps prompts at 32 tokens, and a hit needs
                 # one whole block of prefix in the window. NOTE the
                 # 32-token cap also tail-truncates the long course
                 # context, so at this scale hits come from students
                 # repeating the same course question verbatim — real
-                # lookup/splice/partial-prefill traffic, but not
-                # cross-question context sharing (that is bench.py's
-                # shared-prefix scenario, with token-level control).
+                # lookup/splice/suffix-prefill traffic, but not
+                # cross-question context sharing (that is
+                # tests/test_prefix_cache.py, with token-level control).
                 import dataclasses as _dc
 
                 engine = PagedEngine(
                     _dc.replace(config, length_buckets=(16, 32)),
                     slots=4, chunk=4, prefix_cache=True,
                     prefix_cache_blocks=128, prefix_block_tokens=8,
-                    # Fused stall-free admission, like cluster.toml: the
-                    # soak exercises staged chunked prefill under real
-                    # diurnal churn (decode_stalled_tokens stays 0).
+                    # The soak exercises staged chunked prefill under
+                    # real diurnal churn, at a chunk the tiny table fits.
                     prefill_chunk_tokens=8,
                 )
                 if self.cfg.bulk_scoring:

@@ -146,9 +146,9 @@ class WorkloadGenerator:
         than this context, and the engine keeps a prompt's TAIL — so in
         the tiny-paged soak the measured hits come from students
         repeating the same course question verbatim (still the radix
-        partial-prefill path); genuine cross-question context sharing is
-        exercised with token-level control by bench.py's shared-prefix
-        scenario and tests/test_prefix_cache.py."""
+        splice path); genuine cross-question context sharing is
+        exercised with token-level control by
+        tests/test_prefix_cache.py."""
         return (f"{course} assignment context: {ASSIGNMENT_TEXT} "
                 f"Course question: ")
 

@@ -206,7 +206,7 @@ def compile_count_guard(
     `RecompileError` at the moment it happens instead of shipping as a
     silent tens-of-seconds stall per request.
 
-        with compile_count_guard(eng._step, eng._install) as guard:
+        with compile_count_guard(eng._megastep, eng._stage) as guard:
             eng.drain()
         # guard.new_compiles() also available for reporting
 

@@ -348,26 +348,12 @@ MEGASTEP_DEAD_LANE_TOKENS = counter(
     "pad token positions decoded by slots that finished inside a "
     "megastep before its boundary let the host reap them (spec-mode "
     "lanes count spec_tokens+1 positions each; megastep overhead, zero "
-    "in chunk-loop mode)",
+    "at K=1)",
 )
 HOST_DISPATCHES_PER_TOKEN = gauge(
     "host_dispatches_per_token",
     "host program dispatches paid per emitted token on the paged engine "
     "(cumulative ratio; the megastep exists to shrink it)",
-)
-PREFILL_STALL_MS = counter(
-    "prefill_stall_ms",
-    "host wall milliseconds the paged decode train spent blocked on "
-    "sequential admission (prefill dispatches + the first-token sync "
-    "while live slots waited); 0 by construction under fused staged "
-    "admission (prefill_chunk_tokens > 0)",
-)
-DECODE_STALLED_TOKENS = counter(
-    "decode_stalled_tokens",
-    "proxy decode tokens the live slots gave up to blocking sequential "
-    "admission (live slots x chunk per admission prefill that paused "
-    "the train); 0 by construction under fused staged admission — the "
-    "fused-prefill before/after number",
 )
 PREFIX_CACHE_HIT_TOKENS = counter(
     "prefix_cache_hit_tokens",
@@ -478,30 +464,10 @@ SCORE_PREEMPT_WAIT_MS = counter(
 # ENGINE_PROGRAM_HISTOGRAMS below, and the same measurements become
 # `engine.<program>` spans on the request trace.
 
-ENGINE_PROG_PREFILL = histogram(
-    "engine_prog_prefill",
-    "paged-engine _prefill program dispatch wall time (one fresh-slot "
-    "prompt pass)",
-)
-ENGINE_PROG_INSTALL = histogram(
-    "engine_prog_install",
-    "paged-engine _install program dispatch wall time (splicing a "
-    "prefilled slot into the live state)",
-)
-ENGINE_PROG_STEP = histogram(
-    "engine_prog_step",
-    "paged-engine _step/_spec_step program dispatch wall time (one "
-    "chunk of decode scan iterations)",
-)
 ENGINE_PROG_MEGASTEP = histogram(
     "engine_prog_megastep",
     "paged-engine _megastep program dispatch wall time (K chunks of "
     "decode fused into one device-resident dispatch)",
-)
-ENGINE_PROG_PARTIAL_PREFILL = histogram(
-    "engine_prog_partial_prefill",
-    "paged-engine _partial_prefill program dispatch wall time (a "
-    "shared-prefix cache hit's suffix-only prompt pass)",
 )
 ENGINE_PROG_GROW = histogram(
     "engine_prog_grow",
@@ -510,22 +476,16 @@ ENGINE_PROG_GROW = histogram(
 )
 ENGINE_PROG_STAGE = histogram(
     "engine_prog_stage",
-    "paged-engine _stage program dispatch wall time (fused admission: "
+    "paged-engine _stage program dispatch wall time (admission: "
     "arming a slot's staged prompt; the prefill itself runs inside the "
     "megastep scan)",
 )
 ENGINE_PROG_STAGE_BLOCK = histogram(
     "engine_prog_stage_block",
-    "paged-engine _stage_block program dispatch wall time (fused "
-    "admission: cached shared-prefix blocks spliced into a slot's "
+    "paged-engine _stage_block program dispatch wall time (admission: "
+    "cached shared-prefix blocks spliced into a slot's "
     "pages, a run of up to 16 or a single block a call; one observation "
     "per call)",
-)
-ENGINE_PROG_LOAD_BLOCK = histogram(
-    "engine_prog_load_block",
-    "paged-engine _load_block program dispatch wall time (sequential "
-    "admission: one cached shared-prefix block spliced into a fresh "
-    "prompt cache; one observation per block)",
 )
 ENGINE_PROG_EXPORT_BLOCK = histogram(
     "engine_prog_export_block",
@@ -548,15 +508,10 @@ ENGINE_PROG_GENERATE = histogram(
 # queues (engine/batcher.py). Living HERE keeps the mapping inside the
 # declared namespace (see BREAKER_TRANSITION_COUNTERS).
 ENGINE_PROGRAM_HISTOGRAMS: Dict[str, str] = {
-    "prefill": ENGINE_PROG_PREFILL,
-    "partial_prefill": ENGINE_PROG_PARTIAL_PREFILL,
-    "install": ENGINE_PROG_INSTALL,
-    "step": ENGINE_PROG_STEP,
     "megastep": ENGINE_PROG_MEGASTEP,
     "grow": ENGINE_PROG_GROW,
     "stage": ENGINE_PROG_STAGE,
     "stage_block": ENGINE_PROG_STAGE_BLOCK,
-    "load_block": ENGINE_PROG_LOAD_BLOCK,
     "export_block": ENGINE_PROG_EXPORT_BLOCK,
     "score": ENGINE_PROG_SCORE,
     "generate": ENGINE_PROG_GENERATE,

@@ -4,7 +4,7 @@ BASELINE's student-visible latency is defined at the LMS `GetLLMAnswer`
 entry point — linearizable read fence, session check, BERT relevance gate,
 HMAC'd fan-out to the TPU tutoring node, generation, and the answer back
 through the leader (reference path: GUI_RAFT_LLM_SourceCode/
-lms_gui_final.py:900-929 -> lms_server.py:1237-1274). bench_server.py
+lms_gui_final.py:900-929 -> lms_server.py:1237-1274). `benchmarks/run.py`
 measures the tutoring node alone; this script boots the real deployment —
 3 Raft LMS nodes (quorum of the reference's 5-node topology) + the gate +
 the tutoring server, all from configs/cluster.toml artifacts — registers N

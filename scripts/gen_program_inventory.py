@@ -54,22 +54,14 @@ MD_END = "<!-- program-inventory:end -->"
 # lands as ("shapes", "on-demand") — shipping it warmup-covered is a
 # reviewed decision, not a default.
 CLASSIFICATION = {
-    ("PagedEngine", "_prefill"): ("buckets", "warmup"),
-    ("PagedEngine", "_install"): ("bucket-width-pairs", "warmup"),
-    ("PagedEngine", "_step"): ("widths", "warmup"),
     ("PagedEngine", "_megastep"): ("width-k-pairs", "warmup"),
     ("PagedEngine", "_grow"): ("width-pairs", "warmup"),
+    ("PagedEngine", "_stage"): ("stage-pairs", "warmup"),
     # Shared-prefix cache (engine/prefix_cache.py): domains are empty
     # when [tutoring] prefix_cache is off, so the warmup claim holds in
     # both modes (see static_paged_domain).
-    ("PagedEngine", "_partial_prefill"): ("bucket-suffix-pairs", "warmup"),
-    ("PagedEngine", "_load_block"): ("load-buckets", "warmup"),
-    ("PagedEngine", "_export_block"): ("export-buckets", "warmup"),
-    # Fused staged admission (prefill_chunk_tokens > 0): domains are
-    # empty in sequential mode and take over the admission pairs when
-    # fused (see static_paged_domain's fused_prefill swap).
-    ("PagedEngine", "_stage"): ("stage-pairs", "warmup"),
     ("PagedEngine", "_stage_block"): ("stage-widths", "warmup"),
+    ("PagedEngine", "_export_block"): ("export-widths", "warmup"),
     # Bulk-scoring tenant (engine/scoring.py): the score program's
     # (batch bucket x length bucket) domain is empty when
     # EngineConfig.scoring is off, so the warmup claim holds in both
@@ -153,7 +145,6 @@ def shipped_domains() -> List[Dict[str, object]]:
             megastep_max=effective_megastep_max(t.megastep,
                                                 t.megastep_max),
             prefix_cache=t.prefix_cache,
-            fused_prefill=t.prefill_chunk_tokens > 0,
             tp=ec.tp, ep=ec.ep,
         )
         sdom = static_score_domain(
@@ -162,11 +153,8 @@ def shipped_domains() -> List[Dict[str, object]]:
             enabled=app.scoring.enabled,
         )
         total = (
-            dom["prefill_buckets"] + dom["install_pairs"]
-            + dom["step_widths"] + dom["grow_pairs"]
-            + dom["megastep_pairs"] + dom["partial_pairs"]
-            + dom["export_buckets"] + dom["load_buckets"]
-            + dom["stage_pairs"] + dom["stage_block_widths"]
+            dom["stage_pairs"] + dom["megastep_pairs"] + dom["grow_pairs"]
+            + dom["stage_block_widths"] + dom["export_widths"]
             + dom_score_pairs(sdom)
         )
         rows.append(dict(
@@ -176,7 +164,6 @@ def shipped_domains() -> List[Dict[str, object]]:
             max_new=ec.sampling.max_new_tokens,
             buckets=dom["buckets"], widths=dom["widths"],
             k_ladder=dom["k_ladder"], prefix=t.prefix_cache,
-            fused=t.prefill_chunk_tokens > 0,
             score_pairs=dom_score_pairs(sdom), total=total,
         ))
     return rows
@@ -212,10 +199,10 @@ def render_readme_block(entries: List[dict]) -> str:
     lines.append(
         "| config | model | paged | mesh | spec_tokens | max_new "
         "| prompt buckets | cache widths | megastep ladder "
-        "| prefix cache | fused prefill | score programs "
+        "| prefix cache | score programs "
         "| total warmup programs |"
     )
-    lines.append("|---|---|---|---|---|---|---|---|---|---|---|---|---|")
+    lines.append("|---|---|---|---|---|---|---|---|---|---|---|---|")
     for row in shipped_domains():
         mesh = row["mesh"]
         lines.append(
@@ -227,7 +214,6 @@ def render_readme_block(entries: List[dict]) -> str:
             f"| {', '.join(str(w) for w in row['widths'])} "
             f"| {', '.join(str(k) for k in row['k_ladder'])} "
             f"| {'on' if row['prefix'] else 'off'} "
-            f"| {'on' if row['fused'] else 'off'} "
             f"| {row['score_pairs']} "
             f"| {row['total']} |"
         )

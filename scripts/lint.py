@@ -101,7 +101,7 @@ def changed_paths() -> List[Path]:
         path = REPO / rel
         if path.suffix != ".py" or not path.is_file():
             continue
-        # Only files the full gate covers: a repo-root stray (bench.py)
+        # Only files the full gate covers: a repo-root stray
         # would otherwise make --changed and the tier-1 clean run disagree
         # about what "clean" means.
         if any(path.resolve().is_relative_to(base.resolve())

@@ -63,3 +63,33 @@ def ordered_locks():
     locks.assert_acyclic()
 
 
+
+
+@pytest.fixture
+def eos_first_engine(monkeypatch):
+    """`make(config, prompt, **kw)`: a PagedEngine for which the greedy
+    answer to `prompt` starts with eos, and pad differs from eos — the
+    slot is dead from its flip and its lane holds pad filler. No program
+    is patched: one engine learns the answer's first token, and the next
+    one's tokenizer is loaded with that token as its eos."""
+    from distributed_lms_raft_llm_tpu.engine import PagedEngine
+    from distributed_lms_raft_llm_tpu.utils import tokenizer as tok_lib
+
+    def make(config, prompt, **kw):
+        probe = PagedEngine(config, **kw)
+        rid = probe.submit(prompt)
+        probe.stream_watch(rid)
+        probe.drain()
+        first = probe.pop_final_tokens()[rid][0]
+        assert first != 0
+        real = tok_lib.load_gpt2_tokenizer
+
+        def load(*a, **k):
+            tok = real(*a, **k)
+            tok.eos_id, tok.pad_id = first, 0
+            return tok
+
+        monkeypatch.setattr(tok_lib, "load_gpt2_tokenizer", load)
+        return PagedEngine(config, **kw)
+
+    return make

@@ -76,8 +76,8 @@ def test_forward_matches_the_reference_logits(config, model, seed):
 
 def _through_the_cache(family, cfg, params, ids, n_prompt, bucket, width):
     """Prefill a right-padded bucket, splice into a slot of `width`, decode
-    the rest a token at a time at a per-row offset: `_prefill_program` and
-    `_step_program`'s calls of `family.forward`."""
+    the rest a token at a time at a per-row offset: a whole-bucket call of
+    `family.forward` and `_step_program`'s."""
     prompt = np.zeros((1, bucket), np.int32)
     prompt[0, :n_prompt] = ids[:n_prompt]
     real = (jnp.arange(bucket) < n_prompt)[None]
@@ -296,7 +296,7 @@ def _econf():
 
 @pytest.fixture(scope="module")
 def served():
-    """One fused engine with a prefix cache serves the prompts twice: the
+    """One engine with a prefix cache serves the prompts twice: the
     first round prefills the notes in the scan (staged), the second
     splices them from the radix tree."""
     eng = PagedEngine(_econf(), slots=4, chunk=2, megastep=2, megastep_max=4,
@@ -403,16 +403,19 @@ def test_engine_counts_routing_and_tokens_past_the_window(served):
     assert counts["overrun_lane_steps"] > 0
 
 
-def test_sequential_admission_counts_its_prefill_too():
-    """Without fused admission the prompt is prefilled by a program of its
-    own, whose counts come back with the first token."""
-    eng = PagedEngine(_econf(), slots=2, chunk=2)
-    rid = eng.submit(PROMPTS[1])
-    assert eng.drain()[rid]
-    counts = eng.pop_loop_stats()[0]
+def test_spliced_prefix_tokens_reach_no_expert(served):
+    """The second round's notes come out of the radix tree: the suffix
+    prefilled in the scan and the decode tokens route, and a spliced
+    token, which has no forward pass, reaches no expert."""
+    eng, rounds = served
+    _, (hit, prompt_tokens, _, _), counts = rounds[1]
+    assert hit > prompt_tokens // 2
+    assert counts["prompt_tokens"] == prompt_tokens
+    assert counts["prefill_tokens"] == prompt_tokens - hit
     k, le = eng.cfg.num_experts_per_tok, eng.cfg.num_expert_layers
-    assert counts["moe_picks"] == (
-        counts["prefill_tokens"] + MAX_NEW - 1) * k * le
+    routed = counts["prefill_tokens"] + len(PROMPTS) * (MAX_NEW - 1)
+    assert counts["moe_picks"] == routed * k * le
+    assert counts["moe_picks"] < rounds[0][2]["moe_picks"]
 
 
 def test_scopes_are_in_the_megastep(served):

@@ -185,16 +185,40 @@ def test_paged_decode_programs_compile_and_fit_one_v5e(one_chip, prod):
         assert _device_bytes(compiled.memory_analysis()) < HBM_BYTES // 4
 
 
-def test_paged_prefill_program_compiles_and_fits_one_v5e(one_chip, prod):
-    ids = jax.ShapeDtypeStruct((1, prod.bucket), jnp.int32,
-                               sharding=one_chip)
-    scalar = jax.ShapeDtypeStruct((), jnp.int32, sharding=one_chip)
-    compiled = jax.jit(
-        partial(paged._prefill_program, **prod.statics)
-    ).lower(
-        _with(prod.params, one_chip), ids, scalar,
-        _with(prod.key, one_chip),
-    ).compile()
+@pytest.mark.parametrize(
+    "program", ["stage", "stage_block", "stage_block_run", "export_block"])
+def test_paged_admission_programs_compile_and_fit_one_v5e(one_chip, prod,
+                                                         program):
+    """What an admission dispatches beside the megastep, at production's
+    widest cache: arming a slot, splicing one shared block or a run of
+    them into its pages, and copying a block out for the tree."""
+    from distributed_lms_raft_llm_tpu.engine.prefix_cache import BLOCK_TOKENS
+    from distributed_lms_raft_llm_tpu.engine.program_inventory import (
+        STAGE_RUN_BLOCKS,
+    )
+
+    def sd(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    state = _with(prod.state, one_chip)
+    i32 = sd((), jnp.int32)
+    export = partial(paged._export_block_program, block=BLOCK_TOKENS)
+    blk = _with(jax.eval_shape(export, prod.state.cache, 0, 0), one_chip)
+    key_data = jax.eval_shape(
+        lambda: jax.random.key_data(jax.random.key(0)))
+    fn, donate, args = {
+        "stage": (paged._stage_program, (0,), (
+            state, i32, sd((1, prod.bucket), jnp.int32), i32, i32, i32,
+            sd(key_data.shape, key_data.dtype))),
+        "stage_block": (paged._stage_block_program, (0,),
+                        (state, blk, i32, i32)),
+        "stage_block_run": (paged._stage_block_program, (0,), (
+            state, (blk,) * STAGE_RUN_BLOCKS, i32, i32, i32)),
+        "export_block": (export, (), (state.cache, i32, i32)),
+    }[program]
+    compiled = jax.jit(fn, donate_argnums=donate).lower(*args).compile()
+    # The donated state aliases into the output: the splice holds one
+    # cache, not two.
     assert _device_bytes(compiled.memory_analysis()) < HBM_BYTES // 4
 
 

@@ -38,7 +38,7 @@ PROMPTS = [
 ]
 
 
-def make_engine(fused=True, **kw):
+def make_engine(**kw):
     return PagedEngine(
         EngineConfig(
             model="tiny",
@@ -48,7 +48,7 @@ def make_engine(fused=True, **kw):
         ),
         slots=SLOTS, chunk=2, megastep=2, megastep_max=4,
         prefix_cache=True, prefix_cache_blocks=64, prefix_block_tokens=4,
-        prefill_chunk_tokens=4 if fused else 0,
+        prefill_chunk_tokens=4,
     )
 
 
@@ -57,29 +57,20 @@ def make_engine(fused=True, **kw):
 
 @pytest.fixture(scope="module")
 def lowering_args():
-    """Abstract arguments for every program of one sequential and one
+    """Abstract arguments for every program of one plain and one
     speculative engine (lowering runs nothing and donates nothing)."""
-    eng = make_engine(fused=False, scoring=True)
-    spec = make_engine(fused=False, spec_tokens=2)
+    eng = make_engine(scoring=True)
+    spec = make_engine(spec_tokens=2)
     i32 = jnp.asarray(0, jnp.int32)
     ids = jnp.zeros((1, 16), jnp.int32)
     rng = jax.random.key(0)
     with eng.mesh:
-        c1, first, seen = jax.eval_shape(
-            eng._prefill, eng.params, ids, i32, rng)
-        blk = jax.eval_shape(eng._export_block, c1, i32, i32)
-    cache0 = eng._fresh_prefill_cache(16)
+        blk = jax.eval_shape(eng._export_block, eng.state.cache, i32, i32)
     return {
-        "_prefill": (eng, (eng.params, ids, i32, rng)),
-        "_partial_prefill": (eng, (eng.params, cache0, ids,
-                                   jnp.zeros((1, 4), jnp.int32), i32, i32,
-                                   rng)),
-        "_load_block": (eng, (cache0, blk, i32)),
-        "_export_block": (eng, (c1, i32, i32)),
-        "_install": (eng, (eng.state, i32, c1, ids, i32, first, seen)),
-        "_step": (eng, (eng.params, eng.state, rng)),
-        "_spec_step": (spec, (spec.params, spec.state, rng)),
+        "_export_block": (eng, (eng.state.cache, i32, i32)),
         "_megastep": (eng, (eng.params, eng.state, eng._step_keys(2))),
+        "_spec_megastep": (spec, (spec.params, spec.state,
+                                  spec._step_keys(2))),
         "_stage": (eng, (eng.state, i32, ids, i32, i32, i32,
                          jax.random.key_data(rng))),
         "_stage_block": (eng, (eng.state, blk, i32, i32)),
@@ -90,14 +81,9 @@ def lowering_args():
 
 
 @pytest.mark.parametrize("attr,module", [
-    ("_prefill", "jit__prefill_program"),
-    ("_partial_prefill", "jit__partial_prefill_program"),
-    ("_load_block", "jit__load_block_program"),
     ("_export_block", "jit__export_block_program"),
-    ("_install", "jit__install_program"),
-    ("_step", "jit__step_program"),
-    ("_spec_step", "jit__spec_step_program"),
     ("_megastep", "jit__megastep_program"),
+    ("_spec_megastep", "jit__megastep_program"),
     ("_stage", "jit__stage_program"),
     ("_stage_block", "jit__stage_block_program"),
     ("_grow", "jit__grow_state_program"),
@@ -105,7 +91,7 @@ def lowering_args():
 ])
 def test_program_lowers_under_its_function_name(lowering_args, attr, module):
     eng, args = lowering_args[attr]
-    jitted = getattr(eng, "_step" if attr == "_spec_step" else attr)
+    jitted = getattr(eng, attr.replace("_spec", ""))
     with eng.mesh:
         text = jitted.lower(*args).as_text()
     assert f"module @{module} " in text
@@ -267,15 +253,12 @@ def test_counters_conserve_tokens_and_lane_steps():
     assert set(observations) <= set(metric.ENGINE_LOOP_HISTOGRAMS)
 
 
-@pytest.mark.parametrize("fused,programs", [
-    (True, ("stage_block", "export_block", "stage", "megastep")),
-    (False, ("load_block", "export_block", "prefill", "partial_prefill")),
-])
-def test_block_programs_reach_their_histograms(fused, programs):
-    """`stage_block`, `load_block` and `export_block` were timed and then
-    dropped; now one observation per block, and `engine_dispatches`
-    counts exactly the observations."""
-    engine = make_engine(fused=fused)
+def test_block_programs_reach_their_histograms():
+    """`stage_block` and `export_block` were timed and then dropped; now
+    one observation per call, and `engine_dispatches` counts exactly the
+    observations."""
+    programs = ("stage_block", "export_block", "stage", "megastep")
+    engine = make_engine()
     engine.warmup()
     snap, _ = _counters_after(engine, PROMPTS)
     lat = snap["latency"]

@@ -1,19 +1,16 @@
-"""Stall-free admission: chunked prefill fused into the megastep scan.
+"""Staged admission: chunked prefill inside the megastep scan.
 
-The fusion changes WHERE prefill compute runs (inside the decode scan,
-one bounded chunk per iteration) and WHEN a slot joins the train (at a
-scan-iteration flip instead of a dispatch-boundary install) — never WHAT
-the device computes. Greedy outputs through fused staged admission must
-be bit-identical to the sequential prefill-then-decode engine at every
-ladder rung and any chunk budget, across plain/spec/kv-quant/
-prefix-cache-hit/slot-churn configs. On top of exactness: warmup covers
-the fused program domain with exact inventory equality (a live session
-walking admissions mid-megastep adds zero programs), the decode train
-records ZERO stalled tokens under fused admission while the sequential
-path records them (the PR's before/after number), and the K controller
-holds K >= 2 under a non-empty pending queue. The per-slot n-gram-table
-drafter (`draft_source = "ngram"`) rides along: acceptance pinned above
-prompt-lookup's on a temperature-0.8 workload.
+Prefill compute runs inside the decode scan, one bounded chunk per
+iteration, and a slot joins the train at a scan-iteration flip. Greedy
+outputs through staged admission must be bit-identical to the bucketed
+engine's (`TutoringEngine`, which shares nothing of admission or the
+scan) at every ladder rung and any chunk budget, across plain/spec/
+kv-quant/prefix-cache-hit/slot-churn configs, several prompt buckets and
+cache widths. On top of exactness: warmup covers the program domain with
+exact inventory equality (a live session walking admissions mid-megastep
+adds zero programs), and a `prefill_chunk_tokens` below 1 is refused.
+The per-slot n-gram-table drafter (`draft_source = "ngram"`) rides along:
+acceptance pinned above prompt-lookup's on a temperature-0.8 workload.
 """
 
 import asyncio
@@ -74,16 +71,13 @@ def expected_answers(cfg, prompts):
 
 class TestGreedyBitEquality:
     @pytest.mark.parametrize("megastep", [1, 4])
-    def test_matches_sequential_at_every_rung(self, megastep):
-        """Acceptance pin: fused admission at the ladder floor AND a
-        wide rung — rung 1 included, where the fused engine still
-        dispatches through the megastep program — emits exactly what the
-        sequential prefill-then-decode paged engine and the bucketed
-        engine emit (rung 2 rides in the churn/prefix tests below)."""
+    def test_matches_bucketed_at_every_rung(self, megastep):
+        """Acceptance pin: staged admission at the ladder floor AND a
+        wide rung — rung 1 included, which dispatches through the
+        megastep program too — emits exactly what the bucketed
+        engine emits (rung 2 rides in the churn/prefix tests below)."""
         cfg = make_config()
         expected = expected_answers(cfg, PROMPTS)
-        # (sequential-paged == bucketed at these rungs is test_megastep's
-        # pin; here the fused engine closes the triangle.)
         fused = PagedEngine(cfg, slots=4, chunk=2, megastep=megastep,
                             megastep_max=megastep, prefill_chunk_tokens=4)
         fr = [fused.submit(p) for p in PROMPTS]
@@ -216,6 +210,98 @@ class TestGreedyBitEquality:
         assert [out_pipe[r] for r in rp] == [out_ser[r] for r in rs]
 
 
+# ------------------------ several buckets, several widths, slots reused
+
+
+LONG = "a long question about raft elections and replicated logs"
+MIXED = ["k v", "what now", LONG, "hello world", LONG + "?", "k",
+         "ok then?"]
+
+
+@pytest.mark.parametrize("spec_tokens", [0, 2], ids=["plain", "spec"])
+@pytest.mark.parametrize("budget", [3, 4, 9],
+                         ids=["below", "at", "above"])
+def test_buckets_widths_and_churn_at_a_budget(budget, spec_tokens):
+    """Seven requests of three prompt buckets over two slots: `_stage`
+    runs at every bucket, the live cache grows twice as longer prompts
+    join a short one mid-decode and is rebuilt narrow when idle, slots
+    are reused, and the chunk budget sits below, at and above the smallest
+    bucket (4) — a whole short prompt in one chunk with a pad tail, a long
+    one over many. Every stream is the bucketed engine's."""
+    cfg = make_config(length_buckets=(4, 8, 16))
+    expected = expected_answers(cfg, MIXED)
+    eng = PagedEngine(
+        make_config(length_buckets=(4, 8, 16), spec_tokens=spec_tokens),
+        slots=2, chunk=2, megastep=2, megastep_max=4,
+        prefill_chunk_tokens=budget)
+    assert eng.prefill_chunk == budget and len(eng.widths) == 3
+    staged, widths = set(), []
+    real_stage = eng._stage
+
+    def stage(state, slot, ids, *a):
+        staged.add(ids.shape[1])
+        return real_stage(state, slot, ids, *a)
+
+    eng._stage = stage
+    out = {}
+    rids = [eng.submit(MIXED[0])]
+    out.update(eng.step())
+    widths.append(eng.state.cache.k.shape[3])
+    rids += [eng.submit(p) for p in MIXED[1:5]]
+    while eng.has_work:
+        out.update(eng.step())
+        widths.append(eng.state.cache.k.shape[3])
+    rids += [eng.submit(p) for p in MIXED[5:]]  # idle: rebuilt narrow
+    out.update(eng.step())
+    widths.append(eng.state.cache.k.shape[3])
+    out.update(eng.drain())
+    assert [out[r] for r in rids] == expected
+    assert staged == {4, 8, 16}
+    # Narrow for the first, grown for the second, grown again for the
+    # long one, and rebuilt at the middle width for the last two.
+    assert sorted(set(widths)) == eng.widths
+    assert widths == sorted(widths[:-1]) + [eng.widths[1]]
+
+
+EDGE_SHORT = "the raft log"                      # 12 tokens: bucket 16
+EDGE_LONG = "the raft log elects a leader"       # 28 tokens: bucket 32
+
+
+@pytest.mark.parametrize("order", ["short_then_long", "long_then_short"])
+def test_prefix_hit_across_a_bucket_edge(order):
+    """A hit between prompts of different buckets. Short then long: three
+    blocks published out of a 24-wide cache are spliced into a slot of a
+    cache grown to 40 while the first request still decodes, and the
+    suffix, which starts inside the short bucket and ends past it, is
+    chunked in the scan. Long then short: the whole short prompt is in
+    the tree, and `plan_staged` gives back its last block so that one
+    token is left to compute. Answers are the bucketed engine's."""
+    cfg = make_config(length_buckets=(8, 16, 32))
+    first, second = ((EDGE_SHORT, EDGE_LONG) if order == "short_then_long"
+                     else (EDGE_LONG, EDGE_SHORT))
+    expected = expected_answers(cfg, [first, second])
+    eng = PagedEngine(cfg, slots=2, chunk=2, megastep=2, megastep_max=2,
+                      prefill_chunk_tokens=4, prefix_cache=True,
+                      prefix_cache_blocks=64, prefix_block_tokens=4)
+    out = {}
+    r1 = eng.submit(first)
+    while not eng.prefix_cache.blocks_used:
+        out.update(eng.step())  # until the flip's reap has published
+    assert eng.has_work and r1 not in out, "the first one still decodes"
+    width_before = eng.state.cache.k.shape[3]
+    r2 = eng.submit(second)
+    out.update(eng.step())
+    hit = eng.pop_prefix_hits()[r2]
+    out.update(eng.drain())
+    assert [out[r1], out[r2]] == expected
+    if order == "short_then_long":
+        assert hit == plan_staged(12, 28, 4) == 12
+        assert eng.state.cache.k.shape[3] > width_before
+    else:
+        assert hit == plan_staged(12, 12, 4) == 8
+        assert eng.state.cache.k.shape[3] == width_before
+
+
 # --------------------------------------- a chunk at every scan iteration
 
 
@@ -229,7 +315,7 @@ def test_staged_slots_are_served_every_iteration_in_stage_order(spec_tokens):
     prefill chunks: ONE megastep serves them a chunk per scan iteration
     (not per `chunk` of iterations), oldest staging first, so slot n
     flips within the first sum(chunks of slots 0..n) rows of the
-    `flipped` plane — and every stream is still the sequential path's.
+    `flipped` plane — and every stream is still the bucketed engine's.
     """
     budget = 4
     eng = PagedEngine(
@@ -253,67 +339,12 @@ def test_staged_slots_are_served_every_iteration_in_stage_order(spec_tokens):
         assert int(np.argmax(rows[:, slot])) == served - 1
     assert rows[served:].sum() == 0
     out = eng.drain()
-    seq = PagedEngine(make_config(), slots=4, chunk=2, megastep=8,
-                      megastep_max=8)
-    sr = [seq.submit(p) for p in STAGED_TOGETHER]
-    expected = seq.drain()
-    assert [out[r] for r in rs] == [expected[r] for r in sr]
+    assert [out[r] for r in rs] == expected_answers(
+        make_config(), STAGED_TOGETHER)
     _, observations = eng.pop_loop_stats()
     assert observations["staged_iterations"] == [
         float(sum(chunks[: i + 1]) - 1) for i in range(4)
     ]
-
-
-# ------------------------------------------------- stall-free acceptance
-
-
-def _churn(engine):
-    """A mid-decode arrival: A is admitted and decoding when B and C
-    arrive, so their admissions happen under a LIVE train — the exact
-    scenario sequential admission pays a full prefill stall for and
-    staged admission absorbs into the scan."""
-    engine.submit("a long question about distributed consensus and logs")
-    for _ in range(2):
-        engine.step()  # A live, mid-decode
-    engine.submit("b second question")
-    engine.submit("c third question")
-    engine.drain()
-    return engine.pop_dispatch_stats()
-
-
-def test_sequential_admission_stalls_fused_does_not():
-    """THE before/after number: a request arriving mid-decode pauses the
-    sequential engine's live decode train for its prefill (stalled
-    tokens + stall wall accrue); the fused engine records ZERO decode
-    stall for the identical workload, and its K controller never drops
-    to the chunk loop while requests wait."""
-    cfg = make_config()
-    _, _, _, stall_ms, stalled = _churn(
-        PagedEngine(cfg, slots=2, chunk=2, megastep=2, megastep_max=2)
-    )
-    assert stalled > 0, "sequential admission under churn must stall decode"
-    assert stall_ms > 0
-
-    _, _, _, stall_ms, stalled = _churn(
-        PagedEngine(cfg, slots=2, chunk=2, megastep=2, megastep_max=2,
-                    prefill_chunk_tokens=4)
-    )
-    assert stalled == 0, "fused staged admission must never pause decode"
-    assert stall_ms == 0
-
-    # Saturation: K stays wide (>= 2) the whole time a backlog waits.
-    fused = PagedEngine(cfg, slots=2, chunk=2, megastep=4,
-                        megastep_max=4, prefill_chunk_tokens=4)
-    ks = []
-    for i in range(8):
-        fused.submit(f"question number {i}")
-    while fused.has_work:
-        fused.step()
-        if fused._pending:
-            ks.append(fused.megastep_k)
-    _, _, _, stall_ms, stalled = fused.pop_dispatch_stats()
-    assert stalled == 0 and stall_ms == 0
-    assert ks and min(ks) >= 2, "K must stay wide while admissions drain"
 
 
 # --------------------------------------------- warmup / inventory coverage
@@ -321,8 +352,8 @@ def test_sequential_admission_stalls_fused_does_not():
 
 def test_warmed_fused_session_passes_inventory_guard():
     """compile_count_guard(expected_from_inventory(...)): warmup compiles
-    the fused domain — stage pairs, megasteps at EVERY rung including 1,
-    zero sequential admission programs — and a live session walking
+    the domain — stage pairs, megasteps at EVERY rung including 1
+    — and a live session walking
     admissions mid-megastep, churning slots, and growing the cache adds
     ZERO programs."""
     eng = PagedEngine(
@@ -333,10 +364,10 @@ def test_warmed_fused_session_passes_inventory_guard():
     expectation = expected_from_inventory(eng)
     dom_widths = len(eng.widths)
     assert expectation.expected["_megastep"] == dom_widths * 3  # rungs 1,2,4
-    assert expectation.expected["_step"] == 0
-    assert expectation.expected["_prefill"] == 0
-    assert expectation.expected["_install"] == 0
-    assert expectation.expected["_stage"] > 0
+    assert expectation.expected["_stage"] == 3  # (4,12) (4,24) (16,24)
+    assert set(expectation.expected) == {
+        "_megastep", "_stage", "_stage_block", "_export_block", "_grow",
+        "_score"}
     assert expectation.mismatches() == {}
     with compile_count_guard(expectation) as guard:
         eng.submit("k v")
@@ -362,8 +393,6 @@ def test_warmed_fused_prefix_session_passes_inventory_guard():
     expectation = expected_from_inventory(eng)
     assert expectation.expected["_stage_block"] == len(eng.widths)
     assert expectation.expected["_export_block"] == len(eng.widths)
-    assert expectation.expected["_load_block"] == 0
-    assert expectation.expected["_partial_prefill"] == 0
     assert expectation.mismatches() == {}
     with compile_count_guard(expectation) as guard:
         eng.submit(SHARED + " why?")
@@ -374,6 +403,45 @@ def test_warmed_fused_prefix_session_passes_inventory_guard():
     assert guard.new_compiles() == 0
     hit, total, _ev, _blocks = eng.pop_prefix_stats()
     assert hit > 0
+
+
+@pytest.mark.parametrize("spec_tokens", [0, 2], ids=["plain", "spec"])
+@pytest.mark.parametrize("prefix_cache", [False, True],
+                         ids=["no_tree", "tree"])
+def test_warmup_compiles_exactly_the_staged_domain(prefix_cache, spec_tokens):
+    """Three buckets and three widths, the tree on and off, plain and
+    speculative: after warm-up every program's cache holds exactly the
+    count worked out here from the buckets and widths alone, the
+    inventory says the same, and a session that stages at every bucket,
+    grows the cache and hits the tree compiles nothing."""
+    eng = PagedEngine(
+        make_config(length_buckets=(4, 8, 16), spec_tokens=spec_tokens),
+        slots=2, chunk=2, megastep=2, megastep_max=4,
+        prefill_chunk_tokens=4, prefix_cache=prefix_cache,
+        prefix_cache_blocks=64, prefix_block_tokens=4,
+    )
+    eng.warmup()
+    assert eng.buckets == [4, 8, 16] and len(eng.widths) == 3
+    blocks = len(eng.widths) if prefix_cache else 0
+    want = {
+        # A bucket is staged at its own width and at every wider one.
+        "_stage": 3 + 2 + 1,
+        "_megastep": len(eng.widths) * len(eng.megastep_ks),
+        "_grow": 3,              # 12->16, 12->24, 16->24
+        "_export_block": blocks,
+        "_stage_block": blocks,  # no cache here is a run of blocks wide
+        "_score": 0,
+    }
+    assert {a: getattr(eng, a)._cache_size() for a in want} == want
+    expectation = expected_from_inventory(eng)
+    assert expectation.expected == want
+    with compile_count_guard(expectation) as guard:
+        for prompt in ("k v", LONG, "hello world", LONG, "what now"):
+            eng.submit(prompt)
+        eng.drain()
+    assert guard.new_compiles() == 0
+    if prefix_cache:
+        assert eng.pop_prefix_stats()[0] > 0
 
 
 def test_unwarmed_fused_engine_fails_inventory_guard():
@@ -387,74 +455,6 @@ def test_unwarmed_fused_engine_fails_inventory_guard():
             eng.drain()
 
 
-# ------------------------------------------------------- serving queue
-
-
-class _StallingStubEngine:
-    """Paged-protocol stub whose dispatch stats report a known admission
-    stall: pins the PagedQueue emission path deterministically (driving
-    a real engine into a mid-decode arrival from the queue is a timing
-    race on CPU)."""
-
-    def __init__(self):
-        self._work = []
-        self._rid = 0
-
-    def submit(self, prompt):
-        self._rid += 1
-        self._work.append((self._rid, prompt))
-        return self._rid
-
-    @property
-    def has_work(self):
-        return bool(self._work)
-
-    backlog = 0
-
-    def step(self):
-        done, self._work = self._work[:1], self._work[1:]
-        return [(rid, f"answer to {p}") for rid, p in done]
-
-    def pop_ttfts(self):
-        return {}
-
-    def pop_dispatch_stats(self):
-        return (3, 10, 0, 12.5, 4)
-
-
-def test_paged_queue_reports_stall_metrics():
-    """The serving path surfaces the admission-stall series from
-    `pop_dispatch_stats()`: prefill_stall_ms and decode_stalled_tokens
-    counters when the engine reports a blocking admission, and neither
-    (zero) from a fused engine's real run."""
-
-    async def run(q, n):
-        await q.start()
-        answers = await asyncio.gather(
-            *[q.submit(f"query number {i}") for i in range(n)]
-        )
-        await q.close()
-        return answers
-
-    metrics = Metrics()
-    answers = asyncio.run(run(PagedQueue(_StallingStubEngine(),
-                                         metrics=metrics), 2))
-    assert len(answers) == 2
-    snap = metrics.snapshot()
-    assert snap["counters"].get("decode_stalled_tokens", 0) > 0
-    assert snap["counters"].get("prefill_stall_ms", 0) > 0
-
-    fused_metrics = Metrics()
-    fused = PagedEngine(make_config(), slots=2, chunk=2,
-                        prefill_chunk_tokens=4)
-    answers = asyncio.run(run(PagedQueue(fused, metrics=fused_metrics), 6))
-    assert len(answers) == 6
-    snap = fused_metrics.snapshot()
-    assert snap["counters"].get("decode_stalled_tokens", 0) == 0
-    assert snap["counters"].get("prefill_stall_ms", 0) == 0
-    assert fused_metrics.hist("ttft").snapshot()["count"] == 6
-
-
 # ------------------------------------------------- staged planning + knobs
 
 
@@ -464,6 +464,27 @@ def test_plan_staged_block_alignment():
     assert plan_staged(15, 20, 4) == 12   # block-aligned down
     assert plan_staged(3, 20, 4) == 0     # under one block: cold
     assert plan_staged(0, 20, 4) == 0
+
+
+@pytest.mark.parametrize("tokens", [0, -4])
+def test_a_chunk_budget_below_one_is_refused(tokens):
+    """`prefill_chunk_tokens` is a size: there is no admission path that
+    0 would select."""
+    with pytest.raises(ValueError, match="prefill_chunk_tokens"):
+        PagedEngine(make_config(), slots=2, prefill_chunk_tokens=tokens)
+
+
+def test_tutoring_config_refuses_a_chunk_budget_below_one(tmp_path):
+    from distributed_lms_raft_llm_tpu.config import (
+        TutoringConfig, load_config)
+
+    assert TutoringConfig().prefill_chunk_tokens == 32
+    with pytest.raises(ValueError, match="prefill_chunk_tokens"):
+        TutoringConfig(prefill_chunk_tokens=0)
+    toml = tmp_path / "zero.toml"
+    toml.write_text("[tutoring]\npaged = true\nprefill_chunk_tokens = 0\n")
+    with pytest.raises(ValueError, match="prefill_chunk_tokens"):
+        load_config(str(toml))
 
 
 def test_draft_source_validation():

@@ -382,12 +382,12 @@ def test_unrebound_donated_state_fails_lint():
     )
 
     project = _project_with_patch(PAGED, (
-        "self.state, *outs = self._step(\n"
-        "                    self.params, self.state, rng\n"
-        "                )",
-        "outs = self._step(\n"
-        "                    self.params, self.state, rng\n"
-        "                )[1:]",
+        "self.state, *outs = self._megastep(\n"
+        "                self.params, self.state, rngs\n"
+        "            )",
+        "outs = self._megastep(\n"
+        "                self.params, self.state, rngs\n"
+        "            )[1:]",
     ))
     findings = [
         f for f in DonationSafetyRule().check_project(project)
@@ -398,8 +398,9 @@ def test_unrebound_donated_state_fails_lint():
 
 
 def test_removing_warmup_coverage_fails_lint():
-    """Gutting warmup's step coverage (the direct step AND the drain that
-    reaches step through the call graph) must fail program-inventory —
+    """Gutting warmup's megastep coverage (the direct dispatch AND the
+    drain that reaches it through the call graph) must fail
+    program-inventory —
     the static half; partial removals that static reachability cannot see
     are the runtime guard's half (tests/test_program_inventory.py)."""
     from distributed_lms_raft_llm_tpu.analysis.rules.program_inventory import (
@@ -407,7 +408,9 @@ def test_removing_warmup_coverage_fails_lint():
     )
 
     project = _project_with_patch(PAGED, (
-        "self.state = self._step(self.params, self.state, rng)[0]",
+        "self.state = self._megastep(\n"
+        "                        self.params, self.state, rngs\n"
+        "                    )[0]",
         "pass",
     ), (
         'rid = self.submit("warmup")\n        self.drain()',
@@ -417,7 +420,7 @@ def test_removing_warmup_coverage_fails_lint():
         f for f in ProgramInventoryRule().check_project(project)
         if "warmup no longer covers" in f.message
     ]
-    assert findings, "a warmup that cannot reach _step must fail " \
+    assert findings, "a warmup that cannot reach _megastep must fail " \
         "program-inventory"
 
 
@@ -431,8 +434,8 @@ def test_donating_a_shared_prefix_block_fails_lint():
     )
 
     project = _project_with_patch(PAGED, (
-        "partial(_load_block_program), donate_argnums=(0,),",
-        "partial(_load_block_program), donate_argnums=(0, 1),",
+        "partial(_stage_block_program), donate_argnums=(0,),",
+        "partial(_stage_block_program), donate_argnums=(0, 1),",
     ))
     findings = [
         f for f in DonationSafetyRule().check_project(project)
@@ -477,8 +480,7 @@ def test_host_readback_in_staged_reap_fails_lint():
     )
 
     project = _project_with_patch(PAGED, (
-        "                col = (np.zeros((rows,), bool) if flipped is None\n"
-        "                       else flipped[:, slot])",
+        "                col = flipped[:, slot]",
         "                col = np.asarray(flipped_dev)[:, slot]",
     ))
     findings = HostSyncInDispatchRule().check(project.sources[PAGED])

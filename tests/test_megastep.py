@@ -88,16 +88,14 @@ def test_effective_megastep_max_explicit_ceiling_wins():
 
 def test_controller_shrinks_when_pending_queue_nonempty():
     """The TTFT guard: backlogged work caps K at the guaranteed
-    admission horizon. At the horizon (a slot frees within one chunk) or
-    with no horizon at all, the engine IS the chunk loop — a waiting
-    request is never delayed past the boundary a chunk loop would have
-    admitted it at."""
+    admission horizon — the largest rung that fits the chunks until a
+    slot MUST free — so a boundary falls where a waiting request can be
+    staged."""
     ladder = [1, 2, 4, 8]
-    assert next_megastep_k(8, ladder, pending=1, slack_chunks=1) == 1
-    assert next_megastep_k(8, ladder, pending=3, slack_chunks=0) == 1
-    assert next_megastep_k(8, ladder, pending=1, slack_chunks=None) == 1
+    assert next_megastep_k(8, ladder, pending=1, slack_chunks=2) == 2
     assert next_megastep_k(4, ladder, pending=1, slack_chunks=3) == 2
     assert next_megastep_k(8, ladder, pending=1, slack_chunks=5) == 4
+    assert next_megastep_k(8, [1, 2, 4, 6], pending=2, slack_chunks=7) == 6
     assert next_megastep_k(1, [1], pending=5, slack_chunks=9) == 1
 
 
@@ -175,31 +173,22 @@ def test_controller_holds_amortization_under_saturation():
     assert next_megastep_k(2, ladder, pending=1, slack_chunks=4) == 4
 
 
-def test_controller_fused_floor_is_second_rung():
-    """Satellite pin (staged chunked admission): with fusion on, a
-    boundary's only admission value is handing a freed slot to the
+def test_controller_floor_is_second_rung():
+    """A boundary's only admission value is handing a freed slot to the
     stager — the prefill itself drains through scan iterations — so the
     pending-queue shrink must NOT reach the K=1 chunk loop. K stays >= 2
-    under a non-empty pending queue at any slack, while the slack cap
-    still applies above the floor."""
+    under a non-empty pending queue at any slack, and with no horizon at
+    all (only staged requests, which bound nothing until their flip),
+    while the slack cap still applies above the floor."""
     ladder = [1, 2, 4, 8]
-    # The sequential path drops to 1 at these points; fused holds 2.
-    assert next_megastep_k(8, ladder, pending=1, slack_chunks=1,
-                           fused=True) == 2
-    assert next_megastep_k(8, ladder, pending=3, slack_chunks=0,
-                           fused=True) == 2
-    assert next_megastep_k(8, ladder, pending=1, slack_chunks=None,
-                           fused=True) == 2
+    assert next_megastep_k(8, ladder, pending=1, slack_chunks=1) == 2
+    assert next_megastep_k(8, ladder, pending=3, slack_chunks=0) == 2
+    assert next_megastep_k(8, ladder, pending=1, slack_chunks=None) == 2
     # Above the floor the slack/horizon math is unchanged.
-    assert next_megastep_k(8, ladder, pending=1, slack_chunks=5,
-                           fused=True) == 4
-    assert next_megastep_k(1, ladder, pending=16, slack_chunks=64,
-                           fused=True) == 8
-    # Idle growth identical; a [1] ladder (megastep disabled) still
-    # returns its only rung.
-    assert next_megastep_k(1, ladder, pending=0, fused=True) == 2
-    assert next_megastep_k(1, [1], pending=5, slack_chunks=0,
-                           fused=True) == 1
+    assert next_megastep_k(8, ladder, pending=1, slack_chunks=5) == 4
+    assert next_megastep_k(1, ladder, pending=16, slack_chunks=64) == 8
+    # A [1] ladder (megastep disabled) still returns its only rung.
+    assert next_megastep_k(1, [1], pending=5, slack_chunks=0) == 1
 
 
 def test_controller_grows_toward_max_when_idle():
@@ -210,23 +199,38 @@ def test_controller_grows_toward_max_when_idle():
     assert next_megastep_k(1, [1], pending=0) == 1     # disabled
 
 
-def test_engine_controller_tracks_admission_horizon():
-    """Through the real engine: a backlog keeps K wide while no slot can
-    free (slack = remaining budget), steps K down to the floor once the
-    dispatched debt covers the guaranteed finish, and pops back up the
-    moment the freed lanes refill — amortization under saturation,
-    chunk-loop admission timing at the boundary."""
-    eng = PagedEngine(make_config(), slots=2, chunk=2,
-                      megastep=4, megastep_max=4)
+def test_engine_controller_tracks_admission_horizon(monkeypatch):
+    """Through the real engine: a staged request bounds no horizon until
+    its flip is reaped (the floor meanwhile); then a backlog keeps K wide
+    while no slot can free (slack = remaining budget) and steps K down to
+    the floor once the dispatched debt covers the guaranteed finish —
+    amortization under saturation, a boundary where a slot can be handed
+    on, and never the K=1 chunk loop while work waits."""
+    from distributed_lms_raft_llm_tpu.engine import paged as paged_mod
+
+    calls = []  # (pending, slack, K chosen), one per dispatch
+    real = paged_mod.next_megastep_k
+
+    def spy(current, ladder, pending, slack):
+        k = real(current, ladder, pending, slack)
+        calls.append((pending, slack, k))
+        return k
+
+    monkeypatch.setattr(paged_mod, "next_megastep_k", spy)
+    eng = PagedEngine(
+        make_config(sampling=SamplingParams.greedy(max_new_tokens=22)),
+        slots=2, chunk=2, megastep=4, megastep_max=4)
     for i in range(6):
         eng.submit(f"question number {i}")
-    eng.step()  # 2 admitted (7 budget tokens left -> 4-chunk horizon)
-    assert eng.megastep_k == 4
-    eng.step()  # in-flight megastep covers the horizon -> boundary K
-    assert eng.megastep_k == 1
-    eng.step()  # wave reaped, lanes refilled from the backlog -> wide
-    assert eng.megastep_k == 4
+    eng.step()  # 2 staged, 4 waiting: nothing live bounds the horizon
+    assert calls == [(4, None, 2)] and eng.megastep_k == 2
     eng.drain()
+    backlog = [(slack, k) for pending, slack, k in calls if pending]
+    assert all(k >= 2 for _, k in backlog)
+    wide = [slack for slack, k in backlog if k == 4]
+    assert wide and min(wide) >= 4       # wide only while no slot can free
+    floor = [slack for slack, k in backlog if k == 2 and slack is not None]
+    assert floor and max(floor) < 4      # down at the guaranteed finish
 
 
 # ------------------------------------------------------- greedy bit-equality
@@ -336,11 +340,11 @@ def test_mid_megastep_admission_joins_at_next_boundary():
 
 def test_step_dispatches_per_token_reduced_4x_at_k4():
     """The megastep's target number: at K=4 the host pays 4x fewer
-    decode-step dispatches per emitted token than the chunk loop (the
-    per-request prefill+install dispatches are admission constants that
+    decode dispatches per emitted token than at K=1 (the
+    per-request stage dispatch is an admission constant that
     megastep does not touch; the chunk loop proper is what it removes).
     inflight=1 keeps the dispatch count exact (no pipelined overhang)."""
-    max_new = 17  # 1 admission token + 16 decode steps at chunk=1
+    max_new = 17  # 1 first token + 16 decode steps at chunk=1
     cfg = make_config(
         sampling=SamplingParams.greedy(max_new_tokens=max_new),
         length_buckets=(8,),
@@ -352,11 +356,10 @@ def test_step_dispatches_per_token_reduced_4x_at_k4():
                           megastep=megastep, megastep_max=megastep)
         eng.submit(prompt)
         eng.drain()
-        dispatches, tokens, _dead, _stall, _stalled = \
-            eng.pop_dispatch_stats()
+        dispatches, tokens, _dead = eng.pop_dispatch_stats()
         steps = sum(
             1 for name, _, _ in eng.pop_program_times()
-            if name in ("step", "megastep")
+            if name == "megastep"
         )
         return dispatches, tokens, steps
 
@@ -421,8 +424,9 @@ def test_dead_lane_account_matches_first_principles():
     rngs = jnp.stack([jax.random.key(1)] + [
         jax.random.key(100 + i) for i in range(k_chunks - 1)
     ])
-    _, _, active, dead = _megastep_program(
-        params, state, rngs, eos_id=eos, spec_tokens=0, **statics
+    _, _, active, dead, _, _ = _megastep_program(
+        params, state, rngs, eos_id=eos, spec_tokens=0, prefill_chunk=4,
+        **statics
     )
     active = np.asarray(active)
     assert active[0, 0] == 0 and all(active[:, 1] == 1)
@@ -432,14 +436,18 @@ def test_dead_lane_account_matches_first_principles():
 
 
 def test_k1_dispatches_account_no_dead_lanes():
-    """Chunk-loop mode reaps every chunk, so the dead-lane account stays
-    zero by construction."""
+    """At rung 1 the host reaps every chunk, so the megastep's dead-lane
+    account stays zero by construction."""
     eng = PagedEngine(make_config(), slots=2, chunk=2)
+    assert eng.megastep_ks == [1]
     for p in PROMPTS[:2]:
         eng.submit(p)
     eng.drain()
-    _, _, dead, _, _ = eng.pop_dispatch_stats()
-    assert dead == 0
+    dispatches, tokens, dead = eng.pop_dispatch_stats()
+    assert dead == 0 and tokens == 2 * MAX_NEW
+    names = [name for name, _, _ in eng.pop_program_times()]
+    assert dispatches == len(names) and set(names) == {"stage", "megastep"}
+    assert eng.pop_dispatch_stats() == (0, 0, 0)
 
 
 # --------------------------------------------- warmup / inventory coverage
@@ -447,7 +455,7 @@ def test_k1_dispatches_account_no_dead_lanes():
 
 def test_warmed_megastep_session_passes_inventory_guard():
     """compile_count_guard(expected_from_inventory(...)): warmup compiles
-    the full megastep domain (widths x ladder rungs >= 2) and a live
+    the full megastep domain (widths x ladder rungs) and a live
     session that walks the controller across rungs, churns slots, and
     grows the cache adds ZERO programs."""
     eng = PagedEngine(
@@ -457,7 +465,7 @@ def test_warmed_megastep_session_passes_inventory_guard():
     assert eng.megastep_ks == [1, 2, 4]
     eng.warmup()
     expectation = expected_from_inventory(eng)
-    assert expectation.expected["_megastep"] == len(eng.widths) * 2
+    assert expectation.expected["_megastep"] == len(eng.widths) * 3
     assert expectation.mismatches() == {}
     with compile_count_guard(expectation) as guard:
         eng.submit("k v")

@@ -86,21 +86,21 @@ def test_pipelined_outputs_match_serialized():
 
 
 def test_greedy_parity_with_prompt_buckets_and_churn():
-    """Per-prompt prefill buckets (short prompt -> narrow prefill program)
+    """Per-prompt buckets (short prompt -> narrow `_stage` program)
     plus slot reuse: answers still match the bucketed engine exactly."""
     cfg = make_config(length_buckets=(4, 8, 16))
     prompts = list(PROMPTS) + ["k v"]
     expected = TutoringEngine(cfg).answer_batch(prompts)
     paged = PagedEngine(cfg, slots=2)  # 5 requests churn through 2 slots
     widths = set()
-    real_prefill = paged._prefill
-    paged._prefill = lambda params, ids, *a, **kw: (
-        widths.add(ids.shape[1]) or real_prefill(params, ids, *a, **kw)
+    real_stage = paged._stage
+    paged._stage = lambda state, slot, ids, *a: (
+        widths.add(ids.shape[1]) or real_stage(state, slot, ids, *a)
     )
     rids = [paged.submit(p) for p in prompts]
     out = paged.drain()
     assert [out[r] for r in rids] == expected
-    # Short prompts really took narrower prefill programs.
+    # Short prompts really were staged in narrower prompt buckets.
     assert len(widths) >= 2 and min(widths) < 16, widths
 
 
@@ -135,21 +135,15 @@ def test_cache_width_grows_and_shrinks_with_prompt_mix():
 
 
 def test_slot_reuse_evict_then_readmit():
-    """slots=1 forces the second request through an evict→re-admit cycle in
-    the same slot; outputs must match sequential fresh-drain runs."""
+    """slots=1 forces the second request through a hand-on into the slot
+    the first one held; both answers must be the bucketed engine's."""
     cfg = make_config()
-    sequential = PagedEngine(cfg, slots=1)
-    r1 = sequential.submit(PROMPTS[0])
-    out1 = sequential.drain()
-    r2 = sequential.submit(PROMPTS[1])
-    out2 = sequential.drain()
-
-    fresh = PagedEngine(cfg, slots=1)
-    f1 = fresh.submit(PROMPTS[0])
-    f2 = fresh.submit(PROMPTS[1])
-    both = fresh.drain()
-    assert both[f1] == out1[r1]
-    assert both[f2] == out2[r2]
+    expected = TutoringEngine(cfg).answer_batch(list(PROMPTS[:2]))
+    paged = PagedEngine(cfg, slots=1)
+    f1 = paged.submit(PROMPTS[0])
+    f2 = paged.submit(PROMPTS[1])
+    both = paged.drain()
+    assert [both[f1], both[f2]] == expected
 
 
 def test_overflow_budget_clamped_or_rejected():
@@ -217,28 +211,13 @@ def test_paged_queue_recovers_after_step_failure():
     assert isinstance(asyncio.run(run()), str)
 
 
-def test_dead_slot_pad_filler_not_appended_when_pad_differs_from_eos():
+def test_dead_slot_pad_filler_not_appended_when_pad_differs_from_eos(
+        eos_first_engine):
     """Regression (review): with a tokenizer where pad != eos, a slot that
     is inactive from admission (first sampled token is eos) must return an
     empty answer — chunk pad filler is not content."""
-    import numpy as np
-
-    from distributed_lms_raft_llm_tpu.engine.paged import PagedEngine
-
-    paged = PagedEngine(make_config(), slots=2)
-    # Force pad != eos and make admission sample eos immediately by
-    # stubbing the prefill program's sampled first token.
-    paged.tokenizer.pad_id = 0
-    assert paged.tokenizer.eos_id != 0
-    real_prefill = paged._prefill
-
-    def eos_first(params, ids, true_len, rng):
-        cache, _first, seen = real_prefill(params, ids, true_len, rng)
-        import jax.numpy as jnp
-
-        return cache, jnp.asarray(paged.tokenizer.eos_id, jnp.int32), seen
-
-    paged._prefill = eos_first
+    paged = eos_first_engine(make_config(), "anything at all", slots=2)
+    assert paged.tokenizer.eos_id != paged.tokenizer.pad_id
     rid = paged.submit("anything at all")
     out = paged.drain()
     # The request finished with no pad-filler tokens decoded as content.

@@ -218,20 +218,21 @@ def test_stochastic_session_plausible_and_observable():
 
 
 def test_step_program_compiles_once_per_width():
-    """No silent per-step recompiles: the spec step program compiles
-    exactly once per (S, k, width) — S and k are fixed per engine, so once
-    per width — during warmup, and a live session that churns slots,
+    """No silent per-step recompiles: the megastep of the spec step
+    compiles exactly once per (S, k, width, rung) — S and k are fixed per
+    engine, so once per width and rung — during warmup, and a live session that churns slots,
     rebuilds at both widths, and grows the cache mid-batch adds ZERO
     compilations (historically the spelling of replicated shardings
-    differed between the install/grow/step producers, so warmup's compile
+    differed between the state's producers, so warmup's compile
     did not cover the live handoffs — see paged._state_spec)."""
     eng = PagedEngine(
         make_config(length_buckets=(4, 16)), slots=2, chunk=2
     )
     assert len(eng.widths) == 2
     eng.warmup()
-    programs = (eng._step, eng._install, eng._prefill, eng._grow)
-    assert programs[0]._cache_size() == len(eng.widths)
+    programs = (eng._megastep, eng._stage, eng._grow)
+    assert programs[0]._cache_size() == (
+        len(eng.widths) * len(eng.megastep_ks))
     short, lng = "k v", "a long question about raft elections and logs"
     # The reusable runtime guard (utils/guards.py) generalizes this
     # assertion: zero new programs across the whole live session.
@@ -245,20 +246,13 @@ def test_step_program_compiles_once_per_width():
         eng.drain()
 
 
-def test_dead_slot_emits_no_filler_when_pad_differs_from_eos():
+def test_dead_slot_emits_no_filler_when_pad_differs_from_eos(
+        eos_first_engine):
     """A slot inactive from admission (first sampled token is eos) emits
     zero-count windows — the spec reap must return an empty answer even
     when pad != eos (no filler misread as content)."""
-    paged = PagedEngine(make_config(), slots=2)
-    paged.tokenizer.pad_id = 0
-    assert paged.tokenizer.eos_id != 0
-    real_prefill = paged._prefill
-
-    def eos_first(params, ids, true_len, rng):
-        cache, _first, seen = real_prefill(params, ids, true_len, rng)
-        return cache, jnp.asarray(paged.tokenizer.eos_id, jnp.int32), seen
-
-    paged._prefill = eos_first
+    paged = eos_first_engine(make_config(), "anything at all", slots=2)
+    assert paged.tokenizer.eos_id != paged.tokenizer.pad_id
     rid = paged.submit("anything at all")
     out = paged.drain()
     assert out[rid] == paged.tokenizer.decode([])

@@ -2,13 +2,13 @@
 
 The cache changes WHERE prompt KV comes from, never WHAT the device
 computes: a cache-hit generation must equal the cold-prefill generation
-token for token, across every engine configuration (plain, speculative,
-kv-quant, megastep, megastep+spec). On top of exactness: the radix
+token for token, and both the bucketed engine's, across every engine
+configuration (plain, speculative, kv-quant, megastep, megastep+spec). On top of exactness: the radix
 tree's structure (longest-prefix lookup, insert-with-split, LRU
 eviction) is pinned at the unit level, eviction under pressure never
 frees a block a live slot references (ref-count pin), slot churn with
-interleaved hits and misses stays correct, the whole partial-prefill
-program domain is warmup-covered (`expected_from_inventory` equality),
+interleaved hits and misses stays correct, a hit whose suffix crosses a
+prompt-bucket edge splices into a wider cache,
 the serving queue surfaces the new hit-rate/eviction/blocks gauges, and
 the sim workload's same-course concentration knob produces the
 deterministic shared prefixes the cache targets.
@@ -27,10 +27,7 @@ from distributed_lms_raft_llm_tpu.engine import (
     SamplingParams,
     TutoringEngine,
 )
-from distributed_lms_raft_llm_tpu.engine.prefix_cache import (
-    PrefixCache,
-    plan_partial,
-)
+from distributed_lms_raft_llm_tpu.engine.prefix_cache import PrefixCache
 from distributed_lms_raft_llm_tpu.sim import workload as wl
 from distributed_lms_raft_llm_tpu.sim.slo import evaluate_slos
 from distributed_lms_raft_llm_tpu.utils.guards import (
@@ -63,6 +60,16 @@ def make_config(**kw):
         dtype=jnp.float32,
         **kw,
     )
+
+
+def bucketed(cfg, prompts):
+    """The reference: the bucketed engine's answers (no slots, no staged
+    admission, no tree). Speculation is exact, so its plain decode
+    stands for a speculative config too."""
+    import dataclasses
+
+    return TutoringEngine(
+        dataclasses.replace(cfg, spec_tokens=0)).answer_batch(list(prompts))
 
 
 def make_engine(cfg=None, **kw):
@@ -167,40 +174,14 @@ def test_tree_split_keeps_pin_on_deep_node():
     assert pc.lookup(ints(8) + [9]).tokens == 8
 
 
-def test_plan_partial_fits_static_domain():
-    buckets = (8, 16, 32)
-    # Plain hit: block-aligned prefix, smallest suffix bucket that fits.
-    assert plan_partial(8, 20, 32, buckets, 4) == (8, 16)
-    # Smallest admissible suffix wins; the prefix shrinks to fit the
-    # window (blocks are given back rather than overrunning).
-    assert plan_partial(28, 32, 32, buckets, 4) == (24, 8)
-    assert plan_partial(28, 32, 32, (16, 32), 4) == (16, 16)
-    # Hit floor: less than one block of usable prefix => cold.
-    assert plan_partial(3, 10, 16, buckets, 4) == (0, 0)
-    # prefix_used never reaches true_len (>= 1 recomputed token).
-    p, s = plan_partial(16, 16, 16, buckets, 4)
-    assert p < 16 and (p == 0 or 16 - p <= s)
-    # Returned prefix is always block-aligned and window-safe.
-    for hit in (4, 8, 12, 16, 24, 28):
-        for tl in (9, 15, 17, 29, 32):
-            p, s = plan_partial(hit, tl, 32, buckets, 4)
-            if p:
-                assert p % 4 == 0 and p + s <= 32 and tl - p <= s
-
-
 # ------------------------------------------------------- greedy bit-equality
 
 
 class TestCacheHitBitEquality:
-    def _expected(self, cfg, prompts):
-        base = PagedEngine(cfg, slots=2, chunk=2)
-        rids = [base.submit(p) for p in prompts]
-        out = base.drain()
-        return [out[r] for r in rids]
-
     def _assert_two_passes_match(self, eng, prompts, expected):
         """Pass 1 seeds the tree (later same-course requests already
-        hit); pass 2 is fully warm. Both must equal the cold engine."""
+        hit); pass 2 is fully warm. Both must equal the bucketed
+        engine."""
         for pass_no in (1, 2):
             rids = [eng.submit(p) for p in prompts]
             out = eng.drain()
@@ -210,37 +191,40 @@ class TestCacheHitBitEquality:
 
     def test_plain_matches_cold_and_bucketed(self):
         cfg = make_config()
-        expected = self._expected(cfg, PROMPTS)
-        assert expected == TutoringEngine(cfg).answer_batch(list(PROMPTS))
+        expected = bucketed(cfg, PROMPTS)
+        cold = make_engine(cfg, prefix_cache=False)
+        rids = [cold.submit(p) for p in PROMPTS]
+        out = cold.drain()
+        assert [out[r] for r in rids] == expected
         self._assert_two_passes_match(make_engine(cfg), PROMPTS, expected)
 
     @pytest.mark.parametrize("spec_tokens", [2])
     def test_spec_mode(self, spec_tokens):
         cfg = make_config(spec_tokens=spec_tokens)
-        expected = self._expected(cfg, PROMPTS)
+        expected = bucketed(cfg, PROMPTS)
         self._assert_two_passes_match(make_engine(cfg), PROMPTS, expected)
 
     def test_kv_quant(self):
         cfg = make_config(kv_quant=True)
-        expected = self._expected(cfg, PROMPTS)
+        expected = bucketed(cfg, PROMPTS)
         self._assert_two_passes_match(make_engine(cfg), PROMPTS, expected)
 
     def test_megastep(self):
         cfg = make_config()
-        expected = self._expected(cfg, PROMPTS)
+        expected = bucketed(cfg, PROMPTS)
         eng = make_engine(cfg, megastep=4, megastep_max=4)
         self._assert_two_passes_match(eng, PROMPTS, expected)
 
     def test_megastep_with_spec(self):
         cfg = make_config(spec_tokens=2)
-        expected = self._expected(cfg, PROMPTS)
+        expected = bucketed(cfg, PROMPTS)
         eng = make_engine(cfg, megastep=4, megastep_max=4)
         self._assert_two_passes_match(eng, PROMPTS, expected)
 
 
 def test_slot_churn_interleaved_hits_and_misses():
     """More requests than slots, hits and misses interleaved: every
-    stream must match the cache-off engine while the tree is being
+    stream must match the bucketed engine while the tree is being
     built, hit, split, and re-hit under churn."""
     cfg = make_config()
     prompts = [
@@ -253,14 +237,10 @@ def test_slot_churn_interleaved_hits_and_misses():
         "what is paging?",
         CTX + "replicating a log entry",    # repeat again
     ]
-    base = PagedEngine(cfg, slots=2, chunk=2)
-    rb = [base.submit(p) for p in prompts]
-    out_base = base.drain()
-
     eng = make_engine(cfg)
     re_ = [eng.submit(p) for p in prompts]
     out = eng.drain()
-    assert [out[a] for a in re_] == [out_base[b] for b in rb]
+    assert [out[a] for a in re_] == bucketed(cfg, prompts)
     hits = eng.pop_prefix_hits()
     assert len(hits) == len(prompts)
     assert any(v > 0 for v in hits.values())
@@ -270,7 +250,7 @@ def test_slot_churn_interleaved_hits_and_misses():
 def test_eviction_under_pressure_keeps_live_pins_and_stays_exact():
     """A tiny block budget under heavy distinct-prefix churn: evictions
     happen, pinned (in-flight) paths are never freed, and outputs still
-    equal the cache-off engine."""
+    equal the bucketed engine's."""
     cfg = make_config()
     # Budget = ONE prompt's blocks: every distinct publish overruns and
     # evicts; adjacent repeats hit (and pin) before churn can evict them.
@@ -278,10 +258,6 @@ def test_eviction_under_pressure_keeps_live_pins_and_stays_exact():
     prompts += [PROMPTS[0], PROMPTS[0]]
     prompts += [f"more cold churn number {i} ok" for i in range(3)]
     prompts += [PROMPTS[1], PROMPTS[1]]
-    base = PagedEngine(cfg, slots=2, chunk=2)
-    rb = [base.submit(p) for p in prompts]
-    out_base = base.drain()
-
     eng = make_engine(cfg, prefix_cache_blocks=8)
     re_ = [eng.submit(p) for p in prompts]
     # Step (not drain) so we can observe live pins mid-flight.
@@ -295,7 +271,7 @@ def test_eviction_under_pressure_keeps_live_pins_and_stays_exact():
             # The pinned path's deepest node must still be reachable in
             # the tree (eviction never freed a live slot's blocks).
             assert pin.nodes[-1].refs > 0
-    assert [out[a] for a in re_] == [out_base[b] for b in rb]
+    assert [out[a] for a in re_] == bucketed(cfg, prompts)
     assert saw_pin
     assert not eng._prefix_pins  # all released at completion
     hit, total, evicted, blocks_used = eng.pop_prefix_stats()
@@ -306,7 +282,9 @@ def test_eviction_under_pressure_keeps_live_pins_and_stays_exact():
 def test_reset_releases_pins_but_keeps_tree():
     eng = make_engine()
     eng.submit(PROMPTS[0])
-    eng.step()  # admitted; publish happened, possibly pinned
+    while not eng.prefix_cache.blocks_used:
+        eng.step()  # staged, then flipped: the flip's reap publishes
+    assert eng.has_work, "reset with the request still in its slot"
     blocks_before = eng.prefix_cache.blocks_used
     assert blocks_before > 0
     eng.reset()
@@ -325,39 +303,15 @@ def test_reset_releases_pins_but_keeps_tree():
 # ------------------------------------------------- compile-once acceptance
 
 
-def test_partial_prefill_domain_is_warmup_covered():
-    """The acceptance pin: warmup compiles exactly the inventoried
-    program set (partial-prefill pairs, block export/load included), and
-    a live session mixing cold misses, partial hits, deep repeats, and
-    eviction pressure adds ZERO programs."""
-    eng = make_engine(make_config(length_buckets=(8, 16)),
-                      prefix_cache_blocks=8)
-    eng.warmup()
-    expectation = expected_from_inventory(eng)
-    assert expectation.mismatches() == {}
-    # Adjacent repeats hit before LRU churn (budget 8 blocks vs ~4 per
-    # prompt) can evict them; the distinct prompts force evictions.
-    workload = [p for prompt in PROMPTS for p in (prompt, prompt)]
-    workload += ["one more cold miss"]
-    with compile_count_guard(expectation) as guard:
-        for p in workload:
-            eng.submit(p)
-        eng.drain()
-    assert guard.new_compiles() == 0
-    hit, _total, evicted, _blocks = eng.pop_prefix_stats()
-    assert hit > 0 and evicted > 0
-
-
 def test_disabled_prefix_cache_expects_zero_programs():
-    """With the cache off, the partial/export/load wrappers exist but
+    """With the cache off, the block export/splice wrappers exist but
     their expected (and actual) program counts are zero — the manifest
     stays exact in both modes."""
     eng = PagedEngine(make_config(length_buckets=(8,)), slots=2, chunk=2)
     eng.warmup()
     expectation = expected_from_inventory(eng)
-    assert expectation.expected["_partial_prefill"] == 0
     assert expectation.expected["_export_block"] == 0
-    assert expectation.expected["_load_block"] == 0
+    assert expectation.expected["_stage_block"] == 0
     assert expectation.mismatches() == {}
 
 

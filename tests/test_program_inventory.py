@@ -68,8 +68,7 @@ def test_manifest_and_readme_match_static_scan():
 
 def test_manifest_covers_the_paged_program_set():
     attrs = {e.attr for e in inv.entries_for("PagedEngine")}
-    assert attrs == {"_prefill", "_install", "_step", "_megastep", "_grow",
-                     "_partial_prefill", "_load_block", "_export_block",
+    assert attrs == {"_megastep", "_grow", "_export_block",
                      "_stage", "_stage_block", "_score"}
     assert all(
         e.coverage == "warmup" for e in inv.entries_for("PagedEngine")
@@ -94,16 +93,21 @@ def test_static_domain_math_is_engine_math():
         )
         assert dom["widths"] == list(eng.widths)
         assert max(dom["buckets"]) <= eng.bucket
-    # The shared-prefix domain: zero with the cache off, the admissible
-    # (bucket, suffix-bucket) pairs (one whole block of prefix must fit
-    # the window) with it on.
+    # The shared-prefix domain: zero with the cache off, and where no
+    # bucket can hold a block; a program a width with it on.
     off = inv.static_paged_domain(64, 8, (8, 16), 0)
-    assert off["partial_pairs"] == off["export_buckets"] == 0
+    assert off["stage_block_widths"] == off["export_widths"] == 0
+    assert off["stage_pairs"] == 3    # (8,16) (8,24) (16,24)
     on = inv.static_paged_domain(64, 8, (8, 16), 0, prefix_cache=True,
                                  prefix_block_tokens=4)
-    assert on["partial_pairs"] == 1   # only (t=16, s=8) admits a block
-    assert on["export_buckets"] == 2  # both buckets can publish
-    assert on["load_buckets"] == 1    # only t=16 can splice
+    assert on["export_widths"] == 2       # a block leaves either width
+    assert on["stage_block_widths"] == 2  # and is spliced at either
+    none = inv.static_paged_domain(64, 8, (8, 16), 0, prefix_cache=True,
+                                   prefix_block_tokens=32)
+    assert none["stage_block_widths"] == none["export_widths"] == 0
+    # One megastep program per width and rung, rung 1 included.
+    assert inv.static_paged_domain(
+        64, 8, (8, 16), 0, megastep_max=4)["megastep_pairs"] == 2 * 3
 
 
 # ------------------------------------------------- runtime cross-validation
@@ -143,7 +147,7 @@ def test_stale_inventory_expectation_fails_the_guard():
     eng = make_engine()
     eng.warmup()
     expectation = expected_from_inventory(eng)
-    expectation.expected["_step"] += 1  # simulate a stale manifest claim
+    expectation.expected["_megastep"] += 1  # simulate a stale manifest claim
     with pytest.raises(InventoryMismatchError, match="stale"):
         with compile_count_guard(expectation):
             pass

@@ -565,7 +565,7 @@ def test_semester_sim_soak_scaled(tmp_path):
     # questions, so the radix cache serves a real measured hit rate in
     # the verdict (at tiny scale the engine's 32-token window truncates
     # the shared context, so these are verbatim-repeat hits — the
-    # lookup/splice/partial-prefill path, not cross-question context
-    # sharing, which bench.py's shared-prefix scenario pins instead).
+    # lookup/splice/suffix-prefill path, not cross-question context
+    # sharing, which tests/test_prefix_cache.py pins instead).
     assert record["prefix_cache_hit_rate"] is not None
     assert record["prefix_cache_hit_rate"] > 0.2

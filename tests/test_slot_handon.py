@@ -6,7 +6,7 @@ inside the dispatches already in flight (`rows_to_certain_end` == 0), and
 no longer two dispatches after that end has been reaped. The slot then
 carries two requests at once: the departing one lives on in the in-flight
 snapshots until `_walk` finishes it, the successor sits in `_slot_req`.
-Pinned here: greedy streams stay the sequential engine's, request by
+Pinned here: greedy streams stay the bucketed engine's, request by
 request and to the last token; the departing request keeps streaming; the
 kill of a finished slot never hits a successor; a session turn's slot
 waits for its reap; speculative windows and eos endings fall under the
@@ -14,12 +14,14 @@ same test; and the lane account still sums.
 """
 
 import jax.numpy as jnp
+import numpy as np
 import pytest
 
 from distributed_lms_raft_llm_tpu.engine import (
     EngineConfig,
     PagedEngine,
     SamplingParams,
+    TutoringEngine,
 )
 from distributed_lms_raft_llm_tpu.engine import paged as paged_mod
 from distributed_lms_raft_llm_tpu.utils import metrics_registry
@@ -47,17 +49,29 @@ def fused_engine(max_new, spec_tokens=0, **kw):
                        megastep_max=2, prefill_chunk_tokens=4, **kw)
 
 
-_SEQUENTIAL = {}
+_BUCKETED = {}
 
 
-def sequential_tokens(max_new, prompts):
-    """Every prompt's token list from the sequential paged engine (no
-    fused admission, so no hand-on), memoized per budget."""
+def bucketed_tokens(max_new, prompts, memo=True):
+    """Every prompt's token list (eos filtered, as `pop_final_tokens`
+    gives them) from the bucketed engine, which shares nothing of
+    admission, the scan or the hand-on; memoized per budget."""
     key = (max_new, tuple(prompts))
-    if key not in _SEQUENTIAL:
-        eng = PagedEngine(make_config(max_new), slots=2, chunk=2)
-        _SEQUENTIAL[key] = run(eng, prompts)[1]
-    return _SEQUENTIAL[key]
+    if memo and key in _BUCKETED:
+        return _BUCKETED[key]
+    eng = TutoringEngine(make_config(max_new))
+    cap = max(eng.config.batch_buckets)
+    out = []
+    for i in range(0, len(prompts), cap):
+        group = prompts[i:i + cap]
+        ids, mask, _ = eng.encode_prompts(group)
+        res = eng.generate_ids(ids, mask, real_rows=len(group))
+        toks, lengths = np.asarray(res.tokens), np.asarray(res.lengths)
+        out += [[t for t in toks[j, :lengths[j]].tolist()
+                 if t != eng.tokenizer.eos_id] for j in range(len(group))]
+    if memo:
+        _BUCKETED[key] = out
+    return out
 
 
 def departing(eng):
@@ -99,14 +113,14 @@ def run(eng, prompts, each_step=None):
 
 
 @pytest.mark.parametrize("max_new", [4, 8, 16, 24])
-def test_greedy_streams_equal_the_sequential_engines(max_new):
+def test_greedy_streams_equal_the_bucketed_engines(max_new):
     """More requests than slots, inflight 3, K = 2: every request's tokens
-    are the sequential engine's and each has exactly its budget. At a
+    are the bucketed engine's and each has exactly its budget. At a
     budget of 4, one dispatch's rows, an answer that flips early ends
     inside its first dispatch and is never live on the host in time, and
     one that flips late is handed on at its first reap: the same test
     decides, not a second path."""
-    expected = sequential_tokens(max_new, PROMPTS)
+    expected = bucketed_tokens(max_new, PROMPTS)
     assert all(len(t) == max_new for t in expected), "no eos at tiny size"
     _, got, counts = run(fused_engine(max_new), PROMPTS)
     assert got == expected
@@ -146,7 +160,7 @@ def test_successor_is_staged_before_the_reap_and_outlives_it():
                 assert bool(state.staged[slot]) or bool(state.active[slot])
 
     _, got, counts = run(eng, PROMPTS, each_step=watch)
-    assert got == sequential_tokens(max_new, PROMPTS)
+    assert got == bucketed_tokens(max_new, PROMPTS)
     assert counts["slots_handed_on"] == len(seen) > 0
     assert sorted(outlived) == sorted(seen)
     # Handed on with tokens still to come: the end really was unreaped.
@@ -190,9 +204,9 @@ def test_the_k_controller_still_sees_the_backlog_a_hand_on_took(monkeypatch):
     seen = []  # (backlog the controller was given, pending, departing)
     real = paged_mod.next_megastep_k
 
-    def spy(current, ladder, pending, slack, fused=False):
+    def spy(current, ladder, pending, slack):
         seen.append((pending, len(eng._pending), len(eng._departing())))
-        return real(current, ladder, pending, slack, fused=fused)
+        return real(current, ladder, pending, slack)
 
     monkeypatch.setattr(paged_mod, "next_megastep_k", spy)
     eng = PagedEngine(make_config(16), slots=2, chunk=2, inflight=3,
@@ -280,7 +294,7 @@ def test_speculative_windows_fall_under_the_same_test(spec_tokens):
     max_new = 16
     eng = fused_engine(max_new, spec_tokens=spec_tokens)
     _, got, counts = run(eng, PROMPTS, each_step=assert_no_early_hand_on)
-    assert got == sequential_tokens(max_new, PROMPTS)
+    assert got == bucketed_tokens(max_new, PROMPTS)
     assert counts.get("slots_handed_on", 0) > 0
 
 
@@ -288,9 +302,9 @@ def test_an_eos_ending_comes_sooner_and_changes_nothing(monkeypatch):
     """With a token of the greedy streams declared eos, answers end early
     and at different lengths: a request is handed on only by its budget,
     may then end by eos inside the work in flight, and every stream still
-    equals the sequential engine's."""
+    equals the bucketed engine's."""
     max_new = 16
-    plain = sequential_tokens(max_new, PROMPTS)
+    plain = bucketed_tokens(max_new, PROMPTS)
     eos = plain[0][6]
     load = paged_mod.tok_lib.load_gpt2_tokenizer
 
@@ -300,8 +314,7 @@ def test_an_eos_ending_comes_sooner_and_changes_nothing(monkeypatch):
         return tok
 
     monkeypatch.setattr(paged_mod.tok_lib, "load_gpt2_tokenizer", with_eos)
-    seq = PagedEngine(make_config(max_new), slots=2, chunk=2)
-    expected = run(seq, PROMPTS)[1]
+    expected = bucketed_tokens(max_new, PROMPTS, memo=False)
     assert any(len(t) < max_new for t in expected), "some answer ends by eos"
     assert any(len(t) == max_new for t in expected), "and some by budget"
     eng = fused_engine(max_new)
@@ -322,7 +335,7 @@ def test_lane_counters_sum_and_the_overrun_shrinks(monkeypatch):
 
     def account(eng):
         _, got, c = run(eng, PROMPTS)
-        _, emitted, dead, _, _ = eng.pop_dispatch_stats()
+        _, emitted, dead = eng.pop_dispatch_stats()
         assert emitted == n * max_new
         decode = emitted - n  # first tokens are the prefill's
         parts = (decode + dead + c["staged_lane_steps"]

@@ -259,8 +259,7 @@ def _tiny_paged(metrics, **kw):
         sampling=SamplingParams.greedy(max_new_tokens=8),
         # 56 = the tiny position table (64) minus max_new: the largest
         # bucket the engine admits without tail-truncating the prompt.
-        # The 32 bucket gives plan_partial a suffix window a turn-2
-        # splice fits into (prefix_used + suffix_bucket <= bucket).
+        # The 32 bucket is the width a turn-2 splice runs at.
         length_buckets=(16, 32, 56), batch_buckets=(1, 2, 4),
         dtype=jnp.float32,
     )
