@@ -601,11 +601,14 @@ def _admission_chunk(params, s: SlotState, *, cfg, sampling, model,
     megastep's entry and is served, oldest first, a chunk an iteration
     until none is left).
 
-    If any slot is staged: slice that slot's pages out of the live cache,
-    forward the next `prefill_chunk` prompt ids from its transcript row
-    (KV scatters at the per-row ragged cursor offset — out-of-range pad
+    If any slot is staged: forward the next `prefill_chunk` prompt ids
+    from its transcript row onto that slot's pages of the live cache, in
+    place (`forward`'s `rows`: the chunk is a batch of one that addresses
+    row `slot`; KV scatters at the ragged cursor offset — out-of-range pad
     tails of the final chunk are dropped by the scatter, never clamped
-    into real pages), and splice the updated pages back. When the cursor
+    into real pages — and the attention reads that row alone; slicing the
+    slot's pages out and splicing them back made the compiler relay the
+    whole cache four times a chunk). When the cursor
     covers the true length, the flip: sample the first token from the
     last real position's logits with the staged rng and the full-prompt
     seen mask, then mark the slot live (length=true_len, transcript gains
@@ -637,22 +640,6 @@ def _admission_chunk(params, s: SlotState, *, cfg, sampling, model,
         ).astype(jnp.int32)
         cur = s.stage_cursor[slot]
         tl = s.stage_len[slot]
-        l, _, h, w, dh = s.cache.k.shape
-        ck = jax.lax.dynamic_slice(
-            s.cache.k, (zero, slot, zero, zero, zero), (l, 1, h, w, dh)
-        )
-        cv = jax.lax.dynamic_slice(
-            s.cache.v, (zero, slot, zero, zero, zero), (l, 1, h, w, dh)
-        )
-        cks = cvs = None
-        if s.cache.quantized:
-            cks = jax.lax.dynamic_slice(
-                s.cache.ks, (zero, slot, zero, zero), (l, 1, h, w)
-            )
-            cvs = jax.lax.dynamic_slice(
-                s.cache.vs, (zero, slot, zero, zero), (l, 1, h, w)
-            )
-        c1 = KVCache(ck, cv, cur[None], ks=cks, vs=cvs)
         ids = jax.lax.dynamic_slice(s.transcript, (slot, cur), (1, c))
         # Pad-tail positions clamp to the last real position; their
         # outputs/KV are garbage nothing reads (causal frontier + the
@@ -660,25 +647,12 @@ def _admission_chunk(params, s: SlotState, *, cfg, sampling, model,
         positions = jnp.minimum(
             cur + jnp.arange(c, dtype=jnp.int32), tl - 1
         )[None, :]
-        logits, c1, counts = _forward(
+        logits, cache, counts = _forward(
             model, params, cfg, ids,
             (cur + jnp.arange(c, dtype=jnp.int32) < tl)[None, :],
-            cache=c1, positions=positions,
+            cache=s.cache._replace(length=cur[None]), rows=slot[None],
+            positions=positions,
         )
-        k2 = jax.lax.dynamic_update_slice(
-            s.cache.k, c1.k, (zero, slot, zero, zero, zero)
-        )
-        v2 = jax.lax.dynamic_update_slice(
-            s.cache.v, c1.v, (zero, slot, zero, zero, zero)
-        )
-        ks2 = vs2 = None
-        if s.cache.quantized:
-            ks2 = jax.lax.dynamic_update_slice(
-                s.cache.ks, c1.ks, (zero, slot, zero, zero)
-            )
-            vs2 = jax.lax.dynamic_update_slice(
-                s.cache.vs, c1.vs, (zero, slot, zero, zero)
-            )
         done = cur + c >= tl
         li = jnp.clip(tl - 1 - cur, 0, c - 1)
         last = jax.lax.dynamic_index_in_dim(logits[0], li, 0,
@@ -692,8 +666,7 @@ def _admission_chunk(params, s: SlotState, *, cfg, sampling, model,
         first = sample_step(rng, last[None, :], seen0, sampling)[0]
         seen1 = update_seen(seen0, first[None])[0]
         new = s._replace(
-            cache=s.cache._replace(
-                k=k2, v=v2, ks=ks2, vs=vs2,
+            cache=cache._replace(
                 length=s.cache.length.at[slot].set(
                     jnp.where(done, tl, s.cache.length[slot])
                 ),

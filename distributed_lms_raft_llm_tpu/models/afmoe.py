@@ -236,6 +236,7 @@ def forward(
     kv_mask: Optional[jax.Array] = None,
     live: Optional[jax.Array] = None,
     aux: bool = False,
+    rows: Optional[jax.Array] = None,
 ):
     """Run the decoder; returns (logits [B, T, V] float32, updated cache),
     and with `aux` a third value, {"counts": int32 [3], "routing": int32
@@ -243,7 +244,7 @@ def forward(
     forward: positions drive the rotary embedding of the sliding layers and
     nothing else; masks are built on cache SLOTS, which differ from
     positions by a row's padding only, so the window is the same distance
-    in both."""
+    in both; `rows` names the cache rows a ragged batch addresses."""
     b, t = input_ids.shape
     eps, dh = cfg.rms_norm_eps, cfg.head_dim
     nh, nkv = cfg.num_heads, cfg.num_kv_heads
@@ -277,7 +278,10 @@ def forward(
     if cache is not None:
         ck, cv = cache.k, cache.v
     zero = jnp.zeros((), jnp.int32)
-    rows = jnp.arange(b)[:, None]
+    if rows is not None and offset.ndim != 1:
+        raise ValueError("rows names the cache rows of a ragged batch "
+                         "(per-row cache.length)")
+    at_rows = (jnp.arange(b) if rows is None else rows)[:, None]
 
     def attention(h, ap, kind, layer):
         nonlocal ck, cv
@@ -294,15 +298,16 @@ def forward(
             if offset.ndim == 1:
                 # Ragged slots: each row's T tokens at its own offset;
                 # out-of-range tails are dropped, never clamped.
-                ck = ck.at[layer, rows, :, q_slots, :].set(
+                ck = ck.at[layer, at_rows, :, q_slots, :].set(
                     k_w.transpose(0, 2, 1, 3))
-                cv = cv.at[layer, rows, :, q_slots, :].set(
+                cv = cv.at[layer, at_rows, :, q_slots, :].set(
                     v_w.transpose(0, 2, 1, 3))
             else:
                 start = (layer, zero, zero, offset, zero)
                 ck = jax.lax.dynamic_update_slice(ck, k_w[None], start)
                 cv = jax.lax.dynamic_update_slice(cv, v_w[None], start)
-            k, v = ck[layer].astype(q.dtype), cv[layer].astype(q.dtype)
+            at = layer if rows is None else (layer, rows)
+            k, v = ck[at].astype(q.dtype), cv[at].astype(q.dtype)
         # Grouped keys and values without repeating them: the `groups`
         # query heads of a kv head are folded into the query axis.
         a = attend(q.reshape(b, nkv, groups * t, dh), k, v, masks[kind])

@@ -118,6 +118,18 @@ class KVCache(NamedTuple):
         )
 
 
+def layer_rows(plane: jax.Array, layer,
+               rows: Optional[jax.Array]) -> jax.Array:
+    """What a batch attends over in one layer of a stacked cache plane
+    ([L, rows, ...]): the layer's whole plane when `rows` is None (batch
+    element i owns cache row i), else the given rows of it, [B, ...] — a
+    batch that addresses rows of a wider cache (`forward`'s `rows`) reads
+    its own rows' pages and nobody else's."""
+    if rows is None:
+        return jax.lax.dynamic_index_in_dim(plane, layer, 0, keepdims=False)
+    return plane[layer, rows]
+
+
 def quantize_kv(x: jax.Array) -> Tuple[jax.Array, jax.Array]:
     """Symmetric per-(batch, head, slot) int8: [B, H, T, Dh] -> (int8 same
     shape, f32 [B, H, T] scales). One scale per cache slot keeps the

@@ -34,6 +34,7 @@ from .common import (
     causal_window_mask,
     dense,
     layer_norm,
+    layer_rows,
     merge_heads,
     quantize_kv,
     split_heads,
@@ -249,6 +250,7 @@ def forward(
     positions: Optional[jax.Array] = None,
     kv_mask: Optional[jax.Array] = None,
     collect_moe_aux: bool = False,
+    rows: Optional[jax.Array] = None,
 ):
     """Run the transformer; returns (logits [B, T, V] float32, updated cache).
 
@@ -266,6 +268,13 @@ def forward(
                  slot indices (contiguous, no padding). The engine passes
                  per-row positions when prompts are left-padded.
     kv_mask    — [B, num_keys] validity of each key slot (False = padding).
+    rows       — ragged slots only: [B] int32, the cache row batch element i
+                 addresses, for a batch narrower than the cache (the paged
+                 engine's prefill chunk: one staged slot of the live
+                 multi-slot cache). Its new keys are scattered into those
+                 rows in place, it attends over those rows alone, every
+                 other row comes back untouched, and `cache.length` is
+                 per batch element, [B]. Default: row i for element i.
     collect_moe_aux — full-sequence (cache=None) mode only: additionally
                  return the mean per-layer MoE load-balance scalar
                  (models/moe.py; 0 for dense blocks) as a third element —
@@ -354,6 +363,11 @@ def forward(
                 "fused_decode_attention and quant_kv are mutually exclusive "
                 "(the pallas kernel reads a full-precision cache)"
             )
+        if rows is not None and (offset.ndim != 1 or fused):
+            raise ValueError(
+                "rows names the cache rows of a ragged batch (per-row "
+                "cache.length), which the fused decode kernel cannot read"
+            )
         quant_kv = cfg.quant_kv
         # The attend-mask is layer-invariant; its additive-bias form is
         # computed once per step, outside the layer scan.
@@ -377,19 +391,20 @@ def forward(
                     # speculative verify window — engine.spec). Advanced
                     # indices [B,1] rows × [B,T] slots land in front, so
                     # values go [B, T, H, Dh].
-                    rows = jnp.arange(k_new.shape[0])[:, None]
+                    at_rows = (jnp.arange(k_new.shape[0]) if rows is None
+                               else rows)[:, None]
                     slots = offset[:, None] + jnp.arange(t)[None, :]
-                    ck2 = ck.at[layer, rows, :, slots, :].set(
+                    ck2 = ck.at[layer, at_rows, :, slots, :].set(
                         k_w.transpose(0, 2, 1, 3)
                     )
-                    cv2 = cv.at[layer, rows, :, slots, :].set(
+                    cv2 = cv.at[layer, at_rows, :, slots, :].set(
                         v_w.transpose(0, 2, 1, 3)
                     )
                     if quant_kv:
-                        cks2 = cks.at[layer, rows, :, slots].set(
+                        cks2 = cks.at[layer, at_rows, :, slots].set(
                             k_s.transpose(0, 2, 1)
                         )
-                        cvs2 = cvs.at[layer, rows, :, slots].set(
+                        cvs2 = cvs.at[layer, at_rows, :, slots].set(
                             v_s.transpose(0, 2, 1)
                         )
                 else:
@@ -412,21 +427,15 @@ def forward(
                     return attention_ops.decode_attention(
                         q, ck2, cv2, layer, bias
                     )
-                k_att = jax.lax.dynamic_index_in_dim(
-                    ck2, layer, 0, keepdims=False
-                )
-                v_att = jax.lax.dynamic_index_in_dim(
-                    cv2, layer, 0, keepdims=False
-                )
+                k_att = layer_rows(ck2, layer, rows)
+                v_att = layer_rows(cv2, layer, rows)
                 if quant_kv:
                     return attend_quant(
                         q,
                         k_att,
-                        jax.lax.dynamic_index_in_dim(cks2, layer, 0,
-                                                     keepdims=False),
+                        layer_rows(cks2, layer, rows),
                         v_att,
-                        jax.lax.dynamic_index_in_dim(cvs2, layer, 0,
-                                                     keepdims=False),
+                        layer_rows(cvs2, layer, rows),
                         mask,
                     )
                 return attend(

@@ -34,6 +34,7 @@ from .common import (
     attend_quant,
     causal_window_mask,
     dense,
+    layer_rows,
     merge_heads,
     quantize_kv,
     repeat_kv,
@@ -149,13 +150,15 @@ def forward(
     cache: Optional[KVCache] = None,
     positions: Optional[jax.Array] = None,
     kv_mask: Optional[jax.Array] = None,
+    rows: Optional[jax.Array] = None,
 ) -> Tuple[jax.Array, Optional[KVCache]]:
     """Run the decoder; returns (logits [B, T, V] float32, updated cache).
 
     Same contract as gpt2.forward (shared by engine.generate): positions are
     absolute (drive RoPE and nothing else — there is no position table),
     cache slots are written at offset `cache.length`, `kv_mask` marks valid
-    key slots. Same overflow precondition as gpt2.forward applies.
+    key slots, `rows` names the cache rows a ragged batch addresses. Same
+    overflow precondition as gpt2.forward applies.
     """
     b, t = input_ids.shape
     eps = cfg.rms_norm_eps
@@ -240,6 +243,11 @@ def forward(
                 "fused_decode_attention and quant_kv are mutually exclusive "
                 "(the pallas kernel reads a full-precision cache)"
             )
+        if rows is not None and (offset.ndim != 1 or fused):
+            raise ValueError(
+                "rows names the cache rows of a ragged batch (per-row "
+                "cache.length), which the fused decode kernel cannot read"
+            )
         quant_kv = cfg.quant_kv
         bias = attention_ops.mask_to_bias(mask) if fused else None
 
@@ -260,19 +268,20 @@ def forward(
                     # own offset (T=1 for paged decode; T=k+1 for the
                     # speculative verify window — engine.spec). Same layout
                     # as gpt2.forward.
-                    rows = jnp.arange(k_new.shape[0])[:, None]
+                    at_rows = (jnp.arange(k_new.shape[0]) if rows is None
+                               else rows)[:, None]
                     slots = offset[:, None] + jnp.arange(t)[None, :]
-                    ck2 = ck.at[layer, rows, :, slots, :].set(
+                    ck2 = ck.at[layer, at_rows, :, slots, :].set(
                         k_w.transpose(0, 2, 1, 3)
                     )
-                    cv2 = cv.at[layer, rows, :, slots, :].set(
+                    cv2 = cv.at[layer, at_rows, :, slots, :].set(
                         v_w.transpose(0, 2, 1, 3)
                     )
                     if quant_kv:
-                        cks2 = cks.at[layer, rows, :, slots].set(
+                        cks2 = cks.at[layer, at_rows, :, slots].set(
                             k_s.transpose(0, 2, 1)
                         )
-                        cvs2 = cvs.at[layer, rows, :, slots].set(
+                        cvs2 = cvs.at[layer, at_rows, :, slots].set(
                             v_s.transpose(0, 2, 1)
                         )
                 else:
@@ -292,15 +301,11 @@ def forward(
                     return attention_ops.decode_attention(
                         q, ck2, cv2, layer, bias
                     )
-                k_att = jax.lax.dynamic_index_in_dim(ck2, layer, 0,
-                                                     keepdims=False)
-                v_att = jax.lax.dynamic_index_in_dim(cv2, layer, 0,
-                                                     keepdims=False)
+                k_att = layer_rows(ck2, layer, rows)
+                v_att = layer_rows(cv2, layer, rows)
                 if quant_kv:
-                    ks_att = jax.lax.dynamic_index_in_dim(cks2, layer, 0,
-                                                          keepdims=False)
-                    vs_att = jax.lax.dynamic_index_in_dim(cvs2, layer, 0,
-                                                          keepdims=False)
+                    ks_att = layer_rows(cks2, layer, rows)
+                    vs_att = layer_rows(cvs2, layer, rows)
                     return attend_quant(
                         q,
                         repeat_kv(k_att, groups),

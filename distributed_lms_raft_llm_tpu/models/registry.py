@@ -4,9 +4,17 @@ The engine (engine/engine.py, engine/generate.py) is model-agnostic — it
 drives any family exposing the same functional surface:
 
     init_params(rng, cfg) -> params
-    forward(params, cfg, ids, cache=, positions=, kv_mask=) -> (logits, cache)
+    forward(params, cfg, ids, cache=, positions=, kv_mask=, rows=)
+        -> (logits, cache)
     init_cache(cfg, batch, max_len, dtype=) -> KVCache
     params_from_hf(state_dict, cfg) -> params
+
+`rows` ([B] int32, ragged `cache.length` only; default: row i for batch
+element i) names the cache rows a batch narrower than the cache addresses:
+its keys and values are scattered into those rows in place, it attends
+over those rows alone, and every other row comes back as it went in. The
+paged engine's prefill chunk is such a batch, one staged slot of the live
+multi-slot cache (`engine/paged.py` `_admission_chunk`).
 
 The reference hardcodes one architecture behind `from_pretrained("gpt2")`
 (reference: GUI_RAFT_LLM_SourceCode/tutoring_server.py:10); here presets

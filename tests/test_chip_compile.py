@@ -15,6 +15,7 @@ same tests.
 
 import dataclasses
 import os
+import re
 from functools import partial
 
 import jax
@@ -311,3 +312,117 @@ def test_trinity_mini_cut_step_and_megastep_compile_and_fit_one_v5e(one_chip):
         # temporary of 537 MB a projection.
         assert ma.temp_size_in_bytes < 2 * expert_stack
         assert "ragged-dot" in compiled.as_text()
+
+
+# ------------------- a prefill chunk touches its slot's pages in place
+
+def _copies_inside_loops(text: str, shape: str) -> list:
+    """Names of the `copy` operations of `shape` that a compiled module's
+    text holds in a computation some `while` or `conditional` reaches
+    (its body, condition or branches, and whatever those call). Copies in
+    the entry computation run once a dispatch and are not listed."""
+    calls, copies, roots, comp = {}, {}, set(), None
+    for line in text.splitlines():
+        head = re.match(r"(?:ENTRY )?%?([\w.\-]+) .*\{\s*$", line)
+        if head:
+            comp = head.group(1)
+            calls[comp], copies[comp] = set(), []
+            continue
+        if comp is None or not line.startswith(" "):
+            continue
+        called = set(re.findall(
+            r"(?:calls|body|condition|to_apply|true_computation|"
+            r"false_computation)=%?([\w.\-]+)", line))
+        for group in re.findall(r"branch_computations=\{([^}]*)\}", line):
+            called.update(re.findall(r"%?([\w.\-]+)", group))
+        calls[comp] |= called
+        # A `while` or a `conditional` (their result is a tuple, whose
+        # text has spaces: told by their attributes, not by the opcode).
+        if re.search(r"\b(?:body|branch_computations|true_computation)=",
+                     line):
+            roots |= called
+        op = re.match(r"\s*(?:ROOT )?%?([\w.\-]+) = (\S+) copy\(", line)
+        if op and op.group(2).startswith(shape):
+            copies[comp].append(op.group(1))
+    inside, todo = set(), list(roots)
+    while todo:
+        c = todo.pop()
+        if c not in inside:
+            inside.add(c)
+            todo.extend(calls.get(c, ()))
+    return sorted(n for c in inside for n in copies.get(c, ()))
+
+
+def test_copies_inside_loops_reads_a_module_text():
+    text = """
+%fused_copy (p: s8[2,4]) -> s8[2,4] {
+  ROOT %copy.1 = s8[2,4]{0,1} copy(%p)
+}
+%branch_1 (t: (s8[2,4])) -> (s8[2,4]) {
+  %copy.2 = s8[2,4]{0,1:T(8,128)(4,1)} copy(%x)
+  %copy.3 = s8[2,8]{0,1} copy(%y)
+  %fusion.1 = s8[2,4]{1,0} fusion(%copy.2), kind=kLoop, calls=%fused_copy
+}
+%branch_0 (t: (s8[2,4])) -> (s8[2,4]) {
+  ROOT %tuple = (s8[2,4]{1,0}) tuple(%t)
+}
+%body (c: (s8[2,4])) -> (s8[2,4]) {
+  %conditional.1 = (s8[2,4]{1,0}, s32[]) conditional(%i, %a, %b), branch_computations={%branch_0, %branch_1}
+}
+%cond (c: (s8[2,4])) -> pred[] {
+  ROOT %lt = pred[] compare(%a, %b), direction=LT
+}
+ENTRY %main.1 (a: s8[2,4]) -> s8[2,4] {
+  %copy.4 = s8[2,4]{0,1} copy(%a)
+  %while.1 = (s8[2,4]{1,0}, s32[]) while(%copy.4), condition=%cond, body=%body
+}
+"""
+    assert _copies_inside_loops(text, "s8[2,4]") == ["copy.1", "copy.2"]
+    assert _copies_inside_loops(text, "s8[2,8]") == ["copy.3"]
+    assert _copies_inside_loops(text, "s8[4,2]") == []
+
+
+@pytest.mark.parametrize("preset,quant_kv,width,max_temps", [
+    # int8 weights and int8 K/V, as benchmarks/configs/gpt2-xl.json serves
+    # it. 5.80 GB of temporaries while a chunk sliced its slot's pages out
+    # and back (two slot-major copies of 1.26 GB among them), 3.23 since.
+    ("gpt2-xl", True, 384, 4 * 1024**3),
+    ("trinity-mini-1d4e", False, 2688, None),
+])
+def test_megastep_touches_a_staged_slots_pages_in_place(
+        one_chip, preset, quant_kv, width, max_temps):
+    """The megastep of both benchmark configurations (16 slots, chunk 16,
+    prefill chunks of 32, K = 2) for a described v5e: no copy of a whole K
+    or V plane inside the scans or the staged branch. A chunk that reaches
+    its slot's pages through a private `[L, 1, H, W, Dh]` cache makes the
+    compiler relay both planes slot-major and back, four whole-plane
+    copies for 32 tokens. What the entry computation copies, once a
+    dispatch, is ROADMAP S2's."""
+    family, cfg = registry.resolve(preset, jnp.bfloat16, jnp.bfloat16)
+    init = partial(family.init_params, jax.random.key(0), cfg)
+    if quant_kv:
+        cfg = dataclasses.replace(cfg, quant_kv=True)
+        params = jax.eval_shape(
+            lambda: quant.quantize_params(init(), family.name))
+    else:
+        params = jax.eval_shape(init)
+    state = jax.eval_shape(
+        partial(paged._fresh_state, family, cfg, 16, width))
+    compiled = jax.jit(
+        partial(paged._megastep_program, chunk=16, spec_tokens=0,
+                prefill_chunk=32, draft_fn=build_drafts, eos_id=50256,
+                pad_id=50256, cfg=cfg, model=family,
+                sampling=SamplingParams.reference_defaults()),
+        donate_argnums=(1,),
+    ).lower(
+        _with(params, one_chip), _with(state, one_chip),
+        _with(jax.eval_shape(
+            lambda: jax.random.split(jax.random.key(0), 2)), one_chip),
+    ).compile()
+    k = state.cache.k
+    plane = f"{'s8' if quant_kv else 'bf16'}[{','.join(map(str, k.shape))}]"
+    text = compiled.as_text()
+    assert plane in text
+    assert _copies_inside_loops(text, plane) == []
+    if max_temps is not None:
+        assert compiled.memory_analysis().temp_size_in_bytes < max_temps
