@@ -138,11 +138,19 @@ class TutoringEngine:
         self.family, self.cfg = registry.resolve(
             config.model, config.dtype, config.param_dtype
         )
-        if config.ep > 1 and self.family.name != "gpt2_moe":
+        if config.ep > 1 and not self.family.expert_parallel:
             raise ValueError(
-                f"ep={config.ep} requires an MoE family; {config.model!r} "
-                f"has no expert axis to shard — the ep devices would "
-                f"silently replicate (shrinking dp) instead of helping"
+                f"ep={config.ep} requires an MoE family whose expert axis "
+                f"shards over ep; the {self.family.name!r} family of "
+                f"{config.model!r} has none "
+                f"— the ep devices would silently replicate (shrinking "
+                f"dp) instead of helping"
+            )
+        if config.tp > 1 and self.family.latent_cache:
+            raise ValueError(
+                f"tp={config.tp}: {config.model!r} caches a latent with "
+                f"no heads axis (models/mla.py); there is nothing for tp "
+                f"to shard"
             )
         if (
             config.spec_tokens > 0

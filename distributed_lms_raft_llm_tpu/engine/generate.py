@@ -148,7 +148,7 @@ def _grow_cache(cache: KVCache, new_len: int) -> KVCache:
     pad = [(0, 0), (0, 0), (0, 0), (0, new_len - cur), (0, 0)]
     return cache._replace(
         k=jnp.pad(cache.k, pad),
-        v=jnp.pad(cache.v, pad),
+        v=None if cache.v is None else jnp.pad(cache.v, pad),
         ks=None if cache.ks is None else jnp.pad(cache.ks, pad[:-1]),
         vs=None if cache.vs is None else jnp.pad(cache.vs, pad[:-1]),
     )

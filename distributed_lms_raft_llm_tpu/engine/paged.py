@@ -213,9 +213,11 @@ def _forward(model, params, cfg, ids, live, **kw):
     """`model.forward` -> (logits, cache, counts): `counts` is empty but
     for a family with routed experts (`ModelFamily.routed`), which is told
     which tokens are `live` ([B] or [B, T] bool: an idle lane or a pad
-    position reaches no expert) and hands back its three counts, int32 [3]
-    (picks computed, experts reached, expert seats offered). Every other
-    family is called exactly as before, so its programs do not change."""
+    position reaches no expert) and hands back its counts, int32
+    [len(model.counters)] (picks computed, experts reached, expert seats
+    offered; a family that holds a share of a layer's experts adds the
+    picks that landed on it). Every other family is called exactly as
+    before, so its programs do not change."""
     if not model.routed:
         return (*model.forward(params, cfg, ids, **kw), ())
     logits, cache, aux = model.forward(params, cfg, ids, live=live,
@@ -241,21 +243,22 @@ def _export_block_program(c1: KVCache, off, slot, *, block: int) -> KVBlock:
     scatters at >= prompt_len). Publishing copies rather
     than aliasing: the source is transient engine state, and a tree that
     aliased it would see its buffers donated away by the next program."""
-    l, _, h, _, dh = c1.k.shape
     zero = jnp.zeros((), jnp.int32)
     off = jnp.asarray(off, jnp.int32)
     slot = jnp.asarray(slot, jnp.int32)
-    k = jax.lax.dynamic_slice(c1.k, (zero, slot, zero, off, zero),
-                              (l, 1, h, block, dh))
-    v = jax.lax.dynamic_slice(c1.v, (zero, slot, zero, off, zero),
-                              (l, 1, h, block, dh))
-    ks = vs = None
-    if c1.quantized:
-        ks = jax.lax.dynamic_slice(c1.ks, (zero, slot, zero, off),
-                                   (l, 1, h, block))
-        vs = jax.lax.dynamic_slice(c1.vs, (zero, slot, zero, off),
-                                   (l, 1, h, block))
-    return KVBlock(k=k, v=v, ks=ks, vs=vs)
+
+    def cut(plane):
+        # Each plane the family's `init_cache` declares, by its own shape
+        # ([L, S, H, T, ...]); one it does not have (models/mla.py: a
+        # latent cache is one plane) stays None.
+        if plane is None:
+            return None
+        l, _, h, _, *rest = plane.shape
+        return jax.lax.dynamic_slice(
+            plane, (zero, slot, zero, off) + (zero,) * len(rest),
+            (l, 1, h, block, *rest))
+
+    return KVBlock(k=cut(c1.k), v=cut(c1.v), ks=cut(c1.ks), vs=cut(c1.vs))
 
 
 def _stage_program(state: SlotState, slot, ids, true_len, cursor0, seq,
@@ -322,6 +325,8 @@ def _stage_block_program(state: SlotState, block, slot, off,
     off = jnp.asarray(off, jnp.int32)
 
     def put(plane, new):
+        if plane is None:
+            return None
         at = (zero, slot, zero, off) + (zero,) * (plane.ndim - 4)
         # lint: disable-next=tracer-hygiene
         if tokens is not None:
@@ -331,13 +336,11 @@ def _stage_block_program(state: SlotState, block, slot, off,
                 keep, new, jax.lax.dynamic_slice(plane, at, new.shape))
         return jax.lax.dynamic_update_slice(plane, new, at)
 
-    k, v = put(state.cache.k, block.k), put(state.cache.v, block.v)
-    ks = vs = None
-    if state.cache.quantized:
-        ks, vs = put(state.cache.ks, block.ks), put(state.cache.vs, block.vs)
-    return state._replace(
-        cache=state.cache._replace(k=k, v=v, ks=ks, vs=vs)
-    )
+    c = state.cache
+    return state._replace(cache=c._replace(
+        k=put(c.k, block.k), v=put(c.v, block.v),
+        ks=put(c.ks, block.ks), vs=put(c.vs, block.vs),
+    ))
 
 
 def cfg_tmax(cfg, sampling: SamplingParams, bucket: int) -> int:
@@ -375,14 +378,13 @@ def _grow_state_program(state: SlotState, new_len: int) -> SlotState:
     arrives)."""
     grow = new_len - state.cache.k.shape[3]
     pad = [(0, 0), (0, 0), (0, 0), (0, grow), (0, 0)]
-    cache = state.cache._replace(
-        k=jnp.pad(state.cache.k, pad),
-        v=jnp.pad(state.cache.v, pad),
-        ks=None if state.cache.ks is None else jnp.pad(state.cache.ks,
-                                                       pad[:-1]),
-        vs=None if state.cache.vs is None else jnp.pad(state.cache.vs,
-                                                       pad[:-1]),
-    )
+
+    def wider(plane):
+        return None if plane is None else jnp.pad(plane, pad[:plane.ndim])
+
+    c = state.cache
+    cache = c._replace(k=wider(c.k), v=wider(c.v), ks=wider(c.ks),
+                       vs=wider(c.vs))
     return state._replace(
         cache=cache,
         transcript=jnp.pad(state.transcript, [(0, 0), (0, grow)]),
@@ -391,7 +393,8 @@ def _grow_state_program(state: SlotState, new_len: int) -> SlotState:
 
 def _sum_counts(*counts) -> tuple:
     """() or (sum,) of a scan iteration's routed-experts counts, each
-    itself () or (int32 [3],): the decode's and the admission chunk's."""
+    itself () or (int32 [len(model.counters)],): the decode's and the
+    admission chunk's."""
     found = [c[0] for c in counts if c]
     return (sum(found[1:], found[0]),) if found else ()
 
@@ -437,7 +440,7 @@ def _step_program(params, state: SlotState, rng, *, cfg, sampling,
 
     A family with routed experts (`ModelFamily.routed`) adds one LAST
     output, its counts summed over the iterations and `admit`'s forward
-    passes, int32 [3] (`_forward`); only `_live_lanes` route.
+    passes (`_forward`); only `_live_lanes` route.
     """
     tmax = state.cache.k.shape[3]
 
@@ -626,7 +629,8 @@ def _admission_chunk(params, s: SlotState, *, cfg, sampling, model,
     n_slots = s.tok.shape[0]
     no_flip = jnp.zeros((n_slots,), jnp.bool_)
     no_first = jnp.full((n_slots,), pad_id, jnp.int32)
-    no_counts = (jnp.zeros((3,), jnp.int32),) if model.routed else ()
+    no_counts = ((jnp.zeros((len(model.counters),), jnp.int32),)
+                 if model.routed else ())
 
     def run(s: SlotState):
         c = prefill_chunk
@@ -724,7 +728,7 @@ def _megastep_program(params, state: SlotState, rngs, *, cfg, sampling,
       the batched reap learns admission outcomes without an extra sync
       and starts the slot's decode walk at that row.
     - a family with routed experts: the above plus, LAST, its
-      counts summed over the dispatch, int32 [3] (`_forward`).
+      counts summed over the dispatch (`_forward`).
 
     `active[j]` is the post-chunk-j snapshot — the same fresh non-donated
     plane the single-chunk program returns, K of them — so the host's
@@ -926,12 +930,24 @@ class PagedEngine:
                 "spec_tokens with an MoE model requires capacity_factor >= "
                 "num_experts (no token dropping; models/moe.py caveat)"
             )
-        if config.ep > 1 and self.family.name != "gpt2_moe":
+        if config.ep > 1 and not self.family.expert_parallel:
             # Mirror TutoringEngine: silently replicating the ep ways into
             # dp would waste an ep-factor of devices with no signal.
             raise ValueError(
-                f"ep={config.ep} requires an MoE family; {config.model!r} "
-                f"has no expert axis to shard"
+                f"ep={config.ep} requires an MoE family whose expert axis "
+                f"shards over ep; the {self.family.name!r} family of "
+                f"{config.model!r} has none "
+                f"(a routed family's grouped product takes whole expert "
+                f"stacks, and the exchange between chips that each hold a "
+                f"share of a layer's experts is not built)"
+            )
+        if config.tp > 1 and self.family.latent_cache:
+            raise ValueError(
+                f"tp={config.tp}: {config.model!r} caches a latent with "
+                f"no heads axis (models/mla.py), and the paged KV planes "
+                f"shard their heads axis over tp; a latent cache is "
+                f"replicated over the chips that share a layer, each "
+                f"serving its own lanes"
             )
         if config.sp > 1:
             raise ValueError(
@@ -1113,7 +1129,7 @@ class PagedEngine:
         #  dead-lane scalar device array,
         #  flipped / firsts [K, chunk, S] bool / int32 admission planes,
         #  slot->request snapshot at dispatch time,
-        #  a routed family's counts int32 [3], else None).
+        #  a routed family's counts int32 [len(counters)], else None).
         # Every device entry is a fresh non-donated buffer (see
         # _step_program's snapshot note), so dispatches pipeline under
         # the donation invariants.
@@ -1931,7 +1947,8 @@ class PagedEngine:
             stage_rng=put(state.stage_rng, "stage_rng"),
             cache=state.cache._replace(
                 k=put(state.cache.k, "cache.k"),
-                v=put(state.cache.v, "cache.v"),
+                v=(None if state.cache.v is None
+                   else put(state.cache.v, "cache.v")),
                 ks=(None if state.cache.ks is None
                     else put(state.cache.ks, "cache.ks")),
                 vs=(None if state.cache.vs is None
@@ -1956,7 +1973,7 @@ class PagedEngine:
 
         return blk._replace(
             k=put(blk.k, "k"),
-            v=put(blk.v, "v"),
+            v=None if blk.v is None else put(blk.v, "v"),
             ks=None if blk.ks is None else put(blk.ks, "ks"),
             vs=None if blk.vs is None else put(blk.vs, "vs"),
         )
@@ -2057,11 +2074,10 @@ class PagedEngine:
                                firsts, list(self._slot_req), moe))
 
     def _count_moe(self, counts) -> None:
-        """A routed family's three counts of some forward passes, read
-        back from the device (`_forward`)."""
-        picks, reached, seats = (int(c) for c in counts)
-        self._count(moe_picks=picks, moe_experts_reached=reached,
-                    moe_expert_seats=seats)
+        """A routed family's counts of some forward passes, read back
+        from the device (`_forward`), each into the counter of its name."""
+        self._count(**{name: int(c) for name, c
+                       in zip(self.family.counters, counts)})
 
     def _reap(self, toks_dev, counts_dev, active_dev, dead_dev,
               flipped_dev, firsts_dev, slot_snapshot,

@@ -63,13 +63,14 @@ BLOCK_TOKENS = 16
 
 class KVBlock(NamedTuple):
     """One immutable device-resident KV block: `block_tokens` consecutive
-    positions of a single sequence ([L, 1, H, B, Dh] per plane; int8
+    positions of a single sequence ([L, 1, H, B, Dh] per plane, each by
+    its own H and Dh, `v` None for a family whose cache is one plane; int8
     scale planes [L, 1, H, B] ride along for a quantized cache). Shared
     structure: never donated, never written in place — the lint sweep
     and the reversion pin in tests/test_lint_clean.py enforce it."""
 
     k: jax.Array
-    v: jax.Array
+    v: Optional[jax.Array]
     ks: Optional[jax.Array] = None
     vs: Optional[jax.Array] = None
 

@@ -210,21 +210,118 @@ def swiglu(x: jax.Array, mp: Params) -> jax.Array:
                  mp["wd"])
 
 
-def moe_mlp(h: jax.Array, mp: Params, cfg: AfmoeConfig, live: jax.Array):
+def moe_mlp(h: jax.Array, mp: Params, cfg, live: jax.Array):
     """An expert layer's MLP, [B, T, D] -> ([B, T, D], chosen experts
-    [B, T, k], group sizes [E]); `live` [B, T] bool."""
+    [B, T, k], group sizes of the experts held [E held]); `live` [B, T]
+    bool. A configuration whose `experts_held` is (first, count) holds
+    that share of the layer's `num_experts` (the stacks are [count, ..]):
+    it routes over all of them and computes its own experts' part plus
+    the shared expert's (`moe.grouped_swiglu`). A tree without `br` has
+    no balancing bias."""
     b, t, d = h.shape
     x = h.reshape(b * t, d)
+    held = getattr(cfg, "experts_held", None)
     with jax.named_scope("moe.route"):
         top_i, top_w = route_sigmoid(
-            x, mp["wr"], mp["br"], cfg.num_experts_per_tok, cfg.route_norm,
-            cfg.route_scale)
+            x, mp["wr"], mp.get("br"), cfg.num_experts_per_tok,
+            cfg.route_norm, cfg.route_scale)
     with jax.named_scope("moe.experts"):
         y, sizes = grouped_swiglu(x, top_i, top_w, live.reshape(b * t),
-                                  mp["wg"], mp["wu"], mp["wd"])
+                                  mp["wg"], mp["wu"], mp["wd"],
+                                  first=held[0] if held else None)
     with jax.named_scope("moe.shared"):
         y = y + swiglu(x, mp["shared"])
     return y.reshape(b, t, d), top_i.reshape(b, t, -1), sizes
+
+
+# What a routed family counts a forward pass, in the order of `counts`:
+# picks computed, experts reached, expert seats offered (experts held x
+# expert layers). A family that holds a share of a layer's experts names a
+# fourth after them, `moe_picks_held`, the picks that landed on the share
+# (`axk1.COUNTERS`; here every pick does). A family's tuple is the one
+# thing that says which: its forward hands it to `run_layers`, and
+# `registry.ModelFamily.counters` to the engine.
+COUNTERS = ("moe_picks", "moe_experts_reached", "moe_expert_seats")
+
+
+def run_layers(params: Params, cfg, x: jax.Array, live: jax.Array,
+               attention, scope, counters=COUNTERS):
+    """The unrolled trunk of a list-of-layers family, from the embedded
+    tokens to the last layer's output: x [B, T, D] -> (x, counts, routing
+    [Le] of [B, T, k]). Pre-norm sublayers, each with a norm AFTER it too
+    where the layer's tree has one (`ln1p`, `ln2p`: afmoe's four norms a
+    layer; a tree without them is the plain `h += Attn(N1(h))`,
+    `h += Mlp(N2(h))`). `attention(h, ap, layer)` is the family's own,
+    cache and all; `scope(layer)` names its span. A layer with a `moe`
+    subtree is routed (`moe_mlp`), one with `mlp` a dense SwiGLU.
+    `counters` is the family's tuple of count names (above)."""
+    eps = cfg.rms_norm_eps
+    share = "moe_picks_held" in counters
+    routing = []
+    counts = jnp.zeros((len(counters),), jnp.int32)
+    for layer, lp in enumerate(params["layers"]):
+        with jax.named_scope(scope(layer)):
+            a = attention(rms_norm(x, lp["ln1"]["scale"], eps), lp["attn"],
+                          layer)
+            if "ln1p" in lp:
+                a = rms_norm(a, lp["ln1p"]["scale"], eps)
+            x = x + a
+        h = rms_norm(x, lp["ln2"]["scale"], eps)
+        if "moe" in lp:
+            y, top_i, sizes = moe_mlp(h, lp["moe"], cfg, live)
+            routing.append(top_i)
+            seen = [jnp.sum(sizes), jnp.sum(sizes > 0).astype(jnp.int32),
+                    jnp.asarray(sizes.shape[0], jnp.int32)]
+            if share:
+                seen = [jnp.sum(live).astype(jnp.int32) * top_i.shape[-1],
+                        *seen[1:], seen[0]]
+            counts = counts + jnp.stack(seen)
+        else:
+            with jax.named_scope("mlp.dense"):
+                y = swiglu(h, lp["mlp"])
+        if "ln2p" in lp:
+            y = rms_norm(y, lp["ln2p"]["scale"], eps)
+        x = x + y
+    return x, counts, routing
+
+
+def head(params: Params, cfg, x: jax.Array, counts, routing, aux: bool):
+    """Final norm and the untied head; with `aux` the routing's record
+    beside the logits (module docstring)."""
+    x = rms_norm(x, params["lnf"]["scale"], cfg.rms_norm_eps)
+    logits = quant.unembed(x, params["lm_head"])
+    if not aux:
+        return (logits,)
+    b, t = x.shape[:2]
+    return logits, {
+        "counts": counts,
+        "routing": (jnp.stack(routing) if routing
+                    else jnp.zeros((0, b, t, cfg.num_experts_per_tok),
+                                   jnp.int32)),
+    }
+
+
+def batch_slots(input_ids: jax.Array, cache: Optional[KVCache],
+                positions: Optional[jax.Array], live: Optional[jax.Array],
+                rows: Optional[jax.Array]):
+    """What `forward`'s contract leaves to defaults, for a batch [B, T]:
+    (offset, the cache slots its tokens go to [B, T], positions [B, T],
+    live [B, T] bool). The offset is the cache's length, scalar or per
+    row; positions default to the slots, every token to live."""
+    b, t = input_ids.shape
+    offset = jnp.zeros((), jnp.int32) if cache is None else cache.length
+    off_row = offset[:, None] if offset.ndim else offset[None, None]
+    q_slots = jnp.broadcast_to(
+        off_row + jnp.arange(t, dtype=jnp.int32)[None, :], (b, t))
+    if positions is None:
+        positions = q_slots
+    if live is None:
+        live = jnp.ones((b, t), bool)
+    live = jnp.broadcast_to(live.reshape(b, -1), (b, t))
+    if rows is not None and offset.ndim != 1:
+        raise ValueError("rows names the cache rows of a ragged batch "
+                         "(per-row cache.length)")
+    return offset, q_slots, positions, live
 
 
 def forward(
@@ -250,15 +347,8 @@ def forward(
     nh, nkv = cfg.num_heads, cfg.num_kv_heads
     groups = nh // nkv
 
-    offset = jnp.zeros((), jnp.int32) if cache is None else cache.length
-    off_row = offset[:, None] if offset.ndim else offset[None, None]
-    q_slots = jnp.broadcast_to(
-        off_row + jnp.arange(t, dtype=jnp.int32)[None, :], (b, t))
-    if positions is None:
-        positions = q_slots
-    if live is None:
-        live = jnp.ones((b, t), bool)
-    live = jnp.broadcast_to(live.reshape(b, -1), (b, t))
+    offset, q_slots, positions, live = batch_slots(
+        input_ids, cache, positions, live, rows)
 
     num_keys = t if cache is None else cache.k.shape[3]
     masks = {}
@@ -278,9 +368,6 @@ def forward(
     if cache is not None:
         ck, cv = cache.k, cache.v
     zero = jnp.zeros((), jnp.int32)
-    if rows is not None and offset.ndim != 1:
-        raise ValueError("rows names the cache rows of a ragged batch "
-                         "(per-row cache.length)")
     at_rows = (jnp.arange(b) if rows is None else rows)[:, None]
 
     def attention(h, ap, kind, layer):
@@ -316,38 +403,18 @@ def forward(
         return dense((a.astype(jnp.float32) * gate).astype(a.dtype),
                      ap["wo"])
 
-    routing, counts = [], jnp.zeros((3,), jnp.int32)
-    for layer, (lp, kind) in enumerate(zip(params["layers"], cfg.types)):
-        scope = "attn.window" if kind == SLIDING else "attn.full"
-        with jax.named_scope(scope):
-            a = attention(rms_norm(x, lp["ln1"]["scale"], eps), lp["attn"],
-                          kind, layer)
-            x = x + rms_norm(a, lp["ln1p"]["scale"], eps)
-        h = rms_norm(x, lp["ln2"]["scale"], eps)
-        if "moe" in lp:
-            y, top_i, sizes = moe_mlp(h, lp["moe"], cfg, live)
-            routing.append(top_i)
-            counts = counts + jnp.stack([
-                jnp.sum(sizes), jnp.sum(sizes > 0).astype(jnp.int32),
-                jnp.asarray(cfg.num_experts, jnp.int32)])
-        else:
-            with jax.named_scope("mlp.dense"):
-                y = swiglu(h, lp["mlp"])
-        x = x + rms_norm(y, lp["ln2p"]["scale"], eps)
+    def attend_layer(h, ap, layer):
+        return attention(h, ap, cfg.types[layer], layer)
 
+    x, counts, routing = run_layers(
+        params, cfg, x, live, attend_layer,
+        lambda layer: ("attn.window" if cfg.types[layer] == SLIDING
+                       else "attn.full"))
     new_cache = None
     if cache is not None:
         new_cache = KVCache(k=ck, v=cv, length=cache.length + t)
-    x = rms_norm(x, params["lnf"]["scale"], eps)
-    logits = quant.unembed(x, params["lm_head"])
-    if not aux:
-        return logits, new_cache
-    return logits, new_cache, {
-        "counts": counts,
-        "routing": (jnp.stack(routing) if routing
-                    else jnp.zeros((0, b, t, cfg.num_experts_per_tok),
-                                   jnp.int32)),
-    }
+    logits, *rest = head(params, cfg, x, counts, routing, aux)
+    return (logits, new_cache, *rest)
 
 
 def params_from_hf(sd, cfg: AfmoeConfig) -> Params:

@@ -68,7 +68,10 @@ def dense(x: jax.Array, w, b: Optional[jax.Array] = None) -> jax.Array:
 class KVCache(NamedTuple):
     """Static-shape per-model KV cache.
 
-    k, v: [num_layers, batch, num_kv_heads, max_len, head_dim]
+    k, v: [num_layers, batch, num_kv_heads, max_len, head_dim]; a family
+            declares its own planes in `init_cache`, each [L, B, H, T, F]
+            with its own H and F, and `v` is None where there is one plane
+            (models/mla.py: a latent cache, one head, no values).
     length: [] int32 — number of valid positions already written.
     ks, vs: per-slot dequantization scales [L, B, Hkv, max_len] f32 when the
             cache is int8-quantized (halves the HBM bytes the decode loop
@@ -81,7 +84,7 @@ class KVCache(NamedTuple):
     """
 
     k: jax.Array
-    v: jax.Array
+    v: Optional[jax.Array]
     length: jax.Array
     ks: Optional[jax.Array] = None
     vs: Optional[jax.Array] = None

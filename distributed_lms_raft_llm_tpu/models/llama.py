@@ -128,16 +128,23 @@ def init_cache(cfg: LlamaConfig, batch: int, max_len: int, dtype=None) -> KVCach
     )
 
 
-def rope(x: jax.Array, positions: jax.Array, theta: float) -> jax.Array:
+def rope(x: jax.Array, positions: jax.Array, theta: float,
+         inv_freq=None, table_scale: float = 1.0) -> jax.Array:
     """Rotary embedding, HF rotate_half convention.
 
-    x: [B, H, T, Dh]; positions: [B, T] absolute positions.
+    x: [B, H, T, Dh]; positions: [B, T] absolute positions. `inv_freq`
+    [Dh/2] replaces the base's own frequencies (a scaled rotary embedding:
+    models/mla.py `yarn_inv_freq`), and `table_scale` multiplies cos and
+    sin where the scaling asks for it.
     """
     dh = x.shape[-1]
-    inv_freq = 1.0 / (theta ** (jnp.arange(0, dh, 2, dtype=jnp.float32) / dh))
+    if inv_freq is None:
+        inv_freq = 1.0 / (theta ** (jnp.arange(0, dh, 2, dtype=jnp.float32) / dh))
     freqs = positions[:, None, :, None].astype(jnp.float32) * inv_freq  # [B,1,T,Dh/2]
     cos = jnp.concatenate([jnp.cos(freqs)] * 2, axis=-1)
     sin = jnp.concatenate([jnp.sin(freqs)] * 2, axis=-1)
+    if table_scale != 1.0:
+        cos, sin = cos * table_scale, sin * table_scale
     x1, x2 = jnp.split(x.astype(jnp.float32), 2, axis=-1)
     rotated = jnp.concatenate([-x2, x1], axis=-1)
     return (x.astype(jnp.float32) * cos + rotated * sin).astype(x.dtype)

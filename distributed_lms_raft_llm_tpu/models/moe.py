@@ -193,15 +193,17 @@ def route_sigmoid(x: jax.Array, wr: jax.Array, bias: jax.Array, k: int,
     Scores are `sigmoid(x wr)` over ALL experts, in float32 at full
     precision (a TPU's default float32 product is one bfloat16 pass, and
     two experts' scores can differ by less than that rounds). The k experts
-    are the top-k of `score + bias`: the per-expert balancing bias takes
-    part in the CHOICE only, the weights are the chosen experts' own
-    scores, over their sum when `norm`, times `scale`.
+    are the top-k of `score + bias` (`bias` None: of the scores): the
+    per-expert balancing bias takes part in the CHOICE only, the weights
+    are the chosen experts' own scores, over their sum when `norm`, times
+    `scale`.
     """
     logits = jnp.einsum("sd,de->se", x.astype(jnp.float32),
                         wr.astype(jnp.float32),
                         precision=jax.lax.Precision.HIGHEST)
     scores = jax.nn.sigmoid(logits)
-    _, top_i = jax.lax.top_k(scores + bias.astype(jnp.float32), k)
+    choice = scores if bias is None else scores + bias.astype(jnp.float32)
+    _, top_i = jax.lax.top_k(choice, k)
     top_w = jnp.take_along_axis(scores, top_i, axis=-1)
     if norm:
         top_w = top_w / (jnp.sum(top_w, axis=-1, keepdims=True) + 1e-20)
@@ -210,7 +212,7 @@ def route_sigmoid(x: jax.Array, wr: jax.Array, bias: jax.Array, k: int,
 
 def grouped_swiglu(x: jax.Array, top_i: jax.Array, top_w: jax.Array,
                    live: jax.Array, wg: jax.Array, wu: jax.Array,
-                   wd: jax.Array):
+                   wd: jax.Array, first=None):
     """Routed SwiGLU experts without capacity and without drops:
     x [S, D], top_i / top_w [S, k], live [S] bool -> (y [S, D],
     group sizes [E] int32).
@@ -227,10 +229,23 @@ def grouped_swiglu(x: jax.Array, top_i: jax.Array, top_w: jax.Array,
     size 0 and is not read. The stacks must be whole buffers: a slice of
     a layer-stacked [L, E, D, M] array into the kernel is a copy of a
     layer's experts per call (models/afmoe.py keeps one leaf a layer).
+
+    `first` (None: the stacks are all the experts there are) says the
+    stacks are a SHARE of the layer's experts, `first` .. `first` + E - 1
+    of those the router chose among: a chip's experts in an expert-parallel
+    deployment. The routing and its weights are over all experts (`top_w`
+    comes normalised over a token's k picks); a pick of an absent expert
+    is then dropped like a token that is not live, and `y` is this share's
+    part of the layer's result, to which the absent experts' chips would
+    add theirs. Group sizes are the held experts'.
     """
     s, k = top_i.shape
     e = wg.shape[0]
-    expert = jnp.where(live[:, None], top_i, e).reshape(s * k)
+    here = live[:, None]
+    if first is not None:
+        top_i = top_i - first
+        here = here & (top_i >= 0) & (top_i < e)
+    expert = jnp.where(here, top_i, e).reshape(s * k)
     order = jnp.argsort(expert, stable=True)
     sizes = jnp.zeros((e + 1,), jnp.int32).at[expert].add(1)[:e]
     xs = x[order // k]                                       # [S*k, D]

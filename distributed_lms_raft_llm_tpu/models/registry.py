@@ -19,14 +19,15 @@ multi-slot cache (`engine/paged.py` `_admission_chunk`).
 The reference hardcodes one architecture behind `from_pretrained("gpt2")`
 (reference: GUI_RAFT_LLM_SourceCode/tutoring_server.py:10); here presets
 cover the GPT-2 family (BASELINE configs 1-4), Llama (config 5), the
-home-made GPT-2 with routed experts, and Arcee's afmoe (Trinity-Mini).
+home-made GPT-2 with routed experts, Arcee's afmoe (Trinity-Mini) and SK
+Telecom's axk1 (A.X-K1: latent attention, a share of a layer's experts).
 """
 
 from __future__ import annotations
 
 from typing import Any, Callable, NamedTuple, Tuple
 
-from . import afmoe, convert, gpt2, llama, moe
+from . import afmoe, axk1, convert, gpt2, llama, moe
 
 
 class ModelFamily(NamedTuple):
@@ -39,6 +40,15 @@ class ModelFamily(NamedTuple):
     # then hands out its routing's three counts (models/afmoe.py): the
     # paged engine tells such a family its idle lanes and reads the counts.
     routed: bool = False
+    # The names of a routed family's counts, in their order
+    # (`afmoe.COUNTERS`): the engine's counters of the same names.
+    counters: Tuple[str, ...] = ()
+    # The expert stacks shard their expert axis over `ep`
+    # (parallel/partition.py): the engines refuse ep > 1 for the others.
+    expert_parallel: bool = False
+    # The cache is a latent (models/mla.py): one head, nothing for `tp`
+    # to shard, and the engines refuse tp > 1.
+    latent_cache: bool = False
 
 
 GPT2_FAMILY = ModelFamily(
@@ -51,12 +61,17 @@ LLAMA_FAMILY = ModelFamily(
 )
 MOE_FAMILY = ModelFamily(
     "gpt2_moe", moe.init_params, moe.forward, moe.init_cache,
-    moe.params_from_hf,
+    moe.params_from_hf, expert_parallel=True,
 )
 
 AFMOE_FAMILY = ModelFamily(
     "afmoe", afmoe.init_params, afmoe.forward, afmoe.init_cache,
-    afmoe.params_from_hf, routed=True,
+    afmoe.params_from_hf, routed=True, counters=afmoe.COUNTERS,
+)
+AXK1_FAMILY = ModelFamily(
+    "axk1", axk1.init_params, axk1.forward, axk1.init_cache,
+    axk1.params_from_hf, routed=True, counters=axk1.COUNTERS,
+    latent_cache=True,
 )
 
 # preset -> (family, config factory)
@@ -73,6 +88,9 @@ PRESETS = {
     "trinity-mini": (AFMOE_FAMILY, afmoe.AfmoeConfig.trinity_mini),
     "trinity-mini-1d4e": (AFMOE_FAMILY, afmoe.AfmoeConfig.trinity_mini_1d4e),
     "afmoe-tiny": (AFMOE_FAMILY, afmoe.AfmoeConfig.tiny),
+    "ax-k1": (AXK1_FAMILY, axk1.AxK1Config.ax_k1),
+    "ax-k1-1d4e-12of192": (AXK1_FAMILY, axk1.AxK1Config.ax_k1_1d4e_share),
+    "axk1-tiny": (AXK1_FAMILY, axk1.AxK1Config.tiny),
 }
 
 

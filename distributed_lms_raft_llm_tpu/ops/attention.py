@@ -150,6 +150,72 @@ def decode_attention(q: jax.Array, k_cache: jax.Array, v_cache: jax.Array,
     return out.reshape(b, h, 1, dh)
 
 
+def _latent_decode_kernel(l_ref, q_ref, c_ref, bias_ref, o_ref, *,
+                          scale: float):
+    """One cache row: all heads' absorbed queries [H, C] against the row's
+    latent [S, C], which is keys and values both. The row crosses HBM once;
+    scores and softmax in f32."""
+    del l_ref  # consumed by the BlockSpec index maps
+    q, c = q_ref[0], c_ref[0, 0]
+    sc = jax.lax.dot_general(q, c, (((1,), (1,)), ((), ())),
+                             preferred_element_type=jnp.float32)  # [H, S]
+    sc = sc * scale + bias_ref[0]
+    p = jnp.exp(sc - jnp.max(sc, axis=-1, keepdims=True))
+    denom = jnp.sum(p, axis=-1, keepdims=True)
+    o = jax.lax.dot_general(p.astype(c.dtype), c, (((1,), (0,)), ((), ())),
+                            preferred_element_type=jnp.float32)   # [H, C]
+    o_ref[0] = (o / denom).astype(o_ref.dtype)
+
+
+def latent_decode_fits(s: int, c: int, itemsize: int) -> bool:
+    """Whether one row's latent [S, C], double-buffered at whole 128-lane
+    tiles, fits the kernel's VMEM budget (2,688 x 576 bfloat16 does: 6.9
+    MB); `models/mla.py` refuses a decode step over a longer cache."""
+    return 2 * s * -(-c // 128) * 128 * itemsize <= _KV_VMEM_BUDGET
+
+
+def latent_decode_attention(q: jax.Array, plane: jax.Array, layer: int,
+                            bias: jax.Array, scale: float, *,
+                            interpret: bool = False) -> jax.Array:
+    """Fused decode attention over one layer of a latent (MLA) cache, in
+    the absorbed form: keys and values are the same cached rows.
+
+    q      [B, H, C] — a decode step's queries folded into the latent
+           ([q_nope Wuk^T | q_rope]; models/mla.py)
+    plane  [L, B, S, C] — the stacked latent cache, [c_kv | k_rope]
+    layer  which layer's rows to attend over
+    bias   [B, 1, S] f32 — additive mask (0 = attend, NEG_INF = not)
+    returns softmax(q . plane^T * scale + bias) . plane, [B, H, C] in q's
+    dtype: the caller keeps the columns that are values (c_kv's).
+
+    The grid is the batch: one step reads one row's [S, C] once, where
+    XLA's two products read it twice, and relaid the whole plane
+    slot-minor first (C = 576 is four and a half lane tiles; by the
+    compiler's own text for a described v5e, a copy of all layers' plane
+    after every layer's scatter). The row is read out of the STACKED plane
+    through a scalar-prefetched layer index, as `decode_attention` reads
+    K and V.
+    """
+    b, h, c = q.shape
+    s = plane.shape[2]
+    return pl.pallas_call(
+        functools.partial(_latent_decode_kernel, scale=scale),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(b,),
+            in_specs=[
+                pl.BlockSpec((1, h, c), lambda i, l: (i, 0, 0)),
+                pl.BlockSpec((1, 1, s, c), lambda i, l: (l[0], i, 0, 0)),
+                pl.BlockSpec((1, 1, s), lambda i, l: (i, 0, 0)),
+            ],
+            out_specs=pl.BlockSpec((1, h, c), lambda i, l: (i, 0, 0)),
+        ),
+        out_shape=jax.ShapeDtypeStruct((b, h, c), q.dtype),
+        name="mla_decode",
+        interpret=interpret,
+    )(jnp.asarray(layer, jnp.int32)[None], q, plane, bias)
+
+
 def mask_to_bias(mask: jax.Array) -> jax.Array:
     """[B, 1, T, S] boolean attend-mask -> [B, 1, S] additive f32 bias
     (layer-invariant: compute once per decode step, outside the layer scan)."""

@@ -113,9 +113,10 @@ MOE_RULES: List[Tuple[str, PartitionSpec]] = [
 # axis. Attention and the dense and shared SwiGLUs shard like Llama's
 # (q/k/v/gate/up column-parallel, o/down row-parallel). The routed
 # experts [E, D, M], the router, its bias and the norms are replicated:
-# the grouped product takes whole stacks, and a chip's share of a layer's
-# experts needs a routed layer that is told which experts it holds
-# (ROADMAP S4; the engines refuse ep > 1 for this family). At tp = 1
+# the grouped product takes whole stacks (a chip's share of a layer's
+# experts is a configuration's `experts_held`, models/axk1.py; spreading
+# one layer's stacks over `ep` with the exchange between the shares is
+# ROADMAP S4, and the engines refuse ep > 1 for this family). At tp = 1
 # every spec degrades to replication.
 AFMOE_RULES: List[Tuple[str, PartitionSpec]] = [
     (r"embed$", P("tp")),
@@ -124,6 +125,16 @@ AFMOE_RULES: List[Tuple[str, PartitionSpec]] = [
     (r"layers/\d+/attn/wo$", P("tp")),
     (r"layers/\d+/(mlp|moe/shared)/w[gu]$", P(None, "tp")),
     (r"layers/\d+/(mlp|moe/shared)/wd$", P("tp")),
+    (r".*", P()),
+]
+
+# axk1 (models/axk1.py): afmoe's tree with latent attention (models/mla.py)
+# and, in the expert stacks, the share of a layer's experts this process
+# holds. Everything is replicated: the engines refuse tp > 1 (the latent
+# cache has no heads axis to shard) and ep > 1 (the share is the
+# configuration's, `experts_held`; the exchange between shares is not
+# built) for this family.
+AXK1_RULES: List[Tuple[str, PartitionSpec]] = [
     (r".*", P()),
 ]
 
@@ -140,6 +151,7 @@ RULES_FOR = {
     "bert": BERT_RULES,
     "gpt2_moe": MOE_RULES,
     "afmoe": AFMOE_RULES,
+    "axk1": AXK1_RULES,
 }
 
 # ---------------------------------------------- paged state plane table

@@ -580,7 +580,7 @@ MOE_PICKS = counter(
     "expert picks computed by the routed layers (live tokens x experts "
     "per token x expert layers), summed on the device over a dispatch's "
     "forward passes and read back at its reap; only a family with routed "
-    "experts counts (models/afmoe.py)",
+    "experts counts (models/afmoe.py, models/axk1.py)",
 )
 MOE_EXPERTS_REACHED = counter(
     "moe_experts_reached",
@@ -589,9 +589,16 @@ MOE_EXPERTS_REACHED = counter(
 )
 MOE_EXPERT_SEATS = counter(
     "moe_expert_seats",
-    "experts there were to reach: experts x expert layers, once per "
+    "experts there were to reach: experts held x expert layers, once per "
     "forward pass (reached / seats is the share of the expert weights a "
     "pass reads; an idle lane that routed would raise it)",
+)
+MOE_PICKS_HELD = counter(
+    "moe_picks_held",
+    "of moe_picks, those that landed on an expert this process holds: "
+    "only a family that holds a share of each layer's experts counts "
+    "(models/axk1.py `experts_held`; held / picks is the share of the "
+    "routed work this chip does, 12 / 192 where the router is fair to it)",
 )
 ENGINE_TOKENS_PAST_WINDOW = counter(
     "engine_tokens_past_window",
@@ -652,6 +659,7 @@ ENGINE_LOOP_COUNTERS: Dict[str, str] = {
     "moe_picks": MOE_PICKS,
     "moe_experts_reached": MOE_EXPERTS_REACHED,
     "moe_expert_seats": MOE_EXPERT_SEATS,
+    "moe_picks_held": MOE_PICKS_HELD,
     "tokens_past_window": ENGINE_TOKENS_PAST_WINDOW,
 }
 ENGINE_LOOP_HISTOGRAMS: Dict[str, str] = {

@@ -314,6 +314,46 @@ def test_trinity_mini_cut_step_and_megastep_compile_and_fit_one_v5e(one_chip):
         assert "ragged-dot" in compiled.as_text()
 
 
+def test_ax_k1_cut_megastep_runs_absorbed_over_the_latent_cache(one_chip):
+    """`ax-k1-1d4e-12of192` at the published widths, from shapes alone, in
+    the serving settings of benchmarks/configs/ax-k1.json (32 slots, width
+    2,688, chunk 16, prefill chunks of 32, K = 2): 6.98 GB of bfloat16
+    weights beside a latent cache of 0.50 GB; decode attention is the
+    kernel `mla_decode` over the cache's one plane, nothing holds the
+    cache expanded to heads, and the plane is not copied whole inside the
+    loops; the held experts go through the TPU's grouped kernel."""
+    family, cfg = registry.resolve("ax-k1-1d4e-12of192", jnp.bfloat16,
+                                   jnp.bfloat16)
+    params = _with(jax.eval_shape(
+        lambda: family.init_params(jax.random.key(0), cfg)), one_chip)
+    assert sum(x.size for x in jax.tree.leaves(params)) == 3_491_257_344
+    state = jax.eval_shape(partial(paged._fresh_state, family, cfg, 32, 2688))
+    assert state.cache.v is None
+    assert state.cache.k.shape == (5, 32, 1, 2688, 576)
+    mega = jax.jit(
+        partial(paged._megastep_program, chunk=16, spec_tokens=0,
+                prefill_chunk=32, draft_fn=build_drafts, eos_id=50256,
+                pad_id=50256, cfg=cfg, model=family,
+                sampling=SamplingParams.reference_defaults()),
+        donate_argnums=(1,),
+    ).lower(params, _with(state, one_chip), _with(jax.eval_shape(
+        lambda: jax.random.split(jax.random.key(0), 2)), one_chip)).compile()
+    ma = mega.memory_analysis()
+    assert _device_bytes(ma) < 0.75 * HBM_BYTES
+    assert ma.temp_size_in_bytes < 2 * 1024**3
+    text = mega.as_text()
+    assert "ragged-dot" in text and "mla_decode" in text
+    # Keys or values of the 64 heads over the cache's width: [.., 64,
+    # 2688, 128 | 192 | 256] or its transpose, for one lane or for all.
+    expanded = re.findall(
+        r"(?:bf16|f32)\[(?:\d+,)*(?:64,2688|2688,64),(?:128|192|256)\]",
+        text)
+    assert expanded == []
+    for plane in ("bf16[5,32,1,2688,576]", "bf16[5,32,2688,576]"):
+        assert plane in text
+        assert _copies_inside_loops(text, plane) == []
+
+
 # ------------------- a prefill chunk touches its slot's pages in place
 
 def _copies_inside_loops(text: str, shape: str) -> list:
