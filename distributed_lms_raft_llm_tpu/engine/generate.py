@@ -107,7 +107,7 @@ def prefill(
     real_lens = jnp.sum(prompt_mask.astype(jnp.int32), axis=1)  # [B]
 
     # Prompt-sized cache: decode pads it up per segment (module docstring).
-    cache = model.init_cache(cfg, b, t, dtype=cfg.dtype)
+    cache = model.init_cache(cfg, b, t, dtype=cfg.dtype, groups=1)
     # Slots 0..t-1 hold the (partly padded) prompt; decode slots are real.
     kv_mask = jnp.concatenate(
         [prompt_mask.astype(jnp.bool_), jnp.ones((b, max_new), jnp.bool_)], axis=1

@@ -123,7 +123,8 @@ log = logging.getLogger(__name__)
 class SlotState(NamedTuple):
     """Device-side state of all S slots."""
 
-    cache: KVCache     # k/v [L, S, H, Tmax, Dh]; length [S] per-slot
+    cache: KVCache     # k/v [L, S, H, Tmax, Dh] (a family that folds its
+    #                    heads: [L, S, tp, Tmax, F]); length [S] per-slot
     tok: jax.Array     # [S] last sampled token per slot
     active: jax.Array  # [S] bool
     seen: jax.Array    # [S, V] repetition-penalty presence mask
@@ -347,12 +348,17 @@ def cfg_tmax(cfg, sampling: SamplingParams, bucket: int) -> int:
     return min(bucket + sampling.max_new_tokens, cfg.max_position_embeddings)
 
 
-def _fresh_state(family, cfg, slots: int, width: int) -> SlotState:
+def _fresh_state(family, cfg, slots: int, width: int,
+                 groups: int = 1) -> SlotState:
     """All-idle SlotState for `slots` slots at cache width `width`,
     unplaced: `PagedEngine._init_state` puts every plane on its table
     sharding, and tests/test_chip_compile.py lowers the step programs
-    from these shapes (`jax.eval_shape`) without an engine."""
-    cache = family.init_cache(cfg, slots, width, dtype=cfg.dtype)
+    from these shapes (`jax.eval_shape`) without an engine. `groups` is
+    the tp ways: a family that folds its heads into the feature axis
+    (models/common.py `folds_heads`) keeps that many head groups on the
+    axis the plane table shards."""
+    cache = family.init_cache(cfg, slots, width, dtype=cfg.dtype,
+                              groups=groups)
     cache = cache._replace(length=jnp.zeros((slots,), jnp.int32))
     # Staged-rng plane shape follows the live PRNG impl's key data
     # (threefry: [2] uint32) so wrap_key_data round-trips exactly.
@@ -1316,7 +1322,8 @@ class PagedEngine:
         # (see _plane_spec). KV planes are born tp-sharded over their
         # heads axis; host-state planes replicated.
         return self._canon_state(_fresh_state(
-            self.family, self.cfg, self.slots, width or self.widths[0]
+            self.family, self.cfg, self.slots, width or self.widths[0],
+            groups=self.tp,
         ))
 
     # ------------------------------------------------------------ host API

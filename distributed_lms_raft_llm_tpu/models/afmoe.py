@@ -197,7 +197,9 @@ def init_params(rng: jax.Array, cfg: AfmoeConfig) -> Params:
 
 
 def init_cache(cfg: AfmoeConfig, batch: int, max_len: int,
-               dtype=None) -> KVCache:
+               dtype=None, groups=None) -> KVCache:
+    # `groups` (models/registry.py): a bfloat16 plane of 128-wide heads
+    # tiles unpadded as it is.
     if cfg.quant_kv:
         raise ValueError("afmoe serves the published bfloat16 cache: "
                          "kv_quant is not supported")

@@ -169,7 +169,8 @@ def init_params(rng: jax.Array, cfg: AxK1Config) -> Params:
 
 
 def init_cache(cfg: AxK1Config, batch: int, max_len: int,
-               dtype=None) -> KVCache:
+               dtype=None, groups=None) -> KVCache:
+    # `groups` (models/registry.py): the latent plane has one head.
     if cfg.quant_kv:
         raise ValueError("axk1 serves the published bfloat16 latent cache: "
                          "kv_quant is not supported")

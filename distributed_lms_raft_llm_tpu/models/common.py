@@ -72,11 +72,21 @@ class KVCache(NamedTuple):
             declares its own planes in `init_cache`, each [L, B, H, T, F]
             with its own H and F, and `v` is None where there is one plane
             (models/mla.py: a latent cache, one head, no values).
+            FOLDED planes (`create`'s `groups`; models/gpt2.py's int8 cache)
+            are [L, B, G, T, F]: the H/G heads of a group side by side in
+            one row a position, F = (H/G)*Dh rounded up to whole lanes.
+            Why: inside the layer scans an int8 plane is tiled (32, 128)
+            over its two minor-most axes; over (H, Dh) = (25, 64) that is
+            (32, 128), 2.56 times gpt2-xl's bytes, over (T, F) = (384, 1664)
+            it is 1.04 times (`folds_heads`). Positions stay on axis 3 and
+            G is the axis `tp` shards, so the engines' splices, exports and
+            growth see the planes they are promised.
     length: [] int32 — number of valid positions already written.
     ks, vs: per-slot dequantization scales [L, B, Hkv, max_len] f32 when the
             cache is int8-quantized (halves the HBM bytes the decode loop
             streams per layer — see `quantize_kv`/`attend_quant`); None for
-            a full-precision cache.
+            a full-precision cache. One scale a head and position, folded
+            planes too.
 
     A single scalar length serves the whole batch; per-sequence raggedness is
     handled above the model by the engine's bucketing/batching (engine.paged
@@ -103,10 +113,14 @@ class KVCache(NamedTuple):
         head_dim: int,
         dtype=jnp.bfloat16,
         quantized: bool = False,
+        groups: Optional[int] = None,
     ) -> "KVCache":
         shape = (num_layers, batch, num_kv_heads, max_len, head_dim)
         if quantized:
             sshape = shape[:-1]
+            if groups:  # folded planes (class docstring); scales per head
+                shape = (num_layers, batch, groups, max_len,
+                         folded_width(num_kv_heads // groups, head_dim))
             return cls(
                 k=jnp.zeros(shape, jnp.int8),
                 v=jnp.zeros(shape, jnp.int8),
@@ -133,16 +147,81 @@ def layer_rows(plane: jax.Array, layer,
     return plane[layer, rows]
 
 
+def write_scales(plane: jax.Array, layer, rows: Optional[jax.Array],
+                 slots: jax.Array, scales: jax.Array) -> jax.Array:
+    """A ragged batch's new scales [B, H, T] into one layer of a scale
+    plane [L, R, H, W], at positions `slots` [B, T] of the rows `rows`
+    ([B]; None: row i for element i), as a select over the layer's rows
+    and not a scatter of [H]-columns: a scatter makes the scans carry the
+    plane heads-minor (25 of 128 lanes), and every layer then relays its
+    [R, H, W] slice positions-minor for the scores. A slot past the width
+    matches nothing and is dropped, as the scatter drops it."""
+    cur = layer_rows(plane, layer, rows)
+    hit = slots[:, None, :, None] == jnp.arange(plane.shape[-1])  # [B,1,T,W]
+    new = jnp.sum(jnp.where(hit, scales[..., None], 0.0), axis=2)
+    cur = jnp.where(jnp.any(hit, axis=2), new, cur)
+    if rows is None:
+        return jax.lax.dynamic_update_index_in_dim(plane, cur, layer, 0)
+    return plane.at[layer, rows].set(cur)
+
+
 def quantize_kv(x: jax.Array) -> Tuple[jax.Array, jax.Array]:
     """Symmetric per-(batch, head, slot) int8: [B, H, T, Dh] -> (int8 same
     shape, f32 [B, H, T] scales). One scale per cache slot keeps the
     dequant outside the attention dots (scores scale by ks on the
-    un-contracted slot axis; vs folds into the probabilities)."""
+    un-contracted slot axis; vs folds into the probabilities). The scales
+    are per head, taken BEFORE a family folds the heads into one row
+    (`fold_heads`): the bytes and scales are the same either way."""
     xf = x.astype(jnp.float32)
     s = jnp.max(jnp.abs(xf), axis=-1) / 127.0  # [B, H, T]
     s = jnp.maximum(s, 1e-8)
     q = jnp.clip(jnp.round(xf / s[..., None]), -127, 127).astype(jnp.int8)
     return q, s
+
+
+LANES = 128  # the TPU's minor tile dimension
+
+
+def folds_heads(head_dim: int, quantized: bool) -> bool:
+    """Whether a family folds its heads into the feature axis of its K/V
+    planes: an int8 plane `[.., H, T, Dh]` is tiled (32, 128) over (H, Dh)
+    inside the layer scans, so gpt2-xl's (25, 64) is read, and multiplied,
+    as (32, 128): 2.56 times its bytes. Folded, the tile falls on
+    (T, H*Dh) = (384, 1600 -> 1664): 4%."""
+    return quantized and head_dim % LANES != 0
+
+
+def folded_width(heads: int, head_dim: int) -> int:
+    """The feature axis of a folded plane: the heads' bytes rounded up to
+    whole lanes, so that the layout an array has at rest (the runtime puts
+    the lane-multiple axis minor-most) is the one the scans carry."""
+    return -(-heads * head_dim // LANES) * LANES
+
+
+def fold_heads(x: jax.Array, groups: int) -> jax.Array:
+    """[.., H, T, Dh] -> [.., G, T, F]: a group's heads side by side in one
+    row a position (G = 1: a token's whole H*Dh bytes), zeros up to
+    F = `folded_width`."""
+    *lead, h, t, d = x.shape
+    x = x.reshape(*lead, groups, h // groups, t, d)
+    x = jnp.swapaxes(x, -3, -2).reshape(*lead, groups, t, h // groups * d)
+    pad = folded_width(h // groups, d) - x.shape[-1]
+    return jnp.pad(x, [(0, 0)] * (x.ndim - 1) + [(0, pad)])
+
+
+def unfold_heads(x: jax.Array, heads: int, head_dim: int) -> jax.Array:
+    """`fold_heads` undone: [.., G, T, F] -> [.., heads, T, head_dim]."""
+    *lead, g, t, _ = x.shape
+    x = x[..., :heads // g * head_dim]
+    x = x.reshape(*lead, g, t, heads // g, head_dim)
+    return jnp.swapaxes(x, -3, -2).reshape(*lead, heads, t, head_dim)
+
+
+def _head_lanes(heads: int, width: int, head_dim: int, dtype) -> jax.Array:
+    """[heads, width] ones where lane f of a folded row is head f //
+    head_dim's: a row a head, each over its own lanes."""
+    lane_head = jnp.arange(width)[None, :] // head_dim
+    return (lane_head == jnp.arange(heads)[:, None]).astype(dtype)
 
 
 def attend_quant(
@@ -154,25 +233,54 @@ def attend_quant(
     mask: jax.Array,
 ) -> jax.Array:
     """`attend` against an int8 cache: q [B,H,T,Dh], k_q/v_q int8
-    [B,H,S,Dh], ks/vs f32 [B,H,S], mask [B,1,T,S].
+    [B,H,S,Dh] or folded [B,G,S,F] (`fold_heads`), ks/vs f32 [B,H,S],
+    mask [B,1,T,S].
 
     Both dequant multiplies stay OUTSIDE the dots — ks scales the score
     matrix on its un-contracted slot axis, vs folds into the (tiny)
     probability matrix — so the int8 operands feed the MXU directly and
     HBM sees half the bytes of a bf16 cache.
+
+    Over folded planes a decode step (T = 1) never splits a row's lanes
+    into (H, Dh), which is the padded tile again: the query goes
+    block-diagonal, head h's Dh values on head h's lanes of row h and
+    zeros beside, so one product over the whole row gives every head's
+    score, the products and the f32 sums of the per-head form with zeros
+    added; the values come back as [heads, F] rows of which each lane
+    keeps its own head's. A window of queries (a prefill chunk's one row,
+    a speculative window) unfolds the rows it attends over instead.
     """
     dtype = q.dtype
-    head_dim = q.shape[-1]
-    scores = jnp.einsum(
-        "bhqd,bhkd->bhqk", q, k_q.astype(dtype),
-        preferred_element_type=jnp.float32,
-    )
+    b, h, t, head_dim = q.shape
+    folded = k_q.shape[-1] != head_dim
+    if folded and t > 1:
+        k_q = unfold_heads(k_q, h, head_dim)
+        v_q = unfold_heads(v_q, h, head_dim)
+        folded = False
+    if folded:
+        g, s, width = k_q.shape[1:]
+        lanes = _head_lanes(h // g, width, head_dim, dtype)
+        q_rows = fold_heads(q, g) * lanes  # [B,G,1,F] -> [B,G,H/G,F]
+        scores = jnp.einsum(
+            "bghf,bgsf->bghs", q_rows, k_q.astype(dtype),
+            preferred_element_type=jnp.float32,
+        ).reshape(b, h, 1, s)
+    else:
+        scores = jnp.einsum(
+            "bhqd,bhkd->bhqk", q, k_q.astype(dtype),
+            preferred_element_type=jnp.float32,
+        )
     scores = scores * ks[:, :, None, :]
     scores = scores / jnp.sqrt(jnp.asarray(head_dim, jnp.float32))
     scores = jnp.where(mask, scores, NEG_INF)
     probs = jax.nn.softmax(scores, axis=-1)
     probs = (probs * vs[:, :, None, :]).astype(dtype)
-    return jnp.einsum("bhqk,bhkd->bhqd", probs, v_q.astype(dtype))
+    if not folded:
+        return jnp.einsum("bhqk,bhkd->bhqd", probs, v_q.astype(dtype))
+    rows = jnp.einsum("bghs,bgsf->bghf", probs.reshape(b, g, h // g, s),
+                      v_q.astype(dtype))
+    out = jnp.sum(rows * lanes, axis=2, keepdims=True)  # [B,G,1,F]
+    return unfold_heads(out, h, head_dim)
 
 
 def attend(

@@ -31,6 +31,8 @@ from .common import (
     KVCache,
     attend,
     attend_quant,
+    fold_heads,
+    folds_heads,
     causal_window_mask,
     dense,
     layer_norm,
@@ -38,6 +40,8 @@ from .common import (
     merge_heads,
     quantize_kv,
     split_heads,
+    unfold_heads,
+    write_scales,
 )
 
 Params = Dict[str, Any]
@@ -138,10 +142,18 @@ def init_params(rng: jax.Array, cfg: GPT2Config) -> Params:
     }
 
 
-def init_cache(cfg: GPT2Config, batch: int, max_len: int, dtype=None) -> KVCache:
+def init_cache(cfg: GPT2Config, batch: int, max_len: int, dtype=None,
+               groups: Optional[int] = None) -> KVCache:
+    """`groups`: the head groups a served cache keeps as its axis 2 (the
+    engines pass their tp ways, 1 on one chip). Where the int8 planes'
+    tile would be padded (`folds_heads`) they are then declared folded,
+    `[L, B, G, T, (H/G)*Dh]`. Left out, the planes are `[L, B, H, T, Dh]`
+    whatever the tile, and `forward` folds them on its way in."""
+    fold = groups and folds_heads(cfg.head_dim, cfg.quant_kv)
     return KVCache.create(
         cfg.num_layers, batch, cfg.num_heads, max_len, cfg.head_dim,
         dtype or cfg.dtype, quantized=cfg.quant_kv,
+        groups=groups if fold else None,
     )
 
 
@@ -372,6 +384,16 @@ def forward(
         # The attend-mask is layer-invariant; its additive-bias form is
         # computed once per step, outside the layer scan.
         bias = attention_ops.mask_to_bias(mask) if fused else None
+        # Int8 planes whose tile would be padded ride the scan folded
+        # (common.folds_heads). A served cache arrives that way
+        # (`init_cache`'s `groups`); one declared `[L, B, H, T, Dh]` is
+        # folded here and handed back as it came.
+        k0, v0 = cache.k, cache.v
+        folded = folds_heads(cfg.head_dim, quant_kv)
+        fold_here = folded and k0.shape[2:] == (num_heads, num_keys,
+                                                cfg.head_dim)
+        if fold_here:
+            k0, v0 = fold_heads(k0, 1), fold_heads(v0, 1)
 
         def body(carry, xs):
             x, ck, cv, cks, cvs = carry
@@ -384,13 +406,16 @@ def forward(
                     v_w, v_s = quantize_kv(v_new)
                 else:
                     k_w, v_w = k_new.astype(ck.dtype), v_new.astype(cv.dtype)
+                if folded:  # a token's heads as one row a group
+                    k_w = fold_heads(k_w, ck.shape[2])
+                    v_w = fold_heads(v_w, cv.shape[2])
                 cks2, cvs2 = cks, cvs
                 if offset.ndim == 1:
                     # Ragged slots: scatter each row's T new tokens at its
                     # own offset (T=1 for paged decode; T=k+1 for the
                     # speculative verify window — engine.spec). Advanced
                     # indices [B,1] rows × [B,T] slots land in front, so
-                    # values go [B, T, H, Dh].
+                    # values go [B, T, H, Dh] ([B, T, G, (H/G)*Dh] folded).
                     at_rows = (jnp.arange(k_new.shape[0]) if rows is None
                                else rows)[:, None]
                     slots = offset[:, None] + jnp.arange(t)[None, :]
@@ -401,12 +426,8 @@ def forward(
                         v_w.transpose(0, 2, 1, 3)
                     )
                     if quant_kv:
-                        cks2 = cks.at[layer, at_rows, :, slots].set(
-                            k_s.transpose(0, 2, 1)
-                        )
-                        cvs2 = cvs.at[layer, at_rows, :, slots].set(
-                            v_s.transpose(0, 2, 1)
-                        )
+                        cks2 = write_scales(cks, layer, rows, slots, k_s)
+                        cvs2 = write_scales(cvs, layer, rows, slots, v_s)
                 else:
                     start = (layer, zero, zero, offset, zero)
                     ck2 = jax.lax.dynamic_update_slice(ck, k_w[None], start)
@@ -448,9 +469,12 @@ def forward(
 
         layers = jnp.arange(cfg.num_layers, dtype=jnp.int32)
         (x, new_k, new_v, new_ks, new_vs), _ = jax.lax.scan(
-            body, (x, cache.k, cache.v, cache.ks, cache.vs),
+            body, (x, k0, v0, cache.ks, cache.vs),
             (params["blocks"], layers),
         )
+        if fold_here:
+            new_k = unfold_heads(new_k, num_heads, cfg.head_dim)
+            new_v = unfold_heads(new_v, num_heads, cfg.head_dim)
         new_cache = KVCache(k=new_k, v=new_v, length=cache.length + t,
                             ks=new_ks, vs=new_vs)
 

@@ -121,7 +121,9 @@ def init_params(rng: jax.Array, cfg: LlamaConfig) -> Params:
     }
 
 
-def init_cache(cfg: LlamaConfig, batch: int, max_len: int, dtype=None) -> KVCache:
+def init_cache(cfg: LlamaConfig, batch: int, max_len: int, dtype=None,
+               groups=None) -> KVCache:
+    # `groups` (models/registry.py): llama's planes are not folded.
     return KVCache.create(
         cfg.num_layers, batch, cfg.num_kv_heads, max_len, cfg.head_dim,
         dtype or cfg.dtype, quantized=cfg.quant_kv,

@@ -6,7 +6,7 @@ drives any family exposing the same functional surface:
     init_params(rng, cfg) -> params
     forward(params, cfg, ids, cache=, positions=, kv_mask=, rows=)
         -> (logits, cache)
-    init_cache(cfg, batch, max_len, dtype=) -> KVCache
+    init_cache(cfg, batch, max_len, dtype=, groups=) -> KVCache
     params_from_hf(state_dict, cfg) -> params
 
 `rows` ([B] int32, ragged `cache.length` only; default: row i for batch
@@ -15,6 +15,12 @@ its keys and values are scattered into those rows in place, it attends
 over those rows alone, and every other row comes back as it went in. The
 paged engine's prefill chunk is such a batch, one staged slot of the live
 multi-slot cache (`engine/paged.py` `_admission_chunk`).
+
+`groups` is how many head groups a served cache keeps as the axis `tp`
+shards (the engines pass their tp ways): a family whose planes would tile
+padded as `[L, B, H, T, Dh]` declares them `[L, B, G, T, (H/G)*Dh]` then
+(`models/common.py` `folds_heads`: GPT-2's int8 planes), and its `forward`
+takes either form; every other family ignores it.
 
 The reference hardcodes one architecture behind `from_pretrained("gpt2")`
 (reference: GUI_RAFT_LLM_SourceCode/tutoring_server.py:10); here presets

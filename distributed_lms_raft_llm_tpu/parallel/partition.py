@@ -167,7 +167,9 @@ RULES_FOR = {
 #
 # KV planes shard their heads axis over tp: slot cache k/v are
 # [L, S, Hkv, T, Dh] and the int8-KV scale planes ks/vs are
-# [L, S, Hkv, T] — heads is axis 2 in both — so one spec spelling,
+# [L, S, Hkv, T] — heads is axis 2 in both (a family that folds its heads
+# into the feature axis keeps tp head GROUPS there, [L, S, tp, T, F]:
+# models/common.py `fold_heads`) — so one spec spelling,
 # P(None, None, "tp"), serves the pair; the prefix tree's immutable
 # KVBlock runs ([L, 1, H, B, Dh] / [L, 1, H, B]) share the layout and
 # the spec, making a radix hit splice tp-sharded blocks without a

@@ -1,0 +1,154 @@
+#!/usr/bin/env python
+"""What one decode step and one prefill chunk cost on the device, for a
+benchmark configuration's own engine (PERF.md section 5 keeps the readings).
+
+Builds the engine as `benchmarks/serve.py` does (weights drawn from the
+seed) and times its K = 2 megastep at the widest cache width, from the
+host's clock around a call that ends in `block_until_ready`:
+
+- with nothing staged and no lane live: every iteration is the decode step
+  over all slots (a dead lane computes what a live one does);
+- with every slot staged on a prompt of the longest bucket, so that every
+  iteration also serves one prefill chunk: the difference is the chunk.
+
+The state is donated, so each call gets a fresh one (made and staged
+outside the timed region). One JSON line on stdout; `platform` says where
+it ran, and only a TPU's line is a measurement.
+
+    chiprun -- python scripts/steady_probe.py --config gpt2-xl
+    JAX_PLATFORMS=cpu python scripts/steady_probe.py --config tiny --platform cpu
+"""
+
+from __future__ import annotations
+
+import argparse
+import json
+import os
+import statistics
+import sys
+import time
+
+REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+sys.path.insert(0, REPO)
+
+K = 2  # chunks a dispatch: the rung the cells' controller dispatches most
+
+
+def timed(engine, make_state, calls: int) -> tuple:
+    """Milliseconds of `calls` megastep dispatches, each on a state of its
+    own (the first call of a shape compiles and is not among them), and
+    the state the last one left."""
+    import jax
+
+    out = []
+    for i in range(calls + 1):
+        state = jax.block_until_ready(make_state())
+        keys = jax.block_until_ready(engine._step_keys(K))
+        t = time.perf_counter()
+        with engine.mesh:
+            state, *res = engine._megastep(engine.params, state, keys)
+        jax.block_until_ready((state, res))
+        if i:
+            out.append(1e3 * (time.perf_counter() - t))
+    return out, state
+
+
+def traced_ops(engine, make_state, trace_dir: str, top: int) -> dict:
+    """One more megastep under the profiler: the device's time by
+    operation, longest first (`benchmarks/trace.py`)."""
+    import jax
+
+    from benchmarks import trace
+
+    state = jax.block_until_ready(make_state())
+    keys = jax.block_until_ready(engine._step_keys(K))
+    jax.profiler.start_trace(trace_dir)
+    with engine.mesh:
+        jax.block_until_ready(engine._megastep(engine.params, state, keys))
+    jax.profiler.stop_trace()
+    got = trace.reduce_dir(trace_dir)
+    return {"busy_s": got["busy_s"], "loops": got["loops"][:4],
+            "device_ops": got["device_ops"][:top]}
+
+
+def main(argv=None) -> int:
+    ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
+    ap.add_argument("--config", required=True,
+                    help="a name under benchmarks/configs/ (gpt2-xl, ...)")
+    ap.add_argument("--seed", type=int, default=3000000019)
+    ap.add_argument("--calls", type=int, default=5)
+    ap.add_argument("--platform", default="tpu", choices=["tpu", "cpu"])
+    ap.add_argument("--trace-dir", default=None,
+                    help="also trace one megastep of each kind into this "
+                         "directory and print its longest operations")
+    ap.add_argument("--top", type=int, default=16)
+    args = ap.parse_args(argv)
+    with open(os.path.join(REPO, "benchmarks", "configs",
+                           f"{args.config}.json"), encoding="utf-8") as fh:
+        config = json.load(fh)
+
+    import jax
+    import numpy as np
+
+    if args.platform == "cpu":
+        jax.config.update("jax_platforms", "cpu")
+    from benchmarks import serve
+
+    platform = jax.devices()[0].platform
+    if platform != args.platform:
+        print(f"JAX initialised {platform!r}, the probe asked for "
+              f"{args.platform!r}", file=sys.stderr)
+        return 3
+    engine = serve.build_engine(config, args.seed)
+    width, bucket = max(engine.widths), engine.bucket
+    iterations = K * engine.chunk
+
+    def idle():
+        return engine._init_state(width)
+
+    def staged():
+        state = idle()
+        ids = np.full((1, bucket), engine.tokenizer.pad_id, np.int32)
+        with engine.mesh:
+            for slot in range(engine.slots):
+                state = engine._stage(
+                    engine._canon_state(state), engine._i32(slot), ids,
+                    np.int32(bucket), np.int32(0), np.int32(slot),
+                    jax.random.key_data(jax.random.key(slot)),
+                )
+        return engine._canon_state(state)
+
+    chunks = engine.slots * -(-bucket // engine.prefill_chunk)
+    if chunks < iterations:
+        print(f"{chunks} chunks staged cannot fill {iterations} iterations",
+              file=sys.stderr)
+        return 2
+    t_idle, _ = timed(engine, idle, args.calls)
+    t_staged, after = timed(engine, staged, args.calls)
+    m_idle, m_staged = map(statistics.median, (t_idle, t_staged))
+    traces = {}
+    if args.trace_dir:
+        for name, make in (("idle", idle), ("staged", staged)):
+            traces[f"trace_{name}"] = traced_ops(
+                engine, make, os.path.join(args.trace_dir, name), args.top)
+    print(json.dumps({
+        "line": "steady_probe", "config": args.config, "platform": platform,
+        "device_kind": jax.devices()[0].device_kind, "seed": args.seed,
+        "slots": engine.slots, "width": width, "k": K,
+        "iterations": iterations, "prefill_chunk": engine.prefill_chunk,
+        "planes": {name: [str(x.dtype), *x.shape] for name, x
+                   in after.cache._asdict().items()
+                   if x is not None and x.ndim > 1},
+        # Every staged iteration moves its slot's cursor one chunk on.
+        "chunks_served": int(np.sum(np.asarray(after.stage_cursor)))
+        // engine.prefill_chunk,
+        "megastep_idle_ms": t_idle, "megastep_staged_ms": t_staged,
+        "step_ms": m_idle / iterations,
+        "chunk_ms": (m_staged - m_idle) / iterations,
+        **traces,
+    }))
+    return 0
+
+
+if __name__ == "__main__":
+    sys.exit(main())

@@ -14,6 +14,7 @@ same tests.
 """
 
 import dataclasses
+import math
 import os
 import re
 from functools import partial
@@ -137,16 +138,21 @@ class _Production:
                 self.family.name,
             )
         )
-        self.state = jax.eval_shape(
-            partial(paged._fresh_state, self.family, self.cfg, self.slots,
-                    self.width)
-        )
+        self.state = self.fresh_state()
         self.key = jax.eval_shape(lambda: jax.random.key(0))
         self.keys = jax.eval_shape(
             lambda: jax.random.split(jax.random.key(0), 1)
         )
         self.statics = dict(cfg=self.cfg, sampling=self.sampling,
                             model=self.family)
+
+    def fresh_state(self, groups: int = 1):
+        """The idle state's shapes; `groups` is the engine's tp ways (the
+        folded int8 planes keep that many head groups to shard)."""
+        return jax.eval_shape(
+            partial(paged._fresh_state, self.family, self.cfg, self.slots,
+                    self.width, groups)
+        )
 
     def megastep(self):
         """The fused-admission megastep the production config dispatches
@@ -235,19 +241,21 @@ def test_paged_megastep_tp4_shards_planes_over_four_chips(topo, one_chip,
     def plane(name):
         return NamedSharding(mesh, partition.PAGED_PLANE_SPECS[name])
 
-    s_sh = prod.state._replace(
-        cache=prod.state.cache._replace(**{
+    state = prod.fresh_state(groups=4)
+    assert state.cache.k.shape[2] == 4
+    s_sh = state._replace(
+        cache=state.cache._replace(**{
             f: plane(f"cache.{f}") for f in ("k", "v", "ks", "vs", "length")
         }),
-        **{f: plane(f) for f in prod.state._fields if f != "cache"},
+        **{f: plane(f) for f in state._fields if f != "cache"},
     )
     replicated = NamedSharding(mesh, jax.sharding.PartitionSpec())
-    args = (_with(prod.params, p_sh), _with(prod.state, s_sh),
+    args = (_with(prod.params, p_sh), _with(state, s_sh),
             _with(prod.keys, replicated))
     with mesh:
         sharded = prod.megastep().lower(*args).compile()
     whole = prod.megastep().lower(
-        _with(prod.params, one_chip), _with(prod.state, one_chip),
+        _with(prod.params, one_chip), _with(state, one_chip),
         _with(prod.keys, one_chip),
     ).compile()
 
@@ -422,22 +430,12 @@ ENTRY %main.1 (a: s8[2,4]) -> s8[2,4] {
     assert _copies_inside_loops(text, "s8[4,2]") == []
 
 
-@pytest.mark.parametrize("preset,quant_kv,width,max_temps", [
-    # int8 weights and int8 K/V, as benchmarks/configs/gpt2-xl.json serves
-    # it. 5.80 GB of temporaries while a chunk sliced its slot's pages out
-    # and back (two slot-major copies of 1.26 GB among them), 3.23 since.
-    ("gpt2-xl", True, 384, 4 * 1024**3),
-    ("trinity-mini-1d4e", False, 2688, None),
-])
-def test_megastep_touches_a_staged_slots_pages_in_place(
-        one_chip, preset, quant_kv, width, max_temps):
-    """The megastep of both benchmark configurations (16 slots, chunk 16,
-    prefill chunks of 32, K = 2) for a described v5e: no copy of a whole K
-    or V plane inside the scans or the staged branch. A chunk that reaches
-    its slot's pages through a private `[L, 1, H, W, Dh]` cache makes the
-    compiler relay both planes slot-major and back, four whole-plane
-    copies for 32 tokens. What the entry computation copies, once a
-    dispatch, is ROADMAP S2's."""
+def _benchmark_megastep(one_chip, preset, quant_kv, width):
+    """(compiled, state shapes) of a benchmark configuration's megastep
+    (16 slots, chunk 16, prefill chunks of 32, K = 2) for a described
+    v5e, lowered from shapes alone: the parameters and results carry the
+    layouts the runtime gives arrays at rest, which is what one dispatch
+    hands the next."""
     family, cfg = registry.resolve(preset, jnp.bfloat16, jnp.bfloat16)
     init = partial(family.init_params, jax.random.key(0), cfg)
     if quant_kv:
@@ -459,6 +457,27 @@ def test_megastep_touches_a_staged_slots_pages_in_place(
         _with(jax.eval_shape(
             lambda: jax.random.split(jax.random.key(0), 2)), one_chip),
     ).compile()
+    return compiled, state
+
+
+@pytest.mark.parametrize("preset,quant_kv,width,max_temps", [
+    # int8 weights and int8 K/V, as benchmarks/configs/gpt2-xl.json serves
+    # it. 5.80 GB of temporaries while a chunk sliced its slot's pages out
+    # and back (two slot-major copies of 1.26 GB among them), 3.23 while
+    # the scans carried the planes padded (the test below), 0.90 since.
+    ("gpt2-xl", True, 384, 4 * 1024**3),
+    ("trinity-mini-1d4e", False, 2688, None),
+])
+def test_megastep_touches_a_staged_slots_pages_in_place(
+        one_chip, preset, quant_kv, width, max_temps):
+    """The megastep of both benchmark configurations (16 slots, chunk 16,
+    prefill chunks of 32, K = 2) for a described v5e: no copy of a whole K
+    or V plane inside the scans or the staged branch. A chunk that reaches
+    its slot's pages through a private `[L, 1, H, W, Dh]` cache makes the
+    compiler relay both planes slot-major and back, four whole-plane
+    copies for 32 tokens. What the entry computation copies, once a
+    dispatch, is the next test's where the planes are GPT-2's."""
+    compiled, state = _benchmark_megastep(one_chip, preset, quant_kv, width)
     k = state.cache.k
     plane = f"{'s8' if quant_kv else 'bf16'}[{','.join(map(str, k.shape))}]"
     text = compiled.as_text()
@@ -466,3 +485,66 @@ def test_megastep_touches_a_staged_slots_pages_in_place(
     assert _copies_inside_loops(text, plane) == []
     if max_temps is not None:
         assert compiled.memory_analysis().temp_size_in_bytes < max_temps
+
+
+# ------------------- gpt2-xl's int8 planes tile without padding
+
+def _tiled_bytes(shape: str) -> tuple:
+    """(bytes as tiled, bytes of the elements) of an `s8` array as a
+    compiled module writes it, `s8[48,16,1,384,1664]{4,3,1,0,2:T(8,128)
+    (4,1)}`: four int8 rows to a sublane, so the tile covers (32, 128)
+    elements of the two minor-most dimensions."""
+    dims, order = re.match(
+        r"s8\[([\d,]+)\]\{([\d,]+):T\(8,128\)\(4,1\)", shape).groups()
+    dims = [int(d) for d in dims.split(",")]
+    order = [int(d) for d in order.split(",")]
+    tiled = 1
+    for i, d in enumerate(dims):
+        tile = {order[0]: 128, order[1]: 32}.get(i, 1)
+        tiled *= -(-d // tile) * tile
+    return tiled, math.prod(dims)
+
+
+def test_tiled_bytes_reads_a_layout():
+    # The parent's planes inside its scans: (25, 64) under a (32, 128) tile.
+    tiled, held = _tiled_bytes("s8[48,16,25,384,64]{4,2,3,1,0:T(8,128)(4,1)}")
+    assert (held, round(tiled / held, 2)) == (48 * 16 * 25 * 384 * 64, 2.56)
+    tiled, held = _tiled_bytes("s8[48,16,1,384,1664]{4,3,1,0,2:T(8,128)(4,1)}")
+    assert tiled == held
+
+
+def test_gpt2_xl_int8_planes_ride_the_scans_unpadded(one_chip):
+    """gpt2-xl's megastep at int8 K/V, 16 slots, width 384, for a
+    described v5e. Every whole `s8` plane in the module (the parameters,
+    what the two `while` loops carry, the results) is tiled at most 5%
+    over the bytes of the heads it holds; the parameters and the results
+    have the layout the loops carry, the feature axis minor-most and the
+    positions next (a plane whose feature axis is no multiple of 128 lanes
+    is put positions-minor at rest, and every dispatch then relays it on
+    its way in and out); no whole-plane copy is left anywhere; the scale
+    planes are nowhere heads-minor (25 of 128 lanes, and a relay of every
+    layer's slice for the scores: what a scatter of their columns brings);
+    and the temporaries are under 1.8 GB (3.23 GB with the planes `[L, B,
+    H, T, Dh]`, which the scans carried tiled over (25, 64): 2.56 times
+    their bytes; 0.67 now). A change that brings the padded tile or the
+    relays back fails here before it reaches the chip."""
+    compiled, state = _benchmark_megastep(one_chip, "gpt2-xl", True, 384)
+    text = compiled.as_text()
+    k = state.cache.k
+    assert k.shape == (48, 16, 1, 384, 1664) and k.dtype == jnp.int8
+    held = 48 * 16 * 25 * 384 * 64
+    planes = set(re.findall(r"s8\[48,16,[\d,]+\]\{[^}]*\}", text))
+    assert planes
+    for plane in planes:
+        tiled, _ = _tiled_bytes(plane)
+        assert tiled <= 1.05 * held, plane
+        order = re.search(r"\{([\d,]+):", plane).group(1).split(",")
+        dims = plane[3:plane.index("]")].split(",")
+        minor = [int(dims[int(i)]) for i in order if dims[int(i)] != "1"]
+        assert minor[:2] == [1664, 384], plane
+    copies = [line.strip() for line in text.splitlines()
+              if re.search(r"= s8\[48,16,[\d,]+\]\S* copy(-start)?\(", line)]
+    assert copies == []
+    scales = set(re.findall(r"f32\[48,16,25,384\]\{([\d,]+):", text))
+    assert scales and all(order.startswith("3,") for order in scales), scales
+    assert compiled.memory_analysis().temp_size_in_bytes < 1.8e9
