@@ -921,6 +921,10 @@ class PagedQueue:
             if n_prompt:
                 self.metrics.set_gauge("prefix_cache_hit_rate",
                                        n_hit / n_prompt)
+        snap_bytes = getattr(self.engine, "state_snapshot_bytes", None)
+        if snap_bytes is not None:
+            self.metrics.set_gauge("engine_state_snapshot_bytes",
+                                   float(snap_bytes))
         sess = getattr(self.engine, "session_pin_stats", lambda: None)()
         if sess is not None:
             # Session residency: blocks held by live transcript pins

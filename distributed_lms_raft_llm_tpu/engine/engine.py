@@ -152,6 +152,15 @@ class TutoringEngine:
                 f"no heads axis (models/mla.py); there is nothing for tp "
                 f"to shard"
             )
+        if self.family.recurrent_state and (
+                config.spec_tokens > 0 or config.tp > 1):
+            raise ValueError(
+                f"{config.model!r} carries a recurrent state in its cache "
+                f"(models/mamba2.py): spec_tokens={config.spec_tokens} "
+                f"needs a verify window that rolls the state back past a "
+                f"rejected draft, and tp={config.tp} the state's heads "
+                f"sharded beside the mixer's projections; neither is built"
+            )
         if (
             config.spec_tokens > 0
             and self.family.name == "gpt2_moe"

@@ -76,6 +76,7 @@ from __future__ import annotations
 
 import dataclasses
 import logging
+import math
 import time
 from typing import Dict, List, NamedTuple, Optional, Sequence, Tuple
 
@@ -99,6 +100,7 @@ from .prefix_cache import (
     KVBlock,
     Match,
     PrefixCache,
+    StateSnapshot,
     plan_staged,
 )
 from .program_inventory import (
@@ -119,9 +121,28 @@ from .sampling import (
 
 log = logging.getLogger(__name__)
 
+# A recurrent family's state snapshots in the prefix tree
+# (`PagedEngine._snapshot_point`, engine/prefix_cache.py): the tree holds one
+# for every this many blocks of its budget, and a context's FIRST prompt,
+# which matched nothing, snapshots on a stride of this many steps of
+# lcm(prefill chunk, block) (256 tokens at the shipped 32 and 16).
+STATE_BLOCKS_A_SNAPSHOT = 16
+STATE_STRIDE_STEPS = 8
+
 
 class SlotState(NamedTuple):
-    """Device-side state of all S slots."""
+    """Device-side state of all S slots.
+
+    A family with a recurrent state (`ModelFamily.recurrent_state`) keeps
+    it in the cache beside its keys and values, `cache.ssm` [Lm, S, H, P,
+    N] float32 and `cache.conv` [Lm, S, K-1, C]: planes WITHOUT a
+    positions axis, which a forward pass over a lane moves. They advance
+    once for every real token and for nothing else: the decode forward is
+    told its `_live_lanes` (an idle lane, a staged lane before its flip
+    and a lane past its request's cap stand still), a prefill chunk its
+    real positions, and `_stage_program` resets a slot's state for its
+    next tenant (to zeros; `_restore_state_program` then puts a snapshot
+    there on a prefix hit)."""
 
     cache: KVCache     # k/v [L, S, H, Tmax, Dh] (a family that folds its
     #                    heads: [L, S, tp, Tmax, F]); length [S] per-slot
@@ -153,6 +174,16 @@ class SlotState(NamedTuple):
     stage_len: jax.Array     # [S] int32
     stage_seq: jax.Array     # [S] int32
     stage_rng: jax.Array     # [S, *key_data] uint32
+    # A recurrent family's snapshot planes (None for every other): where
+    # a staged slot's prefill reaches position `snap_at` (0: nowhere) at a
+    # chunk's end, that chunk copies the slot's state into its row of
+    # `snap_ssm` / `snap_conv` (shaped as the cache's `ssm` / `conv`), and
+    # the host exports the row into the prefix tree when it reaps the flip
+    # (`_export_state_program`): the state a later request with the same
+    # first `snap_at` tokens starts from.
+    snap_ssm: Optional[jax.Array] = None
+    snap_conv: Optional[jax.Array] = None
+    snap_at: Optional[jax.Array] = None      # [S] int32
 
 
 @dataclasses.dataclass
@@ -179,6 +210,9 @@ class _Request:
     # Scan iterations this request held a lane while staged, in the
     # dispatches reaped before its flip's (`engine_staged_iterations`).
     staged_rows: int = 0
+    # A recurrent family: the prompt position its prefill snapshots its
+    # state at (`SlotState.snap_at`; 0: none).
+    snap_at: int = 0
 
 
 def _plane_spec(name: str) -> jax.sharding.PartitionSpec:
@@ -263,7 +297,7 @@ def _export_block_program(c1: KVCache, off, slot, *, block: int) -> KVBlock:
 
 
 def _stage_program(state: SlotState, slot, ids, true_len, cursor0, seq,
-                   rng_raw) -> SlotState:
+                   rng_raw, snap_at=None) -> SlotState:
     """Arm one slot's staged admission (in-scan chunked prefill): write the
     right-padded prompt into the slot's transcript row and set the
     staged-admission plane — prefill then advances inside the megastep
@@ -276,16 +310,30 @@ def _stage_program(state: SlotState, slot, ids, true_len, cursor0, seq,
     phase still computes a forward for every slot, and an inactive row
     scatters its (garbage) KV at its length position — parked above the
     prompt region, the staged pages can never be corrupted by it (the
-    same clamp position a dead slot writes to). Donates the state."""
+    same clamp position a dead slot writes to). Donates the state.
+
+    A recurrent state has no such region: the slot's `ssm` and `conv` rows
+    are zeroed here, whatever its previous tenant left (its overrun rows
+    may have moved it), and `snap_at` says where the prefill is to
+    snapshot the state (`SlotState`). A prefix hit's snapshot is restored
+    AFTER this program (`_restore_state_program`)."""
     zero = jnp.zeros((), jnp.int32)
     slot = jnp.asarray(slot, jnp.int32)
     width = state.transcript.shape[1]
     transcript = jax.lax.dynamic_update_slice(
         state.transcript, ids, (slot, zero)
     )
+    cache = state.cache
+    # lint: disable-next=tracer-hygiene
+    if cache.ssm is not None:
+        cache = cache._replace(
+            ssm=cache.ssm.at[:, slot].set(0.0),
+            conv=cache.conv.at[:, slot].set(0.0))
+        state = state._replace(snap_at=state.snap_at.at[slot].set(
+            jnp.asarray(snap_at, jnp.int32)))
     return state._replace(
-        cache=state.cache._replace(
-            length=state.cache.length.at[slot].set(width - 1)
+        cache=cache._replace(
+            length=cache.length.at[slot].set(width - 1)
         ),
         active=state.active.at[slot].set(False),
         transcript=transcript,
@@ -344,6 +392,37 @@ def _stage_block_program(state: SlotState, block, slot, off,
     ))
 
 
+def _restore_state_program(state: SlotState, snap: StateSnapshot,
+                           slot) -> SlotState:
+    """Put a prefix hit's state snapshot into a slot's rows of the state
+    planes: the state a prefill from position 0 would have left at the
+    snapshot's boundary, where the staged cursor starts. Donates the
+    state, NEVER the snapshot (shared structure of the prefix tree, as a
+    `KVBlock` is)."""
+    slot = jnp.asarray(slot, jnp.int32)
+    c = state.cache
+
+    def put(plane, new):
+        at = (jnp.zeros((), jnp.int32), slot) + (
+            jnp.zeros((), jnp.int32),) * (plane.ndim - 2)
+        return jax.lax.dynamic_update_slice(plane, new, at)
+
+    return state._replace(cache=c._replace(
+        ssm=put(c.ssm, snap.ssm), conv=put(c.conv, snap.conv)))
+
+
+def _export_state_program(state: SlotState, slot) -> StateSnapshot:
+    """A slot's row of the snapshot planes as a fresh immutable copy the
+    prefix tree owns (`SlotState.snap_at` says which position's state it
+    is). The source stays the engine's: not donated."""
+    slot = jnp.asarray(slot, jnp.int32)
+
+    def cut(plane):
+        return jax.lax.dynamic_slice_in_dim(plane, slot, 1, axis=1)
+
+    return StateSnapshot(ssm=cut(state.snap_ssm), conv=cut(state.snap_conv))
+
+
 def cfg_tmax(cfg, sampling: SamplingParams, bucket: int) -> int:
     return min(bucket + sampling.max_new_tokens, cfg.max_position_embeddings)
 
@@ -363,6 +442,11 @@ def _fresh_state(family, cfg, slots: int, width: int,
     # Staged-rng plane shape follows the live PRNG impl's key data
     # (threefry: [2] uint32) so wrap_key_data round-trips exactly.
     key_shape = jax.random.key_data(jax.random.key(0)).shape
+    snap = {}
+    if cache.ssm is not None:
+        snap = dict(snap_ssm=jnp.zeros_like(cache.ssm),
+                    snap_conv=jnp.zeros_like(cache.conv),
+                    snap_at=jnp.zeros((slots,), jnp.int32))
     return SlotState(
         cache=cache,
         tok=jnp.zeros((slots,), jnp.int32),
@@ -374,6 +458,7 @@ def _fresh_state(family, cfg, slots: int, width: int,
         stage_len=jnp.ones((slots,), jnp.int32),
         stage_seq=jnp.zeros((slots,), jnp.int32),
         stage_rng=jnp.zeros((slots,) + key_shape, jnp.uint32),
+        **snap,
     )
 
 
@@ -381,7 +466,8 @@ def _grow_state_program(state: SlotState, new_len: int) -> SlotState:
     """Zero-pad the cache's slot axis up to `new_len` (width-bucket growth:
     the live cache is only as wide as the widest ACTIVE request needs —
     see PagedEngine._grow_if_needed — and pads up when a longer prompt
-    arrives)."""
+    arrives). Planes without a positions axis (a recurrent family's state
+    and snapshot planes) have no width and pass through as they are."""
     grow = new_len - state.cache.k.shape[3]
     pad = [(0, 0), (0, 0), (0, 0), (0, grow), (0, 0)]
 
@@ -663,6 +749,22 @@ def _admission_chunk(params, s: SlotState, *, cfg, sampling, model,
             cache=s.cache._replace(length=cur[None]), rows=slot[None],
             positions=positions,
         )
+        snap = {}
+        # lint: disable-next=tracer-hygiene
+        if s.snap_ssm is not None:
+            # The chunk that ends at the slot's snapshot position (all of
+            # it real: the position is below the prompt's end) copies the
+            # state it leaves into the slot's snapshot rows.
+            hit = cur + c == s.snap_at[slot]
+
+            def keep(plane, new):
+                row = jax.lax.dynamic_slice_in_dim(new, slot, 1, axis=1)
+                old = jax.lax.dynamic_slice_in_dim(plane, slot, 1, axis=1)
+                return jax.lax.dynamic_update_slice_in_dim(
+                    plane, jnp.where(hit, row, old), slot, axis=1)
+
+            snap = dict(snap_ssm=keep(s.snap_ssm, cache.ssm),
+                        snap_conv=keep(s.snap_conv, cache.conv))
         done = cur + c >= tl
         li = jnp.clip(tl - 1 - cur, 0, c - 1)
         last = jax.lax.dynamic_index_in_dim(logits[0], li, 0,
@@ -691,6 +793,7 @@ def _admission_chunk(params, s: SlotState, *, cfg, sampling, model,
             ),
             staged=s.staged.at[slot].set(~done),
             stage_cursor=s.stage_cursor.at[slot].set(cur + c),
+            **snap,
         )
         return (
             new,
@@ -955,6 +1058,14 @@ class PagedEngine:
                 f"replicated over the chips that share a layer, each "
                 f"serving its own lanes"
             )
+        if self.family.recurrent_state and (self.spec or config.tp > 1):
+            raise ValueError(
+                f"{config.model!r} carries a recurrent state in its cache "
+                f"(models/mamba2.py): spec_tokens={self.spec} needs a "
+                f"verify window that rolls the state back past a rejected "
+                f"draft, and tp={config.tp} the state's heads sharded "
+                f"beside the mixer's projections; neither is built"
+            )
         if config.sp > 1:
             raise ValueError(
                 "sp applies to TutoringEngine.score's ring-attention path; "
@@ -1026,6 +1137,15 @@ class PagedEngine:
             self.prefix_cache = PrefixCache(
                 block_tokens=self.prefix_block_tokens,
                 max_blocks=max(1, prefix_cache_blocks),
+                # A recurrent family's state snapshots: one is every
+                # state-space layer's state of one sequence (8.5 MB at
+                # Nemotron-3-Nano's widths, where a block of its one
+                # attention layer's keys and values is 16 KB), so the tree
+                # holds one for every STATE_BLOCKS_A_SNAPSHOT blocks of
+                # its budget.
+                max_snapshots=(
+                    max(1, prefix_cache_blocks // STATE_BLOCKS_A_SNAPSHOT)
+                    if self.family.recurrent_state else 0),
             )
         # In-scan chunked prefill: admissions are STAGED into SlotState
         # and prefill advances inside the megastep scan, one chunk of this
@@ -1102,6 +1222,13 @@ class PagedEngine:
         self._stage_block = jax.jit(
             named_partial(_stage_block_program), donate_argnums=(0,),
         )
+        # A recurrent family's snapshot programs (zero warmed programs
+        # for every other family and without the prefix cache): the
+        # restore donates the state, never the tree's snapshot.
+        self._restore_state = jax.jit(
+            named_partial(_restore_state_program), donate_argnums=(0,),
+        )
+        self._export_state = jax.jit(named_partial(_export_state_program))
         # No statics to bind; a fresh partial all the same (see above).
         self._grow = jax.jit(
             named_partial(_grow_state_program), static_argnums=(1,),
@@ -1302,8 +1429,18 @@ class PagedEngine:
         width. Grows with `_grow` and shrinks on idle rebuild."""
         c = self.state.cache
         return sum(
-            int(x.nbytes) for x in (c.k, c.v, c.ks, c.vs) if x is not None
+            int(x.nbytes) for x in (c.k, c.v, c.ks, c.vs, c.ssm, c.conv)
+            if x is not None
         )
+
+    @property
+    def state_snapshot_bytes(self) -> Optional[int]:
+        """Bytes of the state snapshots the prefix tree holds (the
+        `engine_state_snapshot_bytes` gauge); None for a family without a
+        recurrent state or without the prefix cache."""
+        if self.prefix_cache is None or not self.family.recurrent_state:
+            return None
+        return self.prefix_cache.snapshot_bytes
 
     @property
     def kv_bytes_per_chip(self) -> int:
@@ -1407,7 +1544,7 @@ class PagedEngine:
                     self.state = self._stage(
                         self.state, self._i32(0), ids, np.int32(1),
                         np.int32(0), np.int32(0),
-                        jax.random.key_data(rng),
+                        jax.random.key_data(rng), *self._snap_arg(0),
                     )
             # The first dispatch consumes the post-stage state — the
             # exact live stage->megastep handoff — and lax.cond compiles
@@ -1444,6 +1581,14 @@ class PagedEngine:
                         self.state = self._stage_block(
                             self.state, (blk,) * STAGE_RUN_BLOCKS,
                             zero, zero, zero)
+                    if self.family.recurrent_state:
+                        # From a canonical state, as `_publish_staged`
+                        # exports and `_stage_admissions` restores.
+                        self.state = self._canon_state(self.state)
+                        self.state = self._restore_state(
+                            self.state,
+                            self._canon_snapshot(self._export_state(
+                                self.state, zero)), zero)
         for i, wa in enumerate(self.widths):
             for wb in self.widths[i + 1:]:
                 throwaway = self._init_state(wa)
@@ -1684,11 +1829,24 @@ class PagedEngine:
             req, bucket, w_req, ids = self._pop_next()
             self._rng, rng = jax.random.split(self._rng)
             cursor0 = 0
+            snapshot = None
             if pc is not None:
                 match = pc.lookup(req.tokens)
                 cursor0 = plan_staged(
                     match.tokens, req.prompt_len, pc.block_tokens
                 )
+                if self.family.recurrent_state:
+                    # A hit is only as long as the deepest state snapshot
+                    # on the matched path; the rest of what the tree
+                    # matched is prefilled again, and the prefill leaves a
+                    # snapshot where the next such prompt can start.
+                    matched = cursor0
+                    cursor0, snapshot = pc.deepest_snapshot(match, matched)
+                    req.snap_at = self._snapshot_point(
+                        cursor0, matched, req.prompt_len)
+                    self._count(
+                        prefix_tokens_recomputed_for_state=matched - cursor0,
+                        state_snapshots_restored=int(snapshot is not None))
                 if cursor0:
                     pc.acquire(match)
                     self._prefix_pins[req.rid] = match
@@ -1723,7 +1881,12 @@ class PagedEngine:
                         np.int32(req.prompt_len), np.int32(cursor0),
                         np.int32(self._stage_seq),
                         jax.random.key_data(rng),
+                        *self._snap_arg(req.snap_at),
                     )
+                if snapshot is not None:
+                    with self._span(PROG + "restore_state"):
+                        self.state = self._restore_state(
+                            self.state, snapshot, self._i32(slot))
             self._stage_seq += 1
             req.live = False
             self._slot_req[slot] = req
@@ -1741,6 +1904,39 @@ class PagedEngine:
         if x is None:
             x = self._scalars[n] = jnp.asarray(n, jnp.int32)
         return x
+
+    def _snap_arg(self, snap_at: int) -> tuple:
+        """`_stage`'s last operand for a family with a recurrent state
+        (the position the prefill snapshots at), nothing for the others:
+        their stage program is the one it was."""
+        return (np.int32(snap_at),) if self.family.recurrent_state else ()
+
+    def _snapshot_point(self, cursor0: int, matched: int,
+                        prompt_len: int) -> int:
+        """Where a staged prefill that starts at `cursor0` is to snapshot
+        its state for later prompts (0: nowhere). A snapshot can stand
+        only where a prefill chunk ends on a block boundary: `cursor0`
+        plus whole steps of lcm(prefill chunk, block). If the tree matched
+        keys and values past the state it could restore (`matched`), that
+        is the BRANCH POINT of this prompt and the earlier ones: the last
+        such boundary at or below it, so the next prompt of the course is
+        a full hit. Else (nothing matched: a context's FIRST prompt, and
+        where it will branch from the next is not known yet) the last
+        multiple of STATE_STRIDE_STEPS steps below the prompt's end: the
+        second prompt of a context of n tokens starts from a snapshot no
+        more than a stride below n, whatever the first one's question
+        was (shorter than a stride), instead of prefilling all n again,
+        and leaves its own at the branch point. A chunk costs about what
+        a decode row costs, so without it the notes cell's three
+        second prompts cost 3.6% of a window (PERF.md section 6, PR 40,
+        call F)."""
+        step = math.lcm(self.prefill_chunk, self.prefix_block_tokens)
+        branch = cursor0 + (matched - cursor0) // step * step
+        if branch > cursor0:
+            return branch
+        stride = STATE_STRIDE_STEPS * step
+        point = (prompt_len - 1) // stride * stride
+        return point if point > cursor0 else 0
 
     def _required_width(self, prompt_len: int) -> int:
         bucket = min(
@@ -1800,6 +1996,15 @@ class PagedEngine:
                 tokens[: (req.prompt_len // blk_t) * blk_t],
                 self.state.cache, slot,
             )
+            if req.snap_at and not pc.has_snapshot(tokens, req.snap_at):
+                # The slot's snapshot rows hold the state its prefill left
+                # at `snap_at`, and nobody writes them before the slot is
+                # staged again, which waits for this reap.
+                with self._span(PROG + "export_state"):
+                    snap = self._canon_snapshot(self._export_state(
+                        self.state, self._i32(slot)))
+                if pc.attach_snapshot(tokens, req.snap_at, snap):
+                    self._count(state_snapshots_taken=1)
         self._prefix_evictions += pc.evict_to_budget()
 
     def _publish_session(self, req: _Request, slot: int) -> None:
@@ -1961,7 +2166,17 @@ class PagedEngine:
                 vs=(None if state.cache.vs is None
                     else put(state.cache.vs, "cache.vs")),
                 length=put(state.cache.length, "cache.length"),
+                ssm=(None if state.cache.ssm is None
+                     else put(state.cache.ssm, "cache.ssm")),
+                conv=(None if state.cache.conv is None
+                      else put(state.cache.conv, "cache.conv")),
             ),
+            snap_ssm=(None if state.snap_ssm is None
+                      else put(state.snap_ssm, "snap_ssm")),
+            snap_conv=(None if state.snap_conv is None
+                       else put(state.snap_conv, "snap_conv")),
+            snap_at=(None if state.snap_at is None
+                     else put(state.snap_at, "snap_at")),
         )
 
     def _canon_block(self, blk: KVBlock) -> KVBlock:
@@ -1984,6 +2199,16 @@ class PagedEngine:
             ks=None if blk.ks is None else put(blk.ks, "ks"),
             vs=None if blk.vs is None else put(blk.vs, "vs"),
         )
+
+    def _canon_snapshot(self, snap: StateSnapshot) -> StateSnapshot:
+        """`_canon_block` for an exported state snapshot."""
+
+        def put(x, name):
+            sh = jax.sharding.NamedSharding(self.mesh, _plane_spec(name))
+            return x if x.sharding == sh else jax.device_put(x, sh)
+
+        return StateSnapshot(ssm=put(snap.ssm, "ssm"),
+                             conv=put(snap.conv, "conv"))
 
     def step(self) -> List[Tuple[int, str]]:
         """Stage pending requests, dispatch the next megastep — K chunks

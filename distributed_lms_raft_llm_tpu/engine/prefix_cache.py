@@ -41,6 +41,20 @@ Design facts, each load-bearing:
   still references is never freed — the budget may transiently overrun
   instead (pinned-overrun is observable via `blocks_used`).
 
+- **State snapshots.** A family whose cache carries a recurrent state
+  (`ModelFamily.recurrent_state`: `KVCache.ssm`, `.conv`) cannot reuse a
+  prefix by its keys and values alone: the state at the end of the prefix
+  is needed too, and a state has no positions to slice. So a node also
+  holds `StateSnapshot`s, each the state a prefill from position 0 leaves
+  after a whole number of its edge's blocks; a hit is then only as long
+  as the deepest snapshot on the matched path (`deepest_snapshot`), the
+  engine splices the blocks up to it and restores it, and prefills the
+  rest. Snapshots are few and large (one is every state-space layer's
+  state of one sequence), so they stand where the engine asks
+  (`engine/paged.py` `_snapshot_point`), count their bytes
+  (`snapshot_bytes`), are bounded in number (`max_snapshots`, the least
+  recently used dropped first) and leave with their nodes.
+
 Concurrency: host-side only, single-threaded by contract — the paged
 engine's host API is single-threaded and the serving queue drives it
 from one runner coroutine, so there is no lock here by design.
@@ -65,14 +79,32 @@ class KVBlock(NamedTuple):
     """One immutable device-resident KV block: `block_tokens` consecutive
     positions of a single sequence ([L, 1, H, B, Dh] per plane, each by
     its own H and Dh, `v` None for a family whose cache is one plane; int8
-    scale planes [L, 1, H, B] ride along for a quantized cache). Shared
-    structure: never donated, never written in place — the lint sweep
-    and the reversion pin in tests/test_lint_clean.py enforce it."""
+    scale planes [L, 1, H, B] ride along for a quantized cache). Only
+    planes WITH a positions axis can be cut into blocks: a recurrent
+    family's `ssm` and `conv` planes have none, so a block holds its
+    attention layers' keys and values alone and the state at a block
+    boundary is a `StateSnapshot` beside it. Shared structure: never
+    donated, never written in place — the lint sweep and the reversion
+    pin in tests/test_lint_clean.py enforce it."""
 
     k: jax.Array
     v: Optional[jax.Array]
     ks: Optional[jax.Array] = None
     vs: Optional[jax.Array] = None
+
+
+class StateSnapshot(NamedTuple):
+    """The recurrent state of ONE sequence after a prefix of whole blocks,
+    immutable and device-resident like a `KVBlock`: `ssm` [Lm, 1, H, P, N]
+    float32 and `conv` [Lm, 1, K-1, C] (models/mamba2.py), planes without
+    a positions axis. Never donated, never written in place."""
+
+    ssm: jax.Array
+    conv: jax.Array
+
+    @property
+    def nbytes(self) -> int:
+        return int(self.ssm.nbytes) + int(self.conv.nbytes)
 
 
 @dataclasses.dataclass
@@ -90,6 +122,9 @@ class _Node:
     )
     refs: int = 0
     last_used: int = 0
+    # blocks of this edge a snapshot stands AFTER (1 .. len(edge)) ->
+    # [the snapshot, the clock when a lookup last chose it].
+    snapshots: Dict[int, list] = dataclasses.field(default_factory=dict)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -138,11 +173,16 @@ class PrefixCache:
     """
 
     def __init__(self, block_tokens: int = BLOCK_TOKENS,
-                 max_blocks: int = 512):
+                 max_blocks: int = 512, max_snapshots: int = 0):
         if block_tokens < 1 or max_blocks < 1:
             raise ValueError("prefix cache needs block_tokens/max_blocks >= 1")
         self.block_tokens = block_tokens
         self.max_blocks = max_blocks
+        # State snapshots (module docstring): how many the tree may hold,
+        # how many it holds and their bytes.
+        self.max_snapshots = max_snapshots
+        self.snapshots = 0
+        self.snapshot_bytes = 0
         self._root = _Node(edge=[], blocks=[], parent=None)
         self._clock = 0
         self.blocks_used = 0
@@ -288,10 +328,14 @@ class PrefixCache:
         ancestors are protected by having children."""
         assert node.parent is not None and 0 < j < len(node.edge)
         top = _Node(edge=node.edge[:j], blocks=node.blocks[:j],
-                    parent=node.parent, last_used=node.last_used)
+                    parent=node.parent, last_used=node.last_used,
+                    snapshots={n: e for n, e in node.snapshots.items()
+                               if n <= j})
         node.parent.children[top.edge[0]] = top
         node.edge = node.edge[j:]
         node.blocks = node.blocks[j:]
+        node.snapshots = {n - j: e for n, e in node.snapshots.items()
+                          if n > j}
         top.children[node.edge[0]] = node
         node.parent = top
         return top
@@ -323,6 +367,67 @@ class PrefixCache:
         cur.children[node.edge[0]] = node
         self.blocks_used += len(fresh)
         return len(fresh)
+
+    # --------------------------------------------------- state snapshots
+
+    def deepest_snapshot(
+        self, match: Match, limit: int
+    ) -> Tuple[int, Optional[StateSnapshot]]:
+        """(tokens, snapshot) of the deepest state snapshot on `match`'s
+        path that stands at or before `limit` tokens; (0, None) where
+        there is none. Touches it for the snapshots' LRU."""
+        best, at, entry = 0, 0, None
+        for node, used in zip(match.nodes, match.used):
+            for n, e in node.snapshots.items():
+                tokens = (at + n) * self.block_tokens
+                if n <= used and best < tokens <= limit:
+                    best, entry = tokens, e
+            at += used
+        if entry is None:
+            return 0, None
+        entry[1] = self._clock
+        return best, entry[0]
+
+    def _snapshot_home(self, tokens: Sequence[int], position: int):
+        """(node, blocks into its edge) of the block boundary `position`
+        tokens into `tokens`, or None where the tree does not hold the
+        path that far."""
+        want = position // self.block_tokens
+        nodes, used, matched = self._walk(self._block_keys(tokens[:position]))
+        if matched < want or not nodes:
+            return None
+        return nodes[-1], used[-1]
+
+    def has_snapshot(self, tokens: Sequence[int], position: int) -> bool:
+        home = self._snapshot_home(tokens, position)
+        return home is not None and home[1] in home[0].snapshots
+
+    def attach_snapshot(self, tokens: Sequence[int], position: int,
+                        snap: StateSnapshot) -> bool:
+        """Hold `snap`, the state after the first `position` tokens of
+        `tokens` (a whole number of blocks the tree already holds), with
+        the node that holds the block before the boundary. Then drop the
+        least recently used snapshots down to `max_snapshots`. False where
+        the tree lacks the path, has one there already, or holds none."""
+        home = self._snapshot_home(tokens, position)
+        if home is None or home[1] in home[0].snapshots \
+                or self.max_snapshots < 1:
+            return False
+        self._clock += 1
+        home[0].snapshots[home[1]] = [snap, self._clock]
+        self.snapshots += 1
+        self.snapshot_bytes += snap.nbytes
+        while self.snapshots > self.max_snapshots:
+            node, n = min(
+                ((node, n) for node in self._iter_nodes()
+                 for n in node.snapshots),
+                key=lambda at: at[0].snapshots[at[1]][1])
+            self._forget(node.snapshots.pop(n)[0])
+        return True
+
+    def _forget(self, snap: StateSnapshot) -> None:
+        self.snapshots -= 1
+        self.snapshot_bytes -= snap.nbytes
 
     # ---------------------------------------------------------- eviction
 
@@ -377,6 +482,8 @@ class PrefixCache:
             assert victim.parent is not None
             del victim.parent.children[victim.edge[0]]
             self.blocks_used -= len(victim.blocks)
+            for snap, _ in victim.snapshots.values():
+                self._forget(snap)
             freed += len(victim.blocks)
         self.evicted_blocks += freed
         return freed
@@ -390,6 +497,7 @@ class PrefixCache:
         tree they pointed into."""
         self._root = _Node(edge=[], blocks=[], parent=None)
         self.blocks_used = 0
+        self.snapshots = self.snapshot_bytes = 0
         self._session_pins = {}
 
     @property

@@ -64,6 +64,11 @@ INVENTORY: Tuple[ProgramEntry, ...] = (
         domain="export-widths", coverage="warmup",
     ),
     ProgramEntry(
+        engine="PagedEngine", attr="_export_state", target="_export_state_program",
+        donate_argnums=(), static_argnums=(),
+        domain="state-widths", coverage="warmup",
+    ),
+    ProgramEntry(
         engine="PagedEngine", attr="_grow", target="_grow_state_program",
         donate_argnums=(0,), static_argnums=(1,),
         domain="width-pairs", coverage="warmup",
@@ -72,6 +77,11 @@ INVENTORY: Tuple[ProgramEntry, ...] = (
         engine="PagedEngine", attr="_megastep", target="_megastep_program",
         donate_argnums=(1,), static_argnums=(),
         domain="width-k-pairs", coverage="warmup",
+    ),
+    ProgramEntry(
+        engine="PagedEngine", attr="_restore_state", target="_restore_state_program",
+        donate_argnums=(0,), static_argnums=(),
+        domain="state-widths", coverage="warmup",
     ),
     ProgramEntry(
         engine="PagedEngine", attr="_score", target="_score_program",
@@ -202,6 +212,7 @@ def static_paged_domain(
     prefix_block_tokens: int = 16,
     tp: int = 1,
     ep: int = 1,
+    recurrent_state: bool = False,
 ) -> Dict[str, object]:
     """The paged engine's static-argument domain, derived from config the
     same way `PagedEngine.__init__` derives it: warmed prompt buckets,
@@ -267,6 +278,10 @@ def static_paged_domain(
         "k_ladder": ladder,
         "megastep_pairs": len(widths) * len(ladder),
         "export_widths": len(widths) if blocks else 0,
+        # A recurrent family's snapshot restore and export
+        # (`ModelFamily.recurrent_state`): one program a width each,
+        # beside the block programs.
+        "state_widths": len(widths) if blocks and recurrent_state else 0,
     }
 
 
@@ -318,6 +333,7 @@ _DOMAIN_SIZES = {
     "width-pairs": lambda d: d["grow_pairs"],
     "width-k-pairs": lambda d: d["megastep_pairs"],
     "export-widths": lambda d: d["export_widths"],
+    "state-widths": lambda d: d["state_widths"],
 }
 
 
@@ -361,6 +377,7 @@ def expected_counts(engine) -> Dict[str, int]:
             prefix_cache=getattr(engine, "prefix_cache", None) is not None,
             prefix_block_tokens=getattr(engine, "prefix_block_tokens", 16),
             tp=engine.config.tp, ep=engine.config.ep,
+            recurrent_state=engine.family.recurrent_state,
         )
         live_mesh = {
             "tp": int(engine.mesh.shape.get("tp", 1)),

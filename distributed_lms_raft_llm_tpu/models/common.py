@@ -87,6 +87,18 @@ class KVCache(NamedTuple):
             streams per layer — see `quantize_kv`/`attend_quant`); None for
             a full-precision cache. One scale a head and position, folded
             planes too.
+    ssm, conv: a recurrent family's per-row state (models/mamba2.py), None
+            for every other: `ssm` [Lm, B, H, P, N] float32, the Lm
+            state-space layers' states, and `conv` [Lm, B, K-1, C], the
+            last K-1 inputs of their causal convolutions. Planes WITHOUT a
+            positions axis: nothing of them grows with the context, and a
+            forward pass that runs over a row MOVES them, so they have no
+            dead region a lane may scribble on. The family advances a
+            row's state once a `live` token and leaves it bit-equal on
+            every other (pad positions, idle lanes); whoever hands a row to
+            a new sequence resets it (`engine/paged.py` `_stage_program`:
+            zeros, or a snapshot restored). `k`/`v` of such a family hold
+            its attention layers alone, [La, B, Hkv, T, Dh].
 
     A single scalar length serves the whole batch; per-sequence raggedness is
     handled above the model by the engine's bucketing/batching (engine.paged
@@ -98,6 +110,8 @@ class KVCache(NamedTuple):
     length: jax.Array
     ks: Optional[jax.Array] = None
     vs: Optional[jax.Array] = None
+    ssm: Optional[jax.Array] = None
+    conv: Optional[jax.Array] = None
 
     @property
     def quantized(self) -> bool:

@@ -239,8 +239,35 @@ def grouped_swiglu(x: jax.Array, top_i: jax.Array, top_w: jax.Array,
     part of the layer's result, to which the absent experts' chips would
     add theirs. Group sizes are the held experts'.
     """
+    def experts(xs, sizes):
+        gate = jax.lax.ragged_dot(xs, wg.astype(x.dtype), sizes)
+        up = jax.lax.ragged_dot(xs, wu.astype(x.dtype), sizes)
+        return jax.lax.ragged_dot(jax.nn.silu(gate) * up,
+                                  wd.astype(x.dtype), sizes)
+
+    return _grouped(x, top_i, top_w, live, wg.shape[0], first, experts)
+
+
+def grouped_relu2(x: jax.Array, top_i: jax.Array, top_w: jax.Array,
+                  live: jax.Array, wu: jax.Array, wd: jax.Array,
+                  first=None):
+    """`grouped_swiglu` for routed experts of TWO projections,
+    `W_down relu(W_up x)^2` (wu [E, D, M], wd [E, M, D]; models/
+    nemotron_h.py): the same sort, grouped products, `first` and group
+    sizes."""
+    def experts(xs, sizes):
+        up = jax.lax.ragged_dot(xs, wu.astype(x.dtype), sizes)
+        return jax.lax.ragged_dot(jnp.square(jax.nn.relu(up)),
+                                  wd.astype(x.dtype), sizes)
+
+    return _grouped(x, top_i, top_w, live, wu.shape[0], first, experts)
+
+
+def _grouped(x, top_i, top_w, live, e: int, first, experts):
+    """The routed layer around its experts' products (`grouped_swiglu`'s
+    contract): `experts(xs, sizes)` takes the picks' rows sorted by expert
+    [S*k, D] and the `e` held experts' group sizes."""
     s, k = top_i.shape
-    e = wg.shape[0]
     here = live[:, None]
     if first is not None:
         top_i = top_i - first
@@ -248,11 +275,7 @@ def grouped_swiglu(x: jax.Array, top_i: jax.Array, top_w: jax.Array,
     expert = jnp.where(here, top_i, e).reshape(s * k)
     order = jnp.argsort(expert, stable=True)
     sizes = jnp.zeros((e + 1,), jnp.int32).at[expert].add(1)[:e]
-    xs = x[order // k]                                       # [S*k, D]
-    gate = jax.lax.ragged_dot(xs, wg.astype(x.dtype), sizes)
-    up = jax.lax.ragged_dot(xs, wu.astype(x.dtype), sizes)
-    out = jax.lax.ragged_dot(jax.nn.silu(gate) * up, wd.astype(x.dtype),
-                             sizes)
+    out = experts(x[order // k], sizes)                      # [S*k, D]
     # Rows past the last group belong to no expert; whatever the kernel
     # left there is dropped, not scaled by a zero weight.
     w = top_w.reshape(s * k)[order]

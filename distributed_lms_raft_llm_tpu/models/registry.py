@@ -25,15 +25,18 @@ takes either form; every other family ignores it.
 The reference hardcodes one architecture behind `from_pretrained("gpt2")`
 (reference: GUI_RAFT_LLM_SourceCode/tutoring_server.py:10); here presets
 cover the GPT-2 family (BASELINE configs 1-4), Llama (config 5), the
-home-made GPT-2 with routed experts, Arcee's afmoe (Trinity-Mini) and SK
-Telecom's axk1 (A.X-K1: latent attention, a share of a layer's experts).
+home-made GPT-2 with routed experts, Arcee's afmoe (Trinity-Mini), SK
+Telecom's axk1 (A.X-K1: latent attention, a share of a layer's experts) and
+NVIDIA's nemotron_h (Nemotron-3-Nano: Mamba-2 blocks whose recurrent state
+lives in the cache beside one attention block's keys and values, relu^2
+experts).
 """
 
 from __future__ import annotations
 
 from typing import Any, Callable, NamedTuple, Tuple
 
-from . import afmoe, axk1, convert, gpt2, llama, moe
+from . import afmoe, axk1, convert, gpt2, llama, moe, nemotron_h
 
 
 class ModelFamily(NamedTuple):
@@ -55,6 +58,13 @@ class ModelFamily(NamedTuple):
     # The cache is a latent (models/mla.py): one head, nothing for `tp`
     # to shard, and the engines refuse tp > 1.
     latent_cache: bool = False
+    # The cache carries a recurrent state (`KVCache.ssm`, `.conv`:
+    # models/mamba2.py) that a forward pass MOVES. The engines refuse
+    # spec_tokens > 0 (a verify window cannot roll the state back past a
+    # rejected draft) and tp > 1 (the state's heads are not sharded) for
+    # such a family, and the paged engine resets a slot's state at staging
+    # and reuses prefixes by state snapshots (engine/prefix_cache.py).
+    recurrent_state: bool = False
 
 
 GPT2_FAMILY = ModelFamily(
@@ -79,6 +89,11 @@ AXK1_FAMILY = ModelFamily(
     axk1.params_from_hf, routed=True, counters=axk1.COUNTERS,
     latent_cache=True,
 )
+NEMOTRON_H_FAMILY = ModelFamily(
+    "nemotron_h", nemotron_h.init_params, nemotron_h.forward,
+    nemotron_h.init_cache, nemotron_h.params_from_hf, routed=True,
+    counters=nemotron_h.COUNTERS, recurrent_state=True,
+)
 
 # preset -> (family, config factory)
 PRESETS = {
@@ -97,6 +112,11 @@ PRESETS = {
     "ax-k1": (AXK1_FAMILY, axk1.AxK1Config.ax_k1),
     "ax-k1-1d4e-12of192": (AXK1_FAMILY, axk1.AxK1Config.ax_k1_1d4e_share),
     "axk1-tiny": (AXK1_FAMILY, axk1.AxK1Config.tiny),
+    "nemotron3-nano": (NEMOTRON_H_FAMILY,
+                       nemotron_h.NemotronHConfig.nemotron3_nano),
+    "nemotron3-nano-9l-64of128": (
+        NEMOTRON_H_FAMILY, nemotron_h.NemotronHConfig.nemotron3_nano_9l_share),
+    "nemotronh-tiny": (NEMOTRON_H_FAMILY, nemotron_h.NemotronHConfig.tiny),
 }
 
 

@@ -138,6 +138,15 @@ AXK1_RULES: List[Tuple[str, PartitionSpec]] = [
     (r".*", P()),
 ]
 
+# nemotron_h (models/nemotron_h.py): one mixer a block. Everything is
+# replicated: the engines refuse tp > 1 (the Mamba blocks' state is
+# [Lm, S, H, P, N] and sharding its heads, with the mixer's projections
+# column- and row-parallel beside them, is not built) and ep > 1 (the share
+# of a layer's experts is the configuration's, as axk1's).
+NEMOTRON_H_RULES: List[Tuple[str, PartitionSpec]] = [
+    (r".*", P()),
+]
+
 # Rule set per model-family name (models/registry.py ModelFamily.name).
 # (The bucketed engine's KV-cache sharding — [L, B, Hkv, T, Dh]: batch
 # over dp, heads over tp — is derived by jit's sharding propagation from
@@ -152,6 +161,7 @@ RULES_FOR = {
     "gpt2_moe": MOE_RULES,
     "afmoe": AFMOE_RULES,
     "axk1": AXK1_RULES,
+    "nemotron_h": NEMOTRON_H_RULES,
 }
 
 # ---------------------------------------------- paged state plane table
@@ -190,6 +200,18 @@ PAGED_PLANE_SPECS: Dict[str, PartitionSpec] = {
     "cache.ks": P(None, None, "tp"),
     "cache.vs": P(None, None, "tp"),
     "cache.length": P(),
+    # A recurrent family's state planes (models/mamba2.py: ssm
+    # [Lm, S, H, P, N], conv [Lm, S, K-1, C]; no positions axis) and the
+    # per-slot snapshot planes the prefill fills (engine/paged.SlotState).
+    # `ssm` has its heads on axis 2 as the KV planes have theirs and takes
+    # their spelling (it is the one the programs' outputs propagate, and
+    # the axis `tp` would shard; the engines refuse tp > 1 for such a
+    # family today, so it is replication). `conv` is replicated.
+    "cache.ssm": P(None, None, "tp"),
+    "cache.conv": P(),
+    "snap_ssm": P(None, None, "tp"),
+    "snap_conv": P(),
+    "snap_at": P(),
     # Bare KVCache / prefix KVBlock planes (single-slot prefill caches
     # and the radix tree's block runs share the heads-at-axis-2 layout).
     "k": P(None, None, "tp"),
@@ -197,6 +219,8 @@ PAGED_PLANE_SPECS: Dict[str, PartitionSpec] = {
     "ks": P(None, None, "tp"),
     "vs": P(None, None, "tp"),
     "length": P(),
+    "ssm": P(None, None, "tp"),
+    "conv": P(),
     # Host-state planes: replicated, canonical spelling.
     "tok": P(),
     "active": P(),

@@ -507,12 +507,26 @@ ENGINE_PROG_GENERATE = histogram(
 # Engine-reported program name -> declared histogram, used by the serving
 # queues (engine/batcher.py). Living HERE keeps the mapping inside the
 # declared namespace (see BREAKER_TRANSITION_COUNTERS).
+ENGINE_PROG_RESTORE_STATE = histogram(
+    "engine_prog_restore_state",
+    "paged-engine _restore_state program dispatch wall time (admission "
+    "of a recurrent family: a prefix hit's state snapshot put into the "
+    "slot's rows of the state planes)",
+)
+ENGINE_PROG_EXPORT_STATE = histogram(
+    "engine_prog_export_state",
+    "paged-engine _export_state program dispatch wall time (a recurrent "
+    "family: the state a prefill snapshotted, copied out of the slot's "
+    "snapshot rows for the prefix tree)",
+)
 ENGINE_PROGRAM_HISTOGRAMS: Dict[str, str] = {
     "megastep": ENGINE_PROG_MEGASTEP,
     "grow": ENGINE_PROG_GROW,
     "stage": ENGINE_PROG_STAGE,
     "stage_block": ENGINE_PROG_STAGE_BLOCK,
     "export_block": ENGINE_PROG_EXPORT_BLOCK,
+    "restore_state": ENGINE_PROG_RESTORE_STATE,
+    "export_state": ENGINE_PROG_EXPORT_STATE,
     "score": ENGINE_PROG_SCORE,
     "generate": ENGINE_PROG_GENERATE,
 }
@@ -606,6 +620,31 @@ ENGINE_TOKENS_PAST_WINDOW = counter(
     "where every window layer drops keys; counted on the host at the "
     "reap, of the same tokens as engine_tokens_emitted",
 )
+ENGINE_STATE_SNAPSHOTS_TAKEN = counter(
+    "engine_state_snapshots_taken",
+    "state snapshots of a recurrent family (models/mamba2.py) that "
+    "entered the prefix tree: the state a prefill left at a block "
+    "boundary, exported when its flip was reaped",
+)
+ENGINE_STATE_SNAPSHOTS_RESTORED = counter(
+    "engine_state_snapshots_restored",
+    "admissions of a recurrent family that started from a state "
+    "snapshot of the prefix tree instead of from zeros",
+)
+ENGINE_PREFIX_TOKENS_RECOMPUTED_FOR_STATE = counter(
+    "engine_prefix_tokens_recomputed_for_state",
+    "prompt tokens whose keys and values the prefix tree matched but "
+    "which were prefilled again because no state snapshot stood that "
+    "deep (a recurrent family's hit is only as long as its deepest "
+    "snapshot); over engine_prompt_tokens_admitted it is the share of "
+    "the prompts the snapshots' placement costs",
+)
+ENGINE_STATE_SNAPSHOT_BYTES = gauge(
+    "engine_state_snapshot_bytes",
+    "bytes of the state snapshots the prefix tree holds for a recurrent "
+    "family (bounded in number, dropped least recently used first and "
+    "with their nodes)",
+)
 QUEUE_WAIT = histogram(
     "queue_wait",
     "engine submit -> popped from the pending queue for admission, per "
@@ -661,6 +700,10 @@ ENGINE_LOOP_COUNTERS: Dict[str, str] = {
     "moe_expert_seats": MOE_EXPERT_SEATS,
     "moe_picks_held": MOE_PICKS_HELD,
     "tokens_past_window": ENGINE_TOKENS_PAST_WINDOW,
+    "state_snapshots_taken": ENGINE_STATE_SNAPSHOTS_TAKEN,
+    "state_snapshots_restored": ENGINE_STATE_SNAPSHOTS_RESTORED,
+    "prefix_tokens_recomputed_for_state":
+        ENGINE_PREFIX_TOKENS_RECOMPUTED_FOR_STATE,
 }
 ENGINE_LOOP_HISTOGRAMS: Dict[str, str] = {
     "queue_wait": QUEUE_WAIT,

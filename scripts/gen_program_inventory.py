@@ -62,6 +62,12 @@ CLASSIFICATION = {
     # both modes (see static_paged_domain).
     ("PagedEngine", "_stage_block"): ("stage-widths", "warmup"),
     ("PagedEngine", "_export_block"): ("export-widths", "warmup"),
+    # A recurrent family's state snapshots (engine/paged.py
+    # `_restore_state_program`, `_export_state_program`): one program a
+    # width, and none for a family without a recurrent state or with the
+    # prefix cache off.
+    ("PagedEngine", "_restore_state"): ("state-widths", "warmup"),
+    ("PagedEngine", "_export_state"): ("state-widths", "warmup"),
     # Bulk-scoring tenant (engine/scoring.py): the score program's
     # (batch bucket x length bucket) domain is empty when
     # EngineConfig.scoring is off, so the warmup claim holds in both

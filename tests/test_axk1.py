@@ -521,7 +521,9 @@ def test_the_benchmark_names_the_configuration_the_cell_and_its_metrics():
                         ("mla_decode_dev_us_per_tok", "latent attention"),
                         ("mla_decode_roofline", "latent attention")):
         metric_ = _named(bench["per_layer"], name)
-        assert metric_["workloads"] == [cell]
+        # First of its cells: a later family that holds a share of its
+        # experts appends its own.
+        assert metric_["workloads"][0] == cell
         assert metric_["layer"].startswith(layer)
         assert os.path.exists(os.path.join(
             REPO, "benchmarks", "layer_metrics", name + ".json"))

@@ -247,7 +247,9 @@ def test_paged_megastep_tp4_shards_planes_over_four_chips(topo, one_chip,
         cache=state.cache._replace(**{
             f: plane(f"cache.{f}") for f in ("k", "v", "ks", "vs", "length")
         }),
-        **{f: plane(f) for f in state._fields if f != "cache"},
+        # (a recurrent family's snapshot planes are None for this one)
+        **{f: plane(f) for f in state._fields
+           if f != "cache" and getattr(state, f) is not None},
     )
     replicated = NamedSharding(mesh, jax.sharding.PartitionSpec())
     args = (_with(prod.params, p_sh), _with(state, s_sh),
@@ -360,6 +362,51 @@ def test_ax_k1_cut_megastep_runs_absorbed_over_the_latent_cache(one_chip):
     for plane in ("bf16[5,32,1,2688,576]", "bf16[5,32,2688,576]"):
         assert plane in text
         assert _copies_inside_loops(text, plane) == []
+
+
+def test_nemotron3_nano_cut_megastep_updates_the_state_in_place(one_chip):
+    """`nemotron3-nano-9l-64of128` at the published widths, from shapes
+    alone, in the serving settings of benchmarks/configs/nemotron3-nano.json
+    (16 slots, width 2,688, chunk 8, prefill chunks of 32, K = 2): 6.5 GB
+    of bfloat16 weights beside the float32 state planes; the decode step's
+    state update is the kernel `ssm_step`, no copy of a whole `ssm` plane
+    (the cache's or the snapshot rows') lies inside the scans, and the held
+    experts' stacks enter the TPU's grouped kernel as whole buffers: padded
+    to whole tiles of 512 (`nemotron_h.pad_experts`), they rest as the
+    kernel takes them, and no copy of a stack is made anywhere (unpadded,
+    `wu` [64, 2688, 1856] rests D-minor and every dispatch copied all
+    four, 638 MB each)."""
+    family, cfg = registry.resolve("nemotron3-nano-9l-64of128", jnp.bfloat16,
+                                   jnp.bfloat16)
+    params = _with(jax.eval_shape(
+        lambda: family.init_params(jax.random.key(0), cfg)), one_chip)
+    # The published 3,166,244,352 and the experts' padding to [3072, 2048].
+    assert sum(x.size for x in jax.tree.leaves(params)) == (
+        3_166_244_352 + 4 * 64 * 2 * (3072 * 2048 - 2688 * 1856))
+    state = jax.eval_shape(partial(paged._fresh_state, family, cfg, 16, 2688))
+    assert state.cache.k.shape == (1, 16, 2, 2688, 128)
+    assert state.cache.ssm.shape == state.snap_ssm.shape == (
+        4, 16, 64, 64, 128)
+    assert state.cache.conv.shape == (4, 16, 3, 6144)
+    mega = jax.jit(
+        partial(paged._megastep_program, chunk=8, spec_tokens=0,
+                prefill_chunk=32, draft_fn=build_drafts, eos_id=50256,
+                pad_id=50256, cfg=cfg, model=family,
+                sampling=SamplingParams.reference_defaults()),
+        donate_argnums=(1,),
+    ).lower(params, _with(state, one_chip), _with(jax.eval_shape(
+        lambda: jax.random.split(jax.random.key(0), 2)), one_chip)).compile()
+    ma = mega.memory_analysis()
+    assert _device_bytes(ma) < 0.75 * HBM_BYTES
+    assert ma.temp_size_in_bytes < 1024**3
+    text = mega.as_text()
+    assert "ragged-dot" in text and "ssm_step" in text
+    plane = "f32[4,16,64,64,128]"
+    assert plane in text
+    assert _copies_inside_loops(text, plane) == []
+    for stack in ("bf16[64,3072,2048]", "bf16[64,2048,3072]"):
+        assert stack in text
+        assert not re.search(re.escape(stack) + r"\S* copy\(", text)
 
 
 # ------------------- a prefill chunk touches its slot's pages in place

@@ -367,7 +367,7 @@ def test_warmed_fused_session_passes_inventory_guard():
     assert expectation.expected["_stage"] == 3  # (4,12) (4,24) (16,24)
     assert set(expectation.expected) == {
         "_megastep", "_stage", "_stage_block", "_export_block", "_grow",
-        "_score"}
+        "_score", "_restore_state", "_export_state"}
     assert expectation.mismatches() == {}
     with compile_count_guard(expectation) as guard:
         eng.submit("k v")
@@ -431,6 +431,9 @@ def test_warmup_compiles_exactly_the_staged_domain(prefix_cache, spec_tokens):
         "_export_block": blocks,
         "_stage_block": blocks,  # no cache here is a run of blocks wide
         "_score": 0,
+        # Snapshot programs of a family with a recurrent state alone.
+        "_restore_state": 0,
+        "_export_state": 0,
     }
     assert {a: getattr(eng, a)._cache_size() for a in want} == want
     expectation = expected_from_inventory(eng)

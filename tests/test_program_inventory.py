@@ -69,7 +69,8 @@ def test_manifest_and_readme_match_static_scan():
 def test_manifest_covers_the_paged_program_set():
     attrs = {e.attr for e in inv.entries_for("PagedEngine")}
     assert attrs == {"_megastep", "_grow", "_export_block",
-                     "_stage", "_stage_block", "_score"}
+                     "_stage", "_stage_block", "_score",
+                     "_restore_state", "_export_state"}
     assert all(
         e.coverage == "warmup" for e in inv.entries_for("PagedEngine")
     ), "the paged engine's whole program set is a warmup promise"
