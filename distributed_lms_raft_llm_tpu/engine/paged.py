@@ -63,8 +63,9 @@ prompt never pauses the decode train:
 - Staging happens at megastep boundaries; a TTFT-aware controller
   (`next_megastep_k`) grows K toward `megastep_max` when idle and, while
   admissions are waiting, caps K at the guaranteed-admission horizon
-  (chunks until some live slot MUST free, `_slack_chunks`), never below
-  the ladder's second rung. K = 1 runs through `_megastep` at rung 1.
+  (chunks until some live slot MUST free, `_slack_chunks`), down to one
+  chunk where an answer ends within it (`engine_one_chunk_dispatches`).
+  K = 1 runs through `_megastep` at rung 1.
 - `_grow`: pads the live cache up to the next width when a longer prompt
   arrives.
 
@@ -952,18 +953,20 @@ def next_megastep_k(current: int, ladder: Sequence[int], pending: int,
     that exposure is the dead-lane account
     (`megastep_dead_lane_tokens`).
 
-    The floor is the ladder's second rung: an admission costs no
-    dispatch of its own at a boundary — it is STAGED there (one async
-    program) and its prefill chunks drain through the scan iterations —
-    so a boundary's only admission value is handing a freed slot to the
-    stager, and the K=1 chunk loop buys nothing over K=2.
-    slack_chunks=None (no live slot to bound) falls to that floor."""
+    The floor is one chunk: a slack of 1 (or 0, an end already due)
+    gives K = 1, so the dispatch ends where the next answer ends and the
+    slot is handed on there. Every row a lane decodes past its answer's
+    end is thrown away (`engine_overrun_lane_steps`), where a boundary
+    costs one more dispatch of a warmed program, sent while the
+    dispatches in flight compute. slack_chunks=None (no live slot to
+    bound: only staged requests) keeps the ladder's second rung, because
+    nothing can be handed on at an earlier boundary."""
     if len(ladder) <= 1:
         return ladder[0] if ladder else 1
     if pending <= 0:
         i = ladder.index(current) if current in ladder else 0
         return ladder[min(len(ladder) - 1, i + 1)]
-    cap = max(ladder[1], slack_chunks or 1)
+    cap = ladder[1] if slack_chunks is None else max(1, slack_chunks)
     return max(k for k in ladder if k <= cap)
 
 
@@ -2245,11 +2248,13 @@ class PagedEngine:
                 # work was waiting for a slot. (An empty backlog grows K,
                 # and a dispatch of K*chunk rows sent while the ends in
                 # flight are unreaped strands every lane in it.)
+                backlog = len(self._pending) + len(self._departing())
                 self.megastep_k = next_megastep_k(
-                    self.megastep_k, self.megastep_ks,
-                    len(self._pending) + len(self._departing()),
+                    self.megastep_k, self.megastep_ks, backlog,
                     self._slack_chunks(),
                 )
+                if backlog and self.megastep_k == 1:
+                    self._count(one_chunk_dispatches=1)
                 with self._span("engine.dispatch", k=self.megastep_k):
                     self._dispatch(self.megastep_k)
             done: List[Tuple[int, str]] = []

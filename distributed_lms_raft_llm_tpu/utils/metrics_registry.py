@@ -589,6 +589,15 @@ ENGINE_SLOTS_HANDED_ON = counter(
     "dispatches not yet reaped, so the slot did not wait for that reap. "
     "Over the requests finished, the share of ends the host foresaw",
 )
+ENGINE_ONE_CHUNK_DISPATCHES = counter(
+    "engine_one_chunk_dispatches",
+    "dispatches the K controller sent at K = 1 while work waited for a "
+    "slot: some answer's end was certain within one chunk, so the "
+    "dispatch ends there and the slot is handed on. Over the "
+    "engine_prog_megastep histogram's count, how often the one-chunk "
+    "floor engaged; with engine_scan_iterations, the rows a dispatch "
+    "carried",
+)
 MOE_PICKS = counter(
     "moe_picks",
     "expert picks computed by the routed layers (live tokens x experts "
@@ -695,6 +704,7 @@ ENGINE_LOOP_COUNTERS: Dict[str, str] = {
     "staged_lane_steps": ENGINE_STAGED_LANE_STEPS,
     "overrun_lane_steps": ENGINE_OVERRUN_LANE_STEPS,
     "slots_handed_on": ENGINE_SLOTS_HANDED_ON,
+    "one_chunk_dispatches": ENGINE_ONE_CHUNK_DISPATCHES,
     "moe_picks": MOE_PICKS,
     "moe_experts_reached": MOE_EXPERTS_REACHED,
     "moe_expert_seats": MOE_EXPERT_SEATS,

@@ -173,22 +173,35 @@ def test_controller_holds_amortization_under_saturation():
     assert next_megastep_k(2, ladder, pending=1, slack_chunks=4) == 4
 
 
-def test_controller_floor_is_second_rung():
-    """A boundary's only admission value is handing a freed slot to the
-    stager — the prefill itself drains through scan iterations — so the
-    pending-queue shrink must NOT reach the K=1 chunk loop. K stays >= 2
-    under a non-empty pending queue at any slack, and with no horizon at
-    all (only staged requests, which bound nothing until their flip),
-    while the slack cap still applies above the floor."""
+def test_controller_floor_is_one_chunk():
+    """Under a backlog a dispatch ends where the next answer ends: a
+    slack of one chunk, or of none (an end already due), gives K = 1, so
+    the lane decodes no second chunk past its answer's end before the
+    slot is handed on. With no horizon at all (only staged requests,
+    which bound nothing until their flip) nothing can be handed on at an
+    earlier boundary and K keeps the second rung. Above the floor the
+    slack cap and, with nobody waiting, the growth rule answer as they
+    always did, rung for rung."""
     ladder = [1, 2, 4, 8]
-    assert next_megastep_k(8, ladder, pending=1, slack_chunks=1) == 2
-    assert next_megastep_k(8, ladder, pending=3, slack_chunks=0) == 2
-    assert next_megastep_k(8, ladder, pending=1, slack_chunks=None) == 2
-    # Above the floor the slack/horizon math is unchanged.
-    assert next_megastep_k(8, ladder, pending=1, slack_chunks=5) == 4
-    assert next_megastep_k(1, ladder, pending=16, slack_chunks=64) == 8
+    for current in ladder:
+        for pending in (1, 3, 16):
+            assert next_megastep_k(current, ladder, pending, 1) == 1
+            assert next_megastep_k(current, ladder, pending, 0) == 1
+            assert next_megastep_k(current, ladder, pending, None) == 2
+            # Above the floor: the largest rung that fits the slack.
+            for slack, k in ((2, 2), (3, 2), (4, 4), (5, 4), (7, 4),
+                             (8, 8), (9, 8), (64, 8)):
+                assert next_megastep_k(current, ladder, pending, slack) == k
+    assert next_megastep_k(8, [1, 2, 4, 6], 2, 1) == 1
+    assert next_megastep_k(8, [1, 2, 4, 6], 2, 7) == 6
+    assert next_megastep_k(6, [1, 2, 4, 6], 2, None) == 2
+    # Nobody waiting: one rung up whatever the slack says, 0 and 1 too.
+    for slack in (None, 0, 1, 2, 64):
+        assert [next_megastep_k(k, ladder, 0, slack) for k in ladder] == [
+            2, 4, 8, 8]
     # A [1] ladder (megastep disabled) still returns its only rung.
     assert next_megastep_k(1, [1], pending=5, slack_chunks=0) == 1
+    assert next_megastep_k(1, [1], pending=5, slack_chunks=None) == 1
 
 
 def test_controller_grows_toward_max_when_idle():
@@ -201,11 +214,11 @@ def test_controller_grows_toward_max_when_idle():
 
 def test_engine_controller_tracks_admission_horizon(monkeypatch):
     """Through the real engine: a staged request bounds no horizon until
-    its flip is reaped (the floor meanwhile); then a backlog keeps K wide
-    while no slot can free (slack = remaining budget) and steps K down to
-    the floor once the dispatched debt covers the guaranteed finish —
-    amortization under saturation, a boundary where a slot can be handed
-    on, and never the K=1 chunk loop while work waits."""
+    its flip is reaped (the second rung meanwhile); then a backlog keeps
+    K wide while no slot can free (slack = remaining budget) and steps K
+    down as the dispatched debt closes on the guaranteed finish, to K = 1
+    exactly where a live slot's end is within a chunk: amortization
+    under saturation, and a boundary where a slot can be handed on."""
     from distributed_lms_raft_llm_tpu.engine import paged as paged_mod
 
     calls = []  # (pending, slack, K chosen), one per dispatch
@@ -226,11 +239,16 @@ def test_engine_controller_tracks_admission_horizon(monkeypatch):
     assert calls == [(4, None, 2)] and eng.megastep_k == 2
     eng.drain()
     backlog = [(slack, k) for pending, slack, k in calls if pending]
-    assert all(k >= 2 for _, k in backlog)
     wide = [slack for slack, k in backlog if k == 4]
     assert wide and min(wide) >= 4       # wide only while no slot can free
-    floor = [slack for slack, k in backlog if k == 2 and slack is not None]
-    assert floor and max(floor) < 4      # down at the guaranteed finish
+    two = [slack for slack, k in backlog if k == 2 and slack is not None]
+    assert two and set(two) <= {2, 3}
+    one = [slack for slack, k in backlog if k == 1]
+    assert one and set(one) <= {0, 1}    # an end within a chunk, and
+    assert all(k == 1 for slack, k in backlog   # nowhere else
+               if slack is not None and slack <= 1)
+    counts, _ = eng.pop_loop_stats()
+    assert counts["one_chunk_dispatches"] == len(one)
 
 
 # ------------------------------------------------------- greedy bit-equality

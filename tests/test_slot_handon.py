@@ -218,9 +218,9 @@ def test_the_k_controller_still_sees_the_backlog_a_hand_on_took(monkeypatch):
         if eng._departing():
             ks.append(eng.megastep_k)
     assert rids and all(b == p + d for b, p, d in seen)
-    # The queue was empty with ends still in flight, and K held its floor.
+    # The queue was empty with ends still in flight, and K did not grow.
     assert any(p == 0 and d > 0 for _, p, d in seen)
-    assert ks and max(ks) == 2
+    assert ks and max(ks) <= 2
 
 
 # ------------------------------------------------------ (c) session turns
@@ -358,3 +358,62 @@ def test_lane_counters_sum_and_the_overrun_shrinks(monkeypatch):
     handed = with_hand_on["slots_handed_on"]
     assert with_hand_on["overrun_lane_steps"] <= (
         handed * (rows - 1) + (n - handed) * 3 * rows)
+
+
+# ------------------------------------- (g) a dispatch ends where an answer ends
+
+
+def second_rung_floor(real):
+    """`next_megastep_k` as it was before the floor fell to one chunk:
+    under a backlog never below the ladder's second rung."""
+    def floored(current, ladder, pending, slack):
+        k = real(current, ladder, pending, slack)
+        return max(k, ladder[1]) if pending > 0 and len(ladder) > 1 else k
+    return floored
+
+
+@pytest.mark.parametrize("chunk", [2, 4])
+def test_under_a_backlog_an_answer_overruns_less_than_a_chunk(
+        monkeypatch, chunk):
+    """Answers of a whole multiple of 2 x chunk rows and more requests
+    than slots: while work waits, the controller ends a dispatch in the
+    chunk in which the nearest answer ends (K = 1,
+    `engine_one_chunk_dispatches`), so a request decodes less than one
+    chunk's rows past its last token; floored at the second rung, as the
+    controller was, it decodes up to two. The tokens are the same."""
+    max_new, n = 4 * chunk, len(PROMPTS)
+
+    def account(eng):
+        rids = [eng.submit(p) for p in PROMPTS]
+        for rid in rids:
+            eng.stream_watch(rid)
+        ended = 0
+        while eng._pending:  # the standing backlog
+            ended += len(eng.step())
+        standing, _ = eng.pop_loop_stats()
+        while eng.has_work:
+            eng.step()
+        finals = eng.pop_final_tokens()
+        return [finals[r] for r in rids], standing, ended
+
+    def engine():
+        return PagedEngine(make_config(max_new), slots=2, chunk=chunk,
+                           inflight=2, megastep=2, megastep_max=4,
+                           prefill_chunk_tokens=4)
+
+    got, counts, ended = account(engine())
+    assert got == bucketed_tokens(max_new, PROMPTS)
+    assert ended >= n - 4
+    assert counts["one_chunk_dispatches"] > 0
+    assert counts["overrun_lane_steps"] <= ended * (chunk - 1)
+    assert metrics_registry.ENGINE_LOOP_COUNTERS["one_chunk_dispatches"] == (
+        "engine_one_chunk_dispatches")
+    assert metrics_registry.is_declared("engine_one_chunk_dispatches")
+
+    monkeypatch.setattr(paged_mod, "next_megastep_k",
+                        second_rung_floor(paged_mod.next_megastep_k))
+    floored, old, old_ended = account(engine())
+    assert floored == got
+    assert "one_chunk_dispatches" not in old
+    assert old["overrun_lane_steps"] > old_ended * (chunk - 1)
+    assert old["scan_iterations"] > counts["scan_iterations"]
