@@ -480,8 +480,7 @@ class TelemetryConfig:
     section because the knobs trade off as a unit: the sample interval
     and ring length bound what `GET /admin/timeline` remembers, the
     fast/slow windows + burn thresholds define when the multi-window
-    burn-rate evaluators page, and the chip ceiling anchors the
-    capacity model's utilization axis.
+    burn-rate evaluators page.
     """
 
     enabled: bool = True            # per-node TimelineSampler + /admin/timeline
@@ -495,10 +494,6 @@ class TelemetryConfig:
     slow_burn: float = 1.0          # slow-window threshold (>= 1 means the
     #                                 budget is being spent faster than it
     #                                 accrues)
-    chip_ceiling_tokens_per_s: Optional[float] = None  # saturation
-    #                                 throughput per chip, MEASURED on the
-    #                                 serving device; the utilization
-    #                                 shares are reported only when set
 
     def __post_init__(self) -> None:
         if self.sample_interval_s <= 0 or self.ring_points < 2:
@@ -512,11 +507,6 @@ class TelemetryConfig:
             )
         if self.fast_burn <= 0 or self.slow_burn <= 0:
             raise ValueError("[telemetry] burn thresholds must be > 0")
-        if (self.chip_ceiling_tokens_per_s is not None
-                and self.chip_ceiling_tokens_per_s <= 0):
-            raise ValueError(
-                "[telemetry] chip_ceiling_tokens_per_s must be > 0"
-            )
 
 
 @dataclasses.dataclass

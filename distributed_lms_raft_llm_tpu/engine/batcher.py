@@ -44,7 +44,14 @@ from typing import Any, AsyncIterator, Deque, Dict, List, Optional, Tuple
 from ..utils import metrics_registry as metric
 from ..utils.resilience import Deadline, DeadlineExpired, Overloaded
 from ..utils.tracing import FLAG_DEADLINE, NULL_SPAN, get_tracer
-from .spans import Span
+from .spans import (
+    BETWEEN_STEPS,
+    NO_SPANS,
+    REAP_WAIT,
+    Span,
+    SpanSum,
+    turn_budget,
+)
 
 log = logging.getLogger(__name__)
 
@@ -832,21 +839,40 @@ class PagedQueue:
                     # rebuild it or every later request fails too.
                     self.engine.reset()
                     break
-                with Span("queue.between_steps"):
-                    reap_wait_s = self._between_steps(done)
+                with Span(BETWEEN_STEPS) as between:
+                    spans = self._between_steps(done)
                 if self.metrics is not None:
-                    self.metrics.hist("engine_host_turn").observe(
-                        max(0.0, time.monotonic() - t_turn - reap_wait_s)
-                    )
+                    self._count_turn(time.monotonic() - t_turn, spans,
+                                     between)
 
-    def _between_steps(self, done: List[Tuple[int, str]]) -> float:
+    def _count_turn(self, wall_s: float, spans: Dict[str, SpanSum],
+                    between: Span) -> None:
+        """One turn of the loop into the metrics: its wall parted into
+        host work, device wait and (what is left) stall, as counters of
+        microseconds, its host work as one `engine_host_work` observation,
+        and `engine_host_turn`, which is the wall less the wall of its
+        `engine.reap.wait` spans."""
+        reap_wait_s = spans.get(REAP_WAIT, NO_SPANS).wall_s
+        self.metrics.hist("engine_host_turn").observe(
+            max(0.0, wall_s - reap_wait_s)
+        )
+        budget = turn_budget(wall_s, spans, between)
+        for key, n in budget.items():
+            self.metrics.inc(metric.ENGINE_LOOP_COUNTERS[key], n)
+        self.metrics.hist(metric.ENGINE_LOOP_HISTOGRAMS["host_work"]).observe(
+            budget["loop_host_work_us"] / 1e6
+        )
+
+    def _between_steps(
+        self, done: List[Tuple[int, str]]
+    ) -> Dict[str, SpanSum]:
         """Everything the loop does from one step()'s return to the next
         call: drain the engine's stats into the metrics, push stream
         chunks, resolve finished requests, and hand the engine what
         arrived meanwhile (a request that expired while backlogged is
-        shed before the next step can admit it). Returns the seconds the
-        step spent blocked on the device (its `engine.reap.wait`)."""
-        reap_wait_s = self._reap_observability()
+        shed before the next step can admit it). Returns the step's
+        spans, summed by name (`pop_loop_stats`)."""
+        spans = self._reap_observability()
         ttfts = self.engine.pop_ttfts()
         if self.metrics is not None:
             for ttft in ttfts.values():
@@ -864,7 +890,7 @@ class PagedQueue:
                 f.set_result(text)
         self._drain_incoming()
         self._shed_expired_pending()
-        return reap_wait_s
+        return spans
 
     def _export_engine_stats(self) -> None:
         """The engine's gauges and drained counts, once per turn."""
@@ -1020,19 +1046,19 @@ class PagedQueue:
             text=text[len(st.abs_text):], final=True, full_text=text,
         ))
 
-    def _reap_observability(self) -> float:
+    def _reap_observability(self) -> Dict[str, SpanSum]:
         """Between steps: drain the engine's measured queue waits (closing
         the matching `queue.wait` spans with the true submit->prefill
         interval), per-program dispatch times (feeding the
         `engine_prog_*` histogram series and the shared-attribution
         accumulator the completion-time engine spans diff against) and
         loop counts and observations (`metric.ENGINE_LOOP_*`). Returns
-        the seconds the last step spent blocked on the device."""
-        reap_wait_s = 0.0
+        the last step's spans summed by name (none from an engine that
+        keeps no loop stats)."""
+        spans: Dict[str, SpanSum] = {}
         pop_loop = getattr(self.engine, "pop_loop_stats", None)
         if pop_loop is not None:
-            counts, observations = pop_loop()
-            reap_wait_s = sum(observations.get("reap_wait", ()))
+            counts, observations, spans = pop_loop()
             if self.metrics is not None:
                 for key, n in counts.items():
                     self.metrics.inc(metric.ENGINE_LOOP_COUNTERS[key], n)
@@ -1067,7 +1093,7 @@ class PagedQueue:
                 entry = self._spans.get(rid)
                 if entry is not None:
                     entry.prefix_hit = hit
-        return reap_wait_s
+        return spans
 
     def _finish_span(self, rid: int) -> None:
         """Synthesize the request's `engine.decode` span: admission (end

@@ -112,7 +112,7 @@ from .program_inventory import (
     stage_runs,
 )
 from .scoring import _score_program, derive_score_shapes, score_texts
-from .spans import PROG, ProgramLog, Span, named_partial
+from .spans import KEYS, PROG, ProgramLog, Span, SpanSum, named_partial
 from .sampling import (
     SamplingParams,
     sample_step,
@@ -1350,8 +1350,8 @@ class PagedEngine:
                 d.pop(rid, None)
 
     def _span(self, name: str, **attrs) -> Span:
-        """A host span (engine/spans.py); `engine.prog.*` ones are the
-        timed dispatches."""
+        """A host span (engine/spans.py), summed by its name in `_progs`;
+        `engine.prog.*` ones are besides the timed dispatches."""
         return Span(name, self._progs, **attrs)
 
     def _count(self, **amounts: int) -> None:
@@ -1364,13 +1364,17 @@ class PagedEngine:
         if len(vals) > self._PROG_TIMES_MAX:
             del vals[: -self._PROG_TIMES_MAX // 2]
 
-    def pop_loop_stats(self) -> Tuple[Dict[str, int], Dict[str, List[float]]]:
-        """Drain (counts, observations) since the last call, keyed as
-        the metrics registry's ENGINE_LOOP_COUNTERS and
-        ENGINE_LOOP_HISTOGRAMS key them; the series' help strings there
+    def pop_loop_stats(self) -> Tuple[Dict[str, int], Dict[str, List[float]],
+                                      Dict[str, SpanSum]]:
+        """Drain (counts, observations, spans) since the last call. The
+        first two are keyed as the metrics registry's ENGINE_LOOP_COUNTERS
+        and ENGINE_LOOP_HISTOGRAMS key them; the series' help strings there
         say what each counts. Observations are seconds, except
-        `decode_lanes` (lanes) and `staged_iterations` (iterations)."""
-        out = (self._counts, self._obs)
+        `decode_lanes` (lanes) and `staged_iterations` (iterations).
+        `spans` holds, by span name, the wall, CPU and runtime-wait seconds
+        of every span this engine closed (engine/spans.py `SpanSum`): what
+        the serving loop parts a turn's wall by (`spans.turn_budget`)."""
+        out = (self._counts, self._obs, self._progs.pop_sums())
         self._counts, self._obs = {}, {}
         return out
 
@@ -1830,7 +1834,8 @@ class PagedEngine:
             if handed_on and not self._end_in_flight(slot):
                 continue
             req, bucket, w_req, ids = self._pop_next()
-            self._rng, rng = jax.random.split(self._rng)
+            with self._span(KEYS):
+                self._rng, rng = jax.random.split(self._rng)
             cursor0 = 0
             snapshot = None
             if pc is not None:
@@ -2094,10 +2099,15 @@ class PagedEngine:
         streams are identical by construction; stochastic streams match
         too whenever the admission interleaving matches)."""
         keys = []
-        for _ in range(k):
-            self._rng, r = jax.random.split(self._rng)
-            keys.append(r)
-        return jnp.stack(keys)
+        # The split, the unpacking of its result and the stack are device
+        # programs of their own: whichever of a turn's calls meets the
+        # runtime's full launch queue sleeps until the device finishes a
+        # program, and on the chip it is most often the first of these.
+        with self._span(KEYS):
+            for _ in range(k):
+                self._rng, r = jax.random.split(self._rng)
+                keys.append(r)
+            return jnp.stack(keys)
 
     def _rows_to_end(self, slot: int) -> Optional[int]:
         """`rows_to_certain_end` of the request in `slot`: its budget

@@ -260,16 +260,11 @@ class ScoringManager:
         *,
         max_job_texts: int = 4096,
         jobs_retained: int = 32,
-        chip_ceiling_tokens_per_s: Optional[float] = None,
     ):
         self.engine = engine
         self.metrics = metrics
         self.max_job_texts = max(1, max_job_texts)
         self.jobs_retained = max(1, jobs_retained)
-        # The device's saturation throughput, when one was measured and
-        # configured ([telemetry] chip_ceiling_tokens_per_s); without it
-        # the scoring_utilization share is not reported.
-        self.chip_ceiling_tokens_per_s = chip_ceiling_tokens_per_s
         # One quantum = one device batch = the largest batch bucket: the
         # single-dispatch granularity interactive work preempts at.
         self.quantum_texts = int(
@@ -284,8 +279,8 @@ class ScoringManager:
         # (created lazily on the serving loop).
         self._wake: Optional[asyncio.Event] = None
         # Recent (monotonic, scored tokens) quanta feeding the
-        # scoring_tokens_per_s / scoring_utilization gauges (sliding
-        # window, same shape as the serving queue's token window).
+        # scoring_tokens_per_s gauge (sliding window, same shape as the
+        # serving queue's token window).
         self._tok_window: Deque[Tuple[float, int]] = deque()  # guarded-by: _lock
         self._tok_window_s = 5.0
         # Aggregate stats (the healthz/bench surface).
@@ -481,16 +476,10 @@ class ScoringManager:
             span = now - self._tok_window[0][0]
             window_tokens = sum(n for _, n in self._tok_window)
         if span > 0.2:
-            tps = window_tokens / span
-            # The tenant-split utilization view: scoring's throughput next
-            # to serving_tokens_per_s for the interactive tenant, and its
-            # share of the chip ceiling where one is configured.
-            self.metrics.set_gauge(metric.SCORING_TOKENS_PER_S, tps)
-            if self.chip_ceiling_tokens_per_s:
-                self.metrics.set_gauge(
-                    metric.SCORING_UTILIZATION,
-                    tps / self.chip_ceiling_tokens_per_s,
-                )
+            # The tenant-split view: scoring's throughput next to
+            # serving_tokens_per_s for the interactive tenant.
+            self.metrics.set_gauge(metric.SCORING_TOKENS_PER_S,
+                                   window_tokens / span)
 
 
 def score_admin_get(path: str,

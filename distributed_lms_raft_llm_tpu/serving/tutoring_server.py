@@ -441,7 +441,6 @@ async def serve_async(
     scoring: bool = False,
     scoring_max_job_texts: int = 4096,
     scoring_jobs_retained: int = 32,
-    scoring_chip_ceiling: Optional[float] = None,
     session_ttl_s: float = 600.0,
     session_max: int = 256,
 ) -> grpc.aio.Server:
@@ -464,7 +463,6 @@ async def serve_async(
             engine, metrics=metrics,
             max_job_texts=scoring_max_job_texts,
             jobs_retained=scoring_jobs_retained,
-            chip_ceiling_tokens_per_s=scoring_chip_ceiling,
         )
     if isinstance(engine, PagedEngine):
         queue = PagedQueue(engine, metrics=metrics, max_queue=max_queue,
@@ -751,7 +749,6 @@ def main(argv=None) -> None:
             "telemetry_interval": cfg.telemetry.sample_interval_s,
             "telemetry_ring": cfg.telemetry.ring_points,
         }, argv=argv)
-        args.scoring_chip_ceiling = cfg.telemetry.chip_ceiling_tokens_per_s
         args.session_ttl_s = cfg.sessions.ttl_s
         args.session_max = cfg.sessions.max_sessions
         if not args.no_telemetry:
@@ -767,7 +764,6 @@ def main(argv=None) -> None:
         configure_from(cfg.tracing)
     else:
         args.sampling_overrides = {}
-        args.scoring_chip_ceiling = None
         args.session_ttl_s = 600.0
         args.session_max = 256
     if args.jax_platform == "cpu":
@@ -870,7 +866,6 @@ def main(argv=None) -> None:
             scoring=args.scoring,
             scoring_max_job_texts=args.scoring_max_job_texts,
             scoring_jobs_retained=args.scoring_jobs_retained,
-            scoring_chip_ceiling=args.scoring_chip_ceiling,
             session_ttl_s=args.session_ttl_s,
             session_max=args.session_max,
         )

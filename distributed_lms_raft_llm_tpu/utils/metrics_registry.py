@@ -379,9 +379,9 @@ PREFIX_CACHE_HIT_RATE = gauge(
 SERVING_TOKENS_PER_S = gauge(
     "serving_tokens_per_s",
     "recent serving throughput on the paged engine: emitted tokens per "
-    "second over the last few seconds of reaps — the utilization "
-    "numerator the capacity model divides by the chip's saturation "
-    "ceiling ([telemetry] chip_ceiling_tokens_per_s, when measured)",
+    "second over the last few seconds of reaps — the numerator the "
+    "capacity model (scripts/telemetry.py --capacity) holds against a "
+    "measured ceiling given with --ceiling",
 )
 SERVING_QUEUE_DEPTH = gauge(
     "serving_queue_depth",
@@ -415,13 +415,6 @@ SCORING_TOKENS_PER_S = gauge(
     "the last few seconds of quanta — the scoring tenant's half of the "
     "tenant-split utilization view (serving_tokens_per_s is the "
     "interactive half)",
-)
-SCORING_UTILIZATION = gauge(
-    "scoring_utilization",
-    "scoring_tokens_per_s as a fraction of the chip saturation ceiling "
-    "([telemetry] chip_ceiling_tokens_per_s; absent until one is "
-    "measured and configured) — how much of the idle headroom the "
-    "background tenant is actually harvesting",
 )
 SCORING_QUANTA = counter(
     "scoring_quanta",
@@ -672,8 +665,68 @@ ENGINE_REAP_WAIT = histogram(
 )
 ENGINE_HOST_TURN = histogram(
     "engine_host_turn",
-    "one turn of the serving loop (engine.step + queue.between_steps) "
-    "less its engine.reap.wait: the host's own work per turn",
+    "one turn of the serving loop (from before engine.step is handed to "
+    "its thread to the return of queue.between_steps) less the wall of "
+    "its engine.reap.wait spans. NOT the host's own work: the host waits "
+    "for the device inside the dispatch call too, so under load this "
+    "reads one or two whole dispatches of device time "
+    "(engine_host_work is the host's work)",
+)
+ENGINE_HOST_WORK = histogram(
+    "engine_host_work",
+    "the host's own work in one turn of the serving loop: the CPU time "
+    "(time.thread_time) of engine.step on its thread and of "
+    "queue.between_steps on the loop's, one observation a turn; the "
+    "turn's engine_loop_host_work_us in seconds",
+)
+ENGINE_LOOP_WALL_US = counter(
+    "engine_loop_wall_us",
+    "wall of the serving loop's turns, microseconds: each from before "
+    "engine.step is handed to its thread to the return of "
+    "queue.between_steps (queue.idle is no turn). device_wait + "
+    "host_work + stall, exactly",
+)
+ENGINE_LOOP_DEVICE_WAIT_US = counter(
+    "engine_loop_device_wait_us",
+    "of engine_loop_wall_us, the step's thread asleep inside a call into "
+    "the runtime: wall less CPU time of the engine.reap.wait, "
+    "engine.prog.* and engine.keys spans under engine.step (whichever "
+    "call meets the runtime's full launch queue sleeps until the device "
+    "finishes a program)",
+)
+ENGINE_LOOP_HOST_WORK_US = counter(
+    "engine_loop_host_work_us",
+    "of engine_loop_wall_us, the loop's own CPU time: engine.step's on "
+    "its thread plus queue.between_steps' on the loop's. The wall less "
+    "this and engine_loop_device_wait_us is the turn's stall: neither "
+    "this code's CPU nor a wait for the device (the hand-off between the "
+    "threads, the GIL held by others, the process descheduled)",
+)
+ENGINE_LOOP_CPU_US_ADMIT = counter(
+    "engine_loop_cpu_us_admit",
+    "CPU time of the engine.admit spans, the engine.prog.* calls inside "
+    "them included, microseconds; with its four siblings it sums to "
+    "engine_loop_host_work_us less engine.step's self time",
+)
+ENGINE_LOOP_CPU_US_DISPATCH = counter(
+    "engine_loop_cpu_us_dispatch",
+    "CPU time of the engine.dispatch spans (the megastep call: a runtime "
+    "that spins where it waits would show here), microseconds",
+)
+ENGINE_LOOP_CPU_US_REAP_WAIT = counter(
+    "engine_loop_cpu_us_reap_wait",
+    "CPU time of the engine.reap.wait spans (copying a dispatch's results "
+    "to numpy), microseconds",
+)
+ENGINE_LOOP_CPU_US_REAP_HOST = counter(
+    "engine_loop_cpu_us_reap_host",
+    "CPU time of the engine.reap.host spans (the token walk, decoding, "
+    "the prefix tree's exports), microseconds",
+)
+ENGINE_LOOP_CPU_US_BETWEEN_STEPS = counter(
+    "engine_loop_cpu_us_between_steps",
+    "CPU time of the queue.between_steps spans on the loop's thread "
+    "(metrics, stream chunks, futures, arrivals), microseconds",
 )
 ENGINE_DECODE_LANES = histogram(
     "engine_decode_lanes",
@@ -714,6 +767,16 @@ ENGINE_LOOP_COUNTERS: Dict[str, str] = {
     "state_snapshots_restored": ENGINE_STATE_SNAPSHOTS_RESTORED,
     "prefix_tokens_recomputed_for_state":
         ENGINE_PREFIX_TOKENS_RECOMPUTED_FOR_STATE,
+    # One turn's budget (engine/spans.py `turn_budget`), counted by
+    # PagedQueue from the spans the engine drains with the rest.
+    "loop_wall_us": ENGINE_LOOP_WALL_US,
+    "loop_device_wait_us": ENGINE_LOOP_DEVICE_WAIT_US,
+    "loop_host_work_us": ENGINE_LOOP_HOST_WORK_US,
+    "loop_cpu_us_admit": ENGINE_LOOP_CPU_US_ADMIT,
+    "loop_cpu_us_dispatch": ENGINE_LOOP_CPU_US_DISPATCH,
+    "loop_cpu_us_reap_wait": ENGINE_LOOP_CPU_US_REAP_WAIT,
+    "loop_cpu_us_reap_host": ENGINE_LOOP_CPU_US_REAP_HOST,
+    "loop_cpu_us_between_steps": ENGINE_LOOP_CPU_US_BETWEEN_STEPS,
 }
 ENGINE_LOOP_HISTOGRAMS: Dict[str, str] = {
     "queue_wait": QUEUE_WAIT,
@@ -721,6 +784,7 @@ ENGINE_LOOP_HISTOGRAMS: Dict[str, str] = {
     "reap_wait": ENGINE_REAP_WAIT,
     "decode_lanes": ENGINE_DECODE_LANES,
     "staged_iterations": ENGINE_STAGED_ITERATIONS,
+    "host_work": ENGINE_HOST_WORK,
 }
 
 # Storage layer (raft/storage.py + lms/persistence.py via lms/node.py).

@@ -28,8 +28,8 @@ goes" as a measured number instead of a guess.
 
 With `--config`, `[telemetry]` supplies the poll interval, burn-rate
 windows/thresholds (the dashboard shows live fast/slow-window burn for
-the degraded-rate SLO), and the chip ceiling; `[sim]` supplies the SLO
-bounds. Flags override the file.
+the degraded-rate SLO); `[sim]` supplies the SLO bounds. Flags override
+the file; the chip's ceiling comes from `--ceiling` alone.
 """
 
 from __future__ import annotations
@@ -64,10 +64,9 @@ _DASH_ROWS: Tuple[Tuple[str, str, str], ...] = (
     ("shed expired/s", "rate", "shed_expired"),
     ("tick stalls/s", "rate", "raft_tick_stalls"),
     ("serving tok/s", "gauge", "serving_tokens_per_s"),
-    # The tenant split: background bulk scoring's share of the chip next
-    # to interactive serving (utilization is vs the configured ceiling).
+    # The tenant split: background bulk scoring next to interactive
+    # serving.
     ("scoring tok/s", "gauge", "scoring_tokens_per_s"),
-    ("scoring util", "gauge", "scoring_utilization"),
     ("score quanta/s", "rate", "scoring_quanta"),
     ("queue depth", "gauge", "serving_queue_depth"),
     ("prefix hit rate", "gauge", "prefix_cache_hit_rate"),
@@ -388,7 +387,7 @@ def main(argv: Optional[List[str]] = None) -> int:
                          "exit")
     ap.add_argument("--config", default=None,
                     help="TOML deployment file; [telemetry] fills "
-                         "interval/windows/ceiling, [sim] the SLO bounds")
+                         "interval/windows, [sim] the SLO bounds")
     ap.add_argument("--capacity", default=None, metavar="TIMELINE.json",
                     help="fit the capacity model over an exported "
                          "timeline (or a semester-sim BENCH record) "
@@ -401,9 +400,9 @@ def main(argv: Optional[List[str]] = None) -> int:
                     help="answer p95 bound (default: [sim] "
                          "slo_answer_p95_s, else 6.0)")
     ap.add_argument("--ceiling", type=float, default=None,
-                    help="chip saturation tok/s (default: [telemetry] "
-                         "chip_ceiling_tokens_per_s, else none: the "
-                         "utilization shares are left out)")
+                    help="capacity: chip saturation tok/s, measured "
+                         "(default none: the utilization shares are "
+                         "left out)")
     ap.add_argument("--stage-p95s", default=None,
                     help="capacity: JSON file of flight-recorder stage "
                          "p95s to fold into the model")
@@ -411,7 +410,6 @@ def main(argv: Optional[List[str]] = None) -> int:
 
     interval = 1.0
     slo_p95 = 6.0
-    ceiling = None
     degraded_bound = 0.5
     windows = {"fast": 60.0, "slow": 600.0}
     if args.config:
@@ -419,7 +417,6 @@ def main(argv: Optional[List[str]] = None) -> int:
 
         cfg = load_config(args.config)
         interval = cfg.telemetry.sample_interval_s
-        ceiling = cfg.telemetry.chip_ceiling_tokens_per_s
         windows = {"fast": cfg.telemetry.fast_window_s,
                    "slow": cfg.telemetry.slow_window_s}
         # The thresholds contextualize the dashboard's burn figures.
@@ -433,8 +430,6 @@ def main(argv: Optional[List[str]] = None) -> int:
         interval = args.interval
     if args.slo_p95 is not None:
         slo_p95 = args.slo_p95
-    if args.ceiling is not None:
-        ceiling = args.ceiling
 
     if args.capacity:
         with open(args.capacity, encoding="utf-8") as fh:
@@ -444,7 +439,7 @@ def main(argv: Optional[List[str]] = None) -> int:
             with open(args.stage_p95s, encoding="utf-8") as fh:
                 stage = json.load(fh)
         model = fit_capacity(doc, slo_p95_s=slo_p95,
-                             ceiling_tokens_per_s=ceiling,
+                             ceiling_tokens_per_s=args.ceiling,
                              node=args.node, stage_p95s=stage)
         print(json.dumps(model))
         return 0

@@ -4,24 +4,31 @@ Pinned here: every engine program lowers to a module named after its
 function (none to `jit__unknown`); under `jax.profiler` the host plane
 holds the loop's spans, each `engine.prog.*` inside its parent; the new
 counters conserve tokens and lane-steps over a run; the block programs
-reach their histograms; and the jax-free modules stay jax-free.
+reach their histograms; the jax-free modules stay jax-free; and every
+turn of the serving loop is parted into host work, device wait and stall,
+which four per-layer metrics of the benchmark read.
 """
 
 import asyncio
 import glob
+import json
 import os
 import subprocess
 import sys
+import time
 
 import jax
 import jax.numpy as jnp
 import pytest
 
+from benchmarks import readers
 from distributed_lms_raft_llm_tpu.engine import (
     EngineConfig,
     PagedEngine,
     PagedQueue,
     SamplingParams,
+    batcher,
+    spans,
 )
 from distributed_lms_raft_llm_tpu.utils import metrics_registry as metric
 from distributed_lms_raft_llm_tpu.utils.metrics import Metrics
@@ -126,6 +133,18 @@ def test_host_plane_holds_the_loop_spans_nested(tmp_path):
 
     engine = make_engine()
     engine.warmup()
+    # What the sink summed, drain after drain (the queue drains it a turn).
+    sink = {}
+    pop = engine.pop_loop_stats
+
+    def pop_and_keep():
+        out = pop()
+        for name, total in out[2].items():
+            n, wall_s = sink.get(name, (0, 0.0))
+            sink[name] = (n + total.n, wall_s + total.wall_s)
+        return out
+
+    engine.pop_loop_stats = pop_and_keep
     options = jax.profiler.ProfileOptions()
     options.python_tracer_level = 0
     options.enable_hlo_proto = False
@@ -134,6 +153,7 @@ def test_host_plane_holds_the_loop_spans_nested(tmp_path):
         answers = _serve(engine, PROMPTS, Metrics())
     finally:
         jax.profiler.stop_trace()
+    pop_and_keep()
     assert len(answers) == len(PROMPTS)
     path = sorted(glob.glob(os.path.join(str(tmp_path), "**", "*.xplane.pb"),
                             recursive=True))[-1]
@@ -147,24 +167,26 @@ def test_host_plane_holds_the_loop_spans_nested(tmp_path):
     ]
     names = {name for evs in lines for name, _, _ in evs}
     assert {"engine.step", "engine.admit", "engine.dispatch",
-            "engine.reap.wait", "engine.reap.host", "queue.between_steps",
-            "queue.idle", "engine.prog.megastep", "engine.prog.stage",
+            "engine.reap.wait", "engine.reap.host", "engine.keys",
+            "queue.between_steps", "queue.idle",
+            "engine.prog.megastep", "engine.prog.stage",
             "engine.prog.stage_block", "engine.prog.export_block"} <= names
-    parents = {"engine.prog.megastep": "engine.dispatch",
-               "engine.prog.stage": "engine.admit",
-               "engine.prog.stage_block": "engine.admit",
-               "engine.prog.grow": "engine.admit",
-               "engine.prog.export_block": "engine.reap.host"}
+    parents = {"engine.keys": ("engine.admit", "engine.dispatch"),
+               "engine.prog.megastep": ("engine.dispatch",),
+               "engine.prog.stage": ("engine.admit",),
+               "engine.prog.stage_block": ("engine.admit",),
+               "engine.prog.grow": ("engine.admit",),
+               "engine.prog.export_block": ("engine.reap.host",)}
     checked = 0
     for evs in lines:
         for name, start, end in evs:
-            if not name.startswith("engine.prog."):
+            if name not in parents:
                 continue
             assert any(
-                pname == parents[name] and ps <= start and end <= pe
+                pname in parents[name] and ps <= start and end <= pe
                 for pname, ps, pe in evs
             ), f"{name} at {start} lies in no {parents[name]}"
-            checked += 1
+            checked += name.startswith("engine.prog.")
         # Every phase of a turn lies inside that turn's engine.step.
         steps = [(s, e) for n, s, e in evs if n == "engine.step"]
         for name, start, end in evs:
@@ -172,6 +194,19 @@ def test_host_plane_holds_the_loop_spans_nested(tmp_path):
                         "engine.reap.wait", "engine.reap.host"):
                 assert any(s <= start and end <= e for s, e in steps), name
     assert checked >= 2 * len(PROMPTS)
+    # The sink's sums are the host plane's: as many spans of every name,
+    # and their wall within the few microseconds a span's own clock reads
+    # lie outside its annotation (the profiler's clock is another clock).
+    plane = {}
+    for evs in lines:
+        for name, start, end in evs:
+            n, wall_s = plane.get(name, (0, 0.0))
+            plane[name] = (n + 1, wall_s + (end - start) / 1e9)
+    assert set(sink) == {n for n in plane if n.startswith("engine.")}
+    for name, (n, wall_s) in sink.items():
+        assert plane[name][0] == n, name
+        assert plane[name][1] <= wall_s + 1e-4 * n, name
+        assert wall_s - plane[name][1] <= 5e-4 * n + 0.01 * wall_s, name
 
 
 # ------------------------------------------------ (c), (e) conservation
@@ -248,7 +283,7 @@ def test_counters_conserve_tokens_and_lane_steps():
     # Every key the engine reports is a declared series.
     engine.submit("again")
     engine.drain()
-    counts, observations = engine.pop_loop_stats()
+    counts, observations, _ = engine.pop_loop_stats()
     assert set(counts) <= set(metric.ENGINE_LOOP_COUNTERS)
     assert set(observations) <= set(metric.ENGINE_LOOP_HISTOGRAMS)
 
@@ -267,6 +302,260 @@ def test_block_programs_reach_their_histograms():
     assert snap["counters"]["engine_dispatches"] == sum(
         h["count"] for name, h in lat.items()
         if name.startswith("engine_prog_"))
+
+
+# ------------------------------------------------- (f) the loop's budget
+
+
+def _burn(seconds):
+    end = time.thread_time() + seconds
+    while time.thread_time() < end:
+        pass
+
+
+def test_a_span_sums_into_its_parent_and_a_runtime_call_counts_once():
+    """Nested spans on one thread: a parent's self time is its own less
+    its children's, a sleep inside a runtime call is `wait_s` all the way
+    up and counted once where calls nest, a sleep outside one is nobody's
+    wait, and an exception closes a span without a record."""
+    log = spans.ProgramLog(8)
+    with spans.Span("engine.step", log) as step:
+        with spans.Span("engine.reap.wait", log) as outer:
+            with spans.Span(spans.PROG + "megastep", log) as call:
+                time.sleep(0.02)
+            time.sleep(0.01)
+        with spans.Span("engine.reap.host", log) as host:
+            time.sleep(0.01)
+            _burn(0.01)
+        with spans.Span("engine.dispatch", log) as dispatch:
+            with spans.Span(spans.KEYS, log) as keys:
+                time.sleep(0.01)
+        with pytest.raises(RuntimeError):
+            with spans.Span("engine.admit", log):
+                raise RuntimeError("no record of this one")
+        with spans.Span("engine.admit", log):
+            pass
+    assert 0.02 <= call.wait_s <= call.wall_s
+    assert call.wait_s + 0.01 <= outer.wait_s <= outer.wall_s
+    assert host.wait_s == 0.0 and host.cpu_s >= 0.01
+    assert host.wall_s - host.cpu_s >= 0.01  # asleep, and nobody's wait
+    assert 0.01 <= keys.wait_s == dispatch.wait_s
+    assert step.wait_s == outer.wait_s + keys.wait_s
+    assert step.kids_wall_s == pytest.approx(
+        outer.wall_s + host.wall_s + dispatch.wall_s,
+        abs=1e-3)  # and the two admits'
+    for sp in (step, outer, call, host):
+        assert 0.0 <= sp.cpu_s <= sp.wall_s
+    sums = log.pop_sums()
+    assert {name: total.n for name, total in sums.items()} == {
+        "engine.step": 1, "engine.reap.wait": 1, "engine.reap.host": 1,
+        "engine.prog.megastep": 1, "engine.admit": 1, "engine.dispatch": 1,
+        "engine.keys": 1}
+    assert sums["engine.step"].self_wall_s == pytest.approx(
+        step.wall_s - step.kids_wall_s)
+    assert sums["engine.reap.wait"].self_cpu_s == pytest.approx(
+        outer.cpu_s - call.cpu_s)
+    assert log.pop_sums() == {} and log.dispatches == 1
+    # The turn's parts from these sums: cut to fit a wall that is too
+    # short for them, each >= 0, and exact where it is long enough.
+    with spans.Span(spans.BETWEEN_STEPS) as between:
+        _burn(0.002)
+    whole = spans.turn_budget(1.0, sums, between)
+    assert whole["loop_wall_us"] == 1_000_000
+    assert whole["loop_device_wait_us"] == round(step.wait_s * 1e6)
+    assert whole["loop_host_work_us"] == round(
+        (step.cpu_s + between.cpu_s) * 1e6)
+    assert whole["loop_cpu_us_reap_host"] == round(host.cpu_s * 1e6)
+    short = spans.turn_budget(0.015, sums, between)
+    assert (short["loop_device_wait_us"] + short["loop_host_work_us"]
+            == short["loop_wall_us"] == 15_000)
+    assert spans.turn_budget(0.5, {}, between)["loop_device_wait_us"] == 0
+    # A coarse CPU clock (the chip machine's ticks 10 ms) gives a 1 ms call
+    # a whole tick: its wait reads negative and its work more than the wall.
+    ticked = spans.SpanSum()
+    ticked.cpu_s, ticked.wait_s = 0.010, -0.009
+    cut = spans.turn_budget(0.002, {"engine.step": ticked}, between)
+    assert (cut["loop_wall_us"], cut["loop_host_work_us"],
+            cut["loop_device_wait_us"]) == (2_000, 2_000, 0)
+
+
+@pytest.fixture(scope="module")
+def warm_engine():
+    engine = make_engine()
+    engine.warmup()
+    return engine
+
+
+def _serve_turns(engine, monkeypatch, turns):
+    """Serve PROMPTS, appending every turn's budget, as `spans.turn_budget`
+    returned it, to `turns`; returns the /metrics snapshot."""
+
+    def recording(wall_s, sums, between):
+        turns.append(spans.turn_budget(wall_s, sums, between))
+        return turns[-1]
+
+    monkeypatch.setattr(batcher, "turn_budget", recording)
+    metrics = Metrics()
+    _serve(engine, PROMPTS, metrics)
+    return metrics.snapshot()
+
+
+def _parts(turn):
+    """(device_wait, host_work, stalled) of one turn, microseconds."""
+    wait, work = turn["loop_device_wait_us"], turn["loop_host_work_us"]
+    return wait, work, turn["loop_wall_us"] - wait - work
+
+
+def test_every_turn_is_parted_three_ways(warm_engine, monkeypatch):
+    turns = []
+    snap = _serve_turns(warm_engine, monkeypatch, turns)
+    assert len(turns) >= MAX_NEW // 4
+    phases = ["loop_cpu_us_" + key for key in spans.STEP_PHASES]
+    for turn in turns:
+        wait, work, stalled = _parts(turn)
+        assert all(isinstance(v, int) for v in turn.values())
+        assert wait >= 0 and work >= 0 and stalled >= 0
+        assert wait + work + stalled == turn["loop_wall_us"] > 0
+        # The phases are siblings under engine.step: with the loop's own
+        # span they are the host's work less engine.step's self time.
+        by_phase = (sum(turn[k] for k in phases)
+                    + turn["loop_cpu_us_between_steps"])
+        assert by_phase <= work + len(phases) + 1  # each rounded alone
+    # The counters are the turns' sums, and each turn was observed once.
+    c, lat = snap["counters"], snap["latency"]
+    for key in turns[0]:
+        assert c[metric.ENGINE_LOOP_COUNTERS[key]] == sum(
+            t[key] for t in turns), key
+    work_hist = lat[metric.ENGINE_LOOP_HISTOGRAMS["host_work"]]
+    assert work_hist["count"] == lat["engine_host_turn"]["count"] == len(turns)
+    assert work_hist["mean_s"] * len(turns) == pytest.approx(
+        c["engine_loop_host_work_us"] / 1e6)
+    assert c["engine_loop_host_work_us"] > 0
+
+
+@pytest.mark.parametrize("where,part,phase", [
+    ("_megastep", 0, None),
+    ("_walk", 1, "loop_cpu_us_reap_host"),
+    ("step", 2, None),
+], ids=["a_sleep_in_the_megastep_call_is_device_wait",
+        "a_busy_loop_in_the_walk_is_host_work",
+        "a_sleep_after_the_step_returns_is_stall"])
+def test_fifty_milliseconds_land_where_they_belong(
+        warm_engine, monkeypatch, where, part, phase):
+    """50 ms put into one turn: asleep inside the runtime call, burning
+    CPU in the reap's host half, or asleep on the step's thread after
+    `engine.step` closed (before `_between_steps`)."""
+    turns, at = [], []  # `at`: which turn the 50 ms went into
+    inner = getattr(warm_engine, where)
+
+    def spend_once():
+        if not at:
+            at.append(len(turns))
+            _burn(0.05) if where == "_walk" else time.sleep(0.05)
+
+    def with_fifty_ms(*args):
+        if where != "step":
+            spend_once()
+            return inner(*args)
+        out = inner(*args)
+        spend_once()
+        return out
+
+    monkeypatch.setattr(warm_engine, where, with_fifty_ms)
+    _serve_turns(warm_engine, monkeypatch, turns)
+    parts = _parts(turns[at[0]])
+    assert parts[part] >= 45_000, parts
+    assert all(p < 45_000 for i, p in enumerate(parts) if i != part), parts
+    if phase:
+        assert turns[at[0]][phase] >= 45_000
+
+
+NEW_METRICS = {
+    "host_work_p95_ms": ("ms", "lower"),
+    "loop_host_work_share": ("%", "lower"),
+    "loop_device_wait_share": ("%", "higher"),
+    "loop_stalled_share": ("%", "lower"),
+}
+REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+
+
+def _load(*parts):
+    with open(os.path.join(REPO, *parts)) as f:
+        return json.load(f)
+
+
+@pytest.fixture(scope="module")
+def served_ctx(warm_engine):
+    """A synthetic `ctx` as `benchmarks/run.py` hands a reader: the
+    server's /metrics at a mark and after a served window."""
+    metrics = Metrics()
+    _serve(warm_engine, PROMPTS[:2], metrics)
+    marked = metrics.snapshot()
+    _serve(warm_engine, PROMPTS, metrics)
+    window = {name: {"p95_s": metrics.hist(name).window_percentile(3600, 95)}
+              for name in metrics.snapshot()["latency"]}
+    return {"marked": {"metrics": marked},
+            "collected": {"metrics": metrics.snapshot(), "window": window}}
+
+
+def _without_the_budget(ctx):
+    """The same documents from a program without this PR's series."""
+    def strip(series):
+        return {k: v for k, v in series.items()
+                if not k.startswith(("engine_loop_", "engine_host_work"))}
+
+    def strip_all(metrics):
+        return {section: strip(series) for section, series in metrics.items()}
+
+    return {"marked": {"metrics": strip_all(ctx["marked"]["metrics"])},
+            "collected": {
+                "metrics": strip_all(ctx["collected"]["metrics"]),
+                "window": strip(ctx["collected"]["window"])}}
+
+
+@pytest.mark.parametrize("name", sorted(NEW_METRICS))
+def test_budget_metric_is_a_data_file_on_a_reader_that_is_there(
+        name, served_ctx):
+    spec = _load("benchmarks", "layer_metrics", name + ".json")
+    # The reader is one the benchmark has, and its series are declared
+    # and reach /metrics on the served path.
+    readers.resolve(spec["reader"])
+    args = spec["args"]
+    series = ([args["histogram"]] if "histogram" in args else
+              [t["counter"] for t in args["numerator"] + args["denominator"]])
+    reached = served_ctx["collected"]["metrics"]
+    for one in series:
+        assert metric.is_declared(one), one
+        assert one in (set(metric.ENGINE_LOOP_COUNTERS.values())
+                       | set(metric.ENGINE_LOOP_HISTOGRAMS.values()))
+        assert one in reached["counters"] or one in reached["latency"], one
+    # Found by its name, not by its place: later PRs append metrics.
+    per_layer = _load("BENCHMARK.json")["per_layer"]
+    (entry,) = [m for m in per_layer if m["name"] == name]
+    unit, better = NEW_METRICS[name]
+    assert entry == {
+        "name": name, "unit": unit, "better": better,
+        "source": "program_span",
+        "layer": "paged engine (engine/paged.py)", "moves": "out_tok_s",
+    }
+    assert entry["layer"] in {m["layer"] for m in per_layer
+                              if m["name"] not in NEW_METRICS}
+    # A number on this program, nothing (and no raise) on the parent's.
+    value = readers.read(spec["reader"], args, served_ctx)
+    assert value is not None and 0 <= value < (100.01 if unit == "%" else 1e4)
+    assert readers.read(spec["reader"], args,
+                        _without_the_budget(served_ctx)) is None
+
+
+def test_the_three_shares_sum_to_a_hundred(served_ctx):
+    shares = []
+    for name in sorted(NEW_METRICS):
+        if NEW_METRICS[name][0] == "%":
+            spec = _load("benchmarks", "layer_metrics", name + ".json")
+            shares.append(readers.read(spec["reader"], spec["args"],
+                                       served_ctx))
+    assert len(shares) == 3
+    assert sum(shares) == pytest.approx(100.0, abs=1e-9)
 
 
 # ------------------------------------------------------ (d) jax-free edge

@@ -341,7 +341,7 @@ def test_staged_slots_are_served_every_iteration_in_stage_order(spec_tokens):
     out = eng.drain()
     assert [out[r] for r in rs] == expected_answers(
         make_config(), STAGED_TOGETHER)
-    _, observations = eng.pop_loop_stats()
+    observations = eng.pop_loop_stats()[1]
     assert observations["staged_iterations"] == [
         float(sum(chunks[: i + 1]) - 1) for i in range(4)
     ]

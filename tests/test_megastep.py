@@ -247,7 +247,7 @@ def test_engine_controller_tracks_admission_horizon(monkeypatch):
     assert one and set(one) <= {0, 1}    # an end within a chunk, and
     assert all(k == 1 for slack, k in backlog   # nowhere else
                if slack is not None and slack <= 1)
-    counts, _ = eng.pop_loop_stats()
+    counts = eng.pop_loop_stats()[0]
     assert counts["one_chunk_dispatches"] == len(one)
 
 

@@ -105,7 +105,7 @@ def run(eng, prompts, each_step=None):
     finals = eng.pop_final_tokens()
     assert [texts[r] for r in rids] == [
         eng.decode_tokens(finals[r]) for r in rids]
-    counts, _ = eng.pop_loop_stats()
+    counts = eng.pop_loop_stats()[0]
     return rids, [finals[r] for r in rids], counts
 
 
@@ -241,7 +241,7 @@ def test_a_session_turn_keeps_its_slot_until_its_reap():
         eng.step()
         gone += [r.rid for r in departing(eng).values()]
     assert gone and session not in gone
-    counts, _ = eng.pop_loop_stats()
+    counts = eng.pop_loop_stats()[0]
     assert counts["slots_handed_on"] == len(set(gone))
     assert eng.session_pin_stats()[0] == 1, "transcript published and pinned"
     assert not eng._session_reqs
@@ -390,7 +390,7 @@ def test_under_a_backlog_an_answer_overruns_less_than_a_chunk(
         ended = 0
         while eng._pending:  # the standing backlog
             ended += len(eng.step())
-        standing, _ = eng.pop_loop_stats()
+        standing = eng.pop_loop_stats()[0]
         while eng.has_work:
             eng.step()
         finals = eng.pop_final_tokens()
