@@ -57,7 +57,7 @@ from .common import (
     split_heads,
 )
 from .llama import rope
-from .moe import grouped_swiglu, route_sigmoid
+from .moe import grouped_swiglu, held_rows, route_sigmoid
 
 Params = Dict[str, Any]
 
@@ -230,7 +230,8 @@ def moe_mlp(h: jax.Array, mp: Params, cfg, live: jax.Array):
     with jax.named_scope("moe.experts"):
         y, sizes = grouped_swiglu(x, top_i, top_w, live.reshape(b * t),
                                   mp["wg"], mp["wu"], mp["wd"],
-                                  first=held[0] if held else None)
+                                  first=held[0] if held else None,
+                                  among=cfg.num_experts)
     with jax.named_scope("moe.shared"):
         y = y + swiglu(x, mp["shared"])
     return y.reshape(b, t, d), top_i.reshape(b, t, -1), sizes
@@ -240,9 +241,12 @@ def moe_mlp(h: jax.Array, mp: Params, cfg, live: jax.Array):
 # picks computed, experts reached, expert seats offered (experts held x
 # expert layers). A family that holds a share of a layer's experts names a
 # fourth after them, `moe_picks_held`, the picks that landed on the share
-# (`axk1.COUNTERS`; here every pick does). A family's tuple is the one
-# thing that says which: its forward hands it to `run_layers`, and
-# `registry.ModelFamily.counters` to the engine.
+# (`axk1.COUNTERS`; here every pick does), and may name two more after
+# it: `moe_passes_bounded`, the routed layers' passes whose products had
+# fewer rows to run over than picks (`moe.held_rows`), and
+# `moe_passes_compacted`, those of them whose held picks fit. A family's
+# tuple is the one thing that says which: its forward hands it to
+# `run_layers`, and `registry.ModelFamily.counters` to the engine.
 COUNTERS = ("moe_picks", "moe_experts_reached", "moe_expert_seats")
 
 
@@ -259,6 +263,7 @@ def run_layers(params: Params, cfg, x: jax.Array, live: jax.Array,
     `counters` is the family's tuple of count names (above)."""
     eps = cfg.rms_norm_eps
     share = "moe_picks_held" in counters
+    bounds = "moe_passes_bounded" in counters
     routing = []
     counts = jnp.zeros((len(counters),), jnp.int32)
     for layer, lp in enumerate(params["layers"]):
@@ -272,11 +277,16 @@ def run_layers(params: Params, cfg, x: jax.Array, live: jax.Array,
         if "moe" in lp:
             y, top_i, sizes = moe_mlp(h, lp["moe"], cfg, live)
             routing.append(top_i)
-            seen = [jnp.sum(sizes), jnp.sum(sizes > 0).astype(jnp.int32),
+            held = jnp.sum(sizes)
+            seen = [held, jnp.sum(sizes > 0).astype(jnp.int32),
                     jnp.asarray(sizes.shape[0], jnp.int32)]
             if share:
                 seen = [jnp.sum(live).astype(jnp.int32) * top_i.shape[-1],
-                        *seen[1:], seen[0]]
+                        *seen[1:], held]
+            if bounds:
+                fit = held_rows(top_i.size, sizes.shape[0], cfg.num_experts)
+                bounded = jnp.asarray(fit < top_i.size, jnp.int32)
+                seen += [bounded, bounded * (held <= fit)]
             counts = counts + jnp.stack(seen)
         else:
             with jax.named_scope("mlp.dense"):

@@ -30,11 +30,15 @@ count) says which this process holds: the expert stacks are [count, ..],
 the router keeps all `num_experts` outputs and its picks, and the routed
 layer computes its own experts' part of the result plus the shared
 expert's (`afmoe.moe_mlp`, `moe.grouped_swiglu`). What the absent experts
-would add is their chips' to add: no code here stands in for them.
+would add is their chips' to add: no code here stands in for them. A
+share under a quarter of the experts has its grouped products run over a
+prefix of a pass's sorted picks, where the held ones are, and over all of
+them in a pass whose held picks outnumber it (`moe.held_rows`).
 
 The trunk is afmoe's (`afmoe.run_layers`, `afmoe.head`): a list of
-per-layer trees, unrolled, the same `aux` record (`counts` has a fourth
-entry, the picks that landed on the share held).
+per-layer trees, unrolled, the same `aux` record (`counts` has three more
+entries: the picks that landed on the share held, the routed layers'
+passes whose products had such a prefix, and those that fit it).
 """
 
 from __future__ import annotations
@@ -52,8 +56,10 @@ from .common import KVCache, causal_window_mask
 Params = Dict[str, Any]
 
 # afmoe's three counts and, a chip holding a share of a layer's experts,
-# the picks that landed on the share.
-COUNTERS = afmoe.COUNTERS + ("moe_picks_held",)
+# the picks that landed on the share, the routed layers' passes whose
+# products were bounded to a prefix of the rows, and those that fit it.
+COUNTERS = afmoe.COUNTERS + ("moe_picks_held", "moe_passes_bounded",
+                             "moe_passes_compacted")
 
 
 @dataclasses.dataclass(frozen=True)
@@ -189,7 +195,7 @@ def forward(
     rows: Optional[jax.Array] = None,
 ):
     """Run the decoder; returns (logits [B, T, V] float32, updated cache),
-    and with `aux` a third value, {"counts": int32 [4] (`COUNTERS`),
+    and with `aux` a third value, {"counts": int32 [6] (`COUNTERS`),
     "routing": int32 [Le, B, T, k]}. Contract as afmoe.forward."""
     t = input_ids.shape[1]
     offset, q_slots, positions, live = batch_slots(

@@ -212,7 +212,7 @@ def route_sigmoid(x: jax.Array, wr: jax.Array, bias: jax.Array, k: int,
 
 def grouped_swiglu(x: jax.Array, top_i: jax.Array, top_w: jax.Array,
                    live: jax.Array, wg: jax.Array, wu: jax.Array,
-                   wd: jax.Array, first=None):
+                   wd: jax.Array, first=None, among=None):
     """Routed SwiGLU experts without capacity and without drops:
     x [S, D], top_i / top_w [S, k], live [S] bool -> (y [S, D],
     group sizes [E] int32).
@@ -238,6 +238,15 @@ def grouped_swiglu(x: jax.Array, top_i: jax.Array, top_w: jax.Array,
     is then dropped like a token that is not live, and `y` is this share's
     part of the layer's result, to which the absent experts' chips would
     add theirs. Group sizes are the held experts'.
+
+    `among` (with `first`) is how many experts the router chose among.
+    The held picks sort first, so where the share is small the products
+    run over a static prefix of the sorted rows alone (`held_rows`), and
+    over all of them, under a `lax.cond`, in a pass whose held picks
+    outnumber that prefix: a bound on the rows, never a capacity, and no
+    pick is dropped. Where the prefix is every row (`first` None, or a
+    share of a quarter and more) there is no `cond` and the program is
+    the one without `among`.
     """
     def experts(xs, sizes):
         gate = jax.lax.ragged_dot(xs, wg.astype(x.dtype), sizes)
@@ -245,37 +254,71 @@ def grouped_swiglu(x: jax.Array, top_i: jax.Array, top_w: jax.Array,
         return jax.lax.ragged_dot(jax.nn.silu(gate) * up,
                                   wd.astype(x.dtype), sizes)
 
-    return _grouped(x, top_i, top_w, live, wg.shape[0], first, experts)
+    return _grouped(x, top_i, top_w, live, wg.shape[0], first, among,
+                    experts)
 
 
 def grouped_relu2(x: jax.Array, top_i: jax.Array, top_w: jax.Array,
                   live: jax.Array, wu: jax.Array, wd: jax.Array,
-                  first=None):
+                  first=None, among=None):
     """`grouped_swiglu` for routed experts of TWO projections,
     `W_down relu(W_up x)^2` (wu [E, D, M], wd [E, M, D]; models/
-    nemotron_h.py): the same sort, grouped products, `first` and group
-    sizes."""
+    nemotron_h.py): the same sort, grouped products, `first`, `among` and
+    group sizes."""
     def experts(xs, sizes):
         up = jax.lax.ragged_dot(xs, wu.astype(x.dtype), sizes)
         return jax.lax.ragged_dot(jnp.square(jax.nn.relu(up)),
                                   wd.astype(x.dtype), sizes)
 
-    return _grouped(x, top_i, top_w, live, wu.shape[0], first, experts)
+    return _grouped(x, top_i, top_w, live, wu.shape[0], first, among,
+                    experts)
 
 
-def _grouped(x, top_i, top_w, live, e: int, first, experts):
+# The factor of `held_rows` over the held picks' mean, and the multiple of
+# rows its prefix is rounded up to (a bfloat16 tile's sublanes).
+HELD_ROWS_FACTOR = 4
+HELD_ROWS_MULTIPLE = 16
+
+
+def held_rows(rows: int, held: int, among: int) -> int:
+    """How many of a pass's `rows` sorted picks the grouped products of a
+    share of `held` of the router's `among` experts run over: four times
+    what a fair router sends to the share, rounded up to the kernel's row
+    multiple, and never more than `rows`. At 32 lanes x 8 picks and 12 of
+    192 held, 64 of 256 rows against 16 +- 4 held picks: a bound twelve
+    deviations out, and past it the products run over every row."""
+    fit = -(-HELD_ROWS_FACTOR * rows * held // among)
+    fit = -(-fit // HELD_ROWS_MULTIPLE) * HELD_ROWS_MULTIPLE
+    return min(fit, rows)
+
+
+def _grouped(x, top_i, top_w, live, e: int, first, among, experts):
     """The routed layer around its experts' products (`grouped_swiglu`'s
     contract): `experts(xs, sizes)` takes the picks' rows sorted by expert
-    [S*k, D] and the `e` held experts' group sizes."""
+    [S*k, D], or a prefix of them that holds every group, and the `e` held
+    experts' group sizes."""
     s, k = top_i.shape
     here = live[:, None]
+    fit = s * k
     if first is not None:
         top_i = top_i - first
         here = here & (top_i >= 0) & (top_i < e)
+        if among is not None:
+            fit = held_rows(s * k, e, among)
     expert = jnp.where(here, top_i, e).reshape(s * k)
     order = jnp.argsort(expert, stable=True)
     sizes = jnp.zeros((e + 1,), jnp.int32).at[expert].add(1)[:e]
-    out = experts(x[order // k], sizes)                      # [S*k, D]
+
+    def over_all():
+        return experts(x[order // k], sizes)                 # [S*k, D]
+
+    def over_prefix():
+        return jnp.pad(experts(x[order[:fit] // k], sizes),
+                       [(0, s * k - fit), (0, 0)])
+
+    # Every group lies in the first `fit` rows, or all rows are run.
+    out = (over_all() if fit == s * k else
+           jax.lax.cond(jnp.sum(sizes) <= fit, over_prefix, over_all))
     # Rows past the last group belong to no expert; whatever the kernel
     # left there is dropped, not scaled by a zero weight.
     w = top_w.reshape(s * k)[order]

@@ -264,7 +264,8 @@ def moe_mlp(h: jax.Array, mp: Params, cfg, live: jax.Array):
         wide = jnp.pad(x, [(0, 0), (0, mp["wu"].shape[1] - d)])
         y, sizes = grouped_relu2(wide, top_i, top_w, live.reshape(b * t),
                                  mp["wu"], mp["wd"],
-                                 first=held[0] if held else None)
+                                 first=held[0] if held else None,
+                                 among=cfg.num_experts)
         y = y[:, :d]
     with jax.named_scope("moe.shared"):
         y = y + relu2(x, mp["shared"])

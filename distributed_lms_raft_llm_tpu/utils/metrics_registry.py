@@ -616,6 +616,21 @@ MOE_PICKS_HELD = counter(
     "(models/axk1.py `experts_held`; held / picks is the share of the "
     "routed work this chip does, 12 / 192 where the router is fair to it)",
 )
+MOE_PASSES_BOUNDED = counter(
+    "moe_passes_bounded",
+    "forward passes of a routed layer whose grouped products had fewer "
+    "rows to run over than the pass had picks: a share of under a quarter "
+    "of the layer's experts, whose held picks sort into a static prefix "
+    "of the rows (models/moe.py `held_rows`); summed over expert layers, "
+    "only a family that holds such a share counts",
+)
+MOE_PASSES_COMPACTED = counter(
+    "moe_passes_compacted",
+    "of moe_passes_bounded, the passes whose held picks fit the prefix, "
+    "so that the products ran over it alone; the others ran over every "
+    "row, and no pick is dropped either way (compacted / bounded is how "
+    "often the bound engages: 1 where the router is fair to the share)",
+)
 ENGINE_TOKENS_PAST_WINDOW = counter(
     "engine_tokens_past_window",
     "tokens emitted at a position at or past the model's sliding_window, "
@@ -762,6 +777,8 @@ ENGINE_LOOP_COUNTERS: Dict[str, str] = {
     "moe_experts_reached": MOE_EXPERTS_REACHED,
     "moe_expert_seats": MOE_EXPERT_SEATS,
     "moe_picks_held": MOE_PICKS_HELD,
+    "moe_passes_bounded": MOE_PASSES_BOUNDED,
+    "moe_passes_compacted": MOE_PASSES_COMPACTED,
     "tokens_past_window": ENGINE_TOKENS_PAST_WINDOW,
     "state_snapshots_taken": ENGINE_STATE_SNAPSHOTS_TAKEN,
     "state_snapshots_restored": ENGINE_STATE_SNAPSHOTS_RESTORED,

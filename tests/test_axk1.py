@@ -7,11 +7,15 @@ which imports nothing of the program) on seeded weights: whole-sequence
 logits, then the served prefill and the absorbed decode through the latent
 cache. A chip's share of a layer's experts is tied to the model: the parts
 all the shares give, with the shared expert counted once, add up to the
-uncut layer. The cache and every prefix block hold 1,152 B a token and
+uncut layer; a share small enough for its grouped products to run over a
+prefix of the sorted picks gives, to the bit, what the products over all
+rows give, in the branch that takes the prefix and in the one that does
+not. The cache and every prefix block hold 1,152 B a token and
 layer at the published sizes; a block exported, spliced and attended gives
 the logits of a fresh prefill; the paged engine serves the family through
 staged admission and the prefix cache and counts the picks that land on
-the share held; both engines refuse `ep` and `tp` for it.
+the share held and the passes that took the prefix; both engines refuse
+`ep` and `tp` for it.
 """
 
 import dataclasses
@@ -32,7 +36,7 @@ from distributed_lms_raft_llm_tpu.engine import (
     TutoringEngine,
 )
 from distributed_lms_raft_llm_tpu.engine import paged
-from distributed_lms_raft_llm_tpu.models import afmoe, mla, registry
+from distributed_lms_raft_llm_tpu.models import afmoe, axk1, mla, moe, registry
 from distributed_lms_raft_llm_tpu.ops import attention as attention_ops
 from distributed_lms_raft_llm_tpu.utils import metrics_registry as metric
 
@@ -88,7 +92,7 @@ def test_forward_matches_the_reference_logits(config, model, seed):
                                  aux=True)
     np.testing.assert_allclose(got[0], want[0], rtol=2e-4, atol=2e-5)
     assert (_chosen(aux["routing"][:, 0], 32) == np.asarray(want[3])).all()
-    assert aux["counts"].shape == (len(family.counters),) == (4,)
+    assert aux["counts"].shape == (len(family.counters),) == (6,)
 
 
 def _through_the_cache(family, cfg, params, ids, n_prompt, bucket, width):
@@ -304,6 +308,105 @@ def test_idle_lanes_reach_no_expert_and_land_no_pick(model):
     assert none["moe_experts_reached"] == 0
 
 
+# ------------------------ a small share's products over the held picks alone
+
+
+def _small_share(skewed):
+    """16 tokens x 4 picks over 32 experts of which 2 are held: 64 rows,
+    of which `held_rows` keeps 16; token 3 is an idle lane. A fair router
+    lands a handful of picks on the share; the skewed one sends every
+    token's first pick there (15 live ones and more: past the prefix)."""
+    ks = jax.random.split(jax.random.key(43), 5)
+    x = jax.random.normal(ks[0], (16, 32), jnp.float32)
+    wr = jax.random.normal(ks[1], (32, 32), jnp.float32)
+    if skewed:
+        wr = wr.at[:, 1].set(100.0 * jnp.sign(x.sum(0)))
+        x = x + 0.5 * jnp.sign(x.sum(0))[None]
+    top_i, top_w = moe.route_sigmoid(x, wr, None, 4, True, 2.5)
+    stacks = [0.2 * jax.random.normal(k, shape, jnp.float32) for k, shape
+              in zip(ks[2:], ((2, 32, 16), (2, 32, 16), (2, 16, 32)))]
+    return x, top_i, top_w, jnp.ones((16,), bool).at[3].set(False), stacks
+
+
+@pytest.mark.parametrize("experts", ["swiglu", "relu2"])
+@pytest.mark.parametrize("skewed", [False, True], ids=["fair", "skewed"])
+def test_a_small_shares_products_over_a_prefix_equal_those_over_all_rows(
+        skewed, experts):
+    """The bound is on the rows, not a capacity: where the held picks fit
+    the prefix the products run over it alone, where they do not over all
+    rows, and either way the output and the group sizes are, to the bit,
+    those of the call that was not told how many experts there are (so
+    has no bound) and those of a per-pick loop to rounding."""
+    x, top_i, top_w, live, (wg, wu, wd) = _small_share(skewed)
+    if experts == "swiglu":
+        call = partial(moe.grouped_swiglu, x, top_i, top_w, live, wg, wu, wd,
+                       first=0)
+        one = lambda t, e: (jax.nn.silu(x[t] @ wg[e]) * (x[t] @ wu[e])) @ wd[e]
+    else:
+        call = partial(moe.grouped_relu2, x, top_i, top_w, live, wu, wd,
+                       first=0)
+        one = lambda t, e: jnp.square(jax.nn.relu(x[t] @ wu[e])) @ wd[e]
+    fit = moe.held_rows(64, 2, 32)
+    assert fit == 16
+    y, sizes = call(among=32)
+    y_all, sizes_all = call()
+    assert ("cond" in str(jax.make_jaxpr(partial(call, among=32))())
+            and "cond" not in str(jax.make_jaxpr(call)()))
+    assert (int(sizes.sum()) > fit) == skewed     # which branch was taken
+    np.testing.assert_array_equal(sizes, sizes_all)
+    np.testing.assert_array_equal(y, y_all)
+    held = np.asarray((top_i < 2) & live[:, None])
+    np.testing.assert_array_equal(
+        sizes, np.bincount(np.asarray(top_i)[held], minlength=2))
+    want = np.zeros((16, 32), np.float32)
+    for t, j in zip(*np.nonzero(held)):
+        want[t] += float(top_w[t, j]) * np.asarray(one(t, int(top_i[t, j])))
+    np.testing.assert_allclose(y, want, rtol=2e-4, atol=2e-5)
+    assert not held[3].any() and not np.asarray(y[3]).any()  # the idle lane
+
+
+@pytest.mark.parametrize("first,among,held", [
+    (None, 32, 32), (0, 32, 16), (8, 32, 8), (0, None, 2)],
+    ids=["all_held", "a_half", "a_quarter", "router_unknown"])
+def test_a_share_of_a_quarter_and_more_has_no_cond_in_its_program(
+        first, among, held):
+    """The programs that must not change: a layer that holds all its
+    experts, or a share whose prefix is every row, lowers to the text it
+    has without `among`, with no conditional in it."""
+    ks = jax.random.split(jax.random.key(1), 4)
+    x = jax.random.normal(ks[0], (8, 32), jnp.float32)
+    top_i = jnp.tile(jnp.arange(4, dtype=jnp.int32)[None] * 8, (8, 1))
+    top_w = jnp.full((8, 4), 0.25, jnp.float32)
+    wg, wu, wd = (0.2 * jax.random.normal(k, shape) for k, shape in zip(
+        ks[1:], ((held, 32, 16), (held, 32, 16), (held, 16, 32))))
+    live = jnp.ones((8,), bool)
+    if among is not None:
+        assert moe.held_rows(32, held, among) == 32
+
+    def text(fn, *stacks, **kw):
+        return jax.jit(partial(fn, first=first, **kw)).lower(
+            x, top_i, top_w, live, *stacks).as_text()
+
+    for fn, stacks in ((moe.grouped_swiglu, (wg, wu, wd)),
+                       (moe.grouped_relu2, (wu, wd))):
+        got = text(fn, *stacks, among=among)
+        assert got == text(fn, *stacks)
+        assert "stablehlo.case" not in got and "stablehlo.if" not in got
+        assert "cond" not in str(jax.make_jaxpr(
+            partial(fn, first=first, among=among))(
+                x, top_i, top_w, live, *stacks))
+
+
+def test_the_prefix_is_four_times_a_fair_routers_rows_in_whole_tiles():
+    assert moe.held_rows(256, 12, 192) == 64      # the cell: 32 lanes x 8
+    assert moe.held_rows(512, 12, 192) == 128
+    assert moe.held_rows(8 * 24, 12, 192) == 48   # a bucket of 24 tokens
+    assert moe.held_rows(8 * 5, 12, 192) == 16    # 10 rows, rounded up
+    assert moe.held_rows(8, 12, 192) == 8         # never above the rows
+    assert moe.held_rows(96, 64, 128) == 96       # nemotron3-nano: a half
+    assert moe.held_rows(128, 128, 128) == 128
+
+
 # ------------------------------------------------ the cache, by its bytes
 
 
@@ -363,22 +466,19 @@ def test_a_block_exported_spliced_and_attended_gives_a_fresh_prefills_logits(
 
 
 def _econf(**kw):
+    kw.setdefault("model", "axk1-tiny")
     return EngineConfig(
-        model="axk1-tiny",
         sampling=SamplingParams.greedy(max_new_tokens=MAX_NEW),
         length_buckets=(16, 48), batch_buckets=(1, 2, 4), dtype=jnp.float32,
         param_dtype=jnp.float32, **kw,
     )
 
 
-@pytest.fixture(scope="module")
-def served():
-    """One engine with a prefix cache serves the prompts twice: the first
-    round prefills the notes in the scan (staged), the second splices
-    them from the radix tree."""
-    eng = PagedEngine(_econf(), slots=4, chunk=2, megastep=2, megastep_max=4,
-                      prefix_cache=True, prefix_cache_blocks=64,
-                      prefix_block_tokens=4, prefill_chunk_tokens=8)
+def _serve(**kw):
+    eng = PagedEngine(_econf(**kw), slots=4, chunk=2, megastep=2,
+                      megastep_max=4, prefix_cache=True,
+                      prefix_cache_blocks=64, prefix_block_tokens=4,
+                      prefill_chunk_tokens=8)
     rounds = []
     for _ in range(2):
         rids = [eng.submit(p) for p in PROMPTS]
@@ -386,6 +486,27 @@ def served():
         rounds.append(([out[r] for r in rids], eng.pop_prefix_stats(),
                        eng.pop_loop_stats()[0]))
     return eng, rounds
+
+
+@pytest.fixture(scope="module")
+def served():
+    """One engine with a prefix cache serves the prompts twice: the first
+    round prefills the notes in the scan (staged), the second splices
+    them from the radix tree."""
+    return _serve()
+
+
+@pytest.fixture(scope="module")
+def served_a_sixteenth():
+    """`served` by a chip that holds 2 of the 32 experts, a share small
+    enough for its products to run over a prefix of the rows (the decode's
+    4 lanes x 4 picks and the chunk's 8 tokens x 4: 16 of 16 and 16 of 32
+    rows): a preset of this test's, no option of the program's."""
+    with pytest.MonkeyPatch.context() as patch:
+        patch.setitem(registry.PRESETS, "axk1-tiny-2of32", (
+            registry.AXK1_FAMILY,
+            partial(axk1.AxK1Config.tiny, experts_held=(0, 2))))
+        return _serve(model="axk1-tiny-2of32")
 
 
 @pytest.fixture(scope="module")
@@ -427,6 +548,26 @@ def test_engine_counts_the_picks_that_land_on_the_share_held(served):
     assert "tokens_past_window" not in counts
     # A spliced token has no forward pass and lands no pick.
     assert rounds[1][2]["moe_picks"] < counts["moe_picks"]
+    # A quarter of the experts: the products run over every row as before.
+    assert counts["moe_passes_bounded"] == counts["moe_passes_compacted"] == 0
+
+
+def test_engine_counts_the_passes_whose_products_ran_over_the_prefix(
+        served_a_sixteenth):
+    """A sixteenth of the experts: the prefill chunk's passes (32 rows, a
+    prefix of 16) are bounded, the decode's (16 rows, all of them) are
+    not, and under this fair router every bounded pass fits its prefix."""
+    eng, rounds = served_a_sixteenth
+    counts = rounds[0][2]
+    assert metric.is_declared(metric.ENGINE_LOOP_COUNTERS["moe_passes_bounded"])
+    le = eng.cfg.num_layers - eng.cfg.num_dense_layers
+    assert eng.cfg.experts_held == (0, 2)
+    assert counts["moe_expert_seats"] % (2 * le) == 0
+    passes = counts["moe_expert_seats"] // (2 * le)
+    assert 0 < counts["moe_passes_bounded"] < passes * le
+    assert counts["moe_passes_bounded"] % le == 0
+    assert counts["moe_passes_compacted"] == counts["moe_passes_bounded"]
+    assert 0 < counts["moe_picks_held"] < counts["moe_picks"] // 4
 
 
 def test_scopes_are_in_the_megastep(served):
@@ -518,6 +659,7 @@ def test_the_benchmark_names_the_configuration_the_cell_and_its_metrics():
             for c in traffic["courses"]] == [(152, 50), (104, 25),
                                              (2304, 25)]
     for name, layer in (("moe_held_picks_share", "routed experts"),
+                        ("moe_compacted_share", "routed experts"),
                         ("mla_decode_dev_us_per_tok", "latent attention"),
                         ("mla_decode_roofline", "latent attention")):
         metric_ = _named(bench["per_layer"], name)

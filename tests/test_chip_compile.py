@@ -353,6 +353,10 @@ def test_ax_k1_cut_megastep_runs_absorbed_over_the_latent_cache(one_chip):
     assert ma.temp_size_in_bytes < 2 * 1024**3
     text = mega.as_text()
     assert "ragged-dot" in text and "mla_decode" in text
+    # 12 of 192 experts held: the grouped products run over the first 64
+    # of a pass's 256 sorted picks, and over all of them as the fallback.
+    for rows in (64, 256):
+        assert re.search(rf"ragged-dot\S* = bf16\[{rows},7168\]", text)
     # Keys or values of the 64 heads over the cache's width: [.., 64,
     # 2688, 128 | 192 | 256] or its transpose, for one lane or for all.
     expanded = re.findall(
