@@ -219,7 +219,9 @@ def moe_mlp(h: jax.Array, mp: Params, cfg, live: jax.Array):
     that share of the layer's `num_experts` (the stacks are [count, ..]):
     it routes over all of them and computes its own experts' part plus
     the shared expert's (`moe.grouped_swiglu`). A tree without `br` has
-    no balancing bias."""
+    no balancing bias. Stacks held wider than D (`models/kimi_linear.py`
+    `pad_experts`: zero rows and columns up to whole tiles of the grouped
+    product) meet zero columns of the input, and the output is cut back."""
     b, t, d = h.shape
     x = h.reshape(b * t, d)
     held = getattr(cfg, "experts_held", None)
@@ -228,10 +230,13 @@ def moe_mlp(h: jax.Array, mp: Params, cfg, live: jax.Array):
             x, mp["wr"], mp.get("br"), cfg.num_experts_per_tok,
             cfg.route_norm, cfg.route_scale)
     with jax.named_scope("moe.experts"):
-        y, sizes = grouped_swiglu(x, top_i, top_w, live.reshape(b * t),
-                                  mp["wg"], mp["wu"], mp["wd"],
-                                  first=held[0] if held else None,
-                                  among=cfg.num_experts)
+        wide = mp["wg"].shape[1]
+        y, sizes = grouped_swiglu(
+            x if wide == d else jnp.pad(x, [(0, 0), (0, wide - d)]),
+            top_i, top_w, live.reshape(b * t), mp["wg"], mp["wu"], mp["wd"],
+            first=held[0] if held else None, among=cfg.num_experts)
+        if wide != d:
+            y = y[:, :d]
     with jax.named_scope("moe.shared"):
         y = y + swiglu(x, mp["shared"])
     return y.reshape(b, t, d), top_i.reshape(b, t, -1), sizes

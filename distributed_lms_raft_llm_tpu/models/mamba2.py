@@ -95,11 +95,13 @@ def gated_norm(y: jax.Array, z: jax.Array, scale: jax.Array, groups: int,
     return yg.reshape(y.shape) * scale.astype(jnp.float32)
 
 
-def _conv(xbc: jax.Array, window: jax.Array, mp: Params, live: jax.Array):
+def causal_conv(xbc: jax.Array, window: jax.Array, mp: Params,
+                live: jax.Array):
     """The causal depthwise convolution of a chunk that continues a
     window: xbc [B, T, C], window [B, K-1, C] (the inputs before it), live
     [B, T] -> (silu(conv + bias) [B, T, C] float32, the window that ends
-    at each row's last live position [B, K-1, C])."""
+    at each row's last live position [B, K-1, C]). A tree without `conv_b`
+    has no bias (models/kda.py)."""
     b, t, _ = xbc.shape
     k1 = window.shape[1]
     xbc = jnp.where(live[..., None], xbc, jnp.zeros((), xbc.dtype))
@@ -107,7 +109,9 @@ def _conv(xbc: jax.Array, window: jax.Array, mp: Params, live: jax.Array):
     w = mp["conv_w"].astype(jnp.float32)                        # [K, C]
     out = sum(seq[:, j:j + t].astype(jnp.float32) * w[j]
               for j in range(k1 + 1))
-    out = jax.nn.silu(out + mp["conv_b"].astype(jnp.float32))
+    if "conv_b" in mp:
+        out = out + mp["conv_b"].astype(jnp.float32)
+    out = jax.nn.silu(out)
     # seq[last + 1 .. last + K-1] are the K-1 inputs up to live position
     # `last` (-1: none was live, and the window stands).
     ends = jnp.max(jnp.where(live, jnp.arange(1, t + 1), 0), axis=1)
@@ -189,7 +193,7 @@ def mixer(u: jax.Array, mp: Params, cfg, live: jax.Array,
     # Batch element i's row of layer `layer`: row i, or the row named.
     at = layer if rows is None else (layer, rows)
     with jax.named_scope("ssm.conv"):
-        xbc, window = _conv(xbc, conv[at], mp, live)
+        xbc, window = causal_conv(xbc, conv[at], mp, live)
         conv = conv.at[at].set(window)
     x = xbc[..., :di].reshape(b, t, h, p)
     bm = xbc[..., di:di + g * n].reshape(b, t, g, n)

@@ -45,6 +45,13 @@ platform picks (`jax.lax.platform_dependent`), no flag does. The program's tree 
 halves apart, `wuk` and `wuv` [kv_lora_rank, heads, nope | v]: sliced out
 of one matrix inside the step they would be copied every call.
 
+Two things are read off the configuration's own keys, for the two families
+that have this attention (`models/axk1.py`, `models/kimi_linear.py`):
+`q_lora_rank` None is ONE query projection `wq` with no query norm, and
+`mla_use_nope` true is NO rotation at all: the 64 "rope" columns are then a
+plain key part all heads share, the scale is `(nope + rope) ** -0.5`
+(`rope_scaling` None), and cache, absorbed products and kernel are the same.
+
 Rotary embedding is YaRN over the rope dimensions (`yarn_inv_freq`, on
 `llama.rope`'s rotate-half machinery); the softmax scale carries YaRN's
 `mscale_all_dim` squared, and the tables' own factor `mscale /
@@ -105,9 +112,20 @@ def yarn_inv_freq(dim: int, theta: float, yarn: Yarn) -> np.ndarray:
     return (inter * ramp + extra * (1.0 - ramp)).astype(np.float32)
 
 
+def rotates(cfg) -> bool:
+    """Whether the shared key part and its query part are rotated: not
+    where the configuration says `mla_use_nope` (Kimi-Linear: the 64 "rope"
+    columns are a plain shared key part, and other layers carry the
+    order)."""
+    return not getattr(cfg, "mla_use_nope", False)
+
+
 def softmax_scale(cfg) -> float:
+    scale = (cfg.qk_nope_head_dim + cfg.qk_rope_head_dim) ** -0.5
+    if cfg.rope_scaling is None:
+        return scale
     m = yarn_mscale(cfg.rope_scaling.factor, cfg.rope_scaling.mscale_all_dim)
-    return (cfg.qk_nope_head_dim + cfg.qk_rope_head_dim) ** -0.5 * m * m
+    return scale * m * m
 
 
 def init_cache(num_layers: int, batch: int, max_len: int, cfg,
@@ -124,13 +142,16 @@ def init_cache(num_layers: int, batch: int, max_len: int, cfg,
 
 def init_params(keys, cfg, normal, ones) -> Params:
     """One layer's attention tree from six keys; `normal(key, *shape)`
-    and `ones(*shape)` are the family's draws."""
+    and `ones(*shape)` are the family's draws. `q_lora_rank` None: one
+    query projection `wq` and no query norm."""
     d, h = cfg.hidden_size, cfg.num_heads
     dn, dr, dv = cfg.qk_nope_head_dim, cfg.qk_rope_head_dim, cfg.v_head_dim
     qr, kr = cfg.q_lora_rank, cfg.kv_lora_rank
+    query = ({"wq": normal(keys[0], d, h * (dn + dr))} if qr is None else
+             {"wqa": normal(keys[0], d, qr), "qn": {"scale": ones(qr)},
+              "wqb": normal(keys[1], qr, h * (dn + dr))})
     return {
-        "wqa": normal(keys[0], d, qr), "qn": {"scale": ones(qr)},
-        "wqb": normal(keys[1], qr, h * (dn + dr)),
+        **query,
         "wkva": normal(keys[2], d, kr + dr), "kvn": {"scale": ones(kr)},
         "wuk": normal(keys[3], kr, h, dn), "wuv": normal(keys[4], kr, h, dv),
         "wo": normal(keys[5], h * dv, d),
@@ -154,17 +175,25 @@ def attention(h: jax.Array, ap: Params, cfg, layer: int,
     b, t, _ = h.shape
     nh, eps = cfg.num_heads, cfg.rms_norm_eps
     dn, dr, kr = cfg.qk_nope_head_dim, cfg.qk_rope_head_dim, cfg.kv_lora_rank
-    yarn = cfg.rope_scaling
-    inv_freq = yarn_inv_freq(dr, cfg.rope_theta, yarn)
-    table = yarn_mscale(yarn.factor, yarn.mscale) / yarn_mscale(
-        yarn.factor, yarn.mscale_all_dim)
+    if rotates(cfg):
+        yarn = cfg.rope_scaling
+        inv_freq = yarn_inv_freq(dr, cfg.rope_theta, yarn)
+        table = yarn_mscale(yarn.factor, yarn.mscale) / yarn_mscale(
+            yarn.factor, yarn.mscale_all_dim)
 
-    def rotate(x):  # [B, H, T, rope]
-        return rope(x, positions, cfg.rope_theta, inv_freq=inv_freq,
-                    table_scale=table)
+        def rotate(x):  # [B, H, T, rope]
+            return rope(x, positions, cfg.rope_theta, inv_freq=inv_freq,
+                        table_scale=table)
+    else:
+        def rotate(x):
+            return x
 
-    c_q = rms_norm(dense(h, ap["wqa"]), ap["qn"]["scale"], eps)
-    q = dense(c_q, ap["wqb"]).reshape(b, t, nh, dn + dr).transpose(0, 2, 1, 3)
+    if cfg.q_lora_rank is None:
+        q = dense(h, ap["wq"])
+    else:
+        c_q = rms_norm(dense(h, ap["wqa"]), ap["qn"]["scale"], eps)
+        q = dense(c_q, ap["wqb"])
+    q = q.reshape(b, t, nh, dn + dr).transpose(0, 2, 1, 3)
     q_nope, q_r = q[..., :dn], rotate(q[..., dn:])
     kva = dense(h, ap["wkva"])
     c_kv = rms_norm(kva[..., :kr], ap["kvn"]["scale"], eps)   # [B, T, kr]

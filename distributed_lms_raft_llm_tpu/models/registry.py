@@ -29,14 +29,25 @@ home-made GPT-2 with routed experts, Arcee's afmoe (Trinity-Mini), SK
 Telecom's axk1 (A.X-K1: latent attention, a share of a layer's experts) and
 NVIDIA's nemotron_h (Nemotron-3-Nano: Mamba-2 blocks whose recurrent state
 lives in the cache beside one attention block's keys and values, relu^2
-experts).
+experts) and Moonshot's kimi_linear (Kimi-Linear: Kimi Delta Attention
+layers whose matrix state lives in the cache beside the latent plane of its
+MLA layers: a latent cache AND a recurrent state).
 """
 
 from __future__ import annotations
 
 from typing import Any, Callable, NamedTuple, Tuple
 
-from . import afmoe, axk1, convert, gpt2, llama, moe, nemotron_h
+from . import (
+    afmoe,
+    axk1,
+    convert,
+    gpt2,
+    kimi_linear,
+    llama,
+    moe,
+    nemotron_h,
+)
 
 
 class ModelFamily(NamedTuple):
@@ -59,7 +70,7 @@ class ModelFamily(NamedTuple):
     # to shard, and the engines refuse tp > 1.
     latent_cache: bool = False
     # The cache carries a recurrent state (`KVCache.ssm`, `.conv`:
-    # models/mamba2.py) that a forward pass MOVES. The engines refuse
+    # models/mamba2.py, models/kda.py) that a forward pass MOVES. The engines refuse
     # spec_tokens > 0 (a verify window cannot roll the state back past a
     # rejected draft) and tp > 1 (the state's heads are not sharded) for
     # such a family, and the paged engine resets a slot's state at staging
@@ -94,6 +105,11 @@ NEMOTRON_H_FAMILY = ModelFamily(
     nemotron_h.init_cache, nemotron_h.params_from_hf, routed=True,
     counters=nemotron_h.COUNTERS, recurrent_state=True,
 )
+KIMI_LINEAR_FAMILY = ModelFamily(
+    "kimi_linear", kimi_linear.init_params, kimi_linear.forward,
+    kimi_linear.init_cache, kimi_linear.params_from_hf, routed=True,
+    counters=kimi_linear.COUNTERS, latent_cache=True, recurrent_state=True,
+)
 
 # preset -> (family, config factory)
 PRESETS = {
@@ -117,6 +133,11 @@ PRESETS = {
     "nemotron3-nano-9l-64of128": (
         NEMOTRON_H_FAMILY, nemotron_h.NemotronHConfig.nemotron3_nano_9l_share),
     "nemotronh-tiny": (NEMOTRON_H_FAMILY, nemotron_h.NemotronHConfig.tiny),
+    "kimi-linear": (KIMI_LINEAR_FAMILY,
+                    kimi_linear.KimiLinearConfig.kimi_linear),
+    "kimi-linear-9l-64of256": (
+        KIMI_LINEAR_FAMILY, kimi_linear.KimiLinearConfig.kimi_linear_9l_share),
+    "kimilinear-tiny": (KIMI_LINEAR_FAMILY, kimi_linear.KimiLinearConfig.tiny),
 }
 
 

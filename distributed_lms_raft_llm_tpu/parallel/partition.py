@@ -147,6 +147,16 @@ NEMOTRON_H_RULES: List[Tuple[str, PartitionSpec]] = [
     (r".*", P()),
 ]
 
+# kimi_linear (models/kimi_linear.py): everything replicated, as axk1 and
+# nemotron_h and for both their reasons: the latent plane has no heads axis
+# and the KDA layers' matrix state [Lk, S, H, K, V] would want its heads
+# sharded beside the mixer's projections, which is not built (the engines
+# refuse tp > 1 twice over); the share of a layer's experts is the
+# configuration's, so ep > 1 is refused too.
+KIMI_LINEAR_RULES: List[Tuple[str, PartitionSpec]] = [
+    (r".*", P()),
+]
+
 # Rule set per model-family name (models/registry.py ModelFamily.name).
 # (The bucketed engine's KV-cache sharding — [L, B, Hkv, T, Dh]: batch
 # over dp, heads over tp — is derived by jit's sharding propagation from
@@ -162,6 +172,7 @@ RULES_FOR = {
     "afmoe": AFMOE_RULES,
     "axk1": AXK1_RULES,
     "nemotron_h": NEMOTRON_H_RULES,
+    "kimi_linear": KIMI_LINEAR_RULES,
 }
 
 # ---------------------------------------------- paged state plane table

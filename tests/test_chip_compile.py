@@ -413,6 +413,52 @@ def test_nemotron3_nano_cut_megastep_updates_the_state_in_place(one_chip):
         assert not re.search(re.escape(stack) + r"\S* copy\(", text)
 
 
+def test_kimi_linear_cut_megastep_updates_both_planes_in_place(one_chip):
+    """`kimi-linear-9l-64of256` at the published widths, from shapes alone,
+    in the serving settings of benchmarks/configs/kimi-linear.json (16
+    slots, width 2,688, chunk 8, prefill chunks of 32, K = 2): 9.35 GB of
+    bfloat16 weights as held beside the float32 state planes and the
+    latent; the decode step's state update is the kernel `kda_step` and its
+    attention the kernel `mla_decode`, no copy of a whole `ssm` plane (the
+    cache's or the snapshot rows') or of the latent plane lies inside the
+    scans, and the held experts' stacks, padded to whole tiles of 512
+    (`kimi_linear.pad_experts`), enter the TPU's grouped kernel as whole
+    buffers: no copy of a stack is made anywhere."""
+    family, cfg = registry.resolve("kimi-linear-9l-64of256", jnp.bfloat16,
+                                   jnp.bfloat16)
+    params = _with(jax.eval_shape(
+        lambda: family.init_params(jax.random.key(0), cfg)), one_chip)
+    # The published 4,272,540,512 and the experts' padding to [2560, 1024].
+    assert sum(x.size for x in jax.tree.leaves(params)) == (
+        4_272_540_512 + 8 * 64 * 3 * (2560 - 2304) * 1024)
+    state = jax.eval_shape(partial(paged._fresh_state, family, cfg, 16, 2688))
+    assert state.cache.k.shape == (2, 16, 1, 2688, 576)
+    assert state.cache.v is None
+    assert state.cache.ssm.shape == state.snap_ssm.shape == (
+        7, 16, 32, 128, 128)
+    assert state.cache.conv.shape == (7, 16, 3, 12288)
+    mega = jax.jit(
+        partial(paged._megastep_program, chunk=8, spec_tokens=0,
+                prefill_chunk=32, draft_fn=build_drafts, eos_id=50256,
+                pad_id=50256, cfg=cfg, model=family,
+                sampling=SamplingParams.reference_defaults()),
+        donate_argnums=(1,),
+    ).lower(params, _with(state, one_chip), _with(jax.eval_shape(
+        lambda: jax.random.split(jax.random.key(0), 2)), one_chip)).compile()
+    ma = mega.memory_analysis()
+    assert _device_bytes(ma) < 0.8 * HBM_BYTES
+    assert ma.temp_size_in_bytes < 1024**3
+    text = mega.as_text()
+    assert "ragged-dot" in text and "kda_step" in text
+    assert "mla_decode" in text
+    for plane in ("f32[7,16,32,128,128]", "bf16[2,16,2688,576]"):
+        assert plane in text
+        assert _copies_inside_loops(text, plane) == []
+    for stack in ("bf16[64,2560,1024]", "bf16[64,1024,2560]"):
+        assert stack in text
+        assert not re.search(re.escape(stack) + r"\S* copy\(", text)
+
+
 # ------------------- a prefill chunk touches its slot's pages in place
 
 def _copies_inside_loops(text: str, shape: str) -> list:

@@ -111,6 +111,30 @@ def test_static_domain_math_is_engine_math():
         64, 8, (8, 16), 0, megastep_max=4)["megastep_pairs"] == 2 * 3
 
 
+@pytest.mark.parametrize("preset,recurrent", [
+    ("axk1-tiny", False), ("nemotronh-tiny", True),
+    ("kimilinear-tiny", True)])
+def test_a_family_adds_no_program_of_its_own(preset, recurrent):
+    """Whatever planes a family's cache declares (a latent plane, state
+    planes, both: `kimi_linear`), its engine's programs are the
+    inventory's: the snapshot programs count a width each for a recurrent
+    family and zero for the others, and nothing else differs."""
+    from distributed_lms_raft_llm_tpu.models import registry
+
+    family, _ = registry.PRESETS[preset]
+    assert family.recurrent_state == recurrent
+    both = inv.static_paged_domain(64, 8, (8, 16), 0, prefix_cache=True,
+                                   prefix_block_tokens=4,
+                                   recurrent_state=family.recurrent_state)
+    plain = inv.static_paged_domain(64, 8, (8, 16), 0, prefix_cache=True,
+                                    prefix_block_tokens=4)
+    assert both["state_widths"] == (2 if recurrent else 0)
+    assert {k: v for k, v in both.items() if k != "state_widths"} == {
+        k: v for k, v in plain.items() if k != "state_widths"}
+    if preset == "kimilinear-tiny":
+        assert family.latent_cache and family.recurrent_state
+
+
 # ------------------------------------------------- runtime cross-validation
 
 
