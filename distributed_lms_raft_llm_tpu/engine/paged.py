@@ -46,17 +46,21 @@ prompt never pauses the decode train:
   (counted on device — `megastep_dead_lane_tokens`) instead of forcing a
   host reap. While any slot is staged, EVERY decode iteration of the
   megastep (each row of the per-token scan inside each of its K chunks,
-  not each chunk) first runs one token-budgeted prefill chunk
-  (`prefill_chunk_tokens` positions, `_admission_chunk`) for the oldest
-  staged slot — the Sarathi-Serve chunked-prefill idea, device-resident —
-  and then advances the live slots by a token. The final chunk samples
+  not each chunk) first runs one token-budgeted prefill pass
+  (`_admission_chunk`: a chunk of `prefill_chunk_tokens` positions for
+  each of the oldest staged slots, as many as fill `PASS_ROWS` positions,
+  in one forward pass that reads the weights once) — the Sarathi-Serve
+  chunked-prefill idea, device-resident — and then advances the live
+  slots by a token. A slot's final chunk samples
   the first token from the last real position's logits with the staged
   rng and the full-prompt seen mask, and flips the slot live in that same
   iteration; `flipped`/`firsts` planes come back stacked [K, chunk, S], a
   row per iteration like the tokens, so the one batched reap learns
   admission outcomes with zero extra syncs, and a request holds its lane
-  staged for about as many iterations as the chunks queued before its
-  last (`engine_staged_iterations`). Prefill compute fills the scan's
+  staged for about as many iterations as the passes queued before its
+  last (`engine_staged_iterations`; `engine_prefill_pass_slots` over
+  `engine_prefill_passes` is how many slots a pass served). Prefill
+  compute fills the scan's
   pipeline bubbles, and greedy outputs are bit-identical to the bucketed
   engine's (`TutoringEngine`) at any K and chunk budget
   (tests/test_fused_prefill.py).
@@ -79,6 +83,7 @@ import dataclasses
 import logging
 import math
 import time
+from functools import partial
 from typing import Dict, List, NamedTuple, Optional, Sequence, Tuple
 
 import jax
@@ -130,6 +135,11 @@ log = logging.getLogger(__name__)
 STATE_BLOCKS_A_SNAPSHOT = 16
 STATE_STRIDE_STEPS = 8
 
+# Positions an in-scan prefill pass holds (`_admission_chunk`): the rows of
+# one tile of the chip's matrix unit, filled with whole chunks of
+# `prefill_chunk_tokens` positions, one staged slot's each.
+PASS_ROWS = 128
+
 
 class SlotState(NamedTuple):
     """Device-side state of all S slots.
@@ -161,7 +171,7 @@ class SlotState(NamedTuple):
     transcript: jax.Array
     # Staged-admission plane (in-scan chunked prefill; all [S]): `staged`
     # marks slots whose prompt is being prefilled inside the megastep scan
-    # (one chunk per decode iteration, for the oldest by `stage_seq`),
+    # (a chunk per decode iteration, for the oldest few by `stage_seq`),
     # `stage_cursor` the next absolute prefill position (starts at the
     # spliced shared-prefix length), `stage_len` the true prompt length
     # (it stays after the flip: a family with routed experts knows a
@@ -492,12 +502,13 @@ def _sum_counts(*counts) -> tuple:
     return (sum(found[1:], found[0]),) if found else ()
 
 
-def _over_iterations(extra: list, model) -> list:
-    """A scanned body's stacked extras with a routed family's counts (the
-    last one, [iterations, 3]) summed over the iterations."""
-    if model.routed:
-        extra = [*extra[:-1], jnp.sum(extra[-1], axis=0)]
-    return extra
+def _over_iterations(extra: list, model, admit) -> list:
+    """A scanned body's stacked extras with its counts summed over the
+    iterations: `admit`'s `served` ([iterations, 3]) and, last, a routed
+    family's ([iterations, 3])."""
+    counts = (admit is not None) + model.routed
+    return [*extra[:len(extra) - counts],
+            *(jnp.sum(c, axis=0) for c in extra[len(extra) - counts:])]
 
 
 def _step_program(params, state: SlotState, rng, *, cfg, sampling,
@@ -526,10 +537,11 @@ def _step_program(params, state: SlotState, rng, *, cfg, sampling,
 
     `admit` (the megastep binds `_admission_chunk` to it) runs at the
     head of EVERY scan iteration, before the decode:
-    state -> (state, flipped [S], firsts [S]). A slot it flips live
-    decodes its first token in that same iteration, and the two planes
-    come back stacked [chunk, S] after the snapshot. None (a caller that
-    lowers the bare chunk) leaves body and outputs as they are.
+    state -> (state, flipped [S], firsts [S], served [3]). A slot it flips
+    live decodes its first token in that same iteration, and the two
+    planes come back stacked [chunk, S] after the snapshot, `served`
+    summed over the iterations after them. None (a caller that lowers the
+    bare chunk) leaves body and outputs as they are.
 
     A family with routed experts (`ModelFamily.routed`) adds one LAST
     output, its counts summed over the iterations and `admit`'s forward
@@ -541,7 +553,7 @@ def _step_program(params, state: SlotState, rng, *, cfg, sampling,
         extra = ()
         if admit is not None:
             s, *extra = admit(s)
-        moe = extra[2:]  # a routed family's counts follow the two planes
+        moe = extra[3:]  # a routed family's counts follow `admit`'s own
         # Inactive/full slots write into their current position; clamp to
         # stay in bounds — the slot is dead or about to be evicted, the
         # data ignored.
@@ -570,14 +582,14 @@ def _step_program(params, state: SlotState, rng, *, cfg, sampling,
                 active=still,
                 seen=seen,
             ),
-            (nxt, *extra[:2], *_sum_counts(counts, moe)),
+            (nxt, *extra[:3], *_sum_counts(counts, moe)),
         )
 
     state, (toks, *extra) = jax.lax.scan(
         one, state, jax.random.split(rng, chunk)
     )
     return (state, toks, state.active.astype(jnp.int8),
-            *_over_iterations(extra, model))
+            *_over_iterations(extra, model, admit))
 
 
 def _spec_step_program(
@@ -612,8 +624,8 @@ def _spec_step_program(
     means the slot was inactive. Like the plain step's outputs, all three
     are fresh buffers that survive the next dispatch donating the state.
     `admit` is `_step_program`'s: it runs before each window, and its
-    [chunk, S] planes follow the snapshot; a routed family's counts come
-    last, as there.
+    [chunk, S] planes and `served` follow the snapshot; a routed family's
+    counts come last, as there.
     """
     k = spec_tokens
     width = state.cache.k.shape[3]
@@ -624,7 +636,7 @@ def _spec_step_program(
         extra = ()
         if admit is not None:
             s, *extra = admit(s)
-        moe = extra[2:]
+        moe = extra[3:]
         offs = jnp.minimum(s.cache.length, width - 1 - k)  # [S] window base
         # Drafts: the pending last token sits at transcript slot `offs`;
         # an anchor must be filled AND have k filled continuation slots
@@ -678,135 +690,214 @@ def _spec_step_program(
                 seen=seen,
                 transcript=transcript,
             ),
-            (emitted, m, *extra[:2], *_sum_counts(counts, moe)),
+            (emitted, m, *extra[:3], *_sum_counts(counts, moe)),
         )
 
     state, (emitted, counts, *extra) = jax.lax.scan(
         one, state, jax.random.split(rng, chunk)
     )
     return (state, emitted, counts, state.active.astype(jnp.int8),
-            *_over_iterations(extra, model))
+            *_over_iterations(extra, model, admit))
 
 
-def _admission_chunk(params, s: SlotState, *, cfg, sampling, model,
-                     eos_id: int, pad_id: int, prefill_chunk: int):
-    """One token-budgeted prefill chunk for the oldest staged admission —
-    the admission phase at the head of every decode iteration (the
-    megastep hands it to the per-token scan body as `admit`; staging
-    happens only between dispatches, so every staged slot is known at a
-    megastep's entry and is served, oldest first, a chunk an iteration
-    until none is left).
+def _prefill_pass(params, s: SlotState, width: int, *, cfg, sampling, model,
+                  eos_id: int, pad_id: int, prefill_chunk: int):
+    """One forward pass that serves the `width` oldest staged slots (by
+    `stage_seq`) a chunk of `prefill_chunk` positions each, as a ragged
+    batch: the weights are read once for all of them and not once a slot.
 
-    If any slot is staged: forward the next `prefill_chunk` prompt ids
-    from its transcript row onto that slot's pages of the live cache, in
-    place (`forward`'s `rows`: the chunk is a batch of one that addresses
-    row `slot`; KV scatters at the ragged cursor offset — out-of-range pad
-    tails of the final chunk are dropped by the scatter, never clamped
-    into real pages — and the attention reads that row alone; slicing the
-    slot's pages out and splicing them back made the compiler relay the
-    whole cache four times a chunk). When the cursor
-    covers the true length, the flip: sample the first token from the
-    last real position's logits with the staged rng and the full-prompt
-    seen mask, then mark the slot live (length=true_len, transcript gains
-    the first token at its cache slot, active unless eos). The computation
-    per real position is that of one whole-prompt forward (same KV values,
-    same causal key set, pad tails masked), so the flipped slot's greedy
+    Row i forwards the next `prefill_chunk` prompt ids of its slot's
+    transcript row onto that slot's pages of the live cache, in place
+    (`forward`'s `rows`: KV scatters at the slot's own cursor —
+    out-of-range pad tails of a final chunk are dropped by the scatter,
+    never clamped into real pages — and the attention reads that slot's
+    row alone; slicing a slot's pages out and splicing them back made the
+    compiler relay the whole cache four times a chunk). A row with no
+    staged slot behind it (fewer staged than `width`) addresses a cache
+    row past the last: a scatter drops what lies out of bounds, so it
+    writes no key, value, scale or state anywhere, and none of its
+    positions is `live`. When a slot's cursor covers its true length, the
+    flip: sample the first token from the last real position's logits
+    with the staged rng and the full-prompt seen mask, then mark the slot
+    live (length=true_len, transcript gains the first token at its cache
+    slot, active unless eos). The computation per real position is that
+    of one whole-prompt forward (same KV values, same causal key set, pad
+    tails masked) whoever shares the pass, so the flipped slot's greedy
     stream is bit-identical to the bucketed engine's.
 
-    Returns (state, flipped [S] bool, firsts [S] int32) — one-hot at the
-    flipped slot — and, for a family with routed experts, the chunk's
-    counts (`_forward`; the chunk's pad tail routes nowhere). A `lax.cond`
-    skips all of it when nothing is staged, so the steady-state decode
-    iteration pays nothing for it.
-    """
+    Returns `_admission_chunk`'s tuple."""
     n_slots = s.tok.shape[0]
-    no_flip = jnp.zeros((n_slots,), jnp.bool_)
-    no_first = jnp.full((n_slots,), pad_id, jnp.int32)
-    no_counts = ((jnp.zeros((len(model.counters),), jnp.int32),)
-                 if model.routed else ())
-
-    def run(s: SlotState):
-        c = prefill_chunk
-        zero = jnp.zeros((), jnp.int32)
-        # FIFO service: the staged slot with the lowest staging sequence
-        # number (slot INDEX would let churn restage a lower slot and
-        # starve an earlier admission's prefill indefinitely).
-        big = jnp.iinfo(jnp.int32).max
-        slot = jnp.argmin(
-            jnp.where(s.staged, s.stage_seq, big)
-        ).astype(jnp.int32)
-        cur = s.stage_cursor[slot]
-        tl = s.stage_len[slot]
-        ids = jax.lax.dynamic_slice(s.transcript, (slot, cur), (1, c))
-        # Pad-tail positions clamp to the last real position; their
-        # outputs/KV are garbage nothing reads (causal frontier + the
-        # decode kv_mask).
-        positions = jnp.minimum(
-            cur + jnp.arange(c, dtype=jnp.int32), tl - 1
-        )[None, :]
-        logits, cache, counts = _forward(
-            model, params, cfg, ids,
-            (cur + jnp.arange(c, dtype=jnp.int32) < tl)[None, :],
-            cache=s.cache._replace(length=cur[None]), rows=slot[None],
-            positions=positions,
-        )
-        snap = {}
-        # lint: disable-next=tracer-hygiene
-        if s.snap_ssm is not None:
-            # The chunk that ends at the slot's snapshot position (all of
-            # it real: the position is below the prompt's end) copies the
-            # state it leaves into the slot's snapshot rows.
-            hit = cur + c == s.snap_at[slot]
-
-            def keep(plane, new):
-                row = jax.lax.dynamic_slice_in_dim(new, slot, 1, axis=1)
-                old = jax.lax.dynamic_slice_in_dim(plane, slot, 1, axis=1)
-                return jax.lax.dynamic_update_slice_in_dim(
-                    plane, jnp.where(hit, row, old), slot, axis=1)
-
-            snap = dict(snap_ssm=keep(s.snap_ssm, cache.ssm),
-                        snap_conv=keep(s.snap_conv, cache.conv))
-        done = cur + c >= tl
-        li = jnp.clip(tl - 1 - cur, 0, c - 1)
-        last = jax.lax.dynamic_index_in_dim(logits[0], li, 0,
-                                            keepdims=False)
-        row = jax.lax.dynamic_slice(
-            s.transcript, (slot, zero), (1, s.transcript.shape[1])
-        )
-        valid = (jnp.arange(s.transcript.shape[1]) < tl)[None, :]
-        seen0 = seen_mask_from_ids(row, valid, cfg.vocab_size)
-        rng = jax.random.wrap_key_data(s.stage_rng[slot])
-        first = sample_step(rng, last[None, :], seen0, sampling)[0]
-        seen1 = update_seen(seen0, first[None])[0]
-        new = s._replace(
-            cache=cache._replace(
-                length=s.cache.length.at[slot].set(
-                    jnp.where(done, tl, s.cache.length[slot])
-                ),
-            ),
-            tok=s.tok.at[slot].set(jnp.where(done, first, s.tok[slot])),
-            active=s.active.at[slot].set(done & (first != eos_id)),
-            seen=s.seen.at[slot].set(
-                jnp.where(done, seen1, s.seen[slot])
-            ),
-            transcript=s.transcript.at[slot, tl].set(
-                jnp.where(done, first, s.transcript[slot, tl])
-            ),
-            staged=s.staged.at[slot].set(~done),
-            stage_cursor=s.stage_cursor.at[slot].set(cur + c),
-            **snap,
-        )
-        return (
-            new,
-            no_flip.at[slot].set(done),
-            no_first.at[slot].set(jnp.where(done, first, pad_id)),
-            *counts,
-        )
-
-    return jax.lax.cond(
-        jnp.any(s.staged), run,
-        lambda s: (s, no_flip, no_first, *no_counts), s
+    c = prefill_chunk
+    # FIFO service: the staged slots with the lowest staging sequence
+    # numbers (slot INDEX would let churn restage a lower slot and
+    # starve an earlier admission's prefill indefinitely).
+    big = jnp.iinfo(jnp.int32).max
+    _, slot = jax.lax.top_k(
+        -jnp.where(s.staged, s.stage_seq, big), width)
+    real = s.staged[slot]  # [W]: fewer staged than rows leaves some bare
+    # A cache row past the last for a bare row, each its own.
+    nowhere = n_slots + jnp.arange(width, dtype=jnp.int32)
+    rows = jnp.where(real, slot, nowhere)
+    cur = s.stage_cursor[slot]
+    tl = s.stage_len[slot]
+    at = cur[:, None] + jnp.arange(c, dtype=jnp.int32)  # [W, c]
+    ids = s.transcript[
+        slot[:, None], jnp.minimum(at, s.transcript.shape[1] - 1)]
+    # Pad-tail positions clamp to the last real position; their
+    # outputs/KV are garbage nothing reads (causal frontier + the
+    # decode kv_mask).
+    logits, cache, counts = _forward(
+        model, params, cfg, ids, (at < tl[:, None]) & real[:, None],
+        cache=s.cache._replace(length=cur), rows=rows,
+        positions=jnp.minimum(at, tl[:, None] - 1),
     )
+    snap = {}
+    # lint: disable-next=tracer-hygiene
+    if s.snap_ssm is not None:
+        # A chunk that ends at its slot's snapshot position (all of
+        # it real: the position is below the prompt's end) copies the
+        # state it leaves into the slot's snapshot rows: a row at a
+        # time, each sliced out and put back where it lies (a gather
+        # of the rows makes the compiler copy the plane's halves).
+        hit = real & (cur + c == s.snap_at[slot])
+
+        def keep(plane, new):
+            for i in range(width):
+                row = jax.lax.dynamic_slice_in_dim(new, slot[i], 1, 1)
+                old = jax.lax.dynamic_slice_in_dim(plane, slot[i], 1, 1)
+                plane = jax.lax.dynamic_update_slice_in_dim(
+                    plane, jnp.where(hit[i], row, old), slot[i], 1)
+            return plane
+
+        snap = dict(snap_ssm=keep(s.snap_ssm, cache.ssm),
+                    snap_conv=keep(s.snap_conv, cache.conv))
+    done = real & (cur + c >= tl)
+    li = jnp.clip(tl - 1 - cur, 0, c - 1)
+    # A dynamic slice a row: the TPU's compiler splits a gather over
+    # [W, c, V] into gathers over copies of the logits' halves.
+    last = jnp.stack([
+        jax.lax.dynamic_index_in_dim(logits[i], li[i], 0, keepdims=False)
+        for i in range(width)])
+    valid = jnp.arange(s.transcript.shape[1]) < tl[:, None]
+    seen0 = seen_mask_from_ids(s.transcript[slot], valid, cfg.vocab_size)
+    first = jax.vmap(lambda raw, logit, seen: sample_step(
+        jax.random.wrap_key_data(raw), logit[None], seen[None],
+        sampling)[0])(s.stage_rng[slot], last, seen0)
+    # The flip writes at the slots that are done and nowhere else.
+    flip = jnp.where(done, slot, nowhere)
+    new = s._replace(
+        cache=cache._replace(length=s.cache.length.at[flip].set(tl)),
+        tok=s.tok.at[flip].set(first),
+        active=s.active.at[flip].set(first != eos_id),
+        seen=s.seen.at[flip].set(update_seen(seen0, first)),
+        transcript=s.transcript.at[flip, tl].set(first),
+        staged=s.staged.at[flip].set(False),
+        stage_cursor=s.stage_cursor.at[rows].set(cur + c),
+        **snap,
+    )
+    return (
+        new,
+        jnp.zeros((n_slots,), jnp.bool_).at[flip].set(True),
+        jnp.full((n_slots,), pad_id, jnp.int32).at[flip].set(first),
+        jnp.stack([jnp.ones((), jnp.int32),
+                   jnp.sum(real, dtype=jnp.int32),
+                   (jnp.sum(s.staged, dtype=jnp.int32) > 1).astype(jnp.int32)]),
+        *counts,
+    )
+
+
+
+def _admission_chunk(params, s: SlotState, *, wide: bool, **statics):
+    """The admission phase at the head of every decode iteration (the
+    megastep hands it to the per-token scan body as `admit`; staging
+    happens only between dispatches, so every staged slot is known at a
+    megastep's entry and is served, oldest first, a chunk a pass until
+    none is left): one `_prefill_pass` for the oldest staged slots, as
+    wide as what is staged asks for.
+
+    Two or more staged: the pass has `PASS_ROWS // prefill_chunk` rows (4
+    at 32 positions; never more than there are slots), and the slots past
+    them wait a pass. One staged: a pass of one row, because the bare rows
+    of the wide one are not free (on a v5e gpt2-xl's pass of four rows
+    takes 13.4 ms whatever stands behind them, and its pass of one 4.6).
+    Nothing staged: a `lax.cond` skips all of it, so the steady-state
+    decode iteration pays nothing for it. Not `wide` (the caller's word:
+    `_megastep_program` says it for every rung but the first): the pass of
+    one row whatever is staged, the oldest first.
+
+    Returns (state, flipped [S] bool, firsts [S] int32, served int32 [3])
+    — the first two hot at the slots that flipped, `served` the passes
+    run, the slot-chunks they served and the passes that found two or
+    more slots staged, (1, rows with a slot, 0 or 1) or zeros —
+    and, for a family with routed experts, the pass's counts (`_forward`;
+    a pad tail and a bare row route nowhere, and an expert counts as
+    reached once a pass, not once a row)."""
+    model, n_slots = statics["model"], s.tok.shape[0]
+    width = max(1, min(n_slots, PASS_ROWS // statics["prefill_chunk"]))
+
+    def idle(s: SlotState):
+        return (s, jnp.zeros((n_slots,), jnp.bool_),
+                jnp.full((n_slots,), statics["pad_id"], jnp.int32),
+                jnp.zeros((3,), jnp.int32),
+                *((jnp.zeros((len(model.counters),), jnp.int32),)
+                  if model.routed else ()))
+
+    def serve(s: SlotState, width: int, ours):
+        return jax.lax.cond(
+            ours, partial(_prefill_pass, params, width=width, **statics),
+            idle, s)
+
+    staged = jnp.sum(s.staged, dtype=jnp.int32)
+    if width == 1 or not wide:
+        return serve(s, 1, staged > 0)
+    # A `cond` a width, one after the other, and not one `conditional` of
+    # three branches: in that, the compiler copies a recurrent family's
+    # whole state plane between the layers of a pass (`tests/
+    # test_chip_compile.py` holds it to none). The count was taken before
+    # either, so at most one of them runs.
+    s, flipped_w, firsts_w, served_w, *counts_w = serve(
+        s, width, staged > 1)
+    s, flipped, firsts, served, *counts = serve(s, 1, staged == 1)
+    return (s, flipped | flipped_w, jnp.where(flipped_w, firsts_w, firsts),
+            served + served_w, *(a + b for a, b in zip(counts, counts_w)))
+
+
+def _chunk_program(params, s: SlotState, rng, *, cfg, sampling, eos_id: int,
+                   pad_id: int, model, spec_tokens: int, chunk: int,
+                   prefill_chunk: int, draft_fn, wide: bool):
+    """One chunk of a megastep, the body its scan repeats:
+    `_step_program` / `_spec_step_program` (selected statically by
+    `spec_tokens`) with `_admission_chunk` bound to its `admit`."""
+    def admit(s: SlotState):
+        with jax.named_scope("prefill_chunk"):
+            return _admission_chunk(
+                params, s, cfg=cfg, sampling=sampling, model=model,
+                eos_id=eos_id, pad_id=pad_id, prefill_chunk=prefill_chunk,
+                wide=wide,
+            )
+
+    body = dict(cfg=cfg, sampling=sampling, eos_id=eos_id, pad_id=pad_id,
+                model=model, chunk=chunk, admit=admit)
+    if spec_tokens:
+        s, *outs = _spec_step_program(
+            params, s, rng, spec_tokens=spec_tokens, draft_fn=draft_fn,
+            **body,
+        )
+    else:
+        s, *outs = _step_program(params, s, rng, **body)
+    return s, tuple(outs)
+
+
+# Jitted for its TRACE, not for a program of its own (it is only ever called
+# inside `_megastep_program`, where the compiler inlines it): a jit keeps
+# what it traced by its arguments' shapes and its statics, and every rung of
+# the ladder scans the same chunk over the same state, so a width's rungs
+# trace the three forward passes of a chunk (the decode step and the two
+# prefill passes) once and not once a rung. Tracing is most of what a start
+# that finds its programs in the compile cache still pays for a megastep.
+_chunk = jax.jit(_chunk_program, static_argnames=(
+    "cfg", "sampling", "eos_id", "pad_id", "model", "spec_tokens", "chunk",
+    "prefill_chunk", "draft_fn", "wide"))
 
 
 def _megastep_program(params, state: SlotState, rngs, *, cfg, sampling,
@@ -822,21 +913,32 @@ def _megastep_program(params, state: SlotState, rngs, *, cfg, sampling,
     is encoded in the rngs shape, so each K compiles its own program; the
     warmed domain is widths x the `megastep_ladder` rungs).
 
-    The scan body is `_step_program`/`_spec_step_program` (selected
-    statically by `spec_tokens`) with `_admission_chunk` bound to its
-    `admit`: it serves the oldest staged slot one prefill chunk of
-    `prefill_chunk` positions BEFORE each decode iteration, so a slot
+    The scan body is `_chunk_program`: `_step_program`/`_spec_step_program`
+    (selected statically by `spec_tokens`) with `_admission_chunk` bound to
+    its `admit`, which serves the oldest staged slots a prefill chunk of
+    `prefill_chunk` positions each BEFORE each decode iteration, so a slot
     joins the train at a scan-iteration boundary, not a chunk or dispatch
-    boundary. The per-chunk outputs stack along a leading K axis:
+    boundary. The pass that serves several slots at once is in the program
+    of ONE chunk (K = 1) alone: that is the rung the controller dispatches
+    while work waits for a slot (`engine_one_chunk_dispatches`: 88 to 100%
+    of a loaded cell's dispatches), which is when prompts are staged
+    together; the longer rungs are what an idle server grows into, they
+    serve one staged slot a pass, and a start is spared a third forward
+    pass in three of every four megastep programs it traces and loads
+    (`setup_s`: +9 s of 50 with the wide pass in every rung). The
+    per-chunk outputs stack along a leading K axis:
 
     - plain: (state, toks [K, chunk, S], active [K, S] int8, dead int32,
     - spec:  (state, emitted [K, chunk, S, k+1], counts [K, chunk, S],
               active [K, S] int8, dead int32,
     - then:   flipped [K, chunk, S] bool, firsts [K, chunk, S] int32) — per
-      decode iteration (a row of the token plane), the slot whose staged
-      prefill completed at its head and the first token it sampled, so
+      decode iteration (a row of the token plane), the slots whose staged
+      prefill completed at its head and the first token each sampled, so
       the batched reap learns admission outcomes without an extra sync
-      and starts the slot's decode walk at that row.
+      and starts a slot's decode walk at that row;
+    - served int32 [3]: the prefill passes the dispatch ran, the
+      slot-chunks they served and the passes that found two or more slots
+      staged (`_admission_chunk`).
     - a family with routed experts: the above plus, LAST, its
       counts summed over the dispatch (`_forward`).
 
@@ -864,32 +966,18 @@ def _megastep_program(params, state: SlotState, rngs, *, cfg, sampling,
     """
     started = state.active  # read before the scan consumes the donation
 
-    def admit(s: SlotState):
-        with jax.named_scope("prefill_chunk"):
-            return _admission_chunk(
-                params, s, cfg=cfg, sampling=sampling, model=model,
-                eos_id=eos_id, pad_id=pad_id, prefill_chunk=prefill_chunk,
-            )
-
-    body = dict(cfg=cfg, sampling=sampling, eos_id=eos_id, pad_id=pad_id,
-                model=model, chunk=chunk, admit=admit)
-
-    def one_chunk(s: SlotState, r):
-        if spec_tokens:
-            s, *outs = _spec_step_program(
-                params, s, r, spec_tokens=spec_tokens, draft_fn=draft_fn,
-                **body,
-            )
-        else:
-            s, *outs = _step_program(params, s, r, **body)
-        return s, tuple(outs)
-
-    state, outs = jax.lax.scan(one_chunk, state, rngs)
+    state, outs = jax.lax.scan(
+        partial(_chunk, params, cfg=cfg, sampling=sampling, eos_id=eos_id,
+                pad_id=pad_id, model=model, spec_tokens=spec_tokens,
+                chunk=chunk, prefill_chunk=prefill_chunk, draft_fn=draft_fn,
+                wide=rngs.shape[0] == 1),
+        state, rngs)
     moe = ()
     if model.routed:
         *outs, moe = outs  # [K, 3] routed-experts counts, one per chunk
         moe = (jnp.sum(moe, axis=0),)
-    *outs, flipped, firsts = outs  # [K, chunk, S] admission planes
+    # [K, chunk, S] admission planes, and the passes' [K, 2] counts.
+    *outs, flipped, firsts, served = outs
     active = outs[-1]  # [K, S] int8 post-chunk snapshots
     lane_tokens = chunk * ((spec_tokens + 1) if spec_tokens else 1)
     # A lane is stranded from the first chunk it is dead AFTER having
@@ -903,7 +991,8 @@ def _megastep_program(params, state: SlotState, rngs, *, cfg, sampling,
     dead = jnp.asarray(lane_tokens, jnp.int32) * jnp.sum(
         (live[:-1] & (active[:-1] == 0)).astype(jnp.int32)
     )
-    return (state, *outs, dead, flipped, firsts, *moe)
+    return (state, *outs, dead, flipped, firsts, jnp.sum(served, axis=0),
+            *moe)
 
 
 def rows_to_certain_end(req: Optional[_Request], tmax: int,
@@ -1265,14 +1354,17 @@ class PagedEngine:
         #  dead-lane scalar device array,
         #  flipped / firsts [K, chunk, S] bool / int32 admission planes,
         #  slot->request snapshot at dispatch time,
-        #  a routed family's counts int32 [len(counters)], else None).
+        #  a routed family's counts int32 [len(counters)], else None,
+        #  served int32 [3]: prefill passes, the slot-chunks they served and
+        #  the passes that found two or more staged).
         # Every device entry is a fresh non-donated buffer (see
         # _step_program's snapshot note), so dispatches pipeline under
         # the donation invariants.
         self._inflight: List[
             Tuple[jax.Array, Optional[jax.Array], jax.Array,
                   jax.Array, jax.Array, jax.Array,
-                  List[Optional[_Request]], Optional[jax.Array]]
+                  List[Optional[_Request]], Optional[jax.Array],
+                  Optional[jax.Array]]
         ] = []
         self._next_rid = 0
         self.last_ttft_s: Optional[float] = None
@@ -2290,7 +2382,7 @@ class PagedEngine:
             )
         if self.family.routed:
             *outs, moe = outs
-        *outs, flipped, firsts = outs
+        *outs, flipped, firsts, served = outs
         if self.spec:
             toks, counts, active, dead = outs
         else:
@@ -2298,10 +2390,10 @@ class PagedEngine:
         self._count(scan_iterations=k * self.chunk,
                     lane_steps=k * self.chunk * self.slots)
         self._push_inflight(toks, counts, active, dead, flipped, firsts,
-                            moe)
+                            moe, served)
 
     def _push_inflight(self, toks, counts, active, dead, flipped,
-                       firsts, moe=None) -> None:
+                       firsts, moe=None, served=None) -> None:
         """Queue one dispatched program's output buffers for a later reap.
 
         No blocking readback here — but START the device->host copies
@@ -2311,14 +2403,15 @@ class PagedEngine:
         same pipe, so learning a staged slot went live costs no extra
         sync.
         """
-        for arr in (toks, counts, active, dead, flipped, firsts, moe):
+        for arr in (toks, counts, active, dead, flipped, firsts, moe,
+                    served):
             if arr is not None:
                 arr.copy_to_host_async()
         # The slot snapshot records which request each column belonged
         # to at dispatch time (a slot reused later belongs to a later
         # dispatch).
         self._inflight.append((toks, counts, active, dead, flipped,
-                               firsts, list(self._slot_req), moe))
+                               firsts, list(self._slot_req), moe, served))
 
     def _count_moe(self, counts) -> None:
         """A routed family's counts of some forward passes, read back
@@ -2328,7 +2421,7 @@ class PagedEngine:
 
     def _reap(self, toks_dev, counts_dev, active_dev, dead_dev,
               flipped_dev, firsts_dev, slot_snapshot,
-              moe_dev=None) -> List[Tuple[int, str]]:
+              moe_dev=None, served_dev=None) -> List[Tuple[int, str]]:
         """Read one dispatch's results — a megastep's whole [K, chunk, S]
         plane in one batched pass — and finish the requests it completed.
         The same pass also learns which staged slots FLIPPED live
@@ -2347,6 +2440,15 @@ class PagedEngine:
             firsts = np.asarray(firsts_dev)    # [K, chunk, S]
             if moe_dev is not None:
                 self._count_moe(np.asarray(moe_dev))
+            if served_dev is not None:
+                passes, served, crowded = np.asarray(served_dev).tolist()
+                # A dispatch of more than one chunk serves one slot a
+                # pass whatever is staged (`_megastep_program`).
+                self._count(prefill_passes=passes,
+                            prefill_pass_slots=served,
+                            prefill_crowded_passes=crowded,
+                            prefill_crowded_narrow_passes=(
+                                crowded if toks.shape[0] > 1 else 0))
         self._observe("reap_wait", wait.wall_s)
         with self._span("engine.reap.host"):
             return self._walk(toks, counts, active, flipped, firsts,

@@ -59,6 +59,11 @@ class ProgramEntry:
 # --- inventory:begin --- (generated by scripts/gen_program_inventory.py)
 INVENTORY: Tuple[ProgramEntry, ...] = (
     ProgramEntry(
+        engine="", attr="_chunk", target="_chunk_program",
+        donate_argnums=(), static_argnums=(),
+        domain="shapes", coverage="on-demand",
+    ),
+    ProgramEntry(
         engine="PagedEngine", attr="_export_block", target="_export_block_program",
         donate_argnums=(), static_argnums=(),
         domain="export-widths", coverage="warmup",

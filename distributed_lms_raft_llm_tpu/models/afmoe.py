@@ -52,6 +52,7 @@ from .common import (
     attend,
     causal_window_mask,
     dense,
+    layer_rows,
     merge_heads,
     rms_norm,
     split_heads,
@@ -410,8 +411,8 @@ def forward(
                 start = (layer, zero, zero, offset, zero)
                 ck = jax.lax.dynamic_update_slice(ck, k_w[None], start)
                 cv = jax.lax.dynamic_update_slice(cv, v_w[None], start)
-            at = layer if rows is None else (layer, rows)
-            k, v = ck[at].astype(q.dtype), cv[at].astype(q.dtype)
+            k = layer_rows(ck, layer, rows).astype(q.dtype)
+            v = layer_rows(cv, layer, rows).astype(q.dtype)
         # Grouped keys and values without repeating them: the `groups`
         # query heads of a kv head are folded into the query axis.
         a = attend(q.reshape(b, nkv, groups * t, dh), k, v, masks[kind])

@@ -158,7 +158,24 @@ def layer_rows(plane: jax.Array, layer,
     its own rows' pages and nobody else's."""
     if rows is None:
         return jax.lax.dynamic_index_in_dim(plane, layer, 0, keepdims=False)
-    return plane[layer, rows]
+    # One dynamic slice a row, not a gather: the TPU's compiler splits a
+    # gather of several rows this long into gathers over SLICES OF THE
+    # WHOLE PLANE, which it copies out first, every layer. The rows are
+    # made a value of their own (the barrier) before anything reads them:
+    # read where they lie by a product that is scheduled around the
+    # layer's write into the same plane, they made the compiler copy a
+    # recurrent family's whole state plane between the layers of a pass
+    # (tests/test_chip_compile.py holds it to none), and so did ONE row
+    # read as `plane[layer, rows]` or as a bare slice in a program that
+    # holds the pass of several rows too: one form for any number of rows.
+    # A row past the last reads the last (a slice's start is clamped, as a
+    # gather's index is).
+    zero = jnp.zeros((), jnp.int32)
+    return jax.lax.optimization_barrier(jnp.concatenate([
+        jax.lax.dynamic_slice(
+            plane, (layer, rows[i]) + (zero,) * (plane.ndim - 2),
+            (1, 1) + plane.shape[2:])[0]
+        for i in range(rows.shape[0])]))
 
 
 def write_scales(plane: jax.Array, layer, rows: Optional[jax.Array],

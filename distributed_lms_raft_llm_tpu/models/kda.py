@@ -46,7 +46,7 @@ import jax
 import jax.numpy as jnp
 
 from ..ops import kda as kda_ops
-from .common import dense
+from .common import dense, layer_rows
 from .mamba2 import causal_conv
 
 Params = Dict[str, Any]
@@ -179,7 +179,8 @@ def mixer(x: jax.Array, mp: Params, cfg, live: jax.Array,
     # Batch element i's row of layer `layer`: row i, or the row named.
     at = layer if rows is None else (layer, rows)
     with jax.named_scope("kda.conv"):
-        qkv, window = causal_conv(qkv, conv[at], mp, live)
+        qkv, window = causal_conv(qkv, layer_rows(conv, layer, rows), mp,
+                                  live)
         conv = conv.at[at].set(window)
     # The activations are the served dtype's values (the convolution's
     # output is rounded to it, as the published kernels take it); the
@@ -202,7 +203,8 @@ def mixer(x: jax.Array, mp: Params, cfg, live: jax.Array,
                     s, layer, *ops))
             o = o[:, None]
         else:
-            o, state = _chunk_scan(q, k, v, g, beta, ssm[at])
+            o, state = _chunk_scan(q, k, v, g, beta,
+                                   layer_rows(ssm, layer, rows))
             ssm = ssm.at[at].set(state)
     with jax.named_scope("kda.out"):
         o = o * jax.lax.rsqrt(jnp.mean(jnp.square(o), axis=-1, keepdims=True)

@@ -37,7 +37,7 @@ import jax
 import jax.numpy as jnp
 
 from ..ops import ssm as ssm_ops
-from .common import dense
+from .common import dense, layer_rows
 
 Params = Dict[str, Any]
 
@@ -193,7 +193,8 @@ def mixer(u: jax.Array, mp: Params, cfg, live: jax.Array,
     # Batch element i's row of layer `layer`: row i, or the row named.
     at = layer if rows is None else (layer, rows)
     with jax.named_scope("ssm.conv"):
-        xbc, window = causal_conv(xbc, conv[at], mp, live)
+        xbc, window = causal_conv(xbc, layer_rows(conv, layer, rows), mp,
+                                  live)
         conv = conv.at[at].set(window)
     x = xbc[..., :di].reshape(b, t, h, p)
     bm = xbc[..., di:di + g * n].reshape(b, t, g, n)
@@ -216,7 +217,8 @@ def mixer(u: jax.Array, mp: Params, cfg, live: jax.Array,
                     s, layer, *ops))
             y = y[:, None]
         else:
-            y, state = _chunk_scan(x, dt, a, bm, cm, ssm[at])
+            y, state = _chunk_scan(x, dt, a, bm, cm,
+                                   layer_rows(ssm, layer, rows))
             ssm = ssm.at[at].set(state)
         y = y + mp["d"].astype(jnp.float32)[:, None] * x
     with jax.named_scope("ssm.out"):

@@ -69,7 +69,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..ops import attention as attention_ops
-from .common import NEG_INF, KVCache, dense, rms_norm
+from .common import NEG_INF, KVCache, dense, layer_rows, rms_norm
 from .llama import rope
 
 Params = Dict[str, Any]
@@ -211,8 +211,7 @@ def attention(h: jax.Array, ap: Params, cfg, layer: int,
             zero = jnp.zeros((), jnp.int32)
             plane = jax.lax.dynamic_update_slice(
                 plane, new[None], (layer, zero, offset, zero))
-        at = layer if rows is None else (layer, rows)
-        latent = plane[at].astype(h.dtype)                    # [B, S, kr+dr]
+        latent = layer_rows(plane, layer, rows).astype(h.dtype)  # [B,S,kr+dr]
 
     with jax.named_scope("mla.absorb"):
         q_lat = jnp.einsum("bhtn,khn->bhtk", q_nope,

@@ -591,6 +591,32 @@ ENGINE_ONE_CHUNK_DISPATCHES = counter(
     "floor engaged; with engine_scan_iterations, the rows a dispatch "
     "carried",
 )
+ENGINE_PREFILL_PASSES = counter(
+    "engine_prefill_passes",
+    "in-scan prefill passes the device ran: one forward pass over a chunk "
+    "of prefill_chunk_tokens positions for each of the oldest staged "
+    "slots, at the head of every scan iteration that found a slot staged; "
+    "counted on the device and read back at the dispatch's reap",
+)
+ENGINE_PREFILL_PASS_SLOTS = counter(
+    "engine_prefill_pass_slots",
+    "slot-chunks the in-scan prefill passes served (a pass serves up to "
+    "128 / prefill_chunk_tokens staged slots a chunk each): over "
+    "engine_prefill_passes, the staged slots that shared one reading of "
+    "the weights",
+)
+ENGINE_PREFILL_CROWDED_PASSES = counter(
+    "engine_prefill_crowded_passes",
+    "in-scan prefill passes that found two or more slots staged: over "
+    "engine_prefill_passes, how often a pass had more than one slot to "
+    "serve",
+)
+ENGINE_PREFILL_CROWDED_NARROW_PASSES = counter(
+    "engine_prefill_crowded_narrow_passes",
+    "of engine_prefill_crowded_passes, those in a dispatch of more than "
+    "one chunk, whose program serves one slot a pass whatever is staged: "
+    "the passes that had more than one slot to serve and served one",
+)
 MOE_PICKS = counter(
     "moe_picks",
     "expert picks computed by the routed layers (live tokens x experts "
@@ -773,6 +799,10 @@ ENGINE_LOOP_COUNTERS: Dict[str, str] = {
     "overrun_lane_steps": ENGINE_OVERRUN_LANE_STEPS,
     "slots_handed_on": ENGINE_SLOTS_HANDED_ON,
     "one_chunk_dispatches": ENGINE_ONE_CHUNK_DISPATCHES,
+    "prefill_passes": ENGINE_PREFILL_PASSES,
+    "prefill_pass_slots": ENGINE_PREFILL_PASS_SLOTS,
+    "prefill_crowded_passes": ENGINE_PREFILL_CROWDED_PASSES,
+    "prefill_crowded_narrow_passes": ENGINE_PREFILL_CROWDED_NARROW_PASSES,
     "moe_picks": MOE_PICKS,
     "moe_experts_reached": MOE_EXPERTS_REACHED,
     "moe_expert_seats": MOE_EXPERT_SEATS,

@@ -3,13 +3,18 @@
 benchmark configuration's own engine (PERF.md section 5 keeps the readings).
 
 Builds the engine as `benchmarks/serve.py` does (weights drawn from the
-seed) and times its K = 2 megastep at the widest cache width, from the
-host's clock around a call that ends in `block_until_ready`:
+seed) and times a megastep of `--chunks` chunks (K) at the widest cache
+width, from the host's clock around a call that ends in `block_until_ready`:
 
 - with nothing staged and no lane live: every iteration is the decode step
   over all slots (a dead lane computes what a live one does);
-- with every slot staged on a prompt of the longest bucket, so that every
-  iteration also serves one prefill chunk: the difference is the chunk.
+- with slots staged on a prompt of the longest bucket (`--staged`: how
+  many, one reading each; all of them where left out), so that every
+  iteration also runs one prefill pass, which serves the oldest staged
+  slots a chunk each (`engine/paged.py` `_admission_chunk`: up to 128 /
+  `prefill_chunk` of them in the program of one chunk, `--chunks 1`; the
+  oldest alone in a longer rung's): the difference is the pass. One slot
+  staged is a pass of one row, all of them a full one.
 
 The state is donated, so each call gets a fresh one (made and staged
 outside the timed region). One JSON line on stdout; `platform` says where
@@ -27,33 +32,36 @@ import os
 import statistics
 import sys
 import time
+from functools import partial
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, REPO)
 
-K = 2  # chunks a dispatch: the rung the cells' controller dispatches most
 
-
-def timed(engine, make_state, calls: int) -> tuple:
+def timed(engine, make_state, calls: int, k: int) -> tuple:
     """Milliseconds of `calls` megastep dispatches, each on a state of its
     own (the first call of a shape compiles and is not among them), and
-    the state the last one left."""
+    the state the last one left with `_megastep_program`'s `served`: its
+    prefill passes, the slot-chunks they served, the passes that found two
+    or more staged."""
     import jax
 
     out = []
     for i in range(calls + 1):
         state = jax.block_until_ready(make_state())
-        keys = jax.block_until_ready(engine._step_keys(K))
+        keys = jax.block_until_ready(engine._step_keys(k))
         t = time.perf_counter()
         with engine.mesh:
             state, *res = engine._megastep(engine.params, state, keys)
         jax.block_until_ready((state, res))
         if i:
             out.append(1e3 * (time.perf_counter() - t))
-    return out, state
+    # Before a routed family's counts.
+    return out, state, res[-2 if engine.family.routed else -1].tolist()
 
 
-def traced_ops(engine, make_state, trace_dir: str, top: int) -> dict:
+def traced_ops(engine, make_state, trace_dir: str, top: int,
+               k: int) -> dict:
     """One more megastep under the profiler: the device's time by
     operation, longest first (`benchmarks/trace.py`)."""
     import jax
@@ -61,7 +69,7 @@ def traced_ops(engine, make_state, trace_dir: str, top: int) -> dict:
     from benchmarks import trace
 
     state = jax.block_until_ready(make_state())
-    keys = jax.block_until_ready(engine._step_keys(K))
+    keys = jax.block_until_ready(engine._step_keys(k))
     jax.profiler.start_trace(trace_dir)
     with engine.mesh:
         jax.block_until_ready(engine._megastep(engine.params, state, keys))
@@ -77,6 +85,14 @@ def main(argv=None) -> int:
                     help="a name under benchmarks/configs/ (gpt2-xl, ...)")
     ap.add_argument("--seed", type=int, default=3000000019)
     ap.add_argument("--calls", type=int, default=5)
+    ap.add_argument("--staged", type=int, action="append",
+                    help="slots to stage for the staged reading, once a "
+                         "reading (default: every slot)")
+    ap.add_argument("--chunks", type=int, default=1, choices=[1, 2, 4, 8],
+                    help="chunks a dispatch (K): 1 is what the cells' "
+                         "controller dispatches while work waits, and the "
+                         "program that holds the pass of several rows; a "
+                         "longer rung serves one slot a pass")
     ap.add_argument("--platform", default="tpu", choices=["tpu", "cpu"])
     ap.add_argument("--trace-dir", default=None,
                     help="also trace one megastep of each kind into this "
@@ -101,51 +117,57 @@ def main(argv=None) -> int:
         return 3
     engine = serve.build_engine(config, args.seed)
     width, bucket = max(engine.widths), engine.bucket
-    iterations = K * engine.chunk
+    iterations = args.chunks * engine.chunk
 
     def idle():
         return engine._init_state(width)
 
-    def staged():
+    def staged(slots):
         state = idle()
-        ids = np.full((1, bucket), engine.tokenizer.pad_id, np.int32)
+        # A prompt of its own a slot, drawn from the seed: a routed
+        # family's chunk reads the experts its tokens pick, and a prompt
+        # of one repeated token picks the same few at every position.
+        draw = np.random.default_rng(args.seed)
         with engine.mesh:
-            for slot in range(engine.slots):
+            for slot in range(slots):
+                ids = draw.integers(0, engine.cfg.vocab_size, (1, bucket),
+                                    dtype=np.int32)
                 state = engine._stage(
                     engine._canon_state(state), engine._i32(slot), ids,
                     np.int32(bucket), np.int32(0), np.int32(slot),
                     jax.random.key_data(jax.random.key(slot)),
+                    *engine._snap_arg(0),
                 )
         return engine._canon_state(state)
 
-    chunks = engine.slots * -(-bucket // engine.prefill_chunk)
-    if chunks < iterations:
-        print(f"{chunks} chunks staged cannot fill {iterations} iterations",
-              file=sys.stderr)
-        return 2
-    t_idle, _ = timed(engine, idle, args.calls)
-    t_staged, after = timed(engine, staged, args.calls)
-    m_idle, m_staged = map(statistics.median, (t_idle, t_staged))
+    t_idle, after, _ = timed(engine, idle, args.calls, args.chunks)
+    m_idle = statistics.median(t_idle)
+    passes = []
+    for slots in args.staged or [engine.slots]:
+        t_staged, _, (ran, served, _) = timed(
+            engine, partial(staged, slots), args.calls, args.chunks)
+        passes.append({
+            "staged": slots, "megastep_staged_ms": t_staged,
+            "passes": ran, "slot_chunks_served": served,
+            "pass_ms": (statistics.median(t_staged) - m_idle) / ran,
+        })
     traces = {}
     if args.trace_dir:
-        for name, make in (("idle", idle), ("staged", staged)):
+        for name, make in (("idle", idle),
+                           ("staged", partial(staged, passes[-1]["staged"]))):
             traces[f"trace_{name}"] = traced_ops(
-                engine, make, os.path.join(args.trace_dir, name), args.top)
+                engine, make, os.path.join(args.trace_dir, name), args.top,
+                args.chunks)
     print(json.dumps({
         "line": "steady_probe", "config": args.config, "platform": platform,
         "device_kind": jax.devices()[0].device_kind, "seed": args.seed,
-        "slots": engine.slots, "width": width, "k": K,
+        "slots": engine.slots, "width": width, "k": args.chunks,
         "iterations": iterations, "prefill_chunk": engine.prefill_chunk,
         "planes": {name: [str(x.dtype), *x.shape] for name, x
                    in after.cache._asdict().items()
                    if x is not None and x.ndim > 1},
-        # Every staged iteration moves its slot's cursor one chunk on.
-        "chunks_served": int(np.sum(np.asarray(after.stage_cursor)))
-        // engine.prefill_chunk,
-        "megastep_idle_ms": t_idle, "megastep_staged_ms": t_staged,
-        "step_ms": m_idle / iterations,
-        "chunk_ms": (m_staged - m_idle) / iterations,
-        **traces,
+        "megastep_idle_ms": t_idle, "step_ms": m_idle / iterations,
+        "passes": passes, **traces,
     }))
     return 0
 

@@ -17,7 +17,7 @@ import dataclasses
 import math
 import os
 import re
-from functools import partial
+from functools import cache, partial
 
 import jax
 import jax.numpy as jnp
@@ -36,6 +36,13 @@ from distributed_lms_raft_llm_tpu.utils import tokenizer as tok_lib
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 HBM_BYTES = 16 * 1024**3  # one TPU v5e chip
+
+# The megastep's two texts: the program of ONE chunk holds the prefill pass
+# of several rows beside the pass of one, a longer rung (2, 4, 8: they
+# differ in the outer scan's length alone) the pass of one row and a state
+# carried by that scan.
+BOTH_RUNGS = pytest.mark.parametrize(
+    "chunks", [1, 2], ids=["one-chunk", "two-chunks"])
 
 
 @pytest.fixture(scope="module")
@@ -324,10 +331,12 @@ def test_trinity_mini_cut_step_and_megastep_compile_and_fit_one_v5e(one_chip):
         assert "ragged-dot" in compiled.as_text()
 
 
-def test_ax_k1_cut_megastep_runs_absorbed_over_the_latent_cache(one_chip):
+@BOTH_RUNGS
+def test_ax_k1_cut_megastep_runs_absorbed_over_the_latent_cache(one_chip,
+                                                                chunks):
     """`ax-k1-1d4e-12of192` at the published widths, from shapes alone, in
     the serving settings of benchmarks/configs/ax-k1.json (32 slots, width
-    2,688, chunk 16, prefill chunks of 32, K = 2): 6.98 GB of bfloat16
+    2,688, chunk 16, prefill chunks of 32): 6.98 GB of bfloat16
     weights beside a latent cache of 0.50 GB; decode attention is the
     kernel `mla_decode` over the cache's one plane, nothing holds the
     cache expanded to heads, and the plane is not copied whole inside the
@@ -347,16 +356,21 @@ def test_ax_k1_cut_megastep_runs_absorbed_over_the_latent_cache(one_chip):
                 sampling=SamplingParams.reference_defaults()),
         donate_argnums=(1,),
     ).lower(params, _with(state, one_chip), _with(jax.eval_shape(
-        lambda: jax.random.split(jax.random.key(0), 2)), one_chip)).compile()
+        lambda: jax.random.split(jax.random.key(0), chunks)),
+        one_chip)).compile()
     ma = mega.memory_analysis()
     assert _device_bytes(ma) < 0.75 * HBM_BYTES
     assert ma.temp_size_in_bytes < 2 * 1024**3
     text = mega.as_text()
     assert "ragged-dot" in text and "mla_decode" in text
     # 12 of 192 experts held: the grouped products run over the first 64
-    # of a pass's 256 sorted picks, and over all of them as the fallback.
-    for rows in (64, 256):
-        assert re.search(rf"ragged-dot\S* = bf16\[{rows},7168\]", text)
+    # of a pass's 256 sorted picks (a decode step's 32 lanes, a prefill
+    # pass of one row's 32 positions), and over all of them as the
+    # fallback; a prefill pass of four rows has 1,024 picks, and runs over
+    # the first 256 of them, or over all: in the program of one chunk alone.
+    for rows, there in ((64, True), (256, True), (1024, chunks == 1)):
+        assert bool(re.search(
+            rf"ragged-dot\S* = bf16\[{rows},7168\]", text)) == there
     # Keys or values of the 64 heads over the cache's width: [.., 64,
     # 2688, 128 | 192 | 256] or its transpose, for one lane or for all.
     expanded = re.findall(
@@ -368,10 +382,12 @@ def test_ax_k1_cut_megastep_runs_absorbed_over_the_latent_cache(one_chip):
         assert _copies_inside_loops(text, plane) == []
 
 
-def test_nemotron3_nano_cut_megastep_updates_the_state_in_place(one_chip):
+@BOTH_RUNGS
+def test_nemotron3_nano_cut_megastep_updates_the_state_in_place(one_chip,
+                                                                chunks):
     """`nemotron3-nano-9l-64of128` at the published widths, from shapes
     alone, in the serving settings of benchmarks/configs/nemotron3-nano.json
-    (16 slots, width 2,688, chunk 8, prefill chunks of 32, K = 2): 6.5 GB
+    (16 slots, width 2,688, chunk 8, prefill chunks of 32): 6.5 GB
     of bfloat16 weights beside the float32 state planes; the decode step's
     state update is the kernel `ssm_step`, no copy of a whole `ssm` plane
     (the cache's or the snapshot rows') lies inside the scans, and the held
@@ -399,7 +415,8 @@ def test_nemotron3_nano_cut_megastep_updates_the_state_in_place(one_chip):
                 sampling=SamplingParams.reference_defaults()),
         donate_argnums=(1,),
     ).lower(params, _with(state, one_chip), _with(jax.eval_shape(
-        lambda: jax.random.split(jax.random.key(0), 2)), one_chip)).compile()
+        lambda: jax.random.split(jax.random.key(0), chunks)),
+        one_chip)).compile()
     ma = mega.memory_analysis()
     assert _device_bytes(ma) < 0.75 * HBM_BYTES
     assert ma.temp_size_in_bytes < 1024**3
@@ -413,10 +430,12 @@ def test_nemotron3_nano_cut_megastep_updates_the_state_in_place(one_chip):
         assert not re.search(re.escape(stack) + r"\S* copy\(", text)
 
 
-def test_kimi_linear_cut_megastep_updates_both_planes_in_place(one_chip):
+@BOTH_RUNGS
+def test_kimi_linear_cut_megastep_updates_both_planes_in_place(one_chip,
+                                                               chunks):
     """`kimi-linear-9l-64of256` at the published widths, from shapes alone,
     in the serving settings of benchmarks/configs/kimi-linear.json (16
-    slots, width 2,688, chunk 8, prefill chunks of 32, K = 2): 9.35 GB of
+    slots, width 2,688, chunk 8, prefill chunks of 32): 9.35 GB of
     bfloat16 weights as held beside the float32 state planes and the
     latent; the decode step's state update is the kernel `kda_step` and its
     attention the kernel `mla_decode`, no copy of a whole `ssm` plane (the
@@ -444,7 +463,8 @@ def test_kimi_linear_cut_megastep_updates_both_planes_in_place(one_chip):
                 sampling=SamplingParams.reference_defaults()),
         donate_argnums=(1,),
     ).lower(params, _with(state, one_chip), _with(jax.eval_shape(
-        lambda: jax.random.split(jax.random.key(0), 2)), one_chip)).compile()
+        lambda: jax.random.split(jax.random.key(0), chunks)),
+        one_chip)).compile()
     ma = mega.memory_analysis()
     assert _device_bytes(ma) < 0.8 * HBM_BYTES
     assert ma.temp_size_in_bytes < 1024**3
@@ -527,9 +547,10 @@ ENTRY %main.1 (a: s8[2,4]) -> s8[2,4] {
     assert _copies_inside_loops(text, "s8[4,2]") == []
 
 
-def _benchmark_megastep(one_chip, preset, quant_kv, width):
+@cache
+def _benchmark_megastep(one_chip, preset, quant_kv, width, chunks):
     """(compiled, state shapes) of a benchmark configuration's megastep
-    (16 slots, chunk 16, prefill chunks of 32, K = 2) for a described
+    (16 slots, chunk 16, prefill chunks of 32, K = `chunks`) for a described
     v5e, lowered from shapes alone: the parameters and results carry the
     layouts the runtime gives arrays at rest, which is what one dispatch
     hands the next."""
@@ -552,11 +573,13 @@ def _benchmark_megastep(one_chip, preset, quant_kv, width):
     ).lower(
         _with(params, one_chip), _with(state, one_chip),
         _with(jax.eval_shape(
-            lambda: jax.random.split(jax.random.key(0), 2)), one_chip),
+            lambda: jax.random.split(jax.random.key(0), chunks)),
+            one_chip),
     ).compile()
     return compiled, state
 
 
+@BOTH_RUNGS
 @pytest.mark.parametrize("preset,quant_kv,width,max_temps", [
     # int8 weights and int8 K/V, as benchmarks/configs/gpt2-xl.json serves
     # it. 5.80 GB of temporaries while a chunk sliced its slot's pages out
@@ -566,15 +589,16 @@ def _benchmark_megastep(one_chip, preset, quant_kv, width):
     ("trinity-mini-1d4e", False, 2688, None),
 ])
 def test_megastep_touches_a_staged_slots_pages_in_place(
-        one_chip, preset, quant_kv, width, max_temps):
+        one_chip, preset, quant_kv, width, max_temps, chunks):
     """The megastep of both benchmark configurations (16 slots, chunk 16,
-    prefill chunks of 32, K = 2) for a described v5e: no copy of a whole K
+    prefill chunks of 32) for a described v5e: no copy of a whole K
     or V plane inside the scans or the staged branch. A chunk that reaches
     its slot's pages through a private `[L, 1, H, W, Dh]` cache makes the
     compiler relay both planes slot-major and back, four whole-plane
     copies for 32 tokens. What the entry computation copies, once a
     dispatch, is the next test's where the planes are GPT-2's."""
-    compiled, state = _benchmark_megastep(one_chip, preset, quant_kv, width)
+    compiled, state = _benchmark_megastep(
+        one_chip, preset, quant_kv, width, chunks)
     k = state.cache.k
     plane = f"{'s8' if quant_kv else 'bf16'}[{','.join(map(str, k.shape))}]"
     text = compiled.as_text()
@@ -610,7 +634,8 @@ def test_tiled_bytes_reads_a_layout():
     assert tiled == held
 
 
-def test_gpt2_xl_int8_planes_ride_the_scans_unpadded(one_chip):
+@BOTH_RUNGS
+def test_gpt2_xl_int8_planes_ride_the_scans_unpadded(one_chip, chunks):
     """gpt2-xl's megastep at int8 K/V, 16 slots, width 384, for a
     described v5e. Every whole `s8` plane in the module (the parameters,
     what the two `while` loops carry, the results) is tiled at most 5%
@@ -625,7 +650,8 @@ def test_gpt2_xl_int8_planes_ride_the_scans_unpadded(one_chip):
     H, T, Dh]`, which the scans carried tiled over (25, 64): 2.56 times
     their bytes; 0.67 now). A change that brings the padded tile or the
     relays back fails here before it reaches the chip."""
-    compiled, state = _benchmark_megastep(one_chip, "gpt2-xl", True, 384)
+    compiled, state = _benchmark_megastep(
+        one_chip, "gpt2-xl", True, 384, chunks)
     text = compiled.as_text()
     k = state.cache.k
     assert k.shape == (48, 16, 1, 384, 1664) and k.dtype == jnp.int8

@@ -128,11 +128,20 @@ def _serve(engine, prompts, metrics=None, stream=False):
     return asyncio.run(run())
 
 
-def test_host_plane_holds_the_loop_spans_nested(tmp_path):
+def test_host_plane_holds_the_loop_spans_nested(tmp_path, monkeypatch):
     from jax.profiler import ProfileData
 
     engine = make_engine()
     engine.warmup()
+    # Every span the sink is handed, one by one: name -> walls in order.
+    handed = {}
+    add = spans.ProgramLog.__call__
+
+    def add_and_keep(log, sp):
+        handed.setdefault(sp.name, []).append(sp.wall_s)
+        add(log, sp)
+
+    monkeypatch.setattr(spans.ProgramLog, "__call__", add_and_keep)
     # What the sink summed, drain after drain (the queue drains it a turn).
     sink = {}
     pop = engine.pop_loop_stats
@@ -195,18 +204,35 @@ def test_host_plane_holds_the_loop_spans_nested(tmp_path):
                 assert any(s <= start and end <= e for s, e in steps), name
     assert checked >= 2 * len(PROMPTS)
     # The sink's sums are the host plane's: as many spans of every name,
-    # and their wall within the few microseconds a span's own clock reads
-    # lie outside its annotation (the profiler's clock is another clock).
+    # their sums the sums of the spans it was handed, and span by span (a
+    # name's spans do not overlap, so both are in time's order) the wall
+    # within the few microseconds a span's own clock reads lie outside its
+    # annotation. Two things this may not assume, both met with six test
+    # workers on the machine. The profiler's clock is another clock and
+    # runs at another RATE (0.12 ms a second faster than the monotonic one
+    # here; a clock under adjustment may be slewed by 0.5), so an
+    # annotation may read longer than its span by a share of its length,
+    # and a step that takes a second under load is no step of 0.2 s. And
+    # the step's thread is now and then descheduled between a span's clock
+    # read and its annotation's, for a time slice that no bound on
+    # microseconds survives: that holds the MEDIAN span of a name to the
+    # microseconds, not each nor their sum. A clock read out of place
+    # would show in every span, and so in the median.
     plane = {}
     for evs in lines:
         for name, start, end in evs:
-            n, wall_s = plane.get(name, (0, 0.0))
-            plane[name] = (n + 1, wall_s + (end - start) / 1e9)
+            plane.setdefault(name, []).append((start, (end - start) / 1e9))
     assert set(sink) == {n for n in plane if n.startswith("engine.")}
     for name, (n, wall_s) in sink.items():
-        assert plane[name][0] == n, name
-        assert plane[name][1] <= wall_s + 1e-4 * n, name
-        assert wall_s - plane[name][1] <= 5e-4 * n + 0.01 * wall_s, name
+        walls = handed[name]
+        inside = [d for _, d in sorted(plane[name])]
+        assert len(inside) == n == len(walls), name
+        assert wall_s == pytest.approx(sum(walls), rel=1e-9), name
+        longest = max(zip(inside, walls), key=lambda dw: dw[0] - dw[1])
+        assert longest[0] <= longest[1] * (1 + 5e-4) + 1e-4, (name, longest)
+        outside = sorted(w - d for d, w in zip(inside, walls))
+        assert outside[(n - 1) // 2] <= 5e-4 + 0.01 * max(walls), (
+            name, outside)
 
 
 # ------------------------------------------------ (c), (e) conservation

@@ -309,42 +309,61 @@ STAGED_TOGETHER = ["what is raft?", "hello world", "explain paging",
                    "k v w x"]
 
 
+@pytest.mark.parametrize("k,chunk", [(1, 16), (8, 2)],
+                         ids=["one-chunk", "eight-chunks"])
 @pytest.mark.parametrize("spec_tokens", [0, 2], ids=["plain", "spec"])
-def test_staged_slots_are_served_every_iteration_in_stage_order(spec_tokens):
+def test_staged_slots_are_served_every_iteration_in_stage_order(
+        spec_tokens, k, chunk):
     """Four slots staged at one dispatch boundary, each needing several
-    prefill chunks: ONE megastep serves them a chunk per scan iteration
-    (not per `chunk` of iterations), oldest staging first, so slot n
-    flips within the first sum(chunks of slots 0..n) rows of the
-    `flipped` plane — and every stream is still the bucketed engine's.
+    prefill chunks: ONE megastep of 16 rows serves them a pass per scan
+    iteration (not per `chunk` of iterations). In the program of one chunk,
+    the rung dispatched while work waits, a pass serves all four a chunk
+    each (128 // 4 rows of chunks, four slots), so slot n flips at the row
+    of its own last chunk, whoever else is staged; in a longer rung a pass
+    serves the oldest staged slot alone, so slot n flips within the first
+    sum(chunks of slots 0..n) rows. Either way every stream is still the
+    bucketed engine's, and the device's counts of passes, of the
+    slot-chunks they served and of the passes that found two or more
+    staged (which in the longer rung served one all the same) are the hand
+    count.
     """
     budget = 4
     eng = PagedEngine(
-        make_config(spec_tokens=spec_tokens), slots=4, chunk=2, inflight=2,
-        megastep=8, megastep_max=8, prefill_chunk_tokens=budget,
+        make_config(spec_tokens=spec_tokens), slots=4, chunk=chunk,
+        inflight=2, megastep=k, megastep_max=k, prefill_chunk_tokens=budget,
     )
     rs = [eng.submit(p) for p in STAGED_TOGETHER]
-    eng.step()  # stage all four, dispatch one K=8 megastep, reap nothing
-    (_, _, _, _, flipped, firsts, snapshot, _), = eng._inflight
+    eng.step()  # stage all four, dispatch one megastep, reap nothing
+    (_, _, _, _, flipped, firsts, snapshot, _, served), = eng._inflight
     assert [r.rid for r in snapshot] == rs, "staged in slot = submit order"
     chunks = [-(-r.prompt_len // budget) for r in snapshot]
-    assert min(chunks) > 1 and sum(chunks) <= 8 * 2
+    assert min(chunks) > 1 and len(set(chunks)) > 1 and sum(chunks) <= 16
+    # The row of each slot's flip, and the passes that ran.
+    if k == 1:
+        last, passes = [need - 1 for need in chunks], max(chunks)
+        crowded = sorted(chunks)[-2]  # until the last but one has flipped
+    else:
+        last = [sum(chunks[: i + 1]) - 1 for i in range(4)]
+        passes = sum(chunks)
+        crowded = passes - chunks[-1]  # until the last is alone
     flipped = np.asarray(flipped)
-    assert flipped.shape == (8, 2, 4) == np.asarray(firsts).shape
+    assert flipped.shape == (k, chunk, 4) == np.asarray(firsts).shape
     rows = flipped.reshape(-1, 4)
-    served = 0
-    for slot, need in enumerate(chunks):
+    for slot, row in enumerate(last):
         assert rows[:, slot].sum() == 1, "one flip per staged slot"
-        served += need
-        # FIFO, one chunk an iteration: its last chunk is row served-1.
-        assert int(np.argmax(rows[:, slot])) == served - 1
-    assert rows[served:].sum() == 0
+        assert int(np.argmax(rows[:, slot])) == row
+    assert rows[passes:].sum() == 0
+    assert np.asarray(served).tolist() == [passes, sum(chunks), crowded]
     out = eng.drain()
     assert [out[r] for r in rs] == expected_answers(
         make_config(), STAGED_TOGETHER)
-    observations = eng.pop_loop_stats()[1]
-    assert observations["staged_iterations"] == [
-        float(sum(chunks[: i + 1]) - 1) for i in range(4)
-    ]
+    counts, observations, _ = eng.pop_loop_stats()
+    assert observations["staged_iterations"] == [float(row) for row in last]
+    assert counts["prefill_passes"] == passes
+    assert counts["prefill_pass_slots"] == sum(chunks)
+    assert counts["prefill_crowded_passes"] == crowded
+    assert counts["prefill_crowded_narrow_passes"] == (0 if k == 1
+                                                       else crowded)
 
 
 # --------------------------------------------- warmup / inventory coverage

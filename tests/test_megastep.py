@@ -442,7 +442,7 @@ def test_dead_lane_account_matches_first_principles():
     rngs = jnp.stack([jax.random.key(1)] + [
         jax.random.key(100 + i) for i in range(k_chunks - 1)
     ])
-    _, _, active, dead, _, _ = _megastep_program(
+    _, _, active, dead, _, _, _ = _megastep_program(
         params, state, rngs, eos_id=eos, spec_tokens=0, prefill_chunk=4,
         **statics
     )
