@@ -245,15 +245,37 @@ def moe_mlp(h: jax.Array, mp: Params, cfg, live: jax.Array):
 
 # What a routed family counts a forward pass, in the order of `counts`:
 # picks computed, experts reached, expert seats offered (experts held x
-# expert layers). A family that holds a share of a layer's experts names a
-# fourth after them, `moe_picks_held`, the picks that landed on the share
-# (`axk1.COUNTERS`; here every pick does), and may name two more after
-# it: `moe_passes_bounded`, the routed layers' passes whose products had
-# fewer rows to run over than picks (`moe.held_rows`), and
-# `moe_passes_compacted`, those of them whose held picks fit. A family's
+# expert layers). A family that holds a share of a layer's experts names
+# three more after them (`SHARE_COUNTERS`): `moe_picks_held`, the picks
+# that landed on the share (here every pick does); `moe_passes_bounded`,
+# the routed layers' passes whose products had fewer rows to run over than
+# picks (`moe.held_rows`: the share's fair part of the pass and twelve
+# deviations more, where that is not every row); and
+# `moe_passes_compacted`, those of them whose held picks fit
+# (`nemotron_h`, whose half of the experts always runs whole, names the
+# first alone and counts in its own loop). A family's
 # tuple is the one thing that says which: its forward hands it to
-# `run_layers`, and `registry.ModelFamily.counters` to the engine.
+# `run_layers` (`layer_counts`), and `registry.ModelFamily.counters` to
+# the engine.
 COUNTERS = ("moe_picks", "moe_experts_reached", "moe_expert_seats")
+SHARE_COUNTERS = COUNTERS + ("moe_picks_held", "moe_passes_bounded",
+                             "moe_passes_compacted")
+
+
+def layer_counts(counters, cfg, live: jax.Array, top_i: jax.Array,
+                 sizes: jax.Array) -> jax.Array:
+    """One routed layer's pass in a family's `counters`, int32
+    [len(counters)]: `moe_mlp`'s picks [B, T, k] and held experts' group
+    sizes, `live` the tokens that routed."""
+    held = jnp.sum(sizes)
+    seen = [held, jnp.sum(sizes > 0).astype(jnp.int32),
+            jnp.asarray(sizes.shape[0], jnp.int32)]
+    if counters != COUNTERS:
+        fit = held_rows(top_i.size, sizes.shape[0], cfg.num_experts)
+        bounded = jnp.asarray(fit < top_i.size, jnp.int32)
+        seen = [jnp.sum(live).astype(jnp.int32) * top_i.shape[-1],
+                *seen[1:], held, bounded, bounded * (held <= fit)]
+    return jnp.stack(seen)
 
 
 def run_layers(params: Params, cfg, x: jax.Array, live: jax.Array,
@@ -268,8 +290,6 @@ def run_layers(params: Params, cfg, x: jax.Array, live: jax.Array,
     subtree is routed (`moe_mlp`), one with `mlp` a dense SwiGLU.
     `counters` is the family's tuple of count names (above)."""
     eps = cfg.rms_norm_eps
-    share = "moe_picks_held" in counters
-    bounds = "moe_passes_bounded" in counters
     routing = []
     counts = jnp.zeros((len(counters),), jnp.int32)
     for layer, lp in enumerate(params["layers"]):
@@ -283,17 +303,7 @@ def run_layers(params: Params, cfg, x: jax.Array, live: jax.Array,
         if "moe" in lp:
             y, top_i, sizes = moe_mlp(h, lp["moe"], cfg, live)
             routing.append(top_i)
-            held = jnp.sum(sizes)
-            seen = [held, jnp.sum(sizes > 0).astype(jnp.int32),
-                    jnp.asarray(sizes.shape[0], jnp.int32)]
-            if share:
-                seen = [jnp.sum(live).astype(jnp.int32) * top_i.shape[-1],
-                        *seen[1:], held]
-            if bounds:
-                fit = held_rows(top_i.size, sizes.shape[0], cfg.num_experts)
-                bounded = jnp.asarray(fit < top_i.size, jnp.int32)
-                seen += [bounded, bounded * (held <= fit)]
-            counts = counts + jnp.stack(seen)
+            counts = counts + layer_counts(counters, cfg, live, top_i, sizes)
         else:
             with jax.named_scope("mlp.dense"):
                 y = swiglu(h, lp["mlp"])

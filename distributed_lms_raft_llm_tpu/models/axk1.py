@@ -31,9 +31,11 @@ the router keeps all `num_experts` outputs and its picks, and the routed
 layer computes its own experts' part of the result plus the shared
 expert's (`afmoe.moe_mlp`, `moe.grouped_swiglu`). What the absent experts
 would add is their chips' to add: no code here stands in for them. A
-share under a quarter of the experts has its grouped products run over a
-prefix of a pass's sorted picks, where the held ones are, and over all of
-them in a pass whose held picks outnumber it (`moe.held_rows`).
+share has its grouped products run over a prefix of a pass's sorted picks,
+where the held ones are: the share's fair part of the pass and twelve
+deviations more (`moe.held_rows`: 64 of a decode row's 256), wherever that
+is not every row, and over all of them in a pass whose held picks
+outnumber the prefix.
 
 The trunk is afmoe's (`afmoe.run_layers`, `afmoe.head`): a list of
 per-layer trees, unrolled, the same `aux` record (`counts` has three more
@@ -58,8 +60,7 @@ Params = Dict[str, Any]
 # afmoe's three counts and, a chip holding a share of a layer's experts,
 # the picks that landed on the share, the routed layers' passes whose
 # products were bounded to a prefix of the rows, and those that fit it.
-COUNTERS = afmoe.COUNTERS + ("moe_picks_held", "moe_passes_bounded",
-                             "moe_passes_compacted")
+COUNTERS = afmoe.SHARE_COUNTERS
 
 
 @dataclasses.dataclass(frozen=True)

@@ -62,9 +62,8 @@ from .common import KVCache, causal_window_mask
 
 Params = Dict[str, Any]
 
-# afmoe's three counts and, a chip holding a share of a layer's experts,
-# the picks that landed on the share (as `nemotron_h.COUNTERS`).
-COUNTERS = afmoe.COUNTERS + ("moe_picks_held",)
+# afmoe's three counts and a share's three (`afmoe.SHARE_COUNTERS`).
+COUNTERS = afmoe.SHARE_COUNTERS
 
 # `linear_attn_config` as published, layers numbered from 1.
 PUBLISHED_FULL = (4, 8, 12, 16, 20, 24, 27)
@@ -244,7 +243,7 @@ def forward(
     rows: Optional[jax.Array] = None,
 ):
     """Run the decoder; returns (logits [B, T, V] float32, updated cache),
-    and with `aux` a third value, {"counts": int32 [4] (`COUNTERS`),
+    and with `aux` a third value, {"counts": int32 [6] (`COUNTERS`),
     "routing": int32 [Le, B, T, k], "attn_in": [La, B, T, D], what the MLA
     layers' projections were given (a comparison reads the latent cache
     against its own float32 product of it)}. Contract in the module

@@ -244,9 +244,10 @@ def grouped_swiglu(x: jax.Array, top_i: jax.Array, top_w: jax.Array,
     run over a static prefix of the sorted rows alone (`held_rows`), and
     over all of them, under a `lax.cond`, in a pass whose held picks
     outnumber that prefix: a bound on the rows, never a capacity, and no
-    pick is dropped. Where the prefix is every row (`first` None, or a
-    share of a quarter and more) there is no `cond` and the program is
-    the one without `among`.
+    pick is dropped. Where the prefix is every row (`first` None, a share
+    of a half of the experts and more, or one whose twelve deviations
+    cover the pass: a quarter of 32 rows) there is no `cond` and the
+    program is the one without `among`.
     """
     def experts(xs, sizes):
         gate = jax.lax.ragged_dot(xs, wg.astype(x.dtype), sizes)
@@ -274,20 +275,33 @@ def grouped_relu2(x: jax.Array, top_i: jax.Array, top_w: jax.Array,
                     experts)
 
 
-# The factor of `held_rows` over the held picks' mean, and the multiple of
-# rows its prefix is rounded up to (a bfloat16 tile's sublanes).
-HELD_ROWS_FACTOR = 4
+# How many deviations past the fair router's mean `held_rows` reaches, and
+# the multiple of rows its prefix is rounded up to (a bfloat16 tile's
+# sublanes).
+HELD_ROWS_DEVIATIONS = 12
 HELD_ROWS_MULTIPLE = 16
 
 
 def held_rows(rows: int, held: int, among: int) -> int:
     """How many of a pass's `rows` sorted picks the grouped products of a
-    share of `held` of the router's `among` experts run over: four times
-    what a fair router sends to the share, rounded up to the kernel's row
-    multiple, and never more than `rows`. At 32 lanes x 8 picks and 12 of
-    192 held, 64 of 256 rows against 16 +- 4 held picks: a bound twelve
-    deviations out, and past it the products run over every row."""
-    fit = -(-HELD_ROWS_FACTOR * rows * held // among)
+    share of `held` of the router's `among` experts run over: what a fair
+    router sends to the share and twelve of its deviations more (a pick
+    lands on the share with p = held / among: rows p + 12 sqrt(rows p
+    (1 - p))), rounded up to the kernel's row multiple, and never more
+    than `rows`. At 32 lanes x 8 picks and 12 of 192 held, 64 of 256 rows
+    against 16 +- 3.9 held picks; at 16 lanes x 8 and 64 of 256 held, 96
+    of 128 against 32 +- 4.9: the deviation falls against the mean as the
+    mean grows, which a factor over the mean cannot follow. Past the
+    prefix the products run over every row. A share of a half of the
+    experts and more runs over every row always: the held picks are half
+    of a pass and more before any deviation, and such a share's programs
+    are the ones it had (`nemotron3-nano`: nothing to gain over 80 of a
+    decode row's 96, PERF.md section 6, PR 48)."""
+    if 2 * held >= among:
+        return rows
+    p = held / among
+    fit = math.ceil(rows * p + HELD_ROWS_DEVIATIONS
+                    * math.sqrt(rows * p * (1.0 - p)))
     fit = -(-fit // HELD_ROWS_MULTIPLE) * HELD_ROWS_MULTIPLE
     return min(fit, rows)
 

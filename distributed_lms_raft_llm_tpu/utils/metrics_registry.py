@@ -645,10 +645,12 @@ MOE_PICKS_HELD = counter(
 MOE_PASSES_BOUNDED = counter(
     "moe_passes_bounded",
     "forward passes of a routed layer whose grouped products had fewer "
-    "rows to run over than the pass had picks: a share of under a quarter "
-    "of the layer's experts, whose held picks sort into a static prefix "
-    "of the rows (models/moe.py `held_rows`); summed over expert layers, "
-    "only a family that holds such a share counts",
+    "rows to run over than the pass had picks: a share of the layer's "
+    "experts, whose held picks sort into a static prefix of the rows, "
+    "what a fair router sends the share and twelve deviations more where "
+    "that is not every row (models/moe.py `held_rows`; a share of a half "
+    "and more runs whole); summed over expert layers, only a family that "
+    "holds a share under a half counts",
 )
 MOE_PASSES_COMPACTED = counter(
     "moe_passes_compacted",

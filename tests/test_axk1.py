@@ -312,19 +312,21 @@ def test_idle_lanes_reach_no_expert_and_land_no_pick(model):
 
 
 def _small_share(skewed):
-    """16 tokens x 4 picks over 32 experts of which 2 are held: 64 rows,
-    of which `held_rows` keeps 16; token 3 is an idle lane. A fair router
-    lands a handful of picks on the share; the skewed one sends every
-    token's first pick there (15 live ones and more: past the prefix)."""
+    """16 tokens x 4 picks over 32 experts of which 4 are held: 64 rows,
+    of which `held_rows` keeps 48 (a fair router's 8 +- 2.6 held picks and
+    twelve deviations, in whole tiles); token 3 is an idle lane. A fair
+    router lands a handful of picks on the share; the skewed one sends
+    every token's four picks there (the 15 live ones' 60: past the
+    prefix)."""
     ks = jax.random.split(jax.random.key(43), 5)
     x = jax.random.normal(ks[0], (16, 32), jnp.float32)
     wr = jax.random.normal(ks[1], (32, 32), jnp.float32)
     if skewed:
-        wr = wr.at[:, 1].set(100.0 * jnp.sign(x.sum(0)))
+        wr = wr.at[:, :4].set(100.0 * jnp.sign(x.sum(0))[:, None])
         x = x + 0.5 * jnp.sign(x.sum(0))[None]
     top_i, top_w = moe.route_sigmoid(x, wr, None, 4, True, 2.5)
     stacks = [0.2 * jax.random.normal(k, shape, jnp.float32) for k, shape
-              in zip(ks[2:], ((2, 32, 16), (2, 32, 16), (2, 16, 32)))]
+              in zip(ks[2:], ((4, 32, 16), (4, 32, 16), (4, 16, 32)))]
     return x, top_i, top_w, jnp.ones((16,), bool).at[3].set(False), stacks
 
 
@@ -346,8 +348,8 @@ def test_a_small_shares_products_over_a_prefix_equal_those_over_all_rows(
         call = partial(moe.grouped_relu2, x, top_i, top_w, live, wu, wd,
                        first=0)
         one = lambda t, e: jnp.square(jax.nn.relu(x[t] @ wu[e])) @ wd[e]
-    fit = moe.held_rows(64, 2, 32)
-    assert fit == 16
+    fit = moe.held_rows(64, 4, 32)
+    assert fit == 48
     y, sizes = call(among=32)
     y_all, sizes_all = call()
     assert ("cond" in str(jax.make_jaxpr(partial(call, among=32))())
@@ -355,9 +357,9 @@ def test_a_small_shares_products_over_a_prefix_equal_those_over_all_rows(
     assert (int(sizes.sum()) > fit) == skewed     # which branch was taken
     np.testing.assert_array_equal(sizes, sizes_all)
     np.testing.assert_array_equal(y, y_all)
-    held = np.asarray((top_i < 2) & live[:, None])
+    held = np.asarray((top_i < 4) & live[:, None])
     np.testing.assert_array_equal(
-        sizes, np.bincount(np.asarray(top_i)[held], minlength=2))
+        sizes, np.bincount(np.asarray(top_i)[held], minlength=4))
     want = np.zeros((16, 32), np.float32)
     for t, j in zip(*np.nonzero(held)):
         want[t] += float(top_w[t, j]) * np.asarray(one(t, int(top_i[t, j])))
@@ -365,23 +367,31 @@ def test_a_small_shares_products_over_a_prefix_equal_those_over_all_rows(
     assert not held[3].any() and not np.asarray(y[3]).any()  # the idle lane
 
 
-@pytest.mark.parametrize("first,among,held", [
-    (None, 32, 32), (0, 32, 16), (8, 32, 8), (0, None, 2)],
-    ids=["all_held", "a_half", "a_quarter", "router_unknown"])
-def test_a_share_of_a_quarter_and_more_has_no_cond_in_its_program(
-        first, among, held):
+@pytest.mark.parametrize("first,among,held,tokens,k", [
+    (None, 32, 32, 8, 4), (0, 32, 16, 8, 4), (8, 32, 8, 8, 4),
+    (0, None, 2, 8, 4), (0, 128, 64, 16, 6), (0, 128, 64, 32, 6),
+    (0, 128, 64, 128, 6)],
+    ids=["all_held", "a_half", "a_quarter", "router_unknown",
+         "nemotron3_nano_decode", "nemotron3_nano_chunk",
+         "nemotron3_nano_wide_pass"])
+def test_a_share_whose_twelve_deviations_cover_every_row_has_no_cond(
+        first, among, held, tokens, k):
     """The programs that must not change: a layer that holds all its
-    experts, or a share whose prefix is every row, lowers to the text it
-    has without `among`, with no conditional in it."""
+    experts, a share whose prefix is every row (a quarter of a tiny
+    family's 32 rows), or a share of a half and more, which runs whole at
+    any size (`nemotron3-nano`'s half of a decode row's 96, of a chunk's
+    192 and of the pass of four rows' 768, of which twelve deviations
+    would be 560), lowers to the text it has without `among`, with no
+    conditional in it."""
     ks = jax.random.split(jax.random.key(1), 4)
-    x = jax.random.normal(ks[0], (8, 32), jnp.float32)
-    top_i = jnp.tile(jnp.arange(4, dtype=jnp.int32)[None] * 8, (8, 1))
-    top_w = jnp.full((8, 4), 0.25, jnp.float32)
-    wg, wu, wd = (0.2 * jax.random.normal(k, shape) for k, shape in zip(
+    x = jax.random.normal(ks[0], (tokens, 32), jnp.float32)
+    top_i = jnp.tile(jnp.arange(k, dtype=jnp.int32)[None] * 8, (tokens, 1))
+    top_w = jnp.full((tokens, k), 1.0 / k, jnp.float32)
+    wg, wu, wd = (0.2 * jax.random.normal(k_, shape) for k_, shape in zip(
         ks[1:], ((held, 32, 16), (held, 32, 16), (held, 16, 32))))
-    live = jnp.ones((8,), bool)
+    live = jnp.ones((tokens,), bool)
     if among is not None:
-        assert moe.held_rows(32, held, among) == 32
+        assert moe.held_rows(tokens * k, held, among) == tokens * k
 
     def text(fn, *stacks, **kw):
         return jax.jit(partial(fn, first=first, **kw)).lower(
@@ -397,14 +407,29 @@ def test_a_share_of_a_quarter_and_more_has_no_cond_in_its_program(
                 x, top_i, top_w, live, *stacks))
 
 
-def test_the_prefix_is_four_times_a_fair_routers_rows_in_whole_tiles():
-    assert moe.held_rows(256, 12, 192) == 64      # the cell: 32 lanes x 8
-    assert moe.held_rows(512, 12, 192) == 128
-    assert moe.held_rows(8 * 24, 12, 192) == 48   # a bucket of 24 tokens
-    assert moe.held_rows(8 * 5, 12, 192) == 16    # 10 rows, rounded up
-    assert moe.held_rows(8, 12, 192) == 8         # never above the rows
-    assert moe.held_rows(96, 64, 128) == 96       # nemotron3-nano: a half
-    assert moe.held_rows(128, 128, 128) == 128
+# Rows a pass has -> rows its products run over, on the cells' own paths:
+# a decode row, a prefill pass of one row's 32 positions, the pass of four.
+@pytest.mark.parametrize("rows,held,among,fit", [
+    (128, 64, 256, 96), (256, 64, 256, 160), (1024, 64, 256, 432),
+    (256, 12, 192, 64), (256, 12, 192, 64), (1024, 12, 192, 160),
+    (96, 64, 128, 96), (192, 64, 128, 192), (768, 64, 128, 768),
+    (768, 63, 128, 560), (8, 12, 192, 8), (128, 128, 128, 128)],
+    ids=["kimi_linear_decode", "kimi_linear_chunk", "kimi_linear_wide_pass",
+         "ax_k1_decode", "ax_k1_chunk", "ax_k1_wide_pass",
+         "nemotron3_nano_decode", "nemotron3_nano_chunk",
+         "nemotron3_nano_wide_pass_a_half_runs_whole",
+         "under_a_half_is_bounded", "never_above_the_rows",
+         "all_held_gives_every_row"])
+def test_the_prefix_is_a_fair_routers_mean_and_twelve_deviations_in_whole_tiles(
+        rows, held, among, fit):
+    assert moe.held_rows(rows, held, among) == fit
+    p = held / among
+    reach = rows * p + moe.HELD_ROWS_DEVIATIONS * (rows * p * (1 - p)) ** 0.5
+    if fit == rows:
+        assert 2 * held >= among or reach > rows - moe.HELD_ROWS_MULTIPLE
+    else:
+        assert 2 * held < among and fit % moe.HELD_ROWS_MULTIPLE == 0
+        assert reach <= fit < reach + moe.HELD_ROWS_MULTIPLE
 
 
 # ------------------------------------------------ the cache, by its bytes
@@ -497,16 +522,17 @@ def served():
 
 
 @pytest.fixture(scope="module")
-def served_a_sixteenth():
-    """`served` by a chip that holds 2 of the 32 experts, a share small
-    enough for its products to run over a prefix of the rows (the decode's
-    4 lanes x 4 picks and the chunk's 8 tokens x 4: 16 of 16 and 16 of 32
-    rows): a preset of this test's, no option of the program's."""
+def served_one_of_32():
+    """`served` by a chip that holds 1 of the 32 experts, a share small
+    enough at these sizes for its products to run over a prefix of the
+    rows (the decode's 4 lanes x 4 picks and the chunk's 8 tokens x 4: 16
+    of 16 and 16 of 32 rows): a preset of this test's, no option of the
+    program's."""
     with pytest.MonkeyPatch.context() as patch:
-        patch.setitem(registry.PRESETS, "axk1-tiny-2of32", (
+        patch.setitem(registry.PRESETS, "axk1-tiny-1of32", (
             registry.AXK1_FAMILY,
-            partial(axk1.AxK1Config.tiny, experts_held=(0, 2))))
-        return _serve(model="axk1-tiny-2of32")
+            partial(axk1.AxK1Config.tiny, experts_held=(0, 1))))
+        return _serve(model="axk1-tiny-1of32")
 
 
 @pytest.fixture(scope="module")
@@ -548,24 +574,31 @@ def test_engine_counts_the_picks_that_land_on_the_share_held(served):
     assert "tokens_past_window" not in counts
     # A spliced token has no forward pass and lands no pick.
     assert rounds[1][2]["moe_picks"] < counts["moe_picks"]
-    # A quarter of the experts: the products run over every row as before.
-    assert counts["moe_passes_bounded"] == counts["moe_passes_compacted"] == 0
+    # A quarter of the experts at these sizes: twelve deviations cover
+    # every row of a decode step's 16 and of a one-row pass's 32, whose
+    # products run over them all; a pass of four rows (128 picks, a prefix
+    # of 96) is bounded, and fits.
+    wide = (counts.get("prefill_crowded_passes", 0)
+            - counts.get("prefill_crowded_narrow_passes", 0))
+    assert (counts["moe_passes_bounded"] == counts["moe_passes_compacted"]
+            == wide * le)
 
 
 def test_engine_counts_the_passes_whose_products_ran_over_the_prefix(
-        served_a_sixteenth):
-    """A sixteenth of the experts: the prefill chunk's passes (32 rows, a
-    prefix of 16) are bounded, the decode's (16 rows, all of them) are
-    not, and under this fair router every bounded pass fits its prefix."""
-    eng, rounds = served_a_sixteenth
+        served_one_of_32):
+    """One expert of 32: the prefill chunk's passes (32 rows, a prefix of
+    16: a fair router's 1 +- 1 held picks and twelve deviations) are
+    bounded, the decode's (16 rows, all of them) are not, and under this
+    fair router every bounded pass fits its prefix."""
+    eng, rounds = served_one_of_32
     counts = rounds[0][2]
     assert metric.is_declared(metric.ENGINE_LOOP_COUNTERS["moe_passes_bounded"])
     le = eng.cfg.num_layers - eng.cfg.num_dense_layers
-    assert eng.cfg.experts_held == (0, 2)
-    assert counts["moe_expert_seats"] % (2 * le) == 0
-    passes = counts["moe_expert_seats"] // (2 * le)
-    assert 0 < counts["moe_passes_bounded"] < passes * le
-    assert counts["moe_passes_bounded"] % le == 0
+    assert eng.cfg.experts_held == (0, 1)
+    assert (moe.held_rows(32, 1, 32), moe.held_rows(16, 1, 32)) == (16, 16)
+    passes = counts["moe_expert_seats"] // le
+    assert 0 < counts["prefill_passes"] < passes
+    assert counts["moe_passes_bounded"] == counts["prefill_passes"] * le
     assert counts["moe_passes_compacted"] == counts["moe_passes_bounded"]
     assert 0 < counts["moe_picks_held"] < counts["moe_picks"] // 4
 

@@ -294,6 +294,78 @@ def test_paged_megastep_tp4_shards_planes_over_four_chips(topo, one_chip,
     assert "all-reduce" in sharded.as_text()
 
 
+# ------------- a share's grouped products: a prefix, and all rows behind it
+
+def _bounded_products(text: str) -> dict:
+    """(rows of the first branch's grouped products, rows of the second's)
+    -> how many `conditional`s of a compiled module's text have such
+    branches: `moe._grouped`'s `lax.cond`, whose first branch runs the
+    products over every sorted pick and whose second over the held picks'
+    prefix. A branch's products are those in its own computation and in
+    what it calls as a fusion; a conditional or a loop inside it is another
+    conditional's or nobody's (the admission's `cond` around a whole prefill
+    pass counts for nothing here)."""
+    calls, rows, pairs, comp = {}, {}, [], None
+    for line in text.splitlines():
+        head = re.match(r"(?:ENTRY )?%?([\w.\-]+) .*\{\s*$", line)
+        if head:
+            comp = head.group(1)
+            calls[comp], rows[comp] = set(), set()
+            continue
+        if comp is None or not line.startswith(" "):
+            continue
+        calls[comp] |= set(re.findall(r"(?:calls|to_apply)=%?([\w.\-]+)",
+                                      line))
+        product = re.search(r"ragged-dot\S* = bf16\[(\d+),\d+\]", line)
+        if product:
+            rows[comp].add(int(product.group(1)))
+        for group in re.findall(r"branch_computations=\{([^}]*)\}", line):
+            pairs.append(re.findall(r"%?([\w.\-]+)", group))
+
+    def reached(c, seen):
+        if c in seen:
+            return set()
+        seen.add(c)
+        return rows.get(c, set()).union(
+            *(reached(d, seen) for d in calls.get(c, ())))
+
+    found = {}
+    for branches in pairs:
+        got = tuple(tuple(sorted(reached(b, set()))) for b in branches)
+        if len(got) == 2 and all(len(r) == 1 for r in got):
+            key = (got[0][0], got[1][0])
+            found[key] = found.get(key, 0) + 1
+    return found
+
+
+def test_bounded_products_reads_a_module_text():
+    text = """
+%fused_dot (p: bf16[96,64]) -> bf16[96,32] {
+  ROOT %ragged-dot-none.3 = bf16[96,32]{1,0} custom-call(%p, %w, %n)
+}
+%all_rows (t: (bf16[128,64])) -> (f32[128,32]) {
+  %ragged-dot-metadata.1 = s32[4,8]{1,0} custom-call(%n)
+  %ragged-dot-none.1 = bf16[128,32]{1,0:T(8,128)(2,1)} custom-call(%x, %w, %n)
+  %ragged-dot-none.2 = bf16[128,64]{1,0} custom-call(%y, %v, %n)
+}
+%prefix (t: (bf16[128,64])) -> (f32[128,32]) {
+  %fusion.1 = bf16[96,32]{1,0} fusion(%x), kind=kOutput, calls=%fused_dot
+}
+%idle (t: (s32[])) -> (s32[]) {
+  ROOT %tuple = (s32[]) tuple(%t)
+}
+%pass (t: (s32[])) -> (s32[]) {
+  %conditional.1 = (f32[128,32]{1,0}) conditional(%fits, %a, %b), branch_computations={%all_rows, %prefix}
+}
+ENTRY %main.1 (a: s32[]) -> s32[] {
+  %conditional.2 = (s32[]) conditional(%staged, %a, %b), branch_computations={%idle, %pass}
+}
+"""
+    assert _bounded_products(text) == {(128, 96): 1}
+    assert _bounded_products(text.replace("bf16[96,", "bf16[128,")) == {
+        (128, 128): 1}
+
+
 # ------------------------------------- Trinity-Mini's cut (models/afmoe.py)
 
 def test_trinity_mini_cut_step_and_megastep_compile_and_fit_one_v5e(one_chip):
@@ -329,6 +401,8 @@ def test_trinity_mini_cut_step_and_megastep_compile_and_fit_one_v5e(one_chip):
         # temporary of 537 MB a projection.
         assert ma.temp_size_in_bytes < 2 * expert_stack
         assert "ragged-dot" in compiled.as_text()
+        # Every expert held: no prefix, and no `cond` around the products.
+        assert _bounded_products(compiled.as_text()) == {}
 
 
 @BOTH_RUNGS
@@ -365,12 +439,14 @@ def test_ax_k1_cut_megastep_runs_absorbed_over_the_latent_cache(one_chip,
     assert "ragged-dot" in text and "mla_decode" in text
     # 12 of 192 experts held: the grouped products run over the first 64
     # of a pass's 256 sorted picks (a decode step's 32 lanes, a prefill
-    # pass of one row's 32 positions), and over all of them as the
-    # fallback; a prefill pass of four rows has 1,024 picks, and runs over
-    # the first 256 of them, or over all: in the program of one chunk alone.
-    for rows, there in ((64, True), (256, True), (1024, chunks == 1)):
-        assert bool(re.search(
-            rf"ragged-dot\S* = bf16\[{rows},7168\]", text)) == there
+    # pass of one row's 32 positions: a fair router's 16 +- 3.9 held picks
+    # and twelve deviations), and over all of them as the fallback; a
+    # prefill pass of four rows has 1,024 picks, and runs over the first
+    # 160 of them, or over all: in the program of one chunk alone.
+    wide = {(1024, 160): 4} if chunks == 1 else {}
+    assert _bounded_products(text) == {(256, 64): 8, **wide}
+    for rows in (64, 256):
+        assert re.search(rf"ragged-dot\S* = bf16\[{rows},7168\]", text)
     # Keys or values of the 64 heads over the cache's width: [.., 64,
     # 2688, 128 | 192 | 256] or its transpose, for one lane or for all.
     expanded = re.findall(
@@ -422,6 +498,15 @@ def test_nemotron3_nano_cut_megastep_updates_the_state_in_place(one_chip,
     assert ma.temp_size_in_bytes < 1024**3
     text = mega.as_text()
     assert "ragged-dot" in text and "ssm_step" in text
+    # A half of the experts held runs whole (`moe.held_rows`): a decode
+    # row's 96 picks, a one-row pass's 192 and the pass of four rows' 768
+    # (the program of one chunk alone; K = 2, 4, 8 differ in the outer
+    # scan's length alone), so no program has a conditional from a routed
+    # layer.
+    assert _bounded_products(text) == {}
+    for rows, there in ((96, True), (192, True), (768, chunks == 1)):
+        assert bool(re.search(
+            rf"ragged-dot\S* = bf16\[{rows},3072\]", text)) == there
     plane = "f32[4,16,64,64,128]"
     assert plane in text
     assert _copies_inside_loops(text, plane) == []
@@ -471,6 +556,19 @@ def test_kimi_linear_cut_megastep_updates_both_planes_in_place(one_chip,
     text = mega.as_text()
     assert "ragged-dot" in text and "kda_step" in text
     assert "mla_decode" in text
+    # 64 of 256 experts held: a decode row's grouped products run over
+    # the first 96 of its 128 sorted picks (a fair router's 32 +- 4.9 held
+    # picks and twelve deviations) inside a `conditional` beside the
+    # products over all 128; a prefill pass of one row over 160 of 256,
+    # the pass of four rows (the program of one chunk alone) over 432 of
+    # 1,024; eight routed layers each. The `cond`s lie inside the scans,
+    # and neither plane is copied for them (below).
+    wide = {(1024, 432): 8} if chunks == 1 else {}
+    assert _bounded_products(text) == {(128, 96): 8, (256, 160): 8, **wide}
+    for rows in (96, 128):
+        for columns in (1024, 2560):
+            assert re.search(
+                rf"ragged-dot\S* = bf16\[{rows},{columns}\]", text)
     for plane in ("f32[7,16,32,128,128]", "bf16[2,16,2688,576]"):
         assert plane in text
         assert _copies_inside_loops(text, plane) == []

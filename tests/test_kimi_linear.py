@@ -21,6 +21,7 @@ state.
 import dataclasses
 import json
 import os
+from functools import partial
 
 import jax
 import jax.numpy as jnp
@@ -41,6 +42,7 @@ from distributed_lms_raft_llm_tpu.models import (
     kda,
     kimi_linear,
     mla,
+    moe,
     registry,
 )
 from distributed_lms_raft_llm_tpu.models.common import rms_norm
@@ -53,7 +55,8 @@ MAX_NEW = 8
 NOTES = "a quorum of nodes agrees on each entry. "
 PROMPTS = (NOTES + "why?", NOTES + "who leads?", "what is a term?")
 FAMILY_COUNTS = ("moe_picks", "moe_experts_reached", "moe_expert_seats",
-                 "moe_picks_held")
+                 "moe_picks_held", "moe_passes_bounded",
+                 "moe_passes_compacted")
 
 
 def _load(name):
@@ -468,6 +471,43 @@ def test_idle_lanes_reach_no_expert_and_land_no_pick(model):
     assert idle["moe_experts_reached"] == 0
 
 
+# ------------------ the cell's decode row over the held picks' prefix alone
+
+
+@pytest.mark.parametrize("biased", [False, True], ids=["fair", "biased"])
+def test_a_decode_rows_products_over_96_rows_equal_those_over_all_128(biased):
+    """The cell's decode row by its counts: 16 lanes x 8 picks over 256
+    experts of which 64 are held, so 128 sorted rows, of which `held_rows`
+    keeps 96 (a fair router's 32 +- 4.9 held picks and twelve deviations).
+    A fair router's pass takes the prefix; one whose balancing bias sends
+    every pick to the share (128 held picks) takes the fallback; either
+    way the output and the group sizes are, to the bit, those of the call
+    that has no bound."""
+    ks = jax.random.split(jax.random.key(48), 5)
+    x = jax.random.normal(ks[0], (16, 32), jnp.float32)
+    wr = jax.random.normal(ks[1], (32, 256), jnp.float32)
+    bias = jnp.where(jnp.arange(256) < 64, 2.0 if biased else 0.0, 0.0)
+    top_i, top_w = moe.route_sigmoid(x, wr, bias, 8, True, 2.446)
+    wg, wu, wd = (0.2 * jax.random.normal(k, shape, jnp.float32)
+                  for k, shape in zip(ks[2:], ((64, 32, 16), (64, 32, 16),
+                                               (64, 16, 32))))
+    call = partial(moe.grouped_swiglu, x, top_i, top_w, jnp.ones((16,), bool),
+                   wg, wu, wd, first=0)
+    fit = moe.held_rows(128, 64, 256)
+    assert fit == 96
+    y, sizes = call(among=256)
+    y_all, sizes_all = call()
+    bounded = str(jax.make_jaxpr(partial(call, among=256))())
+    assert "cond" in bounded and "f32[96,32]" in bounded
+    assert "cond" not in str(jax.make_jaxpr(call)())
+    held = int(np.asarray(top_i < 64).sum())
+    assert int(sizes.sum()) == held > 0
+    assert held == 128 if biased else held <= fit  # which branch was taken
+    np.testing.assert_array_equal(sizes, sizes_all)
+    np.testing.assert_array_equal(y, y_all)
+    assert np.asarray(y).any()
+
+
 # ------------------------------------------------- through the paged engine
 
 
@@ -535,12 +575,31 @@ def test_a_prefix_hit_gives_the_cold_stream(served, alone, round_):
     assert set(counts) <= set(metric.ENGINE_LOOP_COUNTERS)
     for name in FAMILY_COUNTS:
         assert metric.is_declared(metric.ENGINE_LOOP_COUNTERS[name])
-        assert counts[name] > 0
+        assert counts[name] > 0 or name.startswith("moe_passes")
     # A snapshot is every KDA layer's state and window of one sequence.
     state = eng.state.cache
     one = (state.ssm[:, :1].nbytes + state.conv[:, :1].nbytes)
     assert eng.state_snapshot_bytes == eng.prefix_cache.snapshot_bytes
     assert eng.state_snapshot_bytes % one == 0 and eng.state_snapshot_bytes
+
+
+@pytest.mark.parametrize("round_", [0, 1, 2])
+def test_engine_counts_the_passes_whose_products_ran_over_a_prefix(
+        served, round_):
+    """A quarter of the experts at these sizes: twelve deviations cover
+    every row of a decode step's 16 picks and of a one-row pass's 32, and
+    a pass of four rows (128 picks, a prefix of 96) is bounded and, under
+    this fair router, fits: the family publishes both counts."""
+    eng, rounds = served
+    counts = rounds[round_][2]
+    assert eng.family.counters == FAMILY_COUNTS
+    le = eng.cfg.num_layers - eng.cfg.num_dense_layers
+    assert (moe.held_rows(16, 8, 32), moe.held_rows(32, 8, 32),
+            moe.held_rows(128, 8, 32)) == (16, 32, 96)
+    wide = (counts.get("prefill_crowded_passes", 0)
+            - counts.get("prefill_crowded_narrow_passes", 0))
+    assert (counts["moe_passes_bounded"] == counts["moe_passes_compacted"]
+            == wide * le)
 
 
 def test_a_snapshot_at_the_published_sizes_is_15_megabytes():

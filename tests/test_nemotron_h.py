@@ -37,7 +37,12 @@ from distributed_lms_raft_llm_tpu.engine.prefix_cache import (
     PrefixCache,
     StateSnapshot,
 )
-from distributed_lms_raft_llm_tpu.models import mamba2, nemotron_h, registry
+from distributed_lms_raft_llm_tpu.models import (
+    mamba2,
+    moe,
+    nemotron_h,
+    registry,
+)
 from distributed_lms_raft_llm_tpu.models.common import rms_norm
 from distributed_lms_raft_llm_tpu.ops import ssm as ssm_ops
 from distributed_lms_raft_llm_tpu.utils import metrics_registry as metric
@@ -422,6 +427,27 @@ def test_a_request_admitted_from_a_snapshot_gives_the_cold_stream(
                  "prefix_tokens_recomputed_for_state"):
         assert metric.is_declared(metric.ENGINE_LOOP_COUNTERS[name])
     assert eng.state_snapshot_bytes == eng.prefix_cache.snapshot_bytes > 0
+
+
+@pytest.mark.parametrize("round_", [0, 1, 2])
+def test_a_half_of_the_experts_runs_whole_and_counts_no_bounded_pass(
+        served, round_):
+    """A half of the experts and more runs its products over every row at
+    any size (`moe.held_rows`: the tiny family's 12, 24 and 96 picks a
+    pass, the cell's 96, 192 and 768), so the family has no bounded pass
+    to count and publishes the four counts it had."""
+    eng, rounds = served
+    counts = rounds[round_][2]
+    assert eng.family.counters == (
+        "moe_picks", "moe_experts_reached", "moe_expert_seats",
+        "moe_picks_held")
+    for name in eng.family.counters:
+        assert metric.is_declared(metric.ENGINE_LOOP_COUNTERS[name])
+    assert [moe.held_rows(r, 8, 16) for r in (12, 24, 96)] == [12, 24, 96]
+    assert [moe.held_rows(r, 64, 128) for r in (96, 192, 768)] == [
+        96, 192, 768]
+    assert "moe_passes_bounded" not in counts
+    assert 0 < counts["moe_picks_held"] < counts["moe_picks"]
 
 
 def test_a_hit_counts_only_what_was_restored(served):
