@@ -274,9 +274,13 @@ def end_to_end(name: str, ctx: dict):
     """An end-to-end metric by its name: `ttft_p<N>_ms`, `answer_p<N>_ms`
     (nearest rank over every request asked in the window, a failed one
     counting as the client's deadline), `ttft_mean_ms`, `answer_mean_ms`,
-    `out_tok_s`, `setup_s`."""
+    `out_tok_s`, `setup_s`. A name may go on after a dot
+    (`out_tok_s.mid`): the same quantity, listed a second time with a
+    bound and a `workloads` list of its own, for the cells whose runs keep
+    a finer bound than the quantity's every cell can."""
     from benchmarks import readers
 
+    name = name.split(".", 1)[0]
     m = re.fullmatch(r"(ttft|answer)_(?:p(\d+)|(mean))_ms", name)
     if m:
         return readers.client_latency(
@@ -287,6 +291,12 @@ def end_to_end(name: str, ctx: dict):
     if name == "setup_s":
         return ctx["setup_s"], "s"
     raise RunFailure(f"no end-to-end metric is called {name!r}")
+
+
+def grew(now: dict, then: dict) -> dict:
+    """What each series grew by, the ones that did not move left out."""
+    return {k: v - then.get(k, 0) for k, v in sorted(now.items())
+            if v != then.get(k, 0)}
 
 
 def applies(metric: dict, workload: str) -> bool:
@@ -392,6 +402,19 @@ async def run(args) -> int:
         drain_s=max((o.last or 0.0) for o in outcomes) - (t0 + seconds)
         if outcomes else None)
 
+    # What the server counted over the window, mark to collect: the series
+    # the per-layer readers divide, on every run, so that an untraced run's
+    # `out_tok_s` can be laid beside its own passes, dispatches and lanes.
+    then, now = marks["marked"].get("metrics", {}), collected["metrics"]
+
+    def observed(doc: dict) -> dict:
+        return {k: h.get("count", 0)
+                for k, h in doc.get("latency", {}).items()}
+
+    say("window_counters", window_s=collected.get("window_s"),
+        counters=grew(now.get("counters", {}), then.get("counters", {})),
+        observations=grew(observed(now), observed(then)))
+
     say("latencies_ms", **{
         f"{which}_{p if p == 'mean' else 'p' + p}": readers.client_latency(
             {"which": which, "percentile": p}, ctx)
@@ -476,6 +499,10 @@ async def run(args) -> int:
         result["device"]["window_s"] = reduced["window_s"]
         result["breakdown"] = {"device_ops": reduced["device_ops"][:10],
                                "idle_gaps": reduced["idle_gaps"][:10]}
+    # Every number compared beside its limit, last on the result's line.
+    result["compared"] = {
+        line["what"]: {"value": line["value"], "limit": line["limit"]}
+        for line in compared}
     if "jax" in sys.modules:
         raise RunFailure("the load-generating parent imported jax")
     # The same lines close the standard error: of a run that is not correct

@@ -119,8 +119,13 @@ def test_a_configuration_names_its_family_and_its_limits(config):
     doc = load("configs", config + ".json")
     assert doc["family"] in families.names()
     limits = doc["check"]["limits"]
-    assert limits and all(isinstance(v, float) and v > 0
+    # A distance has a limit above 0; an exact comparison (a recurrent
+    # family's `idle_rows_state_change`) has the limit 0.
+    assert limits and all(isinstance(v, float) and v >= 0
                           for v in limits.values())
+    assert any(v > 0 for v in limits.values())
+    assert all(name.endswith("_change") for name, v in limits.items()
+               if v == 0)
 
 
 # ------------------------------------------------------------- counter_ratio

@@ -226,18 +226,33 @@ def test_the_counter_metrics_read_a_share_or_nothing():
     assert readers.read(past["reader"], past["args"], parent) is None
 
 
-def test_the_cell_and_its_metrics_are_appended_to_the_benchmark():
+def named(entries, name):
+    found = [e for e in entries if e["name"] == name]
+    assert len(found) == 1, (name, found)
+    return found[0]
+
+
+def test_the_cell_and_its_metrics_are_found_by_name():
+    # Later PRs append configurations, cells and metrics: an entry is found
+    # by its name, never by its place.
     bench = load(os.pardir, "BENCHMARK.json")
-    assert [c["name"] for c in bench["configs"]][-1] == "trinity-mini"
-    assert [w["name"] for w in bench["workloads"]][-1] == CELL
-    names = [m["name"] for m in bench["per_layer"]]
-    assert tuple(names[-4:]) == NEW_METRICS
-    layers = {m["layer"] for m in bench["per_layer"][:-4]}
-    for m in bench["per_layer"][-4:]:
-        assert m["moves"] == "out_tok_s" and m["workloads"] == [CELL]
+    conf = named(bench["configs"], "trinity-mini")
+    assert conf["file"] == "benchmarks/configs/trinity-mini.json"
+    work = named(bench["workloads"], CELL)
+    assert (work["config"], work["traffic"], work["chips"]) == (
+        "trinity-mini", "notes-herd", 1)
+    layers = {m["layer"] for m in bench["per_layer"]
+              if m["name"] not in NEW_METRICS}
+    for name in NEW_METRICS:
+        m = named(bench["per_layer"], name)
+        # Later routed families list their cells beside this one.
+        assert m["moves"] == "out_tok_s" and CELL in m["workloads"]
         assert os.path.isfile(os.path.join(
-            BENCH, "layer_metrics", m["name"] + ".json"))
-    assert bench["per_layer"][-3]["layer"] in layers
+            BENCH, "layer_metrics", name + ".json"))
+    assert named(bench["per_layer"],
+                 "tokens_past_window_share")["workloads"] == [CELL]
+    assert named(bench["per_layer"],
+                 "tokens_past_window_share")["layer"] in layers
     cell = load("workloads", CELL + ".json")
     spec = load("traffic", cell["traffic"] + ".json")
     assert (cell["config"], cell["students"]) == ("trinity-mini", 32)
