@@ -33,7 +33,10 @@ built (PERF.md section 6, PR 44): the three grouped products over 26 reached
 experts of 64 and 128 rows took 0.835 ms at [2304, 1024], 54% of what their
 bytes need, and 0.708 ms at [2560, 1024] (63% of the published bytes' time,
 70% of the padded bytes'). The padding costs 11% more bytes an expert read
-and 0.81 GB of HBM. `pad_experts` is the one place that knows.
+and 0.81 GB of HBM. `pad_experts` is the one place that knows. The rows are
+tiled too, by the same rule: `moe.tiled_rows` hands the products a pass's
+sorted picks in tiles of 32 (the decode row's prefix of 96 as it is, its
+fallback's 128 as 160, the wide pass's 432 as 480).
 
 Final RMSNorm, untied head, no biases. The trunk is afmoe's
 (`afmoe.run_layers`, `afmoe.head`): a list of per-layer trees, unrolled.

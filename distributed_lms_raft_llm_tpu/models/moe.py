@@ -44,6 +44,7 @@ from __future__ import annotations
 
 import dataclasses
 import math
+from functools import partial
 from typing import Any, Dict
 
 import jax
@@ -221,8 +222,15 @@ def grouped_swiglu(x: jax.Array, top_i: jax.Array, top_w: jax.Array,
     product over the expert stacks (`jax.lax.ragged_dot`: wg, wu [E, D, M],
     wd [E, M, D]; on TPU a grouped-matmul kernel that visits only the
     (row tile, expert) pairs that exist), then weighted and summed back
-    per token. Shapes are static, and each row's product stands alone, so
-    a token's output does not depend on what shares its forward pass. A
+    per token. The kernel tiles the ROWS too, by the largest power of two
+    that divides their count, and an expert it visits multiplies a whole
+    tile for the one or two rows that are its own: the products are handed
+    `tiled_rows` rows, the sorted picks and rows of no expert behind them,
+    so that a tile is 32 rows whatever the pass (a decode row's 16 lanes x
+    8 picks = 128 rows were ONE tile; handed 160 they are five), unless
+    the pass is long enough for a fair router's groups to outgrow a tile.
+    Shapes are static, and each row's product stands alone, so a token's
+    output does not depend on what shares its forward pass. A
     token that is not `live` (an idle or parked lane, a pad position)
     contributes no pick: its picks sort behind every group and belong to
     none, so it reaches no expert, and an expert nobody picked has group
@@ -306,33 +314,62 @@ def held_rows(rows: int, held: int, among: int) -> int:
     return min(fit, rows)
 
 
+# The rows of a tile of the grouped products, where `tiled_rows` has its way.
+ROW_TILE = 32
+
+
+def tiled_rows(rows: int, group: float) -> int:
+    """How many rows the grouped products are handed for `rows` sorted
+    picks: the least count that holds them and whose largest power-of-two
+    divisor is `ROW_TILE`, an odd multiple of 32 (128 -> 160, 256 -> 288,
+    1,024 -> 1,056, 64 -> 96, 432 -> 480; 96 and 160 stay). The TPU's
+    grouped product tiles its rows by that divisor, and every expert it
+    visits multiplies a whole tile: a bound on the tile, never a capacity
+    (the rows added belong to no expert and are cut off again). `group` is
+    a fair router's mean rows an expert in the pass; a pass whose groups
+    outgrow the tile is left as it is, since there the kernel reads an
+    expert once a tile it spans (2,304 positions x 8 picks over 128
+    experts, 144 rows a group: 34.1 ms as they are, 58.3 in tiles of 32;
+    PERF.md section 6, PR 51)."""
+    if group > ROW_TILE:
+        return rows
+    return ROW_TILE * (-(-rows // ROW_TILE) | 1)
+
+
 def _grouped(x, top_i, top_w, live, e: int, first, among, experts):
     """The routed layer around its experts' products (`grouped_swiglu`'s
     contract): `experts(xs, sizes)` takes the picks' rows sorted by expert
-    [S*k, D], or a prefix of them that holds every group, and the `e` held
-    experts' group sizes."""
+    [S*k, D], or a prefix of them that holds every group, with rows of no
+    expert behind them up to `tiled_rows`, and the `e` held experts' group
+    sizes."""
     s, k = top_i.shape
     here = live[:, None]
-    fit = s * k
+    fit, routed = s * k, e
     if first is not None:
         top_i = top_i - first
         here = here & (top_i >= 0) & (top_i < e)
         if among is not None:
-            fit = held_rows(s * k, e, among)
+            fit, routed = held_rows(s * k, e, among), among
     expert = jnp.where(here, top_i, e).reshape(s * k)
     order = jnp.argsort(expert, stable=True)
     sizes = jnp.zeros((e + 1,), jnp.int32).at[expert].add(1)[:e]
 
-    def over_all():
-        return experts(x[order // k], sizes)                 # [S*k, D]
-
-    def over_prefix():
-        return jnp.pad(experts(x[order[:fit] // k], sizes),
-                       [(0, s * k - fit), (0, 0)])
+    def over(rows):
+        """The products of the first `rows` sorted picks, [S*k, D]."""
+        token = order // k if rows == s * k else order[:rows] // k
+        more = tiled_rows(rows, s * k / routed) - rows
+        if more:    # behind every group: token 0's row, an expert's never
+            token = jnp.pad(token, (0, more))
+        out = experts(x[token], sizes)
+        if more:
+            out = out[:rows]
+        return out if rows == s * k else jnp.pad(
+            out, [(0, s * k - rows), (0, 0)])
 
     # Every group lies in the first `fit` rows, or all rows are run.
-    out = (over_all() if fit == s * k else
-           jax.lax.cond(jnp.sum(sizes) <= fit, over_prefix, over_all))
+    out = (over(s * k) if fit == s * k else
+           jax.lax.cond(jnp.sum(sizes) <= fit, partial(over, fit),
+                        partial(over, s * k)))
     # Rows past the last group belong to no expert; whatever the kernel
     # left there is dropped, not scaled by a zero weight.
     w = top_w.reshape(s * k)[order]

@@ -47,8 +47,10 @@ minor-most (section 6, PR 35): of [64, 2688, 1856] that is D, while the
 grouped product takes its stacks M-minor, and the compiled megastep copied
 all four layers' `wu`, 638 MB each, at the head of every dispatch (the
 compiler's own text for a described v5e). The padding costs 26% more bytes
-an expert read and 1.33 GB of HBM. `pad_experts` is the one place that
-knows.
+an expert read and 1.33 GB of HBM. The product's ROWS are tiled the same
+way, and `moe.tiled_rows` hands it a pass's sorted picks in tiles of 32 (a
+decode row's 96 as they are, a pass's 192 and 768 as 224 and 800: PERF.md
+section 6, PR 51). `pad_experts` is the one place that knows.
 
 The trunk is a list of per-layer trees, unrolled, as afmoe's (whose
 `batch_slots` and `head` it uses): the grouped expert product takes whole

@@ -1,7 +1,8 @@
 #!/usr/bin/env python
-"""What a routed layer's grouped products cost on the device over a prefix
-of a pass's sorted picks, by the prefix (PERF.md section 6 keeps the
-readings; `models/moe.py` `held_rows` is what they size).
+"""What a routed layer's grouped products cost on the device by the rows
+they are handed: the prefix of a pass's sorted picks, and the row tile
+(PERF.md section 6 keeps the readings; `models/moe.py` `held_rows` and
+`tiled_rows` are what they size).
 
 A shape is a cell's routed layers alone: `layers` layers of `held` expert
 stacks at the widths the family holds them, `lanes` tokens of `picks` picks
@@ -9,9 +10,13 @@ among `among` experts, drawn from the seed by a router that is fair to the
 share. The layers are `moe.grouped_swiglu` / `moe.grouped_relu2` themselves
 (the sort, the `lax.cond`, its fallback over every row), `--iters` passes in
 one scan so that a call is long beside its dispatch, each pass with picks of
-its own; `held_rows` is replaced by each prefix in turn, and by the rows
-themselves for the program without a `cond`. Every output is compared with
-that program's to the bit, one more call's too whose router sends the share
+its own. A reading is (prefix, tile): `held_rows` is replaced by the prefix
+(None: the rows themselves, the program without a `cond`) and `tiled_rows`
+by the least odd multiple of the tile that holds the rows (None: the rows
+as they are, what the products were handed before PR 51; the TPU's kernel
+tiles a row count by the largest power of two that divides it). The first
+reading is every row as it is. Every output is compared with that
+program's to the bit, one more call's too whose router sends the share
 more picks than any prefix holds (the fallback). A call is repeated
 `--calls` times and every reading printed, so that run-to-run levels show.
 
@@ -20,7 +25,7 @@ stack is embedded as a constant: minutes of compiling a 2 GB program). One
 JSON line a shape on stdout; `platform` says where it ran, and only a TPU's
 line is a measurement.
 
-    chiprun -- python scripts/probe_fit.py --shape kimi-linear-decode
+    chiprun -- python scripts/probe_fit.py --shape trinity-mini-decode
     JAX_PLATFORMS=cpu python scripts/probe_fit.py --platform cpu --small
 """
 
@@ -36,20 +41,53 @@ import time
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, REPO)
 
-# name -> (layers, held, among, D, M, projections, lanes, picks, prefixes):
+# name -> (layers, held, among, D, M, projections, lanes, picks, readings):
 # D and M as the family pads its stacks (`pad_experts`), `lanes` the tokens
-# of the pass, and the prefixes to read beside the pass's own rows.
+# of the pass, and the (prefix, tile) pairs to read beside the pass's own
+# rows as they are.
+TILES = ((None, 16), (None, 32), (None, 64))
 SHAPES = {
-    # kimi-linear.notes-herd's decode row: 16 lanes x 8 picks of 256.
+    # trinity-mini.notes-herd's decode row: 16 lanes x 8 picks, all 128
+    # held: 128 rows as they are (ONE tile), and handed 144, 160 and 192.
+    "trinity-mini-decode": (4, 128, 128, 2048, 1024, 3, 16, 8, TILES),
+    # Its prefill pass of four rows of 32 positions: 1,024 rows.
+    "trinity-mini-wide-pass": (4, 128, 128, 2048, 1024, 3, 128, 8, TILES),
+    # A long pass of the kind the start's reference check runs: 2,304
+    # positions, 144 rows a group, 18,432 rows against 18,464.
+    "trinity-mini-long-pass": (4, 128, 128, 2048, 1024, 3, 2304, 8,
+                               ((None, 32),)),
+    # Between them: 16 and 32 rows a group (256 and 512 positions).
+    "trinity-mini-pass-of-256": (4, 128, 128, 2048, 1024, 3, 256, 8,
+                                 ((None, 32), (None, 64))),
+    "trinity-mini-pass-of-512": (4, 128, 128, 2048, 1024, 3, 512, 8,
+                                 ((None, 32), (None, 64))),
+    # kimi-linear.notes-herd's decode row: 16 lanes x 8 picks of 256; the
+    # prefix of 96 stays, its fallback's 128 rows become 160.
     "kimi-linear-decode": (8, 64, 256, 2560, 1024, 3, 16, 8,
-                           (48, 64, 80, 96, 112)),
+                           ((48, None), (64, None), (80, None), (96, None),
+                            (112, None), (96, 32))),
+    # Its prefill pass of four rows: a prefix of 432 (tile 16) against 480.
+    "kimi-linear-wide-pass": (8, 64, 256, 2560, 1024, 3, 128, 8,
+                              ((432, None), (432, 32))),
     # ax-k1.notes-crowd's prefill pass of four rows of 32 positions.
-    "ax-k1-wide-pass": (4, 12, 192, 7168, 2048, 3, 128, 8, (160, 256)),
-    # ax-k1.notes-crowd's decode row: 32 lanes x 8 picks of 192.
-    "ax-k1-decode": (4, 12, 192, 7168, 2048, 3, 32, 8, (48, 64)),
+    "ax-k1-wide-pass": (4, 12, 192, 7168, 2048, 3, 128, 8,
+                        ((160, None), (256, None))),
+    # ax-k1.notes-crowd's decode row: 32 lanes x 8 picks of 192; the prefix
+    # of 64 against 96, the fallback's 256 against 288.
+    "ax-k1-decode": (4, 12, 192, 7168, 2048, 3, 32, 8,
+                     ((48, None), (64, None), (64, 32))),
     # nemotron3-nano.notes-herd's decode row: 16 lanes x 6 picks of 128.
-    "nemotron3-nano-decode": (4, 64, 128, 3072, 2048, 2, 16, 6, (80,)),
+    "nemotron3-nano-decode": (4, 64, 128, 3072, 2048, 2, 16, 6,
+                              ((80, None),)),
+    # Its prefill pass of four rows, whole: 768 rows against 800.
+    "nemotron3-nano-wide-pass": (4, 64, 128, 3072, 2048, 2, 128, 6,
+                                 ((None, 32),)),
 }
+
+
+def handed(rows: int, tile) -> int:
+    """Rows the products are handed for `rows` at a reading's `tile`."""
+    return rows if tile is None else tile * (-(-rows // tile) | 1)
 
 
 def draw_picks(rng, passes, layers, lanes, picks, among, held, biased):
@@ -63,23 +101,25 @@ def draw_picks(rng, passes, layers, lanes, picks, among, held, biased):
     return np.argsort(keys, axis=-1)[..., :picks].astype(np.int32)
 
 
-def build(projections, among, fit):
-    """The jitted call with `held_rows` giving `fit` (None: every row):
-    (xs [P, S, D], picks [P, L, S, k], stacks) -> (x after the last layer
-    [P, S, D], held experts' group sizes [P, L, E])."""
+def build(projections, held, among, fit, tile):
+    """The jitted call with `held_rows` giving `fit` (None: every row) and
+    `tiled_rows` the rows' `handed` at `tile`: (xs [P, S, D], picks [P, L,
+    S, k], stacks) -> (x after the last layer [P, S, D], held experts'
+    group sizes [P, L, E])."""
     import jax
     import jax.numpy as jnp
 
     from distributed_lms_raft_llm_tpu.models import moe
 
     grouped = moe.grouped_swiglu if projections == 3 else moe.grouped_relu2
+    first = None if held == among else 0
 
     def one_pass(stacks, x, top_i):
         live = jnp.ones((x.shape[0],), bool)
         top_w = jnp.full(top_i.shape[1:], 1.0 / top_i.shape[-1], jnp.float32)
         sizes = []
         for layer, ws in enumerate(zip(*stacks)):
-            y, n = grouped(x, top_i[layer], top_w, live, *ws, first=0,
+            y, n = grouped(x, top_i[layer], top_w, live, *ws, first=first,
                            among=among)
             x = x + y
             # Keep 240 layers in a row finite: unit rows, as a norm would.
@@ -95,13 +135,14 @@ def build(projections, among, fit):
         return jax.lax.scan(body, None, (xs, picks))[1]
 
     def traced(*args):
-        # `_grouped` asks the module for the prefix when it is traced.
-        real = moe.held_rows
-        moe.held_rows = lambda rows, held, among_: rows if fit is None else fit
+        # `_grouped` asks the module for both when it is traced.
+        real = moe.held_rows, moe.tiled_rows
+        moe.held_rows = lambda rows, held_, among_: fit or rows
+        moe.tiled_rows = lambda rows, group: handed(rows, tile)
         try:
             return call(*args)
         finally:
-            moe.held_rows = real
+            moe.held_rows, moe.tiled_rows = real
 
     return jax.jit(traced)
 
@@ -125,10 +166,10 @@ def probe(name, args) -> dict:
     import jax.numpy as jnp
     import numpy as np
 
-    layers, held, among, d, m, projections, lanes, picks, prefixes = (
+    layers, held, among, d, m, projections, lanes, picks, pairs = (
         SHAPES[name])
     if args.small:
-        layers, d, m = 2, 128, 64
+        layers, d, m, lanes = 2, 128, 64, min(lanes, 144)
     rows = lanes * picks
     rng = np.random.default_rng(args.seed)
     keys = jax.random.split(jax.random.key(args.seed), layers * projections)
@@ -149,22 +190,26 @@ def probe(name, args) -> dict:
                         True)
     held_picks = (fair < held).sum(axis=(2, 3))
 
-    whole = build(projections, among, None)
+    dev = jax.devices()[0]
+    whole = build(projections, held, among, None, None)
     ms, want = timed(whole, (xs, fair, stacks), args.calls)
     _, want_biased = timed(whole, (xs, biased, stacks), 0)
     assert int(np.asarray(want_biased[1]).sum(-1).min()) == rows
-    readings = [{"prefix": rows, "cond": False, "ms": ms,
+    readings = [{"prefix": rows, "tile": None, "handed": rows,
+                 "cond": False, "ms": ms,
                  "pass_ms": statistics.median(ms) / args.iters}]
-    for fit in prefixes:
-        fn = build(projections, among, fit)
+    for fit, tile in pairs:
+        fit = min(fit or rows, rows)
+        fn = build(projections, held, among, fit, tile)
         ms, got = timed(fn, (xs, fair, stacks), args.calls)
         ms_biased, got_biased = timed(fn, (xs, biased, stacks), 1)
         same = all(np.array_equal(np.asarray(a), np.asarray(b))
                    for pair in ((got, want), (got_biased, want_biased))
                    for a, b in zip(*pair))
         readings.append({
-            "prefix": fit, "cond": True, "ms": ms,
-            "pass_ms": statistics.median(ms) / args.iters,
+            "prefix": fit, "tile": tile, "handed": handed(fit, tile),
+            "handed_fallback": handed(rows, tile), "cond": fit < rows,
+            "ms": ms, "pass_ms": statistics.median(ms) / args.iters,
             "passes_that_fit": int((held_picks <= fit).sum()),
             "fallback_pass_ms": ms_biased[0] / args.iters,
             "equal_to_the_bit": same,
@@ -172,7 +217,7 @@ def probe(name, args) -> dict:
     base = readings[0]["pass_ms"]
     for r in readings:
         r["against_all_rows"] = r["pass_ms"] / base - 1.0
-    dev = jax.devices()[0]
+        r["platform"] = dev.platform
     return {
         "line": "probe_fit", "shape": name, "platform": dev.platform,
         "device_kind": dev.device_kind, "seed": args.seed,

@@ -14,6 +14,8 @@ prefill and through a prefix splice, as `engine/generate` answers them.
 import dataclasses
 import json
 import os
+import types
+from functools import partial
 
 import jax
 import jax.numpy as jnp
@@ -229,6 +231,92 @@ def test_a_tokens_output_does_not_depend_on_what_shares_its_pass():
     alone, _ = moe.grouped_swiglu(x[15:], top_i[15:], top_w[15:],
                                   jnp.ones((1,), bool), wg, wu, wd)
     np.testing.assert_allclose(among[15], alone[0], rtol=1e-5, atol=1e-6)
+
+
+# The cells' own passes by their counts: (tokens, picks, held, among) ->
+# the rows the grouped products are handed, over the prefix and over all
+# rows (one number where the prefix is every row). A decode row, a prefill
+# pass of one row's 32 positions, the pass of four rows.
+_PASSES = {
+    "trinity_mini_decode": (16, 8, 128, 128, {160}),
+    "trinity_mini_chunk": (32, 8, 128, 128, {288}),
+    "trinity_mini_wide_pass": (128, 8, 128, 128, {1056}),
+    "ax_k1_decode_and_chunk": (32, 8, 12, 192, {96, 288}),
+    "ax_k1_wide_pass": (128, 8, 12, 192, {160, 1056}),
+    "nemotron3_nano_decode": (16, 6, 64, 128, {96}),
+    "nemotron3_nano_chunk": (32, 6, 64, 128, {224}),
+    "nemotron3_nano_wide_pass": (128, 6, 64, 128, {800}),
+    "kimi_linear_decode": (16, 8, 64, 256, {96, 160}),
+    "kimi_linear_chunk": (32, 8, 64, 256, {160, 288}),
+    "kimi_linear_wide_pass": (128, 8, 64, 256, {480, 1056}),
+}
+
+
+@pytest.mark.parametrize("experts", ["swiglu", "relu2"])
+@pytest.mark.parametrize("skewed", [False, True], ids=["fair", "skewed"])
+@pytest.mark.parametrize("shape", list(_PASSES))
+def test_the_products_are_handed_row_tiles_of_32_and_give_the_same_numbers(
+        monkeypatch, shape, skewed, experts):
+    """The grouped products are handed an odd multiple of 32 rows, the
+    sorted picks and rows of no expert behind them (`moe.tiled_rows`), and
+    the layer's output, its group sizes and the family's counts are, to
+    the bit, those of the products over the rows as they were; a pass whose
+    router sends every pick to the share overflows the prefix, takes the
+    fallback over all rows and loses no pick."""
+    tokens, k, held, among, handed = _PASSES[shape]
+    rows = tokens * k
+    ks = jax.random.split(jax.random.key(51), 5)
+    x = jax.random.normal(ks[0], (tokens, 32), jnp.float32)
+    wr = jax.random.normal(ks[1], (32, among), jnp.float32)
+    bias = jnp.where(jnp.arange(among) < held, 4.0 if skewed else 0.0, 0.0)
+    top_i, top_w = moe.route_sigmoid(x, wr, bias, k, True, 2.5)
+    live = jnp.ones((tokens,), bool).at[3].set(False)
+    wg, wu, wd = (0.2 * jax.random.normal(k_, sh, jnp.float32)
+                  for k_, sh in zip(ks[2:], ((held, 32, 16), (held, 32, 16),
+                                             (held, 16, 32))))
+    first = None if held == among else 0
+    if experts == "swiglu":
+        call = partial(moe.grouped_swiglu, x, top_i, top_w, live, wg, wu, wd,
+                       first=first, among=among)
+    else:
+        call = partial(moe.grouped_relu2, x, top_i, top_w, live, wu, wd,
+                       first=first, among=among)
+    seen = set()
+    real = jax.lax.ragged_dot
+
+    def ragged_dot(lhs, rhs, sizes):
+        seen.add(lhs.shape[0])
+        return real(lhs, rhs, sizes)
+
+    monkeypatch.setattr(jax.lax, "ragged_dot", ragged_dot)
+    y, sizes = call()
+    assert seen == handed and all(n % 64 == 32 for n in seen)
+    fit = moe.held_rows(rows, held, among)
+    assert handed == {moe.tiled_rows(fit, rows / among),
+                      moe.tiled_rows(rows, rows / among)}
+    monkeypatch.setattr(moe, "tiled_rows", lambda n, group: n)
+    seen.clear()
+    y_was, sizes_was = call()
+    assert seen == {fit, rows}
+    np.testing.assert_array_equal(sizes, sizes_was)
+    np.testing.assert_array_equal(y, y_was)
+    # No pick is dropped, whichever branch ran; the idle lane reaches none.
+    here = np.asarray((top_i < held) & live[:, None])
+    np.testing.assert_array_equal(
+        sizes, np.bincount(np.asarray(top_i)[here], minlength=held))
+    if skewed:
+        assert int(sizes.sum()) == (tokens - 1) * k
+        assert fit == rows or int(sizes.sum()) > fit      # the fallback
+    else:
+        assert 0 < int(sizes.sum()) <= fit
+    assert np.asarray(y).any() and not np.asarray(y[3]).any()
+    counted = afmoe.layer_counts(
+        afmoe.SHARE_COUNTERS, types.SimpleNamespace(num_experts=among),
+        live[None], top_i[None], sizes)
+    assert list(np.asarray(counted)) == [
+        (tokens - 1) * k, int((np.asarray(sizes) > 0).sum()), held,
+        int(sizes.sum()), int(fit < rows),
+        int(fit < rows and int(sizes.sum()) <= fit)]
 
 
 @pytest.mark.parametrize("norm", [True, False])

@@ -432,6 +432,33 @@ def test_the_prefix_is_a_fair_routers_mean_and_twelve_deviations_in_whole_tiles(
         assert reach <= fit < reach + moe.HELD_ROWS_MULTIPLE
 
 
+# Rows a pass's products run over, a fair router's mean rows an expert ->
+# rows they are handed: the least odd multiple of 32 that holds them, so
+# that the kernel's row tile, the largest power of two that divides the
+# count, is 32 whatever the pass; a pass whose groups outgrow the tile is
+# left as it is.
+@pytest.mark.parametrize("rows,group,handed", [
+    (128, 1, 160), (256, 2, 288), (1024, 8, 1056), (64, 1.33, 96),
+    (192, 1.5, 224), (768, 6, 800), (432, 4, 480), (96, 0.75, 96),
+    (160, 5.33, 160), (1, 0.1, 32), (32, 1, 32), (33, 1, 96), (12, 1.5, 32),
+    (4096, 32, 4128), (18432, 144, 18432), (4104, 32.06, 4104)],
+    ids=["trinity_mini_decode", "chunk_and_fallbacks", "wide_pass",
+         "ax_k1_prefix", "nemotron3_nano_chunk", "nemotron3_nano_wide_pass",
+         "kimi_linear_wide_prefix", "a_decode_row_of_96_stays",
+         "a_prefix_of_160_stays", "one_row", "one_tile", "a_row_past_a_tile",
+         "a_tiny_familys_pass", "a_group_of_a_tile",
+         "a_reference_checks_long_pass_is_left",
+         "a_group_past_a_tile_is_left"])
+def test_the_products_are_handed_the_least_odd_multiple_of_32_that_holds_them(
+        rows, group, handed):
+    assert moe.tiled_rows(rows, group) == handed
+    if group > moe.ROW_TILE:
+        assert handed == rows
+    else:
+        assert handed >= rows and handed % 64 == moe.ROW_TILE == 32
+        assert handed - rows < 64 and moe.tiled_rows(handed, group) == handed
+
+
 # ------------------------------------------------ the cache, by its bytes
 
 

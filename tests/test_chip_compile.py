@@ -296,6 +296,10 @@ def test_paged_megastep_tp4_shards_planes_over_four_chips(topo, one_chip,
 
 # ------------- a share's grouped products: a prefix, and all rows behind it
 
+# A grouped product in a compiled module's text, and its rows.
+_PRODUCT = re.compile(r"ragged-dot\S* = bf16\[(\d+),\d+\]")
+
+
 def _bounded_products(text: str) -> dict:
     """(rows of the first branch's grouped products, rows of the second's)
     -> how many `conditional`s of a compiled module's text have such
@@ -316,7 +320,7 @@ def _bounded_products(text: str) -> dict:
             continue
         calls[comp] |= set(re.findall(r"(?:calls|to_apply)=%?([\w.\-]+)",
                                       line))
-        product = re.search(r"ragged-dot\S* = bf16\[(\d+),\d+\]", line)
+        product = _PRODUCT.search(line)
         if product:
             rows[comp].add(int(product.group(1)))
         for group in re.findall(r"branch_computations=\{([^}]*)\}", line):
@@ -336,6 +340,16 @@ def _bounded_products(text: str) -> dict:
             key = (got[0][0], got[1][0])
             found[key] = found.get(key, 0) + 1
     return found
+
+
+def _product_rows(text: str) -> set:
+    """The row counts of every grouped product in a compiled module's
+    text; each must be an odd multiple of 32 (`moe.tiled_rows`): the TPU's
+    kernel tiles its rows by the largest power of two that divides their
+    count, and an expert it visits multiplies a whole tile."""
+    rows = set(map(int, _PRODUCT.findall(text)))
+    assert rows and all(n % 64 == 32 for n in rows), rows
+    return rows
 
 
 def test_bounded_products_reads_a_module_text():
@@ -364,6 +378,10 @@ ENTRY %main.1 (a: s32[]) -> s32[] {
     assert _bounded_products(text) == {(128, 96): 1}
     assert _bounded_products(text.replace("bf16[96,", "bf16[128,")) == {
         (128, 128): 1}
+    with pytest.raises(AssertionError):
+        _product_rows(text)                  # 128 rows are ONE tile
+    assert _product_rows(text.replace("bf16[128,", "bf16[160,")) == {
+        96, 160}
 
 
 # ------------------------------------- Trinity-Mini's cut (models/afmoe.py)
@@ -403,6 +421,12 @@ def test_trinity_mini_cut_step_and_megastep_compile_and_fit_one_v5e(one_chip):
         assert "ragged-dot" in compiled.as_text()
         # Every expert held: no prefix, and no `cond` around the products.
         assert _bounded_products(compiled.as_text()) == {}
+    # A decode row's 16 lanes x 8 picks = 128 sorted rows are handed to the
+    # products as 160, five tiles of 32 and not one of 128; the one-chunk
+    # megastep's prefill passes of 256 and 1,024 rows as 288 and 1,056.
+    assert _product_rows(step.as_text()) == {160}
+    assert _product_rows(mega.as_text()) == {160, 288, 1056}
+    assert re.search(r"ragged-dot\S* = bf16\[160,2048\]", step.as_text())
 
 
 @BOTH_RUNGS
@@ -442,10 +466,13 @@ def test_ax_k1_cut_megastep_runs_absorbed_over_the_latent_cache(one_chip,
     # pass of one row's 32 positions: a fair router's 16 +- 3.9 held picks
     # and twelve deviations), and over all of them as the fallback; a
     # prefill pass of four rows has 1,024 picks, and runs over the first
-    # 160 of them, or over all: in the program of one chunk alone.
-    wide = {(1024, 160): 4} if chunks == 1 else {}
-    assert _bounded_products(text) == {(256, 64): 8, **wide}
-    for rows in (64, 256):
+    # 160 of them, or over all: in the program of one chunk alone. The
+    # products are handed those rows in tiles of 32 (`moe.tiled_rows`): 96
+    # for the 64, 288 for the 256, 1,056 for the 1,024; 160 stays.
+    wide = {(1056, 160): 4} if chunks == 1 else {}
+    assert _bounded_products(text) == {(288, 96): 8, **wide}
+    assert _product_rows(text) == {96, 288} | {n for p in wide for n in p}
+    for rows in (96, 288):
         assert re.search(rf"ragged-dot\S* = bf16\[{rows},7168\]", text)
     # Keys or values of the 64 heads over the cache's width: [.., 64,
     # 2688, 128 | 192 | 256] or its transpose, for one lane or for all.
@@ -504,9 +531,14 @@ def test_nemotron3_nano_cut_megastep_updates_the_state_in_place(one_chip,
     # scan's length alone), so no program has a conditional from a routed
     # layer.
     assert _bounded_products(text) == {}
-    for rows, there in ((96, True), (192, True), (768, chunks == 1)):
+    # A decode row's 96 rows are three tiles of 32 as they are: its
+    # products are the ones they were; the passes' 192 and 768 rows are
+    # handed as 224 and 800 (`moe.tiled_rows`).
+    for rows, there in ((96, True), (224, True), (800, chunks == 1)):
         assert bool(re.search(
             rf"ragged-dot\S* = bf16\[{rows},3072\]", text)) == there
+    wide = {800} if chunks == 1 else set()
+    assert _product_rows(text) == {96, 224} | wide
     plane = "f32[4,16,64,64,128]"
     assert plane in text
     assert _copies_inside_loops(text, plane) == []
@@ -563,9 +595,13 @@ def test_kimi_linear_cut_megastep_updates_both_planes_in_place(one_chip,
     # the pass of four rows (the program of one chunk alone) over 432 of
     # 1,024; eight routed layers each. The `cond`s lie inside the scans,
     # and neither plane is copied for them (below).
-    wide = {(1024, 432): 8} if chunks == 1 else {}
-    assert _bounded_products(text) == {(128, 96): 8, (256, 160): 8, **wide}
-    for rows in (96, 128):
+    # What the products are handed is in tiles of 32 (`moe.tiled_rows`):
+    # the prefixes of 96 and 160 as they are, 480 for the 432, and the
+    # fallbacks' 128, 256 and 1,024 as 160, 288 and 1,056.
+    wide = {(1056, 480): 8} if chunks == 1 else {}
+    assert _bounded_products(text) == {(160, 96): 8, (288, 160): 8, **wide}
+    assert _product_rows(text) == {96, 160, 288} | {n for p in wide for n in p}
+    for rows in (96, 160):
         for columns in (1024, 2560):
             assert re.search(
                 rf"ragged-dot\S* = bf16\[{rows},{columns}\]", text)

@@ -488,8 +488,14 @@ def test_fifty_milliseconds_land_where_they_belong(
         return out
 
     monkeypatch.setattr(warm_engine, where, with_fifty_ms)
-    _serve_turns(warm_engine, monkeypatch, turns)
-    parts = _parts(turns[at[0]])
+    # Under six workers the machine can put 50 ms of its own into the same
+    # turn (ROADMAP D22): such a turn is served again, twice at most.
+    for _ in range(3):
+        del turns[:], at[:]
+        _serve_turns(warm_engine, monkeypatch, turns)
+        parts = _parts(turns[at[0]])
+        if all(p < 45_000 for i, p in enumerate(parts) if i != part):
+            break
     assert parts[part] >= 45_000, parts
     assert all(p < 45_000 for i, p in enumerate(parts) if i != part), parts
     if phase:

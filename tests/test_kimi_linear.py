@@ -498,8 +498,13 @@ def test_a_decode_rows_products_over_96_rows_equal_those_over_all_128(biased):
     y, sizes = call(among=256)
     y_all, sizes_all = call()
     bounded = str(jax.make_jaxpr(partial(call, among=256))())
+    # The prefix of 96 rows is three tiles of 32 as it is; the fallback's
+    # 128 rows are handed 160 (`moe.tiled_rows`), and so is the call that
+    # has no bound.
     assert "cond" in bounded and "f32[96,32]" in bounded
-    assert "cond" not in str(jax.make_jaxpr(call)())
+    assert "f32[160,32]" in bounded and "f32[128,16]" not in bounded
+    unbounded = str(jax.make_jaxpr(call)())
+    assert "cond" not in unbounded and "f32[160,32]" in unbounded
     held = int(np.asarray(top_i < 64).sum())
     assert int(sizes.sum()) == held > 0
     assert held == 128 if biased else held <= fit  # which branch was taken

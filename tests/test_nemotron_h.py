@@ -446,6 +446,10 @@ def test_a_half_of_the_experts_runs_whole_and_counts_no_bounded_pass(
     assert [moe.held_rows(r, 8, 16) for r in (12, 24, 96)] == [12, 24, 96]
     assert [moe.held_rows(r, 64, 128) for r in (96, 192, 768)] == [
         96, 192, 768]
+    # What the products are handed: a decode row's 96 rows as they are
+    # (three tiles of 32), the passes' in tiles of 32 too.
+    assert [moe.tiled_rows(r, r / 128) for r in (96, 192, 768)] == [
+        96, 224, 800]
     assert "moe_passes_bounded" not in counts
     assert 0 < counts["moe_picks_held"] < counts["moe_picks"]
 
