@@ -537,7 +537,8 @@ def four_chip_path(*, model="gpt2-large", tp=4, max_new_tokens=48,
     check(all(want) and all(got), "an engine produced an empty stream")
 
     # Same model, same numbers: the full-sequence log-likelihood of the
-    # same texts through both engines' score programs.
+    # same texts through the sharded and the single engine's score
+    # programs.
     lp4 = [r["logprob"] for r in sharded.score(prompts)]
     lp1 = [r["logprob"] for r in single.score(prompts)]
     check(all(np.isfinite(lp4)) and all(np.isfinite(lp1)),

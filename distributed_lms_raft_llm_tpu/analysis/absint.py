@@ -49,6 +49,10 @@ from typing import Callable, Dict, Iterable, List, Optional, Sequence, Set, Tupl
 from .project import FunctionInfo, ModuleInfo, Project, _dotted
 
 ENGINE_PREFIX = "distributed_lms_raft_llm_tpu/engine/"
+# The bucketed reference generator (`TutoringEngine`): the tests hold the
+# served engine's answers equal to it and nothing serves it, so its jit
+# sites make no warm-up claim and stay out of the program inventory.
+REFERENCE_REL = ENGINE_PREFIX + "engine.py"
 
 
 class _Unknown:

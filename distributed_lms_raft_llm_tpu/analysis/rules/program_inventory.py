@@ -17,8 +17,11 @@ the loop on every lint run:
 - **warmup-miss**: an entry with `coverage="warmup"` whose owning class
   has a `warmup` method from which no call to that program is reachable
   (call-graph closure, so coverage through helpers like
-  `TutoringEngine.warmup -> generate_ids` counts). Deleting one warmup
+  `PagedEngine.warmup -> _warm_score` counts). Deleting one warmup
   step fails here before the runtime guard ever runs.
+
+`absint.REFERENCE_REL` (the tests' reference generator) is not scanned:
+it serves nothing, so it has no program to inventory.
 
 Matching keys on (engine, attr, target) — line numbers drift with
 unrelated edits and are deliberately not part of the manifest.
@@ -195,7 +198,7 @@ class ProgramInventoryRule(ProjectRule):
         return [
             s for s in absint.scan_jit_sites(
                 project, self.scan_prefixes,
-                exclude_rels=(self.manifest_rel,),
+                exclude_rels=(self.manifest_rel, absint.REFERENCE_REL),
             )
             if s.attr  # unbound jit expressions have no program identity
         ]

@@ -69,43 +69,40 @@ class TutoringConfig:
     ep: int = 1                  # expert-parallel ways (MoE presets)
     quant: Optional[str] = None  # "int8" = weight-only int8
     kv_quant: bool = False
-    spec_tokens: int = 0         # speculative decoding draft window (exact;
-    #                              both engines — composes with paged)
-    paged: bool = False          # continuous batching
-    max_batch: int = 8
-    max_wait_ms: float = 10.0
+    spec_tokens: int = 0         # speculative decoding draft window (exact)
+    max_batch: int = 8           # the slot count where `slots` is absent
     slots: Optional[int] = None
-    chunk: int = 16              # paged: tokens (spec: verify windows) per
+    chunk: int = 16              # tokens (spec: verify windows) per
     #                              device chunk (one step program; a
     #                              megastep fuses K of them per dispatch)
-    megastep: int = 1            # paged: the K controller's starting rung —
+    megastep: int = 1            # the K controller's starting rung —
     #                              chunks fused into one device-resident
     #                              dispatch (1 = the plain chunk loop)
-    megastep_max: int = 0        # paged: controller ceiling; K grows toward
+    megastep_max: int = 0        # controller ceiling; K grows toward
     #                              it while the pending queue is empty and,
     #                              under load, is capped at the chunks until
     #                              the next guaranteed slot-free (0 = follow
     #                              `megastep`). Worst-case admission wait is
     #                              K*chunk device steps.
-    inflight: int = 2            # paged: dispatched-but-unread programs kept
+    inflight: int = 2            # dispatched-but-unread programs kept
     #                              in flight (dispatch pipelining depth;
     #                              1 = serialized dispatch-sync-reap)
-    prefix_cache: bool = False   # paged: radix shared-prefix KV cache —
+    prefix_cache: bool = False   # radix shared-prefix KV cache —
     #                              prompts sharing a course/assignment
     #                              context prefill it once; later requests
     #                              splice the cached blocks and prefill
     #                              only their uncached suffix
-    prefix_cache_blocks: int = 512  # paged: device-block budget of the
+    prefix_cache_blocks: int = 512  # device-block budget of the
     #                              shared-prefix tree (16 tokens/block);
     #                              ref-count-pinned blocks are never
     #                              evicted, LRU leaves go first
-    prefill_chunk_tokens: int = 32  # paged: arriving prompts are staged
+    prefill_chunk_tokens: int = 32  # arriving prompts are staged
     #                              into SlotState and prefilled this many
     #                              tokens per decode iteration INSIDE the
     #                              megastep program (>= 1). Admission
     #                              latency is bounded by scan iterations,
     #                              not by a prefill dispatch of its own
-    draft_source: str = "prompt_lookup"  # paged+spec: "prompt_lookup"
+    draft_source: str = "prompt_lookup"  # with spec: "prompt_lookup"
     #                              (most-recent n-gram continuation) or
     #                              "ngram" (per-slot modal-continuation
     #                              table — higher acceptance at
@@ -374,10 +371,9 @@ class SimConfig:
     #                               drills to the operations schedule
     #                               (kill-one-of-N blackout,
     #                               drain-and-rejoin, autoscale)
-    tutoring_engine: str = "echo"  # "echo" (wire-complete stand-in),
-    #                                "tiny" (real JAX engine, tier-2 soak),
-    #                                or "tiny-paged" (real paged engine +
-    #                                shared-prefix radix cache)
+    tutoring_engine: str = "echo"  # "echo" (wire-complete stand-in) or
+    #                                "tiny-paged" (the real engine at tiny
+    #                                size + shared-prefix radix cache)
     events: bool = True           # run the operations schedule (transfer,
     #                               quarantine, membership, chaos campaign)
     slo_answer_p95_s: float = 6.0    # ask_llm p95 bound (client + /metrics)
@@ -423,10 +419,10 @@ class SimConfig:
             raise ValueError("[sim] telemetry_sample_s must be > 0")
         if self.lms_groups < 1:
             raise ValueError("[sim] lms_groups must be >= 1")
-        if self.tutoring_engine not in ("echo", "tiny", "tiny-paged"):
+        if self.tutoring_engine not in ("echo", "tiny-paged"):
             raise ValueError(
-                f"[sim] tutoring_engine must be 'echo', 'tiny', or "
-                f"'tiny-paged', got {self.tutoring_engine!r}"
+                f"[sim] tutoring_engine must be 'echo' or 'tiny-paged', "
+                f"got {self.tutoring_engine!r}"
             )
         if self.students < 1 or self.workers < 1 or self.duration_s <= 0:
             raise ValueError("[sim] needs students/workers >= 1 and "

@@ -1,17 +1,22 @@
-"""Dynamic request batching for the tutoring engine.
+"""The serving queue: continuous batching in front of the engine.
 
 The wire contract is unary (`Tutoring.GetLLMAnswer`, one query per RPC —
 reference: GUI_RAFT_LLM_SourceCode/lms.proto:123-125), so batching must
 happen *inside* the server without changing the RPC (SURVEY.md §7 hard part
-3). Concurrent student queries are coalesced into device batches: a request
-waits at most `max_wait_ms` for companions, then the whole group runs as one
-sharded generate program (batch bucketed to powers of two in the engine).
+3). `PagedQueue` drives the engine step by step and hands it what arrived
+between two dispatches, so concurrent student queries share the running
+device batch and a late one joins it at the next dispatch boundary.
 
 The reference handles concurrency with a 10-thread pool and sequential
 model.generate calls (tutoring_server.py:40) — throughput 1/latency. Here
-throughput scales with the batch bucket until the chip saturates.
+throughput scales with the slots until the chip saturates.
 
-Overload behavior (both queues): admission is bounded — `max_queue` waiting
+What the queue asks of an engine is `ENGINE_CONTRACT` below; everything
+else it reaches through `getattr` and does without where it is absent
+(`engine.paged.PagedEngine` has all of it, and so has the simulator's
+JAX-free double, `sim.cluster.EchoEngine`).
+
+Overload behavior: admission is bounded — `max_queue` waiting
 requests, beyond which `submit()` raises `Overloaded` (the server maps it
 to RESOURCE_EXHAUSTED, the wire's backpressure signal) instead of growing
 an unbounded backlog whose tail nobody is still waiting for. Requests may
@@ -19,7 +24,7 @@ carry a `Deadline`; one that expires while queued is dropped *before* its
 prefill is dispatched (counter `shed_expired`), so a saturated chip only
 computes answers that can still be delivered.
 
-Two-tenant scheduling (both queues): with a `ScoringManager`
+Two-tenant scheduling: with a `ScoringManager`
 (engine/scoring.py) attached, the runner co-schedules background bulk
 scoring into idle lanes — Orca-style iteration-level scheduling decides
 *per dispatch* what runs. A scoring quantum (one batch-bucket forward) is
@@ -36,7 +41,6 @@ from __future__ import annotations
 import asyncio
 import dataclasses
 import logging
-import re
 import time
 from collections import deque
 from typing import Any, AsyncIterator, Deque, Dict, List, Optional, Tuple
@@ -62,11 +66,15 @@ log = logging.getLogger(__name__)
 # tenant's preemption-wait account (score_preempt_wait_ms).
 _Item = Tuple[str, Optional[Deadline], asyncio.Future, Any, Any, float]
 
+# The names every engine handed to `PagedQueue` must have; the queue
+# refuses anything else when it is built, not at the first request.
+ENGINE_CONTRACT = ("submit", "step", "has_work", "pop_ttfts", "reset",
+                   "decode_tokens")
+
 # ---------------------------------------------------------------- streaming
 #
-# Both queues expose `submit_stream()`: an async iterator of StreamDelta
-# feeding the StreamLLMAnswer wire path. The resumable-stream contract both
-# implementations honor:
+# `submit_stream()` is an async iterator of StreamDelta feeding the
+# StreamLLMAnswer wire path. The resumable-stream contract:
 #
 # - offsets count TOKENS; within one logical stream they are monotone and
 #   gap-free (delta i+1 starts exactly where delta i ended);
@@ -78,31 +86,8 @@ _Item = Tuple[str, Optional[Deadline], asyncio.Future, Any, Any, float]
 #   — so the wire layer can digest it (the client verifies its spliced
 #   transcript against the digest; any resume divergence is caught there).
 #
-# PagedQueue streams live token progress off the engine's incremental
-# channel (`stream_snapshot`); BatchingQueue engines have no token channel,
-# so the completed answer is re-chunked with the deterministic splitter
-# below — same token boundaries on every node, which is what makes
-# cross-node resume offsets meaningful there too.
-
-# Tokens per delta on the BatchingQueue fallback path.
-STREAM_CHUNK_TOKENS = 8
-
-_STREAM_TOKEN_RE = re.compile(r"\s*\S+")
-
-
-def split_stream_tokens(text: str) -> List[str]:
-    """Deterministic whitespace-preserving tokenization for engines
-    without a native token stream. Concatenation identity:
-    ``''.join(split_stream_tokens(t)) == t`` for every t."""
-    toks = _STREAM_TOKEN_RE.findall(text)
-    consumed = sum(len(t) for t in toks)
-    if consumed < len(text):
-        tail = text[consumed:]
-        if toks:
-            toks[-1] += tail
-        else:
-            toks = [tail]
-    return toks
+# Live token progress comes off the engine's incremental channel
+# (`stream_snapshot`); an engine without one streams a single final delta.
 
 
 @dataclasses.dataclass(frozen=True)
@@ -120,7 +105,7 @@ class StreamDelta:
 
 @dataclasses.dataclass
 class _StreamState:
-    """Per-stream emission state the PagedQueue runner advances between
+    """Per-stream emission state the queue's runner advances between
     engine steps. `abs_text` is the decoded text through `sent_tokens`
     ABSOLUTE tokens (None until the resume skip is resolved); deltas are
     emitted only at decode-prefix-stable boundaries — a snapshot whose
@@ -150,7 +135,7 @@ def _observe_program_times(metrics, entries) -> None:
 
 async def _run_score_quantum(owner) -> None:
     """Dispatch ONE background-scoring quantum off-loop and record its
-    window. Shared by both queues; called only while the interactive
+    window. Called only while the interactive
     pending queue is empty and the engine is idle — the admission policy
     the scoring tenant promises. The engine's `score` program time is
     drained into the `engine_prog_score` histogram here (there is no
@@ -207,281 +192,6 @@ async def _next_item(owner, incoming: asyncio.Queue) -> Optional[_Item]:
     return None
 
 
-class BatchingQueue:
-    """Coalesces submit() calls into engine.answer_batch() invocations."""
-
-    def __init__(
-        self,
-        engine,
-        max_batch: int = 8,
-        max_wait_ms: float = 10.0,
-        metrics=None,
-        max_queue: int = 0,
-        scorer=None,
-    ):
-        self.engine = engine
-        self.max_batch = max_batch
-        self.max_wait_s = max_wait_ms / 1000.0
-        self.metrics = metrics
-        self.max_queue = max_queue  # 0 = unbounded (legacy behavior)
-        # Background scoring tenant (engine/scoring.ScoringManager or
-        # None): quanta run only while no interactive request waits.
-        self._scorer = scorer
-        self._last_quantum: Optional[Tuple[float, float]] = None  # guarded-by: event-loop
-        self.max_preempt_wait_s = 0.0                # guarded-by: event-loop
-        # Loop-confined state: everything below is touched only from
-        # coroutines on the serving loop — the engine call is the ONLY
-        # thing that leaves the loop (run_in_executor), and it receives
-        # plain prompts, never these containers.
-        self._queue: asyncio.Queue[_Item] = asyncio.Queue()  # guarded-by: event-loop
-        self._runner: Optional[asyncio.Task] = None  # guarded-by: event-loop
-        self._closed = False                         # guarded-by: event-loop
-
-    def _inc(self, name: str) -> None:
-        if self.metrics is not None:
-            self.metrics.inc(name)
-
-    @property
-    def waiting(self) -> int:
-        """Requests admitted but not yet in a device batch — what the
-        `max_queue` bound is enforced against (healthz reports it)."""
-        return self._queue.qsize()
-
-    async def start(self) -> None:
-        if self._runner is None:
-            self._runner = asyncio.create_task(self._run())
-
-    async def close(self) -> None:
-        self._closed = True
-        if self._runner is not None:
-            self._runner.cancel()
-            try:
-                await self._runner
-            except asyncio.CancelledError:
-                pass
-            self._runner = None
-        # Fail fast for anything still waiting (queued requests, or a group
-        # whose device batch was cancelled mid-flight) instead of hanging.
-        while not self._queue.empty():
-            _, _, fut, _, qspan, _ = self._queue.get_nowait()
-            qspan.end()
-            if not fut.done():
-                fut.set_exception(RuntimeError("batching queue closed"))
-
-    async def submit(self, prompt: str,
-                     deadline: Optional[Deadline] = None,
-                     span: Any = None) -> str:
-        """Enqueue one query; resolves with its decoded answer.
-
-        Raises `Overloaded` when the bounded queue is full and
-        `DeadlineExpired` when the budget is already gone — both *before*
-        the request occupies a queue slot.
-
-        `span` is the request's trace span (utils/tracing.py): the queue
-        records `queue.wait` (enqueue -> device dispatch) and
-        `engine.batch` children under it, with the engine's per-program
-        dispatch times as grandchildren.
-        """
-        if self._closed:
-            raise RuntimeError("batching queue is closed")
-        if deadline is not None and deadline.expired:
-            self._inc("shed_expired")
-            raise DeadlineExpired("expired before enqueue")
-        if self.max_queue and self._queue.qsize() >= self.max_queue:
-            self._inc("shed_overload")
-            raise Overloaded(
-                f"tutoring queue full ({self._queue.qsize()} waiting)"
-            )
-        span = span if span is not None else NULL_SPAN
-        fut: asyncio.Future = asyncio.get_running_loop().create_future()
-        await self._queue.put(
-            (prompt, deadline, fut, span, span.child("queue.wait"),
-             time.monotonic())
-        )
-        return await fut
-
-    async def submit_stream(
-        self, prompt: str,
-        deadline: Optional[Deadline] = None,
-        span: Any = None,
-        resume_offset: int = 0,
-        session: Optional[Tuple[str, float]] = None,
-    ) -> AsyncIterator[StreamDelta]:
-        """Streaming facade over batch engines without an incremental
-        token channel: the completed answer is delivered as deterministic
-        token-chunk deltas (see the module streaming contract). `session`
-        is accepted for interface parity and ignored — transcript KV
-        pinning needs the paged engine's prefix cache."""
-        answer = await self.submit(prompt, deadline=deadline, span=span)
-        toks = split_stream_tokens(answer)
-        n = len(toks)
-        i = min(max(0, int(resume_offset)), n)
-        if i >= n:
-            yield StreamDelta(offset=n, count=0, text="", final=True,
-                              full_text=answer)
-            return
-        while i < n:
-            j = min(i + STREAM_CHUNK_TOKENS, n)
-            final = j >= n
-            yield StreamDelta(
-                offset=i, count=j - i, text="".join(toks[i:j]),
-                final=final, full_text=answer if final else "",
-            )
-            i = j
-            if not final:
-                # A real yield point between deltas: chunks of concurrent
-                # streams interleave on the wire instead of bursting.
-                await asyncio.sleep(0)
-
-    async def _collect(self, first: _Item) -> List[_Item]:
-        """Gather companions for the (already-popped) first request."""
-        group = [first]
-        deadline = time.monotonic() + self.max_wait_s
-        while len(group) < self.max_batch:
-            remaining = deadline - time.monotonic()
-            if remaining <= 0:
-                break
-            try:
-                item = await asyncio.wait_for(self._queue.get(), timeout=remaining)
-                group.append(item)
-            except asyncio.TimeoutError:
-                break
-        return group
-
-    def _drop_expired(self, group: List[_Item]) -> List[_Item]:
-        """Shed queue-expired requests BEFORE their prefill dispatches:
-        computing an answer whose client has already given up wastes the
-        exact device time an overloaded server is short of."""
-        live: List[_Item] = []
-        for item in group:
-            _, dl, fut, span, qspan, _ = item
-            if dl is not None and dl.expired:
-                self._inc("shed_expired")
-                qspan.end()
-                span.flag(FLAG_DEADLINE)
-                if not fut.done():
-                    fut.set_exception(
-                        DeadlineExpired("expired while queued; prefill skipped")
-                    )
-            else:
-                live.append(item)
-        return live
-
-    def _note_preempt(self, t_enq: float) -> None:
-        """Charge an interactive arrival that landed inside the last
-        scoring quantum's window the wait it paid for the boundary."""
-        if self._last_quantum is None:
-            return
-        q0, q1 = self._last_quantum
-        if q0 <= t_enq < q1:
-            wait_s = q1 - t_enq
-            self.max_preempt_wait_s = max(self.max_preempt_wait_s, wait_s)
-            if self.metrics is not None:
-                self.metrics.inc("score_preempt_wait_ms",
-                                 max(1, int(wait_s * 1000.0)))
-
-    async def _run(self) -> None:
-        loop = asyncio.get_running_loop()
-        while True:
-            first = await _next_item(self, self._queue)
-            if first is None:
-                continue  # a scoring quantum ran; re-check arrivals
-            group = self._drop_expired(await self._collect(first))
-            if not group:
-                continue  # everything expired while queued: zero prefills
-            for item in group:
-                self._note_preempt(item[5])
-            if self.metrics is not None:
-                # Admission pressure at dispatch time: what is STILL
-                # waiting once this group leaves the queue (the telemetry
-                # timeline turns the sampled series into a saturation
-                # signal for the capacity model).
-                self.metrics.set_gauge("serving_queue_depth",
-                                       float(self.waiting))
-            prompts = [p for p, _, _, _, _, _ in group]
-            # Dispatch moment: queue.wait ends, engine.batch begins, for
-            # every request of the group (per-request spans under each
-            # request's own parent; the device batch is shared).
-            espans = []
-            for _, _, _, span, qspan, _ in group:
-                qspan.end()
-                espans.append(
-                    span.child("engine.batch", batch=len(group))
-                )
-            t_batch_unix = time.time()
-            try:
-                # The engine call blocks on device compute; run it off-loop so
-                # new requests keep queueing meanwhile.
-                self._inc("engine_batches")
-                answers = await loop.run_in_executor(
-                    None, self.engine.answer_batch, prompts
-                )
-            except asyncio.CancelledError:
-                # close() mid-batch: resolve the in-flight group before
-                # dying. Drop any program times the dying batch already
-                # recorded so they can't leak into a later queue's traces.
-                pop = getattr(self.engine, "pop_program_times", None)
-                if pop is not None:
-                    pop()
-                for espan in espans:
-                    espan.end()
-                for _, _, fut, _, _, _ in group:
-                    if not fut.done():
-                        fut.set_exception(RuntimeError("batching queue closed"))
-                raise
-            except Exception as e:  # resolve all waiters with the failure
-                log.exception("batch of %d failed", len(prompts))
-                for espan in espans:
-                    espan.set_status("error")
-                # Drain the partial dispatches under THIS failed batch's
-                # spans (they happened here) — leaving them queued would
-                # misattribute them to the next batch's traces.
-                self._finish_engine_spans(espans, t_batch_unix)
-                for _, _, fut, _, _, _ in group:
-                    if not fut.done():
-                        fut.set_exception(e)
-                continue
-            self._finish_engine_spans(espans, t_batch_unix)
-            # The engine measures time-to-first-token between its prefill and
-            # decode programs, per device chunk (requests in later chunks of
-            # an oversized group include their queueing delay).
-            ttfts = getattr(self.engine, "last_batch_ttfts", [])
-            if self.metrics is not None:
-                for i, _ in enumerate(group):
-                    if i < len(ttfts):
-                        self.metrics.hist("ttft").observe(ttfts[i])
-                tpw = getattr(self.engine, "last_spec_tokens_per_window",
-                              None)
-                if tpw is not None:
-                    # Speculation effectiveness: mean emitted tokens per
-                    # verify window (1.0 = nothing accepted). A gauge —
-                    # it is a ratio, not a latency.
-                    self.metrics.set_gauge("spec_tokens_per_window", tpw)
-            for (_, _, fut, _, _, _), answer in zip(group, answers):
-                if not fut.done():
-                    fut.set_result(answer)
-
-    def _finish_engine_spans(self, espans: List[Any],
-                             t_batch_unix: float) -> None:
-        """Close the group's engine spans, grafting the engine's reported
-        per-program dispatch times under each as `engine.<program>`
-        children (one measurement, mirrored under every request that
-        shared the device batch). Engines without the program-times
-        contract get one synthetic `engine.answer_batch` child covering
-        the whole call, so a trace always shows where device time went."""
-        pop = getattr(self.engine, "pop_program_times", None)
-        entries = pop() if pop is not None else []
-        _observe_program_times(self.metrics, entries)
-        for espan in espans:
-            espan.end()
-            if entries:
-                for pname, start_unix, wall_s in entries:
-                    espan.child_timed(f"engine.{pname}", start_unix, wall_s)
-            else:
-                espan.child_timed("engine.answer_batch", t_batch_unix,
-                                  espan.duration_s or 0.0)
-
-
 @dataclasses.dataclass
 class _ReqTrace:
     """Per-request trace state a paged request carries from admission to
@@ -505,11 +215,10 @@ class _ReqTrace:
 
 
 class PagedQueue:
-    """Continuous-batching front-end over `engine.paged.PagedEngine`.
+    """Continuous-batching front-end over `engine.paged.PagedEngine` (or
+    any object with `ENGINE_CONTRACT`).
 
-    Same submit()/start()/close() surface as `BatchingQueue`, different
-    scheduling: instead of coalescing a group and running it to completion,
-    the worker drives the paged engine step by step — new submissions are
+    The worker drives the engine step by step — new submissions are
     drained into the engine *between* dispatches, so a request arriving
     mid-decode joins the running batch at the next dispatch boundary (one
     chunk away, or up to K chunks when the engine is running megasteps;
@@ -522,6 +231,12 @@ class PagedQueue:
 
     def __init__(self, engine, metrics=None, max_queue: int = 0,
                  scorer=None):
+        missing = [n for n in ENGINE_CONTRACT if not hasattr(engine, n)]
+        if missing:
+            raise TypeError(
+                f"PagedQueue needs an engine with {ENGINE_CONTRACT}; "
+                f"{type(engine).__name__} lacks {missing}"
+            )
         self.engine = engine
         self.metrics = metrics
         self.max_queue = max_queue  # bound on not-yet-admitted requests
@@ -532,9 +247,10 @@ class PagedQueue:
         self._scorer = scorer
         self._last_quantum: Optional[Tuple[float, float]] = None  # guarded-by: event-loop
         self.max_preempt_wait_s = 0.0                # guarded-by: event-loop
-        # Loop-confined (see BatchingQueue): the engine's step() runs in an
-        # executor thread, but it never sees these containers — admissions
-        # and reaps happen on the runner coroutine between steps.
+        # Loop-confined: everything below is touched only from coroutines
+        # on the serving loop. The engine's step() runs in an executor
+        # thread, but it never sees these containers — admissions and
+        # reaps happen on the runner coroutine between steps.
         self._incoming: asyncio.Queue[_Item] = asyncio.Queue()  # guarded-by: event-loop
         self._futures: Dict[int, asyncio.Future] = {}  # guarded-by: event-loop
         # Streaming registry: future -> stream state while the request

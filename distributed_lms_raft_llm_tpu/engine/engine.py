@@ -1,20 +1,18 @@
-"""TutoringEngine: the TPU inference runtime behind `Tutoring.GetLLMAnswer`.
+"""TutoringEngine: the plain bucketed generator the tests hold the served
+engine's answers equal to. It serves nothing.
 
-Replaces the reference's module-global HF pipeline (reference:
-GUI_RAFT_LLM_SourceCode/tutoring_server.py:10-31) with a mesh-sharded JAX
-engine:
+`engine.paged.PagedEngine` behind `engine.batcher.PagedQueue` is what the
+server, the shipped configurations, the simulator and the benchmark run.
+This class stays because it shares nothing of admission or of the decode
+scan with it: prompts are tokenized and **left-padded into static
+buckets** (length and batch both bucketed to powers of two), and a
+request batch runs to completion as one jitted prefill + while_loop
+decode program (`engine.generate`), sampling included. Greedy answers of
+the two must be bit-equal (tests/test_paged.py, test_megastep.py,
+test_fused_prefill.py and the families' tests), so a fault in the served
+scan shows against a generator too simple to share it.
 
-- weights live once, sharded over the device mesh per `parallel.partition`
-  rules (tp for weight shards, dp for the request batch);
-- prompts are tokenized, **left-padded into static buckets** (length and
-  batch both bucketed to powers of two) so XLA compiles a small, finite set
-  of programs that are reused forever;
-- generation runs as one jitted prefill + while_loop decode program
-  (`engine.generate`), sampling included — a single device program per
-  request batch, no per-token host round-trip.
-
-The engine is synchronous and stateless per call; request coalescing lives
-in `engine.batcher` and the gRPC front-end in `serving.tutoring_server`.
+`EngineConfig` below is the configuration of both.
 """
 
 from __future__ import annotations
@@ -36,8 +34,7 @@ from ..utils.compilation import enable_compilation_cache
 from ..utils.guards import intended_transfer
 from .generate import GenerateResult, decode, pick_bucket, prefill
 from .sampling import SamplingParams
-from .scoring import _score_program, derive_score_shapes, score_texts
-from .spans import PROG, ProgramLog, Span, named_partial
+from .spans import named_partial
 
 log = logging.getLogger(__name__)
 
@@ -59,18 +56,6 @@ class EngineConfig:
     # stacks shard over the `ep` mesh axis (parallel/partition.py
     # MOE_RULES). Composes with tp x dp; 1 for dense models.
     ep: int = 1
-    # Sequence-parallel ways for the SCORING path (engine.score): the
-    # full-sequence forward runs as ring attention over `sp` shards —
-    # the long-context direction. Generation's cached decode ignores sp.
-    sp: int = 1
-    # Fused Pallas decode attention (ops/attention.py). None = off: with the
-    # cache's [.., S, 64] head-dim-minor layout the kernel's DMA runs at
-    # half-filled 128-lane tiles, so it is not expected to beat XLA's
-    # einsum fusions as it stands (not measured on the v5e); it stays
-    # available for explicit experiments (True) and as the base for a
-    # lane-packed cache layout (ROADMAP S2). Not partition-aware: requires
-    # mesh size 1.
-    fused_attention: Optional[bool] = None
     # Weight-only int8 ("int8") halves the parameter bytes the decode loop
     # streams per step (models/quant.py). None = full-precision (bf16)
     # weights. Composes with tp>1 (the
@@ -87,13 +72,13 @@ class EngineConfig:
     # Speculative decoding (engine/draft.py kernels): propose this many
     # prompt-lookup draft tokens per step and verify them in one forward
     # with exact rejection sampling — several tokens per model call,
-    # identical output distribution. 0 = off. Honored by BOTH engines:
-    # TutoringEngine swaps decode for engine/spec.decode_spec (supersedes
-    # decode_segments; the spec cache grows once to its high-water width),
-    # and PagedEngine generalizes its chunked step to per-slot verify
-    # windows (engine/paged._spec_step_program — slot lengths advance
-    # raggedly by per-row accepted counts). Wins where per-step fixed
-    # costs dominate: low batch, or a paged batch running below capacity.
+    # identical output distribution. 0 = off. PagedEngine generalizes
+    # its chunked step to per-slot verify windows
+    # (engine/paged._spec_step_program — slot lengths advance raggedly by
+    # per-row accepted counts); the reference swaps decode for
+    # engine/spec.decode_spec (supersedes decode_segments; the spec cache
+    # grows once to its high-water width). Wins where per-step fixed
+    # costs dominate: low batch, or a batch running below capacity.
     spec_tokens: int = 0
     # Spec draft source: "prompt_lookup" (most-recent n-gram continuation,
     # engine/draft.build_drafts — the right bet for greedy streams) or
@@ -123,12 +108,6 @@ class TutoringEngine:
     def __init__(self, config: EngineConfig, devices: Optional[Sequence] = None):
         enable_compilation_cache()
         self.config = config
-        if config.spec_tokens > 0 and config.fused_attention:
-            raise ValueError(
-                "spec_tokens and fused_attention are mutually exclusive: "
-                "the pallas decode kernel is single-query, the verify "
-                "window is k+1 wide"
-            )
         if config.spec_tokens > 0 and config.draft_source != "prompt_lookup":
             raise ValueError(
                 f"draft_source {config.draft_source!r} is a paged-engine "
@@ -174,24 +153,9 @@ class TutoringEngine:
                 "distributions than step decode (models/moe.py caveat)"
             )
         self.mesh = mesh_lib.make_mesh(
-            {"tp": config.tp, "ep": config.ep, "sp": config.sp, "dp": -1},
+            {"tp": config.tp, "ep": config.ep, "dp": -1},
             devices=devices,
         )
-        if config.fused_attention:
-            if self.mesh.devices.size != 1:
-                raise ValueError(
-                    "fused_attention requires an unsharded (single-device) "
-                    "mesh — the pallas kernel is not partition-aware"
-                )
-            if config.kv_quant:
-                # Fail at construction, not as a jit traceback at first
-                # warmup/generate (the kernel reads a bf16 cache layout).
-                raise ValueError(
-                    "fused_attention and kv_quant are mutually exclusive: "
-                    "the pallas decode kernel reads the full-precision "
-                    "cache layout"
-                )
-            self.cfg = dataclasses.replace(self.cfg, fused_decode_attention=True)
         if config.kv_quant:
             self.cfg = dataclasses.replace(self.cfg, quant_kv=True)
         self.tokenizer = tok_lib.load_gpt2_tokenizer(
@@ -240,9 +204,8 @@ class TutoringEngine:
         log.info("params ready in %.1fs (mesh %s)", time.monotonic() - t0,
                  dict(zip(self.mesh.axis_names, self.mesh.devices.shape)))
 
-        # Two jitted programs per input shape (prefill, decode): the engine
-        # blocks on prefill's first token — the honest TTFT boundary — then
-        # dispatches decode, donating the state so the KV cache buffers are
+        # Two jitted programs per input shape (prefill, decode): decode is
+        # handed prefill's state donated, so the KV cache buffers are
         # reused in place across the handoff. jit itself specializes/caches
         # per (batch bucket, length bucket).
         statics = dict(
@@ -267,68 +230,9 @@ class TutoringEngine:
                               **statics),
                 donate_argnums=(1,),
             )
-        self.last_ttft_s: Optional[float] = None
-        self.last_batch_ttfts: List[float] = []
-        # Speculative-decoding observability: mean emitted tokens per
-        # verify window of the last generate (1.0 + acceptance; None until
-        # a spec generate ran). Fed to the server's metrics snapshot.
-        # device_result=True generates stash their device scalars here and
-        # the property resolves them lazily — the pipelined dispatch path
-        # never blocks on a readback, yet the gauge still updates.
-        self._pending_spec_stats = None
-        self._last_spec_tpw: Optional[float] = None
-        # Tokens produced through answer_batch (bench harnesses divide by
-        # wall clock for tokens/sec through the serving path).
-        self.total_generated_tokens = 0
-        # (program, wall-clock start, seconds) per answer_batch device
-        # batch, drained by the serving queue into per-program histogram
-        # series and `engine.<program>` trace spans (engine/spans.py).
-        self._progs = ProgramLog(1024)
-        # Bulk-scoring program (engine/scoring.py): bound at construction
-        # like every other program — no lazy first-call compile hiding on
-        # the serving path. With sp > 1 the forward runs as ring
-        # attention over sequence shards (cfg.ring_mesh).
-        score_cfg = self.cfg
-        if config.sp > 1:
-            score_cfg = dataclasses.replace(score_cfg, ring_mesh=self.mesh)
-        self._score = jax.jit(
-            named_partial(_score_program, cfg=score_cfg, model=self.family)
-        )
-        # The score domain warmup covers when `config.scoring` is on —
-        # cross-checked against program_inventory.static_score_domain by
-        # expected_from_inventory, so the mirror cannot rot.
-        self.score_shapes: List[Tuple[int, int]] = (
-            derive_score_shapes(
-                config.length_buckets, config.batch_buckets,
-                self.cfg.max_position_embeddings, sp=config.sp,
-                dp=self.mesh.shape.get("dp", 1),
-            )
-            if config.scoring else []
-        )
-
-    def pop_program_times(self) -> List[Tuple[str, float, float]]:
-        """Drain (program, start_unix, wall_s) recorded since last call."""
-        return self._progs.pop()
-
-    @property
-    def last_spec_tokens_per_window(self) -> Optional[float]:
-        if self._pending_spec_stats is not None:
-            windows, lengths, n = self._pending_spec_stats
-            self._pending_spec_stats = None
-            # Deferred gauge resolution — the pipelined dispatch path never
-            # blocked for these; by now the computation has long finished.
-            with intended_transfer():
-                w = max(1, int(jax.device_get(windows)))
-                lengths = np.asarray(jax.device_get(lengths))
-            self._last_spec_tpw = float(
-                (np.sum(lengths[:n]) - n) / (w * n)
-            )
-        return self._last_spec_tpw
-
-    @last_spec_tokens_per_window.setter
-    def last_spec_tokens_per_window(self, value: Optional[float]) -> None:
-        self._pending_spec_stats = None
-        self._last_spec_tpw = value
+        # Mean emitted tokens per verify window of the last generate
+        # (1.0 + acceptance; None until a spec generate ran).
+        self.last_spec_tokens_per_window: Optional[float] = None
 
     def _max_prompt_len(self) -> int:
         # Spec mode keeps its verify windows inside the position table:
@@ -384,39 +288,20 @@ class TutoringEngine:
         ids = np.zeros((batch, bucket), np.int32)
         mask = np.ones((batch, bucket), bool)
         self.generate_ids(ids, mask)
-        # Scoring-tenant domain (empty unless EngineConfig.scoring): the
-        # first bulk job must not eat an XLA compile on the serving path.
-        self._warm_score()
         return time.monotonic() - t0
 
     def generate_ids(
         self,
         ids: np.ndarray,
         mask: np.ndarray,
-        measure_ttft: bool = True,
-        device_result: bool = False,
         real_rows: Optional[int] = None,
     ) -> GenerateResult:
-        """Generate for a pre-bucketed id batch; records measured TTFT.
-
-        `self.last_ttft_s` is the wall-clock from dispatch to the first
-        sampled token being on the host — an actual measurement (host→device
-        transfer + prefill + first sample + device→host), not an estimate.
-
-        measure_ttft=False skips that blocking readback and device_result=True
-        returns device arrays without fetching: back-to-back calls then
-        pipeline (dispatch N+1 while N computes), which is how a loaded
-        server runs and how throughput should be measured.
-        """
+        """Generate for a pre-bucketed id batch; the result is on the
+        host."""
         self._rng, rng = jax.random.split(self._rng)
-        t0 = time.monotonic()
         with self.mesh:
             state = self._prefill(self.params, input_ids=jnp.asarray(ids),
                                   prompt_mask=jnp.asarray(mask), rng=rng)
-            if measure_ttft:
-                with intended_transfer():  # blocks until the token exists
-                    np.asarray(state.out[:, 0])
-                self.last_ttft_s = time.monotonic() - t0
             # The final state is returned (and dropped) so the donated input
             # state's same-shaped buffers (out/seen/rng/flags) alias into the
             # outputs; the cache intentionally grows instead — see decode().
@@ -424,100 +309,45 @@ class TutoringEngine:
                 result, fin = self._decode(self.params, state,
                                            jnp.asarray(ids))
                 n = real_rows if real_rows is not None else len(ids)
-                if not device_result:
-                    # One extra scalar in the readback we do anyway. The
-                    # prefill-emitted token (one per row, no window ran
-                    # for it) is excluded: 1.0 = windows accepted nothing,
-                    # spec_tokens+1 = full acceptance. Rows finishing
-                    # early pull the mean below 1 (they emit 0 in later
-                    # windows) — the honest aggregate. Only the first
-                    # `real_rows` count: batch-bucket filler rows'
-                    # degenerate speculation must not skew the reading.
-                    with intended_transfer():
-                        windows = max(1, int(jax.device_get(fin.windows)))
-                        result = jax.device_get(result)
-                    self.last_spec_tokens_per_window = float(
-                        (np.sum(result.lengths[:n]) - n) / (windows * n)
-                    )
-                    return result
-                # Pipelined path: no blocking readback here — defer the
-                # gauge math to the property's next access, by which point
-                # the computation has long finished.
-                self._pending_spec_stats = (fin.windows, result.lengths, n)
+                # One extra scalar in the readback we do anyway. The
+                # prefill-emitted token (one per row, no window ran for
+                # it) is excluded: 1.0 = windows accepted nothing,
+                # spec_tokens+1 = full acceptance. Rows finishing early
+                # pull the mean below 1 (they emit 0 in later windows) —
+                # the honest aggregate. Only the first `real_rows` count:
+                # batch-bucket filler rows' degenerate speculation must
+                # not skew the reading.
+                with intended_transfer():
+                    windows = max(1, int(jax.device_get(fin.windows)))
+                    result = jax.device_get(result)
+                self.last_spec_tokens_per_window = float(
+                    (np.sum(result.lengths[:n]) - n) / (windows * n)
+                )
+                return result
             else:
                 result, _ = self._decode(self.params, state)
-        if device_result:
-            return result
         with intended_transfer():  # the call's one sanctioned readback
             return jax.device_get(result)
 
-    @property
-    def score_batch_cap(self) -> int:
-        """Texts per single-dispatch score quantum (the largest batch
-        bucket) — the scoring tenant's preemption granularity."""
-        return max(self.config.batch_buckets)
-
-    def score(self, texts: Sequence[str]) -> List[dict]:
-        """Log-likelihood scoring: per text, the total next-token log
-        probability, token count, perplexity, and a `truncated` flag
-        (True when the text exceeded the length-bucket limit and only
-        its prefix was scored — relevance evals must not read a prefix
-        score as a full-document score).
-
-        Runs the FULL-SEQUENCE forward (no cache) — the long-context
-        direction: with `EngineConfig.sp > 1` the attention runs as ring
-        attention over sequence shards (parallel/ring.py), so documents
-        far beyond a single chip's attention budget score across the
-        mesh. Groups larger than the biggest batch bucket run as several
-        device batches (engine/scoring.py holds the implementation; the
-        `_score` program is bound at construction and warmup-covered
-        when `EngineConfig.scoring` is on). No reference counterpart —
-        the reference cannot evaluate model fit at all; bulk grading,
-        gate-threshold calibration, and course-material relevance evals
-        build on this.
-        """
-        return score_texts(self, texts)
-
-    def _warm_score(self) -> int:
-        """Compile the score program over its full (batch bucket x
-        length bucket) domain so the first bulk job pays zero live XLA
-        compiles; a no-op (empty domain) when scoring is disabled."""
-        for nb, bucket in self.score_shapes:
-            ids = np.full((nb, bucket), self.tokenizer.pad_id, np.int32)
-            mask = np.ones((nb, bucket), bool)
-            with self.mesh:
-                self._score(self.params, jnp.asarray(ids),
-                            jnp.asarray(mask))
-        return len(self.score_shapes)
-
     def answer_batch(self, prompts: Sequence[str]) -> List[str]:
-        """The serving entry: prompts in, decoded answers out.
+        """Prompts in, decoded answers out.
 
         Groups larger than the biggest batch bucket run as several device
-        batches (the batcher normally caps groups, but callers may not).
+        batches.
         """
         if not prompts:
             return []
         cap = max(self.config.batch_buckets)
         answers: List[str] = []
-        ttfts: List[float] = []
-        t_submit = time.monotonic()
         for start in range(0, len(prompts), cap):
             chunk = prompts[start : start + cap]
             ids, mask, _ = self.encode_prompts(chunk)
-            queued_s = time.monotonic() - t_submit
-            with Span(PROG + "generate", self._progs):
-                result = self.generate_ids(ids, mask, real_rows=len(chunk))
-            # Per-request TTFT counts from batch submission: requests in a
-            # later device chunk also waited for every earlier chunk.
-            ttfts.extend([queued_s + (self.last_ttft_s or 0.0)] * len(chunk))
+            result = self.generate_ids(ids, mask, real_rows=len(chunk))
             for i in range(len(chunk)):
                 n = int(result.lengths[i])
-                self.total_generated_tokens += n
                 # Host-side numpy after generate_ids' readback, not a
                 # device sync.  # lint: disable-next=no-host-sync-in-dispatch
                 toks = [t for t in result.tokens[i, :n].tolist()
                         if t != self.tokenizer.eos_id]
                 answers.append(self.tokenizer.decode(toks))
-        self.last_batch_ttfts = ttfts
         return answers

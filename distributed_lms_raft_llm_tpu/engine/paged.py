@@ -1107,14 +1107,6 @@ class PagedEngine:
         )
         if config.kv_quant:
             self.cfg = dataclasses.replace(self.cfg, quant_kv=True)
-        if config.fused_attention:
-            # The pallas decode kernel reads the bucketed engine's cache
-            # layout (scalar length); the paged per-slot ragged offsets are
-            # not supported — fail loudly instead of silently using XLA.
-            raise ValueError(
-                "fused_attention is not supported by the paged engine "
-                "(per-slot ragged cache offsets); use TutoringEngine"
-            )
         # Speculative decoding: k prompt-lookup drafts verified per slot
         # per scan iteration (see _spec_step_program). 0 = the plain
         # one-token chunked step.
@@ -1124,16 +1116,16 @@ class PagedEngine:
             and self.family.name == "gpt2_moe"
             and self.cfg.capacity_factor < self.cfg.num_experts
         ):
-            # Mirror TutoringEngine: capacity drops make a token's output
-            # depend on its forward-pass companions, so the verify window
-            # would sample from different distributions than step decode.
+            # Capacity drops make a token's output depend on its
+            # forward-pass companions, so the verify window would sample
+            # from different distributions than step decode.
             raise ValueError(
                 "spec_tokens with an MoE model requires capacity_factor >= "
                 "num_experts (no token dropping; models/moe.py caveat)"
             )
         if config.ep > 1 and not self.family.expert_parallel:
-            # Mirror TutoringEngine: silently replicating the ep ways into
-            # dp would waste an ep-factor of devices with no signal.
+            # Silently replicating the ep ways into dp would waste an
+            # ep-factor of devices with no signal.
             raise ValueError(
                 f"ep={config.ep} requires an MoE family whose expert axis "
                 f"shards over ep; the {self.family.name!r} family of "
@@ -1158,11 +1150,6 @@ class PagedEngine:
                 f"draft, and tp={config.tp} the state's heads sharded "
                 f"beside the mixer's projections; neither is built"
             )
-        if config.sp > 1:
-            raise ValueError(
-                "sp applies to TutoringEngine.score's ring-attention path; "
-                "the paged engine has no full-sequence forward to shard"
-            )
         # The paged KV plane table splits the heads axis evenly across tp
         # shards (partition.PAGED_PLANE_SPECS) — reject non-divisor tp
         # ways up front with the supported ladder, before any device work.
@@ -1182,8 +1169,8 @@ class PagedEngine:
         )
         self.slots = slots or max(config.batch_buckets)
         # Clamp the prompt bucket so bucket + max_new always fits the
-        # position table (mirrors TutoringEngine._max_prompt_len — long
-        # prompts keep their tail via submit()'s truncation). Without this,
+        # position table (long prompts keep their tail via submit()'s
+        # truncation). Without this,
         # a request reaching tmax mid-decode would have its newest KV slot
         # silently overwritten by the clamped scatter in `_step_program`.
         # Spec mode keeps its verify windows inside the table too: the

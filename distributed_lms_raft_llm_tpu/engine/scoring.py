@@ -6,8 +6,8 @@ chip's throughput unused — the gap is idle compute. This module turns
 course-material relevance, gate-threshold calibration corpora) into a
 schedulable second tenant:
 
-- `_score_program` is the jitted full-sequence forward both engines bind
-  at construction (`TutoringEngine._score` / `PagedEngine._score`) — a
+- `_score_program` is the jitted full-sequence forward the engine binds
+  at construction (`PagedEngine._score`) — a
   first-class inventoried program (`engine/program_inventory.py`, domain
   ``score-pairs``), warmup-covered when `EngineConfig.scoring` is on, so
   the first instructor bulk job never eats an XLA compile on the serving
@@ -15,9 +15,9 @@ schedulable second tenant:
 - `ScoringManager` chunks submitted jobs into single-dispatch **quanta**
   (one batch-bucket forward each — the preemption granularity), with
   resumable progress, per-job stats, and idempotent job ids. The serving
-  queues (engine/batcher.py) admit a quantum ONLY while the interactive
+  queue (engine/batcher.py) admits a quantum ONLY while the interactive
   pending queue is empty and the engine holds no in-flight decode work,
-  and yield at quantum boundaries — an interactive arrival waits behind
+  and yields at quantum boundaries — an interactive arrival waits behind
   at most one in-flight quantum (measured as `score_preempt_wait_ms`).
 - `score_admin_get` backs ``GET /admin/score[/<job-id>]`` on the
   tutoring node's admin plane; ``POST /admin/score`` submits through
@@ -58,11 +58,9 @@ def _score_program(
 ) -> Tuple[jax.Array, jax.Array]:
     """Per-row total next-token log probability and valid-token count.
 
-    The full-sequence forward (no KV cache) — the long-context direction:
-    with `EngineConfig.sp > 1` (TutoringEngine only) `cfg.ring_mesh` is
-    set and attention runs as ring attention over sequence shards
-    (parallel/ring.py). Right-padded rows: pads sit after the causal
-    horizon of every real token and are masked out of the sum.
+    The full-sequence forward (no KV cache). Right-padded rows: pads sit
+    after the causal horizon of every real token and are masked out of
+    the sum.
     """
     logits, *_ = model.forward(params, cfg, ids)
     logp = jax.nn.log_softmax(logits.astype(jnp.float32), axis=-1)
@@ -79,32 +77,17 @@ def derive_score_shapes(
     length_buckets: Sequence[int],
     batch_buckets: Sequence[int],
     max_position_embeddings: int,
-    *,
-    sp: int = 1,
-    dp: int = 1,
 ) -> List[Tuple[int, int]]:
     """Every (batch, length) device shape `score_texts` can dispatch — the
     scoring program's static-argument domain, derived the same way
-    `encode_score_batch` buckets live texts. The engines compute this at
-    construction (`engine.score_shapes`) and warm the full set when
+    `encode_score_batch` buckets live texts. The engine computes this at
+    construction (`engine.score_shapes`) and warms the full set when
     scoring is enabled; `program_inventory.static_score_domain` mirrors
     the math and `expected_from_inventory` cross-checks the two, so the
     mirror cannot rot silently."""
     limit = min(max(length_buckets), max_position_embeddings)
-    if sp > 1:
-        limit = (limit // sp) * sp
-    buckets = set()
-    for b in length_buckets:
-        t = min(b, limit)
-        if sp > 1:
-            t = min(((t + sp - 1) // sp) * sp, limit)
-        buckets.add(t)
-    batches = set()
-    for n in batch_buckets:
-        if sp > 1:
-            n = ((n + dp - 1) // dp) * dp
-        batches.add(n)
-    return sorted((nb, t) for nb in batches for t in buckets)
+    buckets = {min(b, limit) for b in length_buckets}
+    return sorted((nb, t) for nb in set(batch_buckets) for t in buckets)
 
 
 def encode_score_batch(
@@ -117,13 +100,6 @@ def encode_score_batch(
     instead of silently scoring prefixes."""
     cfg = engine.config
     limit = min(max(cfg.length_buckets), engine.cfg.max_position_embeddings)
-    sp = cfg.sp
-    if sp > 1:
-        # The bucket below is rounded UP to a multiple of sp; floor the
-        # limit to a multiple first so the rounded bucket can never exceed
-        # the position table (JAX would clamp the wpe gather silently and
-        # score garbage positions).
-        limit = (limit // sp) * sp
     token_lists: List[List[int]] = []
     truncated: List[bool] = []
     for text in texts:
@@ -134,16 +110,7 @@ def encode_score_batch(
     longest = max(len(t) for t in token_lists)
     bucket = pick_bucket(longest, cfg.length_buckets)
     bucket = min(bucket, limit)
-    if sp > 1:
-        # Ring attention consumes the sequence in sp equal shards; the
-        # sp-floored `limit` above guarantees this stays <= the table.
-        bucket = min(((bucket + sp - 1) // sp) * sp, limit)
     nbatch = pick_bucket(len(texts), cfg.batch_buckets)
-    if sp > 1:
-        # Ring attention shard_maps over the mesh: the batch must tile dp
-        # exactly (filler rows are all-pad, scored then dropped).
-        dp = engine.mesh.shape.get("dp", 1)
-        nbatch = ((nbatch + dp - 1) // dp) * dp
     ids = np.full((nbatch, bucket), engine.tokenizer.pad_id, np.int32)
     mask = np.zeros((nbatch, bucket), bool)
     for i, toks in enumerate(token_lists):

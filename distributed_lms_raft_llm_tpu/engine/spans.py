@@ -20,7 +20,7 @@ live here and not in `utils/tracing.py`, which clients import):
   `wait_s` is the part of that sleep that lay inside a call into the
   runtime (`is_runtime_call`: an `engine.prog.*` span, `engine.reap.wait`
   or `engine.keys`), its own or a child's.
-  `ProgramLog` is the sink both engines use: `engine.prog.<program>` spans
+  `ProgramLog` is the engine's sink: `engine.prog.<program>` spans
   become the (program, start, wall) entries `pop_program_times()` drains
   into the `engine_prog_*` histograms and the per-request flight recorder,
   and each counts as one host dispatch; every span, those included, is

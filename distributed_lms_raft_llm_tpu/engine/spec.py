@@ -6,8 +6,9 @@ autoregressive loop). This module emits SEVERAL tokens per model call
 while sampling from *exactly* the same distribution. The drafting and
 accept/resample kernels live in `engine.draft` (shared with the paged
 engine's chunked verify-window step — `engine.paged`); this module owns
-the group-batched while_loop decode that `TutoringEngine` swaps in for
-`generate.decode` when `spec_tokens > 0`:
+the group-batched while_loop decode that `TutoringEngine` (the tests'
+reference generator) swaps in for `generate.decode` when
+`spec_tokens > 0`:
 
 - **Verification** runs the target model ONCE over [last_tok, d_1..d_k]
   (k+1 positions; the KV write scatters at per-row ragged slots — see
@@ -31,8 +32,8 @@ as ONE ordinary decode step (both are bandwidth-bound; the extra k
 query positions are FLOP-cheap), but sampling runs k+1 times per step.
 The win is therefore largest where per-step fixed costs dominate —
 small batches, i.e. the single-student latency path — and the feature
-is opt-in (`EngineConfig.spec_tokens`, `tutoring_server --spec-tokens`;
-it composes with `--paged` via the paged engine's own verify step).
+is opt-in (`EngineConfig.spec_tokens`; `tutoring_server --spec-tokens`
+selects the served engine's own verify step, `engine.paged`).
 """
 
 from __future__ import annotations

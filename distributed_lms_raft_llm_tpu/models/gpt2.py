@@ -25,7 +25,6 @@ from typing import Any, Dict, Optional, Tuple
 import jax
 import jax.numpy as jnp
 
-from ..ops import attention as attention_ops
 from . import quant
 from .common import (
     KVCache,
@@ -57,14 +56,9 @@ class GPT2Config:
     layer_norm_eps: float = 1e-5
     dtype: Any = jnp.float32  # compute dtype; bfloat16 on TPU
     param_dtype: Any = jnp.float32
-    # Route the single-token decode step through the fused Pallas attention
-    # kernel (ops/attention.py). Static (cfg is a jit static arg); the
-    # engine turns it on for unsharded TPU serving — the kernel is not
-    # partition-aware, so sharded/CPU paths keep the XLA einsums.
-    fused_decode_attention: bool = False
     # int8 KV cache with per-slot scales (common.quantize_kv): halves the
     # HBM bytes every decode step streams for attention. Set by the engine
-    # (EngineConfig.kv_quant); mutually exclusive with the pallas kernel.
+    # (EngineConfig.kv_quant).
     quant_kv: bool = False
     # Long-context sequence parallelism: a jax.sharding.Mesh with an `sp`
     # axis of size > 1 routes FULL-SEQUENCE attention (cache is None — the
@@ -369,21 +363,12 @@ def forward(
         # roofline on a v5e; as carry the update aliases and the decode step
         # drops from ~1.23 ms to ~0.66 ms (batch 8, GPT-2-small).
         zero = jnp.zeros((), jnp.int32)
-        fused = cfg.fused_decode_attention and t == 1
-        if cfg.fused_decode_attention and cfg.quant_kv:
-            raise ValueError(
-                "fused_decode_attention and quant_kv are mutually exclusive "
-                "(the pallas kernel reads a full-precision cache)"
-            )
-        if rows is not None and (offset.ndim != 1 or fused):
+        if rows is not None and offset.ndim != 1:
             raise ValueError(
                 "rows names the cache rows of a ragged batch (per-row "
-                "cache.length), which the fused decode kernel cannot read"
+                "cache.length)"
             )
         quant_kv = cfg.quant_kv
-        # The attend-mask is layer-invariant; its additive-bias form is
-        # computed once per step, outside the layer scan.
-        bias = attention_ops.mask_to_bias(mask) if fused else None
         # Int8 planes whose tile would be padded ride the scan folded
         # (common.folds_heads). A served cache arrives that way
         # (`init_cache`'s `groups`); one declared `[L, B, H, T, Dh]` is
@@ -441,13 +426,6 @@ def forward(
                             cvs, v_s[None], s_start
                         )
                 updated.update(k=ck2, v=cv2, ks=cks2, vs=cvs2)
-                if fused:
-                    # Reads the layer's K/V straight out of the stacked
-                    # cache (scalar-prefetched layer index) — slicing the
-                    # layer first would copy 2×[B,H,S,Dh] per layer.
-                    return attention_ops.decode_attention(
-                        q, ck2, cv2, layer, bias
-                    )
                 k_att = layer_rows(ck2, layer, rows)
                 v_att = layer_rows(cv2, layer, rows)
                 if quant_kv:

@@ -26,7 +26,6 @@ from typing import Any, Dict, Optional, Tuple
 import jax
 import jax.numpy as jnp
 
-from ..ops import attention as attention_ops
 from . import quant
 from .common import (
     KVCache,
@@ -58,10 +57,6 @@ class LlamaConfig:
     rms_norm_eps: float = 1e-5
     dtype: Any = jnp.bfloat16
     param_dtype: Any = jnp.bfloat16
-    # Same contract as GPT2Config.fused_decode_attention; for GQA the
-    # kernel indexes shared KV heads directly, skipping the repeat_kv
-    # materialization as well.
-    fused_decode_attention: bool = False
     # int8 KV cache with per-slot scales (common.quantize_kv); same
     # contract as GPT2Config.quant_kv.
     quant_kv: bool = False
@@ -246,19 +241,12 @@ def forward(
         new_cache = None
     else:
         zero = jnp.zeros((), jnp.int32)
-        fused = cfg.fused_decode_attention and t == 1
-        if cfg.fused_decode_attention and cfg.quant_kv:
-            raise ValueError(
-                "fused_decode_attention and quant_kv are mutually exclusive "
-                "(the pallas kernel reads a full-precision cache)"
-            )
-        if rows is not None and (offset.ndim != 1 or fused):
+        if rows is not None and offset.ndim != 1:
             raise ValueError(
                 "rows names the cache rows of a ragged batch (per-row "
-                "cache.length), which the fused decode kernel cannot read"
+                "cache.length)"
             )
         quant_kv = cfg.quant_kv
-        bias = attention_ops.mask_to_bias(mask) if fused else None
 
         def body(carry, xs):
             x, ck, cv, cks, cvs = carry
@@ -306,10 +294,6 @@ def forward(
                             cvs, v_s[None], s_start
                         )
                 updated.update(k=ck2, v=cv2, ks=cks2, vs=cvs2)
-                if fused:
-                    return attention_ops.decode_attention(
-                        q, ck2, cv2, layer, bias
-                    )
                 k_att = layer_rows(ck2, layer, rows)
                 v_att = layer_rows(cv2, layer, rows)
                 if quant_kv:

@@ -417,7 +417,7 @@ def params_from_hf(sd, cfg):
     """Load an MoE checkpoint. There is no public HF GPT-2-MoE layout, so
     checkpoints use the NATIVE tree layout with slash-joined key paths
     (written by train.checkpoint.export_model) — rebuilt into the param
-    pytree here so `TutoringEngine(model="gpt2-moe", checkpoint=...)`
+    pytree here so `EngineConfig(model="gpt2-moe", checkpoint=...)`
     serves a locally-trained MoE through the standard path."""
     if not any("/" in k for k in sd):
         raise ValueError(

@@ -5,8 +5,8 @@ GUI_RAFT_LLM_SourceCode/tutoring_server.py:33-49 — port 50054, 10-thread
 sync gRPC, one sequential `model.generate` per RPC). This server keeps the
 wire contract byte-identical and changes everything behind it:
 
-- `grpc.aio` front-end; concurrent RPCs coalesce in `engine.BatchingQueue`
-  into sharded device batches instead of queueing on a thread pool;
+- `grpc.aio` front-end; concurrent RPCs join the running device batch
+  through `engine.PagedQueue` instead of queueing on a thread pool;
 - the model is loaded/sharded once at startup and pre-compiled (`warmup`)
   so the first student query doesn't pay the XLA compile;
 - per-query latency lands in a first-class histogram (p50 TTFT is the
@@ -30,13 +30,11 @@ from typing import Dict, Optional, Tuple
 import grpc
 
 from ..engine import (
-    BatchingQueue,
     EngineConfig,
     PagedEngine,
     PagedQueue,
     SamplingParams,
     ScoringManager,
-    TutoringEngine,
 )
 from ..engine.scoring import score_admin_get
 from ..parallel.mesh import (
@@ -76,7 +74,7 @@ FOLLOWUP_TEMPLATE = "\nQuestion: {query}\nAnswer:"
 
 
 class TutoringService(rpc.TutoringServicer):
-    def __init__(self, queue: BatchingQueue, metrics: Metrics,
+    def __init__(self, queue: PagedQueue, metrics: Metrics,
                  auth_key: Optional[str] = None,
                  node_id: Optional[str] = None,
                  session_ttl_s: float = 600.0,
@@ -138,8 +136,7 @@ class TutoringService(rpc.TutoringServicer):
 
     def _drop_session(self, session_id: str) -> None:
         self._sessions.pop(session_id, None)
-        release = getattr(self.queue.engine, "release_session", None) \
-            if hasattr(self.queue, "engine") else None
+        release = getattr(self.queue.engine, "release_session", None)
         if release is not None:
             release(session_id)
         self.metrics.set_gauge("session_active", float(len(self._sessions)))
@@ -400,9 +397,9 @@ def make_tutoring_health(service: TutoringService, queue,
             "device_memory": device_memory(),
             "compile_cache": cache_stats(),
             # Admission pressure at a glance (details in /metrics:
-            # shed_overload / shed_expired / engine_batches). `queued`
-            # is what the bound is enforced against — for the paged
-            # queue that includes the engine's pre-slot backlog.
+            # shed_overload / shed_expired). `queued` is what the
+            # bound is enforced against: it includes the engine's
+            # pre-slot backlog.
             "queue_depth_limit": max_queue,
             "queued": queue.waiting,
             # Drain lifecycle: true while this node refuses new work and
@@ -428,7 +425,6 @@ async def serve_async(
     engine,
     *,
     max_batch: int = 8,
-    max_wait_ms: float = 10.0,
     max_queue: int = 0,
     metrics: Optional[Metrics] = None,
     metrics_period_s: float = 60.0,
@@ -446,10 +442,12 @@ async def serve_async(
 ) -> grpc.aio.Server:
     """Start (and return) the aio server; caller awaits termination.
 
-    `engine` is a `TutoringEngine` (group-batched generate) or a
-    `PagedEngine` (continuous batching: requests join the running batch
-    mid-decode); the matching queue front-end is picked automatically.
-    `max_queue` bounds waiting requests (0 = unbounded): beyond it new
+    `engine` is served through a `PagedQueue` (continuous batching:
+    requests join the running batch mid-decode) whatever it is: a
+    `PagedEngine`, or any object with the queue's `ENGINE_CONTRACT`; one
+    without it is refused here with a `TypeError`. `max_batch` is unused
+    (kept for the callers that pass it; ROADMAP D25). `max_queue` bounds
+    waiting requests (0 = unbounded): beyond it new
     RPCs are refused with RESOURCE_EXHAUSTED instead of queueing forever.
     `scoring` attaches the background bulk-scoring tenant
     (engine/scoring.ScoringManager + POST/GET /admin/score): quanta run
@@ -464,13 +462,8 @@ async def serve_async(
             max_job_texts=scoring_max_job_texts,
             jobs_retained=scoring_jobs_retained,
         )
-    if isinstance(engine, PagedEngine):
-        queue = PagedQueue(engine, metrics=metrics, max_queue=max_queue,
-                           scorer=scorer)
-    else:
-        queue = BatchingQueue(engine, max_batch=max_batch,
-                              max_wait_ms=max_wait_ms, metrics=metrics,
-                              max_queue=max_queue, scorer=scorer)
+    queue = PagedQueue(engine, metrics=metrics, max_queue=max_queue,
+                       scorer=scorer)
     await queue.start()
     server = grpc.aio.server(
         options=[
@@ -597,33 +590,27 @@ def main(argv=None) -> None:
         "--spec-tokens", type=int, default=0,
         help="speculative decoding: verify this many prompt-lookup draft "
         "tokens per step (engine/draft.py kernels; exact — the output "
-        "distribution is unchanged). Works on both engines, including "
-        "--paged (per-slot verify windows; acceptance visible as the "
-        "spec_tokens_per_window gauge and spec_accepted_tokens counter "
-        "in /metrics). Best when per-step fixed costs dominate; 0 = off",
+        "distribution is unchanged): per-slot verify windows; acceptance "
+        "visible as the spec_tokens_per_window gauge and "
+        "spec_accepted_tokens counter in /metrics. Best when per-step "
+        "fixed costs dominate; 0 = off",
     )
     parser.add_argument("--max-new-tokens", type=int, default=128)
-    parser.add_argument("--max-batch", type=int, default=8)
-    parser.add_argument("--max-wait-ms", type=float, default=10.0)
+    parser.add_argument("--max-batch", type=int, default=8,
+                        help="decode slots where --slots is absent")
     parser.add_argument(
         "--queue-depth", type=int, default=64,
         help="bounded admission: waiting requests beyond this are refused "
         "with RESOURCE_EXHAUSTED (0 = unbounded)",
     )
-    parser.add_argument(
-        "--paged", action="store_true",
-        help="continuous batching: requests join the running batch "
-        "mid-decode instead of waiting for the current group",
-    )
     parser.add_argument("--slots", type=int, default=None,
-                        help="paged engine decode slots (default: max batch "
-                        "bucket)")
+                        help="decode slots (default: --max-batch)")
     parser.add_argument("--chunk", type=int, default=16,
-                        help="paged engine tokens per device chunk "
+                        help="tokens per device chunk "
                         "(verify windows when --spec-tokens is set); "
                         "admission joins at dispatch boundaries")
     parser.add_argument("--megastep", type=int, default=1,
-                        help="paged engine megastep: starting K of the "
+                        help="megastep: starting K of the "
                         "TTFT-aware controller — K chunks run "
                         "back-to-back on device per host dispatch "
                         "(1 = the plain chunk loop)")
@@ -635,35 +622,33 @@ def main(argv=None) -> None:
                         "(worst-case wait is K*chunk device steps); "
                         "0 = follow --megastep")
     parser.add_argument("--inflight", type=int, default=2,
-                        help="paged engine dispatch pipelining depth: "
+                        help="dispatch pipelining depth: "
                         "programs dispatched before the oldest is read "
                         "back (1 = serialized)")
     parser.add_argument("--prefix-cache", action="store_true",
-                        help="paged engine radix shared-prefix KV cache: "
+                        help="radix shared-prefix KV cache: "
                         "prompts sharing a course/assignment context "
                         "prefill it once; later requests splice the "
                         "cached blocks and prefill only their suffix "
-                        "(hit rate in /metrics prefix_cache_hit_rate; "
-                        "ignored without --paged)")
+                        "(hit rate in /metrics prefix_cache_hit_rate)")
     parser.add_argument("--prefix-cache-blocks", type=int, default=512,
                         help="shared-prefix cache block budget (16 "
                         "tokens/block; LRU eviction, blocks referenced "
                         "by live slots are never freed)")
     parser.add_argument("--prefill-chunk-tokens", type=int, default=32,
-                        help="paged engine admission: arriving prompts "
+                        help="admission: arriving prompts "
                         "are staged into the decode state and prefilled "
                         "this many tokens (>= 1) per decode iteration "
                         "INSIDE the megastep program, so admission never "
                         "pauses the decode train (admission latency is "
-                        "bounded by scan iterations, not prompt length); "
-                        "ignored without --paged")
+                        "bounded by scan iterations, not prompt length)")
     parser.add_argument("--draft-source", default="prompt_lookup",
                         choices=["prompt_lookup", "ngram"],
                         help="speculative draft source (with "
                         "--spec-tokens): prompt_lookup = most-recent "
                         "n-gram continuation; ngram = per-slot "
-                        "modal-continuation table (paged only, higher "
-                        "acceptance at temperature>0)")
+                        "modal-continuation table (higher acceptance "
+                        "at temperature>0)")
     parser.add_argument("--scoring", action="store_true",
                         help="background bulk-scoring tenant "
                         "(engine/scoring.py): warmup-cover the score "
@@ -728,7 +713,7 @@ def main(argv=None) -> None:
             "vocab": t.vocab, "merges": t.merges, "tp": t.tp,
             "ep": t.ep,
             "quant": t.quant, "max_new_tokens": s.max_new_tokens,
-            "max_batch": t.max_batch, "max_wait_ms": t.max_wait_ms,
+            "max_batch": t.max_batch,
             "queue_depth": cfg.resilience.queue_depth,
             "slots": t.slots, "chunk": t.chunk,
             "megastep": t.megastep, "megastep_max": t.megastep_max,
@@ -740,7 +725,7 @@ def main(argv=None) -> None:
             "auth_key_file": t.auth_key_file,
             # store_true flags merge the same way: presence in argv is what
             # marks them explicit, so the file fills only absent ones.
-            "kv_quant": t.kv_quant, "paged": t.paged,
+            "kv_quant": t.kv_quant,
             "approx_topk": s.approx_top_k,
             "spec_tokens": t.spec_tokens,
             "scoring": cfg.scoring.enabled,
@@ -823,30 +808,21 @@ def main(argv=None) -> None:
         # the first bulk job pays zero live XLA compiles.
         scoring=args.scoring,
     )
-    if args.paged:
-        # --max-batch bounds concurrency in both modes: it is the decode
-        # slot count here (unless --slots overrides it explicitly; with
-        # megastep enabled, raising slots amortizes the per-dispatch host
-        # overhead over more lanes — cluster.toml ships 16).
-        # spec_tokens rides in on the EngineConfig: the paged engine
-        # verifies per-slot draft windows (chunk then counts verify
-        # WINDOWS per chunk, up to spec_tokens+1 tokens each).
-        engine = PagedEngine(config, slots=args.slots or args.max_batch,
-                             chunk=args.chunk, inflight=args.inflight,
-                             megastep=args.megastep,
-                             megastep_max=args.megastep_max,
-                             prefix_cache=args.prefix_cache,
-                             prefix_cache_blocks=args.prefix_cache_blocks,
-                             prefill_chunk_tokens=args.prefill_chunk_tokens)
-    else:
-        if args.prefix_cache:
-            log.warning("--prefix-cache applies to the paged engine only; "
-                        "ignored without --paged")
-        engine = TutoringEngine(config)
+    # --max-batch is the decode slot count unless --slots names it (with
+    # megastep enabled, raising slots amortizes the per-dispatch host
+    # overhead over more lanes — cluster.toml ships 16).
+    # spec_tokens rides in on the EngineConfig: the engine verifies
+    # per-slot draft windows (chunk then counts verify WINDOWS per chunk,
+    # up to spec_tokens+1 tokens each).
+    engine = PagedEngine(config, slots=args.slots or args.max_batch,
+                         chunk=args.chunk, inflight=args.inflight,
+                         megastep=args.megastep,
+                         megastep_max=args.megastep_max,
+                         prefix_cache=args.prefix_cache,
+                         prefix_cache_blocks=args.prefix_cache_blocks,
+                         prefill_chunk_tokens=args.prefill_chunk_tokens)
     if not args.no_warmup:
-        secs = (engine.warmup() if args.paged
-                else engine.warmup(batch=args.max_batch))
-        log.info("warmup compile took %.1fs", secs)
+        log.info("warmup compile took %.1fs", engine.warmup())
 
     auth_key = None
     if args.auth_key_file:
@@ -855,8 +831,7 @@ def main(argv=None) -> None:
 
     async def run():
         server = await serve_async(
-            args.port, engine, max_batch=args.max_batch,
-            max_wait_ms=args.max_wait_ms, max_queue=args.queue_depth,
+            args.port, engine, max_queue=args.queue_depth,
             auth_key=auth_key,
             metrics_port=args.metrics_port,
             telemetry=args.telemetry,

@@ -14,16 +14,19 @@ restarted node comes back at its advertised address (peers re-dial it,
 clients re-discover it).
 
 The default tutoring engine is `EchoEngine` — a wire-complete stand-in
-that exercises the REAL BatchingQueue admission, deadline shedding, HMAC
-path, and gRPC plumbing without paying an XLA compile; the tier-2 soak
-swaps in the real tiny JAX engine (`[sim] tutoring_engine = "tiny"`).
+that exercises the served queue (`engine.PagedQueue`: admission, deadline
+shedding, streaming), the HMAC path and the gRPC plumbing without paying
+an XLA compile; the tier-2 soak swaps in the real engine at tiny size
+(`[sim] tutoring_engine = "tiny-paged"`).
 """
 
 from __future__ import annotations
 
 import asyncio
 import json
+import dataclasses
 import logging
+import re
 import secrets
 import socket
 import threading
@@ -82,37 +85,153 @@ def _free_port() -> int:
     return port
 
 
-class EchoEngine:
-    """Deterministic tutoring stand-in with the `answer_batch` contract.
+def echo_answer(prompt: str) -> str:
+    """The stand-in's answer: the question line of the server's prompt
+    template, echoed."""
+    lines = prompt.splitlines()
+    return f"Echo tutor: {(lines[-2] if len(lines) >= 2 else prompt)[:96]}"
 
-    A tiny sleep gives the latency histograms a real (but bounded)
-    distribution; it runs in the batcher's executor, never on the loop.
-    Speaks the real engines' `pop_program_times` contract too, so sim
-    traces carry an `engine.generate` program span and the
-    `engine_prog_generate` histogram fills — the SAME reap path the
-    TutoringEngine exercises, not a sim-only shortcut.
+
+_WORD_RE = re.compile(r"\s*\S+|\s+$")
+
+
+def echo_tokens(text: str) -> List[str]:
+    """The stand-in's tokens: a word with the blanks before it (a tail of
+    blanks is a token of its own), so they always join back to `text`."""
+    return _WORD_RE.findall(text)
+
+
+@dataclasses.dataclass
+class _EchoRequest:
+    rid: int
+    prompt: str
+    submit_time: float
+    tokens: List[str] = dataclasses.field(default_factory=list)
+    sent: int = 0  # tokens emitted so far
+
+
+class EchoEngine:
+    """Deterministic tutoring stand-in that speaks the contract
+    `engine.PagedQueue` asks of an engine (`batcher.ENGINE_CONTRACT` and
+    everything the queue reaches through `getattr` that a trace, a stream
+    or the scoring tenant needs), with no JAX.
+
+    `submit` backlogs; `step` admits the backlog's head into the free
+    slots (`prefilled` lists the prompts in that order), sleeps `delay_s`
+    once if it admitted anything (the admission's compute; it runs in the
+    queue's executor, never on the loop, and gives the latency histograms
+    a real but bounded distribution) and then hands every slot's request
+    `chunk` more tokens of its answer. A token is a word with the blanks
+    before it, so `decode_tokens` is a join and a streamed answer comes
+    from the double's own token channel. Every step reports itself as one
+    `megastep` on `pop_program_times`, so sim traces carry the served
+    queue's `engine.decode` / `engine.megastep` spans and the
+    `engine_prog_megastep` histogram fills through the SAME reap path the
+    real engine exercises, not a sim-only shortcut.
     """
 
     # Scoring-tenant quantum size (texts per single dispatch), mirroring
-    # the real engines' `score_batch_cap` property.
+    # the real engine's `score_batch_cap` property.
     score_batch_cap = 4
 
-    def __init__(self, delay_s: float = 0.002):
+    def __init__(self, delay_s: float = 0.002, slots: int = 4,
+                 chunk: int = 8,
+                 answer: Callable[[str], str] = echo_answer):
         self.delay_s = delay_s
+        self.slots = slots
+        self.chunk = chunk
+        self._answer = answer
+        self.prefilled: List[str] = []
+        self._next_rid = 0
         self._prog_times: List[Tuple[str, float, float]] = []
+        self.reset()
 
-    def answer_batch(self, prompts: List[str]) -> List[str]:
-        t0, t0_unix = time.monotonic(), time.time()
-        time.sleep(self.delay_s)
-        self._prog_times.append(
-            ("generate", t0_unix, time.monotonic() - t0)
+    def reset(self) -> None:
+        self._pending: List[_EchoRequest] = []
+        self._active: Dict[int, _EchoRequest] = {}
+        self._ttfts: Dict[int, float] = {}
+        self._queue_waits: Dict[int, float] = {}
+        self._stream_watch: set = set()
+        self._final_tokens: Dict[int, List[str]] = {}
+
+    @property
+    def has_work(self) -> bool:
+        return bool(self._pending or self._active)
+
+    @property
+    def backlog(self) -> int:
+        return len(self._pending)
+
+    def submit(self, prompt: str) -> int:
+        self._next_rid += 1
+        self._pending.append(
+            _EchoRequest(self._next_rid, prompt, time.monotonic())
         )
-        return [f"Echo tutor: {p.splitlines()[-2][:96]}"
-                if len(p.splitlines()) >= 2 else f"Echo tutor: {p[:96]}"
-                for p in prompts]
+        return self._next_rid
+
+    def cancel_pending(self, rid: int) -> bool:
+        for i, req in enumerate(self._pending):
+            if req.rid == rid:
+                del self._pending[i]
+                self._stream_watch.discard(rid)
+                return True
+        return False
+
+    def step(self) -> List[Tuple[int, str]]:
+        t0, t0_unix = time.monotonic(), time.time()
+        admitted = []
+        while self._pending and len(self._active) < self.slots:
+            req = self._pending.pop(0)
+            self.prefilled.append(req.prompt)
+            self._queue_waits[req.rid] = t0 - req.submit_time
+            req.tokens = echo_tokens(self._answer(req.prompt))
+            self._active[req.rid] = req
+            admitted.append(req)
+        if admitted:
+            time.sleep(self.delay_s)
+        now = time.monotonic()
+        for req in admitted:
+            self._ttfts[req.rid] = now - req.submit_time
+        done = []
+        for rid, req in list(self._active.items()):
+            req.sent = min(len(req.tokens), req.sent + self.chunk)
+            if req.sent == len(req.tokens):
+                del self._active[rid]
+                if rid in self._stream_watch:
+                    self._stream_watch.discard(rid)
+                    self._final_tokens[rid] = req.tokens
+                done.append((rid, self.decode_tokens(req.tokens)))
+        self._prog_times.append(("megastep", t0_unix, now - t0))
+        return done
+
+    def pop_ttfts(self) -> Dict[int, float]:
+        out, self._ttfts = self._ttfts, {}
+        return out
+
+    def pop_queue_waits(self) -> Dict[int, float]:
+        out, self._queue_waits = self._queue_waits, {}
+        return out
+
+    def stream_watch(self, rid: int) -> None:
+        self._stream_watch.add(rid)
+
+    def stream_unwatch(self, rid: int) -> None:
+        self._stream_watch.discard(rid)
+        self._final_tokens.pop(rid, None)
+
+    def stream_snapshot(self, rids) -> Dict[int, List[str]]:
+        live = (self._active[rid] for rid in rids if rid in self._active)
+        return {req.rid: req.tokens[:req.sent] for req in live}
+
+    def pop_final_tokens(self) -> Dict[int, List[str]]:
+        out, self._final_tokens = self._final_tokens, {}
+        return out
+
+    def decode_tokens(self, tokens) -> str:
+        return "".join(tokens)
 
     def score(self, texts: List[str]) -> List[Dict]:
-        """Deterministic stand-in for the real engines' bulk-scoring
+        """Deterministic stand-in for the real engine's bulk-scoring
         quantum (engine/scoring.score_texts contract: logprob/tokens/
         ppl/truncated per text) — the sim's bulk-grading night runs the
         REAL admin plane, job manager, and co-scheduler against it."""
@@ -597,84 +716,60 @@ class SimCluster:
         (make_tutoring_health/make_tutoring_admin). Node 0 runs the
         configured engine; extra members (and autoscale spawns) run the
         echo stand-in so a 3-node fleet costs no extra XLA compiles."""
-        from ..engine import BatchingQueue, PagedQueue, ScoringManager
+        from ..engine import PagedQueue, ScoringManager
 
-        queue = None
         metrics = Metrics()
-        scorer = None
-        if (self.cfg.tutoring_engine in ("tiny", "tiny-paged")
+        if (self.cfg.tutoring_engine == "tiny-paged"
                 and idx == 0 and not force_echo):
             import jax
 
-            from ..engine import (
-                EngineConfig,
-                PagedEngine,
-                SamplingParams,
-                TutoringEngine,
-            )
+            from ..engine import EngineConfig, PagedEngine, SamplingParams
 
-            config = EngineConfig(
-                model="tiny",
-                sampling=SamplingParams(max_new_tokens=8),
-                length_buckets=(32,), batch_buckets=(1, 2, 4),
-                dtype=jax.numpy.float32,
-                # Bulk-grading night runs against the REAL score path:
-                # warmup covers the score domain so the mid-run job
-                # compiles nothing live.
-                scoring=self.cfg.bulk_scoring,
+            # The real serving configuration scaled down: continuous
+            # batching with the shared-prefix radix cache, so a
+            # concentrated same-course workload (`course_concentration`
+            # > 0) produces a measurable prefix_cache_hit_rate in the
+            # soak's verdict. Two prompt buckets + 8-token blocks: the
+            # tiny position table caps prompts at 32 tokens, and a hit
+            # needs one whole block of prefix in the window. NOTE the
+            # 32-token cap also tail-truncates the long course context,
+            # so at this scale hits come from students repeating the
+            # same course question verbatim — real lookup/splice/
+            # suffix-prefill traffic, but not cross-question context
+            # sharing (that is tests/test_prefix_cache.py, with
+            # token-level control).
+            engine = PagedEngine(
+                EngineConfig(
+                    model="tiny",
+                    sampling=SamplingParams(max_new_tokens=8),
+                    length_buckets=(16, 32), batch_buckets=(1, 2, 4),
+                    dtype=jax.numpy.float32,
+                    # Bulk-grading night runs against the REAL score
+                    # path: warmup covers the score domain so the
+                    # mid-run job compiles nothing live.
+                    scoring=self.cfg.bulk_scoring,
+                ),
+                slots=4, chunk=4, prefix_cache=True,
+                prefix_cache_blocks=128, prefix_block_tokens=8,
+                # The soak exercises staged chunked prefill under real
+                # diurnal churn, at a chunk the tiny table fits.
+                prefill_chunk_tokens=8,
             )
-            if self.cfg.tutoring_engine == "tiny-paged":
-                # The real serving configuration scaled down: paged
-                # continuous batching with the shared-prefix radix
-                # cache, so a concentrated same-course workload
-                # (`course_concentration` > 0) produces a measurable
-                # prefix_cache_hit_rate in the soak's verdict. Two
-                # prompt buckets + 8-token blocks: the tiny position
-                # table caps prompts at 32 tokens, and a hit needs
-                # one whole block of prefix in the window. NOTE the
-                # 32-token cap also tail-truncates the long course
-                # context, so at this scale hits come from students
-                # repeating the same course question verbatim — real
-                # lookup/splice/suffix-prefill traffic, but not
-                # cross-question context sharing (that is
-                # tests/test_prefix_cache.py, with token-level control).
-                import dataclasses as _dc
-
-                engine = PagedEngine(
-                    _dc.replace(config, length_buckets=(16, 32)),
-                    slots=4, chunk=4, prefix_cache=True,
-                    prefix_cache_blocks=128, prefix_block_tokens=8,
-                    # The soak exercises staged chunked prefill under
-                    # real diurnal churn, at a chunk the tiny table fits.
-                    prefill_chunk_tokens=8,
-                )
-                if self.cfg.bulk_scoring:
-                    scorer = ScoringManager(engine, metrics=metrics,
-                                            max_job_texts=1024,
-                                            jobs_retained=8)
-                queue = PagedQueue(engine, metrics=metrics, max_queue=64,
-                                   scorer=scorer)
-            else:
-                engine = TutoringEngine(config)
             # Compile now, while this loop runs nothing else: tutoring
             # boots BEFORE the Raft nodes, so the XLA compile can't stall
             # their tick loops (every node shares this loop+GIL).
-            if queue is not None:
-                engine.warmup()
-            else:
-                engine.warmup(batch=4)
+            engine.warmup()
         else:
             engine = EchoEngine()
-        if self.cfg.bulk_scoring and scorer is None:
+        scorer = None
+        if self.cfg.bulk_scoring:
             # Every fleet member runs the background scoring tenant: the
             # bulk-grading night lands on whichever node the LMS router's
             # background route picks (the coldest one).
             scorer = ScoringManager(engine, metrics=metrics,
                                     max_job_texts=1024, jobs_retained=8)
-        if queue is None:
-            queue = BatchingQueue(engine, max_batch=4, max_wait_ms=5.0,
-                                  metrics=metrics, max_queue=64,
-                                  scorer=scorer)
+        queue = PagedQueue(engine, metrics=metrics, max_queue=64,
+                           scorer=scorer)
         await queue.start()
         server = grpc.aio.server()
         service = TutoringService(queue, metrics, node_id=f"tut{idx}",

@@ -3,7 +3,7 @@
 Round-trips the FULL train state — params, optimizer moments, step — via
 the same safetensors writer the serving path uses (models/convert.py), so
 a fine-tuned model is immediately servable: `export_model()` writes the
-params alone in HF layout for `TutoringEngine(checkpoint=...)`.
+params alone in HF layout for `EngineConfig(checkpoint=...)`.
 
 Layout: one `.safetensors` holding every state leaf under its tree path
 (`params/blocks/attn/wqkv`, `opt_state/1/0/mu/...`), plus `<path>.json`
@@ -93,7 +93,7 @@ def restore_train_state(
 
 def export_model(path: str, state: Any) -> None:
     """Write just the fine-tuned parameters in HF GPT-2 layout (the inverse
-    of the import mapping), so `TutoringEngine(checkpoint=path)` serves the
+    of the import mapping), so `EngineConfig(checkpoint=path)` serves the
     fine-tuned model through the standard checkpoint path. MoE params have
     no HF counterpart layout; they export in the native tree layout
     (slash-joined paths), which `models.moe.params_from_hf` reads back."""

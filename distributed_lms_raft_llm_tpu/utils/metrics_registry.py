@@ -323,9 +323,6 @@ SHED_OVERLOAD = counter(
     "requests refused at admission because the bounded queue was full "
     "(RESOURCE_EXHAUSTED on the wire)",
 )
-ENGINE_BATCHES = counter(
-    "engine_batches", "device batches dispatched by the group batcher"
-)
 SPEC_TOKENS_PER_WINDOW = gauge(
     "spec_tokens_per_window",
     "speculation effectiveness: mean emitted tokens per verify window "
@@ -491,14 +488,9 @@ ENGINE_PROG_SCORE = histogram(
     "score program dispatch wall time (one background-scoring quantum: "
     "a full-sequence batch-bucket forward — the preemption granularity)",
 )
-ENGINE_PROG_GENERATE = histogram(
-    "engine_prog_generate",
-    "bucketed-engine generate dispatch wall time (one grouped device "
-    "batch, prefill through last token)",
-)
 
 # Engine-reported program name -> declared histogram, used by the serving
-# queues (engine/batcher.py). Living HERE keeps the mapping inside the
+# queue (engine/batcher.py). Living HERE keeps the mapping inside the
 # declared namespace (see BREAKER_TRANSITION_COUNTERS).
 ENGINE_PROG_RESTORE_STATE = histogram(
     "engine_prog_restore_state",
@@ -521,7 +513,6 @@ ENGINE_PROGRAM_HISTOGRAMS: Dict[str, str] = {
     "restore_state": ENGINE_PROG_RESTORE_STATE,
     "export_state": ENGINE_PROG_EXPORT_STATE,
     "score": ENGINE_PROG_SCORE,
-    "generate": ENGINE_PROG_GENERATE,
 }
 
 # The paged engine's loop, counted where the work happens (engine/paged.py

@@ -73,7 +73,6 @@ CLASSIFICATION = {
     # EngineConfig.scoring is off, so the warmup claim holds in both
     # modes (see static_score_domain).
     ("PagedEngine", "_score"): ("score-pairs", "warmup"),
-    ("TutoringEngine", "_score"): ("score-pairs", "warmup"),
 }
 DEFAULT_CLASS = ("shapes", "on-demand")
 
@@ -83,7 +82,7 @@ def scanned_entries() -> List[dict]:
     manifest_rel = MANIFEST.relative_to(REPO).as_posix()
     entries = []
     for site in absint.scan_jit_sites(
-        project, exclude_rels=(manifest_rel,)
+        project, exclude_rels=(manifest_rel, absint.REFERENCE_REL)
     ):
         if not site.attr:
             continue
@@ -118,7 +117,7 @@ def render_inventory_block(entries: List[dict]) -> str:
 
 
 def shipped_domains() -> List[Dict[str, object]]:
-    """The paged static-argument domain for config.py defaults and each
+    """The static-argument domain for config.py defaults and each
     configs/*.toml (requires the runtime imports; generation and tests
     only)."""
     from distributed_lms_raft_llm_tpu.config import (
@@ -155,7 +154,7 @@ def shipped_domains() -> List[Dict[str, object]]:
         )
         sdom = static_score_domain(
             tuple(ec.length_buckets), tuple(ec.batch_buckets),
-            cfg.max_position_embeddings, sp=ec.sp,
+            cfg.max_position_embeddings,
             enabled=app.scoring.enabled,
         )
         total = (
@@ -164,8 +163,7 @@ def shipped_domains() -> List[Dict[str, object]]:
             + dom_score_pairs(sdom)
         )
         rows.append(dict(
-            name=name, model=ec.model, paged=app.tutoring.paged,
-            mesh=dom["mesh"],
+            name=name, model=ec.model, mesh=dom["mesh"],
             spec_tokens=ec.spec_tokens,
             max_new=ec.sampling.max_new_tokens,
             buckets=dom["buckets"], widths=dom["widths"],
@@ -195,7 +193,7 @@ def render_readme_block(entries: List[dict]) -> str:
         )
     lines.append("")
     lines.append(
-        "Derived paged static-argument domains (prompt buckets × cache "
+        "Derived static-argument domains (prompt buckets × cache "
         "widths × megastep ladder; `total` is the full warmup-compiled "
         "program count `expected_from_inventory` asserts, per mesh — a "
         "tp=2 engine's programs are different XLA programs than a tp=1 "
@@ -203,17 +201,16 @@ def render_readme_block(entries: List[dict]) -> str:
     )
     lines.append("")
     lines.append(
-        "| config | model | paged | mesh | spec_tokens | max_new "
+        "| config | model | mesh | spec_tokens | max_new "
         "| prompt buckets | cache widths | megastep ladder "
         "| prefix cache | score programs "
         "| total warmup programs |"
     )
-    lines.append("|---|---|---|---|---|---|---|---|---|---|---|---|")
+    lines.append("|---|---|---|---|---|---|---|---|---|---|---|")
     for row in shipped_domains():
         mesh = row["mesh"]
         lines.append(
             f"| {row['name']} | `{row['model']}` "
-            f"| {'yes' if row['paged'] else 'no'} "
             f"| tp={mesh['tp']} ep={mesh['ep']} | {row['spec_tokens']} "
             f"| {row['max_new']} "
             f"| {', '.join(str(b) for b in row['buckets'])} "

@@ -335,10 +335,7 @@ def fit_capacity(
     qdepths = sorted(s["queue_depth"] for s in samples)
     service_p95 = None
     if stage_p95s:
-        for span in ("engine.decode", "engine.batch", "engine.generate"):
-            if span in stage_p95s and "p95_s" in stage_p95s[span]:
-                service_p95 = stage_p95s[span]["p95_s"]
-                break
+        service_p95 = stage_p95s.get("engine.decode", {}).get("p95_s")
     return {
         "metric": "capacity_req_s_per_node_at_slo",
         "value": round(demonstrated, 3),

@@ -63,6 +63,27 @@ def ordered_locks():
     locks.assert_acyclic()
 
 
+@pytest.fixture(scope="session")
+def moves_a_reported_metric():
+    """`check(entry)`: a per-layer metric of BENCHMARK.json says which
+    end-to-end metric it should move. That is one the benchmark HAS, and
+    one every cell of the per-layer metric REPORTS; which one, and under
+    what name, is the benchmark's to say and no test's to pin."""
+    import json
+
+    with open(os.path.join(os.path.dirname(__file__), os.pardir,
+                           "BENCHMARK.json")) as fh:
+        bench = json.load(fh)
+    cells = [w["name"] for w in bench["workloads"]]
+    end_to_end = {m["name"]: m for m in bench["end_to_end"]}
+
+    def check(entry):
+        assert entry["moves"] in end_to_end, entry
+        moved = end_to_end[entry["moves"]]
+        assert set(entry.get("workloads", cells)) <= set(
+            moved.get("workloads", cells)), (entry, moved)
+
+    return check
 
 
 @pytest.fixture

@@ -29,7 +29,6 @@ from distributed_lms_raft_llm_tpu.engine import paged
 from distributed_lms_raft_llm_tpu.engine.draft import build_drafts
 from distributed_lms_raft_llm_tpu.engine.sampling import SamplingParams
 from distributed_lms_raft_llm_tpu.models import quant, registry
-from distributed_lms_raft_llm_tpu.ops.attention import decode_attention
 from distributed_lms_raft_llm_tpu.parallel import mesh as mesh_lib
 from distributed_lms_raft_llm_tpu.parallel import partition
 from distributed_lms_raft_llm_tpu.utils import tokenizer as tok_lib
@@ -84,32 +83,6 @@ def _with(tree, shardings):
     )
 
 
-# ------------------------------------------------------------- the kernel
-
-@pytest.mark.parametrize(
-    "name,layers,batch,heads,kv_heads,s,dh",
-    [
-        ("gpt2", 12, 8, 12, 12, 1024, 64),
-        # 20 heads x [1024, 64->128 lanes] K and V, double-buffered, is
-        # 20 MiB in one block: over the 16 MiB scope until the head axis
-        # was split across grid steps (ops/attention._kv_heads_per_step).
-        ("gpt2-large", 36, 8, 20, 20, 1024, 64),
-        ("gpt2-batch32", 12, 32, 12, 12, 256, 64),
-    ],
-)
-def test_decode_attention_compiles_for_v5e(one_chip, name, layers, batch,
-                                           heads, kv_heads, s, dh):
-    def sd(shape, dtype):
-        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
-
-    cache = sd((layers, batch, kv_heads, s, dh), jnp.bfloat16)
-    compiled = jax.jit(decode_attention).lower(
-        sd((batch, heads, 1, dh), jnp.bfloat16), cache, cache,
-        sd((), jnp.int32), sd((batch, 1, s), jnp.float32),
-    ).compile()
-    assert "tpu_custom_call" in compiled.as_text()
-
-
 # ------------------------------------------------- the paged step programs
 
 class _Production:
@@ -121,9 +94,7 @@ class _Production:
             os.path.join(REPO, "configs", "cluster.toml")
         )
         t = cfg.tutoring
-        assert (t.model, t.quant, t.kv_quant, t.paged) == (
-            "gpt2", "int8", True, True
-        )
+        assert (t.model, t.quant, t.kv_quant) == ("gpt2", "int8", True)
         self.t = t
         econf = config_lib.engine_config(cfg)
         self.sampling = econf.sampling

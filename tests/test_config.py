@@ -43,9 +43,9 @@ def _capture_args(module, argv):
 
     patches = [mock.patch.object(argparse.ArgumentParser, "parse_args",
                                  capture)]
-    for name in ("TutoringEngine", "PagedEngine"):
-        if hasattr(module, name):
-            patches.append(mock.patch.object(module, name, side_effect=stop))
+    if hasattr(module, "PagedEngine"):
+        patches.append(mock.patch.object(module, "PagedEngine",
+                                         side_effect=stop))
 
     def fake_run(coro):
         coro.close()
@@ -155,7 +155,6 @@ def _write_deploy_toml(tmp_path, lms_port, tut_port):
         address = "127.0.0.1:{tut_port}"
         model = "tiny"
         kv_quant = true
-        paged = true
         [sampling]
         max_new_tokens = 8
     """))
@@ -175,7 +174,7 @@ def test_server_cli_config_phases(tmp_path):
     targs = _capture_args(tutoring_server, ["--config", str(f), *cpu])
     assert targs.port == tut_port
     assert targs.model == "tiny"
-    assert targs.kv_quant and targs.paged
+    assert targs.kv_quant and not hasattr(targs, "paged")
     assert targs.max_new_tokens == 8
 
     # Explicit flag beats the file.

@@ -1,4 +1,6 @@
-"""Engine end-to-end on the 8-device CPU mesh: tiny model, real pipeline."""
+"""The reference generator (`TutoringEngine`) end-to-end on the 8-device CPU
+mesh: tiny model, real pipeline; and the served queue closing over a tiny
+`PagedEngine`."""
 
 import asyncio
 
@@ -8,9 +10,10 @@ import pytest
 import jax
 
 from distributed_lms_raft_llm_tpu.engine import (
-    BatchingQueue,
     EngineConfig,
     GateConfig,
+    PagedEngine,
+    PagedQueue,
     RelevanceGate,
     SamplingParams,
     TutoringEngine,
@@ -68,36 +71,6 @@ def test_generation_respects_max_new_tokens(engine):
     assert (result.lengths <= 8).all()
 
 
-def test_batching_queue_coalesces():
-    cfg = EngineConfig(
-        model="tiny",
-        sampling=SamplingParams(max_new_tokens=4),
-        length_buckets=(16,),
-        batch_buckets=(1, 2, 4),
-        dtype=jax.numpy.float32,
-    )
-    eng = TutoringEngine(cfg)
-    calls = []
-    orig = eng.answer_batch
-
-    def spy(prompts):
-        calls.append(len(prompts))
-        return orig(prompts)
-
-    eng.answer_batch = spy
-
-    async def run():
-        q = BatchingQueue(eng, max_batch=4, max_wait_ms=200)
-        await q.start()
-        answers = await asyncio.gather(*[q.submit(f"q{i}") for i in range(4)])
-        await q.close()
-        return answers
-
-    answers = asyncio.run(run())
-    assert len(answers) == 4
-    assert max(calls) >= 2  # at least some coalescing happened
-
-
 def test_relevance_gate_threshold():
     gate = RelevanceGate(GateConfig(model="tiny", dtype=jax.numpy.float32))
     ok, sim = gate.check("what is a binary tree", "binary trees and traversals")
@@ -134,10 +107,10 @@ def test_queue_close_fails_pending_submits():
         batch_buckets=(1,),
         dtype=jax.numpy.float32,
     )
-    eng = TutoringEngine(cfg)
+    eng = PagedEngine(cfg, slots=1, chunk=2)
 
     async def run():
-        q = BatchingQueue(eng, max_batch=1, max_wait_ms=1)
+        q = PagedQueue(eng)
         await q.start()
         tasks = [asyncio.create_task(q.submit(f"q{i}")) for i in range(3)]
         await asyncio.sleep(0.05)  # let some enter flight
@@ -148,69 +121,3 @@ def test_queue_close_fails_pending_submits():
     results = asyncio.run(run())
     # Every pending submit resolved (answer or RuntimeError) — none hang.
     assert all(isinstance(r, (str, RuntimeError)) for r in results)
-
-
-class TestScore:
-    """engine.score: log-likelihood scoring (the long-context surface)."""
-
-    def _engine(self, **kw):
-        kw.setdefault("model", "tiny")
-        kw.setdefault("sampling", SamplingParams(max_new_tokens=4))
-        kw.setdefault("length_buckets", (16, 32))
-        kw.setdefault("batch_buckets", (1, 2))
-        kw.setdefault("dtype", jax.numpy.float32)
-        kw.setdefault("param_dtype", jax.numpy.float32)
-        return TutoringEngine(EngineConfig(**kw))
-
-    def test_matches_manual_log_softmax(self):
-        import jax.numpy as jnp
-
-        eng = self._engine()
-        text = "raft elects a leader"  # fits the 32-token bucket
-        [res] = eng.score([text])
-        toks = eng.tokenizer.encode(text)
-        logits, _ = eng.family.forward(
-            eng.params, eng.cfg, jnp.asarray([toks], jnp.int32)
-        )
-        logp = jax.nn.log_softmax(
-            jnp.asarray(logits[0], jnp.float32), axis=-1
-        )
-        want = float(sum(
-            logp[i, toks[i + 1]] for i in range(len(toks) - 1)
-        ))
-        assert res["tokens"] == len(toks) - 1
-        np.testing.assert_allclose(res["logprob"], want, rtol=1e-4,
-                                   atol=1e-4)
-        np.testing.assert_allclose(
-            res["ppl"], float(np.exp(-want / (len(toks) - 1))), rtol=1e-4
-        )
-
-    def test_ring_sharded_score_matches_single_device(self):
-        dense = self._engine()
-        ring = self._engine(sp=2)
-        assert ring.mesh.shape["sp"] == 2
-        texts = ["the leader replicates logs",
-                 "a quorum is a majority"]
-        a = dense.score(texts)
-        b = ring.score(texts)
-        for ra, rb in zip(a, b):
-            assert ra["tokens"] == rb["tokens"]
-            np.testing.assert_allclose(ra["logprob"], rb["logprob"],
-                                       rtol=1e-4, atol=1e-4)
-
-    def test_moe_scores(self):
-        eng = self._engine(model="moe-tiny")
-        [res] = eng.score(["hello experts"])
-        assert res["tokens"] >= 1 and np.isfinite(res["ppl"])
-
-    def test_oversized_group_chunks(self):
-        # More texts than the largest batch bucket run as several device
-        # batches (mirrors answer_batch), order preserved.
-        eng = self._engine()
-        texts = [f"text number {i}" for i in range(5)]  # cap is 2
-        res = eng.score(texts)
-        assert len(res) == 5
-        # Chunking must not change any individual score.
-        [alone] = eng.score([texts[3]])
-        np.testing.assert_allclose(res[3]["logprob"], alone["logprob"],
-                                   rtol=1e-4, atol=1e-4)

@@ -204,20 +204,19 @@ def test_host_plane_holds_the_loop_spans_nested(tmp_path, monkeypatch):
                 assert any(s <= start and end <= e for s, e in steps), name
     assert checked >= 2 * len(PROMPTS)
     # The sink's sums are the host plane's: as many spans of every name,
-    # their sums the sums of the spans it was handed, and span by span (a
-    # name's spans do not overlap, so both are in time's order) the wall
-    # within the few microseconds a span's own clock reads lie outside its
-    # annotation. Two things this may not assume, both met with six test
-    # workers on the machine. The profiler's clock is another clock and
-    # runs at another RATE (0.12 ms a second faster than the monotonic one
-    # here; a clock under adjustment may be slewed by 0.5), so an
-    # annotation may read longer than its span by a share of its length,
-    # and a step that takes a second under load is no step of 0.2 s. And
-    # the step's thread is now and then descheduled between a span's clock
-    # read and its annotation's, for a time slice that no bound on
-    # microseconds survives: that holds the MEDIAN span of a name to the
-    # microseconds, not each nor their sum. A clock read out of place
-    # would show in every span, and so in the median.
+    # their sums the sums of the spans it was handed, and (a name's spans
+    # do not overlap, so both are in time's order) the MEDIAN span of a
+    # name within the few microseconds a span's own clock reads lie
+    # outside its annotation, from both sides. Not each span, nor their
+    # sum: with six test workers on the machine one annotation in a run
+    # reads half a millisecond LONGER than the monotonic reading that
+    # encloses it (25.299 ms for 24.803: the profiler's clock is another
+    # clock, runs at another rate, 0.12 ms a second faster here, and is
+    # read on a thread that is now and then descheduled between a span's
+    # clock read and its annotation's, for a time slice that no bound on
+    # microseconds survives), and a step that takes a second under load is
+    # no step of 0.2 s. A clock read out of place would show in every
+    # span, and so in the median, whichever side it fell on.
     plane = {}
     for evs in lines:
         for name, start, end in evs:
@@ -228,11 +227,10 @@ def test_host_plane_holds_the_loop_spans_nested(tmp_path, monkeypatch):
         inside = [d for _, d in sorted(plane[name])]
         assert len(inside) == n == len(walls), name
         assert wall_s == pytest.approx(sum(walls), rel=1e-9), name
-        longest = max(zip(inside, walls), key=lambda dw: dw[0] - dw[1])
-        assert longest[0] <= longest[1] * (1 + 5e-4) + 1e-4, (name, longest)
         outside = sorted(w - d for d, w in zip(inside, walls))
-        assert outside[(n - 1) // 2] <= 5e-4 + 0.01 * max(walls), (
-            name, outside)
+        median = outside[(n - 1) // 2]
+        assert -(1e-4 + 5e-4 * max(walls)) <= median, (name, outside)
+        assert median <= 5e-4 + 0.01 * max(walls), (name, outside)
 
 
 # ------------------------------------------------ (c), (e) conservation
@@ -547,7 +545,7 @@ def _without_the_budget(ctx):
 
 @pytest.mark.parametrize("name", sorted(NEW_METRICS))
 def test_budget_metric_is_a_data_file_on_a_reader_that_is_there(
-        name, served_ctx):
+        name, served_ctx, moves_a_reported_metric):
     spec = _load("benchmarks", "layer_metrics", name + ".json")
     # The reader is one the benchmark has, and its series are declared
     # and reach /metrics on the served path.
@@ -565,10 +563,11 @@ def test_budget_metric_is_a_data_file_on_a_reader_that_is_there(
     per_layer = _load("BENCHMARK.json")["per_layer"]
     (entry,) = [m for m in per_layer if m["name"] == name]
     unit, better = NEW_METRICS[name]
-    assert entry == {
+    moves_a_reported_metric(entry)
+    assert {k: v for k, v in entry.items() if k != "moves"} == {
         "name": name, "unit": unit, "better": better,
         "source": "program_span",
-        "layer": "paged engine (engine/paged.py)", "moves": "out_tok_s",
+        "layer": "paged engine (engine/paged.py)",
     }
     assert entry["layer"] in {m["layer"] for m in per_layer
                               if m["name"] not in NEW_METRICS}

@@ -504,7 +504,7 @@ def test_tutoring_config_refuses_a_chunk_budget_below_one(tmp_path):
     with pytest.raises(ValueError, match="prefill_chunk_tokens"):
         TutoringConfig(prefill_chunk_tokens=0)
     toml = tmp_path / "zero.toml"
-    toml.write_text("[tutoring]\npaged = true\nprefill_chunk_tokens = 0\n")
+    toml.write_text("[tutoring]\nprefill_chunk_tokens = 0\n")
     with pytest.raises(ValueError, match="prefill_chunk_tokens"):
         load_config(str(toml))
 

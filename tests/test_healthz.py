@@ -49,18 +49,19 @@ def test_tutoring_server_exposes_endpoint():
     import grpc
 
     from distributed_lms_raft_llm_tpu.engine import (
-        EngineConfig, SamplingParams, TutoringEngine,
+        EngineConfig, PagedEngine, SamplingParams,
     )
     from distributed_lms_raft_llm_tpu.proto import lms_pb2, rpc
     from distributed_lms_raft_llm_tpu.serving import tutoring_server
 
     async def run():
-        engine = TutoringEngine(
+        engine = PagedEngine(
             EngineConfig(
                 model="tiny",
                 sampling=SamplingParams.reference_defaults(max_new_tokens=8),
                 length_buckets=(16,), batch_buckets=(1, 2),
-            )
+            ),
+            slots=2,
         )
         server = await tutoring_server.serve_async(0, engine, metrics_port=0)
         # serve_async binds the gRPC port before returning; for port 0 grab
@@ -68,7 +69,7 @@ def test_tutoring_server_exposes_endpoint():
         hport = server._health.port
         status, body = await _get(hport, "/healthz")
         assert status == 200 and body["ok"]
-        assert body["engine"] == "TutoringEngine"
+        assert body["engine"] == "PagedEngine"
         # The node says what it computes on (a JAX-free launcher reads
         # this instead of asking JAX itself) and where it caches compiles.
         assert body["device"] == {"platform": "cpu", "kind": "cpu",

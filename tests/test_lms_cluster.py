@@ -11,12 +11,12 @@ import jax
 
 from distributed_lms_raft_llm_tpu.client import LMSClient
 from distributed_lms_raft_llm_tpu.engine import (
-    BatchingQueue,
     EngineConfig,
     GateConfig,
+    PagedEngine,
+    PagedQueue,
     RelevanceGate,
     SamplingParams,
-    TutoringEngine,
 )
 from distributed_lms_raft_llm_tpu.lms.node import LMSNode
 from distributed_lms_raft_llm_tpu.lms.service import (
@@ -50,16 +50,17 @@ def cluster(tmp_path_factory):
 
         async def boot():
             # Tutoring node (tiny model).
-            engine = TutoringEngine(
+            engine = PagedEngine(
                 EngineConfig(
                     model="tiny",
                     sampling=SamplingParams(max_new_tokens=6),
                     length_buckets=(32,),
                     batch_buckets=(1, 2, 4),
                     dtype=jax.numpy.float32,
-                )
+                ),
+                slots=4, chunk=2,
             )
-            queue = BatchingQueue(engine, max_batch=4, max_wait_ms=10)
+            queue = PagedQueue(engine)
             await queue.start()
             tut_server = grpc.aio.server()
             rpc.add_TutoringServicer_to_server(

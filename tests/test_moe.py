@@ -387,15 +387,6 @@ class TestServing:
         with pytest.raises(ValueError, match="requires an MoE family"):
             TutoringEngine(EngineConfig(model="tiny", ep=2))
 
-    def test_paged_engine_rejects_sp(self):
-        from distributed_lms_raft_llm_tpu.engine import (
-            EngineConfig,
-            PagedEngine,
-        )
-
-        with pytest.raises(ValueError, match="sp applies to"):
-            PagedEngine(EngineConfig(model="tiny", sp=2))
-
     def test_engine_rejects_spec_with_dropping_moe(self):
         # Default capacity_factor (1.25) drops tokens, which breaks the
         # spec verifier's exactness contract — must fail loudly.

@@ -74,12 +74,13 @@ def test_manifest_covers_the_paged_program_set():
     assert all(
         e.coverage == "warmup" for e in inv.entries_for("PagedEngine")
     ), "the paged engine's whole program set is a warmup promise"
-    # The bulk-scoring program is a warmup promise on BOTH engines
-    # (domain empty when EngineConfig.scoring is off).
-    score = [e for e in inv.entries_for("TutoringEngine")
+    # The bulk-scoring program is a warmup promise (domain empty when
+    # EngineConfig.scoring is off), bound once: the reference generator
+    # serves nothing and has no rows.
+    score = [e for e in inv.entries_for("PagedEngine")
              if e.attr == "_score"]
-    assert score and score[0].coverage == "warmup"
-    assert score[0].domain == "score-pairs"
+    assert score and score[0].domain == "score-pairs"
+    assert inv.entries_for("TutoringEngine") == []
 
 
 def test_static_domain_math_is_engine_math():

@@ -10,7 +10,8 @@ import time
 
 import pytest
 
-from distributed_lms_raft_llm_tpu.engine.batcher import BatchingQueue, PagedQueue
+from distributed_lms_raft_llm_tpu.engine.batcher import PagedQueue
+from distributed_lms_raft_llm_tpu.sim.cluster import EchoEngine
 from distributed_lms_raft_llm_tpu.utils.faults import (
     FaultInjected,
     FaultInjector,
@@ -247,126 +248,15 @@ def test_faulty_transport_drop_error_duplicate():
     asyncio.run(run())
 
 
-# --------------------------------------------------- bounded batcher admission
+# ----------------------------------------------------- bounded queue admission
 
 
-class SlowEngine:
-    """answer_batch blocks long enough for queue pressure to build."""
-
-    def __init__(self, delay_s=0.2):
-        self.delay_s = delay_s
-        self.batches = []
-
-    def answer_batch(self, prompts):
-        self.batches.append(list(prompts))
-        time.sleep(self.delay_s)
-        return [f"ans:{p}" for p in prompts]
-
-
-def test_batching_queue_sheds_on_overload():
-    async def run():
-        engine = SlowEngine(delay_s=0.3)
-        metrics = Metrics()
-        q = BatchingQueue(engine, max_batch=1, max_wait_ms=1,
-                          metrics=metrics, max_queue=1)
-        await q.start()
-        try:
-            t1 = asyncio.ensure_future(q.submit("a"))  # runner picks this up
-            await asyncio.sleep(0.1)                   # a is now in-flight
-            t2 = asyncio.ensure_future(q.submit("b"))  # occupies the 1 slot
-            await asyncio.sleep(0.05)
-            with pytest.raises(Overloaded):
-                await q.submit("c")                    # bounded: refused
-            assert await t1 == "ans:a"
-            assert await t2 == "ans:b"
-        finally:
-            await q.close()
-        snap = metrics.snapshot()
-        assert snap["counters"]["shed_overload"] == 1
-        assert snap["counters"]["engine_batches"] == 2
-        assert ["c"] not in engine.batches
-
-    asyncio.run(run())
-
-
-def test_batching_queue_drops_expired_before_prefill():
-    async def run():
-        engine = SlowEngine(delay_s=0.25)
-        metrics = Metrics()
-        q = BatchingQueue(engine, max_batch=1, max_wait_ms=1, metrics=metrics)
-        await q.start()
-        try:
-            t1 = asyncio.ensure_future(q.submit("a"))
-            await asyncio.sleep(0.1)  # "a" holds the engine for ~0.25s
-            # "b" will expire while queued behind "a".
-            t2 = asyncio.ensure_future(
-                q.submit("b", deadline=Deadline.after(0.05))
-            )
-            assert await t1 == "ans:a"
-            with pytest.raises(DeadlineExpired):
-                await t2
-            # An already-expired submit is refused before even enqueueing.
-            with pytest.raises(DeadlineExpired):
-                await q.submit("c", deadline=Deadline.after(0.0))
-        finally:
-            await q.close()
-        snap = metrics.snapshot()
-        # ZERO prefills for expired requests: only "a" reached the engine.
-        assert engine.batches == [["a"]]
-        assert snap["counters"]["engine_batches"] == 1
-        assert snap["counters"]["shed_expired"] == 2
-
-    asyncio.run(run())
-
-
-class FakePagedEngine:
-    """Paged-engine double mirroring the real pending/slot split: submit()
-    backlogs, step() admits ONE request per call (slots=1), prefill
-    happens at admission."""
-
-    def __init__(self, step_delay_s=0.02):
-        self.step_delay_s = step_delay_s
-        self.prefilled = []          # prompts whose prefill actually ran
-        self._next = 0
-        self._pending = []           # (rid, prompt) awaiting a slot
-        self._active = {}
-
-    @property
-    def has_work(self):
-        return bool(self._pending or self._active)
-
-    @property
-    def backlog(self):
-        return len(self._pending)
-
-    def cancel_pending(self, rid):
-        for i, (r, _) in enumerate(self._pending):
-            if r == rid:
-                del self._pending[i]
-                return True
-        return False
-
-    def submit(self, prompt):
-        self._next += 1
-        self._pending.append((self._next, prompt))
-        return self._next
-
-    def step(self):
-        if not self._active and self._pending:
-            rid, prompt = self._pending.pop(0)
-            self.prefilled.append(prompt)  # admission = prefill
-            self._active[rid] = prompt
-        time.sleep(self.step_delay_s)
-        done = [(rid, f"ans:{p}") for rid, p in self._active.items()]
-        self._active.clear()
-        return done
-
-    def pop_ttfts(self):
-        return {}
-
-    def reset(self):
-        self._pending.clear()
-        self._active.clear()
+def FakePagedEngine(step_delay_s=0.02):
+    """The sim's double with ONE slot, so the pending/slot split of the
+    real engine shows: submit() backlogs, step() admits one request a
+    call (`prefilled` lists the prompts whose prefill ran) and answers it
+    in that same step."""
+    return EchoEngine(step_delay_s, slots=1, answer="ans:{}".format)
 
 
 def test_paged_queue_sheds_expired_before_admission():

@@ -15,10 +15,10 @@ import jax
 
 from distributed_lms_raft_llm_tpu.client import LMSClient
 from distributed_lms_raft_llm_tpu.engine import (
-    BatchingQueue,
     EngineConfig,
+    PagedEngine,
+    PagedQueue,
     SamplingParams,
-    TutoringEngine,
 )
 from distributed_lms_raft_llm_tpu.lms.node import LMSNode
 from distributed_lms_raft_llm_tpu.lms.service import (
@@ -29,6 +29,7 @@ from distributed_lms_raft_llm_tpu.proto import lms_pb2, rpc
 from distributed_lms_raft_llm_tpu.raft import RaftConfig
 from distributed_lms_raft_llm_tpu.raft.grpc_transport import RaftServicer
 from distributed_lms_raft_llm_tpu.serving import tutoring_server as ts
+from distributed_lms_raft_llm_tpu.sim.cluster import EchoEngine
 from distributed_lms_raft_llm_tpu.utils import pdf
 from distributed_lms_raft_llm_tpu.utils.faults import FaultInjector
 from distributed_lms_raft_llm_tpu.utils.metrics import Metrics
@@ -56,18 +57,18 @@ def stack(tmp_path_factory):
         asyncio.set_event_loop(loop)
 
         async def boot():
-            engine = TutoringEngine(
+            engine = PagedEngine(
                 EngineConfig(
                     model="tiny",
                     sampling=SamplingParams(max_new_tokens=6),
                     length_buckets=(32,),
                     batch_buckets=(1, 2, 4),
                     dtype=jax.numpy.float32,
-                )
+                ),
+                slots=4, chunk=2,
             )
             tut_metrics = Metrics()
-            queue = BatchingQueue(engine, max_batch=4, max_wait_ms=10,
-                                  metrics=tut_metrics, max_queue=8)
+            queue = PagedQueue(engine, metrics=tut_metrics, max_queue=8)
             await queue.start()
             tut_server = grpc.aio.server()
             rpc.add_TutoringServicer_to_server(
@@ -240,17 +241,14 @@ def test_tutoring_overload_returns_resource_exhausted(stack):
     queue = stack["queue"]
     loop = stack["loop"]
 
-    # Block the engine worker with a synthetic slow batch, then fill the
-    # bounded queue from the cluster loop so qsize really accumulates.
+    # Block the engine worker with a synthetic slow step, then fill the
+    # bounded queue from the cluster loop so the backlog really
+    # accumulates.
     real_engine = queue.engine
 
-    class Plug:
-        def answer_batch(self, prompts):
-            time.sleep(2.0)
-            return ["plugged"] * len(prompts)
-
     async def saturate():
-        queue.engine = Plug()
+        queue.engine = EchoEngine(delay_s=1.0, slots=queue.max_queue,
+                                  answer=lambda prompt: "plugged")
         # Stage 1: one request the runner takes alone into the (plugged)
         # engine; stage 2: exactly max_queue more fill the bound while the
         # engine is busy.
@@ -259,7 +257,7 @@ def test_tutoring_overload_returns_resource_exhausted(stack):
         futs += [asyncio.ensure_future(queue.submit(f"fill {i}"))
                  for i in range(queue.max_queue)]
         await asyncio.sleep(0.05)
-        assert queue._queue.qsize() >= queue.max_queue
+        assert queue.waiting >= queue.max_queue
         return futs
 
     futs = asyncio.run_coroutine_threadsafe(saturate(), loop).result(10)
@@ -276,8 +274,8 @@ def test_tutoring_overload_returns_resource_exhausted(stack):
                 .get("shed_overload", 0) >= 1)
     finally:
         async def drain():
-            queue.engine = real_engine
             await asyncio.gather(*futs, return_exceptions=True)
+            queue.engine = real_engine
 
         asyncio.run_coroutine_threadsafe(drain(), loop).result(30)
 

@@ -4,14 +4,13 @@ co-scheduler in engine/batcher.py).
 Claims pinned here:
 
 - score numerics are pad/batch-invariant: per-text logprobs are equal
-  batched-vs-singleton across batch AND length buckets, and on the sp>1
-  ring-attention path (CPU mesh);
+  batched-vs-singleton across batch AND length buckets, equal to a
+  log-softmax by hand, and unchanged by chunking an oversized group;
 - `score()` reports truncation per item (and the manager counts it in
   `score_truncated_texts`) instead of silently scoring prefixes;
 - the score program is a first-class inventoried program: a warmed
   scoring-enabled session runs a bulk job with ZERO live compiles and
-  `expected_from_inventory` exact equality holds (both engines); a
-  scoring-disabled bucketed engine is still rejected loudly;
+  `expected_from_inventory` exact equality holds;
 - the co-scheduler admits quanta only while nothing interactive is
   pending, and an interactive request arriving mid-quantum waits at most
   ONE quantum before its prefill dispatches — measured and recorded as
@@ -23,36 +22,25 @@ Claims pinned here:
 import asyncio
 import time
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
 from distributed_lms_raft_llm_tpu.engine import (
-    BatchingQueue,
     EngineConfig,
     PagedEngine,
     PagedQueue,
     SamplingParams,
     ScoringManager,
-    TutoringEngine,
 )
 from distributed_lms_raft_llm_tpu.engine.scoring import score_admin_get
 from distributed_lms_raft_llm_tpu.utils.guards import (
-    InventoryMismatchError,
     compile_count_guard,
     expected_from_inventory,
 )
+from distributed_lms_raft_llm_tpu.sim.cluster import EchoEngine
 from distributed_lms_raft_llm_tpu.utils.metrics import Metrics
-
-
-def tiny_tutoring(**kw):
-    kw.setdefault("model", "tiny")
-    kw.setdefault("sampling", SamplingParams(max_new_tokens=4))
-    kw.setdefault("length_buckets", (16, 32))
-    kw.setdefault("batch_buckets", (1, 2))
-    kw.setdefault("dtype", jnp.float32)
-    kw.setdefault("param_dtype", jnp.float32)
-    return TutoringEngine(EngineConfig(**kw))
 
 
 def tiny_paged(**kw):
@@ -65,6 +53,15 @@ def tiny_paged(**kw):
     return PagedEngine(EngineConfig(**kw), slots=2, chunk=2)
 
 
+def tiny_scorer(**kw):
+    """A served engine for `score` alone, in float32 weights: nothing is
+    warmed or generated."""
+    kw.setdefault("length_buckets", (16, 32))
+    kw.setdefault("param_dtype", jnp.float32)
+    kw.setdefault("scoring", False)
+    return tiny_paged(**kw)
+
+
 # ---------------------------------------------------------- numerics
 
 
@@ -72,7 +69,7 @@ class TestScoreNumerics:
     def test_batched_equals_singleton_across_buckets(self):
         """Pad invariance: a text's logprob must not depend on which
         (batch, length) bucket its companions forced it into."""
-        eng = tiny_tutoring()
+        eng = tiny_scorer()
         texts = [
             "a",                                     # 16-bucket, short
             "the raft consensus algorithm elects a leader and "
@@ -87,26 +84,46 @@ class TestScoreNumerics:
             np.testing.assert_allclose(got["logprob"], alone["logprob"],
                                        rtol=1e-4, atol=1e-4)
 
-    def test_ring_sharded_score_matches_dense_with_truncation(self):
-        """The sp>1 ring-attention path on the CPU mesh agrees with the
-        dense forward, truncation flags included."""
-        dense = tiny_tutoring()
-        ring = tiny_tutoring(sp=2)
-        assert ring.mesh.shape["sp"] == 2
-        long_text = " ".join(["leader election term"] * 40)  # > 32 toks
-        texts = ["the leader replicates logs", long_text]
-        a = dense.score(texts)
-        b = ring.score(texts)
-        for ra, rb in zip(a, b):
-            assert ra["truncated"] == rb["truncated"]
-            assert ra["tokens"] == rb["tokens"]
-            np.testing.assert_allclose(ra["logprob"], rb["logprob"],
-                                       rtol=1e-4, atol=1e-4)
-        assert a[0]["truncated"] is False
-        assert a[1]["truncated"] is True
+    def test_matches_manual_log_softmax(self):
+        eng = tiny_scorer()
+        text = "raft elects a leader"  # fits the 32-token bucket
+        [res] = eng.score([text])
+        toks = eng.tokenizer.encode(text)
+        logits, _ = eng.family.forward(
+            eng.params, eng.cfg, jnp.asarray([toks], jnp.int32)
+        )
+        logp = jax.nn.log_softmax(
+            jnp.asarray(logits[0], jnp.float32), axis=-1
+        )
+        want = float(sum(
+            logp[i, toks[i + 1]] for i in range(len(toks) - 1)
+        ))
+        assert res["tokens"] == len(toks) - 1
+        np.testing.assert_allclose(res["logprob"], want, rtol=1e-4,
+                                   atol=1e-4)
+        np.testing.assert_allclose(
+            res["ppl"], float(np.exp(-want / (len(toks) - 1))), rtol=1e-4
+        )
+
+    def test_moe_scores(self):
+        eng = tiny_scorer(model="moe-tiny")
+        [res] = eng.score(["hello experts"])
+        assert res["tokens"] >= 1 and np.isfinite(res["ppl"])
+
+    def test_oversized_group_chunks(self):
+        # More texts than the largest batch bucket run as several device
+        # batches, order preserved.
+        eng = tiny_scorer()
+        texts = [f"text number {i}" for i in range(5)]  # cap is 2
+        res = eng.score(texts)
+        assert len(res) == 5
+        # Chunking must not change any individual score.
+        [alone] = eng.score([texts[3]])
+        np.testing.assert_allclose(res[3]["logprob"], alone["logprob"],
+                                   rtol=1e-4, atol=1e-4)
 
     def test_truncated_flag_marks_prefix_scores(self):
-        eng = tiny_tutoring(length_buckets=(8,))
+        eng = tiny_scorer(length_buckets=(8,))
         long_text = " ".join(["raft"] * 30)
         short_text = "raft"  # under the 8-token bucket in any tokenizer
         res = eng.score([short_text, long_text])
@@ -141,20 +158,6 @@ class TestScoreInventory:
                        "terms increase monotonically"])  # > one quantum
         assert guard.new_compiles() == 0
 
-    def test_warmed_bucketed_scoring_session_zero_live_compiles(self):
-        eng = tiny_tutoring(scoring=True)
-        eng.warmup(batch=2, bucket=16)
-        expectation = expected_from_inventory(eng)
-        assert expectation.mismatches() == {}
-        with compile_count_guard(expectation.fns["_score"]) as guard:
-            eng.score(["one", "two tokens here", "three"])
-        assert guard.new_compiles() == 0
-
-    def test_scoring_disabled_bucketed_engine_still_rejected(self):
-        eng = tiny_tutoring()  # scoring off
-        with pytest.raises(InventoryMismatchError, match="warmup-covered"):
-            expected_from_inventory(eng)
-
     def test_paged_without_scoring_expects_zero_score_programs(self):
         eng = tiny_paged(scoring=False)
         eng.warmup()
@@ -166,19 +169,18 @@ class TestScoreInventory:
 # ------------------------------------------------------ the job manager
 
 
-class SlowScoreEngine:
-    """Deterministic scoring-contract stand-in with a controllable
-    quantum wall, for co-scheduler timing tests."""
+class SlowScoreEngine(EchoEngine):
+    """The sim's double with a scoring quantum of its own: a wall the
+    co-scheduler timing tests choose, a quantum that can be made to
+    fail, and a truncation flag to count."""
 
     score_batch_cap = 2
 
     def __init__(self, quantum_s: float = 0.0, fail_at: int = -1):
+        super().__init__(delay_s=0.0, answer="ans:{}".format)
         self.quantum_s = quantum_s
         self.fail_at = fail_at
         self.calls = 0
-
-    def answer_batch(self, prompts):
-        return [f"ans:{p}" for p in prompts]
 
     def score(self, texts):
         self.calls += 1
@@ -267,8 +269,7 @@ class TestCoScheduling:
             metrics = Metrics()
             eng = SlowScoreEngine(quantum_s=0.4)
             scorer = ScoringManager(eng, metrics=metrics)
-            q = BatchingQueue(eng, max_batch=2, max_wait_ms=1.0,
-                              metrics=metrics, scorer=scorer)
+            q = PagedQueue(eng, metrics=metrics, scorer=scorer)
             await q.start()
             scorer.submit(["t one", "t two", "t three", "t four"])
             await asyncio.sleep(0.1)  # first quantum is in flight
@@ -334,7 +335,7 @@ class TestCoScheduling:
             metrics = Metrics()
             eng = SlowScoreEngine()
             scorer = ScoringManager(eng, metrics=metrics)
-            q = BatchingQueue(eng, metrics=metrics, scorer=scorer)
+            q = PagedQueue(eng, metrics=metrics, scorer=scorer)
             await q.start()
             await asyncio.sleep(0.05)  # runner parked on the idle wait
             scorer.submit(["a", "b", "c"])

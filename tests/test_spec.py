@@ -368,14 +368,3 @@ class TestEngineWiring:
         with pytest.raises(ValueError, match="max_position_embeddings"):
             decode_spec(params, state, ids, cfg, sampling, eos_id=0,
                         pad_id=0, model=family, spec_tokens=4)
-
-    def test_engine_rejects_spec_with_fused_attention(self):
-        from distributed_lms_raft_llm_tpu.engine import (
-            EngineConfig,
-            TutoringEngine,
-        )
-
-        with pytest.raises(ValueError, match="spec_tokens"):
-            TutoringEngine(EngineConfig(
-                model="tiny", spec_tokens=4, fused_attention=True,
-            ))

@@ -40,15 +40,16 @@ def test_histogram_is_declared_and_reported_by_the_engine(spec):
     assert name in metric.ENGINE_LOOP_HISTOGRAMS.values()
 
 
-def test_benchmark_json_has_the_entry_well_formed():
+def test_benchmark_json_has_the_entry_well_formed(moves_a_reported_metric):
     """Found by its name, not by its place: later PRs append metrics."""
     per_layer = _load("BENCHMARK.json")["per_layer"]
     (entry,) = [m for m in per_layer if m["name"] == NAME]
     accepted = [m for m in per_layer if m is not entry]
-    assert entry == {
+    moves_a_reported_metric(entry)
+    assert {k: v for k, v in entry.items() if k != "moves"} == {
         "name": NAME, "unit": "iterations", "better": "lower",
         "source": "program_counter",
-        "layer": "paged engine (engine/paged.py)", "moves": "out_tok_s",
+        "layer": "paged engine (engine/paged.py)",
     }
     assert entry["layer"] in {m["layer"] for m in accepted}, \
         "an accepted layer's name, to the letter"

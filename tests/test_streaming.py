@@ -25,13 +25,11 @@ import jax.numpy as jnp
 import pytest
 
 from distributed_lms_raft_llm_tpu.engine import (
-    BatchingQueue,
     EngineConfig,
     PagedEngine,
     PagedQueue,
     SamplingParams,
 )
-from distributed_lms_raft_llm_tpu.engine.batcher import split_stream_tokens
 from distributed_lms_raft_llm_tpu.engine.prefix_cache import PrefixCache
 from distributed_lms_raft_llm_tpu.lms.tutoring_pool import (
     TutoringPool,
@@ -41,7 +39,7 @@ from distributed_lms_raft_llm_tpu.proto import lms_pb2, rpc
 from distributed_lms_raft_llm_tpu.serving.tutoring_server import (
     TutoringService,
 )
-from distributed_lms_raft_llm_tpu.sim.cluster import EchoEngine
+from distributed_lms_raft_llm_tpu.sim.cluster import EchoEngine, echo_tokens
 from distributed_lms_raft_llm_tpu.utils.faults import FaultInjector
 from distributed_lms_raft_llm_tpu.utils.metrics import Metrics
 
@@ -119,8 +117,8 @@ def test_release_and_repin_move_the_pin():
 
 async def _start_tutoring(node_id, delay_s=0.002):
     metrics = Metrics()
-    queue = BatchingQueue(EchoEngine(delay_s), max_batch=4,
-                          max_wait_ms=1.0, metrics=metrics)
+    # Three tokens a step: a one-line answer streams in several chunks.
+    queue = PagedQueue(EchoEngine(delay_s, chunk=3), metrics=metrics)
     await queue.start()
     server = grpc.aio.server()
     service = TutoringService(queue, metrics, node_id=node_id)
@@ -174,12 +172,14 @@ def test_streamed_answer_equals_unary_over_grpc():
             ):
                 chunks.append(ch)
             full, digest = _check_contract(chunks)
+            assert len(chunks) > 1, "the token channel yielded one chunk"
             assert full.strip() == unary.response
             assert digest == hashlib.sha256(
                 full.strip().encode()).hexdigest()
-            # Deterministic regeneration: resuming at offset 2 delivers
-            # exactly the token suffix, same digest (same full answer).
-            toks = split_stream_tokens(full)
+            # Deterministic regeneration: resuming at offset 2 (inside
+            # the first chunk) delivers exactly the token suffix, same
+            # digest (same full answer).
+            toks = echo_tokens(full)
             assert len(toks) > 2, "answer too short to exercise resume"
             resumed = []
             async for ch in stub.StreamLLMAnswer(

@@ -550,7 +550,7 @@ def test_capacity_cli_over_bench_record(tmp_path, capsys):
     record = {
         "metric": "semester_sim_ask_p95_s",
         "timeline": _capacity_export(),
-        "slos": {"stage_p95s": {"engine.batch": {"count": 5,
+        "slos": {"stage_p95s": {"engine.decode": {"count": 5,
                                                  "p95_s": 0.012}}},
     }
     path = tmp_path / "record.json"
@@ -574,7 +574,7 @@ def test_trace_report_stage_diff(tmp_path, capsys):
     a = {"slos": {"stage_p95s": {
         "queue.wait": {"count": 10, "p50_s": 0.01, "p95_s": 0.05,
                        "max_s": 0.06},
-        "engine.batch": {"count": 10, "p50_s": 0.02, "p95_s": 0.04,
+        "engine.decode": {"count": 10, "p50_s": 0.02, "p95_s": 0.04,
                          "max_s": 0.05},
         "gate.check": {"count": 10, "p50_s": 0.001, "p95_s": 0.002,
                        "max_s": 0.01},
@@ -582,7 +582,7 @@ def test_trace_report_stage_diff(tmp_path, capsys):
     b = {
         "queue.wait": {"count": 12, "p50_s": 0.01, "p95_s": 0.40,
                        "max_s": 0.50},
-        "engine.batch": {"count": 12, "p50_s": 0.02, "p95_s": 0.04,
+        "engine.decode": {"count": 12, "p50_s": 0.02, "p95_s": 0.04,
                          "max_s": 0.05},
         "raft.commit": {"count": 12, "p50_s": 0.003, "p95_s": 0.004,
                         "max_s": 0.01},

@@ -124,9 +124,9 @@ def test_ask_span_tree_covers_the_full_path(traced_ask):
         "gate.check",              # relevance gate (KeywordGate in sim)
         "tutoring.forward",        # the HMAC'd LMS -> tutoring hop
         "tutoring.GetLLMAnswer",   # tutoring servicer handler fragment
-        "queue.wait",              # batcher admission -> dispatch
-        "engine.batch",            # the request's device batch
-        "engine.generate",         # the engine program (EchoEngine keeps
+        "queue.wait",              # queue admission -> a slot
+        "engine.decode",           # the slot -> the request's last token
+        "engine.megastep",         # the engine program (EchoEngine keeps
                                    # the real pop_program_times contract)
     ):
         assert required in by_name, (
@@ -149,7 +149,7 @@ def test_ask_span_durations_nest_within_e2e_latency(traced_ask):
     _assert_nesting(root)
     # The stages the waterfall attributes must be real time, not zeros.
     by_name = _spans_by_name(tree)
-    assert by_name["engine.generate"][0]["duration_s"] > 0
+    assert by_name["engine.megastep"][0]["duration_s"] > 0
     assert by_name["tutoring.forward"][0]["duration_s"] > 0
 
 
@@ -225,7 +225,7 @@ def test_trace_report_waterfall_smoke(cluster, traced_ask, capsys):
     assert f"trace {rid}" in out
     for stage in ("client.ask_llm", "lms.GetLLMAnswer", "raft.commit",
                   "gate.check", "tutoring.forward", "queue.wait",
-                  "engine.generate"):
+                  "engine.decode", "engine.megastep"):
         assert stage in out, f"waterfall lost stage {stage}"
 
 

@@ -14,7 +14,7 @@ import time
 import grpc
 import pytest
 
-from distributed_lms_raft_llm_tpu.engine import BatchingQueue
+from distributed_lms_raft_llm_tpu.engine import PagedQueue
 from distributed_lms_raft_llm_tpu.lms.tutoring_pool import (
     TutoringPool,
     TutoringUnavailable,
@@ -183,8 +183,7 @@ def test_empty_and_ejected_pools_raise_typed_unavailable():
 
 async def _start_tutoring(node_id, delay_s=0.002, with_health=False):
     metrics = Metrics()
-    queue = BatchingQueue(EchoEngine(delay_s), max_batch=4,
-                          max_wait_ms=1.0, metrics=metrics)
+    queue = PagedQueue(EchoEngine(delay_s), metrics=metrics)
     await queue.start()
     server = grpc.aio.server()
     service = TutoringService(queue, metrics, node_id=node_id)

@@ -10,8 +10,8 @@ import jax
 
 from distributed_lms_raft_llm_tpu.engine import (
     EngineConfig,
+    PagedEngine,
     SamplingParams,
-    TutoringEngine,
 )
 from distributed_lms_raft_llm_tpu.proto import lms_pb2, rpc
 from distributed_lms_raft_llm_tpu.serving import tutoring_server
@@ -20,14 +20,15 @@ from distributed_lms_raft_llm_tpu.serving import tutoring_server
 @pytest.fixture(scope="module")
 def server_addr():
     """Run the aio server on a private event loop thread."""
-    engine = TutoringEngine(
+    engine = PagedEngine(
         EngineConfig(
             model="tiny",
             sampling=SamplingParams(max_new_tokens=6),
             length_buckets=(32,),
             batch_buckets=(1, 2, 4),
             dtype=jax.numpy.float32,
-        )
+        ),
+        slots=4, chunk=2,
     )
     loop = asyncio.new_event_loop()
     started = threading.Event()
@@ -38,12 +39,11 @@ def server_addr():
 
         async def boot():
             server = grpc.aio.server()
-            from distributed_lms_raft_llm_tpu.engine import BatchingQueue
+            from distributed_lms_raft_llm_tpu.engine import PagedQueue
             from distributed_lms_raft_llm_tpu.utils.metrics import Metrics
 
             metrics = Metrics()
-            queue = BatchingQueue(engine, max_batch=4, max_wait_ms=20,
-                                  metrics=metrics)
+            queue = PagedQueue(engine, metrics=metrics)
             await queue.start()
             rpc.add_TutoringServicer_to_server(
                 tutoring_server.TutoringService(queue, metrics), server
