@@ -93,6 +93,7 @@ import numpy as np
 from ..models import convert, registry
 from ..models import quant as quant_lib
 from ..models.common import KVCache
+from ..ops import sparse as sparse_ops
 from ..parallel import mesh as mesh_lib
 from ..parallel import partition
 from ..utils import tokenizer as tok_lib
@@ -281,7 +282,8 @@ def _live_lanes(s: "SlotState", sampling: SamplingParams) -> jax.Array:
         s.cache.length < s.stage_len + (sampling.max_new_tokens - 1))
 
 
-def _export_block_program(c1: KVCache, off, slot, *, block: int) -> KVBlock:
+def _export_block_program(c1: KVCache, off, slot, *, block: int,
+                          pool_stride: int = 1) -> KVBlock:
     """Slice one block-aligned KV run out of a prefilled cache — a fresh
     immutable copy the radix tree owns. `slot` selects the sequence:
     admission publishes straight out of the live multi-slot state (the
@@ -293,18 +295,21 @@ def _export_block_program(c1: KVCache, off, slot, *, block: int) -> KVBlock:
     off = jnp.asarray(off, jnp.int32)
     slot = jnp.asarray(slot, jnp.int32)
 
-    def cut(plane):
+    def cut(plane, per=1):
         # Each plane the family's `init_cache` declares, by its own shape
         # ([L, S, H, T, ...]); one it does not have (models/mla.py: a
-        # latent cache is one plane) stays None.
+        # latent cache is one plane) stays None. `per`: the positions one
+        # entry of the plane stands for (the pooled plane's `pool_stride`:
+        # a block carries the entries of its own positions).
         if plane is None:
             return None
         l, _, h, _, *rest = plane.shape
         return jax.lax.dynamic_slice(
-            plane, (zero, slot, zero, off) + (zero,) * len(rest),
-            (l, 1, h, block, *rest))
+            plane, (zero, slot, zero, off // per) + (zero,) * len(rest),
+            (l, 1, h, block // per, *rest))
 
-    return KVBlock(k=cut(c1.k), v=cut(c1.v), ks=cut(c1.ks), vs=cut(c1.vs))
+    return KVBlock(k=cut(c1.k), v=cut(c1.v), ks=cut(c1.ks), vs=cut(c1.vs),
+                   pool=cut(c1.pool, pool_stride))
 
 
 def _stage_program(state: SlotState, slot, ids, true_len, cursor0, seq,
@@ -339,7 +344,8 @@ def _stage_program(state: SlotState, slot, ids, true_len, cursor0, seq,
     if cache.ssm is not None:
         cache = cache._replace(
             ssm=cache.ssm.at[:, slot].set(0.0),
-            conv=cache.conv.at[:, slot].set(0.0))
+            conv=(None if cache.conv is None
+                  else cache.conv.at[:, slot].set(0.0)))
         state = state._replace(snap_at=state.snap_at.at[slot].set(
             jnp.asarray(snap_at, jnp.int32)))
     return state._replace(
@@ -384,13 +390,16 @@ def _stage_block_program(state: SlotState, block, slot, off,
     slot = jnp.asarray(slot, jnp.int32)
     off = jnp.asarray(off, jnp.int32)
 
-    def put(plane, new):
+    def put(plane, new, per=1):
+        # `per`: the positions an entry of the plane stands for
+        # (`_export_block_program`; a block's own planes say it).
         if plane is None:
             return None
-        at = (zero, slot, zero, off) + (zero,) * (plane.ndim - 4)
+        at = (zero, slot, zero, off // per) + (zero,) * (plane.ndim - 4)
         # lint: disable-next=tracer-hygiene
         if tokens is not None:
-            keep = jnp.arange(new.shape[3]) < jnp.asarray(tokens, jnp.int32)
+            keep = (jnp.arange(new.shape[3])
+                    < jnp.asarray(tokens, jnp.int32) // per)
             keep = keep.reshape((1, 1, 1, -1) + (1,) * (plane.ndim - 4))
             new = jnp.where(
                 keep, new, jax.lax.dynamic_slice(plane, at, new.shape))
@@ -400,6 +409,8 @@ def _stage_block_program(state: SlotState, block, slot, off,
     return state._replace(cache=c._replace(
         k=put(c.k, block.k), v=put(c.v, block.v),
         ks=put(c.ks, block.ks), vs=put(c.vs, block.vs),
+        pool=(None if c.pool is None else put(
+            c.pool, block.pool, block.k.shape[3] // block.pool.shape[3])),
     ))
 
 
@@ -414,6 +425,8 @@ def _restore_state_program(state: SlotState, snap: StateSnapshot,
     c = state.cache
 
     def put(plane, new):
+        if plane is None:  # a state without a convolution's window
+            return None
         at = (jnp.zeros((), jnp.int32), slot) + (
             jnp.zeros((), jnp.int32),) * (plane.ndim - 2)
         return jax.lax.dynamic_update_slice(plane, new, at)
@@ -429,7 +442,8 @@ def _export_state_program(state: SlotState, slot) -> StateSnapshot:
     slot = jnp.asarray(slot, jnp.int32)
 
     def cut(plane):
-        return jax.lax.dynamic_slice_in_dim(plane, slot, 1, axis=1)
+        return None if plane is None else jax.lax.dynamic_slice_in_dim(
+            plane, slot, 1, axis=1)
 
     return StateSnapshot(ssm=cut(state.snap_ssm), conv=cut(state.snap_conv))
 
@@ -456,7 +470,8 @@ def _fresh_state(family, cfg, slots: int, width: int,
     snap = {}
     if cache.ssm is not None:
         snap = dict(snap_ssm=jnp.zeros_like(cache.ssm),
-                    snap_conv=jnp.zeros_like(cache.conv),
+                    snap_conv=(None if cache.conv is None
+                               else jnp.zeros_like(cache.conv)),
                     snap_at=jnp.zeros((slots,), jnp.int32))
     return SlotState(
         cache=cache,
@@ -473,7 +488,8 @@ def _fresh_state(family, cfg, slots: int, width: int,
     )
 
 
-def _grow_state_program(state: SlotState, new_len: int) -> SlotState:
+def _grow_state_program(state: SlotState, new_len: int, *,
+                        pool_stride: int = 1) -> SlotState:
     """Zero-pad the cache's slot axis up to `new_len` (width-bucket growth:
     the live cache is only as wide as the widest ACTIVE request needs —
     see PagedEngine._grow_if_needed — and pads up when a longer prompt
@@ -488,6 +504,12 @@ def _grow_state_program(state: SlotState, new_len: int) -> SlotState:
     c = state.cache
     cache = c._replace(k=wider(c.k), v=wider(c.v), ks=wider(c.ks),
                        vs=wider(c.vs))
+    # lint: disable-next=tracer-hygiene
+    if c.pool is not None:
+        # The pooled plane's own positions axis: the new width's entries.
+        more = sparse_ops.pool_len(new_len, pool_stride) - c.pool.shape[3]
+        cache = cache._replace(pool=jnp.pad(
+            c.pool, [(0, 0), (0, 0), (0, 0), (0, more), (0, 0)]))
     return state._replace(
         cache=cache,
         transcript=jnp.pad(state.transcript, [(0, 0), (0, grow)]),
@@ -762,6 +784,8 @@ def _prefill_pass(params, s: SlotState, width: int, *, cfg, sampling, model,
         hit = real & (cur + c == s.snap_at[slot])
 
         def keep(plane, new):
+            if plane is None:
+                return None
             for i in range(width):
                 row = jax.lax.dynamic_slice_in_dim(new, slot[i], 1, 1)
                 old = jax.lax.dynamic_slice_in_dim(plane, slot[i], 1, 1)
@@ -1221,9 +1245,14 @@ class PagedEngine:
                 # Nemotron-3-Nano's widths, where a block of its one
                 # attention layer's keys and values is 16 KB), so the tree
                 # holds one for every STATE_BLOCKS_A_SNAPSHOT blocks of
-                # its budget.
+                # its budget, and never more than two a slot (a
+                # context's stride snapshot and its branch point for as
+                # many contexts as there are slots to ask from them: with
+                # MiniCPM-SALA's 4,096 blocks and 12.6 MB a snapshot, 96
+                # and 1.2 GB, not 256 and 3.2).
                 max_snapshots=(
-                    max(1, prefix_cache_blocks // STATE_BLOCKS_A_SNAPSHOT)
+                    max(1, min(prefix_cache_blocks // STATE_BLOCKS_A_SNAPSHOT,
+                               2 * self.slots))
                     if self.family.recurrent_state else 0),
             )
         # In-scan chunked prefill: admissions are STAGED into SlotState
@@ -1275,8 +1304,18 @@ class PagedEngine:
         statics = dict(cfg=self.cfg, sampling=config.sampling, model=self.family)
         # With the shared-prefix cache disabled the block programs warm
         # zero entries, and the inventory guard sees one stable program set.
+        # A family that keeps pooled keys beside its keys
+        # (`KVCache.pool`) says how many positions an entry stands for;
+        # every other family's block programs are the ones they were.
+        pooled = ({"pool_stride": self.cfg.pool_stride}
+                  if hasattr(self.cfg, "pool_stride") else {})
+        if pooled and self.prefix_block_tokens % self.cfg.pool_stride:
+            raise ValueError(
+                f"{config.model!r} pools a key every "
+                f"{self.cfg.pool_stride} positions: a prefix block of "
+                f"{self.prefix_block_tokens} tokens would cut an entry")
         self._export_block = jax.jit(named_partial(
-            _export_block_program, block=self.prefix_block_tokens,
+            _export_block_program, block=self.prefix_block_tokens, **pooled,
         ))
         # The live SlotState is donated on every program that replaces it,
         # so admissions and steps update the multi-slot KV cache in place
@@ -1310,8 +1349,8 @@ class PagedEngine:
         self._export_state = jax.jit(named_partial(_export_state_program))
         # No statics to bind; a fresh partial all the same (see above).
         self._grow = jax.jit(
-            named_partial(_grow_state_program), static_argnums=(1,),
-            donate_argnums=(0,),
+            named_partial(_grow_state_program, **pooled),
+            static_argnums=(1,), donate_argnums=(0,),
         )
         # Bulk-scoring program (engine/scoring.py): the background
         # tenant's full-sequence forward. Zero warmed programs when
@@ -1515,7 +1554,8 @@ class PagedEngine:
         width. Grows with `_grow` and shrinks on idle rebuild."""
         c = self.state.cache
         return sum(
-            int(x.nbytes) for x in (c.k, c.v, c.ks, c.vs, c.ssm, c.conv)
+            int(x.nbytes)
+            for x in (c.k, c.v, c.ks, c.vs, c.ssm, c.conv, c.pool)
             if x is not None
         )
 
@@ -1675,6 +1715,11 @@ class PagedEngine:
                             self.state,
                             self._canon_snapshot(self._export_state(
                                 self.state, zero)), zero)
+        # The last width's state goes before the growth transitions make
+        # another as wide (`reset` below builds the one that serves): two
+        # of MiniCPM-SALA's 33,536-wide states beside its weights are more
+        # than the chip holds.
+        self.state = None
         for i, wa in enumerate(self.widths):
             for wb in self.widths[i + 1:]:
                 throwaway = self._init_state(wa)
@@ -2262,6 +2307,8 @@ class PagedEngine:
                      else put(state.cache.ssm, "cache.ssm")),
                 conv=(None if state.cache.conv is None
                       else put(state.cache.conv, "cache.conv")),
+                pool=(None if state.cache.pool is None
+                      else put(state.cache.pool, "cache.pool")),
             ),
             snap_ssm=(None if state.snap_ssm is None
                       else put(state.snap_ssm, "snap_ssm")),
@@ -2290,6 +2337,7 @@ class PagedEngine:
             v=None if blk.v is None else put(blk.v, "v"),
             ks=None if blk.ks is None else put(blk.ks, "ks"),
             vs=None if blk.vs is None else put(blk.vs, "vs"),
+            pool=None if blk.pool is None else put(blk.pool, "pool"),
         )
 
     def _canon_snapshot(self, snap: StateSnapshot) -> StateSnapshot:
@@ -2299,8 +2347,9 @@ class PagedEngine:
             sh = jax.sharding.NamedSharding(self.mesh, _plane_spec(name))
             return x if x.sharding == sh else jax.device_put(x, sh)
 
-        return StateSnapshot(ssm=put(snap.ssm, "ssm"),
-                             conv=put(snap.conv, "conv"))
+        return StateSnapshot(
+            ssm=put(snap.ssm, "ssm"),
+            conv=None if snap.conv is None else put(snap.conv, "conv"))
 
     def step(self) -> List[Tuple[int, str]]:
         """Stage pending requests, dispatch the next megastep — K chunks

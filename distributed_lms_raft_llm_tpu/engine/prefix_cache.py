@@ -91,6 +91,9 @@ class KVBlock(NamedTuple):
     v: Optional[jax.Array]
     ks: Optional[jax.Array] = None
     vs: Optional[jax.Array] = None
+    # A family that selects what it reads (models/minicpm_sala.py): the
+    # pooled keys of the block's own positions, [L, 1, H, B / stride, Dh].
+    pool: Optional[jax.Array] = None
 
 
 class StateSnapshot(NamedTuple):
@@ -100,11 +103,12 @@ class StateSnapshot(NamedTuple):
     a positions axis. Never donated, never written in place."""
 
     ssm: jax.Array
-    conv: jax.Array
+    conv: Optional[jax.Array]  # None: a state without a convolution
 
     @property
     def nbytes(self) -> int:
-        return int(self.ssm.nbytes) + int(self.conv.nbytes)
+        return int(self.ssm.nbytes) + (
+            0 if self.conv is None else int(self.conv.nbytes))
 
 
 @dataclasses.dataclass

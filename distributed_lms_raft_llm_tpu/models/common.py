@@ -99,6 +99,14 @@ class KVCache(NamedTuple):
             a new sequence resets it (`engine/paged.py` `_stage_program`:
             zeros, or a snapshot restored). `k`/`v` of such a family hold
             its attention layers alone, [La, B, Hkv, T, Dh].
+    pool:   a family that selects what it reads (models/minicpm_sala.py)
+            keeps its selector's keys beside the keys they summarise, None
+            for every other: [La, B, Hkv, NP, Dh], one POOLED key for every
+            `cfg.pool_stride` positions of a row, NP the row's groups
+            rounded up to whole lane tiles (`ops/sparse.py`). A plane WITH a
+            positions axis of its own, that many times shorter than `k`'s:
+            it is grown, spliced, exported and evicted with the keys, a
+            prefix block carrying the entries of its own positions.
 
     A single scalar length serves the whole batch; per-sequence raggedness is
     handled above the model by the engine's bucketing/batching (engine.paged
@@ -112,6 +120,7 @@ class KVCache(NamedTuple):
     vs: Optional[jax.Array] = None
     ssm: Optional[jax.Array] = None
     conv: Optional[jax.Array] = None
+    pool: Optional[jax.Array] = None
 
     @property
     def quantized(self) -> bool:

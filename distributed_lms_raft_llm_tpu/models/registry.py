@@ -31,7 +31,11 @@ NVIDIA's nemotron_h (Nemotron-3-Nano: Mamba-2 blocks whose recurrent state
 lives in the cache beside one attention block's keys and values, relu^2
 experts) and Moonshot's kimi_linear (Kimi-Linear: Kimi Delta Attention
 layers whose matrix state lives in the cache beside the latent plane of its
-MLA layers: a latent cache AND a recurrent state).
+MLA layers: a latent cache AND a recurrent state) and OpenBMB's
+minicpm_sala (MiniCPM-SALA: block-sparse attention layers that choose the
+blocks they read through a plane of pooled keys kept beside the keys, and
+Lightning linear-attention layers whose matrix state lives in the cache:
+keys, a selector's cache AND a recurrent state).
 """
 
 from __future__ import annotations
@@ -45,6 +49,7 @@ from . import (
     gpt2,
     kimi_linear,
     llama,
+    minicpm_sala,
     moe,
     nemotron_h,
 )
@@ -57,11 +62,18 @@ class ModelFamily(NamedTuple):
     init_cache: Callable
     params_from_hf: Callable
     # The forward also takes `live` (which tokens are real) and `aux`, and
-    # then hands out its routing's three counts (models/afmoe.py): the
-    # paged engine tells such a family its idle lanes and reads the counts.
+    # then hands out `aux["counts"]`, what its passes counted on the device:
+    # the paged engine tells such a family its idle lanes and pad positions
+    # and reads the counts back with a dispatch's tokens. The flag was named
+    # for routed experts (models/afmoe.py: an idle lane reaches no expert,
+    # and the counts are its routing's) and has come to mean the SEAM: a
+    # family without experts declares it too where a token that is not live
+    # must move nothing and its counts come off the device
+    # (models/minicpm_sala.py: a Lightning state, the sparse layers' lanes
+    # and keys).
     routed: bool = False
-    # The names of a routed family's counts, in their order
-    # (`afmoe.COUNTERS`): the engine's counters of the same names.
+    # The names of such a family's counts, in their order (`afmoe.COUNTERS`,
+    # `minicpm_sala.COUNTERS`): the engine's counters of the same names.
     counters: Tuple[str, ...] = ()
     # The expert stacks shard their expert axis over `ep`
     # (parallel/partition.py): the engines refuse ep > 1 for the others.
@@ -110,6 +122,11 @@ KIMI_LINEAR_FAMILY = ModelFamily(
     kimi_linear.init_cache, kimi_linear.params_from_hf, routed=True,
     counters=kimi_linear.COUNTERS, latent_cache=True, recurrent_state=True,
 )
+MINICPM_SALA_FAMILY = ModelFamily(
+    "minicpm_sala", minicpm_sala.init_params, minicpm_sala.forward,
+    minicpm_sala.init_cache, minicpm_sala.params_from_hf, routed=True,
+    counters=minicpm_sala.COUNTERS, recurrent_state=True,
+)
 
 # preset -> (family, config factory)
 PRESETS = {
@@ -138,6 +155,11 @@ PRESETS = {
     "kimi-linear-9l-64of256": (
         KIMI_LINEAR_FAMILY, kimi_linear.KimiLinearConfig.kimi_linear_9l_share),
     "kimilinear-tiny": (KIMI_LINEAR_FAMILY, kimi_linear.KimiLinearConfig.tiny),
+    "minicpm-sala": (MINICPM_SALA_FAMILY,
+                     minicpm_sala.MiniCPMSalaConfig.minicpm_sala),
+    "minicpm-sala-8l": (MINICPM_SALA_FAMILY,
+                        minicpm_sala.MiniCPMSalaConfig.minicpm_sala_8l),
+    "sala-tiny": (MINICPM_SALA_FAMILY, minicpm_sala.MiniCPMSalaConfig.tiny),
 }
 
 

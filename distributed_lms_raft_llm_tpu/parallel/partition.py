@@ -157,6 +157,15 @@ KIMI_LINEAR_RULES: List[Tuple[str, PartitionSpec]] = [
     (r".*", P()),
 ]
 
+# minicpm_sala (models/minicpm_sala.py): everything replicated, as the
+# other families with a recurrent state: the Lightning layers' matrix state
+# [Ll, S, H, K, V] would want its heads sharded beside the mixer's
+# projections, which is not built (the engines refuse tp > 1), and the
+# sparse layers have two key heads.
+MINICPM_SALA_RULES: List[Tuple[str, PartitionSpec]] = [
+    (r".*", P()),
+]
+
 # Rule set per model-family name (models/registry.py ModelFamily.name).
 # (The bucketed engine's KV-cache sharding — [L, B, Hkv, T, Dh]: batch
 # over dp, heads over tp — is derived by jit's sharding propagation from
@@ -173,6 +182,7 @@ RULES_FOR = {
     "axk1": AXK1_RULES,
     "nemotron_h": NEMOTRON_H_RULES,
     "kimi_linear": KIMI_LINEAR_RULES,
+    "minicpm_sala": MINICPM_SALA_RULES,
 }
 
 # ---------------------------------------------- paged state plane table
@@ -211,6 +221,9 @@ PAGED_PLANE_SPECS: Dict[str, PartitionSpec] = {
     "cache.ks": P(None, None, "tp"),
     "cache.vs": P(None, None, "tp"),
     "cache.length": P(),
+    # A selecting family's pooled keys (models/minicpm_sala.py: pool
+    # [La, S, Hkv, NP, Dh]): heads on axis 2 as the keys they summarise.
+    "cache.pool": P(None, None, "tp"),
     # A recurrent family's state planes (models/mamba2.py: ssm
     # [Lm, S, H, P, N], conv [Lm, S, K-1, C]; no positions axis) and the
     # per-slot snapshot planes the prefill fills (engine/paged.SlotState).
@@ -229,6 +242,7 @@ PAGED_PLANE_SPECS: Dict[str, PartitionSpec] = {
     "v": P(None, None, "tp"),
     "ks": P(None, None, "tp"),
     "vs": P(None, None, "tp"),
+    "pool": P(None, None, "tp"),
     "length": P(),
     "ssm": P(None, None, "tp"),
     "conv": P(),

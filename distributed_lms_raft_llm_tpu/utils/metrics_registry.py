@@ -650,6 +650,30 @@ MOE_PASSES_COMPACTED = counter(
     "row, and no pick is dropped either way (compacted / bounded is how "
     "often the bound engages: 1 where the router is fair to the share)",
 )
+ENGINE_ATTN_LANE_STEPS = counter(
+    "engine_attn_lane_steps",
+    "decode lane-steps of a block-sparse attention layer (live lanes x "
+    "sparse layers), summed on the device over a dispatch's decode steps "
+    "and read back at its reap; only a family that selects what it reads "
+    "counts (models/minicpm_sala.py)",
+)
+ENGINE_SPARSE_LANE_STEPS = counter(
+    "engine_sparse_lane_steps",
+    "of engine_attn_lane_steps, those whose query stood at or past "
+    "dense_len and read the blocks it chose (the kernels sparse_select "
+    "and sparse_decode), not its row",
+)
+ENGINE_SPARSE_KEYS_ATTENDED = counter(
+    "engine_sparse_keys_attended",
+    "keys the lane-steps of engine_attn_lane_steps attended: the chosen "
+    "blocks' keys at or behind the query on the sparse arm, every key "
+    "of the context on the dense one",
+)
+ENGINE_SPARSE_KEYS_IN_CONTEXT = counter(
+    "engine_sparse_keys_in_context",
+    "keys the contexts of the same lane-steps held (attended / in "
+    "context is the share of a row the sparse layers read)",
+)
 ENGINE_TOKENS_PAST_WINDOW = counter(
     "engine_tokens_past_window",
     "tokens emitted at a position at or past the model's sliding_window, "
@@ -802,6 +826,10 @@ ENGINE_LOOP_COUNTERS: Dict[str, str] = {
     "moe_picks_held": MOE_PICKS_HELD,
     "moe_passes_bounded": MOE_PASSES_BOUNDED,
     "moe_passes_compacted": MOE_PASSES_COMPACTED,
+    "attn_lane_steps": ENGINE_ATTN_LANE_STEPS,
+    "sparse_lane_steps": ENGINE_SPARSE_LANE_STEPS,
+    "sparse_keys_attended": ENGINE_SPARSE_KEYS_ATTENDED,
+    "sparse_keys_in_context": ENGINE_SPARSE_KEYS_IN_CONTEXT,
     "tokens_past_window": ENGINE_TOKENS_PAST_WINDOW,
     "state_snapshots_taken": ENGINE_STATE_SNAPSHOTS_TAKEN,
     "state_snapshots_restored": ENGINE_STATE_SNAPSHOTS_RESTORED,

@@ -584,6 +584,50 @@ def test_kimi_linear_cut_megastep_updates_both_planes_in_place(one_chip,
         assert not re.search(re.escape(stack) + r"\S* copy\(", text)
 
 
+@BOTH_RUNGS
+def test_minicpm_sala_cut_megastep_reads_chosen_blocks_in_place(one_chip,
+                                                                chunks):
+    """`minicpm-sala-8l` at the published widths, from shapes alone, in the
+    serving settings of benchmarks/configs/minicpm-sala.json (48 slots,
+    width 33,536, chunk 8, prefill chunks of 32, answers of 512): 5.64 GB of
+    bfloat16 weights beside 3.3 GB of keys and values, their pooled plane
+    and the float32 Lightning state; the decode step's selection, attention
+    and state update are the kernels `sparse_select`, `sparse_decode` and
+    `lightning_step`, and no copy of a whole key, value, pooled or state
+    plane lies inside the scans."""
+    family, cfg = registry.resolve("minicpm-sala-8l", jnp.bfloat16,
+                                   jnp.bfloat16)
+    params = _with(jax.eval_shape(
+        lambda: family.init_params(jax.random.key(0), cfg)), one_chip)
+    assert sum(x.size for x in jax.tree.leaves(params)) == 2_820_545_280
+    state = jax.eval_shape(partial(paged._fresh_state, family, cfg, 48, 33536))
+    assert state.cache.k.shape == state.cache.v.shape == (
+        2, 48, 2, 33536, 128)
+    assert state.cache.pool.shape == (2, 48, 2, 2176, 128)
+    assert state.cache.ssm.shape == state.snap_ssm.shape == (
+        6, 48, 32, 128, 128)
+    assert state.cache.conv is None and state.snap_conv is None
+    mega = jax.jit(
+        partial(paged._megastep_program, chunk=8, spec_tokens=0,
+                prefill_chunk=32, draft_fn=build_drafts, eos_id=50256,
+                pad_id=50256, cfg=cfg, model=family,
+                sampling=SamplingParams.reference_defaults(
+                    max_new_tokens=512)),
+        donate_argnums=(1,),
+    ).lower(params, _with(state, one_chip), _with(jax.eval_shape(
+        lambda: jax.random.split(jax.random.key(0), chunks)),
+        one_chip)).compile()
+    ma = mega.memory_analysis()
+    assert _device_bytes(ma) < 0.9 * HBM_BYTES
+    text = mega.as_text()
+    for kernel in ("sparse_select", "sparse_decode", "lightning_step"):
+        assert kernel in text
+    for plane in ("bf16[2,48,2,33536,128]", "bf16[2,48,2,2176,128]",
+                  "f32[6,48,32,128,128]"):
+        assert plane in text
+        assert _copies_inside_loops(text, plane) == []
+
+
 # ------------------- a prefill chunk touches its slot's pages in place
 
 def _copies_inside_loops(text: str, shape: str) -> list:
