@@ -27,8 +27,10 @@ prompt never pauses the decode train:
   (`engine/prefix_cache.py`): on a hit `_stage_block` splices the cached
   blocks straight into the slot's pages and the cursor starts behind
   them; a flipped request's prompt blocks are published back into the
-  tree (`_export_block`), ref-count-pinned by live slots and LRU-evicted
-  under a block budget.
+  tree (`_export_block`; a long edge as stored runs of
+  `STORED_RUN_BLOCKS` blocks, `_export_run`, which a hit splices with one
+  launch each), ref-count-pinned by live slots and LRU-evicted under a
+  block budget.
 - `_megastep`: K chunks of `_step_program` back-to-back on device (a scan
   over the chunk body), so the host pays one dispatch + one async
   readback per K*chunk tokens. The chunk body is a [S,1] last-tokens
@@ -84,7 +86,7 @@ import logging
 import math
 import time
 from functools import partial
-from typing import Dict, List, NamedTuple, Optional, Sequence, Tuple
+from typing import Callable, Dict, List, NamedTuple, Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -107,15 +109,20 @@ from .prefix_cache import (
     KVBlock,
     Match,
     PrefixCache,
+    RunBlock,
     StateSnapshot,
     plan_staged,
+    splice_pieces,
 )
 from .program_inventory import (
     STAGE_RUN_BLOCKS,
+    STORED_RUN_BLOCKS,
     bucket_has_runs,
+    bucket_holds_stored_run,
     effective_megastep_max,
     megastep_ladder,
     stage_runs,
+    width_holds_stored_run,
 )
 from .scoring import _score_program, derive_score_shapes, score_texts
 from .spans import KEYS, PROG, ProgramLog, Span, SpanSum, named_partial
@@ -285,7 +292,9 @@ def _live_lanes(s: "SlotState", sampling: SamplingParams) -> jax.Array:
 def _export_block_program(c1: KVCache, off, slot, *, block: int,
                           pool_stride: int = 1) -> KVBlock:
     """Slice one block-aligned KV run out of a prefilled cache — a fresh
-    immutable copy the radix tree owns. `slot` selects the sequence:
+    immutable copy the radix tree owns: a block of `block` tokens, or
+    (`_export_run`, `block` a stored run's tokens) a whole stored run in
+    one array a plane. `slot` selects the sequence:
     admission publishes straight out of the live multi-slot state (the
     prompt region 0..prompt_len-1 is never rewritten by decode, which
     scatters at >= prompt_len). Publishing copies rather
@@ -372,10 +381,17 @@ def _stage_block_program(state: SlotState, block, slot, off,
                          tokens=None) -> SlotState:
     """Splice one immutable shared KV block, or a tuple of
     STAGE_RUN_BLOCKS consecutive ones as one run of which the first
-    `tokens` tokens count (a short run comes padded with its last block),
+    `tokens` tokens count (a short run comes padded with its last block;
+    the tuple is concatenated here, on the device), or a STORED RUN (a
+    `KVBlock` whose planes already hold `STORED_RUN_BLOCKS` blocks in one
+    array each, with `tokens`: nothing is concatenated, and what lies past
+    the count keeps the slot's own bytes),
     straight into a slot's pages of the LIVE multi-slot cache at token
-    offset `off` (one compiled program per cache width, and one more
-    where a bucket can share a run). Donates the state — a private
+    offset `off` (one compiled program per cache width, one more
+    where a bucket can share a run and one more where a stored run
+    fits). `dynamic_update_slice` CLAMPS a start that would pass the
+    plane's end, so the caller hands over only what fits at `off`.
+    Donates the state — a private
     accumulator between dispatches — and NEVER the block: tree blocks are
     shared structure (engine/prefix_cache.py), and donating one would free
     KV other admissions still splice from."""
@@ -1099,7 +1115,8 @@ class PagedEngine:
                  megastep_max: int = 0, prefix_cache: bool = False,
                  prefix_cache_blocks: int = 512,
                  prefix_block_tokens: int = BLOCK_TOKENS,
-                 prefill_chunk_tokens: int = 32):
+                 prefill_chunk_tokens: int = 32,
+                 stored_run_blocks: int = STORED_RUN_BLOCKS):
         if prefill_chunk_tokens < 1:
             raise ValueError(
                 f"prefill_chunk_tokens is the size of an in-scan prefill "
@@ -1235,11 +1252,20 @@ class PagedEngine:
         # of immutable device-resident block runs; admission splices the
         # longest cached prefix and prefills only the suffix.
         self.prefix_block_tokens = max(1, prefix_block_tokens)
+        # Blocks in a stored run of the tree (program_inventory.py
+        # STORED_RUN_BLOCKS; tests shrink it as they shrink the block). An
+        # engine none of whose buckets is a run long never publishes one,
+        # and its programs are the ones they were.
+        self.stored_run_blocks = stored_run_blocks if any(
+            bucket_holds_stored_run(
+                t, self.prefix_block_tokens, stored_run_blocks)
+            for t in self.buckets) else 0
         self.prefix_cache: Optional[PrefixCache] = None
         if prefix_cache:
             self.prefix_cache = PrefixCache(
                 block_tokens=self.prefix_block_tokens,
                 max_blocks=max(1, prefix_cache_blocks),
+                run_blocks=self.stored_run_blocks,
                 # A recurrent family's state snapshots: one is every
                 # state-space layer's state of one sequence (8.5 MB at
                 # Nemotron-3-Nano's widths, where a block of its one
@@ -1316,6 +1342,13 @@ class PagedEngine:
                 f"{self.prefix_block_tokens} tokens would cut an entry")
         self._export_block = jax.jit(named_partial(
             _export_block_program, block=self.prefix_block_tokens, **pooled,
+        ))
+        # A stored run leaves the slot whole, one launch (zero warmed
+        # programs where no bucket is a run long).
+        self._export_run = jax.jit(named_partial(
+            _export_block_program,
+            block=max(1, self.stored_run_blocks) * self.prefix_block_tokens,
+            **pooled,
         ))
         # The live SlotState is donated on every program that replaces it,
         # so admissions and steps update the multi-slot KV cache in place
@@ -1649,7 +1682,8 @@ class PagedEngine:
         width), the megastep at every (cache width, ladder rung) pair,
         rung 1 included, every width-growth transition, the scoring
         domain, and — with the shared-prefix cache enabled — the block
-        export and the `_stage_block` splice per width. Returns seconds."""
+        export and the `_stage_block` splice per width (a stored run's too,
+        where one fits). Returns seconds."""
         t0 = time.monotonic()
         buckets = self.buckets
         for width in self.widths:
@@ -1707,6 +1741,17 @@ class PagedEngine:
                         self.state = self._stage_block(
                             self.state, (blk,) * STAGE_RUN_BLOCKS,
                             zero, zero, zero)
+                    if width_holds_stored_run(
+                            width, self.prefix_block_tokens,
+                            self.stored_run_blocks):
+                        # A stored run leaves a slot, enters one, and
+                        # gives up a block for a cache too narrow for it
+                        # (`_run_block`: one program whatever the width).
+                        run = self._canon_block(self._export_run(
+                            self.state.cache, zero, zero))
+                        self.state = self._stage_block(
+                            self.state, run, zero, zero, zero)
+                        self._run_block(RunBlock(run, 0))
                     if self.family.recurrent_state:
                         # From a canonical state, as `_publish_staged`
                         # exports and `_stage_admissions` restores.
@@ -1993,18 +2038,8 @@ class PagedEngine:
             with self.mesh:
                 self._grow_if_needed(w_req)
                 if cursor0:
-                    blocks = match.blocks()[: cursor0 // pc.block_tokens]
-                    fit = self.state.cache.k.shape[3] // pc.block_tokens
-                    for i, n in stage_runs(len(blocks), fit):
-                        args = (blocks[i],) if n == 1 else (
-                            tuple(blocks[i:i + n])
-                            + (blocks[i + n - 1],) * (STAGE_RUN_BLOCKS - n),
-                            self._i32(n * pc.block_tokens))
-                        with self._span(PROG + "stage_block"):
-                            self.state = self._stage_block(
-                                self.state, args[0], self._i32(slot),
-                                self._i32(i * pc.block_tokens), *args[1:],
-                            )
+                    self._splice(
+                        match.blocks()[: cursor0 // pc.block_tokens], slot)
                 with self._span(PROG + "stage"):
                     # numpy operands ride the call's own transfer; a
                     # `jnp.asarray` each would be a program of its own.
@@ -2024,6 +2059,53 @@ class PagedEngine:
             self._slot_req[slot] = req
             if handed_on:
                 self._count(slots_handed_on=1)
+
+    def _splice(self, blocks: list, slot: int) -> None:
+        """Write a hit's blocks, the slot's first `len(blocks)`, into the
+        slot's pages: a stored run (or the head of one the hit ends in:
+        the program keeps the slot's bytes past the count) with one launch
+        of its own arrays where the cache holds the run's whole width at
+        its offset, everything else in runs of STAGE_RUN_BLOCKS blocks
+        (`stage_runs`). The program would CLAMP the start of a run that
+        passes the cache's end, so such a run (a short prompt in a narrow
+        cache that matches the head of a long edge) reaches the slot as
+        the same blocks, cut out of it."""
+        blk_t = self.prefix_block_tokens
+        fit = self.state.cache.k.shape[3] // blk_t
+
+        def launch(block, first: int, *tokens) -> None:
+            self._count(stage_block_launches=1)
+            with self._span(PROG + "stage_block"):
+                self.state = self._stage_block(
+                    self.state, block, self._i32(slot),
+                    self._i32(first * blk_t), *tokens)
+
+        for first, n, run in splice_pieces(blocks):
+            if run is not None and first + self.stored_run_blocks <= fit:
+                launch(run, first, self._i32(n * blk_t))
+                self._count(prefix_tokens_from_runs=n * blk_t)
+                continue
+            own = [self._run_block(b) if isinstance(b, RunBlock) else b
+                   for b in blocks[first:first + n]]
+            for i, m in stage_runs(n, fit, first):
+                if m == 1:
+                    launch(own[i], first + i)
+                else:
+                    launch(tuple(own[i:i + m])
+                           + (own[i + m - 1],) * (STAGE_RUN_BLOCKS - m),
+                           first + i, self._i32(m * blk_t))
+
+    def _run_block(self, entry: RunBlock) -> KVBlock:
+        """A stored run's block as a block of its own arrays: the run is
+        handed to the block export as a cache of one slot, one warmed
+        program whatever width is served."""
+        run = entry.run
+        with self._span(PROG + "export_block"):
+            return self._canon_block(self._export_block(
+                KVCache(k=run.k, v=run.v, length=None, ks=run.ks, vs=run.vs,
+                        pool=run.pool),
+                self._i32(entry.index * self.prefix_block_tokens),
+                self._i32(0)))
 
     def _i32(self, n: int) -> jax.Array:
         """The device int32 scalar `n`, made once per value (slot indices
@@ -2079,7 +2161,7 @@ class PagedEngine:
 
     def _note_admission(self, req: _Request, hit: int) -> None:
         """Count one admitted prompt and the shared-prefix hit it had."""
-        self._count(prompt_tokens=req.prompt_len,
+        self._count(admissions=1, prompt_tokens=req.prompt_len,
                     prefill_tokens=req.prompt_len - hit)
         if self.prefix_cache is not None:
             self._prefix_hit_tokens += hit
@@ -2091,17 +2173,22 @@ class PagedEngine:
                        slot: int) -> None:
         """Insert `tokens`' whole blocks into the radix tree: for each
         one it does not hold, an immutable copy exported from `cache` at
-        `slot`. Runs under `self.mesh`, entered ONCE, like every other
-        dispatch (the jit cache keys on the ambient mesh)."""
+        `slot`, a stored run at a launch where the new blocks are a run
+        long (the run lies inside the prompt, so inside the cache: no
+        start is clamped). Runs under `self.mesh`, entered ONCE, like
+        every other dispatch (the jit cache keys on the ambient mesh)."""
         blk_t = self.prefix_cache.block_tokens
 
-        def make_block(i: int) -> KVBlock:
-            with self._span(PROG + "export_block"):
-                return self._canon_block(self._export_block(
-                    cache, self._i32(i * blk_t), self._i32(slot),
-                ))
+        def export(program) -> Callable[[int], KVBlock]:
+            def make(i: int) -> KVBlock:
+                with self._span(PROG + "export_block"):
+                    return self._canon_block(program(
+                        cache, self._i32(i * blk_t), self._i32(slot),
+                    ))
+            return make
 
-        self.prefix_cache.insert(tokens, make_block)
+        self.prefix_cache.insert(
+            tokens, export(self._export_block), export(self._export_run))
 
     def _publish_staged(self, req: _Request, slot: int) -> None:
         """Publish a flipped request's whole prompt blocks into the radix

@@ -15,11 +15,20 @@ contiguous right-padded slot layout).
 Design facts, each load-bearing:
 
 - **Block granularity.** A cache symbol is a block of `block_tokens`
-  consecutive token ids; nodes store exact block-aligned KV runs
-  ([L, 1, H, B, Dh] per block, plus int8 scale planes when kv-quant).
-  Block alignment is what keeps the device programs' shapes static:
-  the engine's `_stage_block`/`_export_block` programs compile once per
-  cache width, never per prefix length.
+  consecutive token ids, and a block is the unit of everything the tree
+  DECIDES: matching, `plan_staged`'s cut, splitting, pins, the budget's
+  count, eviction, where a snapshot stands. What a node HOLDS for a block
+  is either a `KVBlock` of the block's own arrays ([L, 1, H, B, Dh] per
+  plane, plus int8 scale planes when kv-quant) or a `RunBlock`: its place
+  in a STORED RUN, one array a plane for `run_blocks` consecutive blocks
+  ([L, 1, H, R*B, Dh]), which a publish cuts out of the slot with one
+  launch and a hit splices with one launch and one array a plane. A
+  publish stores the new blocks of an edge as runs, counted from the
+  edge's own first new block, for as many whole runs as it adds; what is
+  left over (and every short prompt) rests as blocks. Both are
+  block-aligned, which keeps the device programs' shapes static: the
+  engine's `_stage_block`/`_export_block`/`_export_run` programs compile
+  once per cache width, never per prefix length.
 - **Immutability.** Tree-owned arrays are never donated and never
   written: the splice (`dynamic_update_slice` into the slot's pages of
   the live cache) READS them, the publish slices fresh copies OUT
@@ -64,7 +73,8 @@ from __future__ import annotations
 
 import dataclasses
 import time
-from typing import Callable, Dict, List, NamedTuple, Optional, Sequence, Tuple
+from typing import (
+    Callable, Dict, List, NamedTuple, Optional, Sequence, Tuple, Union)
 
 import jax
 
@@ -96,6 +106,51 @@ class KVBlock(NamedTuple):
     pool: Optional[jax.Array] = None
 
 
+class RunBlock(NamedTuple):
+    """Block `index` of a STORED RUN: `run` is a `KVBlock` whose planes hold
+    `run_blocks` consecutive blocks of one sequence in one array a plane
+    ([L, 1, H, R*B, Dh]; the pooled plane R*B / stride entries), immutable
+    and shared like any block. The R entries of a run all point at the one
+    `run`, so a `_split` inside it leaves both nodes reading the same
+    arrays, and evicting one of them frees nothing the other needs: the
+    arrays go when the last entry that points at them goes. The tree still
+    counts, splits and evicts by entries, a block each."""
+
+    run: KVBlock
+    index: int
+
+
+def splice_pieces(blocks: Sequence) -> List[Tuple[int, int, Optional[KVBlock]]]:
+    """A matched path's blocks (`Match.blocks()`, or a head of it) as the
+    stretches a splice takes them in: (first block, blocks, stored run) for
+    entries 0 .. n-1 of one stored run in their order, which one launch
+    writes from the run's arrays with the count of tokens that are to be
+    kept, and (first block, blocks, None) for a stretch up to the next
+    run's first entry. On a path from the root a run is entered at its
+    first block (a split keeps the head above the tail, and a leaf goes
+    before its parent), so such a stretch is blocks that rest on their
+    own."""
+
+    def opens_run(b) -> bool:
+        return isinstance(b, RunBlock) and b.index == 0
+
+    out: List[Tuple[int, int, Optional[KVBlock]]] = []
+    i = 0
+    while i < len(blocks):
+        b, n = blocks[i], 1
+        if opens_run(b):
+            while (i + n < len(blocks) and isinstance(blocks[i + n], RunBlock)
+                   and blocks[i + n].run is b.run
+                   and blocks[i + n].index == n):
+                n += 1
+        else:
+            while i + n < len(blocks) and not opens_run(blocks[i + n]):
+                n += 1
+        out.append((i, n, b.run if opens_run(b) else None))
+        i += n
+    return out
+
+
 class StateSnapshot(NamedTuple):
     """The recurrent state of ONE sequence after a prefix of whole blocks,
     immutable and device-resident like a `KVBlock`: `ssm` [Lm, 1, H, P, N]
@@ -114,12 +169,13 @@ class StateSnapshot(NamedTuple):
 @dataclasses.dataclass
 class _Node:
     """One radix-tree node: an edge of consecutive blocks plus the KV
-    runs that back them. `edge[i]` is the tuple of token ids block i of
-    this edge covers; `blocks[i]` its KV. Children key on their edge's
+    that backs them. `edge[i]` is the tuple of token ids block i of
+    this edge covers; `blocks[i]` its KV, a `KVBlock` of its own arrays or
+    a `RunBlock`, its place in a stored run. Children key on their edge's
     first block tuple."""
 
     edge: List[Tuple[int, ...]]
-    blocks: List[KVBlock]
+    blocks: List[Union[KVBlock, RunBlock]]
     parent: Optional["_Node"]
     children: Dict[Tuple[int, ...], "_Node"] = dataclasses.field(
         default_factory=dict
@@ -142,8 +198,8 @@ class Match:
     used: Tuple[int, ...]
     tokens: int
 
-    def blocks(self) -> List[KVBlock]:
-        out: List[KVBlock] = []
+    def blocks(self) -> List[Union[KVBlock, RunBlock]]:
+        out: List[Union[KVBlock, RunBlock]] = []
         for node, n in zip(self.nodes, self.used):
             out.extend(node.blocks[:n])
         return out
@@ -177,11 +233,15 @@ class PrefixCache:
     """
 
     def __init__(self, block_tokens: int = BLOCK_TOKENS,
-                 max_blocks: int = 512, max_snapshots: int = 0):
+                 max_blocks: int = 512, max_snapshots: int = 0,
+                 run_blocks: int = 0):
         if block_tokens < 1 or max_blocks < 1:
             raise ValueError("prefix cache needs block_tokens/max_blocks >= 1")
         self.block_tokens = block_tokens
         self.max_blocks = max_blocks
+        # Blocks in a stored run (module docstring, "Block granularity");
+        # 0: every block rests on its own.
+        self.run_blocks = run_blocks
         # State snapshots (module docstring): how many the tree may hold,
         # how many it holds and their bytes.
         self.max_snapshots = max_snapshots
@@ -331,6 +391,8 @@ class PrefixCache:
         pins (refcounts) stay attached to the blocks they protect —
         ancestors are protected by having children."""
         assert node.parent is not None and 0 < j < len(node.edge)
+        # A `j` inside a stored run leaves both nodes its entries: the one
+        # array a plane is shared, nothing is cut or copied.
         top = _Node(edge=node.edge[:j], blocks=node.blocks[:j],
                     parent=node.parent, last_used=node.last_used,
                     snapshots={n: e for n, e in node.snapshots.items()
@@ -348,11 +410,16 @@ class PrefixCache:
         self,
         tokens: Sequence[int],
         make_block: Callable[[int], KVBlock],
+        make_run: Optional[Callable[[int], KVBlock]] = None,
     ) -> int:
         """Publish `tokens`' uncached full blocks into the tree.
         `make_block(i)` materializes block i's KV (the engine slices it
         out of the completed prefill's cache — called only for blocks
-        the tree does not already hold). Returns blocks added. Does NOT
+        the tree does not already hold); `make_run(i)`, where the caller
+        has one, the `run_blocks` blocks from block i as ONE stored run,
+        and then the new blocks rest as whole runs counted from the first
+        new block, and what is left over as blocks. Returns blocks added
+        (a stored run counts `run_blocks`). Does NOT
         evict; the engine calls `evict_to_budget` after (so a publish
         can never evict blocks its own admission still references)."""
         keys = self._block_keys(tokens)
@@ -364,7 +431,13 @@ class PrefixCache:
             # Divergence inside an edge: split so the shared head is a
             # real node the new tail can branch from.
             cur = self._split(nodes[-1], used[-1])
-        fresh = [make_block(i) for i in range(matched, len(keys))]
+        fresh: List[Union[KVBlock, RunBlock]] = []
+        at, r = matched, self.run_blocks
+        while make_run is not None and 0 < r <= len(keys) - at:
+            run = make_run(at)
+            fresh.extend(RunBlock(run, j) for j in range(r))
+            at += r
+        fresh.extend(make_block(i) for i in range(at, len(keys)))
         self._clock += 1
         node = _Node(edge=list(keys[matched:]), blocks=fresh, parent=cur,
                      last_used=self._clock)

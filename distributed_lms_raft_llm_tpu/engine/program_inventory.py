@@ -72,6 +72,11 @@ INVENTORY: Tuple[ProgramEntry, ...] = (
         domain="export-widths", coverage="warmup",
     ),
     ProgramEntry(
+        engine="PagedEngine", attr="_export_run", target="_export_block_program",
+        donate_argnums=(), static_argnums=(),
+        domain="run-export-widths", coverage="warmup",
+    ),
+    ProgramEntry(
         engine="PagedEngine", attr="_export_state", target="_export_state_program",
         donate_argnums=(), static_argnums=(),
         domain="state-widths", coverage="warmup",
@@ -149,8 +154,10 @@ def megastep_ladder(megastep_max: int) -> List[int]:
     return out
 
 
-# Fused admission splices the shared prefix of a prompt in runs of up to this
-# many blocks, one `_stage_block` dispatch a run. The runtime holds few
+# Fused admission splices what the tree holds as blocks of their own (a short
+# prompt's 6 to 15, an edge's remainder, the tail after the last stored run:
+# STORED_RUN_BLOCKS below) in runs of up to this many blocks, concatenated on
+# the device, one `_stage_block` dispatch a run. The runtime holds few
 # programs ahead of the device, so every small program of an admission is
 # launched while the device waits for it: at a dispatch a block a context
 # of 2,304 tokens (144 blocks) held the device for as long as its splices
@@ -160,23 +167,43 @@ def megastep_ladder(megastep_max: int) -> List[int]:
 # tokens count), so a width compiles one program for all of them.
 STAGE_RUN_BLOCKS = 16
 
+# A long edge of the prefix tree rests as STORED RUNS of this many blocks, one
+# array a plane (engine/prefix_cache.py `RunBlock`): a publish cuts a run out
+# of the slot with one `_export_run` launch and a hit splices it with one
+# `_stage_block` launch, nothing concatenated. A launch costs the loop's
+# thread about 0.4 ms on the chip's machine whatever it hands over (a stored
+# run's 5 arrays, or 16 blocks' 80 at 0.5), so at a launch for every 16
+# blocks a 32,768-token reader's 2,051 blocks held the thread 68 ms an
+# admission and the device waited for it a sixth of the window: what the
+# launch a block was at 144 blocks. By run length, the same hit
+# (`scripts/probe_splice.py`, PERF.md section 6, PR 55): 64 blocks 18 ms,
+# 128 12.5, 256 8.3; 128 is kept because a notes context of 144 blocks is
+# then a run too, and what is left over after the last whole run (up to a
+# run less a block, 16 blocks a launch) grows with the run. Who gets stored
+# runs follows from the publish (how many new consecutive blocks it adds) and
+# the cache width being served (`width_holds_stored_run`), never from a
+# model's name.
+STORED_RUN_BLOCKS = 128
 
-def stage_runs(n_blocks: int, fit_blocks: int) -> list:
-    """How fused admission splices `n_blocks` consecutive blocks into a
-    cache `fit_blocks` blocks wide: (first block, blocks in the call) per
+
+def stage_runs(n_blocks: int, fit_blocks: int, start: int = 0) -> list:
+    """How fused admission splices `n_blocks` consecutive blocks that rest
+    on their own, the first at block `start` of a cache `fit_blocks` blocks
+    wide: (first block of the stretch, blocks in the call) per
     `_stage_block` dispatch, runs of up to STAGE_RUN_BLOCKS. A run's
     program writes a whole run's width, so a last run too near the cache's
     end starts earlier and splices again some blocks the run before it
-    brought; a lone block goes alone, and so does every block of a cache
-    narrower than a run."""
+    brought; a lone block goes alone, and so does every block where the
+    cache has no room for a run from `start`."""
     out, i = [], 0
+    room = fit_blocks - start
     while i < n_blocks:
         n = min(STAGE_RUN_BLOCKS, n_blocks - i)
-        if n == 1 or fit_blocks < STAGE_RUN_BLOCKS:
+        if n == 1 or room < STAGE_RUN_BLOCKS:
             out.append((i, 1))
             i += 1
             continue
-        first = min(i, fit_blocks - STAGE_RUN_BLOCKS)
+        first = min(i, room - STAGE_RUN_BLOCKS)
         out.append((first, i + n - first))
         i += n
     return out
@@ -190,6 +217,23 @@ def bucket_has_runs(bucket: int, block_tokens: int, width: int) -> bool:
     return (bucket - 1) // blk >= 2 and STAGE_RUN_BLOCKS * blk <= width
 
 
+def bucket_holds_stored_run(bucket: int, block_tokens: int,
+                            run_blocks: int) -> bool:
+    """Whether a prompt of this bucket can publish a stored run: its whole
+    blocks are a run or more."""
+    return run_blocks > 0 and bucket // max(1, block_tokens) >= run_blocks
+
+
+def width_holds_stored_run(width: int, block_tokens: int,
+                           run_blocks: int) -> bool:
+    """Whether a cache this wide can be handed a stored run at all (at
+    offset 0): `dynamic_update_slice` needs the run's whole width inside
+    the plane. At a given offset the engine asks again
+    (`PagedEngine._splice`: a start that would pass the end is CLAMPED by
+    the program, so such a run reaches the slot as blocks)."""
+    return run_blocks > 0 and run_blocks * max(1, block_tokens) <= width
+
+
 def static_paged_domain(
     max_position_embeddings: int,
     max_new_tokens: int,
@@ -201,6 +245,7 @@ def static_paged_domain(
     tp: int = 1,
     ep: int = 1,
     recurrent_state: bool = False,
+    stored_run_blocks: int = STORED_RUN_BLOCKS,
 ) -> Dict[str, object]:
     """The paged engine's static-argument domain, derived from config the
     same way `PagedEngine.__init__` derives it: warmed prompt buckets,
@@ -225,7 +270,11 @@ def static_paged_domain(
     width) pair, `_stage_block` splices shared-prefix blocks per width,
     block export reads the live cache (per width, where any bucket can
     hold a block), and every rung of the ladder, rung 1 included, is one
-    `_megastep` program per width.
+    `_megastep` program per width. Where a bucket's prompts are a stored
+    run long (`stored_run_blocks`), every width a run fits exports runs
+    (`_export_run`) and splices them (one more `_stage_block` program),
+    and one more `_export_block` program cuts a block out of a run for a
+    cache too narrow for it.
     """
     extra = max(0, spec_tokens - 1)
     bucket_cap = min(
@@ -243,6 +292,14 @@ def static_paged_domain(
     # hold a block.
     blk = max(1, prefix_block_tokens)
     blocks = prefix_cache and any(t >= blk for t in buckets)
+    # Widths a stored run fits, where some bucket's prompts are a run long
+    # (else the tree never holds one): each exports runs and splices them.
+    run_widths = sum(
+        1 for w in widths
+        if width_holds_stored_run(w, blk, stored_run_blocks)
+    ) if blocks and any(
+        bucket_holds_stored_run(t, blk, stored_run_blocks) for t in buckets
+    ) else 0
     return {
         "mesh": {"tp": max(1, tp), "ep": max(1, ep)},
         "buckets": buckets,
@@ -252,20 +309,25 @@ def static_paged_domain(
         ),
         # One splice program per width, and one more (a run of blocks in
         # one call) per width that serves a bucket whose prompts can share
-        # two blocks or more and is wide enough for a run.
+        # two blocks or more and is wide enough for a run; where the
+        # tree can hold stored runs, one more per width one fits.
         "stage_block_widths": (
             len(widths) + sum(
                 1 for w in widths
                 if any(bucket_has_runs(t, blk, w) and nat[t] <= w
                        for t in buckets)
-            )
+            ) + run_widths
             if blocks else 0
         ),
         "grow_pairs": len(widths) * (len(widths) - 1) // 2,
         # The K is encoded in the stacked rng shape, not a static argnum.
         "k_ladder": ladder,
         "megastep_pairs": len(widths) * len(ladder),
-        "export_widths": len(widths) if blocks else 0,
+        # Besides the widths: a block cut out of a stored run (the run
+        # handed over as a one-slot cache, whatever the width served).
+        "export_widths": (
+            len(widths) + (1 if run_widths else 0) if blocks else 0),
+        "run_export_widths": run_widths,
         # A recurrent family's snapshot restore and export
         # (`ModelFamily.recurrent_state`): one program a width each,
         # beside the block programs.
@@ -305,6 +367,7 @@ _DOMAIN_SIZES = {
     "width-pairs": lambda d: d["grow_pairs"],
     "width-k-pairs": lambda d: d["megastep_pairs"],
     "export-widths": lambda d: d["export_widths"],
+    "run-export-widths": lambda d: d["run_export_widths"],
     "state-widths": lambda d: d["state_widths"],
 }
 
@@ -340,6 +403,8 @@ def expected_counts(engine) -> Dict[str, int]:
             prefix_block_tokens=getattr(engine, "prefix_block_tokens", 16),
             tp=engine.config.tp, ep=engine.config.ep,
             recurrent_state=engine.family.recurrent_state,
+            stored_run_blocks=getattr(
+                engine, "stored_run_blocks", STORED_RUN_BLOCKS),
         )
         live_mesh = {
             "tp": int(engine.mesh.shape.get("tp", 1)),

@@ -474,14 +474,15 @@ ENGINE_PROG_STAGE_BLOCK = histogram(
     "engine_prog_stage_block",
     "paged-engine _stage_block program dispatch wall time (admission: "
     "cached shared-prefix blocks spliced into a slot's "
-    "pages, a run of up to 16 or a single block a call; one observation "
-    "per call)",
+    "pages, a stored run of 128, a run of up to 16 or a single block a "
+    "call; one observation per call)",
 )
 ENGINE_PROG_EXPORT_BLOCK = histogram(
     "engine_prog_export_block",
     "paged-engine _export_block program dispatch wall time (one prompt "
-    "or session block copied out of a cache into the radix tree; one "
-    "observation per block)",
+    "or session block, or one stored run of 128, copied out of a cache "
+    "into the radix tree, or a block cut out of a stored run; one "
+    "observation per call)",
 )
 ENGINE_PROG_SCORE = histogram(
     "engine_prog_score",
@@ -699,6 +700,26 @@ ENGINE_PREFIX_TOKENS_RECOMPUTED_FOR_STATE = counter(
     "snapshot); over engine_prompt_tokens_admitted it is the share of "
     "the prompts the snapshots' placement costs",
 )
+ENGINE_ADMISSIONS = counter(
+    "engine_admissions",
+    "prompts the paged engine admitted into a slot (staged: prompt "
+    "written, the prefix hit spliced, the in-scan prefill armed)",
+)
+ENGINE_STAGE_BLOCK_LAUNCHES = counter(
+    "engine_stage_block_launches",
+    "launches of the prefix splice program (`_stage_block`), of every "
+    "kind: a lone block, a run of up to 16 blocks concatenated on the "
+    "device, a stored run handed over in one array a plane; over "
+    "engine_admissions it is what an admission's hit costs the thread "
+    "that launches",
+)
+ENGINE_PREFIX_TOKENS_FROM_RUNS = counter(
+    "engine_prefix_tokens_from_runs",
+    "prefix-hit tokens that were spliced from stored runs of the prefix "
+    "tree (engine/prefix_cache.py `RunBlock`: a long edge's blocks in one "
+    "array a plane, one launch a run); over prefix_cache_hit_tokens it is "
+    "the share of the hits the stored runs served",
+)
 ENGINE_STATE_SNAPSHOT_BYTES = gauge(
     "engine_state_snapshot_bytes",
     "bytes of the state snapshots the prefix tree holds for a recurrent "
@@ -835,6 +856,9 @@ ENGINE_LOOP_COUNTERS: Dict[str, str] = {
     "state_snapshots_restored": ENGINE_STATE_SNAPSHOTS_RESTORED,
     "prefix_tokens_recomputed_for_state":
         ENGINE_PREFIX_TOKENS_RECOMPUTED_FOR_STATE,
+    "admissions": ENGINE_ADMISSIONS,
+    "stage_block_launches": ENGINE_STAGE_BLOCK_LAUNCHES,
+    "prefix_tokens_from_runs": ENGINE_PREFIX_TOKENS_FROM_RUNS,
     # One turn's budget (engine/spans.py `turn_budget`), counted by
     # PagedQueue from the spans the engine drains with the rest.
     "loop_wall_us": ENGINE_LOOP_WALL_US,

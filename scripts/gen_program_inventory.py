@@ -62,6 +62,10 @@ CLASSIFICATION = {
     # both modes (see static_paged_domain).
     ("PagedEngine", "_stage_block"): ("stage-widths", "warmup"),
     ("PagedEngine", "_export_block"): ("export-widths", "warmup"),
+    # A stored run of the prefix tree (engine/program_inventory.py
+    # STORED_RUN_BLOCKS): cut out of the slot at every width a run fits
+    # where some bucket's prompts are a run long, none elsewhere.
+    ("PagedEngine", "_export_run"): ("run-export-widths", "warmup"),
     # A recurrent family's state snapshots (engine/paged.py
     # `_restore_state_program`, `_export_state_program`): one program a
     # width, and none for a family without a recurrent state or with the
@@ -160,6 +164,7 @@ def shipped_domains() -> List[Dict[str, object]]:
         total = (
             dom["stage_pairs"] + dom["megastep_pairs"] + dom["grow_pairs"]
             + dom["stage_block_widths"] + dom["export_widths"]
+            + dom["run_export_widths"]
             + dom_score_pairs(sdom)
         )
         rows.append(dict(

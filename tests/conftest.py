@@ -114,3 +114,32 @@ def eos_first_engine(monkeypatch):
         return PagedEngine(config, **kw)
 
     return make
+
+
+@pytest.fixture
+def stage_last_prompt():
+    """`stage(engine, prompts)`: serve all but the last prompt one after
+    another, stage the last (its prefix hit spliced, nothing dispatched
+    yet) and read every plane of the cache that has a positions axis as
+    the splice left it, then serve it: (planes, that admission's counters,
+    every answer). Two engines that differ only in how the tree holds its
+    blocks must agree on all three but the launches."""
+    import numpy as np
+
+    def stage(engine, prompts):
+        answers = []
+        for prompt in prompts[:-1]:
+            rid = engine.submit(prompt)
+            answers.append(engine.drain()[rid])
+        engine.pop_loop_stats()
+        rid = engine.submit(prompts[-1])
+        engine._stage_admissions()
+        counts = dict(engine.pop_loop_stats()[0])
+        cache = engine.state.cache
+        planes = {name: np.asarray(getattr(cache, name))
+                  for name in ("k", "v", "ks", "vs", "pool")
+                  if getattr(cache, name) is not None}
+        answers.append(engine.drain()[rid])
+        return planes, counts, answers
+
+    return stage

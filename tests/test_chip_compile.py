@@ -628,6 +628,56 @@ def test_minicpm_sala_cut_megastep_reads_chosen_blocks_in_place(one_chip,
         assert _copies_inside_loops(text, plane) == []
 
 
+@pytest.mark.parametrize(
+    "program", ["export_run", "stage_stored_run", "block_of_a_run"])
+def test_stored_run_programs_compile_and_write_in_place_on_one_v5e(one_chip,
+                                                                  program):
+    """What a 32,768-token reader's admission and publish launch since the
+    tree holds a long edge as stored runs, at `minicpm-sala.reader-herd`'s
+    cache (48 slots x 33,536, keys, values and the pooled plane): a run of
+    STORED_RUN_BLOCKS blocks cut out of a slot, the run written into a slot
+    (the donated state aliases into the output, and nothing the size of a
+    plane is copied or held beside it), and a block cut out of a run for a
+    cache too narrow for it."""
+    from distributed_lms_raft_llm_tpu.engine.prefix_cache import BLOCK_TOKENS
+    from distributed_lms_raft_llm_tpu.engine.program_inventory import (
+        STORED_RUN_BLOCKS, width_holds_stored_run)
+    from distributed_lms_raft_llm_tpu.models.common import KVCache
+
+    family, cfg = registry.resolve("minicpm-sala-8l", jnp.bfloat16,
+                                   jnp.bfloat16)
+    assert width_holds_stored_run(33536, BLOCK_TOKENS, STORED_RUN_BLOCKS)
+    assert not width_holds_stored_run(768, BLOCK_TOKENS, STORED_RUN_BLOCKS)
+    state = _with(jax.eval_shape(
+        partial(paged._fresh_state, family, cfg, 48, 33536)), one_chip)
+    tokens = STORED_RUN_BLOCKS * BLOCK_TOKENS
+    export_run = partial(paged._export_block_program, block=tokens,
+                         pool_stride=cfg.pool_stride)
+    run = _with(jax.eval_shape(export_run, state.cache, 0, 0), one_chip)
+    assert run.k.shape == run.v.shape == (2, 1, 2, tokens, 128)
+    assert run.pool.shape == (2, 1, 2, tokens // cfg.pool_stride, 128)
+    i32 = jax.ShapeDtypeStruct((), jnp.int32, sharding=one_chip)
+    fn, donate, args = {
+        "export_run": (export_run, (), (state.cache, i32, i32)),
+        "stage_stored_run": (paged._stage_block_program, (0,),
+                             (state, run, i32, i32, i32)),
+        "block_of_a_run": (
+            partial(paged._export_block_program, block=BLOCK_TOKENS,
+                    pool_stride=cfg.pool_stride), (),
+            (KVCache(k=run.k, v=run.v, length=None, pool=run.pool),
+             i32, i32)),
+    }[program]
+    compiled = jax.jit(fn, donate_argnums=donate).lower(*args).compile()
+    ma = compiled.memory_analysis()
+    assert _device_bytes(ma) < 0.9 * HBM_BYTES
+    # A key plane is 3.3 GB: temporaries stay the size of a few runs.
+    assert ma.temp_size_in_bytes < 64 * 2 ** 20
+    if program == "stage_stored_run":
+        # Keys and values 3.3 GB, the Lightning state and its snapshot
+        # rows 1.2 GB: all of the state comes back in its own buffers.
+        assert ma.alias_size_in_bytes > 4.5e9
+
+
 # ------------------- a prefill chunk touches its slot's pages in place
 
 def _copies_inside_loops(text: str, shape: str) -> list:

@@ -369,6 +369,104 @@ def test_staged_slots_are_served_every_iteration_in_stage_order(
 # --------------------------------------------- warmup / inventory coverage
 
 
+# ------------------------------------------------- stored runs, every family
+
+# Forty tokens (ten blocks of 4) of notes and questions of eight: a prompt
+# fills twelve blocks, four stored runs of three. The second prompt's
+# question splits the last run (and is where a recurrent family's snapshot
+# comes to stand: `_snapshot_point`), so the third splices three whole runs
+# and the head of a fourth, across the split's two nodes.
+NOTES = "a quorum of nodes agrees on each entry. "
+RUN_PROMPTS = [NOTES + "why so? ", NOTES + "and how?", NOTES + "say more"]
+RUN_FAMILIES = {
+    "plain": dict(model="tiny", length_buckets=(16, 48)),
+    "int8_kv": dict(model="tiny", kv_quant=True, length_buckets=(16, 48)),
+    "latent_cache": dict(model="axk1-tiny", param_dtype=jnp.float32,
+                         length_buckets=(16, 48)),
+    "recurrent": dict(model="sala-tiny", seed=4, length_buckets=(32, 56)),
+}
+
+
+def run_engine(family, run_blocks, prefix_cache=True):
+    return PagedEngine(
+        EngineConfig(sampling=SamplingParams.greedy(max_new_tokens=MAX_NEW),
+                     batch_buckets=(1, 2, 4), dtype=jnp.float32,
+                     **RUN_FAMILIES[family]),
+        slots=2, chunk=2, megastep=2, megastep_max=4, prefill_chunk_tokens=8,
+        prefix_cache=prefix_cache, prefix_cache_blocks=64,
+        prefix_block_tokens=4, stored_run_blocks=run_blocks)
+
+
+@pytest.mark.parametrize("family", sorted(RUN_FAMILIES))
+def test_a_hit_over_stored_runs_is_the_cold_answer_and_the_blocks_bytes(
+        family, stage_last_prompt):
+    """Whole runs plus the head of one: token for token what an engine
+    without a tree generates, and the slot's planes (whichever the family
+    has: values, int8 scales, pooled keys) bit-equal to what the splice of
+    blocks resting on their own leaves."""
+    assert len(NOTES) == 40
+    _, _, cold = stage_last_prompt(
+        run_engine(family, 0, prefix_cache=False), RUN_PROMPTS)
+    planes, counts, answers = stage_last_prompt(
+        run_engine(family, 3), RUN_PROMPTS)
+    want, blocks, same = stage_last_prompt(run_engine(family, 0), RUN_PROMPTS)
+    assert answers == same == cold
+    assert counts["prefix_tokens_from_runs"] == 40
+    assert "prefix_tokens_from_runs" not in blocks
+    assert counts["stage_block_launches"] == 4
+    # Ten blocks on their own: one by one, but for the one cache here that
+    # is STAGE_RUN_BLOCKS blocks wide (64 tokens), which takes them as a run.
+    assert blocks["stage_block_launches"] == (1 if family == "recurrent"
+                                              else 10)
+    assert planes.keys() == want.keys()
+    for name in planes:
+        assert np.array_equal(planes[name], want[name]), name
+
+
+def test_a_reader_of_2051_blocks_is_spliced_in_a_few_launches(monkeypatch):
+    """The launches of a 2,051-block hit by `engine_stage_block_launches`:
+    sixteen stored runs of STORED_RUN_BLOCKS and what is left over in runs
+    of STAGE_RUN_BLOCKS, where the tree before PR 55 took 129."""
+    from functools import partial
+
+    from distributed_lms_raft_llm_tpu.engine import paged
+    from distributed_lms_raft_llm_tpu.engine.program_inventory import (
+        STAGE_RUN_BLOCKS, STORED_RUN_BLOCKS)
+    from distributed_lms_raft_llm_tpu.models import gpt2, registry
+
+    monkeypatch.setitem(registry.PRESETS, "tiny-reader", (
+        registry.PRESETS["tiny"][0],
+        partial(gpt2.GPT2Config.tiny, max_position_embeddings=2200)))
+    blocks, r = 2051, STORED_RUN_BLOCKS
+    bound = -(-blocks // r) + r // STAGE_RUN_BLOCKS + 2
+    launches = {}
+    for run_blocks in (r, 0):
+        eng = PagedEngine(
+            EngineConfig(model="tiny-reader", batch_buckets=(1,),
+                         dtype=jnp.float32, length_buckets=(2100,),
+                         sampling=SamplingParams.greedy(
+                             max_new_tokens=MAX_NEW)),
+            slots=2, chunk=2, prefix_cache=True, prefix_cache_blocks=4096,
+            prefix_block_tokens=1, stored_run_blocks=run_blocks)
+        reader = np.random.default_rng(5).integers(
+            0, eng.cfg.vocab_size, blocks).tolist()
+        with eng.mesh:
+            eng._insert_blocks(reader, eng._canon_state(eng.state).cache, 0)
+        assert eng.prefix_cache.blocks_used == blocks
+        eng._pending.append(paged._Request(
+            rid=0, prompt_len=blocks + 9, tokens=reader + list(range(9)),
+            max_new=MAX_NEW))
+        eng._stage_admissions()
+        counts = eng.pop_loop_stats()[0]
+        assert counts["admissions"] == 1
+        assert eng._prefix_hit_tokens == blocks
+        launches[run_blocks] = counts["stage_block_launches"]
+        assert counts.get("prefix_tokens_from_runs", 0) == (
+            blocks // r * r if run_blocks else 0)
+    assert launches[r] == blocks // r + 1 <= bound
+    assert launches[0] == -(-blocks // STAGE_RUN_BLOCKS) > bound
+
+
 def test_warmed_fused_session_passes_inventory_guard():
     """compile_count_guard(expected_from_inventory(...)): warmup compiles
     the domain — stage pairs, megasteps at EVERY rung including 1
@@ -386,7 +484,7 @@ def test_warmed_fused_session_passes_inventory_guard():
     assert expectation.expected["_stage"] == 3  # (4,12) (4,24) (16,24)
     assert set(expectation.expected) == {
         "_megastep", "_stage", "_stage_block", "_export_block", "_grow",
-        "_score", "_restore_state", "_export_state"}
+        "_score", "_restore_state", "_export_state", "_export_run"}
     assert expectation.mismatches() == {}
     with compile_count_guard(expectation) as guard:
         eng.submit("k v")
@@ -424,6 +522,43 @@ def test_warmed_fused_prefix_session_passes_inventory_guard():
     assert hit > 0
 
 
+def test_warmed_session_over_stored_runs_passes_inventory_guard():
+    """A run of 6 blocks fits the 56-wide cache and not the 16-wide one:
+    after warm-up the inventory's counts are the programs' cache sizes, and
+    a session that publishes a long edge as runs, splits one, splices them
+    whole and in part, and hands a narrow cache a block cut out of a run
+    compiles nothing."""
+    eng = PagedEngine(
+        make_config(length_buckets=(8, 48)), slots=2, chunk=2,
+        megastep=2, megastep_max=4, prefill_chunk_tokens=4,
+        prefix_cache=True, prefix_cache_blocks=64, prefix_block_tokens=4,
+        stored_run_blocks=6,
+    )
+    eng.warmup()
+    assert list(eng.widths) == [16, 56]
+    expectation = expected_from_inventory(eng)
+    assert expectation.expected["_stage_block"] == 2 + 1
+    assert expectation.expected["_export_block"] == 2 + 1
+    assert expectation.expected["_export_run"] == 1
+    assert expectation.mismatches() == {}
+    with compile_count_guard(expectation) as guard:
+        for q in (NOTES + "why so? ", NOTES + "and how?", NOTES[:7],
+                  NOTES + "say more"):
+            eng.submit(q)
+            eng.drain()
+        # Both at once: the short prompt joins the wide cache and is handed
+        # the head of the run itself.
+        eng.submit(NOTES + "and then")
+        eng.submit(NOTES[:7])
+        eng.drain()
+    assert guard.new_compiles() == 0
+    counts = eng.pop_loop_stats()[0]
+    assert counts["admissions"] == 6
+    assert counts["prefix_tokens_from_runs"] == 40 + 40 + 40 + 4
+    # "and then" shares a block of its question with "and how?".
+    assert eng.pop_prefix_stats()[0] == 40 + 4 + 40 + 44 + 4
+
+
 @pytest.mark.parametrize("spec_tokens", [0, 2], ids=["plain", "spec"])
 @pytest.mark.parametrize("prefix_cache", [False, True],
                          ids=["no_tree", "tree"])
@@ -453,6 +588,8 @@ def test_warmup_compiles_exactly_the_staged_domain(prefix_cache, spec_tokens):
         # Snapshot programs of a family with a recurrent state alone.
         "_restore_state": 0,
         "_export_state": 0,
+        # A stored run's export: no bucket here is a run long.
+        "_export_run": 0,
     }
     assert {a: getattr(eng, a)._cache_size() for a in want} == want
     expectation = expected_from_inventory(eng)

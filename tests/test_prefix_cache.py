@@ -27,7 +27,11 @@ from distributed_lms_raft_llm_tpu.engine import (
     SamplingParams,
     TutoringEngine,
 )
-from distributed_lms_raft_llm_tpu.engine.prefix_cache import PrefixCache
+from distributed_lms_raft_llm_tpu.engine.prefix_cache import (
+    PrefixCache,
+    RunBlock,
+    splice_pieces,
+)
 from distributed_lms_raft_llm_tpu.sim import workload as wl
 from distributed_lms_raft_llm_tpu.sim.slo import evaluate_slos
 from distributed_lms_raft_llm_tpu.utils.guards import (
@@ -172,6 +176,180 @@ def test_tree_split_keeps_pin_on_deep_node():
     # and interior nodes have children: only branch b may go.
     pc.evict_to_budget()
     assert pc.lookup(ints(8) + [9]).tokens == 8
+
+
+# ------------------------------------------- stored runs: what a node holds
+
+
+def run_tree(run_blocks=4, **kw):
+    """A tree of 2-token blocks whose runs and blocks say what they are:
+    a run is ("run", name, first block), a block ("blk", name, block)."""
+    pc = PrefixCache(block_tokens=2, run_blocks=run_blocks, **kw)
+
+    def publish(tokens, name):
+        return pc.insert(tokens, lambda i: ("blk", name, i),
+                         lambda i: ("run", name, i))
+
+    return pc, publish
+
+
+def held(pc):
+    """Blocks the tree's nodes hold, counted node by node."""
+    return sum(len(n.blocks) for n in pc._iter_nodes())
+
+
+def test_a_long_edge_rests_as_runs_and_its_remainder_as_blocks():
+    pc, publish = run_tree()
+    assert publish(ints(22), "a") == 11 and pc.blocks_used == 11
+    blocks = pc.lookup(ints(22) + [999]).blocks()
+    assert [b.index for b in blocks[:8]] == [0, 1, 2, 3] * 2
+    assert all(b.run == ("run", "a", 0) for b in blocks[:4])
+    assert all(b.run == ("run", "a", 4) for b in blocks[4:8])
+    assert blocks[8:] == [("blk", "a", i) for i in (8, 9, 10)]
+    # A splice takes them as two runs and one stretch of blocks.
+    assert splice_pieces(blocks) == [
+        (0, 4, ("run", "a", 0)), (4, 4, ("run", "a", 4)), (8, 3, None)]
+    # An edge shorter than a run rests as blocks, as it did.
+    assert publish(ints(6, 500), "s") == 3
+    assert pc.lookup(ints(6, 500) + [9]).blocks() == [
+        ("blk", "s", i) for i in range(3)]
+    # And a tree told of no run length never asks for one.
+    off, publish_off = run_tree(run_blocks=0)
+    publish_off(ints(22), "a")
+    assert not any(isinstance(b, RunBlock)
+                   for b in off.lookup(ints(22) + [9]).blocks())
+
+
+def test_runs_count_from_the_edges_own_start():
+    """Case 1: a second course shares the first block alone, so its edge
+    starts at block 1: its runs are blocks 1-4 and 5-8, not 4-7."""
+    pc, publish = run_tree()
+    publish(ints(18), "a")
+    assert publish(ints(2) + ints(18, 100), "b") == 9
+    pieces = splice_pieces(pc.lookup(ints(2) + ints(18, 100) + [9]).blocks())
+    assert pieces == [(0, 1, ("run", "a", 0)), (1, 4, ("run", "b", 1)),
+                      (5, 4, ("run", "b", 5)), (9, 1, None)]
+    assert pc.blocks_used == held(pc) == 18
+
+
+def test_a_split_inside_a_run_leaves_both_nodes_the_one_array():
+    """Case 2: a new question diverges inside a run. Tree arrays are
+    immutable: the head and the tail keep their entries of the SAME run,
+    nothing is cut, and a later hit over both is one piece again."""
+    pc, publish = run_tree()
+    publish(ints(16), "a")
+    publish(ints(12) + ints(4, 70), "q")       # diverges at block 6 of 8
+    match = pc.lookup(ints(16) + [9])
+    assert [len(n.blocks) for n in match.nodes] == [6, 2]
+    top, tail = match.nodes
+    assert top.blocks[4].run is tail.blocks[0].run
+    assert [b.index for b in top.blocks[4:] + tail.blocks] == [0, 1, 2, 3]
+    assert splice_pieces(match.blocks()) == [
+        (0, 4, ("run", "a", 0)), (4, 4, ("run", "a", 4))]
+    # The other branch reads the head of the shared run and its own blocks.
+    assert splice_pieces(pc.lookup(ints(12) + ints(4, 70) + [9]).blocks()) \
+        == [(0, 4, ("run", "a", 0)), (4, 2, ("run", "a", 4)), (6, 2, None)]
+    assert pc.blocks_used == held(pc) == 10
+
+
+def test_a_hit_that_ends_inside_a_run_is_the_runs_head():
+    """Case 3: `plan_staged` or a snapshot cuts a hit inside a run; the
+    piece says how many of the run's blocks count."""
+    pc, publish = run_tree()
+    publish(ints(16), "a")
+    blocks = pc.lookup(ints(16) + [9]).blocks()
+    assert splice_pieces(blocks[:7]) == [
+        (0, 4, ("run", "a", 0)), (4, 3, ("run", "a", 4))]
+    assert splice_pieces(blocks[:1]) == [(0, 1, ("run", "a", 0))]
+    assert splice_pieces([]) == []
+    # Entries that do not start their run are never taken for one.
+    assert splice_pieces(blocks[2:6]) == [(0, 2, None),
+                                          (2, 2, ("run", "a", 4))]
+
+
+def test_evicting_beside_a_shared_run_frees_nothing_the_other_reads():
+    """Case 5: the tail of a split run is evicted; the head's entries
+    still point at the run, and `blocks_used` is what the nodes hold."""
+    pc, publish = run_tree(max_blocks=8)
+    publish(ints(16), "a")
+    publish(ints(12) + ints(4, 70), "q")
+    assert pc.blocks_used == held(pc) == 10
+    pc.lookup(ints(12) + ints(4, 70) + [9])    # the question is the newer
+    assert pc.evict_to_budget() == 2           # a's tail: blocks 6 and 7
+    assert pc.blocks_used == held(pc) == 8
+    match = pc.lookup(ints(16) + [9])
+    assert match.tokens == 12
+    assert splice_pieces(match.blocks()) == [
+        (0, 4, ("run", "a", 0)), (4, 2, ("run", "a", 4))]
+    # The next over-budget leaf to go is the question's, then the shared
+    # head: every count is a node's entries.
+    pc.max_blocks = 1
+    assert pc.evict_to_budget() == 8 and pc.blocks_used == held(pc) == 0
+
+
+# ----------------------------- stored runs through the engine: the slot's planes
+
+# 40 tokens (ten blocks of 4) of one course and of another, and questions of
+# 8, so that a prompt fills the 48 bucket's twelve blocks.
+RUN_A = "the raft leader election works by votes "
+RUN_B = "a paged cache keeps every block in place"
+assert len(RUN_A) == len(RUN_B) == 40
+HEAD = "abcd"
+
+# What is served first, then the admission whose splice is looked at; blocks
+# in a stored run; the tokens that admission's hit holds, and how many of them
+# stored runs brought.
+RUN_SCENARIOS = {
+    # Case 1: both courses share the first block, so the second course's
+    # edge starts at block 1 and its runs are blocks 1-3, 4-6, 7-9.
+    "edge_starts_inside_a_span": (
+        [HEAD + RUN_A[:36] + "why so? ", HEAD + RUN_B[:36] + "and how?",
+         HEAD + RUN_B[:36] + "say more"], 3, 40, 40),
+    # Case 2: the second prompt diverges at block 8, inside the run 6-8;
+    # the third reads that run across the split's two nodes.
+    "split_inside_a_run": (
+        [RUN_A + "why so? ", RUN_A[:34] + "with terms and", RUN_A + "say more"],
+        3, 40, 40),
+    # Case 3: seven blocks match, the last the head of the run 6-8.
+    "hit_ends_inside_a_run": (
+        [RUN_A + "why so? ", RUN_A[:30] + "something else now"], 3, 28, 28),
+    # Case 4: the 8 bucket's cache is 16 tokens wide and a run 20: the
+    # program would clamp its start, so the block is cut out of the run.
+    "cache_too_narrow_for_a_run": (
+        [RUN_A + "why so? ", RUN_A[:7]], 5, 4, 0),
+}
+
+
+def run_engine(run_blocks):
+    return make_engine(make_config(length_buckets=(8, 48)),
+                       stored_run_blocks=run_blocks)
+
+
+@pytest.mark.parametrize("scenario", sorted(RUN_SCENARIOS))
+def test_a_stored_runs_splice_leaves_the_planes_the_blocks_did(
+        scenario, stage_last_prompt):
+    import numpy as np
+
+    prompts, run_blocks, hit, from_runs = RUN_SCENARIOS[scenario]
+    eng, off = run_engine(run_blocks), run_engine(0)
+    planes, counts, answers = stage_last_prompt(eng, prompts)
+    assert answers == bucketed(make_config(length_buckets=(8, 48)), prompts)
+    assert eng.stored_run_blocks == run_blocks
+    assert eng._prefix_hit_tokens >= hit
+    assert counts.get("prefix_tokens_from_runs", 0) == from_runs
+    # The same traffic with every block resting on its own: the parent's
+    # splice. Same bytes in every plane, fewer launches.
+    want, blocks, same = stage_last_prompt(off, prompts)
+    assert off.stored_run_blocks == 0 and same == answers
+    assert "prefix_tokens_from_runs" not in blocks
+    assert planes.keys() == want.keys()
+    for name in planes:
+        assert np.array_equal(planes[name], want[name]), name
+    assert counts["admissions"] == blocks["admissions"] == 1
+    if from_runs:
+        assert counts["stage_block_launches"] <= -(-hit // (4 * run_blocks)) + 1
+    assert eng.prefix_cache.blocks_used == off.prefix_cache.blocks_used == sum(
+        len(n.blocks) for n in eng.prefix_cache._iter_nodes())
 
 
 # ------------------------------------------------------- greedy bit-equality

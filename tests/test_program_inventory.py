@@ -70,7 +70,7 @@ def test_manifest_covers_the_paged_program_set():
     attrs = {e.attr for e in inv.entries_for("PagedEngine")}
     assert attrs == {"_megastep", "_grow", "_export_block",
                      "_stage", "_stage_block", "_score",
-                     "_restore_state", "_export_state"}
+                     "_restore_state", "_export_state", "_export_run"}
     assert all(
         e.coverage == "warmup" for e in inv.entries_for("PagedEngine")
     ), "the paged engine's whole program set is a warmup promise"
@@ -110,6 +110,64 @@ def test_static_domain_math_is_engine_math():
     # One megastep program per width and rung, rung 1 included.
     assert inv.static_paged_domain(
         64, 8, (8, 16), 0, megastep_max=4)["megastep_pairs"] == 2 * 3
+
+
+@pytest.mark.parametrize("run_blocks,want", [
+    # Nothing is a run long: the programs of the tree before stored runs.
+    (0, (2, 2, 0)), (128, (2, 2, 0)), (7, (2, 2, 0)),
+    # The 24 bucket's prompts are a run of 6 blocks (24 tokens) long: the
+    # 32-wide cache holds one and exports, splices and cuts it; the 16-wide
+    # cannot be handed one, and gets its blocks cut out of it.
+    (6, (3, 3, 1)),
+    # A run of 3 blocks fits either width.
+    (3, (4, 3, 2)),
+], ids=["off", "shipped_length", "a_block_too_long", "widest_only",
+        "every_width"])
+def test_stored_runs_are_counted_where_a_width_holds_one(run_blocks, want):
+    """The stored run's splice (`_stage_block`, one more program), its
+    export (`_export_run`) and the cut of a block out of it
+    (`_export_block`, one program whatever the width) are in the domain
+    for every width that holds a run, where some bucket is a run long."""
+    dom = inv.static_paged_domain(64, 8, (8, 24), 0, prefix_cache=True,
+                                  prefix_block_tokens=4,
+                                  stored_run_blocks=run_blocks)
+    assert dom["widths"] == [16, 32]
+    assert (dom["stage_block_widths"], dom["export_widths"],
+            dom["run_export_widths"]) == want
+    assert inv.static_paged_domain(
+        64, 8, (8, 24), 0, stored_run_blocks=run_blocks)[
+            "run_export_widths"] == 0          # no tree, no program
+    assert inv.width_holds_stored_run(32, 4, 6)
+    assert not inv.width_holds_stored_run(16, 4, 6)
+    assert not inv.width_holds_stored_run(4096, 16, 0)
+    assert inv.bucket_holds_stored_run(24, 4, 6)
+    assert not inv.bucket_holds_stored_run(23, 4, 6)
+
+
+def test_the_shipped_cells_count_their_stored_run_programs():
+    """`minicpm-sala.reader-herd`'s widths (768 and 33,536): three programs
+    more than before stored runs, all at the wide cache; a notes cell's
+    2,560 bucket is a run long too; `gpt2-xl.deadline-herd` has the
+    programs it had."""
+    sala = inv.static_paged_domain(33536 + 512, 512, (256, 33024), 0,
+                                   megastep_max=8, prefix_cache=True,
+                                   recurrent_state=True)
+    assert sala["widths"] == [768, 33536]
+    assert (sala["stage_block_widths"], sala["export_widths"],
+            sala["run_export_widths"]) == (5, 3, 1)
+    before = inv.static_paged_domain(33536 + 512, 512, (256, 33024), 0,
+                                     megastep_max=8, prefix_cache=True,
+                                     recurrent_state=True,
+                                     stored_run_blocks=0)
+    assert (before["stage_block_widths"], before["export_widths"],
+            before["run_export_widths"]) == (4, 2, 0)
+    xl = inv.static_paged_domain(1024, 128, (256,), 0, prefix_cache=True)
+    assert xl == inv.static_paged_domain(1024, 128, (256,), 0,
+                                         prefix_cache=True,
+                                         stored_run_blocks=0)
+    assert inv.stage_runs(3, 2096, start=2048) == [(0, 3)]
+    assert inv.stage_runs(20, 40, start=17) == [(0, 16), (7, 13)]
+    assert inv.stage_runs(3, 18, start=8) == [(0, 1), (1, 1), (2, 1)]
 
 
 @pytest.mark.parametrize("preset,recurrent", [
