@@ -35,7 +35,7 @@ from typing import Tuple
 import jax
 import jax.numpy as jnp
 
-from .sampling import NEG_INF, SamplingParams, apply_repetition_penalty
+from .sampling import NEG_INF, SamplingParams, apply_repetition_penalty, top_k
 
 
 def build_drafts(
@@ -159,7 +159,7 @@ def _processed_top(
         if params.approx_top_k:
             vals, idx = jax.lax.approx_max_k(logits, k)
         else:
-            vals, idx = jax.lax.top_k(logits, k)
+            vals, idx = top_k(logits, k)
     else:
         vals = jnp.sort(logits, axis=-1)[..., ::-1]
         idx = jnp.argsort(logits, axis=-1)[..., ::-1]

@@ -355,6 +355,54 @@ ENTRY %main.1 (a: s32[]) -> s32[] {
         96, 160}
 
 
+# ------------------------- the sampler's selections by their width (PR 56)
+
+def _selection_widths(text: str) -> dict:
+    """{"TopK": widths, "sort": widths}: the length of the axis each
+    selection of a compiled module's text runs over. The TPU's compiler
+    spells `lax.top_k` as a custom call to `TopK` over its operand's last
+    axis, or, where it finds the row short or batched, as a `sort` with a
+    slice behind it."""
+    shapes, found = {}, {"TopK": set(), "sort": set()}
+    lines = text.splitlines()
+    for line in lines:
+        op = re.match(r"\s*(?:ROOT )?%?([\w.\-]+) = \(?(\w+\[[\d,]*\])", line)
+        if op:
+            shapes[op.group(1)] = [
+                int(d) for d in re.findall(r"\d+", op.group(2).split("[")[1])]
+    for line in lines:
+        call = re.search(r" custom-call\(%?([\w.\-]+)[,)].*"
+                         r"custom_call_target=\"TopK\"", line)
+        if call:
+            found["TopK"].add(shapes[call.group(1)][-1])
+        sort = re.match(
+            r"\s*(?:ROOT )?%?([\w.\-]+) = .* sort\(.*dimensions=\{(\d+)\}",
+            line)
+        if sort:
+            found["sort"].add(shapes[sort.group(1)][int(sort.group(2))])
+    return found
+
+
+def test_selection_widths_reads_a_module_text():
+    text = """
+%fused_computation (param_0.1: f32[16,200192]) -> (f32[16,50], s32[16,50]) {
+  %param_0.1 = f32[16,200192]{1,0:T(8,128)} parameter(0)
+  %fusion.1 = f32[16,200192]{1,0:T(8,128)} fusion(%param_0.1), kind=kLoop, calls=%fused_computation.1
+  ROOT %custom-call.1 = (f32[16,50]{1,0:T(8,128)}, s32[16,50]{1,0:T(8,128)}) custom-call(%fusion.1), custom_call_target="TopK", called_computations={%gt}
+}
+ENTRY %main.1 (x.1: f32[16,200192]) -> f32[16,50] {
+  %reshape.3 = f32[16,6400]{1,0:T(8,128)S(1)} reshape(%fusion.9)
+  %custom-call.2 = (f32[16,50]{1,0:T(8,128)S(1)}, s32[16,50]{1,0:T(8,128)S(1)}) custom-call(%reshape.3), custom_call_target="TopK", called_computations={%gt}
+  %custom-call.3 = f32[800,128]{1,0:T(8,128)S(1)} custom-call(), custom_call_target="AllocateBuffer"
+  %sort = (f32[16,1564]{1,0:T(8,128)}, s32[16,1564]{1,0:T(8,128)S(1)}) sort(%bitcast.1, %iota), dimensions={1}, is_stable=true, to_apply=%gt
+  %sort.2 = (f32[4,1,73448]{2,0,1:T(4,128)S(1)}, s32[4,1,73448]{2,0,1:T(4,128)S(1)}) sort(%a, %b), dimensions={2}, to_apply=%gt
+  %sort.3 = s32[1024]{0:T(1024)} sort(%c), dimensions={0}, to_apply=%lt
+}
+"""
+    assert _selection_widths(text) == {
+        "TopK": {200192, 6400}, "sort": {1564, 73448, 1024}}
+
+
 # ------------------------------------- Trinity-Mini's cut (models/afmoe.py)
 
 def test_trinity_mini_cut_step_and_megastep_compile_and_fit_one_v5e(one_chip):
@@ -397,6 +445,21 @@ def test_trinity_mini_cut_step_and_megastep_compile_and_fit_one_v5e(one_chip):
     # megastep's prefill passes of 256 and 1,024 rows as 288 and 1,056.
     assert _product_rows(step.as_text()) == {160}
     assert _product_rows(mega.as_text()) == {160, 288, 1056}
+    # The sampler's top-50 of 200,192 logits: the 1,564 maxima of groups of
+    # 128, the 400 maxima of groups of 16 among the 50 picked groups'
+    # 6,400, and 800 candidates. No selection of the program, the first
+    # token's rows under `vmap` among them, is as wide as the 4,096 columns
+    # from which the compiler calls its `TopK` (the parent: a call over
+    # `f32[16,200192]` a decode row, a sort of `f32[4,1,200192]` a pass),
+    # and the view the groups are read through is the logits' own bytes:
+    # nothing as wide as the vocabulary is copied or padded in the scans.
+    for compiled in (mega, step):
+        text = compiled.as_text()
+        widths = _selection_widths(text)
+        assert not widths["TopK"] and max(widths["sort"]) == 1564
+        assert {1564, 400, 800} <= widths["sort"]
+        for op in ("copy", "pad"):
+            assert _copies_inside_loops(text, "f32[16,200192]", op) == []
     assert re.search(r"ragged-dot\S* = bf16\[160,2048\]", step.as_text())
 
 
@@ -626,6 +689,17 @@ def test_minicpm_sala_cut_megastep_reads_chosen_blocks_in_place(one_chip,
                   "f32[6,48,32,128,128]"):
         assert plane in text
         assert _copies_inside_loops(text, plane) == []
+    # The sampler's top-50 of 73,448 logits: 574 group maxima, 400 among
+    # the picked groups' 6,400, 800 candidates (the parent's text called
+    # `TopK` over `f32[48,73448]` and sorted `f32[4,1,73448]` for a pass's
+    # first tokens). The vocabulary is no multiple of 128, so the 24
+    # columns of `-inf` that fill the last group are a `pad` of their own;
+    # no copy beside it.
+    widths = _selection_widths(text)
+    assert not widths["TopK"] and max(widths["sort"]) == 800
+    assert {574, 400, 800} <= widths["sort"]
+    for logits in ("f32[48,73448]", "f32[48,73472]"):
+        assert _copies_inside_loops(text, logits) == []
 
 
 @pytest.mark.parametrize(
@@ -680,11 +754,12 @@ def test_stored_run_programs_compile_and_write_in_place_on_one_v5e(one_chip,
 
 # ------------------- a prefill chunk touches its slot's pages in place
 
-def _copies_inside_loops(text: str, shape: str) -> list:
-    """Names of the `copy` operations of `shape` that a compiled module's
-    text holds in a computation some `while` or `conditional` reaches
-    (its body, condition or branches, and whatever those call). Copies in
-    the entry computation run once a dispatch and are not listed."""
+def _copies_inside_loops(text: str, shape: str, op: str = "copy") -> list:
+    """Names of the `copy` operations (or another operation's, by its
+    name) of `shape` that a compiled module's text holds in a computation
+    some `while` or `conditional` reaches (its body, condition or
+    branches, and whatever those call). Copies in the entry computation
+    run once a dispatch and are not listed."""
     calls, copies, roots, comp = {}, {}, set(), None
     for line in text.splitlines():
         head = re.match(r"(?:ENTRY )?%?([\w.\-]+) .*\{\s*$", line)
@@ -705,9 +780,10 @@ def _copies_inside_loops(text: str, shape: str) -> list:
         if re.search(r"\b(?:body|branch_computations|true_computation)=",
                      line):
             roots |= called
-        op = re.match(r"\s*(?:ROOT )?%?([\w.\-]+) = (\S+) copy\(", line)
-        if op and op.group(2).startswith(shape):
-            copies[comp].append(op.group(1))
+        found = re.match(
+            rf"\s*(?:ROOT )?%?([\w.\-]+) = (\S+) {op}\(", line)
+        if found and found.group(2).startswith(shape):
+            copies[comp].append(found.group(1))
     inside, todo = set(), list(roots)
     while todo:
         c = todo.pop()

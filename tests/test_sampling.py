@@ -1,8 +1,11 @@
 """Sampling ops vs HF transformers LogitsProcessors (golden parity)."""
 
+from functools import partial
+
 import numpy as np
 import pytest
 
+import jax
 import jax.numpy as jnp
 
 from distributed_lms_raft_llm_tpu.engine import sampling
@@ -100,3 +103,119 @@ def test_approx_top_k_samples_from_plausible_set():
     _, exact_idx = jax.lax.top_k(logits, 100)
     for row in range(4):
         assert int(toks[row]) in np.asarray(exact_idx[row]), row
+
+
+# --------------------------------- the exact top-k in stages (PR 56)
+
+# The six cells' vocabularies, and one that is a multiple of no group size
+# (its last group is partly padding at 64, 128 and 256 columns alike).
+VOCABULARIES = [50257, 200192, 20480, 65536, 40960, 73448, 33001]
+K = 50
+
+
+def _rows(kind: str, rows: int, vocab: int) -> np.ndarray:
+    rng = np.random.default_rng(vocab + rows)
+    noise = rng.normal(size=(rows, vocab)).astype(np.float32) * 3
+    if kind == "float32":
+        return noise
+    if kind == "bfloat16_rounded":  # ties two or three to a value
+        return np.asarray(
+            jnp.asarray(noise).astype(jnp.bfloat16).astype(jnp.float32))
+    if kind == "integer_rounded":
+        # A handful of values: every group's maximum ties with hundreds of
+        # others and the k-th value with thousands (-0.0 beside 0.0).
+        return np.round(noise / 3)
+    assert kind == "planted"
+    x = np.zeros((rows, vocab), np.float32)
+    # Row 0: every logit equal; the lowest ids win.
+    # Row 1: 70 equal maxima at the row's end, in the last, partly padded
+    # group and the one before it; the lowest 50 of them win.
+    x[1, -70:] = 1.0
+    # Row 2: 30 finite logits scattered, the rest -inf like the padding:
+    # the 30, then the lowest ids, never a padded column.
+    x[2] = -np.inf
+    x[2, rng.choice(vocab, 30, replace=False)] = rng.normal(size=30)
+    # Row 3 on: k-th value tied across groups far apart.
+    for r in range(3, rows):
+        x[r] = noise[r]
+        x[r, rng.choice(vocab, 200, replace=False)] = 9.0
+    return x
+
+
+@pytest.mark.parametrize("kind", [
+    "float32", "bfloat16_rounded", "integer_rounded", "planted"])
+@pytest.mark.parametrize("vocab", VOCABULARIES)
+def test_grouped_top_k_is_lax_top_k_element_for_element(vocab, kind):
+    """Values AND indices, ties included: as one jitted call over eight
+    rows (the tiled view), over five (rows of their own), under `jax.vmap`
+    a row at a time as the first token is sampled, and through the rule."""
+    x = jnp.asarray(_rows(kind, 8, vocab))
+    want_vals, want_idx = (np.asarray(a) for a in jax.lax.top_k(x, K))
+    assert sampling.group_size(vocab, K) == 128
+    forms = {"rule": jax.jit(lambda x: sampling.top_k(x, K))}
+    for g in (64, 128, 256):
+        two = partial(sampling.grouped_top_k, k=K, g=g)
+        forms[f"eight rows, {g}"] = jax.jit(two)
+        forms[f"five rows, {g}"] = jax.jit(lambda x, two=two: two(x[:5]))
+        forms[f"a row at a time, {g}"] = jax.jit(jax.vmap(two))
+    for name, form in forms.items():
+        vals, idx = (np.asarray(a) for a in form(x))
+        assert idx.dtype == want_idx.dtype and vals.dtype == want_vals.dtype
+        np.testing.assert_array_equal(
+            vals, want_vals[:len(vals)], err_msg=name)
+        np.testing.assert_array_equal(
+            idx, want_idx[:len(idx)], err_msg=name)
+
+
+def test_the_rule_for_the_group_by_the_rows_width():
+    """A row the TPU's compiler sorts whole (the CPU rehearsals'
+    vocabularies, `k >= V`) takes `lax.top_k`; a long one groups of a tile
+    line; the k lines it leaves, 6,400 columns, groups of 16; and every
+    width reaches one stage in a few."""
+    for width in (50, 64, 512, 1024, 4095):
+        assert sampling.group_size(width, K) == 0
+    assert [sampling.group_size(w, K) for w in (
+        4096, 6400, 12799, 12800, 20480, 200192)] == [8, 16, 16, 128, 128, 128]
+    for k in (1, 8, 50, 300, 5000):
+        for width in (4096, 6400, 50257, 200192, 1 << 24):
+            stages = 0
+            while (g := sampling.group_size(width, k)):
+                assert -(-width // g) >= k and k * g < width
+                width, stages = k * g, stages + 1
+            assert stages <= 4
+    x = jnp.asarray(_rows("bfloat16_rounded", 3, 512))
+    for got, want in zip(sampling.top_k(x, K), jax.lax.top_k(x, K)):
+        np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+    x = jnp.asarray(_rows("integer_rounded", 3, 50257))
+    for k in (8, 300):
+        for got, want in zip(sampling.top_k(x, k), jax.lax.top_k(x, k)):
+            np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+
+
+@pytest.mark.parametrize("rows,vocab", [(16, 200192), (48, 73448)])
+def test_sample_step_draws_the_parents_tokens(rows, vocab):
+    """`sample_step` against its spelling before PR 56 (`lax.top_k` over
+    the row, inlined here), one key: the same token in every row."""
+    params = sampling.SamplingParams.reference_defaults()
+    logits = jnp.asarray(_rows("bfloat16_rounded", rows, vocab))
+    seen = jnp.asarray(
+        np.random.default_rng(1).random((rows, vocab)) < 0.001)
+
+    def parent(rng, logits, seen):
+        logits = sampling.apply_repetition_penalty(
+            logits, seen, params.repetition_penalty) / params.temperature
+        top_vals, top_idx = jax.lax.top_k(logits, params.top_k)
+        probs = jax.nn.softmax(top_vals, axis=-1)
+        cum = jnp.cumsum(probs, axis=-1)
+        top_vals = jnp.where(
+            (cum - probs) > params.top_p, sampling.NEG_INF, top_vals)
+        choice = jax.random.categorical(rng, top_vals, axis=-1)
+        return jnp.take_along_axis(
+            top_idx, choice[:, None], axis=-1)[:, 0].astype(jnp.int32)
+
+    for seed in (0, 56):
+        key = jax.random.key(seed)
+        np.testing.assert_array_equal(
+            np.asarray(jax.jit(sampling.sample_step, static_argnums=3)(
+                key, logits, seen, params)),
+            np.asarray(jax.jit(parent)(key, logits, seen)))
