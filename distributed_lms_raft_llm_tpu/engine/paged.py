@@ -82,6 +82,7 @@ reference: GUI_RAFT_LLM_SourceCode/tutoring_server.py:21-29).
 from __future__ import annotations
 
 import dataclasses
+import functools
 import logging
 import math
 import time
@@ -138,8 +139,10 @@ log = logging.getLogger(__name__)
 # A recurrent family's state snapshots in the prefix tree
 # (`PagedEngine._snapshot_point`, engine/prefix_cache.py): the tree holds one
 # for every this many blocks of its budget, and a context's FIRST prompt,
-# which matched nothing, snapshots on a stride of this many steps of
-# lcm(prefill chunk, block) (256 tokens at the shipped 32 and 16).
+# which matched nothing, snapshots on a stride of at most this many steps of
+# lcm(prefill chunk, block) (256 tokens at the shipped 32 and 16): fewer
+# where the state is small beside the keys and values of a stride
+# (`PagedEngine._stride_steps`).
 STATE_BLOCKS_A_SNAPSHOT = 16
 STATE_STRIDE_STEPS = 8
 
@@ -154,8 +157,9 @@ class SlotState(NamedTuple):
 
     A family with a recurrent state (`ModelFamily.recurrent_state`) keeps
     it in the cache beside its keys and values, `cache.ssm` [Lm, S, H, P,
-    N] float32 and `cache.conv` [Lm, S, K-1, C]: planes WITHOUT a
-    positions axis, which a forward pass over a lane moves. They advance
+    N] float32 and `cache.conv` [Lm, S, K-1, C] (either alone where the
+    state is no more: `_has_state`): planes WITHOUT a positions axis,
+    which a forward pass over a lane moves. They advance
     once for every real token and for nothing else: the decode forward is
     told its `_live_lanes` (an idle lane, a staged lane before its flip
     and a lane past its request's cap stand still), a prefill chunk its
@@ -193,7 +197,8 @@ class SlotState(NamedTuple):
     stage_len: jax.Array     # [S] int32
     stage_seq: jax.Array     # [S] int32
     stage_rng: jax.Array     # [S, *key_data] uint32
-    # A recurrent family's snapshot planes (None for every other): where
+    # A recurrent family's snapshot planes (None for every other, and
+    # each None where the cache has no such plane): where
     # a staged slot's prefill reaches position `snap_at` (0: nowhere) at a
     # chunk's end, that chunk copies the slot's state into its row of
     # `snap_ssm` / `snap_conv` (shaped as the cache's `ssm` / `conv`), and
@@ -261,6 +266,13 @@ def _plane_spec(name: str) -> jax.sharding.PartitionSpec:
     second compile.
     """
     return partition.PAGED_PLANE_SPECS[name]
+
+
+def _has_state(cache: KVCache) -> bool:
+    """Whether the cache carries a recurrent state: an `ssm` plane, a
+    `conv` plane, or both (a Lightning state has no window, a short
+    convolution's whole state is its window)."""
+    return cache.ssm is not None or cache.conv is not None
 
 
 def _forward(model, params, cfg, ids, live, **kw):
@@ -350,9 +362,10 @@ def _stage_program(state: SlotState, slot, ids, true_len, cursor0, seq,
     )
     cache = state.cache
     # lint: disable-next=tracer-hygiene
-    if cache.ssm is not None:
+    if _has_state(cache):
         cache = cache._replace(
-            ssm=cache.ssm.at[:, slot].set(0.0),
+            ssm=(None if cache.ssm is None
+                 else cache.ssm.at[:, slot].set(0.0)),
             conv=(None if cache.conv is None
                   else cache.conv.at[:, slot].set(0.0)))
         state = state._replace(snap_at=state.snap_at.at[slot].set(
@@ -441,7 +454,7 @@ def _restore_state_program(state: SlotState, snap: StateSnapshot,
     c = state.cache
 
     def put(plane, new):
-        if plane is None:  # a state without a convolution's window
+        if plane is None:  # a state without this plane
             return None
         at = (jnp.zeros((), jnp.int32), slot) + (
             jnp.zeros((), jnp.int32),) * (plane.ndim - 2)
@@ -484,8 +497,9 @@ def _fresh_state(family, cfg, slots: int, width: int,
     # (threefry: [2] uint32) so wrap_key_data round-trips exactly.
     key_shape = jax.random.key_data(jax.random.key(0)).shape
     snap = {}
-    if cache.ssm is not None:
-        snap = dict(snap_ssm=jnp.zeros_like(cache.ssm),
+    if _has_state(cache):
+        snap = dict(snap_ssm=(None if cache.ssm is None
+                              else jnp.zeros_like(cache.ssm)),
                     snap_conv=(None if cache.conv is None
                                else jnp.zeros_like(cache.conv)),
                     snap_at=jnp.zeros((slots,), jnp.int32))
@@ -791,7 +805,7 @@ def _prefill_pass(params, s: SlotState, width: int, *, cfg, sampling, model,
     )
     snap = {}
     # lint: disable-next=tracer-hygiene
-    if s.snap_ssm is not None:
+    if s.snap_at is not None:
         # A chunk that ends at its slot's snapshot position (all of
         # it real: the position is below the prompt's end) copies the
         # state it leaves into the slot's snapshot rows: a row at a
@@ -2148,9 +2162,40 @@ class PagedEngine:
         branch = cursor0 + (matched - cursor0) // step * step
         if branch > cursor0:
             return branch
-        stride = STATE_STRIDE_STEPS * step
+        steps = self._stride_steps
+        if cursor0 and steps < STATE_STRIDE_STEPS:
+            # A small state's short stride is for a context's FIRST
+            # prompt: a prompt that started from a snapshot and branches
+            # nowhere past it would leave one a step below its own end,
+            # inside its own question, at nearly every admission, and a
+            # burst of those pushes the context's own snapshot out of the
+            # tree's least-recently-used few (`max_snapshots`).
+            return 0
+        stride = steps * step
         point = (prompt_len - 1) // stride * stride
         return point if point > cursor0 else 0
+
+    @functools.cached_property
+    def _stride_steps(self) -> int:
+        """Steps between the places a context's FIRST prompt may snapshot
+        at: as many as the tokens whose keys and values weigh what ONE
+        snapshot weighs, in whole steps, and at most STATE_STRIDE_STEPS.
+        A state of megabytes (a matrix a head: 8.5 to 15 MB a sequence
+        beside 1 to 2.3 KB of keys a token) keeps the 8 steps it had; a
+        state that is its convolutions' windows alone (82 KB beside 6 KB a
+        token) takes one, so that a context SHORTER than 8 steps leaves a
+        snapshot too: without one its next prompts each prefill it again
+        until the first of them has flipped and been reaped (PERF.md
+        section 6, PR 57, has what that cost, and what a stride of one
+        step did while it applied to EVERY prompt: `_snapshot_point`). A
+        ratio of bytes a slot, so the same at every width: worked out
+        once."""
+        c = self.state.cache
+        state = sum(x.nbytes for x in (c.ssm, c.conv) if x is not None)
+        token = sum(x.nbytes for x in (c.k, c.v, c.ks, c.vs, c.pool)
+                    if x is not None) / c.k.shape[3]
+        step = math.lcm(self.prefill_chunk, self.prefix_block_tokens)
+        return max(1, min(STATE_STRIDE_STEPS, math.ceil(state / token / step)))
 
     def _required_width(self, prompt_len: int) -> int:
         bucket = min(
@@ -2435,7 +2480,7 @@ class PagedEngine:
             return x if x.sharding == sh else jax.device_put(x, sh)
 
         return StateSnapshot(
-            ssm=put(snap.ssm, "ssm"),
+            ssm=None if snap.ssm is None else put(snap.ssm, "ssm"),
             conv=None if snap.conv is None else put(snap.conv, "conv"))
 
     def step(self) -> List[Tuple[int, str]]:

@@ -155,15 +155,16 @@ class StateSnapshot(NamedTuple):
     """The recurrent state of ONE sequence after a prefix of whole blocks,
     immutable and device-resident like a `KVBlock`: `ssm` [Lm, 1, H, P, N]
     float32 and `conv` [Lm, 1, K-1, C] (models/mamba2.py), planes without
-    a positions axis. Never donated, never written in place."""
+    a positions axis; either may be the whole of it. Never donated, never
+    written in place."""
 
-    ssm: jax.Array
+    ssm: Optional[jax.Array]   # None: a state that is its window alone
     conv: Optional[jax.Array]  # None: a state without a convolution
 
     @property
     def nbytes(self) -> int:
-        return int(self.ssm.nbytes) + (
-            0 if self.conv is None else int(self.conv.nbytes))
+        return sum(int(x.nbytes) for x in (self.ssm, self.conv)
+                   if x is not None)
 
 
 @dataclasses.dataclass

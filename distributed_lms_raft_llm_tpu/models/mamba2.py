@@ -96,12 +96,13 @@ def gated_norm(y: jax.Array, z: jax.Array, scale: jax.Array, groups: int,
 
 
 def causal_conv(xbc: jax.Array, window: jax.Array, mp: Params,
-                live: jax.Array):
+                live: jax.Array, act=jax.nn.silu):
     """The causal depthwise convolution of a chunk that continues a
     window: xbc [B, T, C], window [B, K-1, C] (the inputs before it), live
-    [B, T] -> (silu(conv + bias) [B, T, C] float32, the window that ends
+    [B, T] -> (act(conv + bias) [B, T, C] float32, the window that ends
     at each row's last live position [B, K-1, C]). A tree without `conv_b`
-    has no bias (models/kda.py)."""
+    has no bias (models/kda.py); `act` None is no activation
+    (models/lfm2.py: the gates around the convolution are the caller's)."""
     b, t, _ = xbc.shape
     k1 = window.shape[1]
     xbc = jnp.where(live[..., None], xbc, jnp.zeros((), xbc.dtype))
@@ -111,7 +112,8 @@ def causal_conv(xbc: jax.Array, window: jax.Array, mp: Params,
               for j in range(k1 + 1))
     if "conv_b" in mp:
         out = out + mp["conv_b"].astype(jnp.float32)
-    out = jax.nn.silu(out)
+    if act is not None:
+        out = act(out)
     # seq[last + 1 .. last + K-1] are the K-1 inputs up to live position
     # `last` (-1: none was live, and the window stands).
     ends = jnp.max(jnp.where(live, jnp.arange(1, t + 1), 0), axis=1)

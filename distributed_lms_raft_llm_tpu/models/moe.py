@@ -187,7 +187,7 @@ def moe_mlp(h: jax.Array, mp: Dict[str, jax.Array], cfg,
 
 
 def route_sigmoid(x: jax.Array, wr: jax.Array, bias: jax.Array, k: int,
-                  norm: bool, scale: float):
+                  norm: bool, scale: float, eps: float = 1e-20):
     """Sigmoid routing (the DeepSeek-V3 line, afmoe): x [S, D] ->
     (experts [S, k] int32, weights [S, k] float32).
 
@@ -196,7 +196,8 @@ def route_sigmoid(x: jax.Array, wr: jax.Array, bias: jax.Array, k: int,
     two experts' scores can differ by less than that rounds). The k experts
     are the top-k of `score + bias` (`bias` None: of the scores): the
     per-expert balancing bias takes part in the CHOICE only, the weights
-    are the chosen experts' own scores, over their sum when `norm`, times
+    are the chosen experts' own scores, over their sum (+ `eps`: a
+    family's published epsilon, models/lfm2.py's 1e-6) when `norm`, times
     `scale`.
     """
     logits = jnp.einsum("sd,de->se", x.astype(jnp.float32),
@@ -207,7 +208,7 @@ def route_sigmoid(x: jax.Array, wr: jax.Array, bias: jax.Array, k: int,
     _, top_i = jax.lax.top_k(choice, k)
     top_w = jnp.take_along_axis(scores, top_i, axis=-1)
     if norm:
-        top_w = top_w / (jnp.sum(top_w, axis=-1, keepdims=True) + 1e-20)
+        top_w = top_w / (jnp.sum(top_w, axis=-1, keepdims=True) + eps)
     return top_i.astype(jnp.int32), top_w * scale
 
 

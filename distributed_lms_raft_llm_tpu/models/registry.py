@@ -35,7 +35,10 @@ MLA layers: a latent cache AND a recurrent state) and OpenBMB's
 minicpm_sala (MiniCPM-SALA: block-sparse attention layers that choose the
 blocks they read through a plane of pooled keys kept beside the keys, and
 Lightning linear-attention layers whose matrix state lives in the cache:
-keys, a selector's cache AND a recurrent state).
+keys, a selector's cache AND a recurrent state) and Liquid AI's lfm2_moe
+(LFM2-8B-A1B: gated short-convolution layers whose whole state is a row's
+last two inputs, `KVCache.conv` with NO `ssm` plane, beside grouped-query
+attention layers' keys and values, and a layer's routed experts held whole).
 """
 
 from __future__ import annotations
@@ -48,6 +51,7 @@ from . import (
     convert,
     gpt2,
     kimi_linear,
+    lfm2,
     llama,
     minicpm_sala,
     moe,
@@ -82,7 +86,10 @@ class ModelFamily(NamedTuple):
     # to shard, and the engines refuse tp > 1.
     latent_cache: bool = False
     # The cache carries a recurrent state (`KVCache.ssm`, `.conv`:
-    # models/mamba2.py, models/kda.py) that a forward pass MOVES. The engines refuse
+    # models/mamba2.py, models/kda.py; either plane alone: a Lightning
+    # state has no window, models/minicpm_sala.py, and a short
+    # convolution's whole state IS its window, models/lfm2.py) that a
+    # forward pass MOVES. The engines refuse
     # spec_tokens > 0 (a verify window cannot roll the state back past a
     # rejected draft) and tp > 1 (the state's heads are not sharded) for
     # such a family, and the paged engine resets a slot's state at staging
@@ -127,6 +134,11 @@ MINICPM_SALA_FAMILY = ModelFamily(
     minicpm_sala.init_cache, minicpm_sala.params_from_hf, routed=True,
     counters=minicpm_sala.COUNTERS, recurrent_state=True,
 )
+LFM2_FAMILY = ModelFamily(
+    "lfm2_moe", lfm2.init_params, lfm2.forward, lfm2.init_cache,
+    lfm2.params_from_hf, routed=True, counters=lfm2.COUNTERS,
+    recurrent_state=True,
+)
 
 # preset -> (family, config factory)
 PRESETS = {
@@ -160,6 +172,9 @@ PRESETS = {
     "minicpm-sala-8l": (MINICPM_SALA_FAMILY,
                         minicpm_sala.MiniCPMSalaConfig.minicpm_sala_8l),
     "sala-tiny": (MINICPM_SALA_FAMILY, minicpm_sala.MiniCPMSalaConfig.tiny),
+    "lfm2-8b-a1b": (LFM2_FAMILY, lfm2.Lfm2MoeConfig.lfm2_8b_a1b),
+    "lfm2-8b-a1b-13l": (LFM2_FAMILY, lfm2.Lfm2MoeConfig.lfm2_8b_a1b_13l),
+    "lfm2-tiny": (LFM2_FAMILY, lfm2.Lfm2MoeConfig.tiny),
 }
 
 

@@ -166,6 +166,16 @@ MINICPM_SALA_RULES: List[Tuple[str, PartitionSpec]] = [
     (r".*", P()),
 ]
 
+# lfm2_moe (models/lfm2.py): everything replicated, as the four other
+# routed families' stacks: the engines refuse tp > 1 for a family with a
+# recurrent state (the conv layers' windows [Lc, S, K-1, D] have no heads
+# axis; sharding their channels beside the operator's projections is not
+# built) and ep > 1 (a share of a layer's experts is the configuration's,
+# `experts_held`; the exchange between shares is not built).
+LFM2_RULES: List[Tuple[str, PartitionSpec]] = [
+    (r".*", P()),
+]
+
 # Rule set per model-family name (models/registry.py ModelFamily.name).
 # (The bucketed engine's KV-cache sharding — [L, B, Hkv, T, Dh]: batch
 # over dp, heads over tp — is derived by jit's sharding propagation from
@@ -183,6 +193,7 @@ RULES_FOR = {
     "nemotron_h": NEMOTRON_H_RULES,
     "kimi_linear": KIMI_LINEAR_RULES,
     "minicpm_sala": MINICPM_SALA_RULES,
+    "lfm2_moe": LFM2_RULES,
 }
 
 # ---------------------------------------------- paged state plane table

@@ -655,8 +655,20 @@ ENGINE_ATTN_LANE_STEPS = counter(
     "engine_attn_lane_steps",
     "decode lane-steps of a block-sparse attention layer (live lanes x "
     "sparse layers), summed on the device over a dispatch's decode steps "
-    "and read back at its reap; only a family that selects what it reads "
-    "counts (models/minicpm_sala.py)",
+    "and read back at its reap (models/minicpm_sala.py); in a family of "
+    "conv and attention layers (models/lfm2.py) the live tokens' passes "
+    "through an attention layer, decode and prefill alike, beside "
+    "engine_conv_lane_steps",
+)
+ENGINE_CONV_LANE_STEPS = counter(
+    "engine_conv_lane_steps",
+    "live tokens' passes through a gated short-convolution layer (live "
+    "tokens x conv layers, a decode lane or a prefill position that is "
+    "real), summed on the device over a dispatch's forward passes and "
+    "read back at its reap; only models/lfm2.py counts. Over it plus "
+    "engine_attn_lane_steps: the conv layers' share of the operator "
+    "passes, the cut's ten of thirteen wherever no layer kind was lost "
+    "and no dead lane counted",
 )
 ENGINE_SPARSE_LANE_STEPS = counter(
     "engine_sparse_lane_steps",
@@ -848,6 +860,7 @@ ENGINE_LOOP_COUNTERS: Dict[str, str] = {
     "moe_passes_bounded": MOE_PASSES_BOUNDED,
     "moe_passes_compacted": MOE_PASSES_COMPACTED,
     "attn_lane_steps": ENGINE_ATTN_LANE_STEPS,
+    "conv_lane_steps": ENGINE_CONV_LANE_STEPS,
     "sparse_lane_steps": ENGINE_SPARSE_LANE_STEPS,
     "sparse_keys_attended": ENGINE_SPARSE_KEYS_ATTENDED,
     "sparse_keys_in_context": ENGINE_SPARSE_KEYS_IN_CONTEXT,

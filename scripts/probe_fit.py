@@ -82,6 +82,17 @@ SHAPES = {
     # Its prefill pass of four rows, whole: 768 rows against 800.
     "nemotron3-nano-wide-pass": (4, 64, 128, 3072, 2048, 2, 128, 6,
                                  ((None, 32),)),
+    # lfm2-8b-a1b.notes-hall's decode row: 64 lanes x 4 picks over all 32
+    # experts, 8 rows a group: 256 rows as they are (ONE tile) and handed
+    # 288 and 320; M as published (1,792 = 7 x 256) and padded to 2,048.
+    "lfm2-decode": (4, 32, 32, 2048, 1792, 3, 64, 4,
+                    ((None, 32), (None, 64))),
+    "lfm2-decode-padded": (4, 32, 32, 2048, 2048, 3, 64, 4,
+                           ((None, 32), (None, 64))),
+    # Its prefill pass of four rows of 32 positions: 512 rows, 16 a group.
+    "lfm2-wide-pass": (4, 32, 32, 2048, 1792, 3, 128, 4, ((None, 32),)),
+    "lfm2-wide-pass-padded": (4, 32, 32, 2048, 2048, 3, 128, 4,
+                              ((None, 32),)),
 }
 
 

@@ -648,6 +648,72 @@ def test_kimi_linear_cut_megastep_updates_both_planes_in_place(one_chip,
 
 
 @BOTH_RUNGS
+def test_lfm2_cut_megastep_shifts_the_windows_in_place(one_chip, chunks):
+    """`lfm2-8b-a1b-13l` at the published widths, from shapes alone, in the
+    serving settings of benchmarks/configs/lfm2-8b-a1b.json (64 slots,
+    width 2,816, chunk 8, prefill chunks of 32, answers of 256): 10.42 GB
+    of bfloat16 weights as held beside 1.11 GB of keys and values and the
+    conv layers' windows; the conv operator's decode step is the kernel
+    `shortconv_step`, which the compiler accepts over a bfloat16 plane of
+    two rows a slot and which updates it where it lies (the plane is 5 MB:
+    the one-row pass parks it in the chip's fast memory and back, which
+    is the compiler's to choose). All 32 experts are held: the
+    grouped products run over every row, in tiles of 32 (a decode row's
+    256 picks as 288, a one-row pass's 128 as 160, the pass of four rows'
+    512 as 544), with no conditional from a routed layer, and the stacks,
+    their inner width padded to 2,048 (`lfm2.pad_experts`), enter the
+    TPU's grouped kernel as whole buffers. The keys' and values' planes
+    are carried with a position's 8 heads in one row of 512 and nothing
+    copies them in the decode scan or in the pass of four rows; the
+    one-row pass of the program of one chunk copies the KEYS' plane twice
+    (the compiler schedules that pass's read of a layer's row across the
+    next layer's scatter, and lays the conditional's result with the unit
+    axis elsewhere; the values' plane, written the same way, has
+    neither): PERF.md section 7 carries it as an open item, and this test
+    holds it to those two."""
+    family, cfg = registry.resolve("lfm2-8b-a1b-13l", jnp.bfloat16,
+                                   jnp.bfloat16)
+    params = _with(jax.eval_shape(
+        lambda: family.init_params(jax.random.key(0), cfg)), one_chip)
+    # The published 4,606,249,728 and the experts' padding to 2,048.
+    assert sum(x.size for x in jax.tree.leaves(params)) == (
+        4_606_249_728 + 12 * 32 * 3 * 2048 * (2048 - 1792))
+    state = jax.eval_shape(partial(paged._fresh_state, family, cfg, 64, 2816))
+    assert state.cache.k.shape == state.cache.v.shape == (3, 64, 1, 2816, 512)
+    assert state.cache.ssm is None and state.snap_ssm is None
+    assert state.cache.conv.shape == state.snap_conv.shape == (
+        10, 64, 2, 2048)
+    sampling = dataclasses.replace(SamplingParams.reference_defaults(),
+                                   max_new_tokens=256)
+    mega = jax.jit(
+        partial(paged._megastep_program, chunk=8, spec_tokens=0,
+                prefill_chunk=32, draft_fn=build_drafts, eos_id=50256,
+                pad_id=50256, cfg=cfg, model=family, sampling=sampling),
+        donate_argnums=(1,),
+    ).lower(params, _with(state, one_chip), _with(jax.eval_shape(
+        lambda: jax.random.split(jax.random.key(0), chunks)),
+        one_chip)).compile()
+    ma = mega.memory_analysis()
+    assert _device_bytes(ma) < 0.8 * HBM_BYTES
+    assert ma.temp_size_in_bytes < 1024**3
+    text = mega.as_text()
+    assert "ragged-dot" in text and "shortconv_step" in text
+    assert _bounded_products(text) == {}
+    wide = {544} if chunks == 1 else set()
+    assert _product_rows(text) == {160, 288} | wide
+    for rows in (160, 288):
+        assert re.search(rf"ragged-dot\S* = bf16\[{rows},2048\]", text)
+    assert "bf16[10,64,2,2048]" in text
+    planes = [n for shape in ("bf16[3,64,1,2816,512]", "bf16[3,64,2816,512]",
+                              "bf16[1,64,2816,512]", "bf16[64,2816,512]")
+              for n in _copies_inside_loops(text, shape)]
+    assert len(planes) == (2 if chunks == 1 else 0), planes
+    stack = "bf16[32,2048,2048]"
+    assert stack in text
+    assert not re.search(re.escape(stack) + r"\S* copy\(", text)
+
+
+@BOTH_RUNGS
 def test_minicpm_sala_cut_megastep_reads_chosen_blocks_in_place(one_chip,
                                                                 chunks):
     """`minicpm-sala-8l` at the published widths, from shapes alone, in the

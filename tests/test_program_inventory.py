@@ -172,10 +172,11 @@ def test_the_shipped_cells_count_their_stored_run_programs():
 
 @pytest.mark.parametrize("preset,recurrent", [
     ("axk1-tiny", False), ("nemotronh-tiny", True),
-    ("kimilinear-tiny", True)])
+    ("kimilinear-tiny", True), ("lfm2-tiny", True)])
 def test_a_family_adds_no_program_of_its_own(preset, recurrent):
     """Whatever planes a family's cache declares (a latent plane, state
-    planes, both: `kimi_linear`), its engine's programs are the
+    planes, both: `kimi_linear`; a window plane alone: `lfm2_moe`), its
+    engine's programs are the
     inventory's: the snapshot programs count a width each for a recurrent
     family and zero for the others, and nothing else differs."""
     from distributed_lms_raft_llm_tpu.models import registry
