@@ -96,6 +96,7 @@ import numpy as np
 from ..models import convert, registry
 from ..models import quant as quant_lib
 from ..models.common import KVCache
+from ..ops import attention as attention_ops
 from ..ops import sparse as sparse_ops
 from ..parallel import mesh as mesh_lib
 from ..parallel import partition
@@ -1049,6 +1050,54 @@ def _megastep_program(params, state: SlotState, rngs, *, cfg, sampling,
             *moe)
 
 
+class _LaneLengths:
+    """The host's copy of the device's `cache.length` and `active`, as of
+    the last dispatch REAPED, for an engine whose decode attention goes by
+    a lane's length (`ops/attention.py` `quant_decode_attention`): what
+    `engine_attn_positions_read` is counted from, with no read-back of its
+    own. The device moves both planes inside a dispatch by rules the reap's
+    planes determine (`_step_program`: a flip sets a lane to its prompt's
+    length, an active lane gains a position a row until its token is eos),
+    and the host moves them between dispatches (`_stage_program` parks a
+    lane at the width's last position, a reaped end kills its lane): the
+    host's moves are queued (`ops`) and ride with the next dispatch sent
+    (`sent`, in the order of `PagedEngine._inflight`), so that a reap
+    replays them in the device's order."""
+
+    def __init__(self, slots: int):
+        self.length = np.zeros((slots,), np.int64)
+        self.active = np.zeros((slots,), bool)
+        self.ops: List[Tuple[int, int]] = []  # (slot, parked at | -1: killed)
+        # A dispatch in flight: (the moves before it, its width, its width
+        # if its attention went by lengths, else 0).
+        self.sent: List[Tuple[List[Tuple[int, int]], int, int]] = []
+
+    def dispatched(self, width: int, held: int) -> None:
+        self.sent.append((self.ops, width, held))
+        self.ops = []
+
+    def replay(self, ops, toks, flipped, firsts, prompt_lens, eos: int,
+               width: int) -> int:
+        """One dispatch's rows ([rows, S] planes) after the host's `ops`:
+        the positions the kernel fetched, over every lane-step."""
+        for slot, parked in ops:
+            self.active[slot] = False
+            if parked >= 0:
+                self.length[slot] = parked
+        length, active, read = self.length, self.active, 0
+        for row in range(toks.shape[0]):
+            flip = flipped[row]
+            if flip.any():
+                length = np.where(flip, prompt_lens, length)
+                active = np.where(flip, firsts[row] != eos, active)
+            read += int(attention_ops.quant_decode_positions(
+                np.minimum(length, width - 1) + 1, width).sum())
+            length = np.where(active, np.minimum(length + 1, width), length)
+            active = active & (toks[row] != eos)
+        self.length, self.active = length, active
+        return read
+
+
 def rows_to_certain_end(req: Optional[_Request], tmax: int,
                         rows_in_flight: int) -> Optional[int]:
     """Scan iterations (rows) still to DISPATCH before `req`'s end is
@@ -1415,6 +1464,12 @@ class PagedEngine:
         )
         self._rng = jax.random.key(config.seed)
         self.state = self._init_state()
+        # Int8 planes and one query a lane-step: the decode attention may
+        # go by the lanes' lengths (whether it does at a dispatch's width
+        # is `_attn_width`'s), and the host keeps their copy.
+        self._lanes = (_LaneLengths(self.slots)
+                       if self.state.cache.ks is not None and not self.spec
+                       else None)
         self._slot_req: List[Optional[_Request]] = [None] * self.slots
         self._pending: List[_Request] = []
         # Dispatched-but-unread megasteps, oldest first:
@@ -1623,6 +1678,17 @@ class PagedEngine:
         `mesh` block and the `serving_kv_bytes_per_chip` gauge report,
         and the resource multi-chip paged serving exists to split."""
         return self.kv_bytes_total // max(1, self.tp)
+
+    def _attn_width(self) -> int:
+        """The live cache's width where the decode step's attention reads
+        a lane's live positions alone (`quant_decode_engages`, by the
+        planes' shapes), else 0."""
+        cache = self.state.cache
+        if self._lanes is None or not attention_ops.quant_decode_engages(
+                (self.slots, cache.ks.shape[2], 1, self.cfg.head_dim),
+                cache.k.shape):
+            return 0
+        return cache.k.shape[3]
 
     def _init_state(self, width: Optional[int] = None) -> SlotState:
         # Plane-table mesh shardings from birth, in the canonical
@@ -1918,6 +1984,8 @@ class PagedEngine:
         the engine.
         """
         self.state = self._init_state()
+        if self._lanes is not None:
+            self._lanes = _LaneLengths(self.slots)
         self._slot_req = [None] * self.slots
         self._pending = []
         self._inflight = []
@@ -1953,6 +2021,8 @@ class PagedEngine:
             )
             if needed != self.state.cache.k.shape[3]:
                 self.state = self._init_state(needed)
+                if self._lanes is not None:  # nothing in flight: as new
+                    self._lanes = _LaneLengths(self.slots)
 
     def _pop_next(self) -> Tuple[_Request, int, int, np.ndarray]:
         """Take the oldest pending request: record its queue wait, pick
@@ -2064,6 +2134,9 @@ class PagedEngine:
                         jax.random.key_data(rng),
                         *self._snap_arg(req.snap_at),
                     )
+                if self._lanes is not None:  # `_stage_program` parks it
+                    self._lanes.ops.append(
+                        (slot, self.state.cache.k.shape[3] - 1))
                 if snapshot is not None:
                     with self._span(PROG + "restore_state"):
                         self.state = self._restore_state(
@@ -2557,6 +2630,9 @@ class PagedEngine:
             toks, active, dead = outs
         self._count(scan_iterations=k * self.chunk,
                     lane_steps=k * self.chunk * self.slots)
+        if self._lanes is not None:
+            self._lanes.dispatched(self.state.cache.k.shape[3],
+                                   self._attn_width())
         self._push_inflight(toks, counts, active, dead, flipped, firsts,
                             moe, served)
 
@@ -2619,8 +2695,29 @@ class PagedEngine:
                                 crowded if toks.shape[0] > 1 else 0))
         self._observe("reap_wait", wait.wall_s)
         with self._span("engine.reap.host"):
+            if self._lanes is not None and self._lanes.sent:
+                self._count_attn_positions(toks, flipped, firsts,
+                                           slot_snapshot,
+                                           *self._lanes.sent.pop(0))
             return self._walk(toks, counts, active, flipped, firsts,
                               slot_snapshot)
+
+    def _count_attn_positions(self, toks, flipped, firsts, slot_snapshot,
+                              ops, width: int, held: int) -> None:
+        """`_LaneLengths`' turn of a reap, before `_walk` queues its kills:
+        the host's moves `ops` that went before the dispatch, then its
+        rows at its `width`; counted where the attention went by lengths
+        (`held`: the width then, else 0)."""
+        lanes = toks.shape[-1]
+        read = self._lanes.replay(
+            ops, toks.reshape(-1, lanes), flipped.reshape(-1, lanes),
+            firsts.reshape(-1, lanes),
+            np.fromiter((0 if r is None else r.prompt_len
+                         for r in slot_snapshot), np.int64, lanes),
+            self.tokenizer.eos_id, width)
+        if held:
+            self._count(attn_positions_read=read,
+                        attn_positions_held=held * toks.size)
 
     def _walk(self, toks, counts, active, flipped, firsts,
               slot_snapshot) -> List[Tuple[int, str]]:
@@ -2763,6 +2860,8 @@ class PagedEngine:
                     self.state = self.state._replace(
                         active=self.state.active.at[slot].set(False)
                     )
+                    if self._lanes is not None:
+                        self._lanes.ops.append((slot, -1))
         self._count(staged_lane_steps=staged, overrun_lane_steps=overrun)
         self._observe("decode_lanes", decoded / rows)
         return done

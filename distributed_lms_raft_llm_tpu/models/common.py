@@ -21,10 +21,13 @@ Conventions
 
 from __future__ import annotations
 
+import functools
 from typing import NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+
+from ..ops import attention as attention_ops
 
 NEG_INF = -1e30  # large finite negative: avoids NaNs from (-inf) - (-inf)
 
@@ -321,6 +324,62 @@ def attend_quant(
                       v_q.astype(dtype))
     out = jnp.sum(rows * lanes, axis=2, keepdims=True)  # [B,G,1,F]
     return unfold_heads(out, h, head_dim)
+
+
+@functools.partial(jax.jit, static_argnames=("interpret",))
+def _quant_decode(q, k_q, ks, v_q, vs, layer, mask, lengths,
+                  interpret: bool = False) -> jax.Array:
+    """`attend_quant_layer`'s kernel arm: the queries folded as the planes'
+    rows are, the planes as a layer's lanes one after the other (a
+    reshape), the kernel's rows unfolded to [B, H, 1, Dh]. Jitted for its
+    TRACE (the compiler inlines it): the kernel's body is some thousand
+    operations, and an engine's start traces the decode step twice a
+    cache width (`engine/paged.py` `_chunk`, wide and not)."""
+    _, h, _, head_dim = q.shape
+    out = attention_ops.quant_decode_attention(
+        fold_heads(q, 1)[:, 0],
+        _head_lanes(h, k_q.shape[-1], head_dim, q.dtype),
+        k_q.reshape(-1, *k_q.shape[3:]), ks,
+        v_q.reshape(-1, *v_q.shape[3:]), vs, layer, mask, lengths,
+        head_dim=head_dim, interpret=interpret)
+    return unfold_heads(out[:, None], h, head_dim)
+
+
+def attend_quant_layer(
+    q: jax.Array,
+    k_q: jax.Array,
+    ks: jax.Array,
+    v_q: jax.Array,
+    vs: jax.Array,
+    layer,
+    rows: Optional[jax.Array],
+    mask: jax.Array,
+    lengths: jax.Array,
+) -> jax.Array:
+    """`attend_quant` over one layer of the STACKED int8 planes
+    ([L, R, G, S, F], scales [L, R, H, S]) as a layer scan carries them,
+    the step's own rows written; `rows` as `layer_rows` takes them,
+    `lengths` [B] what `attention_ops.mask_lengths` reads off the mask.
+
+    A decode step over folded planes (one query a row, one group, the
+    batch the planes' own rows, a width of whole blocks:
+    `quant_decode_engages`, shapes alone) is on the TPU ONE kernel that
+    reads each lane's live positions out of the planes where they lie
+    (`ops/attention.py` `quant_decode_attention`), and `attend_quant`'s two
+    products over the layer's whole planes everywhere else: the kernel's
+    reference, a prefill chunk, a speculative window, `tp` groups."""
+    def products():
+        return attend_quant(
+            q, layer_rows(k_q, layer, rows), layer_rows(ks, layer, rows),
+            layer_rows(v_q, layer, rows), layer_rows(vs, layer, rows), mask)
+
+    if rows is None and attention_ops.quant_decode_engages(
+            q.shape, k_q.shape):
+        return jax.lax.platform_dependent(
+            tpu=lambda: _quant_decode(q, k_q, ks, v_q, vs, layer, mask,
+                                      lengths),
+            default=products)
+    return products()
 
 
 def attend(

@@ -26,10 +26,11 @@ import jax
 import jax.numpy as jnp
 
 from . import quant
+from ..ops import attention as attention_ops
 from .common import (
     KVCache,
     attend,
-    attend_quant,
+    attend_quant_layer,
     fold_heads,
     folds_heads,
     causal_window_mask,
@@ -379,6 +380,9 @@ def forward(
                                                 cfg.head_dim)
         if fold_here:
             k0, v0 = fold_heads(k0, 1), fold_heads(v0, 1)
+        # What each row's mask lets it see, once a pass: the decode
+        # kernel reads a lane's planes that far (`attend_quant_layer`).
+        lengths = attention_ops.mask_lengths(mask) if quant_kv else None
 
         def body(carry, xs):
             x, ck, cv, cks, cvs = carry
@@ -426,17 +430,11 @@ def forward(
                             cvs, v_s[None], s_start
                         )
                 updated.update(k=ck2, v=cv2, ks=cks2, vs=cvs2)
+                if quant_kv:
+                    return attend_quant_layer(
+                        q, ck2, cks2, cv2, cvs2, layer, rows, mask, lengths)
                 k_att = layer_rows(ck2, layer, rows)
                 v_att = layer_rows(cv2, layer, rows)
-                if quant_kv:
-                    return attend_quant(
-                        q,
-                        k_att,
-                        layer_rows(cks2, layer, rows),
-                        v_att,
-                        layer_rows(cvs2, layer, rows),
-                        mask,
-                    )
                 return attend(
                     q, k_att.astype(q.dtype), v_att.astype(q.dtype), mask
                 )

@@ -2,7 +2,9 @@
 
 XLA's automatic fusion covers almost everything in this framework; kernels
 live here only where a hand schedule measurably beats it: `attention`
-(`latent_decode_attention`, decode over a latent cache), `ssm`
+(`latent_decode_attention`, decode over a latent cache;
+`quant_decode_attention`, decode over folded int8 planes that reads each
+lane's live positions alone), `ssm`
 (`ssm_step`), `kda` (`kda_step`), `lightning` (`lightning_step`),
 `sparse` (`sparse_append`, `sparse_select`, `sparse_decode`: a decode step
 that reads the blocks it chose) and `shortconv` (`shortconv_step`: a kernel

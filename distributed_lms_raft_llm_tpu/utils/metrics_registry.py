@@ -567,6 +567,24 @@ ENGINE_OVERRUN_LANE_STEPS = counter(
     "still in its slot (none once the slot has been handed on: "
     "engine_slots_handed_on)",
 )
+ENGINE_ATTN_POSITIONS_READ = counter(
+    "engine_attn_positions_read",
+    "positions of its K and V rows the decode kernel that goes by a "
+    "lane's length fetches (ops/attention.py quant_decode_attention: the "
+    "length rounded up to whole blocks of 32), summed over every decode "
+    "lane-step of the dispatches reaped, live lanes and dead ones (an "
+    "empty lane keeps its last tenant's length, a staged one is parked at "
+    "the width); the host replays the lanes' lengths from what a reap "
+    "reads anyway. Counted where the engine's planes are the kernel's "
+    "(folded int8, one group, a width of whole blocks, no speculation), "
+    "whatever backend runs, and absent elsewhere",
+)
+ENGINE_ATTN_POSITIONS_HELD = counter(
+    "engine_attn_positions_held",
+    "the cache's width summed over the same lane-steps: what the two "
+    "products over the whole planes read, and engine_attn_positions_read's "
+    "denominator",
+)
 ENGINE_SLOTS_HANDED_ON = counter(
     "engine_slots_handed_on",
     "slots staged for the next request while their previous request's "
@@ -847,6 +865,8 @@ ENGINE_LOOP_COUNTERS: Dict[str, str] = {
     "lane_steps": ENGINE_LANE_STEPS,
     "staged_lane_steps": ENGINE_STAGED_LANE_STEPS,
     "overrun_lane_steps": ENGINE_OVERRUN_LANE_STEPS,
+    "attn_positions_read": ENGINE_ATTN_POSITIONS_READ,
+    "attn_positions_held": ENGINE_ATTN_POSITIONS_HELD,
     "slots_handed_on": ENGINE_SLOTS_HANDED_ON,
     "one_chunk_dispatches": ENGINE_ONE_CHUNK_DISPATCHES,
     "prefill_passes": ENGINE_PREFILL_PASSES,

@@ -7,7 +7,17 @@ seed) and times a megastep of `--chunks` chunks (K) at the widest cache
 width, from the host's clock around a call that ends in `block_until_ready`:
 
 - with nothing staged and no lane live: every iteration is the decode step
-  over all slots (a dead lane computes what a live one does);
+  over all slots. "A dead lane computes what a live one does" holds for the
+  weights and for attention that XLA's products run over the whole width;
+  a kernel that goes by a lane's length (`ops/attention.py`
+  `quant_decode_attention`: gpt2-xl's int8 planes) reads ONE block of an
+  empty lane, so this reading flatters such a configuration's attention;
+- with `--contexts`, every lane live at a context of its own (`cell`: drawn
+  from the seed over what the configuration's first cell sends, a course, the
+  server's template, a question and a share of the answer; or `LO:HI`, or a
+  list the lanes cycle through): the decode step at the lengths the cell has,
+  which is the reading to compare two trees by where attention goes by
+  lengths. A context is cut to the width less the dispatch's iterations;
 - with slots staged on a prompt of the longest bucket (`--staged`: how
   many, one reading each; all of them where left out), so that every
   iteration also runs one prefill pass, which serves the oldest staged
@@ -60,6 +70,30 @@ def timed(engine, make_state, calls: int, k: int) -> tuple:
     return out, state, res[-2 if engine.family.routed else -1].tolist()
 
 
+def cell_contexts(config: dict, draw, lanes: int) -> list:
+    """`lanes` contexts as the configuration's first cell has them in the
+    middle of its window: a course's context by its share, the server's
+    template, a question (the traffic's clipped log-normal) and a uniform
+    share of the answer. Only a traffic file with `courses` and
+    `question_tokens` can be read so."""
+    with open(os.path.join(REPO, "BENCHMARK.json"), encoding="utf-8") as fh:
+        cell = next(w for w in json.load(fh)["workloads"]
+                    if w["config"] == config["name"])
+    with open(os.path.join(REPO, "benchmarks", "traffic",
+                           f"{cell['traffic']}.json"), encoding="utf-8") as fh:
+        traffic = json.load(fh)
+    courses, q = traffic["courses"], traffic["question_tokens"]
+    share = [c["share"] for c in courses]
+    course = draw.choice(len(courses), size=lanes,
+                         p=[x / sum(share) for x in share])
+    question = draw.lognormal(0.0, q["sigma"], lanes) * q["median"]
+    out = draw.integers(
+        0, config["serving"]["sampling"]["max_new_tokens"], lanes)
+    return [int(courses[c]["context_tokens"] + traffic["template_tokens"]
+                + min(max(round(x), q["lo"]), q["hi"]) + o)
+            for c, x, o in zip(course, question, out)]
+
+
 def traced_ops(engine, make_state, trace_dir: str, top: int,
                k: int) -> dict:
     """One more megastep under the profiler: the device's time by
@@ -93,6 +127,12 @@ def main(argv=None) -> int:
                          "controller dispatches while work waits, and the "
                          "program that holds the pass of several rows; a "
                          "longer rung serves one slot a pass")
+    ap.add_argument("--contexts", action="append", default=[],
+                    help="also time the dispatch with every lane live, "
+                         "once a reading: "
+                         "`cell` (the first cell's contexts, drawn from "
+                         "the seed), LO:HI (uniform), or N,N,... (the "
+                         "lanes cycle through the list)")
     ap.add_argument("--platform", default="tpu", choices=["tpu", "cpu"])
     ap.add_argument("--trace-dir", default=None,
                     help="also trace one megastep of each kind into this "
@@ -140,8 +180,38 @@ def main(argv=None) -> int:
                 )
         return engine._canon_state(state)
 
+    def live(contexts):
+        # Every lane decoding at its context: what the planes hold does
+        # not move a time, the lengths the attention goes by do.
+        state = idle()
+        put = lambda x, like: jax.device_put(  # noqa: E731
+            np.asarray(x, like.dtype), like.sharding)
+        return engine._canon_state(state._replace(
+            cache=state.cache._replace(
+                length=put(contexts, state.cache.length)),
+            active=put(np.ones(engine.slots), state.active),
+            stage_len=put(contexts, state.stage_len)))
+
     t_idle, after, _ = timed(engine, idle, args.calls, args.chunks)
     m_idle = statistics.median(t_idle)
+    lived, live_states = [], []
+    for spec in args.contexts:
+        draw = np.random.default_rng(args.seed)
+        if spec == "cell":
+            contexts = cell_contexts(config, draw, engine.slots)
+        elif ":" in spec:
+            lo, hi = map(int, spec.split(":"))
+            contexts = draw.integers(lo, hi + 1, engine.slots).tolist()
+        else:
+            given = [int(x) for x in spec.split(",")]
+            contexts = [given[i % len(given)] for i in range(engine.slots)]
+        contexts = [min(c, width - iterations) for c in contexts]
+        t_live, _, _ = timed(engine, partial(live, contexts), args.calls,
+                             args.chunks)
+        live_states.append(partial(live, contexts))
+        lived.append({
+            "contexts": contexts, "megastep_live_ms": t_live,
+            "step_live_ms": statistics.median(t_live) / iterations})
     passes = []
     for slots in args.staged or [engine.slots]:
         t_staged, _, (ran, served, _) = timed(
@@ -154,7 +224,8 @@ def main(argv=None) -> int:
     traces = {}
     if args.trace_dir:
         for name, make in (("idle", idle),
-                           ("staged", partial(staged, passes[-1]["staged"]))):
+                           ("staged", partial(staged, passes[-1]["staged"])),
+                           *(("live", make) for make in live_states[:1])):
             traces[f"trace_{name}"] = traced_ops(
                 engine, make, os.path.join(args.trace_dir, name), args.top,
                 args.chunks)
@@ -167,6 +238,7 @@ def main(argv=None) -> int:
                    in after.cache._asdict().items()
                    if x is not None and x.ndim > 1},
         "megastep_idle_ms": t_idle, "step_ms": m_idle / iterations,
+        "live": lived,
         "passes": passes, **traces,
     }))
     return 0

@@ -1012,3 +1012,33 @@ def test_gpt2_xl_int8_planes_ride_the_scans_unpadded(one_chip, chunks):
     scales = set(re.findall(r"f32\[48,16,25,384\]\{([\d,]+):", text))
     assert scales and all(order.startswith("3,") for order in scales), scales
     assert compiled.memory_analysis().temp_size_in_bytes < 1.8e9
+
+
+@BOTH_RUNGS
+def test_gpt2_xl_decode_attention_is_one_kernel_over_live_rows(one_chip,
+                                                              chunks):
+    """gpt2-xl's megastep as `test_gpt2_xl_int8_planes_ride_the_scans_
+    unpadded` compiles it: the decode step's layer body holds ONE Mosaic
+    call, `quant_decode` (ops/attention.py: each lane's live rows of K and
+    V, read where the planes lie), and neither of `attend_quant`'s two
+    products over a layer's whole planes is left anywhere in the module
+    (the in-scan prefill chunk unfolds its rows: other products). The
+    kernel's view of the planes, a layer's lanes one after the other, is a
+    bitcast of what the scans carry: tiled (32, 128) over positions and
+    features, and nowhere copied."""
+    compiled, _ = _benchmark_megastep(one_chip, "gpt2-xl", True, 384, chunks)
+    text = compiled.as_text()
+    calls = [line for line in text.splitlines()
+             if 'custom_call_target="tpu_custom_call"' in line]
+    assert len(calls) == 1, [line[:120] for line in calls]
+    assert re.search(r"/decode/while/body/[^\"]*quant_decode/pallas_call",
+                     calls[0]), calls[0][:400]
+    for product in ("bghf,bgsf->bghs", "bghs,bgsf->bghf"):
+        assert product not in text, product
+    views = set(re.findall(r"s8\[768,384,1664\]\{[^}]*\}", text))
+    assert views and all(
+        view.endswith("{2,1,0}")                      # the call's constraint
+        or view.endswith("{2,1,0:T(8,128)(4,1)}") for view in views), views
+    assert not [line for line in text.splitlines()
+                if re.search(r"= s8\[768,384,1664\]\S* copy(-start)?\(", line)]
+    assert _copies_inside_loops(text, "s8[768,384,1664]") == []
