@@ -210,7 +210,7 @@ class _ReqTrace:
     # Shared-prefix cache hit at admission (prompt tokens spliced from
     # the radix tree; None until the engine reports it, stays None on
     # engines without the prefix contract). Attributed to the request's
-    # engine.prefill/engine.partial_prefill span as prefix_hit_tokens.
+    # engine.stage span (the admission program) as prefix_hit_tokens.
     prefix_hit: Optional[int] = None
 
 
@@ -455,7 +455,15 @@ class PagedQueue:
                     DeadlineExpired("expired while queued; prefill skipped")
                 )
             return
-        rid = self.engine.submit(prompt)
+        # The tokenizer's pass, under its own name in a trace; then the
+        # one wait before `queue_wait`'s clock starts (engine.submit
+        # stamps the request's submit_time): the stay in `_incoming`
+        # plus that pass.
+        with Span("queue.submit"):
+            rid = self.engine.submit(prompt)
+        if self.metrics is not None:
+            self.metrics.hist("incoming_wait").observe(
+                time.monotonic() - t_enq)
         self._futures[rid] = fut
         self._spans[rid] = _ReqTrace(span, qspan, time.monotonic(),
                                      time.time(), 0.0,
@@ -836,8 +844,7 @@ class PagedQueue:
             if n <= 0:
                 continue
             attrs: Dict[str, Any] = dict(shared=True, dispatches=n)
-            if (entry.prefix_hit is not None
-                    and pname in ("prefill", "partial_prefill")):
+            if entry.prefix_hit is not None and pname == "stage":
                 # The request's own admission fact (not a shared
                 # aggregate): prompt tokens spliced from the
                 # shared-prefix cache instead of re-prefilled.

@@ -127,7 +127,16 @@ from .program_inventory import (
     width_holds_stored_run,
 )
 from .scoring import _score_program, derive_score_shapes, score_texts
-from .spans import KEYS, PROG, ProgramLog, Span, SpanSum, named_partial
+from .spans import (
+    KEYS,
+    PROG,
+    DispatchLedger,
+    ProgramLog,
+    Span,
+    SpanSum,
+    is_runtime_call,
+    named_partial,
+)
 from .sampling import (
     SamplingParams,
     sample_step,
@@ -1532,6 +1541,10 @@ class PagedEngine:
         # does the work and drained by pop_loop_stats().
         self._counts: Dict[str, int] = {}
         self._obs: Dict[str, List[float]] = {}
+        # Each megastep's device time on the host's clock, always on
+        # (engine/spans.py `DispatchLedger`), into the same two; told of
+        # every call into the runtime by `_span`.
+        self._ledger = DispatchLedger(self._count, self._observe)
         # Flight-recorder observability, drained by the serving queue:
         # (program, wall-clock start, dispatch seconds) per compiled-
         # program dispatch — program names key the inventory entries and
@@ -1572,7 +1585,14 @@ class PagedEngine:
     def _span(self, name: str, **attrs) -> Span:
         """A host span (engine/spans.py), summed by its name in `_progs`;
         `engine.prog.*` ones are besides the timed dispatches."""
-        return Span(name, self._progs, **attrs)
+        if is_runtime_call(name):
+            self._ledger.call_begins()
+        return Span(name, self._span_closed, **attrs)
+
+    def _span_closed(self, sp: Span) -> None:
+        self._progs(sp)
+        if is_runtime_call(sp.name):
+            self._ledger.call_ended(sp.ended_s)
 
     def _count(self, **amounts: int) -> None:
         for name, n in amounts.items():
@@ -1634,7 +1654,7 @@ class PagedEngine:
     def pop_prefix_hits(self) -> Dict[int, int]:
         """Drain rid -> shared-prefix tokens spliced at that request's
         admission (0 = the whole prompt prefilled). Feeds the per-request
-        `engine.prefill` span attributes on the trace."""
+        `engine.stage` span attributes on the trace."""
         out, self._prefix_hits = self._prefix_hits, {}
         return out
 
@@ -1989,6 +2009,7 @@ class PagedEngine:
         self._slot_req = [None] * self.slots
         self._pending = []
         self._inflight = []
+        self._ledger.reset()
         self.ttfts = {}
         self._stream_watch = set()
         self._final_tokens = {}
@@ -2573,7 +2594,8 @@ class PagedEngine:
         host's readback and reap overlap N+1's device compute instead of
         leaving the device idle for them. Completions therefore surface
         one step() call after their dispatch at steady state; the tail
-        drains in the same call once no live slot remains. Admissions join
+        drains in the same call once no live slot remains (`draining`
+        reaps: the dispatch ledger times none of them). Admissions join
         at dispatch boundaries, so the controller (next_megastep_k) sizes
         K against the waiting work's actual admission opportunity — the
         guaranteed-finish horizon from _slack_chunks — keeping megasteps
@@ -2601,15 +2623,15 @@ class PagedEngine:
                 with self._span("engine.dispatch", k=self.megastep_k):
                     self._dispatch(self.megastep_k)
             done: List[Tuple[int, str]] = []
-            while self._inflight and (
-                len(self._inflight) >= self.inflight_limit
-                if (self._live() or self._any_staged())
-                else True
-            ):
-                done.extend(self._reap(*self._inflight.pop(0)))
-                # _reap may finish the last live request: the loop
-                # condition re-evaluates _live(), so remaining dispatches
-                # drain right here.
+            while self._inflight:
+                # _reap may finish the last live request: `busy` is read
+                # anew each time round, so remaining dispatches drain
+                # right here.
+                busy = self._live() or self._any_staged()
+                if busy and len(self._inflight) < self.inflight_limit:
+                    break
+                done.extend(self._reap(*self._inflight.pop(0),
+                                       draining=not busy))
         return done
 
     def _dispatch(self, k: int) -> None:
@@ -2617,7 +2639,8 @@ class PagedEngine:
         self.state = self._canon_state(self.state)
         counts = moe = None
         rngs = self._step_keys(k)
-        with self.mesh, self._span(PROG + "megastep"):
+        dry = self._ledger.device_dry()
+        with self.mesh, self._span(PROG + "megastep", k=k):
             self.state, *outs = self._megastep(
                 self.params, self.state, rngs
             )
@@ -2628,6 +2651,7 @@ class PagedEngine:
             toks, counts, active, dead = outs
         else:
             toks, active, dead = outs
+        self._ledger.dispatched(toks.is_ready, dry)
         self._count(scan_iterations=k * self.chunk,
                     lane_steps=k * self.chunk * self.slots)
         if self._lanes is not None:
@@ -2665,7 +2689,8 @@ class PagedEngine:
 
     def _reap(self, toks_dev, counts_dev, active_dev, dead_dev,
               flipped_dev, firsts_dev, slot_snapshot,
-              moe_dev=None, served_dev=None) -> List[Tuple[int, str]]:
+              moe_dev=None, served_dev=None,
+              draining: bool = False) -> List[Tuple[int, str]]:
         """Read one dispatch's results — a megastep's whole [K, chunk, S]
         plane in one batched pass — and finish the requests it completed.
         The same pass also learns which staged slots FLIPPED live
@@ -2675,6 +2700,7 @@ class PagedEngine:
         straight from the live cache, and its decode walk starts at the
         flip's row (earlier rows are pre-flip pad filler, not content)."""
         # THE sync point of the engine loop.
+        passes = crowded = 0
         with self._span("engine.reap.wait") as wait, intended_transfer():
             toks = np.asarray(toks_dev)  # [K, chunk, S(, k+1)]
             counts = None if counts_dev is None else np.asarray(counts_dev)
@@ -2693,8 +2719,13 @@ class PagedEngine:
                             prefill_crowded_passes=crowded,
                             prefill_crowded_narrow_passes=(
                                 crowded if toks.shape[0] > 1 else 0))
+        k = toks.shape[0]
+        device_us = self._ledger.reaped(k, k * toks.shape[1], passes,
+                                        crowded, draining)
         self._observe("reap_wait", wait.wall_s)
-        with self._span("engine.reap.host"):
+        with self._span("engine.reap.host", k=k, passes=passes,
+                        wide=crowded if k == 1 else 0,
+                        device_us=device_us):
             if self._lanes is not None and self._lanes.sent:
                 self._count_attn_positions(toks, flipped, firsts,
                                            slot_snapshot,

@@ -29,12 +29,19 @@ live here and not in `utils/tracing.py`, which clients import):
 - `turn_budget(...)`: one turn of the serving loop (engine/batcher.py
   `PagedQueue._run`) parted into the host's own work, its wait for the
   device and what neither explains, in whole microseconds.
+- `DispatchLedger`: each megastep's device time, read on the host's clock
+  as two completions apart (each seen at the end of the call into the
+  runtime that waited for it) and laid against what the dispatch held
+  (its iterations, its prefill passes by their width).
 
 Span names: `engine.step` > `engine.admit` | `engine.dispatch` |
-`engine.reap.wait` | `engine.reap.host`, each with `engine.prog.*`
-children at the dispatch sites and `engine.keys` where the next sampling
-keys are split off (an admission, a dispatch); `queue.between_steps` and
-`queue.idle` on the serving loop's thread (engine/batcher.py).
+`engine.reap.wait` | `engine.reap.host` (with `k`, `passes`, `wide` and
+`device_us`: the ledger's reading of the dispatch it walks), each with
+`engine.prog.*` children at the dispatch sites (`engine.prog.megastep`
+with its `k`) and `engine.keys` where the next sampling keys are split
+off (an admission, a dispatch); `queue.between_steps`, `queue.idle` and
+`queue.submit` (the tokenizer's pass at an admission) on the serving
+loop's thread (engine/batcher.py).
 """
 
 from __future__ import annotations
@@ -101,6 +108,11 @@ class Span:
         self._ann.__enter__()
         self._c0 = time.thread_time()
         return self
+
+    @property
+    def ended_s(self) -> float:
+        """When it was left, on the monotonic clock."""
+        return self._t0 + self.wall_s
 
     def __exit__(self, exc_type, exc, tb) -> bool:
         cpu_s = time.thread_time() - self._c0
@@ -205,3 +217,148 @@ def turn_budget(wall_s: float, sums: Dict[str, SpanSum],
     for key, name in STEP_PHASES.items():
         out["loop_cpu_us_" + key] = us(sums.get(name, NO_SPANS).cpu_s)
     return out
+
+
+class DispatchLedger:
+    """Device time of each megastep as the host saw two completions apart.
+
+    The host is ahead of the device: it launches dispatch N+1 (and N+2)
+    before it reaps N, and for most of its wall it sleeps inside a call
+    into the runtime, which returns when the device finishes a program:
+    the reap's blocking read where the results were still to come, else
+    whichever launch met the runtime's full queue (the megastep's, a key
+    split's, an admission program's). So a megastep's completion is SEEN
+    where a call into the runtime began with it unfinished and ended with
+    it finished (`is_ready()` on its tokens at both ends: `call_begins`,
+    `call_ended`), and it is dated at that call's end. Where two
+    consecutive dispatches were both seen completing, and the later was
+    queued while the earlier still ran, the time between the two is the
+    device's time for the later one, with nothing idle inside. No thread,
+    no callback in a program, no profiler: a readiness flag at each end of
+    the calls the engine's spans already wrap, handed in by the engine, so
+    a test drives this with a made-up clock.
+
+    What the interval holds besides the megastep, and nothing is
+    subtracted: the copies of its few KB of results back to the host
+    (started at the dispatch), and the admission's own small programs
+    (`stage`, `stage_block`, `restore_state`, the key splits), which run
+    on the device between two megasteps and so lie inside the interval of
+    the dispatch they were launched before.
+
+    A dispatch that finished outside any such call (while the host walked
+    tokens, or between two steps), or together with its successor inside
+    one, is untimed (`late`: the host was not there) and anchors nothing.
+    One seen completing whose predecessor was not, or that was sent to a
+    device that had run dry, and the engine's first, the first after a
+    `reset()` and the reaps of the drain once nothing is live, are untimed
+    too (`unanchored`) but anchor the next. Timed and untimed together are
+    every megastep reaped. Counts go to `count(**amounts)` under the keys
+    of the metrics registry's ENGINE_LOOP_COUNTERS, a bare one-chunk
+    dispatch's seconds an iteration to `observe("bare_iteration_device",
+    s)`: over the timed dispatches of ONE chunk the sums are those least
+    squares needs for `us = b + n narrow + w wide`
+    (`benchmarks/layer_readers/dispatch_ledger.py` solves them)."""
+
+    __slots__ = ("_count", "_observe", "_anchor", "_flight", "_armed")
+    # What a reaped megastep is, exactly one of.
+    VERDICTS = ("timed_dispatches", "timed_long_dispatches",
+                "untimed_dispatches_late", "untimed_dispatches_unanchored")
+    _MISSED = -1.0  # a completion nobody saw, where a time would stand
+
+    def __init__(self, count: Callable[..., None],
+                 observe: Callable[[str, float], None]):
+        self._count, self._observe = count, observe
+        self.reset()
+
+    def reset(self) -> None:
+        # When the dispatch reaped last was SEEN completing, else None.
+        self._anchor: Optional[float] = None
+        # The dispatches in flight, oldest first: [is_ready, when it was
+        # seen completing (None: not yet; _MISSED), sent to a dry device].
+        self._flight: List[list] = []
+        # Its place in `_flight`, between a call's two ends.
+        self._armed: Optional[int] = None
+
+    def call_begins(self) -> None:
+        """Before a call into the runtime: the oldest dispatch in flight
+        that is still unfinished is the one this call may see completing.
+        One found finished here completed where nobody looked."""
+        self._armed = None
+        for i, entry in enumerate(self._flight):
+            if entry[1] is None:
+                if not entry[0]():
+                    self._armed = i
+                    return
+                entry[1] = self._MISSED
+
+    def call_ended(self, t: float) -> None:
+        """After that call, at `t` (monotonic seconds): if the dispatch is
+        finished now, `t` is when it completed, unless its successor
+        finished inside the same call (then neither was seen)."""
+        i, self._armed = self._armed, None
+        if i is None or not self._flight[i][0]():
+            return
+        self._flight[i][1] = t
+        if i + 1 < len(self._flight) and self._flight[i + 1][0]():
+            self._flight[i][1] = self._flight[i + 1][1] = self._MISSED
+
+    def device_dry(self) -> bool:
+        """Before a megastep's launch: whether nothing is in flight or the
+        newest in flight has already finished, so that the device ran out
+        of megasteps before this one was sent. (One that finishes while
+        the launch is held in the runtime's full queue leaves the device
+        waiting for the launch's last stretch, under a millisecond, which
+        then lies inside the interval: not dry.)"""
+        newest = self._flight[-1] if self._flight else None
+        return newest is None or newest[1] is not None or newest[0]()
+
+    def dispatched(self, is_ready: Callable[[], bool], dry: bool) -> None:
+        """After a megastep's launch returned; `is_ready()` says whether
+        it has finished, `dry` what `device_dry()` said before it."""
+        self._count(dispatches_device_dry=int(dry))
+        self._flight.append([is_ready, None, dry])
+
+    def reaped(self, k: int, iterations: int, passes: int, crowded: int,
+               draining: bool = False) -> int:
+        """The oldest dispatch in flight was read back (the reads are a
+        call into the runtime like any other: it was seen completing in
+        them or before, or missed). `k` chunks of `iterations` scan
+        iterations in all; `passes` prefill passes, `crowded` of them
+        with two or more slots staged: the wide pass at k = 1, a narrow
+        one on a longer rung. `draining`: nothing is live, no dispatch
+        follows this one. Returns the dispatch's device time in whole
+        microseconds, 0 where it is untimed."""
+        _, seen, dry = self._flight.pop(0)
+        anchor = self._anchor
+        late = seen is None or seen == self._MISSED
+        self._anchor = None if late or draining else seen
+        if late:
+            verdict = "untimed_dispatches_late"
+        elif anchor is None or dry or draining:
+            verdict = "untimed_dispatches_unanchored"
+        else:
+            verdict = "timed_dispatches" if k == 1 else "timed_long_dispatches"
+        # All four every time, three of them by 0: a series enters
+        # /metrics at its first count, and a share over the four has to be
+        # readable over a window in which one of them never occurred.
+        self._count(**{v: int(v == verdict) for v in self.VERDICTS})
+        if verdict.startswith("untimed"):
+            return 0
+        us = int(round((seen - anchor) * 1e6))
+        if k > 1:
+            self._count(timed_long_iterations=iterations,
+                        timed_long_device_us=us)
+            return us
+        wide, narrow = crowded, passes - crowded
+        self._count(timed_iterations=iterations, timed_device_us=us,
+                    timed_narrow_passes=narrow, timed_wide_passes=wide,
+                    timed_narrow_sq=narrow * narrow,
+                    timed_wide_sq=wide * wide,
+                    timed_narrow_x_wide=narrow * wide,
+                    timed_us_x_narrow=us * narrow,
+                    timed_us_x_wide=us * wide)
+        if not passes:
+            self._count(timed_bare_dispatches=1, timed_bare_device_us=us)
+            self._observe("bare_iteration_device",
+                          (seen - anchor) / iterations)
+        return us

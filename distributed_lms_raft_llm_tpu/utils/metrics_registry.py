@@ -750,6 +750,120 @@ ENGINE_PREFIX_TOKENS_FROM_RUNS = counter(
     "array a plane, one launch a run); over prefix_cache_hit_tokens it is "
     "the share of the hits the stored runs served",
 )
+ENGINE_TIMED_DISPATCHES = counter(
+    "engine_timed_dispatches",
+    "megasteps of ONE chunk (K = 1) whose device time the dispatch ledger "
+    "read (engine/spans.py DispatchLedger): device time as the host saw "
+    "two completions apart, each seen at the end of the call into the "
+    "runtime that began with the dispatch unfinished (the reap's read, or "
+    "a launch that met the runtime's full queue), the later dispatch "
+    "queued while the earlier ran, so nothing idle lies between. Over "
+    "these the "
+    "engine_timed_* sums are those least squares needs for device_us = b "
+    "+ n x narrow passes + w x wide passes",
+)
+ENGINE_TIMED_ITERATIONS = counter(
+    "engine_timed_iterations",
+    "scan iterations of the engine_timed_dispatches (each its chunk)",
+)
+ENGINE_TIMED_DEVICE_US = counter(
+    "engine_timed_device_us",
+    "device time as the host saw two completions apart, summed over the "
+    "engine_timed_dispatches, microseconds: the megastep, the copies of "
+    "its few KB of results, and the admission's small programs launched "
+    "before it (stage, stage_block, restore_state, the key splits); "
+    "nothing is subtracted",
+)
+ENGINE_TIMED_NARROW_PASSES = counter(
+    "engine_timed_narrow_passes",
+    "prefill passes of one row the engine_timed_dispatches ran (their "
+    "passes less the crowded ones)",
+)
+ENGINE_TIMED_WIDE_PASSES = counter(
+    "engine_timed_wide_passes",
+    "prefill passes of four rows the engine_timed_dispatches ran (the "
+    "passes that found two or more slots staged, at K = 1)",
+)
+ENGINE_TIMED_NARROW_SQ = counter(
+    "engine_timed_narrow_sq",
+    "sum over the engine_timed_dispatches of (narrow passes) squared",
+)
+ENGINE_TIMED_WIDE_SQ = counter(
+    "engine_timed_wide_sq",
+    "sum over the engine_timed_dispatches of (wide passes) squared",
+)
+ENGINE_TIMED_NARROW_X_WIDE = counter(
+    "engine_timed_narrow_x_wide",
+    "sum over the engine_timed_dispatches of narrow passes x wide passes",
+)
+ENGINE_TIMED_US_X_NARROW = counter(
+    "engine_timed_us_x_narrow",
+    "sum over the engine_timed_dispatches of device time as the host saw "
+    "two completions apart (microseconds) x narrow passes",
+)
+ENGINE_TIMED_US_X_WIDE = counter(
+    "engine_timed_us_x_wide",
+    "sum over the engine_timed_dispatches of device time as the host saw "
+    "two completions apart (microseconds) x wide passes",
+)
+ENGINE_TIMED_BARE_DISPATCHES = counter(
+    "engine_timed_bare_dispatches",
+    "of engine_timed_dispatches, those that ran no prefill pass: the "
+    "decode iterations alone",
+)
+ENGINE_TIMED_BARE_DEVICE_US = counter(
+    "engine_timed_bare_device_us",
+    "device time as the host saw two completions apart, summed over the "
+    "engine_timed_bare_dispatches, microseconds; over their iterations "
+    "(bare dispatches x chunk) it is a decode iteration's device time, "
+    "read directly",
+)
+ENGINE_TIMED_LONG_DISPATCHES = counter(
+    "engine_timed_long_dispatches",
+    "megasteps of a longer rung (K > 1: an idle server grows into them, "
+    "and their pass is always narrow) whose device time the dispatch "
+    "ledger read",
+)
+ENGINE_TIMED_LONG_ITERATIONS = counter(
+    "engine_timed_long_iterations",
+    "scan iterations of the engine_timed_long_dispatches (K x chunk each)",
+)
+ENGINE_TIMED_LONG_DEVICE_US = counter(
+    "engine_timed_long_device_us",
+    "device time as the host saw two completions apart, summed over the "
+    "engine_timed_long_dispatches, microseconds",
+)
+ENGINE_UNTIMED_DISPATCHES_LATE = counter(
+    "engine_untimed_dispatches_late",
+    "megasteps reaped whose completion no call into the runtime saw: "
+    "they finished while the host walked tokens or stood between two "
+    "steps (or two finished inside one call), so the dispatch is untimed "
+    "and anchors nothing (a pause of the HOST shows here and as a stall "
+    "in the loop's budget)",
+)
+ENGINE_UNTIMED_DISPATCHES_UNANCHORED = counter(
+    "engine_untimed_dispatches_unanchored",
+    "megasteps seen completing whose predecessor's completion was not "
+    "seen (it was late), or that were sent to a dry device, and the "
+    "engine's first dispatch, the first after a reset and the reaps of "
+    "the drain once nothing is live: "
+    "untimed, but the next is timed from it. Timed (one chunk and long) "
+    "and untimed (late and unanchored) together are every megastep "
+    "reaped",
+)
+ENGINE_DISPATCHES_DEVICE_DRY = counter(
+    "engine_dispatches_device_dry",
+    "megasteps sent while the newest one in flight was already finished "
+    "(or none was in flight): the device ran out of megasteps before "
+    "this one was sent",
+)
+ENGINE_BARE_ITERATION_DEVICE = histogram(
+    "engine_bare_iteration_device",
+    "device time as the host saw two completions apart of a one-chunk "
+    "dispatch that ran no prefill pass, over its iterations: seconds a "
+    "decode iteration, one observation a bare timed dispatch. A pause ON "
+    "the device is an observation many times the median",
+)
 ENGINE_STATE_SNAPSHOT_BYTES = gauge(
     "engine_state_snapshot_bytes",
     "bytes of the state snapshots the prefix tree holds for a recurrent "
@@ -760,6 +874,14 @@ QUEUE_WAIT = histogram(
     "queue_wait",
     "engine submit -> popped from the pending queue for admission, per "
     "request (waiting for a slot)",
+)
+INCOMING_WAIT = histogram(
+    "incoming_wait",
+    "PagedQueue.submit -> the engine has the request, per request: its "
+    "stay in the queue's incoming list until the loop's next turn, plus "
+    "the tokenizer's pass inside engine.submit (the queue.submit span). "
+    "Before queue_wait's clock starts: incoming_wait + queue_wait + "
+    "prefill_wait is the server's whole share of a first token",
 )
 PREFILL_WAIT = histogram(
     "prefill_wait",
@@ -892,6 +1014,25 @@ ENGINE_LOOP_COUNTERS: Dict[str, str] = {
     "admissions": ENGINE_ADMISSIONS,
     "stage_block_launches": ENGINE_STAGE_BLOCK_LAUNCHES,
     "prefix_tokens_from_runs": ENGINE_PREFIX_TOKENS_FROM_RUNS,
+    # The dispatch ledger (engine/spans.py `DispatchLedger`).
+    "timed_dispatches": ENGINE_TIMED_DISPATCHES,
+    "timed_iterations": ENGINE_TIMED_ITERATIONS,
+    "timed_device_us": ENGINE_TIMED_DEVICE_US,
+    "timed_narrow_passes": ENGINE_TIMED_NARROW_PASSES,
+    "timed_wide_passes": ENGINE_TIMED_WIDE_PASSES,
+    "timed_narrow_sq": ENGINE_TIMED_NARROW_SQ,
+    "timed_wide_sq": ENGINE_TIMED_WIDE_SQ,
+    "timed_narrow_x_wide": ENGINE_TIMED_NARROW_X_WIDE,
+    "timed_us_x_narrow": ENGINE_TIMED_US_X_NARROW,
+    "timed_us_x_wide": ENGINE_TIMED_US_X_WIDE,
+    "timed_bare_dispatches": ENGINE_TIMED_BARE_DISPATCHES,
+    "timed_bare_device_us": ENGINE_TIMED_BARE_DEVICE_US,
+    "timed_long_dispatches": ENGINE_TIMED_LONG_DISPATCHES,
+    "timed_long_iterations": ENGINE_TIMED_LONG_ITERATIONS,
+    "timed_long_device_us": ENGINE_TIMED_LONG_DEVICE_US,
+    "untimed_dispatches_late": ENGINE_UNTIMED_DISPATCHES_LATE,
+    "untimed_dispatches_unanchored": ENGINE_UNTIMED_DISPATCHES_UNANCHORED,
+    "dispatches_device_dry": ENGINE_DISPATCHES_DEVICE_DRY,
     # One turn's budget (engine/spans.py `turn_budget`), counted by
     # PagedQueue from the spans the engine drains with the rest.
     "loop_wall_us": ENGINE_LOOP_WALL_US,
@@ -910,6 +1051,7 @@ ENGINE_LOOP_HISTOGRAMS: Dict[str, str] = {
     "decode_lanes": ENGINE_DECODE_LANES,
     "staged_iterations": ENGINE_STAGED_ITERATIONS,
     "host_work": ENGINE_HOST_WORK,
+    "bare_iteration_device": ENGINE_BARE_ITERATION_DEVICE,
 }
 
 # Storage layer (raft/storage.py + lms/persistence.py via lms/node.py).

@@ -232,3 +232,58 @@ def test_trace_report_waterfall_smoke(cluster, traced_ask, capsys):
 def test_trace_report_unknown_trace_fails(cluster, capsys):
     url = f"http://127.0.0.1:{cluster.health_port(cluster.node_ids()[0])}"
     assert trace_report.main(["--endpoint", url, "never-existed"]) == 2
+
+
+# ------------------------------------------- the real engine's prefix hit
+
+
+@pytest.fixture(scope="module")
+def paged_cluster(tmp_path_factory):
+    """A second cluster whose tutoring node serves the real paged engine
+    at tiny size, prefix cache on (the echo stand-in splices nothing)."""
+    c = SimCluster(str(tmp_path_factory.mktemp("trace-e2e-paged")),
+                   SimConfig(tutoring_engine="tiny-paged"))
+    c.start()
+    try:
+        assert c.wait_leader(timeout=20.0) is not None
+        yield c
+    finally:
+        c.stop()
+
+
+def test_a_prefix_hit_rides_the_requests_stage_span(paged_cluster):
+    """The same question twice: the second admission splices its prompt's
+    blocks from the radix tree, and its trace says how many tokens, on
+    the admission program that exists (`engine.stage`; the `prefill` and
+    `partial_prefill` programs the attribute used to ride are gone)."""
+    client = LMSClient(
+        paged_cluster.client_servers(),
+        discovery_rounds=8, discovery_backoff_s=0.2,
+        rpc_retries=6, rpc_timeout=5.0,
+        request_timeout_s=20.0, llm_timeout_s=15.0,
+        backoff_base_s=0.02, backoff_max_s=0.3, seed=12,
+    )
+    try:
+        assert client.register("prefixee", "pw", "student") is not None
+        assert client.login("prefixee", "pw")
+        assert client.upload_assignment(
+            "prefixee_hw.pdf", pdf.make_pdf(ASSIGNMENT_TEXT))
+        hits = []
+        for n in (1, 2):
+            rid = f"trace-e2e-prefix-{n}"
+            resp = client.ask_llm(
+                "Explain Raft leader election and log replication.",
+                budget_s=15.0, request_id=rid)
+            assert resp.success
+            doc = paged_cluster.admin_get(paged_cluster.node_ids()[0],
+                                          f"/admin/trace/{rid}")
+            assert doc["ok"]
+            by_name = _spans_by_name(doc["trace"])
+            (stage,) = by_name["engine.stage"]
+            assert stage["attrs"]["shared"] is True
+            hits.append(stage["attrs"]["prefix_hit_tokens"])
+            assert "prefix_hit_tokens" not in by_name[
+                "engine.megastep"][0]["attrs"]
+    finally:
+        client.close()
+    assert hits[0] == 0 and hits[1] > 0
